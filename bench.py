@@ -1,12 +1,13 @@
 """Benchmark: flagship GPT training-step throughput on the available chip(s).
 
-Prints TWO JSON lines (driver records the last):
+Needs a TPU: without one it exits non-zero and prints no metric (a number
+from a CPU is never written under a device metric's name).
+
+Prints one JSON line per leg; the last two are
   1. gpt2-350m ZeRO-1 sustained throughput (round-2 continuity metric)
   2. gpt2-1.3b ZeRO-3 device-resident throughput — the BASELINE.md
      north-star config, runnable on ONE v5e chip via pure-bf16 state
-     (params-are-master + bf16 moments + bf16 grad accumulation; host
-     offload is relay-bandwidth-starved here — see docs/BENCHMARKS.md
-     roofline notes).
+     (params-are-master + bf16 moments + bf16 grad accumulation).
 
 Baseline: the reference's headline sustained training throughput of
 50 TFLOPS/GPU (ZeRO-3 Offload on V100, docs/_posts/2021-03-08-zero3-offload.md:65;
@@ -40,10 +41,10 @@ def _emit(r, metric):
 def paged_decode_microbench():
     """int8-vs-baseline paged-decode attention step (round 17): same block
     table, same query, pool stored int8 + per-row scales vs the model
-    dtype. On TPU this times the in-kernel dequant tier (int8 crosses
-    HBM); on CPU the jnp reference's post-gather dequant. Emits one JSON
-    line; under ``DSTPU_SERVE_BENCH_GATE=1`` an int8 step slower than 2x
-    the baseline is fatal (the SERVEBENCH gate convention)."""
+    dtype. Times the Pallas kernel's in-kernel dequant tier (int8 crosses
+    HBM); TPU only. Emits one JSON line; under ``DSTPU_SERVE_BENCH_GATE=1``
+    an int8 step slower than 2x the baseline is fatal (the SERVEBENCH gate
+    convention)."""
     import time
 
     import jax
@@ -53,10 +54,9 @@ def paged_decode_microbench():
     from deepspeed_tpu.ops.attention import paged_attention
     from deepspeed_tpu.quant_format import kv_quantize
 
-    on_tpu = jax.default_backend() == "tpu"
-    base_dtype = jnp.bfloat16 if on_tpu else jnp.float32
-    B, nh, hd, bs = (8, 16, 64, 32) if on_tpu else (4, 8, 64, 32)
-    num_blocks, nbk = (1024, 32) if on_tpu else (128, 8)
+    base_dtype = jnp.bfloat16
+    B, nh, hd, bs = 8, 16, 64, 32
+    num_blocks, nbk = 1024, 32
     rng = np.random.default_rng(0)
     kp = rng.standard_normal((nh, num_blocks, bs, hd)).astype(np.float32)
     vp = rng.standard_normal((nh, num_blocks, bs, hd)).astype(np.float32)
@@ -73,11 +73,11 @@ def paged_decode_microbench():
         q, k, v, bt, lens, k_scale=ks, v_scale=vs))
 
     def timed(fn, *a, iters=30):
-        np.asarray(fn(*a).reshape(-1)[0])           # compile + warm
+        jax.block_until_ready(fn(*a))               # compile + warm
         t0 = time.perf_counter()
         for _ in range(iters):
             out = fn(*a)
-        np.asarray(out.reshape(-1)[0])
+        jax.block_until_ready(out)
         return (time.perf_counter() - t0) / iters
 
     t_base = timed(f_base, q, kb, vb)
@@ -93,7 +93,8 @@ def paged_decode_microbench():
                    "batch": B, "heads": nh, "head_dim": hd,
                    "block_size": bs, "blocks_per_seq": nbk,
                    "pool_blocks": num_blocks,
-                   "backend": jax.default_backend()},
+                   "platform": jax.devices()[0].platform,
+                   "device_kind": jax.devices()[0].device_kind},
     }), flush=True)
     if t_int8 > 2.0 * t_base:
         msg = (f"PAGED-DECODE REGRESSION: int8 step {t_int8 * 1e3:.3f}ms > "
@@ -108,95 +109,88 @@ def main():
     import jax
     from deepspeed_tpu.benchmarks.training_bench import run_training_bench
 
-    on_tpu = jax.default_backend() == "tpu"
-    if on_tpu:
-        import gc
+    if jax.devices()[0].platform != "tpu":
+        raise SystemExit("bench.py measures the chip: JAX found no TPU "
+                         f"({jax.devices()[0].platform}); nothing printed")
+    from deepspeed_tpu.utils.compile_cache import configure_compile_cache
+    configure_compile_cache()
+    import gc
 
-        # tiny HBM footprint: the decode microbench runs before the
-        # training legs claim the chip
-        paged_decode_microbench()
-        gc.collect()
-        jax.clear_caches()
-        # the 1.3b legs need nearly the whole chip: run them FIRST (clean
-        # HBM), free everything, then run the 350m leg; emit the north-star
-        # 1.3b seq-1024 line LAST so the driver records it.
-        # Per-step timings are individually fenced (round-3 Weak #1); step
-        # counts are sized so every leg runs 45-90 s of timed steps at the
-        # round-5 gas settings. Config rationale: docs/BENCHMARKS.md
-        # round-5 sweep (fixed ~0.33 s/step overhead amortized by gas;
-        # "full" remat frees HBM for micro 2 at seq 2048).
-        r13 = run_training_bench("gpt2-1.3b", seq=1024, micro=2, gas=128,
-                                 steps=5, zero_stage=3, remat=True,
-                                 remat_policy="dots", fused_loss=True,
-                                 pure_bf16=True, grad_accum_dtype="bf16",
-                                 verbose=False)
-        gc.collect()
-        jax.clear_caches()
-        # seq 2048: "full" remat frees enough HBM for micro 2 (round 4's
-        # micro-1 was the binding constraint: 84.5 TF); gas 32 amortizes
-        # the fixed step overhead; 512-token CE chunks suit the longer seq
-        r20 = run_training_bench("gpt2-1.3b", seq=2048, micro=2, gas=32,
-                                 steps=6, zero_stage=3, remat=True,
-                                 remat_policy="full", fused_loss=True,
-                                 loss_chunk=512, pure_bf16=True,
-                                 grad_accum_dtype="bf16", verbose=False)
-        gc.collect()
-        jax.clear_caches()
-        # modern-decoder leg (round 4): TinyLlama-1.1B shapes — RMSNorm,
-        # SwiGLU, GQA 32q/4kv, rotary, untied head (docs/BENCHMARKS.md)
-        rll = run_training_bench("llama-1.1b", seq=1024, micro=2, gas=64,
-                                 steps=6, zero_stage=3, remat=True,
-                                 remat_policy="dots", fused_loss=True,
-                                 pure_bf16=True, grad_accum_dtype="bf16",
-                                 verbose=False)
-        gc.collect()
-        jax.clear_caches()
-        # masked BERT-large @ seq 2048 (round 6): REAL ragged padding masks
-        # riding the flash kernel in-kernel vs the O(S²)-materializing jnp
-        # fallback — the verdict's "unrepresentative maskless leg" replaced.
-        # The jnp leg needs micro 2 + full remat (its [B,H,S,S] logits are
-        # the memory hog the kernel path exists to avoid).
-        rbf = run_training_bench("bert-large", seq=2048, micro=8, gas=4,
-                                 steps=4, zero_stage=1, remat=True,
-                                 remat_policy="dots", masked=True,
-                                 attention_impl="flash", verbose=False)
-        gc.collect()
-        jax.clear_caches()
-        rbr = run_training_bench("bert-large", seq=2048, micro=2, gas=4,
-                                 steps=3, zero_stage=1, remat=True,
-                                 remat_policy="full", masked=True,
-                                 attention_impl="reference", verbose=False)
-        gc.collect()
-        jax.clear_caches()
-        _emit(rbf, "bert_large_masked_seq2048_flash_tflops_per_chip")
-        print(json.dumps({
-            "metric": "bert_large_masked_seq2048_flash_vs_jnp",
-            "value": round(rbf["value"] / max(rbr["value"], 1e-9), 3),
-            "unit": "x",
-            "detail": {"flash_tflops": rbf["value"],
-                       "jnp_tflops": rbr["value"],
-                       "flash": rbf["detail"], "jnp": rbr["detail"]},
-        }), flush=True)
-        # micro 4 (the round-4 cold-start autotune's pick over the hand
-        # micro 16) x gas 128 (round-5 amortization sweep)
-        r = run_training_bench("gpt2-350m", seq=1024, micro=4, gas=128,
-                               steps=6, zero_stage=1, remat=True,
-                               remat_policy="dots", fused_loss=True,
-                               verbose=False)
-        _emit(r, "gpt2_train_tflops_per_chip")
-        _emit(rll, "llama_1p1b_zero3_train_tflops_per_chip")
-        _emit(r20, "gpt2_1p3b_seq2048_zero3_train_tflops_per_chip")
-        _emit(r13, "gpt2_1p3b_zero3_train_tflops_per_chip")
-    else:  # smoke path for CPU-only environments
-        paged_decode_microbench()
-        r = run_training_bench("gpt2-tiny", seq=128, micro=8, gas=1, steps=3,
-                               zero_stage=1, fused_loss=True, verbose=False)
-        _emit(r, "gpt2_train_tflops_per_chip")
-        r = run_training_bench("gpt2-tiny", seq=128, micro=8, gas=1, steps=3,
-                               zero_stage=3, pure_bf16=True,
-                               grad_accum_dtype="bf16", fused_loss=True,
-                               verbose=False)
-        _emit(r, "gpt2_1p3b_zero3_train_tflops_per_chip")
+    # tiny HBM footprint: the decode microbench runs before the
+    # training legs claim the chip
+    paged_decode_microbench()
+    gc.collect()
+    jax.clear_caches()
+    # the 1.3b legs need nearly the whole chip: run them FIRST (clean
+    # HBM), free everything, then run the 350m leg; emit the north-star
+    # 1.3b seq-1024 line LAST.
+    # Per-step timings are individually fenced (round-3 Weak #1); step
+    # counts are sized so every leg runs 45-90 s of timed steps at the
+    # round-5 gas settings. Config rationale: docs/BENCHMARKS.md
+    # round-5 sweep (fixed ~0.33 s/step overhead amortized by gas;
+    # "full" remat frees HBM for micro 2 at seq 2048).
+    r13 = run_training_bench("gpt2-1.3b", seq=1024, micro=2, gas=128,
+                             steps=5, zero_stage=3, remat=True,
+                             remat_policy="dots", fused_loss=True,
+                             pure_bf16=True, grad_accum_dtype="bf16",
+                             verbose=False)
+    gc.collect()
+    jax.clear_caches()
+    # seq 2048: "full" remat frees enough HBM for micro 2 (round 4's
+    # micro-1 was the binding constraint: 84.5 TF); gas 32 amortizes
+    # the fixed step overhead; 512-token CE chunks suit the longer seq
+    r20 = run_training_bench("gpt2-1.3b", seq=2048, micro=2, gas=32,
+                             steps=6, zero_stage=3, remat=True,
+                             remat_policy="full", fused_loss=True,
+                             loss_chunk=512, pure_bf16=True,
+                             grad_accum_dtype="bf16", verbose=False)
+    gc.collect()
+    jax.clear_caches()
+    # modern-decoder leg (round 4): TinyLlama-1.1B shapes — RMSNorm,
+    # SwiGLU, GQA 32q/4kv, rotary, untied head (docs/BENCHMARKS.md)
+    rll = run_training_bench("llama-1.1b", seq=1024, micro=2, gas=64,
+                             steps=6, zero_stage=3, remat=True,
+                             remat_policy="dots", fused_loss=True,
+                             pure_bf16=True, grad_accum_dtype="bf16",
+                             verbose=False)
+    gc.collect()
+    jax.clear_caches()
+    # masked BERT-large @ seq 2048 (round 6): REAL ragged padding masks
+    # riding the flash kernel in-kernel vs the O(S²)-materializing jnp
+    # fallback — the verdict's "unrepresentative maskless leg" replaced.
+    # The jnp leg needs micro 2 + full remat (its [B,H,S,S] logits are
+    # the memory hog the kernel path exists to avoid).
+    rbf = run_training_bench("bert-large", seq=2048, micro=8, gas=4,
+                             steps=4, zero_stage=1, remat=True,
+                             remat_policy="dots", masked=True,
+                             attention_impl="flash", verbose=False)
+    gc.collect()
+    jax.clear_caches()
+    rbr = run_training_bench("bert-large", seq=2048, micro=2, gas=4,
+                             steps=3, zero_stage=1, remat=True,
+                             remat_policy="full", masked=True,
+                             attention_impl="reference", verbose=False)
+    gc.collect()
+    jax.clear_caches()
+    _emit(rbf, "bert_large_masked_seq2048_flash_tflops_per_chip")
+    print(json.dumps({
+        "metric": "bert_large_masked_seq2048_flash_vs_jnp",
+        "value": round(rbf["value"] / max(rbr["value"], 1e-9), 3),
+        "unit": "x",
+        "detail": {"flash_tflops": rbf["value"],
+                   "jnp_tflops": rbr["value"],
+                   "flash": rbf["detail"], "jnp": rbr["detail"]},
+    }), flush=True)
+    # micro 4 (the round-4 cold-start autotune's pick over the hand
+    # micro 16) x gas 128 (round-5 amortization sweep)
+    r = run_training_bench("gpt2-350m", seq=1024, micro=4, gas=128,
+                           steps=6, zero_stage=1, remat=True,
+                           remat_policy="dots", fused_loss=True,
+                           verbose=False)
+    _emit(r, "gpt2_train_tflops_per_chip")
+    _emit(rll, "llama_1p1b_zero3_train_tflops_per_chip")
+    _emit(r20, "gpt2_1p3b_seq2048_zero3_train_tflops_per_chip")
+    _emit(r13, "gpt2_1p3b_zero3_train_tflops_per_chip")
 
 
 if __name__ == "__main__":

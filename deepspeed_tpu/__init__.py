@@ -8,18 +8,6 @@ on jax/XLA/pjit/shard_map/Pallas. Public surface mirrors the reference's
 
 from typing import Optional, Tuple
 
-# forward-compat (OPT-IN): the package targets the modern `jax.shard_map`
-# entry point; older jax (the 0.4.x line) only ships
-# jax.experimental.shard_map with different kwargs. DSTPU_JAX_COMPAT=1
-# installs an adapter before any submodule imports. Off by default: on
-# the 0.4.x jaxlib the adapter unlocks compile paths (qwZ+TP, SPMD
-# pipeline) that ABORT inside XLA — a clean trace-time AttributeError is
-# strictly safer than a compiler crash taking down the process.
-import os as _os
-if _os.environ.get("DSTPU_JAX_COMPAT") == "1":
-    from .utils.jax_compat import install_shard_map_compat as _ism
-    _ism()
-
 from .version import __version__
 from .config import DeepSpeedConfig, load_config
 from . import comm

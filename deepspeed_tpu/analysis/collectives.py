@@ -74,11 +74,7 @@ for _name, _pos in _FACADE_FNS.items():
     FACADE_COLLECTIVES[f"deepspeed_tpu.comm.comm.{_name}"] = _pos
 
 #: Wrappers that establish a named-axis context for the callable they map
-SHARD_WRAPPERS = {"jax.shard_map", "shard_map",
-                  "jax.experimental.shard_map.shard_map",
-                  # the version-portable wrapper (modern kwargs, legacy
-                  # fallback) the comm-plan collectives build through
-                  "deepspeed_tpu.utils.jax_compat.shard_map"}
+SHARD_WRAPPERS = {"jax.shard_map", "shard_map"}
 PMAP_WRAPPERS = {"jax.pmap"}
 
 #: Mesh constructors whose axis tuple declares axis names project-wide

@@ -42,7 +42,7 @@ TRACING_WRAPPERS = {
     "jax.lax.scan", "jax.lax.cond", "jax.lax.while_loop",
     "jax.lax.fori_loop", "jax.lax.switch", "jax.lax.map",
     "jax.lax.associative_scan", "jax.lax.custom_root",
-    "jax.experimental.shard_map.shard_map", "shard_map",
+    "shard_map",
     "jax.experimental.multihost_utils.host_local_array_to_global_array",
     "flax.linen.scan", "flax.linen.remat", "nn.scan", "nn.remat",
 }

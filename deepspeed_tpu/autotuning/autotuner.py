@@ -375,6 +375,10 @@ def subprocess_runner(cmd: List[str], exps_dir: str,
     def run(config: Dict, slot: Optional[Dict] = None,
             deadline: Optional[Callable[[], Optional[float]]] = None
             ) -> Dict[str, float]:
+        # one process per chip: sound only while THIS process stays off
+        # JAX — a parent that holds the TPU leaves the experiment none
+        from ..utils.chip_owner import refuse_children_on_held_tpu
+        refuse_children_on_held_tpu("autotuner script runner", 1)
         with lock:
             n = next(counter)
         cfg_path = os.path.join(exps_dir, f"exp_{n}_config.json")
@@ -389,15 +393,13 @@ def subprocess_runner(cmd: List[str], exps_dir: str,
         env = dict(os.environ, **{METRIC_FILE_ENV: metric_path})
         if slot:
             # pin the launch to its reservation (parallel scheduler):
-            # device slots restrict the runtime's visible accelerators
-            # (TPU + CUDA spellings so the child's backend picks it up),
+            # device slots restrict the runtime's visible accelerators,
             # host slots carry explicit env
             if slot.get("devices"):
                 dev = str(slot["devices"])
                 env["DSTPU_SLOT_DEVICES"] = dev
                 env["TPU_VISIBLE_CHIPS"] = dev
                 env["TPU_VISIBLE_DEVICES"] = dev
-                env["CUDA_VISIBLE_DEVICES"] = dev
             env.update(slot.get("env") or {})
         out_path = os.path.join(exps_dir, f"exp_{n}_output.log")
         out_f = open(out_path, "w")

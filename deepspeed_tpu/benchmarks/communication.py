@@ -34,7 +34,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..utils.jax_compat import shard_map
 
 #: wire formats each op can sweep (exact always; quantized/overlap where
 #: an implementation exists in runtime/comm). The overlap family times a
@@ -112,22 +111,22 @@ def _collective_fn(op: str, mesh, axis: str = "all") -> Callable:
     manual = {axis}
 
     if op == "all_reduce":
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             lambda x: jax.lax.psum(x, axis),
             mesh=mesh, in_specs=P(axis), out_specs=P(axis),
             axis_names=manual, check_vma=False))
     if op == "all_gather":
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             lambda x: jax.lax.all_gather(x, axis, tiled=True),
             mesh=mesh, in_specs=P(axis), out_specs=P(),
             axis_names=manual, check_vma=False))
     if op == "reduce_scatter":
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             lambda x: jax.lax.psum_scatter(x, axis, tiled=True),
             mesh=mesh, in_specs=P(), out_specs=P(axis),
             axis_names=manual, check_vma=False))
     if op == "all_to_all":
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             lambda x: jax.lax.all_to_all(
                 x.reshape(n, -1), axis, split_axis=0, concat_axis=0,
                 tiled=True).reshape(-1),
@@ -135,7 +134,7 @@ def _collective_fn(op: str, mesh, axis: str = "all") -> Callable:
             axis_names=manual, check_vma=False))
     if op == "pt2pt":
         perm = [(i, (i + 1) % n) for i in range(n)]
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             lambda x: jax.lax.ppermute(x, axis, perm),
             mesh=mesh, in_specs=P(axis), out_specs=P(axis),
             axis_names=manual, check_vma=False))
@@ -403,6 +402,8 @@ def main(argv=None):
                         "regression; DSTPU_COMM_BENCH_GATE=1 makes it "
                         "fatal)")
     args = p.parse_args(argv)
+    from ..utils.compile_cache import configure_compile_cache
+    configure_compile_cache()
     dtype = {"bfloat16": jnp.bfloat16, "float32": jnp.float32,
              "float16": jnp.float16}[args.dtype]
     sizes = [float(s) for s in args.sizes_mb.split(",")]

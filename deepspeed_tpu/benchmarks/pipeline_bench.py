@@ -138,9 +138,7 @@ def _pipe_bench_row(pp, n_micro, hidden, layers, seq, mb, steps):
     optimizer-equivalent step, ``mpmd_step_s`` the per-stage-programs
     executor on submeshes of the same mesh — same model, same schedule
     tables, so the delta IS the placement cost (host-driven dispatch +
-    explicit transfers vs one compiled scan). On jax builds without
-    ``jax.shard_map`` the SPMD cell records null (the documented 0.4.x
-    gap) and the MPMD cell still anchors the convention.
+    explicit transfers vs one compiled scan).
     """
     import jax
     import jax.numpy as jnp
@@ -173,17 +171,14 @@ def _pipe_bench_row(pp, n_micro, hidden, layers, seq, mb, steps):
 
     mpmd_s = timed(lambda: piped.mpmd_value_and_grad(params, batch,
                                                      mesh=mesh))
-    spmd_s = None
-    if hasattr(jax, "shard_map"):
-        fn = jax.jit(lambda p, b: piped.train_value_and_grad(p, b,
-                                                             mesh=mesh))
-        compiled = fn.lower(params, batch).compile()
-        spmd_s = timed(lambda: compiled(params, batch))
+    fn = jax.jit(lambda p, b: piped.train_value_and_grad(p, b, mesh=mesh))
+    compiled = fn.lower(params, batch).compile()
+    spmd_s = timed(lambda: compiled(params, batch))
     t = build_1f1b_tables(n_micro, pp)
     return {
         "pp": pp, "n_micro": n_micro, "hidden": hidden, "layers": layers,
         "seq": seq, "mb": mb,
-        "spmd_step_s": None if spmd_s is None else round(spmd_s, 4),
+        "spmd_step_s": round(spmd_s, 4),
         "mpmd_step_s": round(mpmd_s, 4),
         "bubble_theory": round(bubble_fraction(n_micro, pp), 4),
         "bubble_1f1b_measured": round(1.0 - n_micro / t["ticks"], 4),
@@ -238,31 +233,17 @@ def _record_sweep(rows, baseline_dir):
 
 
 def _ensure_devices(n):
-    """Re-exec in a clean subprocess configured for n virtual CPU devices
-    when the current process's jax is already pinned to another backend
-    (shared recipe: utils/respawn.clean_cpu_env)."""
-    import subprocess
-    import sys
+    """The bench runs on the devices JAX reports and on nothing else: with
+    fewer than it needs it fails (no re-exec onto virtual CPU devices — a
+    row must name the device it was measured on)."""
     import jax
-    from ..utils.respawn import clean_cpu_env
-    if len(jax.devices()) >= n:
-        return False
-    env = clean_cpu_env(n)
-    env["DSTPU_PIPEBENCH_CHILD"] = "1"
-    proc = subprocess.run(
-        [sys.executable, "-m", "deepspeed_tpu.benchmarks.pipeline_bench"]
-        + sys.argv[1:], env=env)
-    if proc.returncode == -6:
-        # older jaxlibs hard-abort on the raised CPU-collective timeout
-        # flags ("Unknown flags in XLA_FLAGS") — retry without them, the
-        # dryrun_multichip recipe
-        env = clean_cpu_env(n, collective_timeout_flags=False)
-        env["DSTPU_PIPEBENCH_CHILD"] = "1"
-        proc = subprocess.run(
-            [sys.executable, "-m",
-             "deepspeed_tpu.benchmarks.pipeline_bench"]
-            + sys.argv[1:], env=env)
-    sys.exit(proc.returncode)
+    have = len(jax.devices())
+    if have < n:
+        raise SystemExit(
+            f"pipeline_bench needs {n} devices; JAX reports {have} "
+            f"({jax.devices()[0].platform}). For a CPU rehearsal set "
+            "JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_"
+            f"count={n} yourself.")
 
 
 def main(argv=None):
@@ -282,22 +263,19 @@ def main(argv=None):
                         "PIPEBENCH_r<k>.json under --baseline-dir")
     p.add_argument("--baseline-dir", default=".", dest="baseline_dir")
     args = p.parse_args(argv)
-    if os.environ.get("DSTPU_PIPEBENCH_CHILD") != "1":
-        _ensure_devices(max(args.pp * 2, 8))
+    _ensure_devices(max(args.pp * 2, 8))
+    from ..utils.compile_cache import configure_compile_cache
+    configure_compile_cache()
 
     print(json.dumps({"bubble_table": _bubble_rows(
         [(m, args.pp) for m in args.micros]
         + [(8, 2), (16, 8)])}))
     import jax
-    if hasattr(jax, "shard_map"):
-        for n_micro in args.micros:
-            row = _wallclock_and_memory(args.pp, n_micro, args.hidden,
-                                        args.layers, args.seq, args.mb,
-                                        args.steps)
-            print(json.dumps(row))
-    else:
-        print(json.dumps({"skipped": "spmd wallclock/memory rows: this "
-                          "jax build has no jax.shard_map (0.4.x)"}))
+    for n_micro in args.micros:
+        row = _wallclock_and_memory(args.pp, n_micro, args.hidden,
+                                    args.layers, args.seq, args.mb,
+                                    args.steps)
+        print(json.dumps(row))
     if not (args.placements or args.record):
         return
     rows = []

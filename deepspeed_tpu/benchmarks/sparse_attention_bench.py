@@ -23,28 +23,15 @@ from ..ops.sparse_attention import BSLongformerSparsityConfig
 
 
 def _timed(attn_fn, q, k, v, iters=20):
-    """Per-call latency with the loop INSIDE one compiled program: host->chip
-    RPC (hundreds of us..ms on tunneled setups) would otherwise swamp the
-    kernel. Each iteration depends on the last so nothing is elided; the
-    marginal cost comes from differencing two loop lengths."""
-    import jax.lax as lax
-
-    def many(n):
-        def run(q, k, v):
-            def body(i, carry):
-                qq = q.at[0, 0, 0, 0].add(carry.astype(q.dtype))
-                o = attn_fn(qq, k, v)
-                return o[0, 0, 0, 0].astype(jnp.float32)
-            return lax.fori_loop(0, n, body, jnp.zeros((), jnp.float32))
-        f = jax.jit(run)
-        np.asarray(f(q, k, v))              # compile + warm; fetch = fence
-        t0 = time.perf_counter()
-        np.asarray(f(q, k, v))              # value fetch forces completion
-        return time.perf_counter() - t0
-
-    t_long = many(iters)
-    t_short = many(iters // 4)
-    return (t_long - t_short) / (iters - iters // 4)
+    """Mean per-call latency of the jitted forward: warm once, then time
+    ``iters`` calls ended by ``block_until_ready``."""
+    f = jax.jit(attn_fn)
+    jax.block_until_ready(f(q, k, v))       # compile + warm
+    t0 = time.perf_counter()
+    for _ in range(iters):
+        out = f(q, k, v)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / iters
 
 
 def run(seqs, heads=8, head_dim=128, block=128, window_blocks=5):
@@ -76,6 +63,8 @@ def main(argv=None):
     p = argparse.ArgumentParser()
     p.add_argument("--seqs", default="4096,8192,16384")
     args = p.parse_args(argv)
+    from ..utils.compile_cache import configure_compile_cache
+    configure_compile_cache()
     run([int(s) for s in args.seqs.split(",")])
 
 

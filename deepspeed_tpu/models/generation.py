@@ -276,8 +276,23 @@ def forward_with_cache(cfg: TransformerConfig, params: PyTree,
         prefer_kernel = B >= 2 and max_len >= 4 * 512
     use_kernel = ((cfg.attention_impl == "flash"
                    or (cfg.attention_impl == "auto" and prefer_kernel))
-                  and jax.default_backend() == "tpu"
-                  and pad is None and not quant_kv)
+                  and jax.default_backend() == "tpu")
+    if use_kernel:
+        # the route away from the kernel is decided HERE, from shapes and
+        # regime, and said once — never by catching what the kernel raises
+        from ..ops.pallas.decode_attention import untileable
+        from ..utils.logging import warning_once
+        reason = ("ragged (left-padded) batches need per-sample masks"
+                  if pad is not None else
+                  "the int8 cache needs the dequant read" if quant_kv else
+                  untileable(T_new, max_len, hd))
+        if reason is not None:
+            use_kernel = False
+            # a whole-prompt prefill (T > 64) off the decode kernel is the
+            # documented regime, not news
+            if T_new <= 64:
+                warning_once("decode attention on TPU takes the jnp path: "
+                             f"{reason}")
 
     # prefill on the flash kernel (empty cache — caller's contract): alibi,
     # softcap and a UNIFORM static window all run in-kernel; mixed per-layer
@@ -355,17 +370,15 @@ def forward_with_cache(cfg: TransformerConfig, params: PyTree,
                                 interpret=flash_interp)
         if o is None and use_kernel:
             from ..ops.pallas.decode_attention import decode_attention
-            try:
-                # stacked form: the kernel indexes layer li out of the
-                # carried [L, ...] cache itself — no materialized slice;
-                # alibi slopes / softcap ride in-kernel
-                o = decode_attention(q, k_all, v_all, pos + T_new,
-                                     window=window, sm_scale=sm_scale,
-                                     layer_idx=li,
-                                     alibi_slopes=prefill_slopes,
-                                     softcap=cfg.attn_softcap)
-            except ValueError:
-                o = None                       # shapes don't tile
+            # stacked form: the kernel indexes layer li out of the
+            # carried [L, ...] cache itself — no materialized slice;
+            # alibi slopes / softcap ride in-kernel. Shapes were tested
+            # above (``untileable``), so an error here is an error
+            o = decode_attention(q, k_all, v_all, pos + T_new,
+                                 window=window, sm_scale=sm_scale,
+                                 layer_idx=li,
+                                 alibi_slopes=prefill_slopes,
+                                 softcap=cfg.attn_softcap)
         if o is None:
             # the slice reads fuse into the attention consumers (no copy)
             k_cache = jax.lax.dynamic_index_in_dim(k_all, li, 0,

@@ -530,14 +530,7 @@ def _spec_constraint(x, spec: P):
         if not any(e is not None for e in filtered):
             return x
         return jax.lax.with_sharding_constraint(x, P(*filtered))
-    # jax-version compat: get_abstract_mesh moved under jax.sharding only in
-    # newer releases; older trees keep it in jax._src.mesh (and lack
-    # sharding-in-types entirely — see the typeof probe below)
-    _get_ctx = getattr(jax.sharding, "get_abstract_mesh", None)
-    ctx = _get_ctx() if _get_ctx is not None else None
-    # old jax: no public accessor (jax._src.mesh's same-named thread-local
-    # has different semantics) — getattr below treats ctx as absent
-    if getattr(ctx, "empty", None) is False:
+    if not jax.sharding.get_abstract_mesh().empty:
         return jax.lax.with_sharding_constraint(x, spec)
     from ..parallel.mesh import get_global_mesh
     mm = get_global_mesh()
@@ -560,14 +553,10 @@ def _spec_constraint(x, spec: P):
     # DSTPU_FORCE_MESH_CONSTRAINTS=1 restores the always-constrain
     # behavior for that idiom (documented in docs/USAGE.md)
     import os
-    _typeof = getattr(jax, "typeof", None)
-    if os.environ.get("DSTPU_FORCE_MESH_CONSTRAINTS") != "1" \
-            and _typeof is not None:
-        # jax without sharding-in-types (no typeof) predates the empty-aval
-        # -mesh scope hazard: constrain unconditionally there
-        aval_mesh = getattr(getattr(_typeof(x), "sharding", None),
+    if os.environ.get("DSTPU_FORCE_MESH_CONSTRAINTS") != "1":
+        aval_mesh = getattr(getattr(jax.typeof(x), "sharding", None),
                             "mesh", None)
-        if aval_mesh is None or getattr(aval_mesh, "empty", False):
+        if aval_mesh is None or aval_mesh.empty:
             return x
     # a computation not laid out on the session mesh (e.g. a smaller
     # ad-hoc batch) can't take the constraint — detectable as
@@ -895,13 +884,11 @@ class Transformer(nn.Module):
             # CPU activation checkpointing (reference: checkpointing.py
             # cpu_checkpointing — saved activations live in host memory):
             # offload the attention outputs to pinned host, recompute the rest
-            if hasattr(jax.checkpoint_policies,
-                       "save_and_offload_only_these_names"):
-                policies["offload"] = \
-                    jax.checkpoint_policies.save_and_offload_only_these_names(
-                        names_which_can_be_saved=[],
-                        names_which_can_be_offloaded=["attn_out"],
-                        offload_src="device", offload_dst="pinned_host")
+            policies["offload"] = \
+                jax.checkpoint_policies.save_and_offload_only_these_names(
+                    names_which_can_be_saved=[],
+                    names_which_can_be_offloaded=["attn_out"],
+                    offload_src="device", offload_dst="pinned_host")
             if cfg.remat_policy not in policies:
                 raise ValueError(f"unknown remat_policy '{cfg.remat_policy}'; "
                                  f"have {sorted(policies)}")

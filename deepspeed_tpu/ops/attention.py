@@ -104,6 +104,20 @@ def mha_reference(q: jnp.ndarray,
     return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(q.dtype), v)
 
 
+def _window_fine_block(S: int) -> int:
+    return 64 if S % 64 == 0 else 16
+
+
+def sliding_window_untileable(S: int, D: int) -> Optional[str]:
+    """Why :func:`sliding_window_attention` cannot tile [.., S, D], or None
+    — the shape test dispatch makes BEFORE the call."""
+    from .pallas.block_sparse_attention import tile_plan
+    fine = _window_fine_block(S)
+    if S % fine:
+        return f"seq_len {S} not divisible by the window layout block {fine}"
+    return tile_plan(S, D, fine)[2]
+
+
 def sliding_window_attention(q, k, v, window: int, *,
                              sm_scale: Optional[float] = None,
                              interpret: bool = False) -> jnp.ndarray:
@@ -111,12 +125,13 @@ def sliding_window_attention(q, k, v, window: int, *,
     visits only blocks intersecting the window (compute AND K/V DMA scale
     with window, not seq) and the kernel applies the EXACT per-token window
     in-block — same numerics as the dense (q_pos - k_pos < window) mask.
-    Raises when shapes can't tile; callers fall back to the flash kernel's
-    in-kernel window (MXU skip only) and then the dense-mask path."""
+    Raises when shapes can't tile; callers ask
+    :func:`sliding_window_untileable` first and take the flash kernel's
+    in-kernel window (MXU skip only) on a reason."""
     from .pallas.block_sparse_attention import block_sparse_flash_attention
     from .sparse_attention import LocalSlidingWindowSparsityConfig
     B, H, S, D = q.shape
-    fine = 64 if S % 64 == 0 else 16
+    fine = _window_fine_block(S)
     w_blocks = -(-(window - 1) // fine) + 1 if window > 1 else 1
     cfg = LocalSlidingWindowSparsityConfig(
         num_heads=H, block=fine, num_sliding_window_blocks=w_blocks,
@@ -148,25 +163,36 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
     (ops/pallas/paged_attention.py) serves the decode regime (T == 1, TPU
     or interpret) with ALiBi/softcap/window in-kernel; every other regime
     — prefill (T > 1, possibly with PADDED trailing queries positioned by
-    ``q_start``), CPU, untileable shapes — runs the exact jnp gather
-    reference. int8 pools ride both paths via ``k_scale``/``v_scale``
-    (per-(layer, head, slot) f32, dequantized in-kernel / post-gather).
+    ``q_start``), CPU, untileable shapes (warned once on a TPU) — runs the
+    exact jnp gather reference. int8 pools ride both paths via
+    ``k_scale``/``v_scale`` (per-(layer, head, slot) f32, dequantized
+    in-kernel / post-gather).
     ``impl="reference"`` forces the oracle.
     """
     kw = dict(sm_scale=sm_scale, alibi_slopes=alibi_slopes, softcap=softcap,
               window=window, layer_idx=layer_idx, k_scale=k_scale,
               v_scale=v_scale)
     on_tpu = jax.default_backend() == "tpu"
-    if impl in ("auto", "flash") and (on_tpu or interpret) \
-            and q.shape[2] == 1:
-        # T == 1: the query position is ctx - 1 by the decode contract, so
-        # q_start (== ctx - 1 when given) carries no extra information
+    if impl in ("auto", "flash") and (on_tpu or interpret):
         from .pallas.paged_attention import paged_attention as _kernel
-        try:
+        from .pallas.paged_attention import untileable
+        # the shape test comes BEFORE the call: whatever the kernel itself
+        # raises (the chip's compiler refusing it, a pool without scales)
+        # is an error, never a quiet route to the reference
+        reason = untileable(q.shape, k_pool.shape,
+                            stacked=layer_idx is not None,
+                            quant=k_scale is not None, interpret=interpret)
+        if reason is None:
+            # T == 1: the query position is ctx - 1 by the decode contract,
+            # so q_start (== ctx - 1 when given) carries no extra information
             return _kernel(q, k_pool, v_pool, block_tables, context_lens,
                            interpret=interpret, **kw)
-        except ValueError:
-            pass                    # shapes don't tile — gather reference
+        if on_tpu and q.shape[2] == 1:
+            # prefill (T > 1) riding the reference is the documented
+            # regime; a DECODE step doing so on a TPU is said once
+            from ..utils.logging import warning_once
+            warning_once("paged_attention on TPU takes the jnp gather "
+                         f"reference: {reason}")
     from .pallas.paged_attention import paged_attention_reference
     return paged_attention_reference(q, k_pool, v_pool, block_tables,
                                      context_lens, q_start=q_start, **kw)
@@ -214,12 +240,15 @@ def attention(q: jnp.ndarray,
                    and alibi_slopes is None and softcap == 0.0
                    and dropout_rate == 0.0)
     if pure_window and on_tpu and impl in ("auto", "flash"):
-        try:
+        reason = sliding_window_untileable(q.shape[-2], q.shape[-1])
+        if reason is None:
             return sliding_window_attention(q, k, v, window,
                                             sm_scale=sm_scale,
                                             interpret=interpret)
-        except ValueError:
-            pass        # shapes don't tile — flash in-kernel window below
+        # said once; the flash kernel's in-kernel window serves it below
+        from ..utils.logging import warning_once
+        warning_once("sliding-window attention on TPU skips the block-skip "
+                     f"layout kernel: {reason}")
     if impl == "auto":
         impl = "flash" if (on_tpu and kernel_capable) else "reference"
     if impl in ("ring", "ulysses"):

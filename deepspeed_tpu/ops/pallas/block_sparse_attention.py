@@ -403,6 +403,32 @@ def _bs_bwd(sched_meta, H, causal, sm_scale, block_q, block_k, fine, window,
 _bs_flash.defvjp(_bs_fwd, _bs_bwd)
 
 
+def tile_plan(S: int, D: int, fine_block: int, block_q: int = 256,
+              block_k: int = 256) -> Tuple[int, int, Optional[str]]:
+    """(block_q, block_k, reason): the kernel blocks an S x S, head-dim D
+    problem with a ``fine_block`` layout runs at, and the reason it cannot
+    tile (None when it can). Dispatchers ask this BEFORE the call and route
+    on the reason, so an error out of the kernel — the chip's compiler
+    refusing it, say — is never read as "shapes don't tile"."""
+    block_q = min(block_q, S)
+    block_k = min(block_k, S)
+    if fine_block > block_q or fine_block > block_k:
+        # a very coarse layout: the fine block IS the kernel block
+        block_q = block_k = fine_block
+    # the q side of the layout rides the BlockSpec at block_q//8 granularity —
+    # that step must subdivide a fine block exactly
+    while block_q > 8 and (block_q // 8 > fine_block
+                           or fine_block % (block_q // 8)):
+        block_q //= 2
+    reason = None
+    if (S % block_q or S % block_k or block_q % 8
+            or block_k % fine_block or D % 8):
+        reason = (
+            f"block_sparse_flash_attention cannot tile S={S}, D={D} with "
+            f"kernel blocks ({block_q},{block_k}) and fine block {fine_block}")
+    return block_q, block_k, reason
+
+
 def block_sparse_flash_attention(q: jnp.ndarray,
                                  k: jnp.ndarray,
                                  v: jnp.ndarray,
@@ -420,27 +446,16 @@ def block_sparse_flash_attention(q: jnp.ndarray,
     bool at ``fine_block`` granularity (SparsityConfig.make_layout output).
 
     Returns exactly what the dense-mask oracle returns for the same layout
-    (rows with no active keys produce zeros). Raises when shapes can't tile —
-    callers fall back to the mask path (ops/sparse_attention.sparse_attention).
+    (rows with no active keys produce zeros). Raises the :func:`tile_plan`
+    reason when shapes can't tile — callers ask :func:`tile_plan` first and
+    take the mask path on a reason (ops/sparse_attention.sparse_attention).
     """
     B, H, S, D = q.shape
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(D))
-    block_q = min(block_q, S)
-    block_k = min(block_k, S)
-    if fine_block > block_q or fine_block > block_k:
-        # a very coarse layout: the fine block IS the kernel block
-        block_q = block_k = fine_block
-    # the q side of the layout rides the BlockSpec at block_q//8 granularity —
-    # that step must subdivide a fine block exactly
-    while block_q > 8 and (block_q // 8 > fine_block
-                           or fine_block % (block_q // 8)):
-        block_q //= 2
-    if (S % block_q or S % block_k or block_q % 8
-            or block_k % fine_block or D % 8):
-        raise ValueError(
-            f"block_sparse_flash_attention cannot tile S={S}, D={D} with "
-            f"kernel blocks ({block_q},{block_k}) and fine block {fine_block}")
+    block_q, block_k, reason = tile_plan(S, D, fine_block, block_q, block_k)
+    if reason is not None:
+        raise ValueError(reason)
     nf = S // fine_block
     if layout.shape != (H, nf, nf):
         raise ValueError(f"layout shape {layout.shape} != {(H, nf, nf)} for "

@@ -37,7 +37,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import NEG_INF
 
-__all__ = ["decode_attention"]
+__all__ = ["decode_attention", "untileable"]
 
 
 def _kernel(scal_ref, q_ref, k_ref, v_ref, slopes_ref, o_ref, acc, m_scr,
@@ -101,6 +101,29 @@ def _head_group(nh: int, block_k: int, hd: int, itemsize: int) -> int:
     return hg
 
 
+def _snap_block_k(max_len: int, block_k: int) -> int:
+    return block_k if max_len % block_k == 0 else int(np.gcd(max_len, block_k))
+
+
+def untileable(T: int, max_len: int, hd: int, block_k: int = 512, *,
+               interpret: bool = False) -> Optional[str]:
+    """The kernel's tiling rules as a test made BEFORE the call: the reason
+    these shapes cannot ride the kernel, or None when they can (so a
+    compiler refusal is never read as "shapes don't tile")."""
+    if T > 64:
+        # decode-regime kernel: per-program scratch scales with T, and a
+        # large-T call is the PREFILL, which is an ordinary causal attention
+        # the MXU-shaped flash/jnp paths already handle well
+        return f"decode_attention is for small T (got {T})"
+    if max_len % block_k != 0 and _snap_block_k(max_len, block_k) < 128:
+        return f"max_len {max_len} has no >=128 block tiling"
+    if hd % 8 != 0 and not interpret:
+        # Mosaic pads sub-128 lane dims (64 measured fine on v5e); truly odd
+        # head dims take the jnp path
+        return f"head_dim {hd} does not tile"
+    return None
+
+
 def decode_attention(q: jnp.ndarray,
                      k_cache: jnp.ndarray,
                      v_cache: jnp.ndarray,
@@ -128,25 +151,17 @@ def decode_attention(q: jnp.ndarray,
        kernel). softcap: Gemma-2 tanh logit cap, STATIC float (it changes
        the compiled math). Returns [B, nh, T, hd].
 
-    Raises ValueError when shapes can't tile (tiny head_dim / max_len) —
-    callers fall back to the jnp path.
+    Raises ValueError (the :func:`untileable` reason) when shapes can't
+    tile (tiny head_dim / max_len) — callers ask :func:`untileable` FIRST
+    and take the jnp path on a reason.
     """
     B, nh, T, hd = q.shape
-    if T > 64:
-        # decode-regime kernel: per-program scratch scales with T, and a
-        # large-T call is the PREFILL, which is an ordinary causal attention
-        # the MXU-shaped flash/jnp paths already handle well
-        raise ValueError(f"decode_attention is for small T (got {T})")
     stacked = layer_idx is not None
     max_len = k_cache.shape[3 if stacked else 2]
-    if max_len % block_k != 0:
-        block_k = int(np.gcd(max_len, block_k))
-        if block_k < 128:
-            raise ValueError(f"max_len {max_len} has no >=128 block tiling")
-    if hd % 8 != 0 and not interpret:
-        # Mosaic pads sub-128 lane dims (64 measured fine on v5e); truly odd
-        # head dims fall back to the jnp path
-        raise ValueError(f"head_dim {hd} does not tile")
+    reason = untileable(T, max_len, hd, block_k, interpret=interpret)
+    if reason is not None:
+        raise ValueError(reason)
+    block_k = _snap_block_k(max_len, block_k)
     nk = max_len // block_k
     Tp = max(8, -(-T // 8) * 8)                  # sublane-pad the q rows
     hg = _head_group(nh, block_k, hd, k_cache.dtype.itemsize)

@@ -40,7 +40,7 @@ from jax.experimental.pallas import tpu as pltpu
 from .decode_attention import _head_group
 from .flash_attention import NEG_INF
 
-__all__ = ["paged_attention", "paged_attention_reference"]
+__all__ = ["paged_attention", "paged_attention_reference", "untileable"]
 
 #: query rows per program — a single decode token is broadcast to the
 #: sublane minimum so every operand is a legal (>=8)x128 tile
@@ -72,13 +72,22 @@ def _kernel(bt_ref, lens_ref, misc_ref, q_ref, k_ref, v_ref, *rest, hg, bs,
         if quant:
             # int8 tier (round 17): the DMA moved int8 rows + one f32
             # scale per (head, slot); dequantize HERE, on the block
-            # already in VMEM — only int8 crossed HBM
-            ks = ks_ref[0, :, 0] if stacked else ks_ref[:, 0]   # [hg, bs]
+            # already in VMEM — only int8 crossed HBM. The scale rows
+            # arrive [hg, 1, bs] (slots on the LANE axis, the layout the
+            # chip's compiler takes), which is the score tile's own
+            # layout: q.(k_i * s_i) == (q.k_i) * s_i, so the K scale
+            # multiplies the scores and the V scale the probabilities —
+            # no lane->sublane relayout, and the int8 -> q.dtype convert
+            # is exact (|int8| <= 127)
+            ks = ks_ref[0, :, 0] if stacked else ks_ref[:, 0]   # [hg, 1, bs]
             vs = vs_ref[0, :, 0] if stacked else vs_ref[:, 0]
-            k = (k.astype(jnp.float32) * ks[..., None]).astype(q.dtype)
-            v = (v.astype(jnp.float32) * vs[..., None]).astype(q.dtype)
+            k = k.astype(jnp.float32).astype(q.dtype)
+            v = v.astype(jnp.float32).astype(q.dtype)
         s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
-                                preferred_element_type=jnp.float32) * sm_scale
+                                preferred_element_type=jnp.float32)
+        if quant:
+            s = s * ks
+        s = s * sm_scale
         if softcap:
             s = jnp.tanh(s / softcap) * softcap
         # one real query at absolute (logical) position ctx - 1, broadcast
@@ -99,8 +108,9 @@ def _kernel(bt_ref, lens_ref, misc_ref, q_ref, k_ref, v_ref, *rest, hg, bs,
         p = jnp.exp(s - m_cur)
         l_scr[:, :, :1] = (l_scr[:, :, :1] * alpha
                            + jnp.sum(p, axis=2, keepdims=True))
+        pv = p * vs if quant else p
         acc[:] = acc[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            pv.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
         m_scr[:, :, :1] = m_cur
 
@@ -109,6 +119,30 @@ def _kernel(bt_ref, lens_ref, misc_ref, q_ref, k_ref, v_ref, *rest, hg, bs,
         l = l_scr[:, :, :1]
         o_ref[0, 0] = (acc[:] / jnp.where(l == 0.0, 1.0, l)).astype(
             o_ref.dtype)
+
+
+def untileable(q_shape, pool_shape, *, stacked: bool, quant: bool,
+               interpret: bool = False) -> Optional[str]:
+    """The kernel's tiling rules as a test made BEFORE the call: the reason
+    these shapes cannot ride the kernel, or None when they can. Dispatchers
+    route on this instead of catching the kernel's errors, so a refusal by
+    the chip's compiler can never be read as "shapes don't tile"."""
+    T, hd = q_shape[2], q_shape[3]
+    if T != 1:
+        return (f"paged_attention decodes 1 token/seq (got T={T}); "
+                "prefill rides the gather reference/flash paths")
+    if interpret:
+        return None
+    bs = pool_shape[3 if stacked else 2]
+    if bs % 8 != 0:
+        return (f"block_size {bs} does not tile (sublane multiple of 8 "
+                "required)")
+    if hd % 8 != 0:
+        return f"head_dim {hd} does not tile"
+    if quant and bs % 32 != 0:
+        return (f"block_size {bs} does not tile the int8 KV tier (int8 "
+                "sublane multiple of 32 required)")
+    return None
 
 
 def paged_attention(q: jnp.ndarray,
@@ -149,31 +183,30 @@ def paged_attention(q: jnp.ndarray,
        bias slope * (k_pos - q_pos)). ``softcap``: Gemma-2 tanh cap
        (STATIC float — it changes the compiled math).
 
-    Returns [B, nh, 1, hd]. Raises ValueError when shapes can't tile —
-    callers fall back to :func:`paged_attention_reference`.
+    Returns [B, nh, 1, hd]. Raises ValueError (the :func:`untileable`
+    reason) when shapes can't tile — callers ask :func:`untileable` FIRST
+    and route to :func:`paged_attention_reference` on a reason, so an
+    error out of this function is never mistaken for one.
     """
     B, nh, T, hd = q.shape
-    if T != 1:
-        raise ValueError(f"paged_attention decodes 1 token/seq (got T={T}); "
-                         "prefill rides the gather reference/flash paths")
     stacked = layer_idx is not None
-    bs = k_pool.shape[3 if stacked else 2]
-    nb = k_pool.shape[2 if stacked else 1]
-    if bs % 8 != 0 and not interpret:
-        raise ValueError(f"block_size {bs} does not tile (sublane multiple "
-                         "of 8 required)")
-    if hd % 8 != 0 and not interpret:
-        raise ValueError(f"head_dim {hd} does not tile")
     quant = k_scale is not None
+    reason = untileable(q.shape, k_pool.shape, stacked=stacked, quant=quant,
+                        interpret=interpret)
+    if reason is not None:
+        raise ValueError(reason)
+    bs = k_pool.shape[3 if stacked else 2]
     if quant:
         if k_pool.dtype != jnp.int8:
             raise ValueError("k_scale/v_scale given but the pool dtype is "
                              f"{k_pool.dtype} — scales pair with int8 pools")
-        if bs % 32 != 0 and not interpret:
-            raise ValueError(f"block_size {bs} does not tile the int8 KV "
-                             "tier (int8 sublane multiple of 32 required)")
-        ks_pool = jnp.asarray(k_scale, jnp.float32).reshape(k_pool.shape[:-1])
-        vs_pool = jnp.asarray(v_scale, jnp.float32).reshape(v_pool.shape[:-1])
+        # [..., num_blocks, 1, block_size]: the unit axis before the lane
+        # axis makes the scale block's last two dims equal the array's —
+        # the Mosaic block rule a (1, block_size) tile of a
+        # (num_blocks, block_size) array breaks
+        sc_shape = k_pool.shape[:-2] + (1, bs)
+        ks_pool = jnp.asarray(k_scale, jnp.float32).reshape(sc_shape)
+        vs_pool = jnp.asarray(v_scale, jnp.float32).reshape(sc_shape)
     elif k_pool.dtype == jnp.int8:
         raise ValueError("int8 KV pool needs k_scale/v_scale "
                          "(quant_format.kv_quantize layout)")
@@ -217,17 +250,17 @@ def paged_attention(q: jnp.ndarray,
     operands = [qf, k_pool, v_pool]
     if quant:
         # scale blocks follow the K/V through the SAME clamped
-        # block-table index_map (hd dim dropped: one f32 per slot row)
+        # block-table index_map (one f32 per slot, slots on the lane axis)
         if stacked:
             sc_spec = pl.BlockSpec(
-                (1, hg, 1, bs),
+                (1, hg, 1, 1, bs),
                 lambda b, g, j, bt_s, lens_s, misc_s: (
-                    misc_s[1], g, _phys(j, bt_s, lens_s, b), 0))
+                    misc_s[1], g, _phys(j, bt_s, lens_s, b), 0, 0))
         else:
             sc_spec = pl.BlockSpec(
-                (hg, 1, bs),
+                (hg, 1, 1, bs),
                 lambda b, g, j, bt_s, lens_s, misc_s: (
-                    g, _phys(j, bt_s, lens_s, b), 0))
+                    g, _phys(j, bt_s, lens_s, b), 0, 0))
         in_specs += [sc_spec, sc_spec]
         operands += [ks_pool, vs_pool]
     has_alibi = alibi_slopes is not None

@@ -106,7 +106,7 @@ def _kernel(x_ref, q_ref, s_ref, o_ref, acc, *, nkb):
     x = x_ref[:].astype(jnp.float32)
     acc[:] += jax.lax.dot_general(
         x, w, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32) * s_ref[0][None, :]
+        preferred_element_type=jnp.float32) * s_ref[0]
 
     @pl.when(kb == nkb - 1)
     def _finalize():
@@ -131,6 +131,12 @@ def quant_matmul(x: jnp.ndarray,
     lead, K = x.shape[:-1], x.shape[-1]
     on_tpu = jax.default_backend() == "tpu"
     if not (on_tpu or interpret) or N % _BN != 0:
+        if on_tpu:
+            # the CPU oracle runs in silence; on a TPU the route off the
+            # kernel is said once, with the reason
+            from ...utils.logging import warning_once
+            warning_once(f"quant_matmul on TPU takes the jnp reference: "
+                         f"N={N} is not a multiple of the lane width {_BN}")
         return quant_matmul_reference(x, q, scales)
 
     xf = x.reshape(-1, K)
@@ -144,7 +150,10 @@ def quant_matmul(x: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((_BM, block), lambda m, n, kb: (m, kb)),
             pl.BlockSpec((block, _BN), lambda m, n, kb: (kb, n)),
-            pl.BlockSpec((1, _BN), lambda m, n, kb: (kb, n)),
+            # scales ride as [nkb, 1, N]: the unit axis makes the block's
+            # last two dims (1, _BN) legal for the chip's compiler — a
+            # (1, _BN) tile of the 2-D [nkb, N] array is refused
+            pl.BlockSpec((1, 1, _BN), lambda m, n, kb: (kb, 0, n)),
         ],
         out_specs=pl.BlockSpec((_BM, _BN), lambda m, n, kb: (m, n)),
         scratch_shapes=[pltpu.VMEM((_BM, _BN), jnp.float32)],
@@ -155,7 +164,7 @@ def quant_matmul(x: jnp.ndarray,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((Mp, N), x.dtype),
             interpret=interpret,
-        )(xf, q, scales)
+        )(xf, q, scales.reshape(nkb, 1, N))
     return out[:M].reshape(lead + (N,))
 
 

@@ -40,7 +40,9 @@ class OpRegistry:
             try:
                 self._probe_cache[key] = bool(impl.probe())
             except Exception as e:
-                logger.debug("op probe %s failed: %s", key, e)
+                # a probe that RAISES is a fault, not an answer: say so
+                logger.warning("op probe %s raised (%s: %s); reporting it "
+                               "unavailable", key, type(e).__name__, e)
                 self._probe_cache[key] = False
         return self._probe_cache[key]
 

@@ -224,17 +224,21 @@ def sparse_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if auto:
         use_kernel = jax.default_backend() == "tpu"
     if use_kernel:
-        from .pallas.block_sparse_attention import block_sparse_flash_attention
-        try:
+        from .pallas.block_sparse_attention import (
+            block_sparse_flash_attention, tile_plan)
+        # only the AUTO path may take the dense-mask oracle for shapes that
+        # don't tile (said once, with the reason); an explicit
+        # use_kernel=True means the caller wants the FLOP-scaling contract
+        # and must hear that it can't be met. The test is made BEFORE the
+        # call: whatever the kernel itself raises is an error, not a route
+        reason = tile_plan(S, q.shape[-1], config.block)[2] if auto else None
+        if reason is None:
             return block_sparse_flash_attention(
                 q, k, v, layout, config.block, causal=causal,
                 sm_scale=sm_scale, interpret=interpret)
-        except ValueError:
-            # only the AUTO path may quietly fall back to the dense-mask
-            # oracle; an explicit use_kernel=True means the caller wants the
-            # FLOP-scaling contract and must hear that it can't be met
-            if not auto:
-                raise
+        from ..utils.logging import warning_once
+        warning_once(f"sparse_attention on TPU takes the dense-mask "
+                     f"reference: {reason}")
     mask = layout_to_dense_mask(layout, config.block)[None]   # [1, H, S, S]
     from .attention import mha_reference
     return mha_reference(q, k, v, causal=causal, mask=mask, sm_scale=sm_scale)

@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ...utils.jax_compat import shard_map as _shard_map
 
 
 def _chunk(x: jnp.ndarray, n: int, multiple: int = 1) -> jnp.ndarray:
@@ -96,7 +95,7 @@ def compressed_allreduce(x: jnp.ndarray,
         out = out[:flat.size].reshape(x.shape).astype(x.dtype)
         return out, new_w_err[None], new_s_err[None]
 
-    mapped = _shard_map(inner, mesh=mesh,
+    mapped = jax.shard_map(inner, mesh=mesh,
                            in_specs=(P(axis), P(axis), P(axis)),
                            out_specs=(P(), P(axis), P(axis)),
                            axis_names={axis}, check_vma=False)
@@ -142,7 +141,7 @@ def quantized_allreduce(x: jnp.ndarray,
                out_scales[:, None]).reshape(-1)[:flat.size]
         return out.reshape(x.shape).astype(x.dtype), new_err[None]
 
-    mapped = _shard_map(inner, mesh=mesh, in_specs=(P(axis), P(axis)),
+    mapped = jax.shard_map(inner, mesh=mesh, in_specs=(P(axis), P(axis)),
                            out_specs=(P(), P(axis)),
                            axis_names={axis}, check_vma=False)
     # graftlint: disable=TPU002 (called from the runner's outer jitted step: one construction per outer trace)
@@ -220,9 +219,6 @@ def make_quantized_gather(mesh, axis, dim: int, bits: int = 8,
             return full.astype(xs.dtype)
 
         in_spec, out_spec, manual = _specs(x.ndim)
-        # deliberately jax.shard_map, NOT the jax_compat wrapper: the
-        # qwZ+TP composition ABORTS inside XLA on the 0.4.x jaxlib (see
-        # utils/jax_compat docstring) — a clean AttributeError is safer
         mapped = jax.shard_map(inner, mesh=mesh, in_specs=in_spec,
                                out_specs=out_spec, axis_names=manual,
                                check_vma=False)
@@ -300,7 +296,7 @@ def hierarchical_quantized_allreduce(x: jnp.ndarray,
                out_scales[:, None]).reshape(-1)[:flat.size]
         return out.reshape(x.shape).astype(x.dtype), new_err[None]
 
-    mapped = _shard_map(inner, mesh=mesh,
+    mapped = jax.shard_map(inner, mesh=mesh,
                            in_specs=(P((inter_axis, intra_axis)),
                                      P(inter_axis)),
                            out_specs=(P(), P(inter_axis)),

@@ -40,10 +40,8 @@ reduce-scatter backward) from one executor. The int8 gather carries a
 shard_map body — the MoE queue-exchange lesson) whose backward is the
 same exact chunk-sized psum_scatter.
 
-Every region is built through ``utils.jax_compat.shard_map`` and is
-fully-manual over the ZeRO/DP axes only (TP axes stay auto) — the
-shape class verified to compile on the 0.4.x jaxlib, unlike the
-qwZ+TP composition ``jax_compat`` warns about.
+Every region is a ``jax.shard_map`` that is fully manual over the
+ZeRO/DP axes only (TP axes stay auto).
 """
 
 from __future__ import annotations
@@ -53,7 +51,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ...utils.jax_compat import shard_map
 from .quantized import (DEFAULT_BLOCK, _axes_size, _axes_tuple,
                         ag_quantized_local, rs_exact_local,
                         rs_quantized_local)
@@ -145,7 +142,7 @@ def overlap_grad_sync(x: jnp.ndarray, *, mesh, axis="data",
         out = jnp.concatenate(outs) if len(outs) > 1 else outs[0]
         return out.reshape(x0.shape).astype(x0.dtype)
 
-    mapped = shard_map(inner, mesh=mesh, in_specs=P(axes), out_specs=P(),
+    mapped = jax.shard_map(inner, mesh=mesh, in_specs=P(axes), out_specs=P(),
                        axis_names=set(axes), check_vma=False)
     # graftlint: disable=TPU002 (called under the caller's outer jit: one construction per outer trace)
     return jax.jit(mapped)(x)
@@ -190,7 +187,7 @@ def make_overlap_gather(mesh, axis, dim: int, *,
         # custom_vjp around the shard-LOCAL chunk exchange (defined at
         # make time, called inside the shard_map body — an outer
         # custom_vjp wrapping the whole shard_map leaks tracers under
-        # nn.scan lifting on the 0.4.x jax line)
+        # nn.scan lifting)
         @jax.custom_vjp
         def _chunk_gather(c):
             deq = ag_quantized_local(c.reshape(-1), axes, bits=bits,
@@ -228,7 +225,7 @@ def make_overlap_gather(mesh, axis, dim: int, *,
 
     spec_in = [None] * max(dim + 1, 1)
     spec_in[dim] = axes if len(axes) > 1 else axes[0]
-    mapped = shard_map(inner, mesh=mesh, in_specs=P(*spec_in),
+    mapped = jax.shard_map(inner, mesh=mesh, in_specs=P(*spec_in),
                        out_specs=P(), axis_names=set(axes),
                        check_vma=False)
 
@@ -277,7 +274,7 @@ def chunked_ag_matmul(x: jnp.ndarray, w: jnp.ndarray, *, mesh, axis,
             acc = acc + xk.astype(jnp.float32) @ wk.astype(jnp.float32)
         return acc.astype(x.dtype)
 
-    mapped = shard_map(inner, mesh=mesh,
+    mapped = jax.shard_map(inner, mesh=mesh,
                        in_specs=(P(), P(axes if len(axes) > 1 else axes[0])),
                        out_specs=P(), axis_names=set(axes), check_vma=False)
     # graftlint: disable=TPU002 (called under the caller's outer jit: one construction per outer trace)
@@ -306,7 +303,7 @@ def chunked_rs(g: jnp.ndarray, *, mesh, axis,
         out = jnp.concatenate(outs) if len(outs) > 1 else outs[0]
         return out[None]
 
-    mapped = shard_map(inner, mesh=mesh, in_specs=P(axes),
+    mapped = jax.shard_map(inner, mesh=mesh, in_specs=P(axes),
                        out_specs=P(axes), axis_names=set(axes),
                        check_vma=False)
     # graftlint: disable=TPU002 (called under the caller's outer jit: one construction per outer trace)
@@ -341,7 +338,7 @@ def chunked_matmul_rs(u: jnp.ndarray, v: jnp.ndarray, *, mesh, axis,
         out = jnp.concatenate(outs) if len(outs) > 1 else outs[0]
         return out[None]
 
-    mapped = shard_map(inner, mesh=mesh, in_specs=(P(axes), P()),
+    mapped = jax.shard_map(inner, mesh=mesh, in_specs=(P(axes), P()),
                        out_specs=P(axes), axis_names=set(axes),
                        check_vma=False)
     # graftlint: disable=TPU002 (called under the caller's outer jit: one construction per outer trace)

@@ -21,10 +21,7 @@ error model). Unlike ``compressed_allreduce`` these are STATELESS (no
 error feedback): the grad sync is used under the comm-plan accuracy
 guard, and the dispatch quantization error is bounded per block.
 
-Every region is built through :func:`...utils.jax_compat.shard_map`, so
-the same call sites run on jaxlibs with or without native
-``jax.shard_map`` (the shapes used here are verified to compile on the
-0.4.x line, unlike the qwZ+TP composition jax_compat warns about).
+Every region is a ``jax.shard_map``.
 """
 
 from __future__ import annotations
@@ -36,7 +33,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ...quant_format import QUANT_BLOCK, block_dequant, block_quant
-from ...utils.jax_compat import shard_map
 
 #: re-export: the wire format's block granularity is THE shared format's
 #: (deepspeed_tpu/quant_format.py — single-sourced round 17; the
@@ -156,7 +152,7 @@ def quantized_reduce_scatter(x: jnp.ndarray, *, mesh, axis="data",
                                        bits=bits, block=block, mean=mean)
         return served[None]
 
-    mapped = shard_map(inner, mesh=mesh, in_specs=P(axes),
+    mapped = jax.shard_map(inner, mesh=mesh, in_specs=P(axes),
                        out_specs=P(axes), axis_names=set(axes),
                        check_vma=False)
     # graftlint: disable=TPU002 (called under the caller's outer jit: one construction per outer trace)
@@ -201,7 +197,7 @@ def grad_sync(x: jnp.ndarray, *, mesh, axis="data", algo: str = "int8",
         out = full[:flat.size].reshape(x0.shape).astype(x0.dtype)
         return out
 
-    mapped = shard_map(inner, mesh=mesh, in_specs=P(axes), out_specs=P(),
+    mapped = jax.shard_map(inner, mesh=mesh, in_specs=P(axes), out_specs=P(),
                        axis_names=set(axes), check_vma=False)
     # graftlint: disable=TPU002 (called under the caller's outer jit: one construction per outer trace)
     return jax.jit(mapped)(x)
@@ -221,7 +217,7 @@ def quantized_all_to_all(x: jnp.ndarray, *, mesh, axis="expert",
         return a2a_quantized_local(xl, axes, bits=bits, block=block)
 
     spec = [axis] + [None] * (x.ndim - 1)
-    mapped = shard_map(inner, mesh=mesh, in_specs=P(*spec),
+    mapped = jax.shard_map(inner, mesh=mesh, in_specs=P(*spec),
                        out_specs=P(*spec), axis_names=set(axes),
                        check_vma=False)
     # graftlint: disable=TPU002 (called under the caller's outer jit: one construction per outer trace)
@@ -260,7 +256,7 @@ def make_queue_exchange(mesh, *, algo: str = "int8", bits: int = 8,
         # cotangents ride the SAME int8 wire format) sits INSIDE the
         # shard_map body, around the shard-local exchange: an outer
         # custom_vjp wrapping the whole shard_map leaks tracers under
-        # flax's nn.scan lifting on the 0.4.x jax line. The dim-0 peer
+        # flax's nn.scan lifting. The dim-0 peer
         # exchange is an involution and its own transpose, so one
         # function serves both directions and both passes.
         @jax.custom_vjp
@@ -293,10 +289,10 @@ def make_queue_exchange(mesh, *, algo: str = "int8", bits: int = 8,
 
     group_spec = P(manual, None, None, None)
     queue_spec = P("expert", ("data", "seq"), None)
-    dispatch = shard_map(to_queues_local, mesh=mesh, in_specs=group_spec,
+    dispatch = jax.shard_map(to_queues_local, mesh=mesh, in_specs=group_spec,
                          out_specs=queue_spec, axis_names=set(manual),
                          check_vma=False)
-    combine = shard_map(to_groups_local, mesh=mesh, in_specs=queue_spec,
+    combine = jax.shard_map(to_groups_local, mesh=mesh, in_specs=queue_spec,
                         out_specs=group_spec, axis_names=set(manual),
                         check_vma=False)
     return dispatch, combine

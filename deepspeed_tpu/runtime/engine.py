@@ -1059,9 +1059,7 @@ class DeepSpeedEngine:
         stacked per-rank layout needs a fused step the engine owns, and
         data parallelism optionally COMPOSED with TP (round 14): the
         model axis stays auto in the partial-auto stacked region, each
-        leaf syncing over its own stacked layout — but only where native
-        ``jax.shard_map`` exists (the 0.4.x legacy adapter aborts inside
-        XLA on auto-TP operands; see utils/jax_compat)."""
+        leaf syncing over its own stacked layout."""
         if self.onebit is not None:
             return False, "the 1-bit runner owns the train step"
         if self.offload is not None:
@@ -1078,14 +1076,6 @@ class DeepSpeedEngine:
                                f"{self.mesh_mgr.shape[ax]} (data "
                                "parallelism, optionally with TP, "
                                "required)")
-        if self.mesh_mgr.shape["model"] != 1 and \
-                not hasattr(jax, "shard_map"):
-            return False, (f"mesh axis 'model' has size "
-                           f"{self.mesh_mgr.shape['model']}: the "
-                           "TP-composed explicit sync needs native "
-                           "jax.shard_map (this jaxlib's legacy "
-                           "shard_map aborts inside XLA on auto-TP "
-                           "operands)")
         if self.mesh_mgr.shape["data"] <= 1:
             return False, "a single DP rank has nothing to sync"
         return True, ""
@@ -1206,8 +1196,8 @@ class DeepSpeedEngine:
         (``comm.planned_grad_sync``) — and everything from the synced
         grads on — clip, optimizer, skip arms, sentinel — is the shared
         ``_finalize_step`` tail, so the two programs differ ONLY in how
-        grad bytes cross the wire. With TP composed (round 14, native
-        jax.shard_map only) the model axis stays AUTO: params ride in
+        grad bytes cross the wire. With TP composed (round 14) the model
+        axis stays AUTO: params ride in
         TP-sharded, the model trace keeps its TP constraints (the
         local region strips only the manual DP axes), and each grad
         leaf syncs over its own stacked layout."""
@@ -1219,7 +1209,6 @@ class DeepSpeedEngine:
         tp_composed = self.mesh_mgr.shape["model"] > 1
         from ..comm.planned import planned_grad_sync
         from ..comm_plan.runtime import local_region
-        from ..utils.jax_compat import shard_map
 
         def local(params, micros_all, rng, scale):
             r = jax.random.fold_in(rng, lax.axis_index(axes))
@@ -1250,7 +1239,7 @@ class DeepSpeedEngine:
             gsum, losses = lax.scan(body, zero, (micros_all, rngs))
             return (jax.tree.map(lambda g: g[None], gsum), losses[None])
 
-        mapped = shard_map(local, mesh=mesh,
+        mapped = jax.shard_map(local, mesh=mesh,
                            in_specs=(P(), P(None, axes), P(), P()),
                            out_specs=(P(axes), P(axes)),
                            axis_names=set(axes), check_vma=False)

@@ -39,7 +39,6 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .comm.compressed import chunk_elems, compressed_allreduce
-from ..utils.jax_compat import shard_map as _shard_map
 
 PyTree = Any
 
@@ -83,7 +82,7 @@ def stacked_local_grads(runner, params, micros, rng, scale):
         sq = sum(jnp.sum(jnp.square(x)) for x in jax.tree.leaves(g))
         return g, (jnp.mean(losses) / scale)[None], sq[None]
 
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         local, mesh=runner.mesh,
         in_specs=(P(), P(None, runner.axis), P(), P()),
         out_specs=(P(runner.axis), P(runner.axis), P(runner.axis)),

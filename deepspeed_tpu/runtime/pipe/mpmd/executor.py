@@ -8,9 +8,7 @@ host-side interpreter walks :func:`..schedule.stage_instruction_stream`
 tick by tick, moving activations and cotangents through an explicit
 :mod:`channel`. Nothing here touches ``shard_map`` or collectives — a
 stage program only ever sees its own devices, which is exactly why a
-stage can die, recompile, and rejoin alone (driver.py) and why this
-path runs on jax builds whose SPMD pipeline cannot (the 0.4.x
-``jax.shard_map`` gap).
+stage can die, recompile, and rejoin alone (driver.py).
 
 Numerical contract: identical accumulation ORDER to the SPMD 1F1B
 executor — grads and the last-stage loss accumulate in backward-table

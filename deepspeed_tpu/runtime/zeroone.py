@@ -46,7 +46,6 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .comm.compressed import chunk_elems, compressed_allreduce
-from ..utils.jax_compat import shard_map as _shard_map
 from .onebit import hlo_collective_bytes  # noqa: F401  (re-export for tests)
 
 PyTree = Any
@@ -333,7 +332,7 @@ class ZeroOneRunner:
                 return (stack(m_new), stack(u_new),
                         (jnp.mean(losses) / scale)[None], norm_r[None])
 
-            mapped = _shard_map(
+            mapped = jax.shard_map(
                 local, mesh=self.mesh,
                 in_specs=(P(), P(), P(self.axis), P(self.axis),
                           P(None, self.axis), P(), P(), P()),

@@ -118,6 +118,10 @@ def paged_forward(cfg: TransformerConfig,
     sm_scale = (cfg.attn_scale if cfg.attn_scale is not None
                 else 1.0 / np.sqrt(hd))
 
+    # a model built with attention_impl="reference" serves on the gather
+    # oracle (the twin a kernel-routed serve is compared against)
+    attn_impl = "reference" if cfg.attention_impl == "reference" else "auto"
+
     bt = jnp.asarray(block_tables, jnp.int32)
     q_start = jnp.asarray(q_start, jnp.int32).reshape(B)
     ctx = jnp.asarray(context_lens, jnp.int32).reshape(B)
@@ -209,7 +213,7 @@ def paged_forward(cfg: TransformerConfig,
         o = paged_attention(q, kp5, vp5, bt, ctx, sm_scale=sm_scale,
                             alibi_slopes=slopes,
                             softcap=cfg.attn_softcap, window=window,
-                            layer_idx=li, q_start=q_start,
+                            layer_idx=li, q_start=q_start, impl=attn_impl,
                             interpret=interpret, **scale_kw)
         o = o.transpose(0, 2, 1, 3).reshape(B, T, nh * hd)
         attn_out = dense(o, p["attn_proj"])

@@ -195,8 +195,8 @@ class ProcessFleet:
                 "--serving-json", self._serving_json,
                 "--hb-dir", self.heartbeat_dir,
                 "--hb-interval", str(self.fcfg.heartbeat_interval)]
-        # sys.path INSIDE the child, never PYTHONPATH (the MPMD driver's
-        # bootstrap: an inherited PYTHONPATH shadows TPU-plugin deps)
+        # sys.path INSIDE the child (the MPMD driver's bootstrap): the
+        # worker imports this package whatever the supervisor's cwd
         import deepspeed_tpu
         pkg_root = os.path.dirname(os.path.dirname(deepspeed_tpu.__file__))
         boot = ("import sys; sys.path.insert(0, {root!r}); "
@@ -230,6 +230,12 @@ class ProcessFleet:
     def start(self) -> "ProcessFleet":
         if self._started:
             return self
+        # one process per chip: the caller of init_inference(...,
+        # model_parameters=params) has touched JAX, so on a TPU it holds
+        # the chips these workers need — fail now, not after warmup()
+        from ..utils.chip_owner import refuse_children_on_held_tpu
+        refuse_children_on_held_tpu(
+            'serving.fleet.placement "process"', self.n_replicas)
         self._started = True
         self._server = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
         self._server.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)

@@ -339,8 +339,7 @@ def test_sequence_parallel_conflicting_impl_raises():
 
 # tier-2 (round 10 budget): fattest passing legs demoted per the standing
 # guardrail — tier-1 crept past ~80% of the 870s budget once the comm-plan
-# legs landed and the jax_compat shard_map wrapper recovered the 1-bit
-# family on 0.4.x hosts; cheaper cousins still gate tier-1
+# legs landed; cheaper cousins still gate tier-1
 @pytest.mark.slow
 def test_sparse_model_forward_matches_layout_mask():
     """attention_impl='sparse' (as the engine wires it): 'dense' mode must

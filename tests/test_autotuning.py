@@ -58,8 +58,7 @@ def test_autotuner_ranks_and_records_failures(tmp_path):
 
 # tier-2 (round 10 budget): fattest passing legs demoted per the standing
 # guardrail — tier-1 crept past ~80% of the 870s budget once the comm-plan
-# legs landed and the jax_compat shard_map wrapper recovered the 1-bit
-# family on 0.4.x hosts; cheaper cousins still gate tier-1
+# legs landed; cheaper cousins still gate tier-1
 @pytest.mark.slow
 def test_engine_runner_on_cpu_mesh(tmp_path):
     """End-to-end: grid over micro-batch x ZeRO stage with real engines;

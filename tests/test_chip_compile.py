@@ -1,0 +1,178 @@
+"""The chip's compiler, asked without the chip: every kernel of the main path
+compiles for a DESCRIBED TPU v5e at the widths ``chip_smoke.py`` runs.
+
+Interpret mode cannot see what Mosaic refuses (block shapes the tiling
+rules reject, too much fast memory, a kernel the partitioner cannot split):
+before PR 21 the int8 paged-attention tier and ``quant_matmul`` had passed
+every interpret-mode test and never compiled. Nothing runs here — these
+tests say nothing about results or times.
+
+The topology is described inside a module-scoped fixture (never at import:
+only one process at a time may load the TPU library, and every xdist worker
+imports this file), in the test's own process, with the persistent
+compilation cache off. The dispatch wrappers ask ``jax.default_backend()``
+and see the CPU, so the tests call the kernel entries or steer the wrapper
+with monkeypatch — the program has no option for it.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+# gpt2-1.3b serving widths (chip_smoke.FULL): 24 layers, 16 heads x 128,
+# block 32, pool 1024 blocks, 8 lanes, 32 blocks per sequence
+L, NH, HD, BS, NB, B, NBK = 24, 16, 128, 32, 1024, 8, 32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """ShapeDtypeStruct factory on one described chip; the persistent cache
+    is off for the module (an entry written for a described chip cannot be
+    read back without one, and the retry warns)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    one = SingleDeviceSharding(topo.devices[0])
+    yield lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one)
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _kernels(fn, *args):
+    """Compile for the described chip; the kernel scopes in the program."""
+    import re
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return [re.search(r'op_name="([^"]+)"', line).group(1)
+            for line in text.splitlines()
+            if "tpu_custom_call" in line and "custom-call(" in line]
+
+
+@pytest.mark.parametrize("heads,head_dim", [(16, 128), (32, 64)])
+def test_flash_fwd_bwd_compiles(chip, heads, head_dim):
+    from deepspeed_tpu.ops.pallas.flash_attention import _flash
+    x = chip((2, heads, 1024, head_dim), jnp.bfloat16)
+
+    def loss(q, k, v):
+        # the kernel entry at the blocks flash_attention() picks for S=1024
+        out = _flash(q, k, v, (None, None, None), heads, True,
+                     head_dim ** -0.5, 1024, 1024, 1024, 1024, 0, 0.0,
+                     False, False)
+        return out.astype(jnp.float32).sum()
+
+    names = _kernels(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)
+    for scope in ("flash_attention_fwd", "flash_attention_bwd_dq",
+                  "flash_attention_bwd_dkv"):
+        assert any(scope in n for n in names), (scope, names)
+
+
+def test_flash_masked_wrapper_compiles(chip, monkeypatch):
+    """bert-large's padded-batch leg (S 2048, D 64, key mask) through the
+    public wrapper, steered onto the kernel path from the test."""
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    x = chip((4, 16, 2048, 64), jnp.bfloat16)
+    m = chip((4, 1, 1, 2048), jnp.bool_)
+
+    def loss(q, k, v, m):
+        return flash_attention(q, k, v, causal=False,
+                               mask=m).astype(jnp.float32).sum()
+
+    names = _kernels(jax.grad(loss, argnums=(0, 1, 2)), x, x, x, m)
+    assert len(names) == 3, names
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_paged_attention_stacked_pool_compiles(chip, quant):
+    """The serving decode kernel on the stacked [L, nh, blocks, bs, hd] pool
+    with a traced layer index; int8 adds the per-slot scale operands whose
+    (1, block_size) tile the compiler refused before PR 21."""
+    from deepspeed_tpu.ops.pallas.paged_attention import paged_attention
+    q = chip((B, NH, 1, HD), jnp.bfloat16)
+    pool = chip((L, NH, NB, BS, HD), jnp.int8 if quant else jnp.bfloat16)
+    bt, lens, li = (chip((B, NBK), jnp.int32), chip((B,), jnp.int32),
+                    chip((), jnp.int32))
+    if quant:
+        # init_pool's scale layout: [L, nh, num_slots, 1]
+        sc = chip((L, NH, NB * BS, 1), jnp.float32)
+        names = _kernels(
+            lambda q, k, v, bt, lens, li, ks, vs: paged_attention(
+                q, k, v, bt, lens, layer_idx=li, k_scale=ks, v_scale=vs),
+            q, pool, pool, bt, lens, li, sc, sc)
+    else:
+        names = _kernels(
+            lambda q, k, v, bt, lens, li: paged_attention(
+                q, k, v, bt, lens, layer_idx=li),
+            q, pool, pool, bt, lens, li)
+    assert len(names) == 1 and "paged_attention" in names[0], names
+
+
+@pytest.mark.parametrize("K,N", [(2048, 6144), (2048, 8192), (8192, 2048)],
+                         ids=["qkv", "mlp_fc", "mlp_proj"])
+def test_quant_matmul_compiles(chip, monkeypatch, K, N):
+    """The three 1.3B projections of the int8 weight tier (the (1, 128)
+    scale tile of a 2-D [K/256, N] array was refused before PR 21)."""
+    from deepspeed_tpu.ops.pallas.quant_matmul import quant_matmul
+    from deepspeed_tpu.quant_format import QUANT_BLOCK
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    names = _kernels(quant_matmul, chip((B, 1, K), jnp.bfloat16),
+                     chip((K, N), jnp.int8),
+                     chip((K // QUANT_BLOCK, N), jnp.float32))
+    assert len(names) == 1 and "quant_matmul" in names[0], names
+
+
+def test_decode_attention_stacked_cache_compiles(chip):
+    """The dense-cache decode kernel models/generation.py routes to (B 8,
+    cache 2048, traced layer index)."""
+    from deepspeed_tpu.ops.pallas.decode_attention import decode_attention
+    q = chip((B, NH, 1, HD), jnp.bfloat16)
+    cache = chip((L, B, NH, 2048, HD), jnp.bfloat16)
+    names = _kernels(
+        lambda q, k, v, cur, li: decode_attention(q, k, v, cur,
+                                                  layer_idx=li),
+        q, cache, cache, chip((), jnp.int32), chip((), jnp.int32))
+    assert len(names) == 1 and "decode_attention" in names[0], names
+
+
+def test_sliding_window_kernel_compiles(chip):
+    """The block-skip layout kernel a pure causal window routes to."""
+    from deepspeed_tpu.ops.attention import sliding_window_attention
+    x = chip((2, 16, 2048, 128), jnp.bfloat16)
+    names = _kernels(lambda q, k, v: sliding_window_attention(q, k, v, 512),
+                     x, x, x)
+    assert len(names) == 1 and "block_sparse_attention" in names[0], names
+
+
+def test_flash_partitions_over_a_four_chip_mesh(topo, chip, monkeypatch):
+    """A Mosaic kernel cannot be partitioned by the compiler; on a mesh of
+    several chips flash_attention wraps itself in shard_map (PR 21: the
+    ZeRO-3 dp=4 step was refused here, not on the chip)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+    from deepspeed_tpu.parallel.mesh import MESH_AXES
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4, 1, 1, 1), MESH_AXES)
+    x = jax.ShapeDtypeStruct(
+        (8, 16, 1024, 128), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P(("data", "expert"))))
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    names = _kernels(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)
+    assert len(names) == 3 and all("shard_map" in n for n in names), names

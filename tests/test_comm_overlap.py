@@ -465,9 +465,7 @@ def test_accuracy_guard_exempts_exact_wire_overlap():
 def test_envelope_degrade_matrix():
     """Round-14 contract: a forced non-exact grad sync OUTSIDE the
     envelope degrades to exact with a warning instead of raising, and
-    this pins exactly which configs degrade on this host. TP now sits
-    INSIDE the envelope where native jax.shard_map exists; on the 0.4.x
-    line it degrades (the legacy adapter aborts inside XLA)."""
+    this pins exactly which configs degrade. TP sits INSIDE the envelope."""
     require_devices(8)
     import logging
     from deepspeed_tpu.utils.logging import logger as ds_logger
@@ -508,13 +506,8 @@ def test_envelope_degrade_matrix():
                                 sharding_rules=mcfg.tp_rules())
     finally:
         ds_logger.removeHandler(handler)
-    if hasattr(jax, "shard_map"):
-        # modern jaxlib: TP composes — the forced verdict holds
-        assert eng.comm_plan_ctx.resolved["grad_reduce_scatter"] == "int8"
-    else:
-        assert eng.comm_plan_ctx.resolved["grad_reduce_scatter"] == "exact"
-        assert any("native jax.shard_map" in m for m in records), records
-        assert np.isfinite(float(eng.train_batch(batch)["loss"]))
+    # TP composes — the forced verdict holds
+    assert eng.comm_plan_ctx.resolved["grad_reduce_scatter"] == "int8"
     # an unexecutable forced algo NAME still raises (never silently runs
     # something else)
     with pytest.raises(ValueError, match="not executable"):
@@ -525,13 +518,9 @@ def test_envelope_degrade_matrix():
 
 @pytest.mark.slow
 def test_tp_composed_explicit_sync_parity():
-    """The widened envelope actually syncing under TP (native
-    jax.shard_map hosts only): int8 grad sync with tp_size=2 tracks the
-    exact twin. Skipped on the 0.4.x line, where the envelope test above
-    pins the degrade instead."""
+    """The widened envelope actually syncing under TP: int8 grad sync
+    with tp_size=2 tracks the exact twin."""
     require_devices(8)
-    if not hasattr(jax, "shard_map"):
-        pytest.skip("TP-composed explicit sync needs native jax.shard_map")
     from deepspeed_tpu.models import build_model, causal_lm_loss
 
     def mk(extra):

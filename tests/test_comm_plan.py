@@ -199,12 +199,11 @@ def test_grad_sync_matches_mean_and_propagates_nonfinite(mesh8):
 
 def test_quantized_all_to_all_matches_exact(mesh8):
     from deepspeed_tpu.runtime.comm.quantized import quantized_all_to_all
-    from deepspeed_tpu.utils.jax_compat import shard_map
     rng = np.random.default_rng(2)
     vals = rng.standard_normal((64, 48)).astype(np.float32)
     x = jax.device_put(jnp.asarray(vals), NamedSharding(mesh8, P("data")))
     got = np.asarray(quantized_all_to_all(x, mesh=mesh8, axis="data"))
-    exact = shard_map(
+    exact = jax.shard_map(
         lambda xl: jax.lax.all_to_all(xl, "data", split_axis=0,
                                       concat_axis=0, tiled=True),
         mesh=mesh8, in_specs=P("data"), out_specs=P("data"),
@@ -266,10 +265,9 @@ def test_wire_bytes_grad_sync_int8_vs_exact(mesh8):
 
 def test_wire_bytes_all_to_all_int8_vs_exact(mesh8):
     from deepspeed_tpu.runtime.comm.quantized import quantized_all_to_all
-    from deepspeed_tpu.utils.jax_compat import shard_map
     x = jax.device_put(jnp.ones((64, 4096), jnp.float32),
                        NamedSharding(mesh8, P("data")))
-    exact = jax.jit(shard_map(
+    exact = jax.jit(jax.shard_map(
         lambda xl: jax.lax.all_to_all(xl, "data", split_axis=0,
                                       concat_axis=0, tiled=True),
         mesh=mesh8, in_specs=P("data"), out_specs=P("data"),

@@ -416,8 +416,9 @@ def test_decode_hot_path_has_no_bulk_dequant_outside_kernels():
     pool-slice or packed-kernel size happens outside a pallas_call — the
     round-12 O(pool) dequant copy and the _kernel_of full-weight
     materialization are structurally absent from the hot path."""
-    model, cfg = build_model("gpt2-tiny", max_seq_len=256,
-                             attention_impl="reference", dtype=jnp.float32)
+    # attention_impl stays "auto": a "reference" model serves on the gather
+    # oracle (serving/model_runner.py), and this audit wants the kernels
+    model, cfg = build_model("gpt2-tiny", max_seq_len=256, dtype=jnp.float32)
     ids = np.zeros((2, 1), np.int32)
     params = ensure_scan_layout(
         model.init(jax.random.PRNGKey(0), {"input_ids": ids})["params"],

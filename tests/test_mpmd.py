@@ -1,13 +1,9 @@
 """MPMD pipeline placement (round 13): schedule/placement split, explicit
 transfer channel, per-stage programs, one-stage elastic restart.
 
-Parity strategy on this host matters: the SPMD pipeline executors need
-``jax.shard_map`` (absent on the 0.4.x jaxlib — the documented
-pre-existing failure class), so the always-on oracle is plain autodiff
-of the SAME parameters through the non-pipelined model, and the
-MPMD-vs-SPMD engine legs guard on shard_map availability. The MPMD path
-itself never touches shard_map — it is the pipeline placement that DOES
-run on 0.4.x hosts.
+Parity strategy: the oracle is plain autodiff of the SAME parameters
+through the non-pipelined model, plus MPMD-vs-SPMD legs over the same
+schedule tables. The MPMD path itself never touches shard_map.
 """
 
 import json
@@ -34,8 +30,6 @@ from deepspeed_tpu.runtime.pipe.mpmd import (LocalChannel, MPMDPipeline,
                                              StageWorkerSpec,
                                              mpmd_value_and_grad)
 from deepspeed_tpu.testing import chaos
-
-HAS_SHARD_MAP = hasattr(jax, "shard_map")
 
 
 # -- schedule layer: tables + instruction streams -----------------------------
@@ -208,10 +202,6 @@ def test_mpmd_executor_matches_autodiff(schedule):
                                atol=1e-6)
 
 
-@pytest.mark.skipif(not HAS_SHARD_MAP,
-                    reason="SPMD 1F1B executor needs jax.shard_map "
-                           "(pre-existing 0.4.x gap; the MPMD side of this "
-                           "parity is still covered vs autodiff)")
 def test_mpmd_executor_matches_spmd_executor():
     require_devices(4)
     """Both placements of the SAME schedule tables produce the same loss
@@ -350,9 +340,6 @@ def test_mpmd_engine_trains_and_8step_losses_match_plain_engine():
         assert abs(a - b) < 2e-3, (i, a, b, mp_losses, pl_losses)
 
 
-@pytest.mark.skipif(not HAS_SHARD_MAP,
-                    reason="SPMD pipeline engine needs jax.shard_map "
-                           "(pre-existing 0.4.x gap)")
 def test_mpmd_engine_loss_parity_vs_spmd_pipeline_engine():
     require_devices(2)
     """The acceptance leg verbatim: MPMD vs SPMD pipeline engines on the
@@ -517,10 +504,7 @@ def _run_driver(workdir, steps=6, specs=None, **kw):
 
 def test_two_process_mpmd_two_stage_run(tmp_path):
     """The cross-process reference path: two stage WORKER processes over
-    the socket channel, per-stage checkpoints, rc 0, one loss per step.
-    (This is the pipeline-over-processes coverage that still runs on the
-    0.4.x host where the SPMD 2-proc TP+PP leg cannot — see
-    test_multiprocess.py's xfail.)"""
+    the socket channel, per-stage checkpoints, rc 0, one loss per step."""
     rc, losses, sup = _run_driver(str(tmp_path), steps=4)
     assert rc == 0 and sup.restarts == [0, 0]
     assert set(losses) == set(range(4))

@@ -286,19 +286,6 @@ def test_two_process_sharded_save_with_per_rank_failpoint(tmp_path):
 
 
 @pytest.mark.slow
-@pytest.mark.xfail(
-    not hasattr(__import__("jax"), "shard_map"),
-    reason="pre-existing since PR 6, triaged round 13: the PP leg's SPMD "
-           "pipeline (runtime/pipe/spmd.pipeline_apply) calls jax.shard_map, "
-           "absent on 0.4.x jaxlib — the worker dies with AttributeError "
-           "after the TP leg passes. Deliberately NOT routed through "
-           "utils.jax_compat.shard_map: the 0.4.x legacy-shard_map adapter "
-           "ABORTS inside XLA on SPMD-pipeline compiles (documented in "
-           "jax_compat.py / PR 3). Cross-process pipeline coverage on this "
-           "host lives in test_mpmd.py::test_two_process_mpmd_two_stage_run "
-           "(the MPMD placement needs no shard_map); this leg un-xfails on "
-           "jax>=0.5 hosts.",
-    strict=False)
 def test_two_process_tp_and_pp(tmp_path):
     """TP=2 and PP=2 over two REAL OS processes x 4 global devices (2 local
     each): the reference runs its whole feature matrix under

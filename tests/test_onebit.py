@@ -433,8 +433,7 @@ def test_zeroone_engine_program_schedule():
 
 # tier-2 (round 10 budget): fattest passing legs demoted per the standing
 # guardrail — tier-1 crept past ~80% of the 870s budget once the comm-plan
-# legs landed and the jax_compat shard_map wrapper recovered the 1-bit
-# family on 0.4.x hosts; cheaper cousins still gate tier-1
+# legs landed; cheaper cousins still gate tier-1
 @pytest.mark.slow
 def test_zeroone_trains_and_local_steps_are_collective_free():
     """End-to-end: 0/1 Adam trains through all four program kinds, and the

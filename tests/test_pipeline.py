@@ -709,9 +709,8 @@ def test_pipe_bench_discovery_and_regression(tmp_path):
 
 
 def test_repo_has_recorded_pipe_sweep():
-    """PIPEBENCH_r01 anchors the convention (CPU host; the SPMD cell is
-    null there — the 0.4.x shard_map gap — and fills in on real-chip
-    runs)."""
+    """PIPEBENCH_r01 anchors the convention (a CPU-host record whose SPMD
+    cell is null; the regression gate ignores a null cell)."""
     import os
     from deepspeed_tpu.benchmarks.pipeline_bench import latest_pipe_bench
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

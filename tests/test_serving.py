@@ -8,6 +8,8 @@ greedy serving output must be TOKEN-EXACT with one-at-a-time generation
 arrivals, mixed lengths, admissions and evictions.
 """
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -614,8 +616,10 @@ def test_int8_kv_pool_parity_jnp_and_kernel(tiny):
     for p, o in zip(prompts, outs):
         assert o == _oracle_tokens(cfg, params, p, 6), \
             "int8 pool beyond the quantization error bound"
-    # the Pallas int8 tier: same pools, dequant in-kernel
-    eng_k = ServingEngine(cfg, params,
+    # the Pallas int8 tier: same pools, dequant in-kernel (the fixture's
+    # attention_impl="reference" would serve on the gather oracle)
+    eng_k = ServingEngine(dataclasses.replace(cfg, attention_impl="auto"),
+                          params,
                           serving=dict(SERVE_CFG, kv_cache_dtype="int8"),
                           interpret=True)
     outs_k = eng_k.generate_batch(prompts, max_new_tokens=6)
@@ -642,7 +646,8 @@ def test_int8_weight_only_decode_parity(tiny):
     for p, o in zip(prompts, outs):
         assert o == _oracle_tokens(cfg, params, p, 6), \
             "int8 weight-only decode beyond the quantization error bound"
-    eng_k = ServingEngine(cfg, params,
+    eng_k = ServingEngine(dataclasses.replace(cfg, attention_impl="auto"),
+                          params,
                           serving=dict(SERVE_CFG, weight_dtype="int8",
                                        kv_cache_dtype="int8"),
                           interpret=True)
