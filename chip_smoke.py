@@ -25,9 +25,10 @@ belongs to one process at a time) and starts no other.
 The last line of stdout is exactly
 ``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}``.
 
-CPU rehearsal at a tiny size: ``scripts/smoke_rehearsal.py`` drives these
-phase functions with ``TINY`` sizes and interpreted kernels; it never
-prints the ``ok`` line.
+The phase functions take their sizes as an argument and this file holds
+one set of them, the full width; no check here can be turned off.
+``scripts/smoke_rehearsal.py`` drives the same functions on the CPU with
+sizes of its own and never prints the ``ok`` line.
 """
 
 from __future__ import annotations
@@ -48,8 +49,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 @dataclasses.dataclass(frozen=True)
 class Sizes:
-    """One run's sizes. ``FULL`` is what the script runs; ``TINY`` is the CPU
-    rehearsal (scripts/smoke_rehearsal.py) and is never reported."""
+    """One run's sizes; the defaults are the full width this script runs."""
     preset: str = "gpt2-1.3b"
     model_kw: Tuple[Tuple[str, object], ...] = ()
     seq: int = 1024
@@ -73,24 +73,20 @@ class Sizes:
 
 
 FULL = Sizes()
-TINY = Sizes(preset="gpt2-tiny",
-             model_kw=(("hidden_size", 128), ("num_layers", 2),
-                       ("num_heads", 2), ("vocab_size", 512)),
-             seq=128, rows=4, rows_multichip=8, steps=4, lr=1e-2,
-             block_size=32, pool_blocks=24, max_batch=4,
-             prompts=((64, True), (32, False), (96, True), (64, False)),
-             new_tokens=(6, 4, 6, 4), int8_prompts=(32, 64),
-             int8_new_tokens=4)
 
-#: a greedy mismatch between the kernel-routed server and its
-#: reference-routed twin is a rounding tie only when the two tokens' logits
-#: lie this close (in bf16 ulps at the logits' magnitude); anything wider
-#: fails the smoke
-NEAR_TIE_ULPS = 4.0
 #: --multichip: |loss(4 chips) - loss(1 chip)| per step, same seeded batch.
 #: Both runs hold bf16 params and accumulate bf16 grads; the reduction order
 #: differs (reduce-scatter over 4 vs 4 sequential micro-steps)
 MULTICHIP_LOSS_TOL = 0.05
+#: --multichip: the compute params ZeRO-3 keeps whole BY RULE besides those
+#: under stage3_param_persistence_threshold. Every other parameter, and
+#: every optimizer-state leaf, must be split over all the chips.
+WHOLE_BY_RULE = {
+    "['wte']['embedding']":
+        "vocab 50257 has no factor 4, and the policy never splits a compute "
+        "param's feature dim (runtime/zero/stages.py insert_zero_axes, "
+        "avoid_last); its Adam moments ARE split",
+}
 
 
 def check(ok: bool, what: str) -> None:
@@ -155,7 +151,7 @@ def peak_bytes(dev) -> Optional[int]:
 
 def train_phase(sz: Sizes, workdir: str, watch: CompileWatch, *,
                 rows: int, devices: Optional[Sequence] = None,
-                expect_kernels: bool = True, label: str = "train"):
+                label: str = "train"):
     """A few ZeRO-3 steps through ``deepspeed_tpu.initialize`` on the mesh of
     ``devices`` (None: the engine's default, every chip JAX reports).
     Returns (engine, result dict); the caller frees the engine."""
@@ -248,13 +244,13 @@ def train_phase(sz: Sizes, workdir: str, watch: CompileWatch, *,
     calls = kernel_calls(text)
     print(f"[{label}] Pallas custom calls in the compiled step: "
           f"{sorted(set(calls))}", flush=True)
-    if expect_kernels:
-        for scope in ("flash_attention_fwd", "flash_attention_bwd_dq",
-                      "flash_attention_bwd_dkv"):
-            check(any(scope in c for c in calls),
-                  f"[{label}] compiled step holds the {scope} kernel")
+    for scope in ("flash_attention_fwd", "flash_attention_bwd_dq",
+                  "flash_attention_bwd_dkv"):
+        check(any(scope in c for c in calls),
+              f"[{label}] compiled step holds the {scope} kernel")
     peaks = [peak_bytes(d) for d in engine.mesh.devices.flat]
     print(f"[{label}] peak_bytes_in_use per device: {peaks}", flush=True)
+    check(all(peaks), f"[{label}] every device reports its peak memory")
     return engine, {"losses": losses, "step_s": steady, "text": text,
                     "peaks": peaks}
 
@@ -359,7 +355,7 @@ def _logit_gap(ie, prefix: List[int], tok_a: int, tok_b: int):
     return float(logits[tok_a]), float(logits[tok_b])
 
 
-def _kernel_parity(cfg, sz: Sizes, interpret: bool) -> None:
+def _kernel_parity(cfg, sz: Sizes) -> None:
     """Op-level parity ON THE DEVICE of the two int8 kernels against the
     repo's jnp oracles, at the serving widths."""
     import jax
@@ -381,8 +377,8 @@ def _kernel_parity(cfg, sz: Sizes, interpret: bool) -> None:
     lens = jnp.asarray(rng.integers(1, nbk * bs + 1, size=B), jnp.int32)
     q = jnp.asarray(rng.standard_normal((B, nh, 1, hd)), jnp.bfloat16)
     run = lambda impl: jax.jit(lambda *a: paged_attention(
-        *a[:3], bt, lens, k_scale=a[3], v_scale=a[4], impl=impl,
-        interpret=interpret))(q, kq, vq, ks, vs)
+        *a[:3], bt, lens, k_scale=a[3], v_scale=a[4], impl=impl))(
+            q, kq, vq, ks, vs)
     got, want = (np.asarray(run(i), np.float32) for i in ("auto",
                                                           "reference"))
     err = float(np.max(np.abs(got - want)))
@@ -394,8 +390,7 @@ def _kernel_parity(cfg, sz: Sizes, interpret: bool) -> None:
         w = jnp.asarray(rng.standard_normal((K, N)) * 0.02, jnp.float32)
         x = jnp.asarray(rng.standard_normal((B, 1, K)), jnp.bfloat16)
         wq, sc = pack_kernel(w)
-        got = np.asarray(jax.jit(lambda x, wq, sc: quant_matmul(
-            x, wq, sc, interpret=interpret))(x, wq, sc), np.float32)
+        got = np.asarray(jax.jit(quant_matmul)(x, wq, sc), np.float32)
         want = np.asarray(quant_matmul_reference(x, wq, sc), np.float32)
         err = float(np.max(np.abs(got - want)))
         scale = float(np.max(np.abs(want)))
@@ -404,8 +399,7 @@ def _kernel_parity(cfg, sz: Sizes, interpret: bool) -> None:
               f"(max abs err {err:.4f} of {scale:.2f})")
 
 
-def serve_phase(sz: Sizes, workdir: str, params, watch: CompileWatch, *,
-                interpret: bool = False, expect_kernels: bool = True) -> None:
+def serve_phase(sz: Sizes, workdir: str, params, watch: CompileWatch) -> None:
     """Serve through ``init_inference(...).serve()``: the kernel-routed
     server, its reference-routed twin, then the int8 tier."""
     import jax
@@ -433,19 +427,18 @@ def serve_phase(sz: Sizes, workdir: str, params, watch: CompileWatch, *,
 
     snap = watch.snapshot()
     ie = ds.init_inference(model, cfg_path, model_parameters=params)
-    srv = ie.serve(interpret=interpret)
+    srv = ie.serve()
     outs = _serve(srv, prompts, sz.new_tokens, "serve")
     print(f"[serve] compiles so far: {watch.since(snap)}", flush=True)
     check(srv._decode_fn._cache_size() == 1,
           "[serve] one decode compile (_decode_fn._cache_size() == 1)")
     check(srv.stats["prefix_hit_tokens"] > 0,
           f"[serve] prefix_hit_tokens {srv.stats['prefix_hit_tokens']} > 0")
-    if expect_kernels:
-        calls = kernel_calls(_decode_text(srv))
-        print(f"[serve] Pallas custom calls in the decode step: "
-              f"{sorted(set(calls))}", flush=True)
-        check(any("paged_attention" in c for c in calls),
-              "[serve] compiled decode step holds the paged kernel")
+    calls = kernel_calls(_decode_text(srv))
+    print(f"[serve] Pallas custom calls in the decode step: "
+          f"{sorted(set(calls))}", flush=True)
+    check(any("paged_attention" in c for c in calls),
+          "[serve] compiled decode step holds the paged kernel")
     peak = peak_bytes(jax.devices()[0])
     print(f"[serve] peak_bytes_in_use {peak} (the process's peak so far, "
           "train phase included)", flush=True)
@@ -454,35 +447,29 @@ def serve_phase(sz: Sizes, workdir: str, params, watch: CompileWatch, *,
     # the twin: same dtype, same weights, same requests in the same order —
     # only the decode attention differs (gather reference, no kernel)
     twin_ie = ds.init_inference(twin_model, cfg_path, model_parameters=params)
-    twin = twin_ie.serve(interpret=interpret)
+    twin = twin_ie.serve()
     twin_outs = _serve(twin, prompts, sz.new_tokens, "serve-twin")
-    if expect_kernels:
-        check(not kernel_calls(_decode_text(twin)),
-              "[serve-twin] the twin's decode step holds NO Pallas kernel")
+    check(not kernel_calls(_decode_text(twin)),
+          "[serve-twin] the twin's compiled decode step holds NO Pallas "
+          "kernel")
     _free_server(twin)
-    ties = 0
-    for i, (a, b) in enumerate(zip(outs, twin_outs)):
-        if a == b:
-            continue
+    parted = [i for i, (a, b) in enumerate(zip(outs, twin_outs)) if a != b]
+    for i in parted:
+        # the diagnostic of a failure: where the two part, and how far
+        # apart the model's own full forward puts the two tokens
+        a, b = outs[i], twin_outs[i]
         pos = next(j for j, (x, y) in enumerate(zip(a, b)) if x != y)
         la, lb = _logit_gap(ie, prompts[i] + a[:pos], a[pos], b[pos])
-        ulp = 2.0 ** -8 * max(abs(la), abs(lb), 1.0)
         print(f"[serve] request {i}: kernel and twin part at generated "
               f"position {pos}: token {a[pos]} (logit {la:.4f}) vs "
-              f"{b[pos]} (logit {lb:.4f}); gap {abs(la - lb):.4f} = "
-              f"{abs(la - lb) / ulp:.1f} bf16 ulps", flush=True)
-        check(abs(la - lb) <= NEAR_TIE_ULPS * ulp,
-              f"[serve] request {i} mismatch is a rounding tie "
-              f"(<= {NEAR_TIE_ULPS} ulps); a wider gap means the kernel "
-              "and the reference disagree")
-        ties += 1
-    check(ties <= len(outs) // 2,
-          f"[serve] outputs equal the reference-routed twin: "
-          f"{len(outs) - ties}/{len(outs)} token-exact, {ties} parted at a "
-          "rounding tie")
+              f"{b[pos]} (logit {lb:.4f}); gap {abs(la - lb):.4f}",
+              flush=True)
+    check(not parted,
+          f"[serve] outputs equal the reference-routed twin, token for "
+          f"token: {len(outs) - len(parted)}/{len(outs)} requests")
 
     # ---- the int8 tier: int8 KV pool + blockwise-int8 weights ------------
-    _kernel_parity(cfg, sz, interpret)
+    _kernel_parity(cfg, sz)
     q_path = os.path.join(workdir, "inference_config_int8.json")
     with open(q_path, "w") as f:
         json.dump({"dtype": "bfloat16",
@@ -492,9 +479,7 @@ def serve_phase(sz: Sizes, workdir: str, params, watch: CompileWatch, *,
     q_prompts = [rng.integers(1, cfg.vocab_size, size=n).tolist()
                  for n in sz.int8_prompts]
     q_new = [sz.int8_new_tokens] * len(q_prompts)
-    qsrv = ds.init_inference(model, q_path,
-                             model_parameters=params).serve(
-                                 interpret=interpret)
+    qsrv = ds.init_inference(model, q_path, model_parameters=params).serve()
     check(str(qsrv.pools["k"].dtype) == "int8"
           and str(qsrv.params["blocks"]["attn_qkv"]["kernel"].dtype)
           == "int8", "[int8] pool and packed weights are int8")
@@ -502,58 +487,52 @@ def serve_phase(sz: Sizes, workdir: str, params, watch: CompileWatch, *,
     check(all(0 <= t < cfg.vocab_size for o in q_outs for t in o),
           "[int8] every token is in the vocabulary")
     check(qsrv._decode_fn._cache_size() == 1, "[int8] one decode compile")
-    if expect_kernels:
-        calls = kernel_calls(_decode_text(qsrv))
-        print(f"[int8] Pallas custom calls in the decode step: "
-              f"{sorted(set(calls))}", flush=True)
-        check(any("paged_attention" in c for c in calls),
-              "[int8] compiled decode step holds the int8 paged kernel")
-        check(any("quant_matmul" in c for c in calls),
-              "[int8] compiled decode step holds the int8 matmul kernel")
+    calls = kernel_calls(_decode_text(qsrv))
+    print(f"[int8] Pallas custom calls in the decode step: "
+          f"{sorted(set(calls))}", flush=True)
+    check(any("paged_attention" in c for c in calls),
+          "[int8] compiled decode step holds the int8 paged kernel")
+    check(any("quant_matmul" in c for c in calls),
+          "[int8] compiled decode step holds the int8 matmul kernel")
     _free_server(qsrv)
 
 
 # -------------------------------------------------------------- multichip
 
 
-def _shard_report(tree, devices, label: str, threshold: int) -> None:
-    """Every leaf the ZeRO-3 policy shards lies on len(devices) distinct
-    devices with 1/len of its bytes each; the leaves it keeps whole by rule
-    (below stage3_param_persistence_threshold, or no dim divisible by the
-    dp degree that is not the feature dim) are listed."""
+def _shard_report(tree, devices, label: str,
+                  whole_ok=lambda name, leaf: False) -> None:
+    """Every array leaf lies on len(devices) distinct devices with
+    1/len(devices) of its bytes on each, except those ``whole_ok`` names —
+    the leaves the ZeRO-3 policy keeps whole by a stated rule, which are
+    listed."""
     import jax
-    import numpy as np
     n = len(devices)
-    sharded_b = whole_b = big_b = 0
-    big_whole = []
+    split_b = 0
+    whole, wrong = [], []
     for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
         if not hasattr(leaf, "addressable_shards") or leaf.ndim == 0:
             continue
         name = jax.tree_util.keystr(path)
         shards = leaf.addressable_shards
-        if {s.device for s in shards} != set(devices):
-            check(False, f"[{label}] {name} has a shard on each of the {n} "
-                  "devices")
-        big = int(np.prod(leaf.shape)) >= threshold
-        big_b += leaf.nbytes if big else 0
-        if {s.data.nbytes for s in shards} == {leaf.nbytes // n}:
-            sharded_b += leaf.nbytes
+        if {s.device for s in shards} == set(devices) and len(shards) == n \
+                and all(s.data.nbytes * n == leaf.nbytes for s in shards):
+            split_b += leaf.nbytes
+        elif whole_ok(name, leaf):
+            whole.append((name, leaf.shape))
         else:
-            whole_b += leaf.nbytes
-            if big:
-                big_whole.append((name, leaf.shape, leaf.nbytes))
-    print(f"[{label}] {sharded_b / 1e9:.3f} GB in leaves split {n} ways "
-          f"(a quarter of the bytes on each of {n} distinct devices), "
-          f"{whole_b / 1e9:.3f} GB kept whole; whole leaves above the "
-          f"persistence threshold: {big_whole}", flush=True)
-    whole_big_b = sum(w[2] for w in big_whole)
-    check(sharded_b > 0 and whole_big_b <= 0.1 * big_b,
-          f"[{label}] at least 90% of the bytes in leaves above the "
-          f"persistence threshold ({threshold} elements) are split {n} ways")
+            wrong.append((name, leaf.shape,
+                          [(str(s.device), s.data.shape) for s in shards]))
+    print(f"[{label}] {split_b / 1e9:.3f} GB in leaves with a quarter of "
+          f"their bytes on each of {n} distinct devices; whole by rule: "
+          f"{whole}", flush=True)
+    check(split_b > 0 and not wrong,
+          f"[{label}] every leaf not whole by rule is split over {n} "
+          f"distinct devices, 1/{n} of its bytes each"
+          + (f" — NOT: {wrong}" if wrong else ""))
 
 
-def multichip_phase(sz: Sizes, workdir: str, watch: CompileWatch,
-                    expect_kernels: bool = True) -> None:
+def multichip_phase(sz: Sizes, workdir: str, watch: CompileWatch) -> None:
     """ZeRO-3 over dp=4 and its comparison: the SAME seeded batch of
     ``rows_multichip`` rows, split four ways (micro 2 x dp 4 x gas 1) on the
     mesh of all four chips, against micro 2 x gas 4 on a mesh of one."""
@@ -562,11 +541,13 @@ def multichip_phase(sz: Sizes, workdir: str, watch: CompileWatch,
     check(len(devs) == 4, f"--multichip needs 4 devices (JAX reports "
           f"{len(devs)})")
     engine, four = train_phase(sz, workdir, watch, rows=sz.rows_multichip,
-                               devices=devs, expect_kernels=expect_kernels,
-                               label="dp4")
+                               devices=devs, label="dp4")
     thr = engine.config.zero_optimization.param_persistence_threshold
-    _shard_report(engine.state.params, devs, "dp4 params", thr)
-    _shard_report(engine.state.opt_state, devs, "dp4 optimizer state", thr)
+    print(f"[dp4] whole by rule: params under the persistence threshold "
+          f"({thr} elements), and {WHOLE_BY_RULE}", flush=True)
+    _shard_report(engine.state.params, devs, "dp4 params",
+                  lambda name, leaf: leaf.size < thr or name in WHOLE_BY_RULE)
+    _shard_report(engine.state.opt_state, devs, "dp4 optimizer state")
     n_ag = len(re.findall(r"\sall-gather(-start)?\(", four["text"]))
     # the TPU compiler writes a reduce-scatter either as the op or as a
     # kCustom fusion named all-reduce-scatter (all-reduce + slice, fused)
@@ -578,14 +559,11 @@ def multichip_phase(sz: Sizes, workdir: str, watch: CompileWatch,
           f"reduce-scatter (op or all-reduce-scatter fusion) {n_rs}, "
           f"all-reduce {n_ar}, all-to-all {n_a2a}", flush=True)
     check(n_ag > 0, "[dp4] compiled step holds all-gather")
-    check(n_rs > 0 or not expect_kernels,
-          "[dp4] compiled step holds reduce-scatter (the CPU backend of a "
-          "rehearsal forms none)")
+    check(n_rs > 0, "[dp4] compiled step holds reduce-scatter")
     free_engine(engine)
 
     engine, one = train_phase(sz, workdir, watch, rows=sz.rows_multichip,
-                              devices=devs[:1],
-                              expect_kernels=expect_kernels, label="dp1")
+                              devices=devs[:1], label="dp1")
     free_engine(engine)
     diffs = [abs(a - b) for a, b in zip(four["losses"], one["losses"])]
     print(f"[multichip] |loss dp4 - loss dp1| per step: "
@@ -593,10 +571,9 @@ def multichip_phase(sz: Sizes, workdir: str, watch: CompileWatch,
     check(max(diffs) <= MULTICHIP_LOSS_TOL,
           f"[multichip] losses agree within {MULTICHIP_LOSS_TOL} (bf16 "
           "state, different reduction order)")
-    if all(p is not None for p in four["peaks"] + one["peaks"]):
-        check(max(four["peaks"]) < one["peaks"][0],
-              f"[multichip] per-device peak on four chips "
-              f"{max(four['peaks'])} < one-chip peak {one['peaks'][0]}")
+    check(max(four["peaks"]) < one["peaks"][0],
+          f"[multichip] per-device peak on four chips {max(four['peaks'])} "
+          f"< one-chip peak {one['peaks'][0]}")
     print(f"[multichip] step median: dp4 {four['step_s']:.3f}s, dp1 "
           f"{one['step_s']:.3f}s (same {sz.rows_multichip}-row batch)",
           flush=True)

@@ -359,15 +359,14 @@ def forward_with_cache(cfg: TransformerConfig, params: PyTree,
                                              (li, 0, 0, pos, 0))
         o = None
         if use_prefill_flash:
-            from ..ops.pallas.flash_attention import flash_attention
+            from ..ops.attention import flash_attention_on_mesh
             # empty cache: attention over the FRESH k/v is exactly the
             # causal prefill; alibi distances from arange positions match
             # q_abs because pos == 0
-            o = flash_attention(q, k, v, causal=True, sm_scale=sm_scale,
-                                window=uniform_window,
-                                softcap=cfg.attn_softcap,
-                                alibi_slopes=prefill_slopes,
-                                interpret=flash_interp)
+            o = flash_attention_on_mesh(
+                q, k, v, causal=True, sm_scale=sm_scale,
+                window=uniform_window, softcap=cfg.attn_softcap,
+                alibi_slopes=prefill_slopes, interpret=flash_interp)
         if o is None and use_kernel:
             from ..ops.pallas.decode_attention import decode_attention
             # stacked form: the kernel indexes layer li out of the

@@ -198,6 +198,80 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
                                      context_lens, q_start=q_start, **kw)
 
 
+def flash_attention_on_mesh(q, k, v, *, mask=None, alibi_slopes=None,
+                            interpret: bool = False, **kw) -> jnp.ndarray:
+    """The flash kernel where its operands live (``kw``: the kernel
+    wrapper's own arguments).
+
+    On one device, off the kernel path (CPU without ``interpret``), or
+    inside a fully manual ``shard_map`` body (shapes are local already)
+    this IS ``ops.pallas.flash_attention.flash_attention``. On a mesh of
+    several chips the compiler refuses a kernel it would have to split
+    ("Mosaic kernels cannot be automatically partitioned"), so the call is
+    made per shard under ``jax.shard_map`` over every mesh axis that is not
+    manual yet (the lowering wants ALL of them manual, unit axes too): batch over the mesh manager's ``BATCH_AXES``, heads over
+    its ``TP_AXIS``. Attention is independent per (batch, head), so the
+    split is exact. A dim its axes do not divide stays whole, and so does
+    everything along any other axis wider than 1 (pipe, seq): every chip
+    along such an axis then repeats the same attention — the result is
+    right, the work is multiplied, and on a TPU that is said once.
+    """
+    from .pallas.flash_attention import flash_attention
+    kw["interpret"] = interpret
+    mesh = getattr(getattr(jax.typeof(q), "sharding", None), "mesh", None)
+    on_tpu = jax.default_backend() == "tpu"
+    free = () if mesh is None or mesh.empty or not (on_tpu or interpret) \
+        else tuple(a for a, t in zip(mesh.axis_names, mesh.axis_types)
+                   if t != jax.sharding.AxisType.Manual)
+    size = lambda axes: int(np.prod([mesh.shape[a] for a in axes]))
+    if not free or size(free) == 1:
+        return flash_attention(q, k, v, mask=mask, alibi_slopes=alibi_slopes,
+                               **kw)
+    from jax.sharding import PartitionSpec as P
+    from ..parallel.mesh import BATCH_AXES, TP_AXIS
+    B, H = q.shape[:2]
+    b_axes = tuple(a for a in BATCH_AXES if a in free)
+    h_axes = tuple(a for a in (TP_AXIS,) if a in free)
+    whole = []
+    if b_axes and B % size(b_axes):
+        whole.append(f"batch {B} is not divisible by {size(b_axes)}")
+        b_axes = ()
+    if h_axes and H % size(h_axes):
+        whole.append(f"heads {H} are not divisible by {size(h_axes)}")
+        h_axes = ()
+    repeated = tuple(a for a in free
+                     if a not in b_axes + h_axes and mesh.shape[a] > 1)
+    if repeated and on_tpu:
+        from ..utils.logging import warning_once
+        why = "; ".join(whole) or "attention splits over batch and heads only"
+        warning_once(
+            f"flash attention [B {B}, H {H}] is repeated on each of the "
+            f"{size(repeated)} chips along mesh axes {repeated}: {why}")
+    b_spec, h_spec = b_axes or None, (h_axes[0] if h_axes else None)
+    qspec = P(b_spec, h_spec, None, None)
+    args, specs = [q, k, v], [qspec, qspec, qspec]
+    if mask is not None:
+        mask = jnp.asarray(mask)      # rank > 4 is refused by the local call
+        mask = mask.reshape((1,) * (4 - mask.ndim) + mask.shape)
+        args.append(mask)
+        specs.append(P(b_spec if mask.shape[0] == B else None,
+                       h_spec if mask.shape[1] == H and H > 1 else None,
+                       None, None))
+    if alibi_slopes is not None:
+        args.append(jnp.asarray(alibi_slopes, jnp.float32).reshape(H))
+        specs.append(P(h_spec))
+
+    def local(q, k, v, *rest):
+        rest = list(rest)
+        m = rest.pop(0) if mask is not None else None
+        sl = rest.pop(0) if alibi_slopes is not None else None
+        return flash_attention(q, k, v, mask=m, alibi_slopes=sl, **kw)
+
+    return jax.shard_map(local, mesh=mesh, in_specs=tuple(specs),
+                         out_specs=qspec, axis_names=frozenset(free),
+                         check_vma=False)(*args)
+
+
 def attention(q: jnp.ndarray,
               k: jnp.ndarray,
               v: jnp.ndarray,
@@ -272,12 +346,10 @@ def attention(q: jnp.ndarray,
                            "falling back to reference")
             impl = "reference"
         else:
-            from .pallas.flash_attention import flash_attention
-            return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale,
-                                   mask=mask, alibi_slopes=alibi_slopes,
-                                   window=window, softcap=softcap,
-                                   block_q=block_q, block_k=block_k,
-                                   interpret=interpret)
+            return flash_attention_on_mesh(
+                q, k, v, causal=causal, sm_scale=sm_scale, mask=mask,
+                alibi_slopes=alibi_slopes, window=window, softcap=softcap,
+                block_q=block_q, block_k=block_k, interpret=interpret)
     # reference: materialize what the kernel computes from indices
     if alibi_slopes is not None:
         ali = alibi_bias_from_slopes(alibi_slopes, q.shape[-2], k.shape[-2])

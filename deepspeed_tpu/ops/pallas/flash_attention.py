@@ -47,7 +47,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
 
@@ -551,12 +550,6 @@ def flash_attention(q: jnp.ndarray,
     # exact reference instead of crashing in pallas_call
     on_tpu = jax.default_backend() == "tpu"
     runnable = interpret or on_tpu
-    mesh = _operand_mesh(q)
-    if mesh is not None and runnable:
-        return _partitioned(mesh, q, k, v, mask, alibi_slopes, dict(
-            causal=causal, sm_scale=sm_scale, window=window, softcap=softcap,
-            block_q=block_q, block_k=block_k, block_q_bwd=block_q_bwd,
-            block_k_bwd=block_k_bwd, interpret=interpret))
 
     # classify the mask (shape work only; materialization happens after the
     # tiling check passes)
@@ -623,56 +616,6 @@ def flash_attention(q: jnp.ndarray,
     return _flash(q, k, v, (kvm, qkm, slopes3), H, causal, sm_scale, block_q,
                   block_k, block_q_bwd, block_k_bwd, window, softcap,
                   mask_per_head, interpret)
-
-
-def _operand_mesh(x):
-    """The mesh ``x`` is laid out on when that mesh spans several devices
-    and none of its axes is already manual (a ``shard_map`` body sees local
-    shapes and needs no further split); else None."""
-    mesh = getattr(getattr(jax.typeof(x), "sharding", None), "mesh", None)
-    if mesh is None or mesh.empty or mesh.size <= 1:
-        return None
-    if any(t == jax.sharding.AxisType.Manual for t in mesh.axis_types):
-        return None
-    return mesh
-
-
-def _partitioned(mesh, q, k, v, mask, alibi_slopes, kw):
-    """Run the kernel per shard: a Mosaic kernel cannot be partitioned by
-    the compiler ("Mosaic kernels cannot be automatically partitioned"), so
-    on a mesh of several chips the call is wrapped in ``jax.shard_map`` —
-    batch split over the mesh's batch axes, heads over its "model" axis.
-    Attention is independent per (batch, head), so the split is exact; a
-    dim the axes do not divide stays whole (replicated work, same result).
-    """
-    from ...parallel.mesh import BATCH_AXES
-    B, H = q.shape[:2]
-    size = lambda axes: int(np.prod([mesh.shape[a] for a in axes]))
-    b_axes = tuple(a for a in BATCH_AXES if a in mesh.axis_names)
-    b_axes = b_axes if b_axes and B % size(b_axes) == 0 else None
-    h_axis = "model" if "model" in mesh.axis_names \
-        and H % mesh.shape["model"] == 0 else None
-    qspec = P(b_axes, h_axis, None, None)
-    args, specs = [q, k, v], [qspec, qspec, qspec]
-    if mask is not None:
-        mask = jnp.asarray(mask)      # rank > 4 is refused by the local call
-        mask = mask.reshape((1,) * (4 - mask.ndim) + mask.shape)
-        args.append(mask)
-        specs.append(P(b_axes if mask.shape[0] == B else None,
-                       h_axis if mask.shape[1] == H and H > 1 else None,
-                       None, None))
-    if alibi_slopes is not None:
-        args.append(jnp.asarray(alibi_slopes, jnp.float32).reshape(H))
-        specs.append(P(h_axis))
-
-    def local(q, k, v, *rest):
-        rest = list(rest)
-        m = rest.pop(0) if mask is not None else None
-        sl = rest.pop(0) if alibi_slopes is not None else None
-        return flash_attention(q, k, v, mask=m, alibi_slopes=sl, **kw)
-
-    return jax.shard_map(local, mesh=mesh, in_specs=tuple(specs),
-                         out_specs=qspec, check_vma=False)(*args)
 
 
 def _reference_fallback(q, k, v, causal, sm_scale, mask, alibi_slopes,

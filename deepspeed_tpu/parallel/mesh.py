@@ -34,6 +34,9 @@ ZERO_AXES = ("data", "expert", "seq")
 EXPERT_ZERO_AXES = ("data", "seq")
 # Axes over which the global batch is split.
 BATCH_AXES = ("data", "expert")
+# Axis over which tensor parallelism splits the attention heads (and the
+# projections' wide dim — models/transformer.py's TP rules name it).
+TP_AXIS = "model"
 
 
 class MeshManager:

@@ -117,6 +117,43 @@ def test_dropout_stays_on_reference(flash_spy):
     assert not flash_spy.kernel_calls
 
 
+@pytest.mark.parametrize("batch,split", [(4, True), (3, False)],
+                         ids=["split", "batch_stays_whole"])
+def test_flash_on_a_mesh_runs_per_shard_and_says_what_repeats(
+        flash_spy, monkeypatch, batch, split):
+    """Operands laid out on a multi-device mesh: the dispatch layer runs the
+    kernel per shard under shard_map (batch over the batch axes, heads over
+    the TP axis) and the result is exact. A batch the axes do not divide
+    stays whole — every chip along them repeats the work — and on a TPU
+    that is said once with the reason, never silently."""
+    import deepspeed_tpu.utils.logging as ds_logging
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from deepspeed_tpu.parallel.mesh import BATCH_AXES, MESH_AXES, TP_AXIS
+    said = []
+    monkeypatch.setattr(ds_logging, "warning_once", said.append)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(1, 2, 1, 1, 2),
+                MESH_AXES)
+    rng = np.random.default_rng(5)
+    q, k, v = qkv(rng, (batch, 4, 128, 32))
+    ref = mha_reference(q, k, v, causal=True)
+    sh = NamedSharding(mesh, P(BATCH_AXES if split else None, TP_AXIS))
+    qs, ks, vs = (jax.device_put(x, sh) for x in (q, k, v))
+    fn = lambda q, k, v: attention(q, k, v, causal=True, impl="flash")
+    assert "shard_map" in str(jax.make_jaxpr(fn)(qs, ks, vs))
+    out = jax.jit(fn)(qs, ks, vs)
+    assert flash_spy.kernel_calls, "the kernel was not reached on the mesh"
+    # per-shard shapes at the kernel entry: heads always split over "model"
+    assert flash_spy.kernel_calls[-1][0].shape == (
+        batch // 2 if split else batch, 2, 128, 32)
+    np.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-4)
+    if split:
+        assert not said, said
+    else:
+        assert len(set(said)) == 1 and "batch 3 is not divisible by 2" in \
+            said[0] and "'data'" in said[0], said
+
+
 # ---------------------------------------------------------------------------
 # model-level routing: the HF-zoo regimes ride the kernel through Block
 # ---------------------------------------------------------------------------

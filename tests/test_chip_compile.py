@@ -157,22 +157,33 @@ def test_sliding_window_kernel_compiles(chip):
     assert len(names) == 1 and "block_sparse_attention" in names[0], names
 
 
-def test_flash_partitions_over_a_four_chip_mesh(topo, chip, monkeypatch):
+@pytest.mark.parametrize("layout", ["dp4", "dp2_tp2", "dp2_manual_tp2"])
+def test_flash_runs_per_shard_on_a_four_chip_mesh(topo, chip, monkeypatch,
+                                                  layout):
     """A Mosaic kernel cannot be partitioned by the compiler; on a mesh of
-    several chips flash_attention wraps itself in shard_map (PR 21: the
-    ZeRO-3 dp=4 step was refused here, not on the chip)."""
+    several chips the dispatch layer (ops/attention.py) runs it per shard
+    under shard_map (PR 21: the ZeRO-3 dp=4 step was refused here, not on
+    the chip). dp4 is the smoke's --multichip layout; dp2_tp2 composes
+    tensor parallelism (heads over "model"); dp2_manual_tp2 is the
+    comm-plan step's shape — batch axes manual already, "model" still auto."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
-    from deepspeed_tpu.parallel.mesh import MESH_AXES
+    from deepspeed_tpu.ops.attention import attention
+    from deepspeed_tpu.parallel.mesh import BATCH_AXES, MESH_AXES, TP_AXIS
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    mesh = Mesh(np.array(topo.devices).reshape(1, 4, 1, 1, 1), MESH_AXES)
-    x = jax.ShapeDtypeStruct(
-        (8, 16, 1024, 128), jnp.bfloat16,
-        sharding=NamedSharding(mesh, P(("data", "expert"))))
+    dp, tp = (4, 1) if layout == "dp4" else (2, 2)
+    mesh = Mesh(np.array(topo.devices).reshape(1, dp, 1, 1, tp), MESH_AXES)
+    spec = P(BATCH_AXES, TP_AXIS if tp > 1 else None)
+    x = jax.ShapeDtypeStruct((8, 16, 1024, 128), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, spec))
 
     def loss(q, k, v):
-        return flash_attention(q, k, v).astype(jnp.float32).sum()
+        return attention(q, k, v, impl="flash").astype(jnp.float32).sum()
 
-    names = _kernels(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)
+    fn = jax.grad(loss, argnums=(0, 1, 2))
+    if layout == "dp2_manual_tp2":
+        fn = jax.shard_map(fn, mesh=mesh, in_specs=(P(BATCH_AXES),) * 3,
+                           out_specs=(P(BATCH_AXES),) * 3,
+                           axis_names=frozenset(BATCH_AXES), check_vma=False)
+    names = _kernels(fn, x, x, x)
     assert len(names) == 3 and all("shard_map" in n for n in names), names

@@ -251,7 +251,11 @@ def test_paged_int8_gqa_rotary_decode_kernel_vs_reference():
     model, cfg = build_model(
         "llama-1.1b", hidden_size=32, num_layers=2, num_heads=4,
         num_kv_heads=2, mlp_dim_override=64, vocab_size=64, max_seq_len=64,
-        attention_impl="reference", dtype=jnp.float32)
+        dtype=jnp.float32)
+    # attention_impl stays "auto": a "reference" model serves on the gather
+    # oracle whatever ``interpret`` says (serving/model_runner.py), and the
+    # kernel leg must really hold the kernel
+    assert cfg.attention_impl == "auto"
     ids = np.asarray([[3, 1, 4, 1, 5, 9, 2], [6, 5, 3, 5, 8, 9, 7]],
                      np.int32)
     params = ensure_scan_layout(
@@ -261,8 +265,12 @@ def test_paged_int8_gqa_rotary_decode_kernel_vs_reference():
     bt = np.asarray([[1, 2], [3, 4]], np.int32)
     T = ids.shape[1]
     run = lambda interp: _gqa_decode(cfg, params, ids, bt, bs, nbk, interp)
-    logits_k = run(True)
-    logits_r = run(False)
+    logits_k, n_kernels = run(True)
+    logits_r, n_ref_kernels = run(False)
+    # one paged_attention pallas_call in the scanned layer body of the
+    # kernel leg, none in the reference leg — a routing change cannot
+    # quietly turn this into reference-vs-reference
+    assert n_kernels >= 1 and n_ref_kernels == 0, (n_kernels, n_ref_kernels)
     np.testing.assert_allclose(logits_k, logits_r, rtol=2e-5, atol=2e-5)
     assert np.array_equal(logits_k[:, -1].argmax(-1),
                           logits_r[:, -1].argmax(-1))
@@ -280,8 +288,9 @@ def _gqa_decode(cfg, params, ids, bt, bs, nbk, interpret):
                              jnp.full((B,), T, jnp.int32), bs,
                              interpret=interpret)
     nxt = jnp.asarray([[7], [2]], jnp.int32)
-    logits, _ = paged_forward(cfg, params, nxt, pools, jnp.asarray(bt),
-                              jnp.full((B,), T, jnp.int32),
-                              jnp.full((B,), T + 1, jnp.int32), bs,
-                              interpret=interpret)
-    return np.asarray(logits)
+    decode = lambda pools: paged_forward(
+        cfg, params, nxt, pools, jnp.asarray(bt),
+        jnp.full((B,), T, jnp.int32), jnp.full((B,), T + 1, jnp.int32), bs,
+        interpret=interpret)[0]
+    n_kernels = str(jax.make_jaxpr(decode)(pools)).count("pallas_call")
+    return np.asarray(decode(pools)), n_kernels

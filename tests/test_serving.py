@@ -596,7 +596,24 @@ def test_sampling_filters_guard_and_greedy_invariance(tiny):
         assert cache_size() == 1
 
 
-def test_int8_kv_pool_parity_jnp_and_kernel(tiny):
+@pytest.fixture
+def paged_kernel_traces(monkeypatch):
+    """One entry per trace that reaches the Pallas paged kernel (True: with
+    int8 scales) — a "kernel leg" whose list stays empty is comparing the
+    reference with itself."""
+    import deepspeed_tpu.ops.pallas.paged_attention as paged_mod
+    traces = []
+    real = paged_mod.paged_attention
+
+    def spy(*args, **kw):
+        traces.append(kw.get("k_scale") is not None)
+        return real(*args, **kw)
+
+    monkeypatch.setattr(paged_mod, "paged_attention", spy)
+    return traces
+
+
+def test_int8_kv_pool_parity_jnp_and_kernel(tiny, paged_kernel_traces):
     """The quantized pool tier (serving.kv_cache_dtype='int8'):
     quantize-on-write, dequantize IN-kernel (round 17 — the round-12
     construction guard is gone). Greedy outputs match the f32 oracle
@@ -616,6 +633,7 @@ def test_int8_kv_pool_parity_jnp_and_kernel(tiny):
     for p, o in zip(prompts, outs):
         assert o == _oracle_tokens(cfg, params, p, 6), \
             "int8 pool beyond the quantization error bound"
+    assert not paged_kernel_traces, "the jnp leg reached the kernel"
     # the Pallas int8 tier: same pools, dequant in-kernel (the fixture's
     # attention_impl="reference" would serve on the gather oracle)
     eng_k = ServingEngine(dataclasses.replace(cfg, attention_impl="auto"),
@@ -623,10 +641,12 @@ def test_int8_kv_pool_parity_jnp_and_kernel(tiny):
                           serving=dict(SERVE_CFG, kv_cache_dtype="int8"),
                           interpret=True)
     outs_k = eng_k.generate_batch(prompts, max_new_tokens=6)
+    assert paged_kernel_traces and all(paged_kernel_traces), \
+        "the kernel leg never reached the int8 paged kernel"
     assert outs_k == outs, "in-kernel dequant diverged from the jnp path"
 
 
-def test_int8_weight_only_decode_parity(tiny):
+def test_int8_weight_only_decode_parity(tiny, paged_kernel_traces):
     """serving.weight_dtype='int8' (round 17): dense kernels pack ONCE to
     blockwise int8 + per-256-element f32 scales and every decode matmul
     rides the quant path. Greedy outputs are token-equal with the
@@ -652,6 +672,8 @@ def test_int8_weight_only_decode_parity(tiny):
                                        kv_cache_dtype="int8"),
                           interpret=True)
     outs_k = eng_k.generate_batch(prompts, max_new_tokens=6)
+    assert paged_kernel_traces and all(paged_kernel_traces), \
+        "the kernel leg never reached the int8 paged kernel"
     assert outs_k == outs, "quantized kernels diverged from the jnp path"
     with pytest.raises(ValueError):
         ServingEngine(cfg, params,
