@@ -343,7 +343,12 @@ class RunSupervisor:
         try:
             for line in proc.stdout:
                 if STARTED_SENTINEL in line:
-                    st.started = True
+                    # a bool that only ever goes False -> True, read by the
+                    # monitor after this reader saw the sentinel or not at
+                    # all: a stale False only costs one more connect retry.
+                    # (The finding was masked until PR 24 while a second
+                    # class, utils/timer._Timer, also had a `started`.)
+                    st.started = True  # graftlint: disable=TPU018
                     continue
                 prefixed = f"[{host}] {line}"
                 if log is not None:

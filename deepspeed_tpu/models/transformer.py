@@ -668,91 +668,100 @@ class Block(nn.Module):
                                            dtype=cfg.dtype,
                                            param_dtype=jnp.float32, name=name)
 
-        # attention ----------------------------------------------------------
-        kv = cfg.kv_heads
-        if nh % kv != 0:
-            raise ValueError(f"num_heads {nh} not divisible by "
-                             f"num_kv_heads {kv}")
-        h = x if cfg.post_ln else ln("ln1")(x)
-        # one fused qkv matmul even under GQA: [H, (nh + 2*kv) * hd]
-        qkv = dense((nh + 2 * kv) * hd, "attn_qkv", bias=cfg.qkv_bias)(h)
-        q, k, v = jnp.split(qkv, [nh * hd, (nh + kv) * hd], axis=-1)
-        to_heads = lambda t, n: t.reshape(B, S, n, hd).transpose(0, 2, 1, 3)
-        q, k, v = to_heads(q, nh), to_heads(k, kv), to_heads(v, kv)
-        if cfg.qk_norm:
-            # Qwen3: RMSNorm over head_dim on q/k, before rotary (HF
-            # Qwen3Attention.q_norm/k_norm — per-head, scale-only)
-            qk_ln = lambda name: nn.RMSNorm(
-                epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
-                param_dtype=jnp.float32, name=name)
-            q = qk_ln("q_norm")(q)
-            k = qk_ln("k_norm")(k)
-        if cfg.pos_embed == "rotary":
-            pos = positions if positions is not None else jnp.arange(S)
-            inv_freq = cfg.rope_inv_freq(S)     # None = plain-theta table
-            q = apply_rotary(q, pos, cfg.rotary_dim, cfg.rotary_interleaved,
-                             cfg.rope_theta, inv_freq=inv_freq)
-            k = apply_rotary(k, pos, cfg.rotary_dim, cfg.rotary_interleaved,
-                             cfg.rope_theta, inv_freq=inv_freq)
-        if kv != nh:
-            # grouped-query: each k/v head serves nh/kv query heads
-            k = jnp.repeat(k, nh // kv, axis=1)
-            v = jnp.repeat(v, nh // kv, axis=1)
-        bias = None
-        slopes = None
-        if cfg.pos_embed == "alibi":
-            if positions is None:
-                # default arange positions: pass the per-head slopes so the
-                # flash kernel rebuilds the bias from block indices — no
-                # [B, H, S, S] materialization on the kernel path
-                slopes = jnp.asarray(alibi_slopes(nh), jnp.float32)
+        with jax.named_scope("block.attn"):
+            # attention ----------------------------------------------------------
+            kv = cfg.kv_heads
+            if nh % kv != 0:
+                raise ValueError(f"num_heads {nh} not divisible by "
+                                 f"num_kv_heads {kv}")
+            h = x if cfg.post_ln else ln("ln1")(x)
+            # one fused qkv matmul even under GQA: [H, (nh + 2*kv) * hd]
+            qkv = dense((nh + 2 * kv) * hd, "attn_qkv", bias=cfg.qkv_bias)(h)
+            q, k, v = jnp.split(qkv, [nh * hd, (nh + kv) * hd], axis=-1)
+            to_heads = lambda t, n: t.reshape(B, S, n, hd).transpose(0, 2, 1, 3)
+            q, k, v = to_heads(q, nh), to_heads(k, kv), to_heads(v, kv)
+            if cfg.qk_norm:
+                # Qwen3: RMSNorm over head_dim on q/k, before rotary (HF
+                # Qwen3Attention.q_norm/k_norm — per-head, scale-only)
+                qk_ln = lambda name: nn.RMSNorm(
+                    epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
+                    param_dtype=jnp.float32, name=name)
+                q = qk_ln("q_norm")(q)
+                k = qk_ln("k_norm")(k)
+            if cfg.pos_embed == "rotary":
+                pos = positions if positions is not None else jnp.arange(S)
+                inv_freq = cfg.rope_inv_freq(S)     # None = plain-theta table
+                q = apply_rotary(q, pos, cfg.rotary_dim, cfg.rotary_interleaved,
+                                 cfg.rope_theta, inv_freq=inv_freq)
+                k = apply_rotary(k, pos, cfg.rotary_dim, cfg.rotary_interleaved,
+                                 cfg.rope_theta, inv_freq=inv_freq)
+            if kv != nh:
+                # grouped-query: each k/v head serves nh/kv query heads
+                k = jnp.repeat(k, nh // kv, axis=1)
+                v = jnp.repeat(v, nh // kv, axis=1)
+            bias = None
+            slopes = None
+            if cfg.pos_embed == "alibi":
+                if positions is None:
+                    # default arange positions: pass the per-head slopes so the
+                    # flash kernel rebuilds the bias from block indices — no
+                    # [B, H, S, S] materialization on the kernel path
+                    slopes = jnp.asarray(alibi_slopes(nh), jnp.float32)
+                else:
+                    # packed / per-sample position ids: the distance matrix is
+                    # genuinely data-dependent, materialize it
+                    bias = alibi_bias(nh, positions, positions)
+            mask = attn_mask
+            win = 0
+            if window is not None:
+                # local sliding window (GPT-Neo): q attends k in (q-window, q].
+                # attention() routes this to the block-skip sliding-window kernel
+                # on TPU (compute scales with the window); with a user mask or
+                # under tracing where `window` is dynamic, it composes into the
+                # dense mask (exact either way)
+                if isinstance(window, (int, np.integer)):
+                    win = max(int(window), 0)          # <=0 means global
+                else:
+                    q_pos = jnp.arange(S)[:, None]
+                    k_pos = jnp.arange(S)[None, :]
+                    wmask = (q_pos - k_pos < window) | (window <= 0)
+                    mask = (wmask[None, None] if mask is None
+                            else mask & wmask[None, None])
+            drop_rng = (self.make_rng("dropout")
+                        if train and cfg.dropout > 0.0 else None)
+            if cfg.attention_impl == "sparse":
+                out = _sparse_block_attention(
+                    cfg, q, k, v, mask=mask, bias=bias, slopes=slopes,
+                    window=win, sm_scale=cfg.attn_scale,
+                    dropout_rate=cfg.dropout if train else 0.0,
+                    dropout_rng=drop_rng)
             else:
-                # packed / per-sample position ids: the distance matrix is
-                # genuinely data-dependent, materialize it
-                bias = alibi_bias(nh, positions, positions)
-        mask = attn_mask
-        win = 0
-        if window is not None:
-            # local sliding window (GPT-Neo): q attends k in (q-window, q].
-            # attention() routes this to the block-skip sliding-window kernel
-            # on TPU (compute scales with the window); with a user mask or
-            # under tracing where `window` is dynamic, it composes into the
-            # dense mask (exact either way)
-            if isinstance(window, (int, np.integer)):
-                win = max(int(window), 0)          # <=0 means global
-            else:
-                q_pos = jnp.arange(S)[:, None]
-                k_pos = jnp.arange(S)[None, :]
-                wmask = (q_pos - k_pos < window) | (window <= 0)
-                mask = (wmask[None, None] if mask is None
-                        else mask & wmask[None, None])
-        drop_rng = (self.make_rng("dropout")
-                    if train and cfg.dropout > 0.0 else None)
-        if cfg.attention_impl == "sparse":
-            out = _sparse_block_attention(
-                cfg, q, k, v, mask=mask, bias=bias, slopes=slopes,
-                window=win, sm_scale=cfg.attn_scale,
-                dropout_rate=cfg.dropout if train else 0.0,
-                dropout_rng=drop_rng)
-        else:
-            out = attention(q, k, v, causal=cfg.causal, mask=mask, bias=bias,
-                            alibi_slopes=slopes, sm_scale=cfg.attn_scale,
-                            dropout_rate=cfg.dropout if train else 0.0,
-                            dropout_rng=drop_rng, impl=cfg.attention_impl,
-                            window=win, softcap=cfg.attn_softcap)
-        # tag so the "dots" remat policy keeps it: the Pallas kernel output is
-        # not a dot_general, and recomputing flash fwd in bwd costs ~2ms/layer
-        from jax.ad_checkpoint import checkpoint_name
-        out = checkpoint_name(out, "attn_out")
-        # nh*hd == H unless head_dim_override decouples them (Mistral-Nemo)
-        out = out.transpose(0, 2, 1, 3).reshape(B, S, nh * hd)
-        out = dense(H, "attn_proj", bias=cfg.attn_out_bias)(out)
-        if cfg.dropout > 0.0 and train:
-            out = nn.Dropout(cfg.dropout)(out, deterministic=False)
+                out = attention(q, k, v, causal=cfg.causal, mask=mask, bias=bias,
+                                alibi_slopes=slopes, sm_scale=cfg.attn_scale,
+                                dropout_rate=cfg.dropout if train else 0.0,
+                                dropout_rng=drop_rng, impl=cfg.attention_impl,
+                                window=win, softcap=cfg.attn_softcap)
+            # tag so the "dots" remat policy keeps it: the Pallas kernel output is
+            # not a dot_general, and recomputing flash fwd in bwd costs ~2ms/layer
+            from jax.ad_checkpoint import checkpoint_name
+            out = checkpoint_name(out, "attn_out")
+            # nh*hd == H unless head_dim_override decouples them (Mistral-Nemo)
+            out = out.transpose(0, 2, 1, 3).reshape(B, S, nh * hd)
+            out = dense(H, "attn_proj", bias=cfg.attn_out_bias)(out)
+            if cfg.dropout > 0.0 and train:
+                out = nn.Dropout(cfg.dropout)(out, deterministic=False)
 
         aux = jnp.zeros((), jnp.float32)
 
-        def mlp(h):
+        def mlp(h, norm=None):
+            """The MLP branch (``norm``: the pre-norm to apply first), one
+            device scope."""
+            with jax.named_scope("block.mlp"):
+                if norm is not None:
+                    h = ln(norm)(h)
+                return _mlp(h)
+
+        def _mlp(h):
             if cfg.moe_experts > 0:
                 from ..moe.layer import ExpertMLP, GatedExpertMLP, MoE
                 if cfg.gated_mlp:
@@ -788,7 +797,8 @@ class Block(nn.Module):
         if cfg.parallel_residual:
             # GPT-J: one shared LN feeds both branches; GPT-NeoX: a separate
             # ln2 feeds the MLP branch. Single residual add either way.
-            m, aux = mlp(ln("ln2")(x) if cfg.parallel_residual_dual_ln else h)
+            m, aux = (mlp(x, "ln2") if cfg.parallel_residual_dual_ln
+                      else mlp(h))
             if cfg.dropout > 0.0 and train:
                 m = nn.Dropout(cfg.dropout)(m, deterministic=False)
             return _batch_constraint(x + out + m), aux
@@ -805,7 +815,7 @@ class Block(nn.Module):
             # Gemma-2 sandwich: norm each branch OUTPUT before its residual
             out = ln("post_attn_norm")(out)
         x = _batch_constraint(x + out)
-        m, aux = mlp(ln("ln2")(x))
+        m, aux = mlp(x, "ln2")
         if cfg.post_block_norms:
             m = ln("post_mlp_norm")(m)
         if cfg.dropout > 0.0 and train:
@@ -840,28 +850,29 @@ class Transformer(nn.Module):
         user_positions = position_ids
         if position_ids is None:
             position_ids = jnp.arange(S)[None, :]
-        x = wte(input_ids)
-        if cfg.embed_scale is not None:
-            x = x * jnp.asarray(cfg.embed_scale, cfg.dtype)
-        if cfg.pos_embed == "learned":
-            wpe = nn.Embed(cfg.max_seq_len, cfg.hidden_size, dtype=cfg.dtype,
-                           param_dtype=jnp.float32, name="wpe")
-            x = x + wpe(position_ids)
-        if cfg.token_type_vocab > 0:
-            tte = nn.Embed(cfg.token_type_vocab, cfg.hidden_size,
-                           dtype=cfg.dtype, param_dtype=jnp.float32,
-                           name="tte")
-            token_type_ids = (batch.get("token_type_ids")
-                              if isinstance(batch, dict) else None)
-            if token_type_ids is None:
-                token_type_ids = jnp.zeros_like(input_ids)
-            x = x + tte(token_type_ids)
-        if cfg.embed_ln:
-            x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
-                             param_dtype=jnp.float32, name="ln_emb")(x)
-        if cfg.dropout > 0.0 and train:
-            x = nn.Dropout(cfg.dropout)(x, deterministic=False)
-        x = _batch_constraint(x)
+        with jax.named_scope("embed"):
+            x = wte(input_ids)
+            if cfg.embed_scale is not None:
+                x = x * jnp.asarray(cfg.embed_scale, cfg.dtype)
+            if cfg.pos_embed == "learned":
+                wpe = nn.Embed(cfg.max_seq_len, cfg.hidden_size, dtype=cfg.dtype,
+                               param_dtype=jnp.float32, name="wpe")
+                x = x + wpe(position_ids)
+            if cfg.token_type_vocab > 0:
+                tte = nn.Embed(cfg.token_type_vocab, cfg.hidden_size,
+                               dtype=cfg.dtype, param_dtype=jnp.float32,
+                               name="tte")
+                token_type_ids = (batch.get("token_type_ids")
+                                  if isinstance(batch, dict) else None)
+                if token_type_ids is None:
+                    token_type_ids = jnp.zeros_like(input_ids)
+                x = x + tte(token_type_ids)
+            if cfg.embed_ln:
+                x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
+                                 param_dtype=jnp.float32, name="ln_emb")(x)
+            if cfg.dropout > 0.0 and train:
+                x = nn.Dropout(cfg.dropout)(x, deterministic=False)
+            x = _batch_constraint(x)
 
         # padding mask [B, 1, 1, S] broadcast over heads and query positions
         attn_mask = (attention_mask[:, None, None, :].astype(bool)
@@ -943,13 +954,17 @@ class Transformer(nn.Module):
 
                 xs = windows
                 split = {"params": True, "dropout": True, "gating": True}
-            x, auxes = nn.scan(
-                body,
-                variable_axes={"params": 0},
-                split_rngs=split,
-                length=cfg.num_layers,
-                metadata_params={nn.PARTITION_NAME: "layers"},
-            )(block(cfg, name="blocks"), x, xs)
+            # "layers" names what the loop itself costs (slicing the
+            # stacked parameters, stacking residuals and gradients); the
+            # blocks' own scopes lie inside it
+            with jax.named_scope("layers"):
+                x, auxes = nn.scan(
+                    body,
+                    variable_axes={"params": 0},
+                    split_rngs=split,
+                    length=cfg.num_layers,
+                    metadata_params={nn.PARTITION_NAME: "layers"},
+                )(block(cfg, name="blocks"), x, xs)
             aux_total = jnp.sum(auxes)
         else:
             aux_total = jnp.zeros((), jnp.float32)
@@ -990,11 +1005,12 @@ class Transformer(nn.Module):
                                       float(i))
                 aux_total = aux_total + aux
 
-        if not cfg.post_ln:
-            # post-LN stacks (BERT) end already normalized by each block's ln2
-            norm_cls = nn.RMSNorm if cfg.norm == "rmsnorm" else nn.LayerNorm
-            x = norm_cls(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
-                         param_dtype=jnp.float32, name="ln_f")(x)
+        with jax.named_scope("head"):
+            if not cfg.post_ln:
+                # post-LN stacks (BERT) end already normalized by each block's ln2
+                norm_cls = nn.RMSNorm if cfg.norm == "rmsnorm" else nn.LayerNorm
+                x = norm_cls(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
+                             param_dtype=jnp.float32, name="ln_f")(x)
         if cfg.mlm_head:
             # BERT cls.predictions: transform (dense+act+LN) then decoder
             # (tied embedding + output bias)
@@ -1031,22 +1047,24 @@ class Transformer(nn.Module):
             labels = batch.get("labels", input_ids) if isinstance(batch, dict) \
                 else input_ids
             # encoder stacks (BERT bench path) predict in place: no shift
-            loss = _fused_causal_lm_loss(x, emb, labels,
-                                         cfg.loss_chunk,
-                                         shift=1 if cfg.causal else 0)
+            with jax.named_scope("loss"):
+                loss = _fused_causal_lm_loss(x, emb, labels,
+                                             cfg.loss_chunk,
+                                             shift=1 if cfg.causal else 0)
             if cfg.moe_experts > 0:
                 return loss, aux_total
             return loss
-        if cfg.tie_embeddings:
-            logits = wte.attend(x)
-        else:
-            logits = nn.Dense(cfg.vocab_size, use_bias=cfg.lm_head_bias,
-                              dtype=cfg.dtype,
-                              param_dtype=jnp.float32, name="lm_head")(x)
-        logits = logits.astype(jnp.float32)
-        if cfg.final_logit_softcap:
-            from ..ops.attention import apply_softcap
-            logits = apply_softcap(logits, cfg.final_logit_softcap)
+        with jax.named_scope("head"):
+            if cfg.tie_embeddings:
+                logits = wte.attend(x)
+            else:
+                logits = nn.Dense(cfg.vocab_size, use_bias=cfg.lm_head_bias,
+                                  dtype=cfg.dtype,
+                                  param_dtype=jnp.float32, name="lm_head")(x)
+            logits = logits.astype(jnp.float32)
+            if cfg.final_logit_softcap:
+                from ..ops.attention import apply_softcap
+                logits = apply_softcap(logits, cfg.final_logit_softcap)
         if cfg.moe_experts > 0:
             return logits, aux_total
         return logits

@@ -92,9 +92,15 @@ def module_flops_breakdown(fn: Callable, *args, depth: int = 2,
     """
     jaxpr = jax.make_jaxpr(fn)(*args, **kwargs)
 
+    from ..utils.telemetry import SCOPES
+
     def scope(eqn) -> str:
+        # the flax module path only: the program's device scopes
+        # (utils/telemetry.SCOPES: "layers", "block.mlp", ...) ride the
+        # same name stack and are another vocabulary
         names = [getattr(e, "name", str(e))
                  for e in getattr(eqn.source_info.name_stack, "stack", ())]
+        names = [n for n in names if n not in SCOPES]
         return "/".join(names[:depth]) if names else "<toplevel>"
 
     def add(acc, key, val):

@@ -44,7 +44,7 @@ from ..ops.optimizers import Optimizer, build_optimizer
 from ..parallel.mesh import MeshManager, build_mesh_from_config
 from ..utils.logging import log_dist, logger
 from ..utils.partitioning import build_tp_specs
-from ..utils.timer import SynchronizedWallClockTimer, ThroughputTimer
+from ..utils import telemetry
 from ..testing import chaos
 from . import checkpointing as ckpt_lib
 from . import heartbeat as hb
@@ -627,8 +627,11 @@ class DeepSpeedEngine:
 
         # observability ------------------------------------------------------
         self.monitor = MonitorMaster(self.config)
-        self.timers = SynchronizedWallClockTimer()
-        self.tput_timer = ThroughputTimer(batch_size=self.config.train_batch_size)
+        # the one recorder (utils/telemetry.py): train.* spans, counters,
+        # and what wall_clock_breakdown, the monitor and the autotuner read
+        self.rec = telemetry.Recorder("train")
+        self.rec.counters.update({"train.h2d_bytes": 0, "compiles": 0})
+        self._spans_reported_ns = 0
         self.global_steps = 0
         self.micro_steps = 0
 
@@ -894,22 +897,29 @@ class DeepSpeedEngine:
             # custom-vjp slice maps grads back to the shards; overlap:
             # chunked gather inside it so its transpose reduce-scatters
             # the grads in the same chunks
-            p = self._qw_gather_params(p)
-            p = self._overlap_gather_params(p)
+            with jax.named_scope("zero.gather"):
+                p = self._qw_gather_params(p)
+                p = self._overlap_gather_params(p)
             if self.compression_spec is not None:
                 from ..compression import apply_compression
                 p = apply_compression(
                     p, self.compression_spec,
                     step if step is not None else jnp.asarray(0, jnp.int32))
             out = self.apply_fn(p, micro, rng, True)
-            loss = self.loss_fn(out, micro)
+            with jax.named_scope("loss"):
+                loss = self.loss_fn(out, micro)
             return (loss * scale_state.scale).astype(jnp.float32), loss
 
         grads, loss = jax.grad(scaled_loss, has_aux=True)(params)
-        grads = jax.tree.map(lambda g, s: lax.with_sharding_constraint(
-            g.astype(self.grad_accum_dtype), s), grads, self.grad_shardings)
+        # where ZeRO places the gradients' reduce-scatter: the constraint
+        # to their sharded layout
+        with jax.named_scope("zero.scatter"):
+            grads = jax.tree.map(lambda g, s: lax.with_sharding_constraint(
+                g.astype(self.grad_accum_dtype), s), grads,
+                self.grad_shardings)
         return grads, loss
 
+    @jax.named_scope("optimizer")
     def _finalize_step(self, state: TrainState, grads_sum, n_micro, lr_arg,
                        spike_limit=None):
         """Shared tail: unscale, clip, optimize, loss-scale bookkeeping.
@@ -1025,8 +1035,10 @@ class DeepSpeedEngine:
                 micro, r = xs
                 grads, loss = self._grads_of_micro(state.params, state.scale,
                                                    micro, r, state.step)
-                acc = jax.tree.map(lambda a, g, s: lax.with_sharding_constraint(a + g, s),
-                                   acc, grads, self.grad_shardings)
+                with jax.named_scope("grad_accum"):
+                    acc = jax.tree.map(
+                        lambda a, g, s: lax.with_sharding_constraint(a + g, s),
+                        acc, grads, self.grad_shardings)
                 return acc, loss
 
             grads_sum, losses = lax.scan(micro_step, zero_grads, (micros, rngs))
@@ -1472,6 +1484,32 @@ class DeepSpeedEngine:
             raise RuntimeError(
                 "engine has no optimizer: add an 'optimizer' section to the "
                 "config or pass optimizer= to initialize()")
+        rec = self.rec
+        with rec.step_span("train.step", step_num=self.global_steps):
+            with rec.span("train.prepare"):
+                batch = self._prepare_batch(batch)
+            with rec.span("train.h2d"):
+                micros = self._put_micro_batches(batch)
+            with rec.span("train.dispatch"):
+                metrics = self._dispatch_step(micros)
+            # the engine blocks on the loss every step: the watchdog's
+            # liveness rule (_after_step: "a wedged collective never
+            # reaches this line") and zeroone's host mean lean on it
+            with rec.span("train.sync"):
+                jax.block_until_ready(metrics["loss"])
+            with rec.span("train.after_step"):
+                self._after_step(metrics)
+            # counted AFTER remediation: a sentinel rollback preserves the
+            # pipeline position (the poisoned span is never replayed), and
+            # this batch was consumed regardless of its verdict
+            self.data_position += 1
+        self._report_spans()
+        return metrics
+
+    def _prepare_batch(self, batch):
+        """Host work on the batch before it goes to the device: the
+        run-phase failpoints, the first-call COMPILE phase, curriculum and
+        progressive layer drop."""
         # run-phase failpoints (testing/chaos.py; armed via DSTPU_CHAOS in
         # subprocess chaos tests, no-ops otherwise): a crashing, preempted
         # or wedged rank at a step boundary
@@ -1500,13 +1538,18 @@ class DeepSpeedEngine:
             # hang the round-4 step-armed clock could never see
             self._report_phase(hb.PHASE_COMPILE)
             chaos.failpoint("run.compile_hang")
-        from ..parallel.mesh import BATCH_AXES
         if self.curriculum is not None:
             batch = self.curriculum(batch, self.global_steps)
         if self.progressive_layer_drop is not None and isinstance(batch, dict):
             theta = self.progressive_layer_drop.update_state(self.global_steps)
             bsz = len(next(iter(batch.values())))
             batch = dict(batch, pld_theta=np.full((bsz,), theta, np.float32))
+        return batch
+
+    def _put_micro_batches(self, batch):
+        """Split the global batch [gas, micro] on the host and put it on
+        the mesh (counter ``train.h2d_bytes``)."""
+        from ..parallel.mesh import BATCH_AXES
         gas = self.config.gradient_accumulation_steps
         micro_sharding = NamedSharding(self.mesh, P(None, BATCH_AXES))
         micros = jax.tree.map(
@@ -1514,9 +1557,15 @@ class DeepSpeedEngine:
                 jnp.asarray(x).reshape((gas, x.shape[0] // gas) + x.shape[1:]),
                 micro_sharding),
             batch)
-        self.tput_timer.start()
-        if self.config.wall_clock_breakdown:
-            self.timers("train_batch").start()
+        self.rec.count("train.h2d_bytes",
+                       sum(x.nbytes for x in jax.tree.leaves(micros)))
+        return micros
+
+    def _dispatch_step(self, micros) -> Dict[str, Any]:
+        """Enqueue one optimizer step over the micro-batches on whichever
+        path the config picked (1-bit, offload, or the fused program);
+        updates ``self.state`` and returns the step's metrics."""
+        gas = self.config.gradient_accumulation_steps
         if self.onebit is not None:
             if self.lr_fn is not None:
                 lr = float(jax.device_get(self.lr_fn(self.state.step)))
@@ -1579,26 +1628,61 @@ class DeepSpeedEngine:
                 metrics["param_gather_algo"] = \
                     self.comm_plan_ctx.resolved.get("param_all_gather",
                                                     "exact")
-        self.tput_timer.stop(sync=metrics["loss"])
-        if self.config.wall_clock_breakdown:
-            # the jitted step is one program: the breakdown the reference
-            # logs per phase (fwd/bwd/step) collapses into step wall time +
-            # sustained throughput (reference: engine wall_clock_breakdown
-            # timer logs, engine.py:2240). timers.log logs internally; the
-            # normalizer turns the accumulated window into a PER-STEP time
-            self.timers("train_batch").stop(sync=metrics["loss"])
-            if (self.global_steps + 1) % self.config.steps_per_print == 0:
-                self.timers.log(["train_batch"],
-                                normalizer=float(self.config.steps_per_print))
-                log_dist(f"throughput: "
-                         f"{self.tput_timer.avg_samples_per_sec:.1f} "
-                         "samples/sec", ranks=[0])
-        self._after_step(metrics)
-        # counted AFTER remediation: a sentinel rollback preserves the
-        # pipeline position (the poisoned span is never replayed), and
-        # this batch was consumed regardless of its verdict
-        self.data_position += 1
         return metrics
+
+    def samples_per_sec(self, start_step: int = 2) -> float:
+        """Sustained samples/s over the recorder's ring: global batch x
+        steps over the time their ``train.dispatch`` + ``train.sync``
+        spans took (enqueue to loss on the host; the batch's way to the
+        device and the host work after the step are not in it), steps
+        before ``start_step`` left out as warm-up."""
+        busy_ns = step_ns = steps = 0
+        for name, _, start, end, attrs in list(self.rec.ring):
+            if name in ("train.dispatch", "train.sync"):
+                step_ns += end - start
+            elif name == "train.step":
+                if attrs["step_num"] >= start_step:
+                    busy_ns += step_ns
+                    steps += 1
+                step_ns = 0
+        if step_ns and self.global_steps > start_step:
+            # called from inside a step (the autotuner's hook): its wait
+            # is over, its train.step entry not yet written
+            busy_ns += step_ns
+            steps += 1
+        if not busy_ns:
+            return 0.0
+        return steps * self.config.train_batch_size / (busy_ns / 1e9)
+
+    def _report_spans(self) -> None:
+        """Every ``steps_per_print`` steps: the per-step host time of each
+        ``train.*`` span since the last report (self time: a span less
+        its children), logged under ``wall_clock_breakdown`` and written
+        to the monitor where one is enabled."""
+        if self.global_steps % self.config.steps_per_print or not (
+                self.config.wall_clock_breakdown or self.monitor.enabled):
+            return
+        times = self.rec.span_times(since_ns=self._spans_reported_ns)
+        self._spans_reported_ns = time.monotonic_ns()
+        steps = max(times.get("train.step", {}).get("count", 0), 1)
+        per_step = {name: row["self_ms"] / steps
+                    for name, row in sorted(times.items())
+                    if name.startswith("train.")}
+        if self.config.wall_clock_breakdown:
+            log_dist(" | ".join(f"{name}: {ms:.2f}ms"
+                                for name, ms in per_step.items()), ranks=[0])
+            log_dist(f"throughput: {self.samples_per_sec():.1f} samples/sec",
+                     ranks=[0])
+        if self.monitor.enabled:
+            self.monitor.write_events(
+                [(f"Train/Telemetry/{name}_self_ms", ms, self.global_steps)
+                 for name, ms in per_step.items()])
+
+    def telemetry(self) -> Dict[str, Any]:
+        """The recorder's snapshot (utils/telemetry.py): counters, gauges
+        and per-span count / total / self time over the ring.
+        ``engine.rec.dump(path)`` writes the ring itself."""
+        return self.rec.snapshot()
 
     def eval_batch(self, batch):
         batch = self.shard_batch(batch)
@@ -1768,23 +1852,8 @@ class DeepSpeedEngine:
     def _after_step(self, metrics):  # graftlint: hotpath
         self.global_steps += 1
         self._step_phase_reached = True
-        if self.watchdog is not None:
-            # step progress IS the liveness signal (dispatch completed; a
-            # wedged collective never reaches this line). start() is
-            # idempotent — the first completed step arms the clock, and
-            # entering STEP retires the COMPILE deadline.
-            self.watchdog.start().enter_phase(hb.PHASE_STEP,
-                                              step=self.global_steps)
-        if self.heartbeat is not None:
-            # throttled: same-phase records within min_interval are dropped.
-            # The rolling step_ms gauge rides along (None before the first
-            # completed step gap — `dstpu health` shows '-' until then)
-            gauge = self._step_clock.mark()
-            self.heartbeat.write(
-                hb.PHASE_STEP, self.global_steps,
-                extra=({straggler_lib.STEP_MS_GAUGE: gauge}
-                       if gauge is not None else None))
-            self._maybe_check_straggler()
+        with self.rec.span("train.after_step.heartbeat"):
+            self._beat_step()
         if self.lr_scheduler is not None:
             self.lr_scheduler.step()
         self._last_metrics = metrics
@@ -1802,8 +1871,9 @@ class DeepSpeedEngine:
             keys = set(self.sentinel.metric_keys)
             if self._cp_guard is not None:
                 keys.add("grad_norm")
-            host = jax.device_get({k: metrics[k]
-                                   for k in keys if k in metrics})
+            with self.rec.span("train.after_step.pull"):
+                host = jax.device_get({k: metrics[k]
+                                       for k in keys if k in metrics})
             if self._cp_guard is not None and "grad_norm" in host:
                 self._cp_guard.observe(float(host["grad_norm"]))
             # one code path for every "wrong numbers" verdict: the folded
@@ -1811,25 +1881,48 @@ class DeepSpeedEngine:
             # strikes, and the post-rollback abort all live in observe()
             verdict = self.sentinel.observe(self.global_steps, host)
             if print_step:
-                if self.monitor.enabled:
-                    events = [("Train/Samples/train_loss",
-                               float(host["loss"]), self.global_steps),
-                              ("Train/Samples/lr", float(host["lr"]),
-                               self.global_steps)]
-                    if self.loss_scaler.enabled:
-                        events.append(("Train/Samples/loss_scale",
-                                       float(host["loss_scale"]),
-                                       self.global_steps))
-                    self.monitor.write_events(events)
-                log_dist(f"step={self.global_steps} "
-                         f"loss={float(host['loss']):.4f} "
-                         f"lr={float(host['lr']):.3e} "
-                         f"grad_norm={float(host['grad_norm']):.3f}",
-                         ranks=[0])
+                with self.rec.span("train.after_step.monitor"):
+                    self._print_step(host)
             if verdict == sentinel_lib.ROLLBACK:
                 self._sentinel_rollback()
         self._maybe_sdc_audit()
         self._autotuning_hook()
+
+    def _beat_step(self) -> None:
+        if self.watchdog is not None:
+            # step progress IS the liveness signal (dispatch completed; a
+            # wedged collective never reaches this line). start() is
+            # idempotent — the first completed step arms the clock, and
+            # entering STEP retires the COMPILE deadline.
+            self.watchdog.start().enter_phase(hb.PHASE_STEP,
+                                              step=self.global_steps)
+        if self.heartbeat is not None:
+            # throttled: same-phase records within min_interval are dropped.
+            # The rolling step_ms gauge rides along (None before the first
+            # completed step gap — `dstpu health` shows '-' until then)
+            gauge = self._step_clock.mark()
+            self.heartbeat.write(
+                hb.PHASE_STEP, self.global_steps,
+                extra=({straggler_lib.STEP_MS_GAUGE: gauge}
+                       if gauge is not None else None))
+            self._maybe_check_straggler()
+
+    def _print_step(self, host) -> None:
+        if self.monitor.enabled:
+            events = [("Train/Samples/train_loss",
+                       float(host["loss"]), self.global_steps),
+                      ("Train/Samples/lr", float(host["lr"]),
+                       self.global_steps)]
+            if self.loss_scaler.enabled:
+                events.append(("Train/Samples/loss_scale",
+                               float(host["loss_scale"]),
+                               self.global_steps))
+            self.monitor.write_events(events)
+        log_dist(f"step={self.global_steps} "
+                 f"loss={float(host['loss']):.4f} "
+                 f"lr={float(host['lr']):.3e} "
+                 f"grad_norm={float(host['grad_norm']):.3f}",
+                 ranks=[0])
 
     def _autotuning_hook(self):
         """Script-mode autotuning (reference: engine autotuning exit after
@@ -1844,7 +1937,7 @@ class DeepSpeedEngine:
             return
         import json
         import sys
-        tput = self.tput_timer.avg_samples_per_sec
+        tput = self.samples_per_sec()
         metrics = {"throughput": float(tput) if tput else 0.0,
                    "train_batch_size": self.config.train_batch_size,
                    "steps": self.global_steps}

@@ -169,13 +169,19 @@ class PipelineEngine(DeepSpeedEngine):
                  else data_iter_or_batch)
         if self.optimizer is None:
             raise RuntimeError("PipelineEngine needs an optimizer")
-        batch = self.shard_batch(batch)
-        self.tput_timer.start()
-        self.state, metrics = self._train_step(self.state, batch,
-                                               self.next_rng(),
-                                               self._current_lr())
-        self.tput_timer.stop(sync=metrics["loss"])
-        self._after_step(metrics)
+        rec = self.rec
+        with rec.step_span("train.step", step_num=self.global_steps):
+            with rec.span("train.h2d"):
+                batch = self.shard_batch(batch)
+            with rec.span("train.dispatch"):
+                self.state, metrics = self._train_step(
+                    self.state, batch, self.next_rng(), self._current_lr())
+            # the per-step wait on the loss, as in DeepSpeedEngine
+            with rec.span("train.sync"):
+                jax.block_until_ready(metrics["loss"])
+            with rec.span("train.after_step"):
+                self._after_step(metrics)
+        self._report_spans()
         return metrics
 
     def eval_batch(self, data_iter_or_batch):

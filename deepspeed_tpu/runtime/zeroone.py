@@ -491,7 +491,7 @@ class ZeroOneRunner:
             # shards (an on-device mean would be a collective in the
             # otherwise collective-free program). The host read adds no new
             # pipeline bubble: the engine blocks on the loss every step
-            # anyway (tput_timer.stop(sync=loss)).
+            # anyway (train_batch's train.sync span).
             new_p, new_s, loss_st, norm_st, overflow, nss = out
             loss = jnp.asarray(self._host_mean(loss_st), jnp.float32)
             norm = jnp.asarray(self._host_mean(norm_st), jnp.float32)

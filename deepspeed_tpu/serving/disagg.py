@@ -250,7 +250,7 @@ class PrefillEngine(ServingEngine):
         return out
 
     def step(self) -> int:
-        with self._lock:
+        with self._lock, self._step_span():
             self._flush_ready()           # a backpressured item first
             done = self._admit()
             done += self._advance_prefill()
@@ -369,7 +369,7 @@ class DecodeEngine(ServingEngine):
             self._holding = None
 
     def step(self) -> int:
-        with self._lock:
+        with self._lock, self._step_span():
             if self._auto_pull:
                 self.handoff.shed_expired()
                 self._pull_handoff()

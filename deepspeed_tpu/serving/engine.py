@@ -42,6 +42,7 @@ from ..models.generation import ensure_scan_layout
 from ..models.transformer import TransformerConfig
 from ..runtime.heartbeat import PHASE_SERVE
 from ..testing import chaos
+from ..utils import telemetry
 from ..utils.logging import log_dist, logger
 from .kv_cache import (NULL_BLOCK, BlockPoolExhausted, SharedPagedState)
 from .model_runner import paged_forward
@@ -50,6 +51,19 @@ from .scheduler import (BATCH, FAILED, FINISHED, PREFILL, PRIORITY_TIERS,
                         Request, Scheduler)
 
 PyTree = Any
+
+#: the engine's counters; ``ServingEngine.stats`` IS its recorder's dict
+#: (utils/telemetry.py), which the paged state counts into as well
+#: (``kv.alloc`` ..., ``prefix.*``: serving/kv_cache.py). The ``*_sum``
+#: counters add one reading a step, taken on entry to ``step()``.
+_COUNTERS = (
+    "completed", "failed", "timeout", "tokens_generated", "prefill_tokens",
+    "prefix_hit_tokens", "preempted",
+    "steps", "steps_with_queue", "queue_len_sum", "lane_sum",
+    "admit_blocked.no_lane", "admit_blocked.no_blocks",
+    "admit_blocked.prefilling", "compiles",
+    "kv.held_blocks_sum", "kv.blocks_reserved_sum", "kv.tokens_written_sum",
+    "prefix.prompt_tokens")
 
 _KV_DTYPES = {"bf16": jnp.bfloat16, "bfloat16": jnp.bfloat16,
               "f32": jnp.float32, "float32": jnp.float32,
@@ -190,13 +204,16 @@ class ServingEngine:
         # disaggregated pair (serving/disagg.py) passes one in — block
         # IDs then mean the same pool slots to both roles, which is what
         # makes the prefill->decode handoff zero-copy
+        self.rec = telemetry.Recorder("serve")
+        self.stats: Dict[str, int] = self.rec.counters
+        self.stats.update(dict.fromkeys(_COUNTERS, 0))
         self._shared = shared if shared is not None else SharedPagedState(
-            cfg, serving, dtype=kv_dtype)
+            cfg, serving, dtype=kv_dtype, counters=self.stats)
         self.scheduler = Scheduler(self.pool, serving.max_queue,
                                    self.max_model_len, self.prefix_cache,
                                    aging_s=serving.fleet.priority_aging_s,
                                    batch_highwater=serving.fleet
-                                   .batch_highwater)
+                                   .batch_highwater, rec=self.rec)
         self._slots: List[Optional[_Seq]] = [None] * self.max_batch
         self._prefilling: Optional[_Prefilling] = None
         self._warming = False      # role warms: no prefix-cache inserts
@@ -208,10 +225,6 @@ class ServingEngine:
         self._watchdog = None
         self._lock = threading.Lock()
         self.steps = 0                     # decode steps executed
-        self.stats: Dict[str, int] = {
-            "completed": 0, "failed": 0, "timeout": 0,
-            "tokens_generated": 0, "prefill_tokens": 0,
-            "prefix_hit_tokens": 0, "preempted": 0}
 
         # ---- compiled programs (fixed shapes; ONE decode specialization) ----
         use_filters = self._use_filters
@@ -223,12 +236,13 @@ class ServingEngine:
             construction-time constant: the program is still compiled
             once) the vectorized per-lane top-k/top-p filter runs on the
             scaled logits first."""
-            greedy = jnp.argmax(logits, axis=-1)
-            scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
-            if use_filters:
-                scaled = lane_topk_topp(scaled, tks, tps)
-            sampled = jax.random.categorical(r, scaled, axis=-1)
-            return jnp.where(temps <= 0.0, greedy, sampled)
+            with jax.named_scope("sample"):
+                greedy = jnp.argmax(logits, axis=-1)
+                scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
+                if use_filters:
+                    scaled = lane_topk_topp(scaled, tks, tps)
+                sampled = jax.random.categorical(r, scaled, axis=-1)
+                return jnp.where(temps <= 0.0, greedy, sampled)
 
         def _decode(params, pools, toks, bt, ctx, r, temps, tks, tps):
             # toks [B] sit at logical position ctx[b]; after the write the
@@ -319,7 +333,8 @@ class ServingEngine:
                       priority=priority)
         if deadline_s is not None:
             req.deadline_ts = req.arrival_ts + float(deadline_s)
-        return self.scheduler.submit(req)
+        with self.rec.span("serve.submit", rid=req.rid):
+            return self.scheduler.submit(req)
 
     # -------------------------------------------------------------- the loop
 
@@ -418,7 +433,7 @@ class ServingEngine:
         ``serving.prefill_chunk_tokens > 0`` running lanes emit a token
         every iteration even while a long prompt prefills (the fairness
         bound tests pin). Returns requests completed this iteration."""
-        with self._lock:
+        with self._lock, self._step_span():
             done = self._admit()
             done += self._advance_prefill()
             if self.active:
@@ -427,6 +442,45 @@ class ServingEngine:
             self.stats["timeout"] = self.scheduler.timed_out
             self._stamp_heartbeat()
             return done
+
+    def _step_span(self):
+        """The span of one ``step()`` (caller holds the engine lock), and
+        the step's readings of queue, lanes and KV reservation, taken on
+        entry: what an outside observer would count before calling
+        ``step()``."""
+        span = self.rec.step_span("serve.step", step=self.steps)
+        c = self.stats
+        c["steps"] += 1
+        queued = self.scheduler.pending
+        if queued:
+            c["steps_with_queue"] += 1
+            c["queue_len_sum"] += queued
+        holders = [s for s in self._slots if s is not None]
+        c["lane_sum"] += len(holders)
+        written = sum(s.ctx for s in holders)
+        if self._prefilling is not None:
+            holders.append(self._prefilling)
+            written += self._prefilling.done
+        # distinct blocks: a forked prefix is held once however many
+        # lanes read it; the reservation counts it for each holder, as
+        # ``ctx`` counts its tokens for each
+        held = len(set().union(*(h.blocks for h in holders)))
+        c["kv.held_blocks_sum"] += held
+        c["kv.blocks_reserved_sum"] += sum(len(h.blocks) for h in holders)
+        c["kv.tokens_written_sum"] += written
+        if held > self.rec.gauges.get("kv.held_blocks_peak", 0):
+            self.rec.gauge("kv.held_blocks_peak", held)
+        return span
+
+    def telemetry(self) -> Dict[str, Any]:
+        """The recorder's snapshot: counters (``stats`` and, with a shared
+        paged state, that state's ``kv.*`` / ``prefix.*``), gauges, and
+        per-span count / total / self time over the ring.
+        ``srv.rec.dump(path)`` writes the ring itself."""
+        snap = self.rec.snapshot()
+        if self.pool.counters is not self.stats:
+            snap["counters"] = {**self.pool.counters, **snap["counters"]}
+        return snap
 
     def run_until_idle(self, max_steps: int = 100_000) -> None:
         """Drive the loop until queue and lanes drain (tests, batch use)."""
@@ -448,7 +502,7 @@ class ServingEngine:
             while not stop.is_set():
                 if self.idle:
                     with self._lock:
-                        self._stamp_heartbeat()
+                        self._beat()      # no span: idling is not recorded
                     stop.wait(idle_wait)
                     continue
                 self.step()
@@ -496,6 +550,10 @@ class ServingEngine:
                 pass
 
     def _stamp_heartbeat(self) -> None:
+        with self.rec.span("serve.heartbeat"):
+            self._beat()
+
+    def _beat(self) -> None:
         if self._watchdog is not None:
             self._watchdog.beat(self.steps)
         if self._heartbeat is not None:
@@ -534,34 +592,82 @@ class ServingEngine:
         prefill (allocates the lifetime blocks); the chunks themselves
         run one per loop iteration in :meth:`_advance_prefill`, so at
         most ONE request is admitted per iteration and decode is never
-        blocked behind a whole long prompt."""
-        self.scheduler.shed_expired()
-        done = 0
-        if self._chunked_mode():
-            if self._prefilling is None and self._admission_capacity():
-                req = self.scheduler.next_admission()
-                if req is not None:
-                    try:
-                        self._prefilling = self._start_prefill(req)
-                    except (BlockPoolExhausted, chaos.ChaosError) as e:
-                        logger.warning("serving: admission of request %d "
-                                       "deferred (%s)", req.rid, e)
-                        self.scheduler.requeue_front(req)
+        blocked behind a whole long prompt. A pass that admits nobody
+        while requests wait counts its one cause
+        (``admit_blocked.prefilling`` / ``.no_lane`` / ``.no_blocks``)."""
+        with self.rec.span("serve.admit"):
+            with self.rec.span("serve.shed"):
+                self.scheduler.shed_expired()
+            stopped_by, admitted, done = self._admit_pass()
+            if not admitted and self.scheduler.pending:
+                self.stats["admit_blocked." + stopped_by] += 1
             return done
+
+    def _admit_pass(self):
+        """(what stopped the pass, requests admitted, requests finished)."""
+        admitted = done = 0
+        if self._chunked_mode():
+            if self._prefilling is not None:
+                return "prefilling", admitted, done
+            if not self._admission_capacity():
+                return "no_lane", admitted, done
+            req = self.scheduler.next_admission()
+            if req is None:
+                return "no_blocks", admitted, done
+            try:
+                self._prefilling = self._start_prefill(req)
+            except (BlockPoolExhausted, chaos.ChaosError) as e:
+                logger.warning("serving: admission of request %d "
+                               "deferred (%s)", req.rid, e)
+                self.scheduler.requeue_front(req)
+                return "no_blocks", admitted, done
+            return "prefilling", 1, done
         while self._free_slot() is not None:
             req = self.scheduler.next_admission()
             if req is None:
-                return done
+                return "no_blocks", admitted, done
             try:
                 done += self._prefill_request(req)
+                admitted += 1
             except (BlockPoolExhausted, chaos.ChaosError) as e:
                 # transient (chaos 'serve.oom' or a racing allocation):
                 # the request goes back to the HEAD — queued, not crashed
                 logger.warning("serving: admission of request %d deferred "
                                "(%s)", req.rid, e)
                 self.scheduler.requeue_front(req)
-                return done
-        return done
+                return "no_blocks", admitted, done
+        return "no_lane", admitted, done
+
+    def _reserve(self, req: Request):
+        """Admission's reservation: fork what the prefix cache holds of
+        the prompt, allocate the rest of the request's LIFETIME blocks,
+        and stamp the end of its queue wait. Returns (prefix tokens,
+        blocks, block table)."""
+        with self.rec.span("serve.admit.alloc", rid=req.rid):
+            P = len(req.prompt)
+            req.state = PREFILL
+            n_pref, forked = (self.prefix_cache.match(req.prompt)
+                              if self.prefix_cache is not None else (0, []))
+            try:
+                total_blocks = self.pool.blocks_for_tokens(
+                    P + max(req.max_new_tokens - 1, 0))
+                priv = self.pool.alloc(total_blocks - len(forked))
+            except BaseException:
+                if forked:
+                    self.pool.release(forked)
+                req.state = QUEUED
+                raise
+            blocks = list(forked) + priv
+            table = np.full((self.nbk,), NULL_BLOCK, np.int32)
+            table[:len(blocks)] = blocks
+            req.prefix_hit_tokens = n_pref
+            req.prefill_progress = n_pref
+            self.stats["prefix_hit_tokens"] += n_pref
+            self.stats["prefix.prompt_tokens"] += P
+            req.admitted_ts = time.monotonic()
+            self.rec.event("serve.req.admitted", rid=req.rid,
+                           arrival_ts=req.arrival_ts, ts=req.admitted_ts)
+            return n_pref, blocks, table
 
     def _chunked_mode(self) -> bool:
         return self._chunk > 0
@@ -569,26 +675,9 @@ class ServingEngine:
     def _start_prefill(self, req: Request) -> _Prefilling:
         """Allocate a request's LIFETIME blocks (admission control is
         identical to whole prefill) and stage it for chunked prefill."""
-        P = len(req.prompt)
-        req.state = PREFILL
-        n_pref, forked = (self.prefix_cache.match(req.prompt)
-                          if self.prefix_cache is not None else (0, []))
-        try:
-            total_blocks = self.pool.blocks_for_tokens(
-                P + max(req.max_new_tokens - 1, 0))
-            priv = self.pool.alloc(total_blocks - len(forked))
-        except BaseException:
-            if forked:
-                self.pool.release(forked)
-            req.state = QUEUED
-            raise
-        blocks = list(forked) + priv
-        table = np.full((self.nbk,), NULL_BLOCK, np.int32)
-        table[:len(blocks)] = blocks
-        req.prefix_hit_tokens = n_pref
-        req.prefill_progress = n_pref
-        self.stats["prefix_hit_tokens"] += n_pref
-        return _Prefilling(req, blocks, table, done=n_pref, total=P)
+        n_pref, blocks, table = self._reserve(req)
+        return _Prefilling(req, blocks, table, done=n_pref,
+                           total=len(req.prompt))
 
     def _advance_prefill(self) -> int:
         """Run AT MOST one chunk of the in-flight chunked prefill (the
@@ -602,21 +691,28 @@ class ServingEngine:
         req = pf.req
         n = (pf.total - pf.done if self._chunk <= 0
              else min(self._chunk, pf.total - pf.done))
-        chunk_toks = req.prompt[pf.done:pf.done + n]
-        Tb = -(-n // self.block_size) * self.block_size
-        ids = np.zeros((1, Tb), np.int32)
-        ids[0, :n] = chunk_toks
-        self._rng, r = jax.random.split(self._rng)
+        with self.rec.span("serve.prefill", rid=req.rid, tokens=n,
+                           final=int(pf.done + n >= pf.total)):
+            return self._prefill_chunk(pf, n)
+
+    def _prefill_chunk(self, pf: _Prefilling, n: int) -> int:
+        req, rec = pf.req, self.rec
+        with rec.span("serve.prefill.build"):
+            chunk_toks = req.prompt[pf.done:pf.done + n]
+            Tb = -(-n // self.block_size) * self.block_size
+            ids = np.zeros((1, Tb), np.int32)
+            ids[0, :n] = chunk_toks
+            self._rng, r = jax.random.split(self._rng)
+            args = (jnp.asarray(ids), jnp.asarray(pf.table[None]),
+                    jnp.asarray([pf.done], jnp.int32),
+                    jnp.asarray([pf.done + n], jnp.int32),
+                    jnp.asarray(n - 1, jnp.int32), r,
+                    jnp.asarray([req.temperature], jnp.float32),
+                    *self._filter_args(req))
         try:
             chaos.failpoint("serve.chunk")
-            tok = self._run_device(
-                self._prefill_fn, jnp.asarray(ids),
-                jnp.asarray(pf.table[None]),
-                jnp.asarray([pf.done], jnp.int32),
-                jnp.asarray([pf.done + n], jnp.int32),
-                jnp.asarray(n - 1, jnp.int32), r,
-                jnp.asarray([req.temperature], jnp.float32),
-                *self._filter_args(req))
+            with rec.span("serve.prefill.dispatch"):
+                tok = self._run_device(self._prefill_fn, *args)
         except BaseException as e:
             # a failed chunk must not leak the lifetime allocation —
             # release EVERYTHING (partial K/V is recomputed on retry; the
@@ -641,22 +737,37 @@ class ServingEngine:
             #                               call is discarded — only the
             #                               final chunk's is real
         self._prefilling = None
-        first = int(np.asarray(tok)[0])
+        with rec.span("serve.prefill.fetch"):
+            first = int(np.asarray(tok)[0])
+        return self._first_token(_Seq(req, pf.blocks, pf.table, pf.total,
+                                      first),
+                                 insert=not self._warming)
+
+    def _first_token(self, seq: _Seq, insert: bool = True) -> int:
+        """A prompt's last chunk is in the pool and its first token
+        fetched: stamp it, register the prompt's full blocks with the
+        prefix cache, and install the sequence (or finish a one-token
+        request). Returns requests finished."""
+        req, rec = seq.req, self.rec
         req.first_token_ts = time.monotonic()
-        req.output_tokens.append(first)
+        rec.event("serve.req.first_token", rid=req.rid,
+                  ts=req.first_token_ts)
+        req.output_tokens.append(seq.last_tok)
         self.stats["tokens_generated"] += 1
-        if self.prefix_cache is not None and not self._warming:
+        if self.prefix_cache is not None and insert:
             # a warm's dummy prompt must not fork blocks into the
             # (possibly SHARED) prefix cache on every launch/restart
-            self.prefix_cache.insert(req.prompt,
-                                     pf.blocks[:pf.total // self.block_size])
-        seq = _Seq(req, pf.blocks, pf.table, pf.total, first)
-        if req.max_new_tokens <= 1 or (req.eos_token_id is not None
-                                       and first == req.eos_token_id):
-            self._finish(seq)
-            return 1
-        self._install(seq)
-        return 0
+            with rec.span("serve.prefill.prefix_insert"):
+                self.prefix_cache.insert(
+                    req.prompt, seq.blocks[:seq.ctx // self.block_size])
+        with rec.span("serve.prefill.install"):
+            if req.max_new_tokens <= 1 or (req.eos_token_id is not None
+                                           and seq.last_tok
+                                           == req.eos_token_id):
+                self._finish(seq)
+                return 1
+            self._install(seq)
+            return 0
 
     def _install(self, seq: _Seq) -> None:
         """Place a fully-prefilled sequence where decode will find it —
@@ -674,41 +785,30 @@ class ServingEngine:
         return jnp.asarray(tks), jnp.asarray(tps)
 
     def _prefill_request(self, req: Request) -> int:
-        P = len(req.prompt)
-        req.state = PREFILL
-        n_pref, forked = (self.prefix_cache.match(req.prompt)
-                          if self.prefix_cache is not None else (0, []))
-        try:
-            total_blocks = self.pool.blocks_for_tokens(
-                P + max(req.max_new_tokens - 1, 0))
-            priv = self.pool.alloc(total_blocks - len(forked))
-        except BaseException:
-            if forked:
-                self.pool.release(forked)
-            req.state = QUEUED
-            raise
-        blocks = list(forked) + priv
-        table = np.full((self.nbk,), NULL_BLOCK, np.int32)
-        table[:len(blocks)] = blocks
-        req.prefix_hit_tokens = n_pref
-        req.prefill_progress = n_pref
-        self.stats["prefix_hit_tokens"] += n_pref
+        n_pref, blocks, table = self._reserve(req)
+        with self.rec.span("serve.prefill", rid=req.rid,
+                           tokens=len(req.prompt) - n_pref, final=1):
+            return self._prefill_whole(req, n_pref, blocks, table)
 
+    def _prefill_whole(self, req: Request, n_pref: int, blocks, table) -> int:
+        P, rec = len(req.prompt), self.rec
         # prefill the suffix, bucket-padded to a block multiple so the
         # compile count is bounded by max_blocks_per_seq
-        suffix = req.prompt[n_pref:]
-        Tb = -(-len(suffix) // self.block_size) * self.block_size
-        ids = np.zeros((1, Tb), np.int32)
-        ids[0, :len(suffix)] = suffix
-        self._rng, r = jax.random.split(self._rng)
+        with rec.span("serve.prefill.build"):
+            suffix = req.prompt[n_pref:]
+            Tb = -(-len(suffix) // self.block_size) * self.block_size
+            ids = np.zeros((1, Tb), np.int32)
+            ids[0, :len(suffix)] = suffix
+            self._rng, r = jax.random.split(self._rng)
+            args = (jnp.asarray(ids), jnp.asarray(table[None]),
+                    jnp.asarray([n_pref], jnp.int32),
+                    jnp.asarray([P], jnp.int32),
+                    jnp.asarray(len(suffix) - 1, jnp.int32), r,
+                    jnp.asarray([req.temperature], jnp.float32),
+                    *self._filter_args(req))
         try:
-            tok = self._run_device(
-                self._prefill_fn, jnp.asarray(ids),
-                jnp.asarray(table[None]), jnp.asarray([n_pref], jnp.int32),
-                jnp.asarray([P], jnp.int32),
-                jnp.asarray(len(suffix) - 1, jnp.int32), r,
-                jnp.asarray([req.temperature], jnp.float32),
-                *self._filter_args(req))
+            with rec.span("serve.prefill.dispatch"):
+                tok = self._run_device(self._prefill_fn, *args)
         except BaseException as e:
             # a failed forward (device OOM, interrupt) must not leak the
             # refcounted blocks — capacity survives the exception. A
@@ -723,65 +823,65 @@ class ServingEngine:
             else:
                 req.state = QUEUED
             raise
-        first = int(np.asarray(tok)[0])
-        req.first_token_ts = time.monotonic()
-        req.output_tokens.append(first)
+        with rec.span("serve.prefill.fetch"):
+            first = int(np.asarray(tok)[0])
         req.prefill_progress = P
-        self.stats["tokens_generated"] += 1
         self.stats["prefill_tokens"] += len(suffix)
-        if self.prefix_cache is not None:
-            self.prefix_cache.insert(req.prompt, blocks[:P // self.block_size])
-        if req.max_new_tokens <= 1 or (req.eos_token_id is not None
-                                       and first == req.eos_token_id):
-            self._finish(_Seq(req, blocks, table, P, first))
-            return 1
-        req.state = RUNNING
-        self._slots[self._free_slot()] = _Seq(req, blocks, table, P, first)
-        return 0
+        return self._first_token(_Seq(req, blocks, table, P, first))
 
     # ---------------------------------------------------------------- decode
 
     def _decode_step(self) -> int:
-        B = self.max_batch
-        toks = np.zeros((B,), np.int32)
-        ctx = np.zeros((B,), np.int32)
-        temps = np.zeros((B,), np.float32)
-        tks = np.zeros((B,), np.int32)
-        tps = np.ones((B,), np.float32)
-        tables = np.full((B, self.nbk), NULL_BLOCK, np.int32)
-        for i, s in enumerate(self._slots):
-            if s is None:
-                continue
-            toks[i] = s.last_tok
-            ctx[i] = s.ctx
-            temps[i] = s.req.temperature
-            tks[i] = s.req.top_k or 0
-            tps[i] = s.req.top_p if s.req.top_p is not None else 1.0
-            tables[i] = s.table
-        self._rng, r = jax.random.split(self._rng)
-        nxt = self._run_device(
-            self._decode_fn, jnp.asarray(toks), jnp.asarray(tables),
-            jnp.asarray(ctx), r, jnp.asarray(temps), jnp.asarray(tks),
-            jnp.asarray(tps))
-        nxt = np.asarray(nxt)
+        with self.rec.span("serve.decode", lanes=self.active):
+            return self._decode_lanes()
+
+    def _decode_lanes(self) -> int:
+        B, rec = self.max_batch, self.rec
+        with rec.span("serve.decode.build"):
+            toks = np.zeros((B,), np.int32)
+            ctx = np.zeros((B,), np.int32)
+            temps = np.zeros((B,), np.float32)
+            tks = np.zeros((B,), np.int32)
+            tps = np.ones((B,), np.float32)
+            tables = np.full((B, self.nbk), NULL_BLOCK, np.int32)
+            for i, s in enumerate(self._slots):
+                if s is None:
+                    continue
+                toks[i] = s.last_tok
+                ctx[i] = s.ctx
+                temps[i] = s.req.temperature
+                tks[i] = s.req.top_k or 0
+                tps[i] = s.req.top_p if s.req.top_p is not None else 1.0
+                tables[i] = s.table
+            self._rng, r = jax.random.split(self._rng)
+            args = (jnp.asarray(toks), jnp.asarray(tables),
+                    jnp.asarray(ctx), r, jnp.asarray(temps),
+                    jnp.asarray(tks), jnp.asarray(tps))
+        with rec.span("serve.decode.dispatch"):
+            nxt = self._run_device(self._decode_fn, *args)
+        with rec.span("serve.decode.fetch"):
+            nxt = np.asarray(nxt)
         done = 0
-        for i, s in enumerate(self._slots):
-            if s is None:
-                continue
-            s.ctx += 1
-            tok = int(nxt[i])
-            s.req.output_tokens.append(tok)
-            s.last_tok = tok
-            self.stats["tokens_generated"] += 1
-            eos = (s.req.eos_token_id is not None
-                   and tok == s.req.eos_token_id)
-            if eos or len(s.req.output_tokens) >= s.req.max_new_tokens:
-                self._slots[i] = None
-                self._finish(s)
-                done += 1
+        with rec.span("serve.decode.bookkeep"):
+            for i, s in enumerate(self._slots):
+                if s is None:
+                    continue
+                s.ctx += 1
+                tok = int(nxt[i])
+                s.req.output_tokens.append(tok)
+                s.last_tok = tok
+                self.stats["tokens_generated"] += 1
+                eos = (s.req.eos_token_id is not None
+                       and tok == s.req.eos_token_id)
+                if eos or len(s.req.output_tokens) >= s.req.max_new_tokens:
+                    self._slots[i] = None
+                    self._finish(s)
+                    done += 1
         return done
 
     def _finish(self, seq: _Seq) -> None:
         self.pool.release(seq.blocks)
         self.stats["completed"] += 1
         seq.req._finish(FINISHED)
+        self.rec.event("serve.req.finished", rid=seq.req.rid,
+                       ts=seq.req.finish_ts)

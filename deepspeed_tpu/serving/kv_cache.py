@@ -88,7 +88,8 @@ class BlockPool:
     surfaces as :class:`BlockPoolExhausted`, which every admission path
     already treats as keep-queued."""
 
-    def __init__(self, num_blocks: int, block_size: int):
+    def __init__(self, num_blocks: int, block_size: int,
+                 counters: Optional[Dict[str, int]] = None):
         if num_blocks < 2:
             raise ValueError("pool needs >= 2 blocks (block 0 is the null "
                              "block)")
@@ -97,6 +98,12 @@ class BlockPool:
         self._free: List[int] = list(range(num_blocks - 1, NULL_BLOCK, -1))
         self._refs: Dict[int, int] = {}
         self._mu = threading.Lock()
+        #: where the pool counts what it decides (the serving engine hands
+        #: in its recorder's counters): blocks handed out, blocks that
+        #: came back to the free list, allocations refused
+        self.counters = {} if counters is None else counters
+        for key in ("kv.alloc", "kv.release", "kv.exhausted"):
+            self.counters.setdefault(key, 0)
 
     @property
     def free_count(self) -> int:
@@ -117,6 +124,7 @@ class BlockPool:
         chaos.failpoint("serve.oom")
         with self._mu:
             if n > len(self._free):
+                self.counters["kv.exhausted"] += 1
                 raise BlockPoolExhausted(
                     f"need {n} blocks, {len(self._free)} free "
                     f"(pool {self.num_blocks - 1} x {self.block_size} "
@@ -124,6 +132,7 @@ class BlockPool:
             out = [self._free.pop() for _ in range(n)]
             for b in out:
                 self._refs[b] = 1
+            self.counters["kv.alloc"] += n
             return out
 
     def fork(self, blocks: Sequence[int]) -> List[int]:
@@ -151,6 +160,7 @@ class BlockPool:
                 else:
                     del self._refs[b]
                     self._free.append(b)
+                    self.counters["kv.release"] += 1
 
     def refcount(self, block: int) -> int:
         return self._refs.get(block, 0)
@@ -183,6 +193,13 @@ class PrefixCache:
 
     def __init__(self, pool: BlockPool):
         self.pool = pool
+        #: the pool's counters: lookups that took blocks, entries made,
+        #: entries evicted, and the entries each eviction's scan read
+        self.counters = pool.counters
+        for key in ("prefix.lookups", "prefix.inserted_entries",
+                    "prefix.evicted_entries",
+                    "prefix.evict_scanned_entries"):
+            self.counters.setdefault(key, 0)
         # key -> (tokens ref, n_blocks, blocks, last_used)
         self._entries: Dict[str, Tuple[Tuple[int, ...], int, List[int],
                                        int]] = {}
@@ -230,6 +247,7 @@ class PrefixCache:
         Returns ``(n_cached_tokens, forked_blocks)`` — the blocks already
         carry the caller's refcount."""
         with self._mu:
+            self.counters["prefix.lookups"] += 1
             n, key, blocks = self._lookup(tokens)
             if key is None:
                 return 0, []
@@ -260,6 +278,7 @@ class PrefixCache:
                     continue
                 held = self.pool.fork(list(blocks[:k]))
                 self._entries[key] = (shared, k, held, self._clock)
+                self.counters["prefix.inserted_entries"] += 1
 
     def evict(self, need_blocks: int,
               protect: Optional[str] = None) -> int:
@@ -276,10 +295,13 @@ class PrefixCache:
                 victims = [k for k in self._entries if k != protect]
                 if not victims:
                     break
+                self.counters["prefix.evict_scanned_entries"] += \
+                    len(victims)
                 key = min(victims, key=lambda k: self._entries[k][3])
                 _, _, blocks, _ = self._entries.pop(key)
                 self.pool.release(blocks)
                 evicted += 1
+            self.counters["prefix.evicted_entries"] += evicted
         return evicted
 
     def clear(self) -> None:
@@ -301,8 +323,10 @@ class SharedPagedState:
     pools back under the lock. A single-threaded engine pays one
     uncontended acquire per step."""
 
-    def __init__(self, cfg, serving, dtype=None):
-        self.pool = BlockPool(serving.pool_blocks, serving.block_size)
+    def __init__(self, cfg, serving, dtype=None,
+                 counters: Optional[Dict[str, int]] = None):
+        self.pool = BlockPool(serving.pool_blocks, serving.block_size,
+                              counters=counters)
         self.pools: Dict[str, Any] = init_pool(
             cfg, serving.pool_blocks, serving.block_size, dtype=dtype)
         self.prefix_cache = (PrefixCache(self.pool)

@@ -129,17 +129,18 @@ def paged_forward(cfg: TransformerConfig,
     # (kernel_qscale) route through the Pallas quant matmul
     dense = partial(_dense, interpret=interpret)
 
-    wte = params["wte"]["embedding"]
-    x = wte.astype(cfg.dtype)[input_ids]
-    if cfg.embed_scale is not None:
-        x = x * jnp.asarray(cfg.embed_scale, x.dtype)
+    with jax.named_scope("embed"):
+        wte = params["wte"]["embedding"]
+        x = wte.astype(cfg.dtype)[input_ids]
+        if cfg.embed_scale is not None:
+            x = x * jnp.asarray(cfg.embed_scale, x.dtype)
 
-    pos = q_start[:, None] + jnp.arange(T)[None, :]        # [B, T] logical
-    if cfg.pos_embed == "learned":
-        wpe = params["wpe"]["embedding"].astype(cfg.dtype)
-        x = x + wpe[jnp.minimum(pos, wpe.shape[0] - 1)]
-    if cfg.embed_ln:
-        x = _layer_norm(x, params["ln_emb"], cfg.layer_norm_eps, rms)
+        pos = q_start[:, None] + jnp.arange(T)[None, :]        # [B, T] logical
+        if cfg.pos_embed == "learned":
+            wpe = params["wpe"]["embedding"].astype(cfg.dtype)
+            x = x + wpe[jnp.minimum(pos, wpe.shape[0] - 1)]
+        if cfg.embed_ln:
+            x = _layer_norm(x, params["ln_emb"], cfg.layer_norm_eps, rms)
 
     slopes = (jnp.asarray(alibi_slopes(nh), jnp.float32)
               if cfg.pos_embed == "alibi" else None)
@@ -161,65 +162,81 @@ def paged_forward(cfg: TransformerConfig,
         x, kv = carry
         k_pool, v_pool = kv["k"], kv["v"]
         p, window, li = xs
-        h = _layer_norm(x, p["ln1"], cfg.layer_norm_eps, rms)
-        qkv = dense(h, p["attn_qkv"])
-        q, k, v = jnp.split(qkv, [nh * hd, (nh + kvh) * hd], axis=-1)
-        to_heads = lambda t, n: t.reshape(B, T, n, hd).transpose(0, 2, 1, 3)
-        q, k, v = to_heads(q, nh), to_heads(k, kvh), to_heads(v, kvh)
-        if cfg.qk_norm:
-            q = _layer_norm(q, p["q_norm"], cfg.layer_norm_eps, rms=True)
-            k = _layer_norm(k, p["k_norm"], cfg.layer_norm_eps, rms=True)
-        if cfg.pos_embed == "rotary":
-            # table covers the pool's per-sequence maximum (nbk * bs) —
-            # plain-theta tables are length-independent, so this matches
-            # generate()'s cache-capacity table exactly
-            inv_freq = cfg.rope_inv_freq(nbk * bs)
-            q = apply_rotary(q, pos, cfg.rotary_dim, cfg.rotary_interleaved,
-                             cfg.rope_theta, inv_freq=inv_freq)
-            k = apply_rotary(k, pos, cfg.rotary_dim, cfg.rotary_interleaved,
-                             cfg.rope_theta, inv_freq=inv_freq)
-        if kvh != nh:
-            # GQA: repeat kv to full heads before the pool write (the pool
-            # stays [*, nh, ...] so the paged kernel applies unchanged)
-            k = jnp.repeat(k, nh // kvh, axis=1)
-            v = jnp.repeat(v, nh // kvh, axis=1)
-        # ONE scatter per layer: [B, nh, T, hd] -> [B*T, nh, hd] rows into
-        # flat slots (padded lanes hit the null block)
-        k_rows = k.transpose(0, 2, 1, 3).reshape(B * T, nh, hd)
-        v_rows = v.transpose(0, 2, 1, 3).reshape(B * T, nh, hd)
-        kv_new = dict(kv)
-        if quant_kv:
-            # quantize-on-write: THE dense path's per-channel format
-            # (same helper — axis=-1 math is rank-agnostic over rows)
-            (kq, ks), (vq, vs) = _kv_quantize(k_rows), _kv_quantize(v_rows)
-            k_pool = k_pool.at[li, :, flat_slots].set(kq)
-            v_pool = v_pool.at[li, :, flat_slots].set(vq)
-            kv_new["k_scale"] = kv["k_scale"].at[li, :, flat_slots].set(ks)
-            kv_new["v_scale"] = kv["v_scale"].at[li, :, flat_slots].set(vs)
-        else:
-            k_pool = k_pool.at[li, :, flat_slots].set(
-                k_rows.astype(k_pool.dtype))
-            v_pool = v_pool.at[li, :, flat_slots].set(
-                v_rows.astype(v_pool.dtype))
-        kv_new["k"], kv_new["v"] = k_pool, v_pool
-        # attention through the block table (kernel on TPU decode, exact
-        # jnp gather elsewhere); the int8 tier passes the pool AS int8
-        # with its scales — dequant happens in-kernel / post-gather,
-        # O(attended blocks), never a pool-slice copy
-        kp5 = k_pool.reshape(L, nh, nb_pool, bs, hd)
-        vp5 = v_pool.reshape(L, nh, nb_pool, bs, hd)
-        scale_kw = (dict(k_scale=kv_new["k_scale"],
-                         v_scale=kv_new["v_scale"]) if quant_kv else {})
-        o = paged_attention(q, kp5, vp5, bt, ctx, sm_scale=sm_scale,
-                            alibi_slopes=slopes,
-                            softcap=cfg.attn_softcap, window=window,
-                            layer_idx=li, q_start=q_start, impl=attn_impl,
-                            interpret=interpret, **scale_kw)
-        o = o.transpose(0, 2, 1, 3).reshape(B, T, nh * hd)
-        attn_out = dense(o, p["attn_proj"])
-        if cfg.post_block_norms:
-            attn_out = _layer_norm(attn_out, p["post_attn_norm"],
-                                   cfg.layer_norm_eps, rms)
+        with jax.named_scope("block.attn"):
+            with jax.named_scope("qkv"):
+                h = _layer_norm(x, p["ln1"], cfg.layer_norm_eps, rms)
+                qkv = dense(h, p["attn_qkv"])
+                q, k, v = jnp.split(qkv, [nh * hd, (nh + kvh) * hd],
+                                    axis=-1)
+                to_heads = lambda t, n: t.reshape(B, T, n, hd).transpose(
+                    0, 2, 1, 3)
+                q, k, v = to_heads(q, nh), to_heads(k, kvh), to_heads(v, kvh)
+                if cfg.qk_norm:
+                    q = _layer_norm(q, p["q_norm"], cfg.layer_norm_eps,
+                                    rms=True)
+                    k = _layer_norm(k, p["k_norm"], cfg.layer_norm_eps,
+                                    rms=True)
+                if cfg.pos_embed == "rotary":
+                    # table covers the pool's per-sequence maximum (nbk *
+                    # bs) — plain-theta tables are length-independent, so
+                    # this matches generate()'s cache-capacity table exactly
+                    inv_freq = cfg.rope_inv_freq(nbk * bs)
+                    rot = partial(apply_rotary, positions=pos,
+                                  rotary_dim=cfg.rotary_dim,
+                                  interleaved=cfg.rotary_interleaved,
+                                  theta=cfg.rope_theta, inv_freq=inv_freq)
+                    q, k = rot(q), rot(k)
+            with jax.named_scope("kv_write"):
+                if kvh != nh:
+                    # GQA: repeat kv to full heads before the pool write (the
+                    # pool stays [*, nh, ...] so the paged kernel applies
+                    # unchanged)
+                    k = jnp.repeat(k, nh // kvh, axis=1)
+                    v = jnp.repeat(v, nh // kvh, axis=1)
+                # ONE scatter per layer: [B, nh, T, hd] -> [B*T, nh, hd] rows
+                # into flat slots (padded lanes hit the null block)
+                k_rows = k.transpose(0, 2, 1, 3).reshape(B * T, nh, hd)
+                v_rows = v.transpose(0, 2, 1, 3).reshape(B * T, nh, hd)
+                kv_new = dict(kv)
+                if quant_kv:
+                    # quantize-on-write: THE dense path's per-channel format
+                    # (same helper — axis=-1 math is rank-agnostic over rows)
+                    (kq, ks), (vq, vs) = (_kv_quantize(k_rows),
+                                          _kv_quantize(v_rows))
+                    k_pool = k_pool.at[li, :, flat_slots].set(kq)
+                    v_pool = v_pool.at[li, :, flat_slots].set(vq)
+                    kv_new["k_scale"] = kv["k_scale"].at[
+                        li, :, flat_slots].set(ks)
+                    kv_new["v_scale"] = kv["v_scale"].at[
+                        li, :, flat_slots].set(vs)
+                else:
+                    k_pool = k_pool.at[li, :, flat_slots].set(
+                        k_rows.astype(k_pool.dtype))
+                    v_pool = v_pool.at[li, :, flat_slots].set(
+                        v_rows.astype(v_pool.dtype))
+                kv_new["k"], kv_new["v"] = k_pool, v_pool
+            with jax.named_scope("attend"):
+                # attention through the block table (kernel on TPU decode,
+                # exact jnp gather elsewhere); the int8 tier passes the pool
+                # AS int8 with its scales — dequant happens in-kernel /
+                # post-gather, O(attended blocks), never a pool-slice copy
+                kp5 = k_pool.reshape(L, nh, nb_pool, bs, hd)
+                vp5 = v_pool.reshape(L, nh, nb_pool, bs, hd)
+                scale_kw = (dict(k_scale=kv_new["k_scale"],
+                                 v_scale=kv_new["v_scale"])
+                            if quant_kv else {})
+                o = paged_attention(q, kp5, vp5, bt, ctx, sm_scale=sm_scale,
+                                    alibi_slopes=slopes,
+                                    softcap=cfg.attn_softcap, window=window,
+                                    layer_idx=li, q_start=q_start,
+                                    impl=attn_impl, interpret=interpret,
+                                    **scale_kw)
+            with jax.named_scope("out"):
+                o = o.transpose(0, 2, 1, 3).reshape(B, T, nh * hd)
+                attn_out = dense(o, p["attn_proj"])
+                if cfg.post_block_norms:
+                    attn_out = _layer_norm(attn_out, p["post_attn_norm"],
+                                           cfg.layer_norm_eps, rms)
 
         def mlp(hin):
             if cfg.moe_experts > 0:
@@ -229,28 +246,31 @@ def paged_forward(cfg: TransformerConfig,
                 return dense(g * dense(hin, p["mlp_fc"]), p["mlp_proj"])
             return dense(act(dense(hin, p["mlp_fc"])), p["mlp_proj"])
 
-        if cfg.parallel_residual:
-            m_in = (_layer_norm(x, p["ln2"], cfg.layer_norm_eps, rms)
-                    if cfg.parallel_residual_dual_ln else h)
-            x_out = x + attn_out + mlp(m_in)
-        else:
-            x_mid = x + attn_out
-            h2 = _layer_norm(x_mid, p["ln2"], cfg.layer_norm_eps, rms)
-            m = mlp(h2)
-            if cfg.post_block_norms:
-                m = _layer_norm(m, p["post_mlp_norm"],
-                                cfg.layer_norm_eps, rms)
-            x_out = x_mid + m
+        with jax.named_scope("block.mlp"):
+            if cfg.parallel_residual:
+                m_in = (_layer_norm(x, p["ln2"], cfg.layer_norm_eps, rms)
+                        if cfg.parallel_residual_dual_ln else h)
+                x_out = x + attn_out + mlp(m_in)
+            else:
+                x_mid = x + attn_out
+                h2 = _layer_norm(x_mid, p["ln2"], cfg.layer_norm_eps, rms)
+                m = mlp(h2)
+                if cfg.post_block_norms:
+                    m = _layer_norm(m, p["post_mlp_norm"],
+                                    cfg.layer_norm_eps, rms)
+                x_out = x_mid + m
         return (x_out, kv_new), None
 
     xs = (params["blocks"], windows, jnp.arange(cfg.num_layers))
-    (x, kv_out), _ = jax.lax.scan(layer, (x, dict(pools)), xs)
-    x = _layer_norm(x, params["ln_f"], cfg.layer_norm_eps, rms)
-    if cfg.tie_embeddings:
-        logits = jnp.einsum("bth,vh->btv", x, wte.astype(x.dtype))
-    else:
-        logits = dense(x, params["lm_head"])
-    if cfg.final_logit_softcap:
-        from ..ops.attention import apply_softcap
-        logits = apply_softcap(logits, cfg.final_logit_softcap)
+    with jax.named_scope("layers"):
+        (x, kv_out), _ = jax.lax.scan(layer, (x, dict(pools)), xs)
+    with jax.named_scope("head"):
+        x = _layer_norm(x, params["ln_f"], cfg.layer_norm_eps, rms)
+        if cfg.tie_embeddings:
+            logits = jnp.einsum("bth,vh->btv", x, wte.astype(x.dtype))
+        else:
+            logits = dense(x, params["lm_head"])
+        if cfg.final_logit_softcap:
+            from ..ops.attention import apply_softcap
+            logits = apply_softcap(logits, cfg.final_logit_softcap)
     return logits.astype(jnp.float32), kv_out

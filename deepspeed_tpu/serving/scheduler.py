@@ -58,6 +58,7 @@ from dataclasses import dataclass, field
 from typing import Callable, Dict, Iterator, List, Optional
 
 from ..testing import chaos
+from ..utils import telemetry
 from ..utils.logging import logger
 from .kv_cache import BlockPool, PrefixCache
 
@@ -152,6 +153,9 @@ class Request:
     #: observability; the retry recomputes from its own prefix hits)
     prefill_progress: int = 0
     arrival_ts: float = field(default_factory=time.monotonic)
+    #: the moment admission reserved the request's lifetime blocks: the
+    #: end of its queue wait (arrival -> admitted -> first token -> finish)
+    admitted_ts: Optional[float] = None
     first_token_ts: Optional[float] = None
     finish_ts: Optional[float] = None
     error: Optional[str] = None
@@ -322,8 +326,12 @@ class Scheduler:
     def __init__(self, pool: BlockPool, max_queue: int = 4096,
                  max_model_len: Optional[int] = None,
                  prefix_cache: Optional[PrefixCache] = None,
-                 aging_s: float = 30.0, batch_highwater: float = 1.0):
+                 aging_s: float = 30.0, batch_highwater: float = 1.0,
+                 rec: Optional[telemetry.Recorder] = None):
         self.pool = pool
+        #: the engine's recorder (a scheduler on its own keeps a private one)
+        self.rec = rec if rec is not None else telemetry.Recorder(
+            "scheduler", keep=False)
         self.prefix_cache = prefix_cache
         self.max_queue = int(max_queue)
         self.max_model_len = max_model_len
@@ -402,14 +410,17 @@ class Scheduler:
             head = self._queue.peeknext()
             if head is None:
                 return None
-            hit_tokens, hit_key = ((0, None) if self.prefix_cache is None
-                                   else self.prefix_cache.peek(head.prompt))
+            with self.rec.span("serve.admit.peek"):
+                hit_tokens, hit_key = (
+                    (0, None) if self.prefix_cache is None
+                    else self.prefix_cache.peek(head.prompt))
             # budget NET of the prefix hit, and the make-room eviction
             # protects the hit's entry — the head's own reusable prefix
             # must never be the victim of admitting the head
             need = self.blocks_needed(head, prefix_tokens=hit_tokens)
             if need > self.pool.free_count and self.prefix_cache is not None:
-                self.prefix_cache.evict(need, protect=hit_key)
+                with self.rec.span("serve.admit.evict"):
+                    self.prefix_cache.evict(need, protect=hit_key)
             if need > self.pool.free_count:
                 return None
             self._queue.remove(head)

@@ -1,0 +1,497 @@
+"""The program's one recorder (utils/telemetry.py) and what the two engines
+record through it: span nesting and self time, the ring's bound, the
+``serve.*`` / ``train.*`` spans and counters, the request stamps, the device
+scopes (metadata only: the optimized HLO keeps its instructions) and
+``scope_paths``.
+"""
+
+import contextlib
+import json
+import logging
+import os
+import re
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import deepspeed_tpu
+from deepspeed_tpu.models import build_model, fused_loss_passthrough
+from deepspeed_tpu.runtime.engine import DeepSpeedEngine
+from deepspeed_tpu.serving.engine import ServingEngine
+from deepspeed_tpu.utils import telemetry
+from tests.util import SimpleModel, random_batch
+
+MS = 1_000_000
+
+SERVE_SPANS = {
+    "serve.submit", "serve.step", "serve.admit", "serve.shed",
+    "serve.admit.peek", "serve.admit.evict", "serve.admit.alloc",
+    "serve.prefill", "serve.prefill.build", "serve.prefill.dispatch",
+    "serve.prefill.fetch", "serve.prefill.prefix_insert",
+    "serve.prefill.install", "serve.decode", "serve.decode.build",
+    "serve.decode.dispatch", "serve.decode.fetch", "serve.decode.bookkeep",
+    "serve.heartbeat", "serve.req.admitted", "serve.req.first_token",
+    "serve.req.finished"}
+TRAIN_SPANS = {
+    "train.step", "train.prepare", "train.h2d", "train.dispatch",
+    "train.sync", "train.after_step", "train.after_step.heartbeat",
+    "train.after_step.pull", "train.after_step.monitor"}
+
+
+# ------------------------------------------------------------- the recorder
+
+
+def test_self_time_is_a_span_less_its_children_on_a_hand_made_ring():
+    # step 0..100 ms holds admit 10..30 (which holds alloc 12..20) and
+    # decode 40..90 (which holds fetch 50..85)
+    ring = [("serve.admit.alloc", "serve.admit", 12 * MS, 20 * MS, {}),
+            ("serve.admit", "serve.step", 10 * MS, 30 * MS, {}),
+            ("serve.decode.fetch", "serve.decode", 50 * MS, 85 * MS, {}),
+            ("serve.decode", "serve.step", 40 * MS, 90 * MS, {}),
+            ("serve.step", None, 0, 100 * MS, {"step": 0})]
+    t = telemetry.span_times(ring)
+    assert {k: v["self_ms"] for k, v in t.items()} == pytest.approx({
+        "serve.admit.alloc": 8, "serve.admit": 12, "serve.decode.fetch": 35,
+        "serve.decode": 15, "serve.step": 30})
+    assert sum(v["self_ms"] for v in t.values()) == pytest.approx(100)
+    assert t["serve.step"]["total_ms"] == pytest.approx(100)
+    # a later start cuts the earlier entries out
+    late = telemetry.span_times(ring, since_ns=35 * MS)
+    assert set(late) == {"serve.decode", "serve.decode.fetch"}
+
+
+def test_nesting_gives_the_parent_and_a_step_carries_its_counter_gains():
+    rec = telemetry.Recorder("t", keep=False)
+    rec.count("a", 5)
+    with rec.step_span("s", step=7):
+        with rec.span("s.x", k=1):
+            rec.count("a", 2)
+            rec.event("s.e", rid=3)
+        rec.count("b")
+    by = {e[0]: e for e in rec.ring}
+    assert by["s.x"][1] == "s" and by["s.e"][1] == "s.x"
+    assert by["s"][1] is None
+    assert by["s"][4] == {"step": 7, "d": {"a": 2, "b": 1}}
+    assert by["s.x"][4] == {"k": 1} and by["s.e"][4] == {"rid": 3}
+    assert by["s.e"][3] - by["s.e"][2] < MS          # zero length, nearly
+    assert by["s"][2] <= by["s.x"][2] <= by["s.x"][3] <= by["s"][3]
+    snap = rec.snapshot()
+    assert snap["counters"] == {"a": 7, "b": 1}
+    assert snap["spans"]["s"]["count"] == 1
+    assert snap["spans"]["s"]["self_ms"] <= snap["spans"]["s"]["total_ms"]
+
+
+def test_ring_is_bounded_and_counts_what_it_drops(tmp_path):
+    rec = telemetry.Recorder("t", ring_size=4, keep=False)
+    for i in range(10):
+        rec.event("e", i=i)
+    assert len(rec.ring) == 4 and rec.dropped == 6
+    assert [e[4]["i"] for e in rec.ring] == [6, 7, 8, 9]
+    assert rec.snapshot()["ring_dropped"] == 6
+    rows = [json.loads(l) for l in open(rec.dump(str(tmp_path / "r.jsonl")))]
+    assert [r["attrs"]["i"] for r in rows] == [6, 7, 8, 9]
+    assert set(rows[0]) == {"name", "parent", "start_ns", "end_ns", "attrs"}
+
+
+def test_the_module_keeps_the_last_few_recorders_only():
+    made = [telemetry.Recorder(f"r{i}") for i in range(telemetry.KEPT + 2)]
+    assert telemetry.recent() == made[-telemetry.KEPT:]
+
+
+def test_a_compile_inside_a_span_is_counted_with_the_span_it_fell_in():
+    rec = telemetry.Recorder("t")
+    f = jax.jit(lambda x: x * 3 + 1)
+    with rec.step_span("s", step=0):
+        with rec.span("s.work"):
+            f(jnp.ones((3,))).block_until_ready()
+    with rec.step_span("s", step=1):
+        f(jnp.ones((3,))).block_until_ready()        # cached: no compile
+    assert rec.counters["compiles"] >= 1
+    compiles = [e for e in rec.ring if e[0] == "compile"]
+    assert compiles and all(e[1] == "s.work" for e in compiles)
+    steps = [e for e in rec.ring if e[0] == "s"]
+    assert steps[0][4]["d"]["compiles"] == rec.counters["compiles"]
+    assert "compiles" not in steps[1][4]["d"]
+
+
+def test_spans_appear_in_a_profiler_trace_under_the_ds_prefix(tmp_path):
+    from jax.profiler import ProfileData
+    rec = telemetry.Recorder("t", keep=False)
+    with jax.profiler.trace(str(tmp_path)):
+        with rec.step_span("train.step", step_num=4):
+            with rec.span("train.sync", why="x"):
+                jnp.ones((8,)).block_until_ready()
+    pb = [os.path.join(d, f) for d, _, fs in os.walk(tmp_path) for f in fs
+          if f.endswith(".xplane.pb")]
+    events = {}
+    for plane in ProfileData.from_file(pb[0]).planes:
+        if plane.name.startswith("/host:CPU"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith(telemetry.PREFIX):
+                        events[e.name] = dict(e.stats)
+    assert set(events) == {"ds/train.step", "ds/train.sync"}
+    assert int(events["ds/train.step"]["step_num"]) == 4
+    assert events["ds/train.sync"]["why"] == "x"
+
+
+# ------------------------------------------------------------------ serving
+
+
+@pytest.fixture(scope="module")
+def tiny_lm():
+    model, cfg = build_model(
+        "gpt2-tiny", hidden_size=32, num_layers=2, num_heads=2,
+        vocab_size=64, max_seq_len=256, dtype=jnp.float32)
+    params = model.init(jax.random.PRNGKey(0),
+                        {"input_ids": np.zeros((1, 8), np.int32)})["params"]
+    return cfg, params
+
+
+def held_from_outside(srv) -> int:
+    """Distinct blocks of the decode lanes and the prompt in prefill: what
+    the benchmark's driver counts before each ``srv.step()``."""
+    held = [s.blocks for s in srv._slots if s is not None]
+    if srv._prefilling is not None:
+        held.append(srv._prefilling.blocks)
+    return len(set().union(*held))
+
+
+@pytest.fixture(scope="module")
+def served(tiny_lm):
+    """A tiny engine (chunked prefill, a pool small enough to block
+    admissions and to evict prefix entries) run to idle; what an outside
+    observer counted before every step."""
+    cfg, params = tiny_lm
+    srv = ServingEngine(cfg, params, interpret=True, serving={
+        "block_size": 16, "pool_blocks": 24, "max_batch": 3,
+        "max_blocks_per_seq": 8, "prefill_chunk_tokens": 32})
+    rng = np.random.default_rng(0)
+    sizes = [(40, 12), (70, 20), (20, 5), (50, 30), (33, 8), (90, 10),
+             (17, 4), (64, 9)]
+    reqs = [srv.submit(list(rng.integers(1, 64, size=n)), max_new_tokens=k)
+            for n, k in sizes]
+    outside = []
+    while not srv.idle:
+        outside.append({"held": held_from_outside(srv), "lanes": srv.active,
+                        "queue": srv.scheduler.pending})
+        srv.step()
+    return srv, reqs, outside
+
+
+def test_a_served_run_yields_every_serve_span(served):
+    srv, reqs, outside = served
+    names = {e[0] for e in srv.rec.ring}
+    assert SERVE_SPANS <= names
+    assert {n for n in names if n.startswith("serve.")} == SERVE_SPANS
+    steps = [e for e in srv.rec.ring if e[0] == "serve.step"]
+    assert [e[4]["step"] for e in steps] == list(range(len(outside)))
+    parents = {e[0]: e[1] for e in srv.rec.ring}
+    assert parents["serve.admit"] == "serve.step"
+    assert parents["serve.admit.peek"] == "serve.admit"
+    assert parents["serve.admit.alloc"] == "serve.admit"
+    assert parents["serve.decode.fetch"] == "serve.decode"
+    assert parents["serve.prefill.prefix_insert"] == "serve.prefill"
+    assert parents["serve.req.first_token"] == "serve.prefill"
+    assert parents["serve.submit"] is None
+    snap = srv.telemetry()
+    assert snap["counters"] == srv.stats
+    assert snap["spans"]["serve.step"]["count"] == len(outside)
+    assert snap["ring_dropped"] == 0
+
+
+def test_inside_and_outside_count_the_same_thing_step_for_step(served):
+    srv, reqs, outside = served
+    steps = [e for e in srv.rec.ring if e[0] == "serve.step"]
+    assert len(steps) == len(outside) == srv.stats["steps"]
+    for entry, seen in zip(steps, outside):
+        d = entry[4]["d"]
+        assert d.get("kv.held_blocks_sum", 0) == seen["held"]
+        assert d.get("lane_sum", 0) == seen["lanes"]
+        assert d.get("queue_len_sum", 0) == seen["queue"]
+        assert d.get("steps_with_queue", 0) == (seen["queue"] > 0)
+    assert srv.stats["kv.held_blocks_sum"] == sum(o["held"] for o in outside)
+    assert srv.rec.gauges["kv.held_blocks_peak"] == \
+        max(o["held"] for o in outside)
+    assert srv.stats["lane_sum"] == sum(o["lanes"] for o in outside)
+    # a reservation is never smaller than what was written into it
+    assert 0 < srv.stats["kv.tokens_written_sum"] <= \
+        srv.stats["kv.blocks_reserved_sum"] * srv.block_size
+    # the pool's own ledger: what went out came back, but for what the
+    # prefix cache still holds
+    assert srv.stats["kv.alloc"] - srv.stats["kv.release"] == \
+        srv.pool.used_count
+    assert srv.stats["prefix.prompt_tokens"] == \
+        sum(len(r.prompt) for r in reqs)
+    assert srv.stats["prefix.lookups"] == len(reqs)
+    assert srv.stats["prefix.inserted_entries"] >= len(reqs)
+    assert srv.stats["prefix.evicted_entries"] >= 1
+    assert srv.stats["prefix.evict_scanned_entries"] >= \
+        srv.stats["prefix.evicted_entries"]
+
+
+def test_a_blocked_step_counts_exactly_one_cause(served):
+    srv, reqs, outside = served
+    causes = ("admit_blocked.no_lane", "admit_blocked.no_blocks",
+              "admit_blocked.prefilling")
+    blocked = 0
+    for entry in (e for e in srv.rec.ring if e[0] == "serve.step"):
+        d = entry[4]["d"]
+        n = sum(d.get(c, 0) for c in causes)
+        assert n in (0, 1)
+        if n:
+            assert d.get("steps_with_queue", 0) == 1
+            assert "prefix.prompt_tokens" not in d      # nobody admitted
+        blocked += n
+    assert blocked == sum(srv.stats[c] for c in causes)
+    assert 0 < blocked <= srv.stats["steps_with_queue"]
+    # this pool and these lanes block for more than one reason
+    assert sum(1 for c in causes if srv.stats[c]) >= 2
+
+
+def test_a_request_has_four_stamps_in_order(served):
+    srv, reqs, outside = served
+    for r in reqs:
+        assert r.arrival_ts <= r.admitted_ts <= r.first_token_ts \
+            <= r.finish_ts
+    events = {(e[0], e[4]["rid"]) for e in srv.rec.ring
+              if e[0].startswith("serve.req.")}
+    for r in reqs:
+        for what in ("admitted", "first_token", "finished"):
+            assert (f"serve.req.{what}", r.rid) in events
+
+
+# ----------------------------------------------------------------- training
+
+
+def lm_engine(extra=None):
+    model, cfg = build_model(
+        "gpt2-tiny", hidden_size=32, num_layers=2, num_heads=2,
+        vocab_size=64, max_seq_len=64, remat=True, remat_policy="dots",
+        fused_loss=True, attention_impl="reference")
+    n = len(jax.devices())
+    config = {"train_batch_size": 2 * n,
+              "train_micro_batch_size_per_gpu": 1,
+              "gradient_accumulation_steps": 2,
+              "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
+              "zero_optimization": {"stage": 3,
+                                    "stage3_param_persistence_threshold": 0},
+              "bf16": {"enabled": True}, **(extra or {})}
+    engine, *_ = deepspeed_tpu.initialize(
+        model=model, config=config, loss_fn=fused_loss_passthrough,
+        example_batch={"input_ids": np.zeros((2, 32), np.int32)})
+    return engine
+
+
+def lm_batch(engine, seed):
+    rows = engine.config.train_batch_size
+    return {"input_ids": np.random.RandomState(seed).randint(
+        0, 64, (rows, 32)).astype(np.int32)}
+
+
+def test_three_train_batches_yield_the_train_spans():
+    engine = lm_engine({"steps_per_print": 1})
+    for i in range(3):
+        engine.train_batch(lm_batch(engine, i))
+    ring = list(engine.rec.ring)
+    names = {e[0] for e in ring if e[0].startswith("train.")}
+    assert names == TRAIN_SPANS
+    steps = [e for e in ring if e[0] == "train.step"]
+    assert [e[4]["step_num"] for e in steps] == [0, 1, 2]
+    parents = {e[0]: e[1] for e in ring}
+    assert parents["train.sync"] == "train.step"
+    assert parents["train.after_step.pull"] == "train.after_step"
+    # the first step compiled inside its dispatch, the others did not
+    assert steps[0][4]["d"]["compiles"] >= 1
+    assert "compiles" not in steps[2][4]["d"]
+    h2d = lm_batch(engine, 0)["input_ids"].nbytes
+    assert [e[4]["d"]["train.h2d_bytes"] for e in steps] == [h2d] * 3
+    snap = engine.telemetry()
+    assert snap["counters"]["train.h2d_bytes"] == 3 * h2d
+    assert snap["spans"]["train.sync"]["count"] == 3
+    assert engine.samples_per_sec() > 0            # step 2 is past warm-up
+
+
+def test_wall_clock_breakdown_logs_parts_and_the_monitor_gets_them(tmp_path):
+    class Capture(logging.Handler):
+        def __init__(self):
+            super().__init__()
+            self.lines = []
+
+        def emit(self, record):
+            self.lines.append(record.getMessage())
+
+    from deepspeed_tpu.utils.logging import logger
+    cap = Capture()
+    logger.addHandler(cap)
+    try:
+        engine, *_ = deepspeed_tpu.initialize(
+            model=SimpleModel(), example_batch=random_batch(4), config={
+                "train_batch_size": 8, "steps_per_print": 2,
+                "wall_clock_breakdown": True,
+                "optimizer": {"type": "Adam", "params": {"lr": 1e-2}},
+                "csv_monitor": {"enabled": True, "job_name": "t",
+                                "output_path": str(tmp_path)}})
+        for i in range(4):
+            engine.train_batch(random_batch(8, seed=i))
+    finally:
+        logger.removeHandler(cap)
+    parts = [l for l in cap.lines if "train.sync:" in l]
+    assert len(parts) == 2                              # steps 2 and 4
+    for name in ("train.prepare", "train.h2d", "train.dispatch",
+                 "train.after_step"):
+        assert f"{name}: " in parts[-1]
+    assert re.search(r"train\.step: \d+\.\d\dms", parts[-1])
+    assert sum("samples/sec" in l for l in cap.lines) == 2
+    written = os.listdir(os.path.join(str(tmp_path), "t"))
+    assert "Train_Telemetry_train.sync_self_ms.csv" in written
+
+
+def test_autotuner_metric_file_gets_its_throughput(tmp_path, monkeypatch):
+    metric = tmp_path / "metric.json"
+    monkeypatch.setenv("DS_AUTOTUNING_METRIC_FILE", str(metric))
+    engine, *_ = deepspeed_tpu.initialize(
+        model=SimpleModel(), example_batch=random_batch(4), config={
+            "train_batch_size": 8,
+            "optimizer": {"type": "Adam", "params": {"lr": 1e-2}},
+            "autotuning": {"enabled": True, "end_profile_step": 4}})
+    with pytest.raises(SystemExit):
+        for i in range(10):
+            engine.train_batch(random_batch(8, seed=i))
+    out = json.load(open(metric))
+    assert out["steps"] == 4 and out["throughput"] > 0
+
+
+# ------------------------------------------------------------ device scopes
+
+
+def instructions(compiled) -> list:
+    """The optimized module's instructions in order: result shape and
+    layout, opcode, operands and attributes. Metadata is cut out, and so
+    are the numbers the compiler hands out to equal names (``%copy.39``):
+    they follow how many instructions were made and merged on the way, and
+    a scope that keeps two equal ops from merging early moves them."""
+    return [re.sub(r"(%[A-Za-z_\-]+(?:\.[A-Za-z_\-]+)*)(?:\.\d+)+", r"\1",
+                   re.sub(r", metadata=\{[^}]*\}", "", line)).strip()
+            for line in compiled.as_text().splitlines() if " = " in line]
+
+
+def train_step_program(engine):
+    step_fn, _ = engine._active_train_step()
+    gas = engine.config.gradient_accumulation_steps
+    micros = {"input_ids": jax.ShapeDtypeStruct(
+        (gas, engine.config.train_batch_size // gas, 32), jnp.int32)}
+    return step_fn, (engine.state, micros, jax.random.PRNGKey(0),
+                     jnp.float32(1e-3))
+
+
+def decode_program(srv):
+    B = srv.max_batch
+    return srv._decode_fn, (
+        srv.params, srv.pools, jnp.zeros((B,), jnp.int32),
+        jnp.zeros((B, srv.nbk), jnp.int32), jnp.zeros((B,), jnp.int32),
+        jax.random.PRNGKey(0), jnp.zeros((B,), jnp.float32),
+        jnp.zeros((B,), jnp.int32), jnp.ones((B,), jnp.float32))
+
+
+def without_scopes(monkeypatch):
+    """Every ``jax.named_scope`` of the program (and flax's) made a no-op."""
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    monkeypatch.setattr(DeepSpeedEngine, "_finalize_step",
+                        DeepSpeedEngine._finalize_step.__wrapped__)
+
+
+def test_scopes_leave_the_train_steps_instructions_alone(monkeypatch):
+    fn, args = train_step_program(lm_engine())
+    scoped = fn.lower(*args).compile()
+    assert "block.attn" in scoped.as_text() and "optimizer" in \
+        scoped.as_text()
+    with monkeypatch.context() as m:
+        without_scopes(m)
+        fn, args = train_step_program(lm_engine())
+        bare = fn.lower(*args).compile()
+    assert "block.attn" not in bare.as_text()
+    assert instructions(scoped) == instructions(bare)
+
+
+def test_scopes_leave_the_decode_steps_instructions_alone(tiny_lm,
+                                                          monkeypatch):
+    cfg, params = tiny_lm
+    serving = {"block_size": 16, "pool_blocks": 24, "max_batch": 3,
+               "max_blocks_per_seq": 8}
+    fn, args = decode_program(ServingEngine(cfg, params, serving=serving))
+    scoped = fn.lower(*args).compile()
+    assert "kv_write" in scoped.as_text()
+    with monkeypatch.context() as m:
+        without_scopes(m)
+        fn, args = decode_program(ServingEngine(cfg, params,
+                                                serving=serving))
+        bare = fn.lower(*args).compile()
+    assert "kv_write" not in bare.as_text()
+    assert instructions(scoped) == instructions(bare)
+
+
+def test_scope_paths_names_every_scope_of_the_train_step():
+    fn, args = train_step_program(lm_engine(
+        {"zero_optimization": {"stage": 3, "zero_quantized_weights": True,
+                               "stage3_param_persistence_threshold": 0}}))
+    paths = telemetry.scope_paths(fn, *args)
+    found = set(paths.values())
+    for scope in ("embed", "layers", "block.attn", "block.mlp", "head",
+                  "loss", "grad_accum", "optimizer", "zero.scatter",
+                  "backward:block.attn", "backward:block.mlp",
+                  "recompute:block.mlp", "backward:loss"):
+        assert scope in found, scope
+    assert any("zero.gather" in p for p in found)
+    for p in found:
+        for seg in p.split(":")[-1].split("."):
+            assert not seg or any(seg in s.split(".")
+                                  for s in telemetry.SCOPES), p
+
+
+def test_scope_paths_names_every_scope_of_the_decode_step(tiny_lm):
+    cfg, params = tiny_lm
+    srv = ServingEngine(cfg, params, serving={
+        "block_size": 16, "pool_blocks": 24, "max_batch": 3,
+        "max_blocks_per_seq": 8})
+    fn, args = decode_program(srv)
+    found = set(telemetry.scope_paths(fn, *args).values())
+    for scope in ("embed", "layers", "block.attn.qkv", "block.attn.kv_write",
+                  "block.attn.attend", "block.attn.out", "block.mlp",
+                  "head", "sample"):
+        assert scope in found, scope
+
+
+def test_scope_of_reads_jaxs_op_names():
+    assert telemetry.scope_of(
+        "jit(train_step)/while/body/closed_call/transpose(jvp(Transformer))"
+        "/while/body/closed_call/blocks.body/checkpoint/blocks/block.attn/"
+        "attn_qkv/dot_general") == "backward:block.attn"
+    assert telemetry.scope_of(
+        "jit(f)/transpose(jvp())/while/body/closed_call/checkpoint/"
+        "rematted_computation/blocks/block.mlp/mlp_fc/dot_general") == \
+        "recompute:block.mlp"
+    assert telemetry.scope_of(
+        "jit(_decode)/while/body/closed_call/block.attn/kv_write/"
+        "scatter") == "block.attn.kv_write"
+    assert telemetry.scope_of("jit(train_step)/optimizer/add") == "optimizer"
+    assert telemetry.scope_of(
+        "jit(train_step)/transpose(jvp(Transformer))/layers/while/body/"
+        "squeeze") == "backward:layers"
+    assert telemetry.scope_of(
+        "jit(_decode)/layers/while/body/closed_call/block.mlp/add") == \
+        "block.mlp"
+    assert telemetry.scope_of("jit(_decode)/jit(clip)/min") == ""
+    assert telemetry.hlo_op_names(
+        '  %copy.39.remat = bf16[16,32]{1,0} copy(bf16[16,32]{0,1} %x), '
+        'metadata={op_name="jit(_decode)/while/body/block.attn/kv_write/'
+        'scatter" source_file="a.py" source_line=3}\n'
+        '  ROOT %fusion.2 = f32[] fusion(f32[] %y), kind=kLoop, '
+        'metadata={op_name="jit(f)/optimizer/add"}\n') == {
+        "copy.39.remat": "jit(_decode)/while/body/block.attn/kv_write/"
+                         "scatter",
+        "fusion.2": "jit(f)/optimizer/add"}
