@@ -24,6 +24,12 @@ context length, and a sliding window. The jnp oracle
 :func:`paged_attention_reference` computes the identical math by dense
 gather — the CPU fallback and the parity target for the interpret-mode
 tests.
+
+The pool operand is row-major ``[L?, nh, num_blocks, block_size, hd]`` and
+the kernel reads it where it lies. Whoever writes the pool has to leave it
+so: ``serving.model_runner`` updates it in place with dynamic-update-slices
+because the layout the chip's compiler gives a scatter's operand (slots
+major) had the whole pool copied to this one before every call.
 """
 
 from __future__ import annotations

@@ -115,6 +115,47 @@ def lane_topk_topp(logits: jnp.ndarray, top_k: jnp.ndarray,
         jnp.arange(B)[:, None], order].set(final_sorted)
 
 
+def step_programs(cfg, block_size: int, *, interpret: bool = False,
+                  use_filters: bool = False):
+    """The loop's two device programs as plain functions, ``(decode,
+    prefill)``: the engine jits them with the pools donated, and
+    tests/test_chip_compile.py compiles the same two for a described chip."""
+    bs = int(block_size)
+
+    def _pick(logits, r, temps, tks, tps):
+        """Per-lane sampling: greedy lanes take argmax, temperature
+        lanes a categorical over logits / temp — one compiled program
+        for any mix. With ``serving.sampling_filters`` (a
+        construction-time constant: the program is still compiled
+        once) the vectorized per-lane top-k/top-p filter runs on the
+        scaled logits first."""
+        with jax.named_scope("sample"):
+            greedy = jnp.argmax(logits, axis=-1)
+            scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
+            if use_filters:
+                scaled = lane_topk_topp(scaled, tks, tps)
+            sampled = jax.random.categorical(r, scaled, axis=-1)
+            return jnp.where(temps <= 0.0, greedy, sampled)
+
+    def _decode(params, pools, toks, bt, ctx, r, temps, tks, tps):
+        # toks [B] sit at logical position ctx[b]; after the write the
+        # valid length is ctx + 1
+        logits, pools = paged_forward(
+            cfg, params, toks[:, None], pools, bt, ctx, ctx + 1, bs,
+            interpret=interpret)
+        return _pick(logits[:, -1], r, temps, tks, tps), pools
+
+    def _prefill(params, pools, ids, bt, q0, ctx, last_idx, r, temps,
+                 tks, tps):
+        logits, pools = paged_forward(
+            cfg, params, ids, pools, bt, q0, ctx, bs, interpret=interpret)
+        last = jax.lax.dynamic_index_in_dim(logits, last_idx, 1,
+                                            keepdims=False)   # [1, V]
+        return _pick(last, r, temps, tks, tps), pools
+
+    return _decode, _prefill
+
+
 @dataclass
 class _Seq:
     """One active lane: a RUNNING request's device-side bookkeeping."""
@@ -227,40 +268,8 @@ class ServingEngine:
         self.steps = 0                     # decode steps executed
 
         # ---- compiled programs (fixed shapes; ONE decode specialization) ----
-        use_filters = self._use_filters
-
-        def _pick(logits, r, temps, tks, tps):
-            """Per-lane sampling: greedy lanes take argmax, temperature
-            lanes a categorical over logits / temp — one compiled program
-            for any mix. With ``serving.sampling_filters`` (a
-            construction-time constant: the program is still compiled
-            once) the vectorized per-lane top-k/top-p filter runs on the
-            scaled logits first."""
-            with jax.named_scope("sample"):
-                greedy = jnp.argmax(logits, axis=-1)
-                scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
-                if use_filters:
-                    scaled = lane_topk_topp(scaled, tks, tps)
-                sampled = jax.random.categorical(r, scaled, axis=-1)
-                return jnp.where(temps <= 0.0, greedy, sampled)
-
-        def _decode(params, pools, toks, bt, ctx, r, temps, tks, tps):
-            # toks [B] sit at logical position ctx[b]; after the write the
-            # valid length is ctx + 1
-            logits, pools = paged_forward(
-                cfg, params, toks[:, None], pools, bt, ctx, ctx + 1, bs,
-                interpret=self.interpret)
-            return _pick(logits[:, -1], r, temps, tks, tps), pools
-
-        def _prefill(params, pools, ids, bt, q0, ctx, last_idx, r, temps,
-                     tks, tps):
-            logits, pools = paged_forward(
-                cfg, params, ids, pools, bt, q0, ctx, bs,
-                interpret=self.interpret)
-            last = jax.lax.dynamic_index_in_dim(logits, last_idx, 1,
-                                                keepdims=False)   # [1, V]
-            return _pick(last, r, temps, tks, tps), pools
-
+        _decode, _prefill = step_programs(cfg, bs, interpret=self.interpret,
+                                          use_filters=self._use_filters)
         # pools are donated: the loop's only live copy moves through the
         # step, so the update is in-place on TPU (no 2x pool HBM)
         self._decode_fn = jax.jit(_decode, donate_argnums=(1,))

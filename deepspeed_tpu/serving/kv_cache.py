@@ -52,10 +52,15 @@ def init_pool(cfg, num_blocks: int, block_size: int,
               dtype=None) -> Dict[str, jnp.ndarray]:
     """Device-side paged pool: k/v ``[L, nh, num_blocks*block_size, hd]``.
 
-    Flat slot layout (slot = block * block_size + offset) so the decode
-    step's K/V write is ONE scatter over the slot axis; the paged-attention
-    kernel views the same buffer as ``[L, nh, num_blocks, block_size, hd]``
-    (a free reshape) to DMA whole blocks through the block table.
+    Flat slot layout (slot = block * block_size + offset), row-major: the
+    paged forward and the paged-attention kernel both view the same buffer
+    as ``[L, nh, num_blocks, block_size, hd]`` (a free reshape), the kernel
+    to DMA whole blocks through the block table, the forward to write new
+    K/V into it in place with ``lax.dynamic_update_slice`` (one slot a lane
+    in a decode step, a block at a time in a prefill). Not with a scatter:
+    the chip's compiler lays a scatter's operand out slots-major, and the
+    pool was then copied whole to the kernel's layout twice a layer
+    (``serving.model_runner._write_kv``).
 
     ``dtype=jnp.int8`` (round 12): the quantized pool tier — k/v store
     int8 with a per-(layer, head, slot) f32 scale (symmetric over the

@@ -13,9 +13,11 @@ One pure function, :func:`paged_forward`, serves BOTH serving regimes:
 It mirrors ``models/generation.forward_with_cache`` numerically (same
 layer math, same f32 score path, same -1e30 masking), so a paged serve
 is token-exact with sequential ``generate()`` calls under greedy
-sampling. The differences are mechanical: K/V land in pool slots via one
-scatter per layer instead of a dynamic-update-slice into a dense cache,
-and attention reads ride ``ops.attention.paged_attention`` — the Pallas
+sampling. The differences are mechanical: K/V land in the pool's blocks
+through the block table — in place, by unrolled dynamic-update-slices in
+the layout the paged kernel reads (:func:`_write_kv` says why not by a
+scatter) — instead of one dynamic-update-slice into a dense cache, and
+attention reads ride ``ops.attention.paged_attention`` — the Pallas
 block-table kernel on TPU decode, the exact jnp gather reference
 elsewhere.
 
@@ -28,7 +30,7 @@ paged sequences are always exact-length.
 from __future__ import annotations
 
 from functools import partial
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -37,8 +39,62 @@ import numpy as np
 from ..models.generation import _dense, _kv_quantize, _layer_norm, _moe_mlp
 from ..models.transformer import TransformerConfig
 from ..ops.attention import paged_attention
+from .kv_cache import NULL_BLOCK
 
 PyTree = Any
+
+
+class _WritePlan(NamedTuple):
+    """Where one ``paged_forward`` call's new K/V goes, the same in every
+    layer: per lane the touched blocks' physical ids and which of their
+    slots take a new row (``paged_forward`` says how they are worked out)."""
+    bs: int                 # slots a block
+    off: jnp.ndarray        # [B] slot of the first position in its block
+    phys: jnp.ndarray       # [B, J] physical block of touched block j
+    keep: jnp.ndarray       # [B, J, bs] this slot takes a new row
+
+
+def _write_kv(pool, li, new, ax, plan: _WritePlan):
+    """``new`` [B, nh, 1, ., .] with its T positions on axis ``ax`` into
+    layer ``li`` of ``pool`` [L, nh, blocks, ., .], whose blocks have their
+    ``bs`` slots on axis ``ax``: ``lax.dynamic_update_slice`` unrolled in
+    Python over lanes and touched blocks. A decode step (T == 1) writes one
+    slot a lane; a prefill reads each touched block, merges the real
+    positions in and writes the block back (done block-wise, a decode step
+    makes the chip's compiler carry the pool in yet another layout).
+
+    NOT a scatter: the chip's compiler gives a scatter's operand the layout
+    {3,1,2,0} (slots major) while the paged kernel takes its operand
+    row-major, so a scattered pool was copied whole to the kernel's layout
+    twice a layer (seven eighths of a decode step, PERF.md PR 25). And not
+    a ``fori_loop`` over the lanes, which ends the same way. Unrolled
+    updates keep the carried pool in the one layout it has at the jit
+    boundary, updated in place; tests/test_chip_compile.py holds the
+    compiled programs to that."""
+    bs, (B, n_touch), T = plan.bs, plan.phys.shape, new.shape[ax]
+    if T == 1:
+        for b in range(B):
+            at = [li, 0, plan.phys[b, 0], 0, 0]
+            at[ax] = plan.off[b]
+            pool = jax.lax.dynamic_update_slice(pool, new[b:b + 1], at)
+        return pool
+    # the new rows padded by one block either side, so that every touched
+    # block's window of them is in range: block j starts at (j + 1) * bs - off
+    pad = [(0, 0)] * 5
+    pad[ax] = (bs, bs)
+    new = jnp.pad(new, pad)
+    size = (1,) + new.shape[1:ax] + (bs,) + new.shape[ax + 1:]
+    for b in range(B):
+        for j in range(n_touch):
+            at = (li, 0, plan.phys[b, j], 0, 0)
+            start = [b, 0, 0, 0, 0]
+            start[ax] = (j + 1) * bs - plan.off[b]
+            rows = jax.lax.dynamic_slice(new, start, size)
+            held = jax.lax.dynamic_slice(pool, at, size)
+            mask = plan.keep[b, j].reshape((bs,) + (1,) * (4 - ax))
+            pool = jax.lax.dynamic_update_slice(
+                pool, jnp.where(mask, rows, held), at)
+    return pool
 
 
 def paged_forward(cfg: TransformerConfig,
@@ -65,7 +121,9 @@ def paged_forward(cfg: TransformerConfig,
     real queries of this call; query positions >= context_lens are
     PADDING (their K/V writes route to the null block, their logits are
     garbage the host never reads). ``block_size`` is static — it shapes
-    the compiled scatter/gather.
+    the compiled write and gather. ``q_start`` may lie in mid-block and
+    ``q_start + T`` may too (a chunk after a 10-token chunk, a prompt's
+    last chunk): the write works block by block from what it is given.
 
     Params must be the scan-layers layout (``ensure_scan_layout``).
     post-LN encoders don't decode; int8 weight-only params work unchanged
@@ -148,19 +206,27 @@ def paged_forward(cfg: TransformerConfig,
                if cfg.layer_windows is not None
                else jnp.zeros((cfg.num_layers,), jnp.int32))
 
-    # write slots: logical position p of lane b lives in pool slot
-    # bt[b, p // bs] * bs + p % bs; PADDED positions (>= ctx) route to the
-    # null block so the fixed-shape step can't corrupt live state
-    blk = jnp.clip(pos // bs, 0, nbk - 1)                  # [B, T]
-    off = pos % bs
-    phys = jnp.take_along_axis(bt, blk, axis=1)            # [B, T]
-    valid = pos < ctx[:, None]
-    slots = jnp.where(valid, phys * bs + off, off)         # null block else
-    flat_slots = slots.reshape(B * T)
+    # the K/V write, planned once for every layer. Logical position p of
+    # lane b lives in pool block bt[b, p // bs] at slot p % bs, and the T
+    # consecutive positions of a lane touch at most n_touch blocks. Slot r
+    # of touched block j holds position (q_start // bs + j) * bs + r; it
+    # takes the new row t = position - q_start where that is a real query
+    # (0 <= t < T, position < ctx) and keeps what it held otherwise, so a
+    # chunk may start or end in mid-block. A touched block with no real
+    # query in it (padding past ctx, the spare block of an aligned chunk)
+    # is the null block: the fixed-shape step can't corrupt live state.
+    n_touch = (T + bs - 2) // bs + 1
+    off = q_start % bs                                              # [B]
+    lblk = (q_start // bs)[:, None] + jnp.arange(n_touch)           # [B, J]
+    held_pos = lblk[:, :, None] * bs + jnp.arange(bs)               # [B, J, bs]
+    t_idx = held_pos - q_start[:, None, None]
+    keep = (t_idx >= 0) & (t_idx < T) & (held_pos < ctx[:, None, None])
+    phys = jnp.take_along_axis(bt, jnp.clip(lblk, 0, nbk - 1), axis=1)
+    phys = jnp.where(keep.any(axis=2), phys, NULL_BLOCK)            # [B, J]
+    plan = _WritePlan(bs, off, phys, keep)
 
     def layer(carry, xs):
         x, kv = carry
-        k_pool, v_pool = kv["k"], kv["v"]
         p, window, li = xs
         with jax.named_scope("block.attn"):
             with jax.named_scope("qkv"):
@@ -193,40 +259,31 @@ def paged_forward(cfg: TransformerConfig,
                     # unchanged)
                     k = jnp.repeat(k, nh // kvh, axis=1)
                     v = jnp.repeat(v, nh // kvh, axis=1)
-                # ONE scatter per layer: [B, nh, T, hd] -> [B*T, nh, hd] rows
-                # into flat slots (padded lanes hit the null block)
-                k_rows = k.transpose(0, 2, 1, 3).reshape(B * T, nh, hd)
-                v_rows = v.transpose(0, 2, 1, 3).reshape(B * T, nh, hd)
                 kv_new = dict(kv)
                 if quant_kv:
                     # quantize-on-write: THE dense path's per-channel format
-                    # (same helper — axis=-1 math is rank-agnostic over rows)
-                    (kq, ks), (vq, vs) = (_kv_quantize(k_rows),
-                                          _kv_quantize(v_rows))
-                    k_pool = k_pool.at[li, :, flat_slots].set(kq)
-                    v_pool = v_pool.at[li, :, flat_slots].set(vq)
-                    kv_new["k_scale"] = kv["k_scale"].at[
-                        li, :, flat_slots].set(ks)
-                    kv_new["v_scale"] = kv["v_scale"].at[
-                        li, :, flat_slots].set(vs)
-                else:
-                    k_pool = k_pool.at[li, :, flat_slots].set(
-                        k_rows.astype(k_pool.dtype))
-                    v_pool = v_pool.at[li, :, flat_slots].set(
-                        v_rows.astype(v_pool.dtype))
-                kv_new["k"], kv_new["v"] = k_pool, v_pool
+                    # (same helper — axis=-1 math is rank-agnostic over rows);
+                    # a scale block keeps its slots on the last axis
+                    (k, ks), (v, vs) = _kv_quantize(k), _kv_quantize(v)
+                    to_lanes = lambda s: s.reshape(B, nh, 1, 1, T)
+                    kv_new["k_scale"] = _write_kv(kv["k_scale"], li,
+                                                  to_lanes(ks), 4, plan)
+                    kv_new["v_scale"] = _write_kv(kv["v_scale"], li,
+                                                  to_lanes(vs), 4, plan)
+                kv_new["k"] = _write_kv(
+                    kv["k"], li, k.astype(kv["k"].dtype)[:, :, None], 3, plan)
+                kv_new["v"] = _write_kv(
+                    kv["v"], li, v.astype(kv["v"].dtype)[:, :, None], 3, plan)
             with jax.named_scope("attend"):
                 # attention through the block table (kernel on TPU decode,
                 # exact jnp gather elsewhere); the int8 tier passes the pool
                 # AS int8 with its scales — dequant happens in-kernel /
                 # post-gather, O(attended blocks), never a pool-slice copy
-                kp5 = k_pool.reshape(L, nh, nb_pool, bs, hd)
-                vp5 = v_pool.reshape(L, nh, nb_pool, bs, hd)
                 scale_kw = (dict(k_scale=kv_new["k_scale"],
                                  v_scale=kv_new["v_scale"])
                             if quant_kv else {})
-                o = paged_attention(q, kp5, vp5, bt, ctx, sm_scale=sm_scale,
-                                    alibi_slopes=slopes,
+                o = paged_attention(q, kv_new["k"], kv_new["v"], bt, ctx,
+                                    sm_scale=sm_scale, alibi_slopes=slopes,
                                     softcap=cfg.attn_softcap, window=window,
                                     layer_idx=li, q_start=q_start,
                                     impl=attn_impl, interpret=interpret,
@@ -262,8 +319,19 @@ def paged_forward(cfg: TransformerConfig,
         return (x_out, kv_new), None
 
     xs = (params["blocks"], windows, jnp.arange(cfg.num_layers))
+    # the loop carries the pools as the kernel reads them, a block's slots
+    # on an axis of their own: for K/V a free view of init_pool's flat slot
+    # axis; the int8 tier's small scale pools change layout here, at the
+    # loop's boundary (the kernel wants a block's slots on the lane axis),
+    # not twice a layer inside it
+    blocked = {name: (pool.reshape(L, nh, nb_pool, 1, bs)
+                      if name.endswith("_scale")
+                      else pool.reshape(L, nh, nb_pool, bs, hd))
+               for name, pool in pools.items()}
     with jax.named_scope("layers"):
-        (x, kv_out), _ = jax.lax.scan(layer, (x, dict(pools)), xs)
+        (x, kv_out), _ = jax.lax.scan(layer, (x, blocked), xs)
+    kv_out = {name: pool.reshape(pools[name].shape)
+              for name, pool in kv_out.items()}
     with jax.named_scope("head"):
         x = _layer_norm(x, params["ln_f"], cfg.layer_norm_eps, rms)
         if cfg.tie_embeddings:

@@ -1,5 +1,7 @@
 """The chip's compiler, asked without the chip: every kernel of the main path
-compiles for a DESCRIBED TPU v5e at the widths ``chip_smoke.py`` runs.
+compiles for a DESCRIBED TPU v5e at the widths ``chip_smoke.py`` runs, and
+the serving loop's two whole programs compile at the benchmark cell's widths
+with the KV pool updated in place (no whole-pool copy in the optimized HLO).
 
 Interpret mode cannot see what Mosaic refuses (block shapes the tiling
 rules reject, too much fast memory, a kernel the partitioner cannot split):
@@ -53,13 +55,16 @@ def chip(topo):
     compilation_cache.reset_cache()
 
 
-def _kernels(fn, *args):
-    """Compile for the described chip; the kernel scopes in the program."""
+def _kernel_scopes(text):
     import re
-    text = jax.jit(fn).lower(*args).compile().as_text()
     return [re.search(r'op_name="([^"]+)"', line).group(1)
             for line in text.splitlines()
             if "tpu_custom_call" in line and "custom-call(" in line]
+
+
+def _kernels(fn, *args):
+    """Compile for the described chip; the kernel scopes in the program."""
+    return _kernel_scopes(jax.jit(fn).lower(*args).compile().as_text())
 
 
 @pytest.mark.parametrize("heads,head_dim", [(16, 128), (32, 64)])
@@ -187,3 +192,94 @@ def test_flash_runs_per_shard_on_a_four_chip_mesh(topo, chip, monkeypatch,
                            axis_names=frozenset(BATCH_AXES), check_vma=False)
     names = _kernels(fn, x, x, x)
     assert len(names) == 3 and all("shard_map" in n for n in names), names
+
+
+def _results(text):
+    """(computation, opcode, dtype, elements) of every instruction of an
+    optimized HLO module's text, fused computations included."""
+    import math
+    import re
+    comp, out = None, []
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            comp = "ENTRY" if line.startswith("ENTRY") else head.group(1)
+            continue
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (\w+)\[([\d,]*)\]\S* "
+                     r"([\w\-]+)\(", line)
+        if m:
+            dims = [int(d) for d in m.group(2).split(",") if d]
+            out.append((comp, m.group(3), m.group(1), math.prod(dims)))
+    return out
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("program", ["decode", "prefill256"])
+def test_serving_step_updates_the_pool_in_place(chip, monkeypatch, program,
+                                                quant):
+    """The serving loop's two programs (``serving.engine.step_programs``, as
+    the engine jits them: pools donated) at the benchmark cell's widths,
+    mistral-7b-l16 with 32 lanes over a 384 x 32 pool: no instruction makes
+    a whole K/V pool by ``copy``, ``transpose`` or ``scatter`` (the decode
+    step: nor a whole layer of one), and the program's temporaries are a
+    fraction of one pool. Before PR 25 the K/V scatter left the pool in a
+    layout the paged kernel does not read: six whole-pool copies a decode
+    step, 2 x 16 of them inside the layer loop, ``temp_size`` of two pools
+    (PERF.md, PR 25)."""
+    from deepspeed_tpu.models import TransformerConfig, build_model
+    from deepspeed_tpu.models.generation import ensure_scan_layout
+    from deepspeed_tpu.serving.engine import step_programs
+    from deepspeed_tpu.serving.kv_cache import init_pool
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    L, NH, HD, BS, NB, B, NBK = 16, 32, 128, 32, 384, 32, 40
+    model, cfg = build_model(TransformerConfig(
+        vocab_size=32000, max_seq_len=32768, hidden_size=NH * HD,
+        num_layers=L, num_heads=NH, num_kv_heads=8, mlp_dim_override=14336,
+        layer_norm_eps=1e-5, norm="rmsnorm", gated_mlp=True,
+        activation="silu", pos_embed="rotary", rotary_interleaved=False,
+        use_bias=False, tie_embeddings=False, layer_windows=(4096,) * L,
+        dtype=jnp.bfloat16))
+    on_chip = lambda tree: jax.tree.map(lambda x: chip(x.shape, x.dtype), tree)
+    params = on_chip(jax.eval_shape(lambda: ensure_scan_layout(jax.tree.map(
+        lambda x: x.astype(jnp.bfloat16), model.init(
+            jax.random.PRNGKey(0),
+            {"input_ids": jnp.zeros((1, 8), jnp.int32)})["params"]), L)))
+    pools = on_chip(jax.eval_shape(lambda: init_pool(
+        cfg, NB, BS, jnp.int8 if quant else jnp.bfloat16)))
+    i32, f32 = jnp.int32, jnp.float32
+    lanes = B if program == "decode" else 1
+    sample = (chip((2,), jnp.uint32), chip((lanes,), f32),
+              chip((lanes,), i32), chip((lanes,), f32))
+    decode, prefill = step_programs(cfg, BS)
+    if program == "decode":
+        fn, args = decode, (chip((B,), i32), chip((B, NBK), i32),
+                            chip((B,), i32))
+    else:
+        fn, args = prefill, (chip((1, 256), i32), chip((1, NBK), i32),
+                             chip((1,), i32), chip((1,), i32), chip((), i32))
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, pools, *args, *sample).compile()
+    text = compiled.as_text()
+
+    layer = NH * NB * BS * HD
+    at_least = layer if program == "decode" else L * layer
+    moved = [r for r in _results(text)
+             if r[1] in ("copy", "transpose", "scatter") and r[3] >= at_least]
+    assert not moved, moved
+    # the int8 tier's scales reach the kernel with a block's slots on the
+    # lane axis, which is not the layout they have at the jit boundary: they
+    # change layout there (four small conversions a step), never in the loop
+    scales = L * NH * NB * BS
+    inside = [r for r in _results(text)
+              if r[0] != "ENTRY" and r[2] == "f32" and r[3] == scales
+              and r[1] in ("copy", "transpose", "scatter", "reshape")]
+    assert not inside, inside
+    pool_bytes = L * layer * (1 if quant else 2)
+    # int8: the two scale pools live lane-padded in the loop (2 x 100 MB,
+    # a quarter of an int8 pool), outside the donated buffers
+    share = 0.5 if quant else 0.1 if program == "decode" else 0.25
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < share * pool_bytes, (temp, pool_bytes)
+    names = _kernel_scopes(text)
+    if program == "decode":
+        assert len(names) == 1 and "paged_attention" in names[0], names

@@ -678,3 +678,154 @@ def test_int8_weight_only_decode_parity(tiny, paged_kernel_traces):
     with pytest.raises(ValueError):
         ServingEngine(cfg, params,
                       serving=dict(SERVE_CFG, weight_dtype="int4"))
+
+
+# ---------------------------------------------------------------------------
+# the K/V write (PR 25): in-place updates, held to the scatter they replaced
+# ---------------------------------------------------------------------------
+
+W_BS, W_NBK, W_BLOCKS = 16, 8, 32
+
+#: name -> (T, lanes as (q_start, ctx, block table)); ctx counts the real
+#: queries of the call, positions past it are padding
+_WRITE_CASES = {
+    # a decode step with two live lanes and two idle ones (an all-null
+    # table: they land in the null block), and a lane whose one position is
+    # padding (q_start == ctx): its own block 20 must not take the row
+    "decode_idle_lanes": (1, [(4, 5, [3, 9]), (0, 1, []), (37, 38, [5, 6, 7]),
+                              (0, 1, []), (20, 20, [19, 20])]),
+    # a bucket-padded prompt: positions 21..31 are padding
+    "prefill_padded_tail": (32, [(0, 21, [4, 8])]),
+    # the chunk after a prefix hit of one block (block 11, shared) and a
+    # first chunk of 10: it starts at slot 10 of the second block
+    "q_start_mid_block": (16, [(26, 36, [11, 12, 13])]),
+    # a prompt's last chunk, 21 real tokens of 32: ends in mid-block
+    "last_chunk_mid_block": (32, [(32, 53, [11, 2, 14, 15])]),
+    # everything before q_start came from the prefix cache: its blocks are
+    # another sequence's too and must come out as they went in
+    "shared_prefix_unwritten": (16, [(32, 48, [11, 21, 17])]),
+}
+
+
+def _scatter_write_kv(bt, q_start, ctx, T):
+    """The write as it was up to PR 24, in ``_write_kv``'s signature: ONE
+    scatter a layer into flat slots, padded positions into the null block
+    at their offset."""
+    pos = q_start[:, None] + np.arange(T)[None, :]
+    phys = np.take_along_axis(bt, np.clip(pos // W_BS, 0, W_NBK - 1), axis=1)
+    slots = jnp.asarray(np.where(pos < ctx[:, None],
+                                 phys * W_BS + pos % W_BS,
+                                 pos % W_BS).reshape(-1))
+
+    def write(pool, li, new, ax, plan):
+        L, nh, nb = pool.shape[:3]
+        B, w = new.shape[0], pool.shape[7 - ax]      # hd (ax 3) or 1 (ax 4)
+        rows = jnp.moveaxis(new.reshape(B, nh, T, w) if ax == 3
+                            else new.reshape(B, nh, T, 1), 1, 2)
+        flat = pool.reshape(L, nh, nb * W_BS, w)
+        return flat.at[li, :, slots].set(
+            rows.reshape(B * T, nh, w)).reshape(pool.shape)
+    return write
+
+
+@pytest.mark.parametrize("case", sorted(_WRITE_CASES))
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_kv_write_equals_the_scatter_bit_for_bit(tiny, monkeypatch, kv, case):
+    """One ``paged_forward`` call over a pool full of other sequences' K/V:
+    every slot outside the null block (a sink nothing reads) comes out bit
+    for bit as the scatter left it, and no block but the call's own
+    targets changed at all — idle lanes, padding, a shared prefix block."""
+    from deepspeed_tpu.serving import model_runner
+    from deepspeed_tpu.serving.kv_cache import NULL_BLOCK, init_pool
+    cfg, params = tiny
+    T, lanes = _WRITE_CASES[case]
+    B = len(lanes)
+    bt = np.full((B, W_NBK), NULL_BLOCK, np.int32)
+    for b, (_, _, blocks) in enumerate(lanes):
+        bt[b, :len(blocks)] = blocks
+    q_start = np.asarray([q for q, _, _ in lanes], np.int32)
+    ctx = np.asarray([c for _, c, _ in lanes], np.int32)
+    rng = np.random.default_rng(5)
+    ids = rng.integers(1, 64, size=(B, T)).astype(np.int32)
+
+    def filled(name, z):
+        if z.dtype == jnp.int8:
+            return jnp.asarray(rng.integers(-127, 128, z.shape), jnp.int8)
+        r = rng.standard_normal(z.shape).astype(np.float32)
+        return jnp.asarray(np.abs(r) + 0.1 if name.endswith("_scale") else r,
+                           z.dtype)
+
+    before = {name: filled(name, z) for name, z in init_pool(
+        cfg, W_BLOCKS, W_BS,
+        jnp.int8 if kv == "int8" else jnp.bfloat16).items()}
+
+    def run():
+        return jax.jit(lambda pools: model_runner.paged_forward(
+            cfg, params, jnp.asarray(ids), pools, jnp.asarray(bt),
+            jnp.asarray(q_start), jnp.asarray(ctx), W_BS))(before)
+
+    logits, after = run()
+    monkeypatch.setattr(model_runner, "_write_kv",
+                        _scatter_write_kv(bt, q_start, ctx, T))
+    logits_ref, scattered = run()
+
+    def blocks_of(name, pools):
+        a = np.asarray(pools[name])
+        return a.reshape(a.shape[:2] + (W_BLOCKS, W_BS, -1)).view(np.uint8)
+
+    pos = q_start[:, None] + np.arange(T)[None, :]
+    targets = {int(bt[b, p // W_BS]) for b in range(B)
+               for p in pos[b] if p < ctx[b]} - {NULL_BLOCK}
+    assert targets, "the case writes nothing"
+    others = [i for i in range(1, W_BLOCKS) if i not in targets]
+    for name in before:
+        got, want, was = (blocks_of(name, p)
+                          for p in (after, scattered, before))
+        assert np.array_equal(got[:, :, 1:], want[:, :, 1:]), name
+        assert np.array_equal(got[:, :, others], was[:, :, others]), name
+        assert not np.array_equal(got[:, :, sorted(targets)],
+                                  was[:, :, sorted(targets)]), name
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(logits_ref),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("kv", ["model_dtype", "int8"])
+def test_step_programs_hold_no_pool_scatter_on_the_cpu(tiny, kv):
+    """The CPU twin of tests/test_chip_compile.py's guard, for where no TPU
+    topology can be described: the engine's own two compiled programs hold
+    no ``scatter`` that makes a whole pool (the write this PR replaced),
+    and the decode step no pool-shaped ``copy`` either. The prefill program
+    is not held to the second: the CPU compiler fuses a touched block's
+    read into the update before it and then copies the pool to keep both
+    versions, which the chip's compiler does not (the guard for that is the
+    described-chip test)."""
+    import math
+    import re
+    cfg, params = tiny
+    eng = ServingEngine(cfg, params, serving=dict(
+        SERVE_CFG, **({"kv_cache_dtype": "int8"} if kv == "int8" else {})))
+    B, nbk = eng.max_batch, eng.nbk
+    i32, f32 = jnp.int32, jnp.float32
+
+    def sample(n):
+        return (jax.random.PRNGKey(0), jnp.zeros((n,), f32),
+                jnp.zeros((n,), i32), jnp.ones((n,), f32))
+
+    programs = {
+        "decode": eng._decode_fn.lower(
+            eng.params, eng.pools, jnp.zeros((B,), i32),
+            jnp.zeros((B, nbk), i32), jnp.zeros((B,), i32), *sample(B)),
+        "prefill": eng._prefill_fn.lower(
+            eng.params, eng.pools, jnp.zeros((1, 32), i32),
+            jnp.zeros((1, nbk), i32), jnp.zeros((1,), i32),
+            jnp.ones((1,), i32), jnp.asarray(0, i32), *sample(1)),
+    }
+    pool_sizes = {math.prod(p.shape) for p in eng.pools.values()}
+    for name, lowered in programs.items():
+        made = [(m.group(2), m.group(1)) for m in re.finditer(
+            r"= \w+\[([\d,]*)\]\S* (scatter|copy)\(",
+            lowered.compile().as_text())
+            if math.prod(int(d) for d in m.group(1).split(",") if d)
+            in pool_sizes]
+        banned = {"scatter"} | ({"copy"} if name == "decode" else set())
+        assert not [m for m in made if m[0] in banned], (name, made)
