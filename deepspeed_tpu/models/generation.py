@@ -42,6 +42,16 @@ def _layer_norm(x, p, eps, rms: bool = False):
     return (y * p["scale"] + p["bias"]).astype(x.dtype)
 
 
+def _qk_norm(cfg: TransformerConfig, p, q, k, kind: str):
+    """RMSNorm of q and k before rotary, where the config's ``qk_norm`` is
+    of this ``kind``: "projection" (called before the head split) or "head"
+    (after it). Both norm the last axis they are given."""
+    if cfg.qk_norm_kind != kind:
+        return q, k
+    return (_layer_norm(q, p["q_norm"], cfg.layer_norm_eps, rms=True),
+            _layer_norm(k, p["k_norm"], cfg.layer_norm_eps, rms=True))
+
+
 def _kernel_of(p, dtype):
     """Matmul weight, dequantizing the int8 weight-only forms in place.
 
@@ -151,14 +161,45 @@ def ensure_scan_layout(params: PyTree, num_layers: int) -> PyTree:
     return {**rest, "blocks": stacked}
 
 
-def _moe_mlp(cfg: TransformerConfig, p_moe, h):
-    """Decode-path MoE MLP: same gating math as moe/layer.MoE with a no-drop
-    capacity — incremental decode can't see the other timesteps a capacity
-    limit would make it compete with (run eval with a capacity_factor that
-    avoids drops for exact decode/full-forward parity)."""
-    from ..moe.sharded_moe import top1_gating, top2_gating
+def split_stacked_experts(cfg: TransformerConfig, blocks):
+    """``(blocks for the layer loop to slice, the expert stack it must
+    not)``: a dropless MoE's ``moe/experts`` leaves ``[L, E, in, out]`` stay
+    whole beside the loop and ``_moe_mlp(..., layer=li)`` picks the layer
+    inside the grouped-matmul kernel (``moe/dropless.
+    grouped_matmul_of_layer`` says what slicing them costs). ``None`` for
+    every other model."""
+    if not cfg.moe_is_dropless:
+        return blocks, None
+    moe = {k: v for k, v in blocks["moe"].items() if k != "experts"}
+    return {**blocks, "moe": moe}, blocks["moe"]["experts"]
+
+
+def _moe_mlp(cfg: TransformerConfig, p_moe, h, with_routing: bool = False,
+             interpret: bool = False, layer=None):
+    """Decode-path MoE MLP. A dropless config (``cfg.moe_is_dropless``: OLMoE)
+    runs ``moe/dropless.dropless_moe``, the function its training module
+    runs; ``with_routing`` then returns ``(y, Routing)`` for the caller's
+    counters, and with ``layer`` ``p_moe["experts"]`` is the whole stack
+    (:func:`split_stacked_experts`). Top-1/2 GShard configs keep the gating
+    math of moe/layer.MoE with a no-drop capacity — incremental decode can't
+    see the other timesteps a capacity limit would make it compete with (run
+    eval with a capacity_factor that avoids drops for exact
+    decode/full-forward parity)."""
     B, T, H = h.shape
     tokens = h.reshape(B * T, H)
+    if cfg.moe_is_dropless:
+        from ..moe.dropless import dropless_moe
+        from .transformer import _ACTIVATIONS
+        y, routing = dropless_moe(
+            tokens, p_moe["gate"]["kernel"], p_moe["experts"], k=cfg.moe_k,
+            renorm=cfg.moe_norm_topk,
+            act=(_ACTIVATIONS[cfg.activation] if cfg.gated_mlp
+                 else jax.nn.gelu),
+            kernel_of=lambda p: _kernel_of(p, h.dtype), interpret=interpret,
+            layer=layer)
+        y = y.reshape(B, T, H)
+        return (y, routing) if with_routing else y
+    from ..moe.sharded_moe import top1_gating, top2_gating
     gate_logits = tokens.astype(jnp.float32) @ p_moe["gate"]["kernel"]
     gating = top1_gating if cfg.moe_k == 1 else top2_gating
     _aux, combine, dispatch, _ = gating(gate_logits, capacity=B * T)
@@ -324,11 +365,9 @@ def forward_with_cache(cfg: TransformerConfig, params: PyTree,
         q, k, v = jnp.split(qkv, [nh * hd, (nh + kvh) * hd], axis=-1)
         to_heads = lambda t, n: t.reshape(B, T_new, n, hd).transpose(
             0, 2, 1, 3)
+        q, k = _qk_norm(cfg, p, q, k, "projection")   # OLMoE: whole vector
         q, k, v = to_heads(q, nh), to_heads(k, kvh), to_heads(v, kvh)
-        if cfg.qk_norm:
-            # Qwen3: per-head RMSNorm on q/k before rotary
-            q = _layer_norm(q, p["q_norm"], cfg.layer_norm_eps, rms=True)
-            k = _layer_norm(k, p["k_norm"], cfg.layer_norm_eps, rms=True)
+        q, k = _qk_norm(cfg, p, q, k, "head")         # Qwen3: per head
         if cfg.pos_embed == "rotary":
             # q_log: logical (pad-corrected) positions — [B, T] for ragged
             # left-padded batches, [T] otherwise (apply_rotary handles both)
@@ -419,6 +458,9 @@ def forward_with_cache(cfg: TransformerConfig, params: PyTree,
 
         def mlp(hin):
             if cfg.moe_experts > 0:
+                if experts is not None:
+                    return _moe_mlp(cfg, dict(p["moe"], experts=experts),
+                                    hin, layer=li)
                 return _moe_mlp(cfg, p["moe"], hin)
             if cfg.gated_mlp:            # SwiGLU (Llama family)
                 g = act(_dense(hin, p["mlp_gate"]))
@@ -442,7 +484,8 @@ def forward_with_cache(cfg: TransformerConfig, params: PyTree,
             return (x_out, k_all, v_all, ks_all, vs_all), None
         return (x_out, k_all, v_all), None
 
-    xs = (params["blocks"], windows, jnp.arange(cfg.num_layers))
+    blocks, experts = split_stacked_experts(cfg, params["blocks"])
+    xs = (blocks, windows, jnp.arange(cfg.num_layers))
     if quant_kv:
         (x, k_new, v_new, ks_new, vs_new), _ = jax.lax.scan(
             layer, (x, cache["k"], cache["v"], cache["k_scale"],

@@ -762,13 +762,17 @@ def _to_f32(params):
 # policy registry (reference: replace_policy.py replace_policies list)
 def _llama_family_params(sd, prefix, L, qkv_bias=False, o_bias=False,
                          mlp_bias=False, qk_norm=False, moe_experts=0,
-                         norm_plus_one=False, sandwich_norms=False):
+                         norm_plus_one=False, sandwich_norms=False,
+                         moe_names=None):
     """Shared Llama/Mistral/Qwen2/Qwen3/Mixtral block mapping: RMSNorm +
     GQA qkv + SwiGLU (dense, or ``moe_experts`` SwiGLU experts behind a
     router — HF block_sparse_moe w1/w3/w2 -> our moe.experts
     gate/fc/proj). Bias flags are PRESENCE-driven by the caller (Llama
     attention_bias has q/k/v/o biases; Qwen2 has q/k/v only; mlp_bias
-    biases gate/up/down; qk_norm adds Qwen3's per-head q/k RMSNorm)."""
+    biases gate/up/down; qk_norm adds q/k RMSNorm scales, Qwen3's per head
+    or OLMoE's over the whole projection: the same names, another length).
+    ``moe_names``: HF's names of the mixture module and of an expert's gate,
+    up and down projections (Mixtral's; OLMoE: mlp, gate/up/down_proj)."""
     g = lambda n: _np(sd[prefix + n])
     stack = _stacker(g, L)
     # Gemma stores RMSNorm weights as w with the forward computing
@@ -815,20 +819,21 @@ def _llama_family_params(sd, prefix, L, qkv_bias=False, o_bias=False,
                 lambda i, n=hfn: ln_w(g(f"layers.{i}.{n}.weight")))}
     if moe_experts > 0:
         E = moe_experts
+        mod, w_gate, w_up, w_down = moe_names
 
         def estack(w):
             """[L, E, in, out] expert-stacked kernels (HF stores [out, in])."""
             return stack(lambda i: np.stack(
-                [g(f"layers.{i}.block_sparse_moe.experts.{j}.{w}.weight").T
+                [g(f"layers.{i}.{mod}.experts.{j}.{w}.weight").T
                  for j in range(E)]))
 
         blocks["moe"] = {
             "gate": {"kernel": stack(
-                lambda i: g(f"layers.{i}.block_sparse_moe.gate.weight").T)},
+                lambda i: g(f"layers.{i}.{mod}.gate.weight").T)},
             # HF MixtralBlockSparseTop2MLP: w1 = gate, w3 = up, w2 = down
-            "experts": {"gate": {"kernel": estack("w1")},
-                        "fc": {"kernel": estack("w3")},
-                        "proj": {"kernel": estack("w2")}},
+            "experts": {"gate": {"kernel": estack(w_gate)},
+                        "fc": {"kernel": estack(w_up)},
+                        "proj": {"kernel": estack(w_down)}},
         }
     else:
         blocks.update(
@@ -855,7 +860,9 @@ def _load_hf_llama_family(model_or_state_dict, config,
     sd, config = _sd_and_config(model_or_state_dict, config)
     prefix = _prefix(sd, "model.")
     L = config.num_hidden_layers
-    moe_experts = int(getattr(config, "num_local_experts", 0)) if moe else 0
+    olmoe = moe == "olmoe"
+    moe_experts = int(getattr(config, "num_experts" if olmoe
+                              else "num_local_experts", 0)) if moe else 0
     moe_k = int(getattr(config, "num_experts_per_tok", 2)) if moe else 1
     windows = None
     if use_sliding_window:
@@ -919,6 +926,8 @@ def _load_hf_llama_family(model_or_state_dict, config,
     o_bias = prefix + "layers.0.self_attn.o_proj.bias" in sd
     mlp_bias = prefix + "layers.0.mlp.gate_proj.bias" in sd
     qk_norm = prefix + "layers.0.self_attn.q_norm.weight" in sd
+    if qk_norm and olmoe:
+        qk_norm = "projection"      # over the whole q / k vector, not a head
     cfg = TransformerConfig(
         vocab_size=config.vocab_size,
         max_seq_len=config.max_position_embeddings,
@@ -954,6 +963,11 @@ def _load_hf_llama_family(model_or_state_dict, config,
         moe_capacity_factor=(float(moe_experts) / moe_k if moe_experts
                              else 1.25),
         moe_aux_weight=float(getattr(config, "router_aux_loss_coef", 0.01)),
+        # OLMoE: dropless top-k by sorted dispatch (moe/dropless.py), the
+        # k raw softmax weights unless norm_topk_prob
+        moe_dropless=olmoe,
+        moe_norm_topk=(bool(getattr(config, "norm_topk_prob", False))
+                       if olmoe else True),
         # Gemma-2: sandwich norms, tanh softcapping on attention scores and
         # final logits, and the query_pre_attn_scalar attention scale
         post_block_norms=gemma2,
@@ -971,7 +985,12 @@ def _load_hf_llama_family(model_or_state_dict, config,
                                      qk_norm=qk_norm,
                                      moe_experts=moe_experts,
                                      norm_plus_one=norm_plus_one,
-                                     sandwich_norms=gemma2)
+                                     sandwich_norms=gemma2,
+                                     moe_names=(
+                                         ("mlp", "gate_proj", "up_proj",
+                                          "down_proj") if olmoe else
+                                         ("block_sparse_moe", "w1", "w3",
+                                          "w2")))
     if not tie:
         if "lm_head.weight" not in sd:
             # fail loudly like every other CausalLM loader — fabricating a
@@ -1287,6 +1306,20 @@ def load_hf_mixtral(model_or_state_dict, config=None):
                                  use_sliding_window=True, moe=True)
 
 
+def load_hf_olmoe(model_or_state_dict, config=None):
+    """OLMoE (HF OlmoeForCausalLM): the Llama block family with MHA, an
+    RMSNorm over the WHOLE projected q and k vectors (``qk_norm=
+    "projection"``), and in place of the MLP ``num_experts`` SwiGLU experts
+    behind a dropless top-(num_experts_per_tok) softmax router whose weights
+    are not renormalised (``mlp.gate`` + ``mlp.experts.{j}.{gate,up,down}
+    _proj`` -> ``moe/dropless.py`` over the stacked ``[L, E, in, out]``
+    tree). ``clip_qkv`` must be null, as it is in the published configs."""
+    sd, config = _sd_and_config(model_or_state_dict, config)
+    if getattr(config, "clip_qkv", None) is not None:
+        raise NotImplementedError("OLMoE clip_qkv is not implemented")
+    return _load_hf_llama_family(sd, config, moe="olmoe")
+
+
 HF_POLICIES = {
     "llama": load_hf_llama,
     "LlamaForCausalLM": load_hf_llama,
@@ -1298,6 +1331,8 @@ HF_POLICIES = {
     "Qwen3ForCausalLM": load_hf_qwen3,
     "mixtral": load_hf_mixtral,
     "MixtralForCausalLM": load_hf_mixtral,
+    "olmoe": load_hf_olmoe,
+    "OlmoeForCausalLM": load_hf_olmoe,
     "gemma": load_hf_gemma,
     "GemmaForCausalLM": load_hf_gemma,
     "gemma2": load_hf_gemma2,

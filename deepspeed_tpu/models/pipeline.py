@@ -68,6 +68,12 @@ class PipelinedTransformer:
                 "pipelined model does not thread per-layer sliding "
                 "windows (layer_windows); run windowed models on the "
                 "non-pipelined engine")
+        if cfg.moe_is_dropless:
+            # a dropless block hands out [2, E] balance statistics that the
+            # model averages over ALL layers; the pipe carries a scalar
+            raise NotImplementedError(
+                "pipelined model does not support dropless MoE (moe_k > 2 "
+                "or moe_dropless); run it on the non-pipelined engine")
         for knob in ("embed_ln", "token_type_vocab", "mlm_head",
                      "no_lm_head"):
             # same fail-loud contract: the pipelined embed/head plumbing

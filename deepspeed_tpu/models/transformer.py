@@ -133,8 +133,11 @@ class TransformerConfig:
     # biases on the gated-MLP projections (HF LlamaConfig.mlp_bias);
     # None = use_bias
     mlp_bias: Optional[bool] = None
-    # Qwen3: per-head RMSNorm on q and k (over head_dim) before rotary
-    qk_norm: bool = False
+    # RMSNorm on q and k before rotary, of two kinds: True / "head" is
+    # Qwen3's (per head, over head_dim, scale [head_dim]); "projection" is
+    # OLMoE's (over the whole projected vector before the head split,
+    # scale [heads * head_dim])
+    qk_norm: Any = False
     # Gemma: token embeddings scaled by sqrt(hidden_size), applied in the
     # COMPUTE dtype (HF casts the normalizer to the hidden dtype, so bf16
     # runs see the same rounding)
@@ -156,6 +159,13 @@ class TransformerConfig:
     moe_k: int = 1
     moe_capacity_factor: float = 1.25
     moe_aux_weight: float = 0.01
+    # dropless routing (OLMoE): every token reaches all moe_k experts, no
+    # capacity; always so for moe_k > 2 (moe/dropless.py). The aux loss is
+    # then HF's load_balancing_loss_func over all layers' tokens
+    moe_dropless: bool = False
+    # renormalise the moe_k router weights (HF norm_topk_prob; the GShard
+    # top-2 path always does). OLMoE: False
+    moe_norm_topk: bool = True
     # block-sparse attention layout (ds_config "sparse_attention" section;
     # the engine wires it here and sets attention_impl="sparse"): a hashable
     # tuple of (key, value) items — lists as tuples — so the frozen config
@@ -180,10 +190,23 @@ class TransformerConfig:
             raise NotImplementedError(
                 "post_block_norms (Gemma-2 sandwich) + parallel_residual "
                 "is not implemented")
+        if self.qk_norm not in (False, True, "head", "projection"):
+            raise ValueError(
+                f"qk_norm {self.qk_norm!r}: expected False, True / 'head' "
+                "(per head) or 'projection' (over the whole q / k vector)")
         if self.activation_quant not in (None, "int8", "fp8"):
             raise ValueError(
                 f"activation_quant {self.activation_quant!r}: expected "
                 "'int8', 'fp8' or None")
+
+    @property
+    def qk_norm_kind(self) -> Optional[str]:
+        """None, "head" or "projection"."""
+        return "head" if self.qk_norm is True else (self.qk_norm or None)
+
+    @property
+    def moe_is_dropless(self) -> bool:
+        return self.moe_experts > 0 and (self.moe_dropless or self.moe_k > 2)
 
     @property
     def head_dim(self) -> int:
@@ -679,15 +702,18 @@ class Block(nn.Module):
             qkv = dense((nh + 2 * kv) * hd, "attn_qkv", bias=cfg.qkv_bias)(h)
             q, k, v = jnp.split(qkv, [nh * hd, (nh + kv) * hd], axis=-1)
             to_heads = lambda t, n: t.reshape(B, S, n, hd).transpose(0, 2, 1, 3)
+            qk_ln = lambda name: nn.RMSNorm(
+                epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
+                param_dtype=jnp.float32, name=name)
+            if cfg.qk_norm_kind == "projection":
+                # OLMoE: RMSNorm over the whole projected q / k vector,
+                # before the head split (HF OlmoeAttention.q_norm/k_norm)
+                q, k = qk_ln("q_norm")(q), qk_ln("k_norm")(k)
             q, k, v = to_heads(q, nh), to_heads(k, kv), to_heads(v, kv)
-            if cfg.qk_norm:
+            if cfg.qk_norm_kind == "head":
                 # Qwen3: RMSNorm over head_dim on q/k, before rotary (HF
                 # Qwen3Attention.q_norm/k_norm — per-head, scale-only)
-                qk_ln = lambda name: nn.RMSNorm(
-                    epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
-                    param_dtype=jnp.float32, name=name)
-                q = qk_ln("q_norm")(q)
-                k = qk_ln("k_norm")(k)
+                q, k = qk_ln("q_norm")(q), qk_ln("k_norm")(k)
             if cfg.pos_embed == "rotary":
                 pos = positions if positions is not None else jnp.arange(S)
                 inv_freq = cfg.rope_inv_freq(S)     # None = plain-theta table
@@ -763,24 +789,20 @@ class Block(nn.Module):
 
         def _mlp(h):
             if cfg.moe_experts > 0:
-                from ..moe.layer import ExpertMLP, GatedExpertMLP, MoE
-                if cfg.gated_mlp:
-                    # Mixtral family: SwiGLU experts
-                    make_expert = lambda: GatedExpertMLP(
-                        H, cfg.mlp_dim, dtype=cfg.dtype,
-                        use_bias=cfg.use_bias, activation=cfg.activation,
-                        name="experts")
-                else:
-                    make_expert = lambda: ExpertMLP(
-                        H, cfg.mlp_dim, dtype=cfg.dtype,
-                        use_bias=cfg.use_bias, name="experts")
+                from ..moe.layer import MoE
+                # gated_mlp: SwiGLU experts (Mixtral, OLMoE); else the
+                # fc -> gelu -> proj expert
                 return MoE(
                     hidden_size=H,
                     num_experts=cfg.moe_experts,
-                    expert=make_expert,
+                    mlp_dim=cfg.mlp_dim, gated=cfg.gated_mlp,
+                    activation=cfg.activation, use_bias=cfg.use_bias,
                     k=cfg.moe_k,
                     capacity_factor=cfg.moe_capacity_factor,
                     eval_capacity_factor=cfg.moe_capacity_factor,
+                    dropless=cfg.moe_is_dropless,
+                    norm_topk=cfg.moe_norm_topk,
+                    aux_stats=cfg.moe_is_dropless,
                     dtype=cfg.dtype,
                     name="moe")(h, train=train)
             if cfg.gated_mlp:
@@ -965,7 +987,7 @@ class Transformer(nn.Module):
                     length=cfg.num_layers,
                     metadata_params={nn.PARTITION_NAME: "layers"},
                 )(block(cfg, name="blocks"), x, xs)
-            aux_total = jnp.sum(auxes)
+            aux_total = jnp.sum(auxes, axis=0)
         else:
             aux_total = jnp.zeros((), jnp.float32)
             ltd_active = (train and cfg.ltd_tokens > 0
@@ -1005,6 +1027,12 @@ class Transformer(nn.Module):
                                       float(i))
                 aux_total = aux_total + aux
 
+        if cfg.moe_is_dropless:
+            # the blocks handed out [2, E] balance statistics: HF's
+            # load_balancing_loss_func takes the means over ALL layers'
+            # tokens before the product (a layer PLD dropped adds zeros)
+            from ..moe.dropless import balance_loss
+            aux_total = balance_loss(aux_total / cfg.num_layers)
         with jax.named_scope("head"):
             if not cfg.post_ln:
                 # post-LN stacks (BERT) end already normalized by each block's ln2

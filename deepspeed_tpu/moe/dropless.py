@@ -1,0 +1,216 @@
+"""Dropless top-k mixture of experts by sorted-token dispatch.
+
+The one MoE function behind ``moe/layer.MoE`` (``k > 2`` or a dropless
+config), ``models/generation._moe_mlp`` and ``serving/model_runner.
+paged_forward``: OLMoE-class routing (64 experts, top-8) that the GShard
+capacity path in ``sharded_moe.py`` cannot carry, because a one-hot over
+``[tokens, experts, capacity]`` costs ``experts / k`` times the needed
+FLOPs and a capacity drops tokens the architecture never drops.
+
+    route     float32 softmax over the router's logits, top-k (optionally
+              renormalised over the k picks)
+    dispatch  sort the ``tokens x k`` assignments by expert; gather the
+              token rows in that order; ``group_sizes [E]`` = rows an expert
+    experts   the expert MLP as grouped matmuls over the ragged groups
+              (:func:`grouped_matmul`: on TPU the Pallas ``megablox`` kernel,
+              ``gmm`` in a trace; elsewhere ``jax.lax.ragged_dot``) -- cost
+              proportional to ``tokens x k``, whatever the routing
+    combine   undo the sort, weight each pick, sum the k picks of a token
+
+No capacity, no dropped token, no array over ``experts x capacity``. The
+four stages are ``jax.named_scope``s; a trace shows them under
+``block.mlp``. Differentiable (the gathers have transposes, the grouped
+matmul a ``custom_vjp``; the sort order is integer), so the same function
+trains.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Any, Callable, Dict, NamedTuple, Optional
+
+import jax
+import jax.numpy as jnp
+
+
+class Routing(NamedTuple):
+    """What the router decided, for the caller's loss or counters."""
+    probs: jnp.ndarray         # [T, E] float32 softmax of the router
+    experts: jnp.ndarray       # [T, k] int32 the picks, best first
+    weights: jnp.ndarray       # [T, k] float32 their combine weights
+    group_sizes: jnp.ndarray   # [E] int32 rows routed to each expert
+
+
+def route_topk(logits: jnp.ndarray, k: int, renorm: bool) -> Routing:
+    """Softmax in float32, then the k largest. ``renorm`` divides the k
+    weights by their sum (HF ``norm_topk_prob``); OLMoE keeps the raw
+    probabilities."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    weights, experts = jax.lax.top_k(probs, k)
+    if renorm:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    sizes = jnp.zeros((logits.shape[-1],), jnp.int32).at[
+        experts.reshape(-1)].add(1)
+    return Routing(probs, experts.astype(jnp.int32), weights, sizes)
+
+
+def balance_stats(r: Routing) -> jnp.ndarray:
+    """``[2, E]`` float32: the share of tokens that picked each expert,
+    summed over the k pick slots, and the mean router probability. HF's
+    ``load_balancing_loss_func`` is ``E * sum(f * P)`` of these, averaged
+    over every layer's tokens first (:func:`balance_loss`)."""
+    tokens = r.probs.shape[0]
+    f = r.group_sizes.astype(jnp.float32) / tokens
+    return jnp.stack([f, jnp.mean(r.probs, axis=0)])
+
+
+def balance_loss(stats: jnp.ndarray) -> jnp.ndarray:
+    """The auxiliary loss from ``[2, E]`` stats (one layer's, or the mean
+    over layers of equally many tokens each)."""
+    return stats.shape[-1] * jnp.sum(stats[0] * stats[1])
+
+
+#: megablox tile sizes (rows, contraction, output) for 2-byte operands,
+#: measured on a v5e at OLMoE's widths (64 experts, 2048 <-> 1024; PERF.md,
+#: PR 27): one MoE block of three matmuls takes 1.17 ms at 512 rows and 1.31
+#: at 2 048 (XLA's own ragged-dot kernel 2.62 and 2.75; 128 x 128 x 128, the
+#: kernel's default, 8.6). The weight tile is 4 MB, twice buffered: a larger
+#: one does not fit the kernel's 16 MB
+_TILE_ROWS, _TILE_IN, _TILE_OUT = 128, 2048, 1024
+
+
+def _ragged_dot(rows, kernels, group_sizes):
+    return jax.lax.ragged_dot(
+        rows, kernels, group_sizes,
+        preferred_element_type=jnp.float32).astype(rows.dtype)
+
+
+def _use_megablox(interpret: bool) -> bool:
+    return interpret or jax.default_backend() == "tpu"
+
+
+def _megablox(rows, kernels, group_sizes, interpret):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+    m = rows.shape[0]
+    # float32 operands: half the contraction tile, the same bytes
+    tiling = (_TILE_ROWS,
+              min(_TILE_IN * 2 // rows.dtype.itemsize, kernels.shape[1]),
+              min(_TILE_OUT, kernels.shape[2]))
+    # the kernel walks whole row tiles; rows past the last group are not
+    # computed and not kept
+    rows = jnp.pad(rows, ((0, -m % _TILE_ROWS), (0, 0)))
+    return gmm(rows, kernels, group_sizes, preferred_element_type=rows.dtype,
+               tiling=tiling, interpret=interpret)[:m]
+
+
+def grouped_matmul_of_layer(rows: jnp.ndarray, kernels: jnp.ndarray,
+                            group_sizes: jnp.ndarray, layer: jnp.ndarray,
+                            interpret: bool = False) -> jnp.ndarray:
+    """:func:`grouped_matmul` against layer ``layer`` (traced) of kernels
+    stacked ``[L, E, in, out]``, for a layer loop that carries the whole
+    stack (inference; no gradient). The kernel is handed ALL ``L x E``
+    matrices, a free view of the stack, and group sizes that are zero but
+    for this layer's experts: it skips an empty group, so the cost is one
+    layer's. Slicing the layer out first (what ``lax.scan`` over the stack
+    does) costs a copy of the layer's experts every step, because a custom
+    call's operand cannot be a slice: 0.8 GB read and written a layer at
+    OLMoE's widths, more than the matmuls themselves (PERF.md, section 6, PR 26)."""
+    L, E = kernels.shape[:2]
+    if _use_megablox(interpret):
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((L * E,), group_sizes.dtype), group_sizes,
+            (layer * E,))
+        return _megablox(rows, kernels.reshape((L * E,) + kernels.shape[2:]),
+                         sizes, interpret)
+    return _ragged_dot(rows, jax.lax.dynamic_index_in_dim(
+        kernels, layer, 0, keepdims=False), group_sizes)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def grouped_matmul(rows: jnp.ndarray, kernels: jnp.ndarray,
+                   group_sizes: jnp.ndarray,
+                   interpret: bool = False) -> jnp.ndarray:
+    """``rows [R, in]`` sorted by group x ``kernels [E, in, out]`` ->
+    ``[R, out]`` in the rows' dtype, float32 accumulation. On TPU (or with
+    ``interpret``) the megablox kernel, elsewhere ``jax.lax.ragged_dot``;
+    the backward pass is ``ragged_dot``'s own everywhere (megablox's, at
+    these tiles, does not fit the chip's fast memory)."""
+    if _use_megablox(interpret):
+        return _megablox(rows, kernels, group_sizes, interpret)
+    return _ragged_dot(rows, kernels, group_sizes)
+
+
+def _grouped_matmul_fwd(rows, kernels, group_sizes, interpret):
+    return (grouped_matmul(rows, kernels, group_sizes, interpret),
+            (rows, kernels, group_sizes))
+
+
+def _grouped_matmul_bwd(interpret, residual, g):
+    rows, kernels, group_sizes = residual
+    # bilinear: the transposes need no forward result, so the primal
+    # ragged_dot that jax.vjp traces here is dead code to the compiler
+    _, vjp = jax.vjp(lambda r, k: _ragged_dot(r, k, group_sizes),
+                     rows, kernels)
+    return vjp(g) + (None,)
+
+
+grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
+def dropless_moe(tokens: jnp.ndarray, router_kernel: jnp.ndarray,
+                 experts: Dict[str, Any], *, k: int, renorm: bool,
+                 act: Callable, kernel_of: Optional[Callable] = None,
+                 interpret: bool = False,
+                 layer: Optional[jnp.ndarray] = None):
+    """``tokens [T, H]`` through a router and ``E`` expert MLPs, k a token.
+
+    ``experts``: ``{"fc", "proj"[, "gate"]}``, each ``{"kernel" [E, in,
+    out][, "bias" [E, out]]}`` -- the repo's stacked expert tree. With
+    ``gate`` the body is ``proj(act(gate(x)) * fc(x))`` (SwiGLU), without
+    it ``proj(act(fc(x)))``. ``kernel_of(p)`` hands a leaf's kernel in the
+    compute dtype (a cast by default; the decode path dequantizes there).
+    ``interpret`` runs the TPU kernel interpreted (CPU tests). With
+    ``layer`` (a traced index) the expert leaves are the WHOLE stack ``[L,
+    E, ...]`` and the layer is picked inside the kernel
+    (:func:`grouped_matmul_of_layer`); the router's kernel is this layer's.
+    Returns ``(y [T, H], Routing)``."""
+    T, H = tokens.shape
+    if kernel_of is None:
+        kernel_of = lambda p: p["kernel"].astype(tokens.dtype)
+    with jax.named_scope("route"):
+        # the router's few columns decide WHICH weights a token meets, so
+        # its products stay float32 on a chip whose default is one bf16 pass
+        logits = jnp.dot(tokens.astype(jnp.float32),
+                         router_kernel.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        r = route_topk(logits, k, renorm)
+    with jax.named_scope("dispatch"):
+        picks = r.experts.reshape(T * k)
+        order = jnp.argsort(picks, stable=True)       # rows, by expert
+        expert_of_row = picks[order]
+        rows = tokens[order // k]                      # [T * k, H]
+
+    def dense(x, p):
+        if layer is None:
+            y = grouped_matmul(x, kernel_of(p), r.group_sizes, interpret)
+        else:
+            y = grouped_matmul_of_layer(x, kernel_of(p), r.group_sizes,
+                                        layer, interpret)
+        if "bias" in p:
+            bias = p["bias"] if layer is None else p["bias"][layer]
+            y = y + bias.astype(y.dtype)[expert_of_row]
+        return y
+
+    with jax.named_scope("experts"):
+        if "gate" in experts:
+            hidden = act(dense(rows, experts["gate"])) * \
+                dense(rows, experts["fc"])
+        else:
+            hidden = act(dense(rows, experts["fc"]))
+        out = dense(hidden, experts["proj"])          # [T * k, H]
+    with jax.named_scope("combine"):
+        back = jnp.argsort(order)                      # row of pick (t, j)
+        picked = out[back].reshape(T, k, H)
+        y = jnp.einsum("tk,tkh->th", r.weights,
+                       picked.astype(jnp.float32)).astype(tokens.dtype)
+    return y, r

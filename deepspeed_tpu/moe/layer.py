@@ -79,11 +79,11 @@ class _Gate(nn.Module):
     experts: int
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, kernel_only: bool = False):
         k = self.param("kernel", nn.initializers.lecun_normal(),
                        (x.shape[-1], self.experts), jnp.float32)
         k = _constrain(k, None, None)
-        return x @ k
+        return k if kernel_only else x @ k
 
 
 class ExpertMLP(nn.Module):
@@ -141,9 +141,34 @@ class MoE(nn.Module):
     noisy_gate_policy: Optional[str] = None
     mlp_ratio: int = 4
     dtype: Any = jnp.bfloat16
+    # the default expert body, when ``expert`` is None: width, SwiGLU
+    # (``GatedExpertMLP``) or fc -> gelu -> proj (``ExpertMLP``), biases
+    mlp_dim: Optional[int] = None
+    gated: bool = False
+    activation: str = "silu"
+    use_bias: bool = True
+    # facts of the architecture, not speed switches: ``dropless`` routes
+    # every token to all its k experts with no capacity (always so for
+    # k > 2, which the GShard tensors cannot carry); ``norm_topk``
+    # renormalises the k weights (HF ``norm_topk_prob``)
+    dropless: bool = False
+    norm_topk: bool = True
+    # dropless only: return the ``[2, E]`` balance statistics in place of
+    # this layer's scalar loss, for a model that averages them over layers
+    aux_stats: bool = False
+
+    @property
+    def is_dropless(self) -> bool:
+        return self.dropless or self.k > 2
+
+    @property
+    def expert_width(self) -> int:
+        return self.mlp_dim or self.hidden_size * self.mlp_ratio
 
     @nn.compact
     def __call__(self, x, train: bool = False):
+        if self.is_dropless:
+            return self._dropless(x)
         B, S, H = x.shape
         E = self.num_experts
         tokens = x.reshape(B * S, H)
@@ -231,9 +256,7 @@ class MoE(nn.Module):
             queues = dispatched.transpose(1, 0, 2, 3).reshape(E, G * Cg, H)
             queues = _spec_constraint(queues, QUEUE_SPEC)
 
-        expert_factory = self.expert or (lambda: ExpertMLP(
-            self.hidden_size, self.hidden_size * self.mlp_ratio,
-            dtype=self.dtype, name="experts"))
+        expert_factory = self.expert or self._default_expert
         vexpert = nn.vmap(
             lambda mdl, inp: mdl(inp),
             variable_axes={"params": 0},
@@ -256,6 +279,85 @@ class MoE(nn.Module):
                        out_g.astype(self.dtype))
         y = _constrain(y, ("data", "expert", "seq"), None, None)
         return y.reshape(B, S, H), aux.astype(jnp.float32)
+
+
+    def _default_expert(self) -> nn.Module:
+        width = self.expert_width
+        if self.gated:
+            return GatedExpertMLP(self.hidden_size, width, dtype=self.dtype,
+                                  use_bias=self.use_bias,
+                                  activation=self.activation, name="experts")
+        return ExpertMLP(self.hidden_size, width, dtype=self.dtype,
+                         use_bias=self.use_bias, name="experts")
+
+    def _dropless(self, x):
+        """Sorted-token dispatch (``moe/dropless.py``) over the same
+        parameter tree: ``gate/kernel`` and ``experts/{gate,fc,proj}/kernel``
+        stacked ``[E, in, out]``."""
+        from ..models.transformer import _ACTIVATIONS
+        from ..parallel.mesh import get_global_mesh
+        from .dropless import balance_loss, balance_stats, dropless_moe
+        if self.expert is not None:
+            raise ValueError(
+                "dropless MoE (k > 2 or dropless=True) runs the expert body "
+                "as grouped matmuls over stacked kernels and takes no custom "
+                "`expert` module: describe it with mlp_dim / gated / "
+                "activation / use_bias")
+        mm = get_global_mesh()
+        if mm is not None and mm.shape["expert"] > 1:
+            raise NotImplementedError(
+                f"dropless MoE (k={self.k}) on an expert axis of "
+                f"{mm.shape['expert']}: expert-parallel sorted dispatch is "
+                "not implemented yet (ROADMAP R1's training half); run with "
+                "ep=1, or k <= 2 for the GShard capacity path")
+        B, S, H = x.shape
+        width = self.expert_width
+        tokens = _constrain(x.reshape(B * S, H), TOKEN_AXES, None)
+        router_kernel = _Gate(self.num_experts, name="gate")(
+            tokens, kernel_only=True)
+        names = (("gate", H, width),) if self.gated else ()
+        names += (("fc", H, width), ("proj", width, H))
+        experts = _ExpertStack(self.num_experts, names, self.use_bias,
+                               name="experts")()
+        y, r = dropless_moe(
+            tokens.astype(self.dtype), router_kernel, experts,
+            k=self.k, renorm=self.norm_topk,
+            act=_ACTIVATIONS[self.activation] if self.gated else nn.gelu)
+        y = _constrain(y, TOKEN_AXES, None).reshape(B, S, H)
+        stats = balance_stats(r)
+        return y, (stats if self.aux_stats else balance_loss(stats))
+
+
+class _ExpertStack(nn.Module):
+    """The stacked expert kernels under the names the vmapped expert
+    modules give them: ``<name>/kernel [E, in, out]`` (+ ``bias [E, out]``),
+    each expert drawn like one ``nn.Dense``."""
+    experts: int
+    names: Tuple[Tuple[str, int, int], ...]
+    use_bias: bool
+
+    @nn.compact
+    def __call__(self):
+        return {name: _StackedDense(self.experts, fan_in, out, self.use_bias,
+                                    name=name)()
+                for name, fan_in, out in self.names}
+
+
+class _StackedDense(nn.Module):
+    experts: int
+    fan_in: int
+    features: int
+    use_bias: bool
+
+    @nn.compact
+    def __call__(self):
+        p = {"kernel": self.param(
+            "kernel", nn.initializers.lecun_normal(batch_axis=(0,)),
+            (self.experts, self.fan_in, self.features), jnp.float32)}
+        if self.use_bias:
+            p["bias"] = self.param("bias", nn.initializers.zeros_init(),
+                                   (self.experts, self.features), jnp.float32)
+        return p
 
 
 def expert_parallel_apply(apply_fn: Callable,

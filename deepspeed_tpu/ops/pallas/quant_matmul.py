@@ -69,10 +69,11 @@ def pack_decode_weights(params, block: int = QUANT_BLOCK):
 
     Packs the direct matmul leaves of ``blocks`` (attn_qkv, attn_proj,
     mlp_fc/gate/proj — per-layer slices of the stacked [L, K, N] leaves)
-    plus ``lm_head``. Deliberately left alone: the MoE subtree (the
-    router gate's logits pick experts — a quantized argmax flips routing,
-    and the 3-D expert einsums ride ``_kernel_of``'s materializing tier),
-    and anything already carrying a per-channel ``kernel_scale`` pack."""
+    plus ``lm_head``. The int8 tier leaves the MoE subtree in bf16: a
+    quantized router flips picks, and neither the GShard einsums nor the
+    dropless path's grouped matmul (``moe/dropless.py``) has an int8 kernel.
+    Anything already carrying a per-channel ``kernel_scale`` pack is left
+    alone too."""
     def _pack(sub):
         if "kernel_scale" in sub or "kernel_qscale" in sub:
             return sub

@@ -64,6 +64,10 @@ _COUNTERS = (
     "admit_blocked.prefilling", "compiles",
     "kv.held_blocks_sum", "kv.blocks_reserved_sum", "kv.tokens_written_sum",
     "prefix.prompt_tokens")
+#: a dropless MoE model's router load, from the [L, E] counts that ride the
+#: tokens' own fetch (``_count_experts``); a dense model has none of these
+_MOE_COUNTERS = ("moe.assignments", "moe.layer_steps",
+                 "moe.load_max_over_mean_sum", "moe.experts_idle_sum")
 
 _KV_DTYPES = {"bf16": jnp.bfloat16, "bfloat16": jnp.bfloat16,
               "f32": jnp.float32, "float32": jnp.float32,
@@ -119,7 +123,13 @@ def step_programs(cfg, block_size: int, *, interpret: bool = False,
                   use_filters: bool = False):
     """The loop's two device programs as plain functions, ``(decode,
     prefill)``: the engine jits them with the pools donated, and
-    tests/test_chip_compile.py compiles the same two for a described chip."""
+    tests/test_chip_compile.py compiles the same two for a described chip.
+
+    For a dropless MoE config (``cfg.moe_is_dropless``) the int32 token
+    vector each returns carries, behind the tokens, the ``[L, E]`` expert
+    counts of the call, flattened (``ServingEngine._count_experts`` splits
+    them): one array, the fetch the step has already. Every other config
+    gets the programs it always got."""
     bs = int(block_size)
 
     def _pick(logits, r, temps, tks, tps):
@@ -152,6 +162,33 @@ def step_programs(cfg, block_size: int, *, interpret: bool = False,
         last = jax.lax.dynamic_index_in_dim(logits, last_idx, 1,
                                             keepdims=False)   # [1, V]
         return _pick(last, r, temps, tks, tps), pools
+
+    if cfg.moe_is_dropless:
+        return _counting_programs(cfg, bs, interpret, _pick)
+    return _decode, _prefill
+
+
+def _counting_programs(cfg, bs: int, interpret: bool, _pick):
+    """``step_programs``' pair for a dropless MoE config: the same two
+    signatures, the call's expert counts packed behind its tokens."""
+    def packed(tokens, counts):
+        return jnp.concatenate([tokens.astype(jnp.int32),
+                                counts.reshape(-1)])
+
+    def _decode(params, pools, toks, bt, ctx, r, temps, tks, tps):
+        logits, pools, counts = paged_forward(
+            cfg, params, toks[:, None], pools, bt, ctx, ctx + 1, bs,
+            interpret=interpret, expert_counts=True)
+        return packed(_pick(logits[:, -1], r, temps, tks, tps), counts), pools
+
+    def _prefill(params, pools, ids, bt, q0, ctx, last_idx, r, temps,
+                 tks, tps):
+        logits, pools, counts = paged_forward(
+            cfg, params, ids, pools, bt, q0, ctx, bs, interpret=interpret,
+            expert_counts=True)
+        last = jax.lax.dynamic_index_in_dim(logits, last_idx, 1,
+                                            keepdims=False)   # [1, V]
+        return packed(_pick(last, r, temps, tks, tps), counts), pools
 
     return _decode, _prefill
 
@@ -248,6 +285,12 @@ class ServingEngine:
         self.rec = telemetry.Recorder("serve")
         self.stats: Dict[str, int] = self.rec.counters
         self.stats.update(dict.fromkeys(_COUNTERS, 0))
+        # a dropless MoE model only: the outputs of calls whose tokens
+        # nobody fetched (a prompt's middle chunks) stay on the device with
+        # their expert counts and ride the next fetch
+        self._moe_pending: List[Any] = []
+        if cfg.moe_is_dropless:
+            self.stats.update(dict.fromkeys(_MOE_COUNTERS, 0))
         self._shared = shared if shared is not None else SharedPagedState(
             cfg, serving, dtype=kv_dtype, counters=self.stats)
         self.scheduler = Scheduler(self.pool, serving.max_queue,
@@ -300,6 +343,25 @@ class ServingEngine:
         """One jitted call over the live pool buffers (donation-safe
         under the shared state's device lock)."""
         return self._shared.run(fn, self.params, *args)
+
+    def _count_experts(self, out: np.ndarray) -> None:
+        """A dropless MoE model's router load, from a fetched output: the
+        call's ``[L, E]`` expert counts sit behind its tokens
+        (``step_programs``). Those of the calls nobody fetched are counted
+        with it: they were made before ``out``, so fetching them waits for
+        nothing more."""
+        got = [out] + [np.asarray(a) for a in self._moe_pending]
+        self._moe_pending = []
+        E, c = self.cfg.moe_experts, self.stats
+        for a in got:
+            counts = a[len(a) - self.cfg.num_layers * E:].reshape(-1, E)
+            routed = counts.sum(axis=1)
+            live = routed > 0               # a call of padding only: nothing
+            c["moe.assignments"] += int(routed.sum())
+            c["moe.layer_steps"] += int(live.sum())
+            c["moe.load_max_over_mean_sum"] += float(
+                (counts.max(axis=1)[live] * E / routed[live]).sum())
+            c["moe.experts_idle_sum"] += int((counts[live] == 0).sum())
 
     # ------------------------------------------------------------- submission
 
@@ -742,12 +804,16 @@ class ServingEngine:
         req.prefill_progress = pf.done
         self.stats["prefill_tokens"] += n
         if pf.done < pf.total:
+            if self.cfg.moe_is_dropless:
+                self._moe_pending.append(tok)
             return 0                      # sampled token of a mid-chunk
             #                               call is discarded — only the
             #                               final chunk's is real
         self._prefilling = None
         with rec.span("serve.prefill.fetch"):
             first = int(np.asarray(tok)[0])
+            if self.cfg.moe_is_dropless:
+                self._count_experts(np.asarray(tok))
         return self._first_token(_Seq(req, pf.blocks, pf.table, pf.total,
                                       first),
                                  insert=not self._warming)
@@ -834,6 +900,8 @@ class ServingEngine:
             raise
         with rec.span("serve.prefill.fetch"):
             first = int(np.asarray(tok)[0])
+            if self.cfg.moe_is_dropless:
+                self._count_experts(np.asarray(tok))
         req.prefill_progress = P
         self.stats["prefill_tokens"] += len(suffix)
         return self._first_token(_Seq(req, blocks, table, P, first))
@@ -870,6 +938,8 @@ class ServingEngine:
             nxt = self._run_device(self._decode_fn, *args)
         with rec.span("serve.decode.fetch"):
             nxt = np.asarray(nxt)
+            if self.cfg.moe_is_dropless:
+                self._count_experts(nxt)
         done = 0
         with rec.span("serve.decode.bookkeep"):
             for i, s in enumerate(self._slots):

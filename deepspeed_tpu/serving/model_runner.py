@@ -36,7 +36,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.generation import _dense, _kv_quantize, _layer_norm, _moe_mlp
+from ..models.generation import (_dense, _kv_quantize, _layer_norm, _moe_mlp,
+                                 _qk_norm, split_stacked_experts)
 from ..models.transformer import TransformerConfig
 from ..ops.attention import paged_attention
 from .kv_cache import NULL_BLOCK
@@ -106,10 +107,15 @@ def paged_forward(cfg: TransformerConfig,
                   context_lens: jnp.ndarray,
                   block_size: int,
                   *,
-                  interpret: bool = False
-                  ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+                  interpret: bool = False,
+                  expert_counts: bool = False
+                  ) -> Tuple[jnp.ndarray, ...]:
     """Run T tokens per lane at logical positions [q_start, q_start + T)
-    against the paged pool. Returns (logits [B, T, V] f32, updated pools).
+    against the paged pool. Returns (logits [B, T, V] f32, updated pools)
+    and, with ``expert_counts`` (a dropless MoE config only), ``[L, E]``
+    int32: how many of this call's REAL tokens each layer's router sent to
+    each expert (padding positions and lanes with no sequence are routed and
+    computed like any row, and not counted).
 
     input_ids: [B, T]. pools: {"k","v"} [L, nh, num_slots, hd]
     (``serving.kv_cache.init_pool`` layout; ``num_slots`` = pool blocks x
@@ -224,6 +230,13 @@ def paged_forward(cfg: TransformerConfig,
     phys = jnp.take_along_axis(bt, jnp.clip(lblk, 0, nbk - 1), axis=1)
     phys = jnp.where(keep.any(axis=2), phys, NULL_BLOCK)            # [B, J]
     plan = _WritePlan(bs, off, phys, keep)
+    if expert_counts:
+        if not cfg.moe_is_dropless:
+            raise ValueError("expert_counts needs a dropless MoE config")
+        # a real token: a query below its lane's context, in a lane that
+        # holds a sequence (an idle decode lane's table is all null)
+        real = ((pos < ctx[:, None])
+                & (bt[:, :1] != NULL_BLOCK)).astype(jnp.int32)   # [B, T]
 
     def layer(carry, xs):
         x, kv = carry
@@ -236,12 +249,9 @@ def paged_forward(cfg: TransformerConfig,
                                     axis=-1)
                 to_heads = lambda t, n: t.reshape(B, T, n, hd).transpose(
                     0, 2, 1, 3)
+                q, k = _qk_norm(cfg, p, q, k, "projection")
                 q, k, v = to_heads(q, nh), to_heads(k, kvh), to_heads(v, kvh)
-                if cfg.qk_norm:
-                    q = _layer_norm(q, p["q_norm"], cfg.layer_norm_eps,
-                                    rms=True)
-                    k = _layer_norm(k, p["k_norm"], cfg.layer_norm_eps,
-                                    rms=True)
+                q, k = _qk_norm(cfg, p, q, k, "head")
                 if cfg.pos_embed == "rotary":
                     # table covers the pool's per-sequence maximum (nbk *
                     # bs) — plain-theta tables are length-independent, so
@@ -295,9 +305,25 @@ def paged_forward(cfg: TransformerConfig,
                     attn_out = _layer_norm(attn_out, p["post_attn_norm"],
                                            cfg.layer_norm_eps, rms)
 
+        counts = None
+
         def mlp(hin):
+            nonlocal counts
+            if experts is not None:
+                # a dropless mixture: the expert stack stays whole beside
+                # the loop, the kernel picks this layer's
+                y, routing = _moe_mlp(cfg, dict(p["moe"], experts=experts),
+                                      hin, with_routing=True,
+                                      interpret=interpret, layer=li)
+                if not expert_counts:
+                    return y
+                with jax.named_scope("route"):
+                    counts = jnp.zeros((cfg.moe_experts,), jnp.int32).at[
+                        routing.experts.reshape(-1)].add(
+                        jnp.repeat(real.reshape(-1), cfg.moe_k))
+                return y
             if cfg.moe_experts > 0:
-                return _moe_mlp(cfg, p["moe"], hin)
+                return _moe_mlp(cfg, p["moe"], hin, interpret=interpret)
             if cfg.gated_mlp:
                 g = act(dense(hin, p["mlp_gate"]))
                 return dense(g * dense(hin, p["mlp_fc"]), p["mlp_proj"])
@@ -316,9 +342,10 @@ def paged_forward(cfg: TransformerConfig,
                     m = _layer_norm(m, p["post_mlp_norm"],
                                     cfg.layer_norm_eps, rms)
                 x_out = x_mid + m
-        return (x_out, kv_new), None
+        return (x_out, kv_new), counts
 
-    xs = (params["blocks"], windows, jnp.arange(cfg.num_layers))
+    blocks, experts = split_stacked_experts(cfg, params["blocks"])
+    xs = (blocks, windows, jnp.arange(cfg.num_layers))
     # the loop carries the pools as the kernel reads them, a block's slots
     # on an axis of their own: for K/V a free view of init_pool's flat slot
     # axis; the int8 tier's small scale pools change layout here, at the
@@ -329,7 +356,7 @@ def paged_forward(cfg: TransformerConfig,
                       else pool.reshape(L, nh, nb_pool, bs, hd))
                for name, pool in pools.items()}
     with jax.named_scope("layers"):
-        (x, kv_out), _ = jax.lax.scan(layer, (x, blocked), xs)
+        (x, kv_out), counts = jax.lax.scan(layer, (x, blocked), xs)
     kv_out = {name: pool.reshape(pools[name].shape)
               for name, pool in kv_out.items()}
     with jax.named_scope("head"):
@@ -341,4 +368,6 @@ def paged_forward(cfg: TransformerConfig,
         if cfg.final_logit_softcap:
             from ..ops.attention import apply_softcap
             logits = apply_softcap(logits, cfg.final_logit_softcap)
+    if expert_counts:
+        return logits.astype(jnp.float32), kv_out, counts
     return logits.astype(jnp.float32), kv_out

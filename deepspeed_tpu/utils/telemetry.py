@@ -239,7 +239,8 @@ def span_times(entries: List[Entry], since_ns: int = 0
 #: model_runner.py``, the step functions of ``runtime/engine.py``)
 SCOPES = frozenset({
     "embed", "layers", "block.attn", "qkv", "kv_write", "attend", "out",
-    "block.mlp", "head", "loss", "sample", "grad_accum", "optimizer",
+    "block.mlp", "route", "dispatch", "experts", "combine",
+    "head", "loss", "sample", "grad_accum", "optimizer",
     "zero.gather", "zero.scatter"})
 
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s.*?"

@@ -1190,3 +1190,70 @@ def test_hf_qwen2_sliding_window_gating():
     ours = np.asarray(model.apply({"params": params},
                                   {"input_ids": jnp.asarray(ids)}))
     np.testing.assert_allclose(ours, ref, rtol=4e-3, atol=4e-3)
+
+
+def test_hf_olmoe_parity_and_greedy():
+    """OLMoE: MHA with an RMSNorm over the WHOLE projected q and k vectors,
+    64-class routing in small (8 SwiGLU experts, top-4 of a softmax, the
+    weights NOT renormalised, nothing dropped): HF ``mlp.gate`` +
+    ``mlp.experts.{j}.{gate,up,down}_proj`` -> ``moe.gate`` +
+    ``moe.experts.{gate,fc,proj}`` stacked [L, E, in, out], served by the
+    sorted-token dispatch of moe/dropless.py. Norm scales are forced away
+    from 1 first (a loader that dropped q_norm would still pass fresh-init
+    parity). Logits, the load-balancing loss and token-exact greedy decode
+    vs HF."""
+    import dataclasses
+    from deepspeed_tpu.models.generation import generate
+    torch.manual_seed(41)
+    hf = transformers.OlmoeForCausalLM(transformers.OlmoeConfig(
+        vocab_size=96, hidden_size=32, intermediate_size=24,
+        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=4,
+        num_experts=8, num_experts_per_tok=4, norm_topk_prob=False,
+        max_position_embeddings=64, router_aux_loss_coef=0.01)).eval()
+    with torch.no_grad():
+        for layer in hf.model.layers:
+            for norm in (layer.self_attn.q_norm, layer.self_attn.k_norm,
+                         layer.input_layernorm,
+                         layer.post_attention_layernorm):
+                norm.weight.normal_(mean=1.0, std=0.3)
+    ids = np.random.default_rng(41).integers(0, 96, (2, 20))
+    with torch.no_grad():
+        out = hf(torch.tensor(ids), output_router_logits=True,
+                 labels=torch.tensor(ids))
+    params, cfg = load_hf(hf)
+    assert cfg.moe_experts == 8 and cfg.moe_k == 4 and cfg.gated_mlp
+    assert cfg.moe_is_dropless and not cfg.moe_norm_topk
+    assert cfg.qk_norm == "projection" and cfg.kv_heads == 4
+    sd = hf.state_dict()
+    blocks = params["blocks"]
+    # names, transposes, [L, E, in, out] stacking, both norms at full width
+    assert blocks["moe"]["gate"]["kernel"].shape == (2, 32, 8)
+    assert blocks["moe"]["experts"]["gate"]["kernel"].shape == (2, 8, 32, 24)
+    assert blocks["moe"]["experts"]["proj"]["kernel"].shape == (2, 8, 24, 32)
+    np.testing.assert_array_equal(
+        np.asarray(blocks["moe"]["experts"]["fc"]["kernel"][1, 5]),
+        sd["model.layers.1.mlp.experts.5.up_proj.weight"].numpy().T)
+    np.testing.assert_array_equal(
+        np.asarray(blocks["moe"]["gate"]["kernel"][0]),
+        sd["model.layers.0.mlp.gate.weight"].numpy().T)
+    assert blocks["q_norm"]["scale"].shape == (2, 32) == \
+        blocks["k_norm"]["scale"].shape
+    np.testing.assert_array_equal(
+        np.asarray(blocks["k_norm"]["scale"][1]),
+        sd["model.layers.1.self_attn.k_norm.weight"].numpy())
+    model = Transformer(dataclasses.replace(cfg, dtype=jnp.float32,
+                                            attention_impl="reference"))
+    ours, aux = model.apply({"params": params},
+                            {"input_ids": jnp.asarray(ids)})
+    np.testing.assert_allclose(np.asarray(ours), out.logits.numpy(),
+                               rtol=4e-3, atol=4e-3)
+    # HF's load_balancing_loss_func over both layers' tokens together
+    np.testing.assert_allclose(float(aux), float(out.aux_loss), rtol=1e-4)
+    pids = np.random.default_rng(42).integers(0, 96, (2, 10))
+    with torch.no_grad():
+        gref = hf.generate(torch.tensor(pids), max_new_tokens=8,
+                           do_sample=False).numpy()
+    gcfg = dataclasses.replace(cfg, dtype=jnp.float32,
+                               attention_impl="reference")
+    np.testing.assert_array_equal(
+        np.asarray(generate(gcfg, params, jnp.asarray(pids), 8)), gref)

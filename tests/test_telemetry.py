@@ -264,6 +264,92 @@ def test_a_request_has_four_stamps_in_order(served):
             assert (f"serve.req.{what}", r.rid) in events
 
 
+# ------------------------------------------------- a mixture of experts
+
+
+@pytest.fixture(scope="module")
+def served_moe():
+    """A tiny dropless MoE (6 experts, top-3) served with chunked prefill
+    (prompts of two and three chunks: their middle chunks' tokens are never
+    fetched) beside every fetch the engine made."""
+    from deepspeed_tpu.models import TransformerConfig
+    from deepspeed_tpu.parallel import mesh as mesh_mod
+    # an earlier test of this worker may have left a mesh with an expert
+    # axis behind, on which a dropless mixture refuses to build
+    mesh_mod.set_global_mesh(mesh_mod.MeshManager(devices=jax.devices()[:1]))
+    model, cfg = build_model(TransformerConfig(
+        vocab_size=64, hidden_size=32, num_layers=2, num_heads=2,
+        max_seq_len=256, mlp_dim_override=24, gated_mlp=True,
+        activation="silu", norm="rmsnorm", pos_embed="rotary",
+        use_bias=False, tie_embeddings=False, moe_experts=6, moe_k=3,
+        moe_norm_topk=False, dtype=jnp.float32))
+    params = model.init(jax.random.PRNGKey(1),
+                        {"input_ids": np.zeros((1, 8), np.int32)})["params"]
+    srv = ServingEngine(cfg, params, serving={
+        "block_size": 16, "pool_blocks": 24, "max_batch": 3,
+        "max_blocks_per_seq": 8, "prefill_chunk_tokens": 32})
+    fetched = []
+    count = srv._count_experts
+    srv._count_experts = lambda out: fetched.append(out) or count(out)
+    rng = np.random.default_rng(3)
+    sizes = [(40, 6), (70, 9), (20, 5)]
+    reqs = [srv.submit(list(rng.integers(1, 64, size=n)), max_new_tokens=k)
+            for n, k in sizes]
+    srv.run_until_idle()
+    return srv, cfg, reqs, sizes, fetched
+
+
+def test_a_served_moe_counts_its_routers_load(served_moe):
+    srv, cfg, reqs, sizes, fetched = served_moe
+    L, E, k = cfg.num_layers, cfg.moe_experts, cfg.moe_k
+    c = srv.telemetry()["counters"]
+    # every real token met k experts in every layer, padding none: prompt
+    # tokens once, generated tokens but each request's last (never fed back)
+    real = sum(n + new - 1 for n, new in sizes)
+    assert c["moe.assignments"] == real * k * L
+    # one (call, layer) pair for each device call that held a real token
+    calls = sum(-(-n // 32) for n, _ in sizes) + srv.stats["steps"]
+    assert 0 < c["moe.layer_steps"] <= calls * L
+    assert c["moe.layer_steps"] % L == 0
+    # the fullest expert over the mean is between 1 and E / k ... E
+    mean_load = c["moe.load_max_over_mean_sum"] / c["moe.layer_steps"]
+    assert 1.0 <= mean_load <= E
+    assert 0 <= c["moe.experts_idle_sum"] <= (E - k) * c["moe.layer_steps"]
+    # a step's ring entry carries the gains like any counter's
+    gains = sum(e[4].get("d", {}).get("moe.assignments", 0)
+                for e in srv.rec.ring if e[0] == "serve.step")
+    assert gains == c["moe.assignments"]
+
+
+def test_moe_counts_ride_the_tokens_own_fetch(served_moe):
+    srv, cfg, reqs, sizes, fetched = served_moe
+    L, E = cfg.num_layers, cfg.moe_experts
+    # one int32 vector a fetch: the tokens, then the [L, E] counts
+    for out in fetched:
+        assert out.dtype == np.int32
+        assert out.size - L * E in (1, srv.max_batch)   # a prompt's, a step's
+    # as many fetches as a dense engine makes: one a decode step, one a
+    # prompt's LAST chunk; the middle chunks' counts waited on the device
+    spans = [e[0] for e in srv.rec.ring if e[0].endswith(".fetch")]
+    assert len(fetched) == len(spans)
+    assert spans.count("serve.prefill.fetch") == len(reqs)
+    assert not srv._moe_pending
+    assert all(r.state == "FINISHED" or r.done for r in reqs)
+
+
+def test_a_dense_engines_fetch_is_what_it_was(served, tiny_lm):
+    srv, reqs, outside = served
+    assert not [k for k in srv.stats if k.startswith("moe.")]
+    cfg, params = tiny_lm
+    eng = ServingEngine(cfg, params, serving={
+        "block_size": 16, "pool_blocks": 24, "max_batch": 3,
+        "max_blocks_per_seq": 8})
+    fn, args = decode_program(eng)
+    out, _ = fn.lower(*args).compile().out_info
+    assert out.shape == (3,)                    # the tokens and nothing else
+    assert not eng._moe_pending
+
+
 # ----------------------------------------------------------------- training
 
 
