@@ -1,22 +1,43 @@
-"""Pallas paged-attention decode kernel — KV blocks gathered via block table.
+"""Pallas paged-attention decode kernel — KV pages copied via block table.
 
 The serving subsystem (deepspeed_tpu/serving/) keeps the KV cache as a POOL
-of fixed-size blocks shared by every in-flight sequence; a per-sequence
-*block table* maps logical block j to a physical pool block. The decode
-step then needs attention of one fresh query token per sequence against a
-K/V that is physically scattered across the pool. This kernel performs the
-gather INSIDE the pipeline: the K/V BlockSpec index_map reads the block
-table (a prefetched scalar array) to pick the physical block for grid step
-j, so the only HBM traffic is the ``ceil(ctx_len / block_size)`` live
-blocks of each sequence — no materialized per-sequence contiguous copy,
-and per-token cost scales with the tokens each sequence has generated, not
-with the pool size.
+of fixed-size blocks (pages) shared by every in-flight sequence; a
+per-sequence *block table* maps logical page j to a physical pool block. The
+decode step then needs attention of one fresh query token per sequence
+against a K/V that is physically scattered across the pool. This kernel
+walks only the pages a sequence HOLDS: the grid is (sequences, head groups),
+the pools stay in HBM, and a program loops over its sequence's
+``ceil(ctx_len / block_size)`` live pages (from the window's first page when
+a sliding window is set), ``P`` pages a turn. It copies each page's
+``[heads, block_size, head_dim]`` out of the pool itself (the block table is
+a prefetched scalar array), into one of two VMEM buffers: while a group of
+``P * block_size`` keys is computed the next group is in flight, and a
+sequence's last turn starts the next sequence's first group, so only the
+call's very first copy is waited for with nothing to do. No materialized
+per-sequence contiguous copy, no step for a table entry no sequence uses
+(until PR 28 the table's length was a grid axis: an idle lane and a short
+sequence cost as many grid steps as a full one), and per-token cost scales
+with the tokens each sequence has generated, not with the pool or the table.
+
+``P`` is a function of the operand shapes alone (:func:`_pages_per_group`:
+the largest power of two whose four group buffers fit
+:data:`_VMEM_BUDGET`, at most the table's length).
+
+That is :func:`_loop_kernel`, for heads of whole 128-lane tiles. A pool of
+narrower heads (``head_dim % 128``: 64, 80, 96) is padded to 128 lanes a row
+in HBM by the chip's compiler, which then refuses a kernel's own copy of the
+unpadded part; such pools take :func:`_grid_kernel`, the form every pool
+took until PR 28: the pages come through the pipeline (the K/V BlockSpec's
+index_map reads the block table), one a grid step along a third axis as long
+as the table, dead steps clamped to the last live page. Same math
+(:func:`_attend`), chosen from the shape, no switch.
 
 Capability slot of the reference's fused ``softmax_context`` decode kernels
 (csrc/transformer/inference/csrc/pt_binding.cpp:1703-1779) generalized to
-the vLLM-style paged layout; the mechanics (clamped index_map elides dead
-copies, ``@pl.when`` skips dead FLOPs, online-softmax scratch carries
-m/l across blocks) are shared with ops/pallas/decode_attention.py.
+the vLLM-style paged layout; the online-softmax scratch that carries m/l
+across groups is shared in kind with ops/pallas/decode_attention.py, the
+multi-page double-buffered copy with jax's own
+``pallas/ops/tpu/paged_attention`` kernel.
 
 In-kernel score features (parity with the flash/decode kernels): ALiBi via
 per-head slopes, Gemma-2 tanh softcap, causal masking by per-sequence
@@ -29,7 +50,11 @@ The pool operand is row-major ``[L?, nh, num_blocks, block_size, hd]`` and
 the kernel reads it where it lies. Whoever writes the pool has to leave it
 so: ``serving.model_runner`` updates it in place with dynamic-update-slices
 because the layout the chip's compiler gives a scatter's operand (slots
-major) had the whole pool copied to this one before every call.
+major) had the whole pool copied to this one before every call. The int8
+tier's scales are read as ``[L?, nh, num_blocks, 1, lanes]``
+(:func:`scale_rows`), a page's slots on the first lanes of whole 128-lane
+tiles: the chip pads a ``(1, block_size)`` float32 row in HBM to that anyway
+and lets a kernel copy no less.
 """
 
 from __future__ import annotations
@@ -46,85 +71,252 @@ from jax.experimental.pallas import tpu as pltpu
 from .decode_attention import _head_group
 from .flash_attention import NEG_INF
 
-__all__ = ["paged_attention", "paged_attention_reference", "untileable"]
+__all__ = ["paged_attention", "paged_attention_reference", "scale_rows",
+           "untileable"]
 
 #: query rows per program — a single decode token is broadcast to the
 #: sublane minimum so every operand is a legal (>=8)x128 tile
 _QROWS = 8
 
+#: VMEM the K and V group buffers (two slots each) may take together
+_VMEM_BUDGET = 4 << 20
 
-def _kernel(bt_ref, lens_ref, misc_ref, q_ref, k_ref, v_ref, *rest, hg, bs,
-            nbk, sm_scale, softcap, has_alibi, stacked, quant):
+
+def _scale_lanes(bs: int) -> int:
+    """Lanes of one page's scale row as the kernel copies it: whole
+    128-lane tiles. The chip's compiler pads a (1, bs) float32 row in HBM
+    to that anyway and refuses a manual copy of less than the padded row."""
+    return -(-bs // 128) * 128
+
+
+def scale_rows(scale, pool_shape) -> jnp.ndarray:
+    """The int8 tier's scales ``[..., num_blocks, 1, lanes]``, a page's
+    ``block_size`` slots on the first lanes of a row of
+    :func:`_scale_lanes`: as they are when they come so
+    (``serving.model_runner`` carries them so through its layer loop), else
+    from any shape that reshapes to ``[..., num_blocks, block_size]``,
+    padded here (a copy of the whole scale pool)."""
+    bs = pool_shape[-2]
+    scale = jnp.asarray(scale, jnp.float32)
+    rows = pool_shape[:-2] + (1, _scale_lanes(bs))
+    if scale.shape == rows:
+        return scale
+    scale = scale.reshape(pool_shape[:-2] + (1, bs))
+    return jnp.pad(scale, [(0, 0)] * (scale.ndim - 1) + [(0, rows[-1] - bs)])
+
+
+def _pages_per_group(hg: int, bs: int, hd: int, itemsize: int, nbk: int,
+                     quant: bool = False) -> int:
+    """Pages of one copy group, from the operand shapes alone: the largest
+    power of two whose K and V group buffers, two slots each, stay inside
+    :data:`_VMEM_BUDGET` (the int8 tier's scale rows counted as the padded
+    (8, 128) float32 tiles they may take in VMEM), never more than the
+    table holds."""
+    page = 4 * hg * bs * hd * itemsize            # K and V, two slots
+    if quant:
+        page += 4 * hg * 8 * _scale_lanes(bs) * 4
+    p = 1
+    while 2 * p * page <= _VMEM_BUDGET and 2 * p <= nbk:
+        p *= 2
+    return p
+
+
+def _attend(q, k, v, ks, vs, k0, ctx, window, slopes_ref, acc, m_scr, l_scr,
+            *, sm_scale, softcap):
+    """One online-softmax update: the query ``q`` [hg, 8, hd] against the
+    keys ``k`` / values ``v`` [hg, n, hd] at logical positions
+    ``[k0, k0 + n)``, folded into the running max, sum and output."""
+    if ks is not None:
+        # int8 tier (round 17): the copies moved int8 rows + one f32 scale
+        # per (head, slot); dequantize HERE, on the keys already in VMEM —
+        # only int8 crossed HBM. The scales arrive [hg, 1, n] (slots on
+        # the LANE axis, the layout the chip's compiler takes), which is
+        # the score tile's own layout: q.(k_i * s_i) == (q.k_i) * s_i, so
+        # the K scale multiplies the scores and the V scale the
+        # probabilities — no lane->sublane relayout, and the int8 ->
+        # q.dtype convert is exact (|int8| <= 127)
+        k = k.astype(jnp.float32).astype(q.dtype)
+        v = v.astype(jnp.float32).astype(q.dtype)
+    s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+                            preferred_element_type=jnp.float32)
+    if ks is not None:
+        s = s * ks
+    s = s * sm_scale
+    if softcap:
+        s = jnp.tanh(s / softcap) * softcap
+    # one real query at absolute (logical) position ctx - 1, broadcast over
+    # the 8 padded rows; the keys' logical positions do not depend on which
+    # PHYSICAL pages the table routed the copies to
+    q_abs = ctx - 1
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+    if slopes_ref is not None:
+        slope = slopes_ref[0][:, :1][:, None, :]            # [hg, 1, 1]
+        s = s + slope * (k_pos - q_abs).astype(jnp.float32)
+    keep = k_pos <= q_abs                                   # causal + dead tail
+    keep &= (q_abs - k_pos < window) | (window <= 0)        # sliding window
+    s = jnp.where(keep, s, NEG_INF)
+    m_prev = m_scr[:, :, :1]
+    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+    alpha = jnp.exp(m_prev - m_cur)
+    p = jnp.exp(s - m_cur)
+    l_scr[:, :, :1] = l_scr[:, :, :1] * alpha + jnp.sum(p, axis=2,
+                                                        keepdims=True)
+    pv = p * vs if vs is not None else p
+    acc[...] = acc[...] * alpha + jax.lax.dot_general(
+        pv.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)
+    m_scr[:, :, :1] = m_cur
+
+
+def _finish(o_ref, acc, l_scr):
+    l = l_scr[:, :, :1]
+    o_ref[0, 0] = (acc[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
+def _grid_kernel(bt_ref, lens_ref, misc_ref, q_ref, k_ref, v_ref, *rest, bs,
+                 nbk, sm_scale, softcap, has_alibi, stacked, quant):
+    """Heads narrower than a lane tile (``hd % 128``): the chip's compiler
+    pads such a pool's rows to 128 lanes in HBM and refuses a kernel's own
+    copy of less than a padded row, so the pages come through the
+    pipeline, one a grid step along a third axis as long as the table. A
+    dead step (``j >= cnt``) repeats its sequence's last live page, which
+    the pipeline does not copy again, and computes nothing; it still
+    costs its 0.2-0.3 us (PERF.md, PR 28), which is why wider heads take
+    :func:`_loop_kernel`."""
     if quant:
         ks_ref, vs_ref, slopes_ref, o_ref, acc, m_scr, l_scr = rest
     else:
         slopes_ref, o_ref, acc, m_scr, l_scr = rest
     b, j = pl.program_id(0), pl.program_id(2)
     ctx = lens_ref[b]
-    window = misc_ref[0]
-    cnt = (ctx + bs - 1) // bs                    # live blocks of seq b
 
     @pl.when(j == 0)
     def _init():
-        acc[:] = jnp.zeros_like(acc)
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
+        acc[...] = jnp.zeros_like(acc)
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
 
-    @pl.when(j < cnt)
+    @pl.when(j < (ctx + bs - 1) // bs)
     def _compute():
-        q = q_ref[0, 0]                                     # [hg, 8, hd]
-        k = k_ref[0, :, 0] if stacked else k_ref[:, 0]      # [hg, bs, hd]
-        v = v_ref[0, :, 0] if stacked else v_ref[:, 0]
+        page = lambda ref: ref[0, :, 0] if stacked else ref[:, 0]
+        ks = vs = None
         if quant:
-            # int8 tier (round 17): the DMA moved int8 rows + one f32
-            # scale per (head, slot); dequantize HERE, on the block
-            # already in VMEM — only int8 crossed HBM. The scale rows
-            # arrive [hg, 1, bs] (slots on the LANE axis, the layout the
-            # chip's compiler takes), which is the score tile's own
-            # layout: q.(k_i * s_i) == (q.k_i) * s_i, so the K scale
-            # multiplies the scores and the V scale the probabilities —
-            # no lane->sublane relayout, and the int8 -> q.dtype convert
-            # is exact (|int8| <= 127)
-            ks = ks_ref[0, :, 0] if stacked else ks_ref[:, 0]   # [hg, 1, bs]
-            vs = vs_ref[0, :, 0] if stacked else vs_ref[:, 0]
-            k = k.astype(jnp.float32).astype(q.dtype)
-            v = v.astype(jnp.float32).astype(q.dtype)
-        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
-                                preferred_element_type=jnp.float32)
-        if quant:
-            s = s * ks
-        s = s * sm_scale
-        if softcap:
-            s = jnp.tanh(s / softcap) * softcap
-        # one real query at absolute (logical) position ctx - 1, broadcast
-        # over the 8 padded rows; keys of block j cover logical positions
-        # [j*bs, (j+1)*bs) regardless of which PHYSICAL block the table
-        # routed the DMA to
-        q_abs = ctx - 1
-        k_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        if has_alibi:
-            slope = slopes_ref[0][:, :1][:, None, :]        # [hg, 1, 1]
-            s = s + slope * (k_pos - q_abs).astype(jnp.float32)
-        keep = k_pos <= q_abs                               # causal + dead tail
-        keep &= (q_abs - k_pos < window) | (window <= 0)    # sliding window
-        s = jnp.where(keep, s, NEG_INF)
-        m_prev = m_scr[:, :, :1]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
-        alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur)
-        l_scr[:, :, :1] = (l_scr[:, :, :1] * alpha
-                           + jnp.sum(p, axis=2, keepdims=True))
-        pv = p * vs if quant else p
-        acc[:] = acc[:] * alpha + jax.lax.dot_general(
-            pv.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        m_scr[:, :, :1] = m_cur
+            ks, vs = page(ks_ref)[:, :, :bs], page(vs_ref)[:, :, :bs]
+        _attend(q_ref[0, 0], page(k_ref), page(v_ref), ks, vs, j * bs, ctx,
+                misc_ref[0], slopes_ref if has_alibi else None, acc, m_scr,
+                l_scr, sm_scale=sm_scale, softcap=softcap)
 
     @pl.when(j == nbk - 1)
     def _finalize():
-        l = l_scr[:, :, :1]
-        o_ref[0, 0] = (acc[:] / jnp.where(l == 0.0, 1.0, l)).astype(
-            o_ref.dtype)
+        _finish(o_ref, acc, l_scr)
+
+
+def _loop_kernel(bt_ref, lens_ref, misc_ref, q_ref, k_hbm, v_hbm, *rest, hg,
+                 bs, P, nbk, sm_scale, softcap, has_alibi, stacked, quant):
+    if quant:
+        (ks_hbm, vs_hbm, slopes_ref, o_ref, k_buf, v_buf, ks_buf, vs_buf,
+         acc, m_scr, l_scr, state, sem) = rest
+    else:
+        slopes_ref, o_ref, k_buf, v_buf, acc, m_scr, l_scr, state, sem = rest
+    b, g = pl.program_id(0), pl.program_id(1)
+    nb, ng = pl.num_programs(0), pl.num_programs(1)
+    window, layer = misc_ref[0], misc_ref[1]
+
+    def span(b):
+        """Sequence b's first and one-past-last live page, and the groups of
+        P pages that hold them. A window drops the pages wholly before it;
+        an idle lane (ctx 0) has no group at all."""
+        ctx = lens_ref[b]
+        cnt = jnp.minimum((ctx + bs - 1) // bs, nbk)
+        first = jnp.where(window > 0, jnp.maximum(ctx - window, 0) // bs, 0)
+        return first, cnt, first // P, (cnt + P - 1) // P
+
+    def copies(b, g, first, cnt, i, slot):
+        """(live, copy) of every page of sequence b's group i into buffer
+        ``slot``: the heads of group g, one physical page of one layer,
+        out of the pool where it lies. Starting and waiting rebuild the
+        same descriptors."""
+        heads = pl.ds(g * hg, hg)
+        out = []
+        for p in range(P):
+            page = i * P + p
+            live = (page >= first) & (page < cnt)
+            phys = bt_ref[b, jnp.minimum(page, nbk - 1)]
+            at = (layer, heads, phys) if stacked else (heads, phys)
+            rows = pl.ds(p * bs, bs)
+            pairs = [(k_hbm, k_buf.at[slot, :, rows], 0),
+                     (v_hbm, v_buf.at[slot, :, rows], 1)]
+            if quant:
+                pairs += [(ks_hbm, ks_buf.at[slot, :, p], 0),
+                          (vs_hbm, vs_buf.at[slot, :, p], 1)]
+            out += [(live, pltpu.make_async_copy(pool.at[at], dst,
+                                                 sem.at[s, slot]))
+                    for pool, dst, s in pairs]
+        return out
+
+    def start(*group):
+        for live, copy in copies(*group):
+            pl.when(live)(copy.start)
+
+    def wait(*group):
+        for live, copy in copies(*group):
+            pl.when(live)(copy.wait)
+
+    first, cnt, g0, g1 = span(b)
+    # the program that runs next: its first group is started from this
+    # one's last turn of the loop, so only the call's very first group is
+    # waited for with nothing to compute
+    g_nxt = jnp.where(g + 1 < ng, g + 1, 0)
+    b_nxt = jnp.minimum(jnp.where(g + 1 < ng, b, b + 1), nb - 1)
+    first_nxt, cnt_nxt, n0, n1 = span(b_nxt)
+    has_nxt = ((b + 1 < nb) | (g + 1 < ng)) & (n0 < n1)
+
+    @pl.when((b == 0) & (g == 0))
+    def _first_program():
+        # a page the loop skips keeps what the buffer held: masked scores
+        # give it probability 0, and 0 times a stale NaN is a NaN
+        v_buf[...] = jnp.zeros_like(v_buf)
+        if quant:
+            vs_buf[...] = jnp.zeros_like(vs_buf)
+        state[0] = 0        # the slot this program's loop starts in
+        state[1] = 0        # 1: the program before started its first group
+
+    slot0 = state[0]
+
+    @pl.when((g0 < g1) & (state[1] == 0))
+    def _start_own():
+        start(b, g, first, cnt, g0, slot0)
+
+    state[1] = 0
+    acc[...] = jnp.zeros_like(acc)
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+
+    def group(i, _):
+        slot = (slot0 + i - g0) % 2
+
+        @pl.when(i + 1 < g1)
+        def _next_group():
+            start(b, g, first, cnt, i + 1, 1 - slot)
+
+        @pl.when((i + 1 == g1) & has_nxt)
+        def _next_program():
+            start(b_nxt, g_nxt, first_nxt, cnt_nxt, n0, 1 - slot)
+            state[1] = 1
+
+        wait(b, g, first, cnt, i, slot)
+        ks = vs = None
+        if quant:
+            ks, vs = (jnp.concatenate(
+                [buf[slot, :, p, :, :bs] for p in range(P)], -1)
+                for buf in (ks_buf, vs_buf))
+        _attend(q_ref[0, 0], k_buf[slot], v_buf[slot], ks, vs, i * (P * bs),
+                lens_ref[b], window, slopes_ref if has_alibi else None, acc, m_scr,
+                l_scr, sm_scale=sm_scale, softcap=softcap)
+
+    jax.lax.fori_loop(g0, g1, group, None)
+    state[0] = (slot0 + g1 - g0) % 2
+    _finish(o_ref, acc, l_scr)
 
 
 def untileable(q_shape, pool_shape, *, stacked: bool, quant: bool,
@@ -171,19 +363,20 @@ def paged_attention(q: jnp.ndarray,
        ``context_lens[b] - 1`` (context_lens INCLUDES the new token).
     k_pool/v_pool: [nh, num_blocks, block_size, hd]; with ``layer_idx``
        (traced i32 ok) the stacked [L, nh, num_blocks, block_size, hd]
-       layout — the index_map picks the layer straight out of the
+       layout — the kernel's copies pick the layer straight out of the
        scan-carried pool, no materialized per-layer slice.
     k_scale/v_scale: the int8 tier (round 17) — pools are int8 in the
        ``quant_format.kv_quantize`` layout and these carry the f32
-       per-(layer, head, slot) scales (any shape that reshapes to the
-       pool's [..., num_blocks, block_size], e.g. init_pool's
-       [L, nh, num_slots, 1]). The scale blocks ride the SAME block-table
-       index_map as k/v and the dequant happens in-kernel, so the HBM
-       read is int8 + 4 bytes/slot — no pool-slice f32 copy exists.
+       per-(layer, head, slot) scales: :func:`scale_rows`' layout (taken
+       as it is) or any shape that reshapes to the pool's
+       [..., num_blocks, block_size], e.g. init_pool's
+       [L, nh, num_slots, 1] (padded here: a copy of the scale pool a
+       call). The scale rows are copied through the SAME block table
+       beside k/v and the dequant happens in-kernel, so the HBM read is
+       int8 + one padded scale row a page — no pool-slice f32 copy exists.
     block_tables: [B, max_blocks] i32 — logical block j of sequence b
        lives in physical pool block ``block_tables[b, j]``. Entries past
-       the live count are never DMA'd (the index_map clamps them to the
-       last live block, which the pipeline elides as a repeated index).
+       the live count are never read.
     context_lens: [B] i32. ``window``: python int or traced i32, <= 0
        means global. ``alibi_slopes``: [nh] per-head slopes (in-kernel
        bias slope * (k_pos - q_pos)). ``softcap``: Gemma-2 tanh cap
@@ -206,13 +399,8 @@ def paged_attention(q: jnp.ndarray,
         if k_pool.dtype != jnp.int8:
             raise ValueError("k_scale/v_scale given but the pool dtype is "
                              f"{k_pool.dtype} — scales pair with int8 pools")
-        # [..., num_blocks, 1, block_size]: the unit axis before the lane
-        # axis makes the scale block's last two dims equal the array's —
-        # the Mosaic block rule a (1, block_size) tile of a
-        # (num_blocks, block_size) array breaks
-        sc_shape = k_pool.shape[:-2] + (1, bs)
-        ks_pool = jnp.asarray(k_scale, jnp.float32).reshape(sc_shape)
-        vs_pool = jnp.asarray(v_scale, jnp.float32).reshape(sc_shape)
+        ks_pool = scale_rows(k_scale, k_pool.shape)
+        vs_pool = scale_rows(v_scale, v_pool.shape)
     elif k_pool.dtype == jnp.int8:
         raise ValueError("int8 KV pool needs k_scale/v_scale "
                          "(quant_format.kv_quantize layout)")
@@ -233,72 +421,61 @@ def paged_attention(q: jnp.ndarray,
                      jnp.int32).reshape(())
     misc = jnp.stack([win, li])
 
-    # dead grid steps clamp to the sequence's last live block: a repeated
-    # physical index means the pipeline skips the K/V copy
-    def _phys(j, bt_s, lens_s, b):
-        last = jnp.maximum((lens_s[b] + bs - 1) // bs - 1, 0)
-        return bt_s[b, jnp.minimum(j, last)]
-
-    if stacked:
-        kv_spec = pl.BlockSpec(
-            (1, hg, 1, bs, hd),
-            lambda b, g, j, bt_s, lens_s, misc_s: (
-                misc_s[1], g, _phys(j, bt_s, lens_s, b), 0, 0))
+    qo_spec = pl.BlockSpec((1, 1, hg, _QROWS, hd),
+                           lambda b, g, *_: (b, g, 0, 0, 0))
+    online = [pltpu.VMEM((hg, _QROWS, hd), jnp.float32),
+              pltpu.VMEM((hg, _QROWS, 128), jnp.float32),
+              pltpu.VMEM((hg, _QROWS, 128), jnp.float32)]
+    static = dict(bs=bs, nbk=nbk, sm_scale=scale, softcap=softcap,
+                  has_alibi=alibi_slopes is not None, stacked=stacked,
+                  quant=quant)
+    if hd % 128 == 0:
+        # the pools stay in HBM: the kernel copies the pages a lane holds
+        P = _pages_per_group(hg, bs, hd, k_pool.dtype.itemsize, nbk, quant)
+        kernel = partial(_loop_kernel, hg=hg, P=P, **static)
+        grid = (B, ng)
+        kv_specs = [pl.BlockSpec(memory_space=pl.ANY)] * (4 if quant else 2)
+        scratch = [pltpu.VMEM((2, hg, P * bs, hd), k_pool.dtype)] * 2
+        if quant:
+            scratch += [pltpu.VMEM((2, hg, P, 1, _scale_lanes(bs)),
+                                   jnp.float32)] * 2
+        scratch += online + [pltpu.SMEM((2,), jnp.int32),
+                             pltpu.SemaphoreType.DMA((2, 2))]
     else:
-        kv_spec = pl.BlockSpec(
-            (hg, 1, bs, hd),
-            lambda b, g, j, bt_s, lens_s, misc_s: (
-                g, _phys(j, bt_s, lens_s, b), 0, 0))
-    q_spec = pl.BlockSpec((1, 1, hg, _QROWS, hd),
-                          lambda b, g, j, *_: (b, g, 0, 0, 0))
+        # narrow heads (:func:`_grid_kernel`): a page a grid step through
+        # the pipeline, a dead step clamped to the sequence's last live
+        # page, whose repeated index the pipeline does not copy again
+        kernel = partial(_grid_kernel, **static)
+        grid = (B, ng, nbk)
 
-    in_specs = [q_spec, kv_spec, kv_spec]
-    operands = [qf, k_pool, v_pool]
-    if quant:
-        # scale blocks follow the K/V through the SAME clamped
-        # block-table index_map (one f32 per slot, slots on the lane axis)
-        if stacked:
-            sc_spec = pl.BlockSpec(
-                (1, hg, 1, 1, bs),
-                lambda b, g, j, bt_s, lens_s, misc_s: (
-                    misc_s[1], g, _phys(j, bt_s, lens_s, b), 0, 0))
-        else:
-            sc_spec = pl.BlockSpec(
-                (hg, 1, 1, bs),
-                lambda b, g, j, bt_s, lens_s, misc_s: (
-                    g, _phys(j, bt_s, lens_s, b), 0, 0))
-        in_specs += [sc_spec, sc_spec]
-        operands += [ks_pool, vs_pool]
-    has_alibi = alibi_slopes is not None
-    if has_alibi:
+        def page(b, g, j, bt_s, lens_s, misc_s):
+            last = jnp.maximum((lens_s[b] + bs - 1) // bs - 1, 0)
+            at = (g, bt_s[b, jnp.minimum(j, last)], 0, 0)
+            return (misc_s[1],) + at if stacked else at
+
+        lead = (1,) if stacked else ()
+        kv_specs = [pl.BlockSpec(lead + (hg, 1, bs, hd), page)] * 2
+        if quant:
+            kv_specs += [pl.BlockSpec(
+                lead + (hg, 1, 1, _scale_lanes(bs)), page)] * 2
+        scratch = online
+    operands = [qf, k_pool, v_pool] + ([ks_pool, vs_pool] if quant else [])
+    if alibi_slopes is not None:
         sl = jnp.asarray(alibi_slopes, jnp.float32).reshape(ng, hg)
-        slopes = jnp.broadcast_to(sl[:, :, None], (ng, hg, 128))
-        in_specs.append(pl.BlockSpec((1, hg, 128),
-                                     lambda b, g, j, *_: (g, 0, 0)))
-        operands.append(slopes)
+        operands.append(jnp.broadcast_to(sl[:, :, None], (ng, hg, 128)))
+        slopes_spec = pl.BlockSpec((1, hg, 128), lambda b, g, *_: (g, 0, 0))
     else:
         # constant placeholder so the kernel arity is static
-        in_specs.append(pl.BlockSpec((1, 1, 128), lambda b, g, j, *_: (0, 0, 0)))
         operands.append(jnp.zeros((1, 1, 128), jnp.float32))
+        slopes_spec = pl.BlockSpec((1, 1, 128), lambda b, g, *_: (0, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, ng, nbk),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, hg, _QROWS, hd),
-                               lambda b, g, j, *_: (b, g, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((hg, _QROWS, hd), jnp.float32),
-            pltpu.VMEM((hg, _QROWS, 128), jnp.float32),
-            pltpu.VMEM((hg, _QROWS, 128), jnp.float32),
-        ],
-    )
+        num_scalar_prefetch=3, grid=grid,
+        in_specs=[qo_spec] + kv_specs + [slopes_spec], out_specs=qo_spec,
+        scratch_shapes=scratch)
     with jax.named_scope("paged_attention"):
         out = pl.pallas_call(
-            partial(_kernel, hg=hg, bs=bs, nbk=nbk, sm_scale=scale,
-                    softcap=softcap, has_alibi=has_alibi, stacked=stacked,
-                    quant=quant),
-            grid_spec=grid_spec,
+            kernel, grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((B, ng, hg, _QROWS, hd), q.dtype),
             interpret=interpret,
         )(bt, lens, misc, *operands)
@@ -339,10 +516,9 @@ def paged_attention_reference(q: jnp.ndarray,
     B, nh, T, hd = q.shape
     quant = k_scale is not None
     if quant:
-        k_scale = jnp.asarray(k_scale, jnp.float32).reshape(
-            k_pool.shape[:-1])
-        v_scale = jnp.asarray(v_scale, jnp.float32).reshape(
-            v_pool.shape[:-1])
+        bs = k_pool.shape[-2]
+        k_scale = scale_rows(k_scale, k_pool.shape)[..., 0, :bs]
+        v_scale = scale_rows(v_scale, v_pool.shape)[..., 0, :bs]
     if layer_idx is not None:
         k_pool = jax.lax.dynamic_index_in_dim(k_pool, layer_idx, 0,
                                               keepdims=False)

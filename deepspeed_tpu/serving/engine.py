@@ -63,7 +63,7 @@ _COUNTERS = (
     "admit_blocked.no_lane", "admit_blocked.no_blocks",
     "admit_blocked.prefilling", "compiles",
     "kv.held_blocks_sum", "kv.blocks_reserved_sum", "kv.tokens_written_sum",
-    "prefix.prompt_tokens")
+    "prefix.prompt_tokens", "paged.live_pages_sum", "paged.table_pages_sum")
 #: a dropless MoE model's router load, from the [L, E] counts that ride the
 #: tokens' own fetch (``_count_experts``); a dense model has none of these
 _MOE_COUNTERS = ("moe.assignments", "moe.layer_steps",
@@ -930,6 +930,12 @@ class ServingEngine:
                 tks[i] = s.req.top_k or 0
                 tps[i] = s.req.top_p if s.req.top_p is not None else 1.0
                 tables[i] = s.table
+            # the pages the paged kernel walks this step (every lane up to
+            # the token it writes; an idle lane its one null page) against
+            # the tables' full width
+            rec.count("paged.live_pages_sum",
+                      int((ctx // self.block_size + 1).sum()))
+            rec.count("paged.table_pages_sum", B * self.nbk)
             self._rng, r = jax.random.split(self._rng)
             args = (jnp.asarray(toks), jnp.asarray(tables),
                     jnp.asarray(ctx), r, jnp.asarray(temps),

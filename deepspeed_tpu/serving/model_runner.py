@@ -40,6 +40,7 @@ from ..models.generation import (_dense, _kv_quantize, _layer_norm, _moe_mlp,
                                  _qk_norm, split_stacked_experts)
 from ..models.transformer import TransformerConfig
 from ..ops.attention import paged_attention
+from ..ops.pallas.paged_attention import scale_rows
 from .kv_cache import NULL_BLOCK
 
 PyTree = Any
@@ -349,15 +350,17 @@ def paged_forward(cfg: TransformerConfig,
     # the loop carries the pools as the kernel reads them, a block's slots
     # on an axis of their own: for K/V a free view of init_pool's flat slot
     # axis; the int8 tier's small scale pools change layout here, at the
-    # loop's boundary (the kernel wants a block's slots on the lane axis),
-    # not twice a layer inside it
-    blocked = {name: (pool.reshape(L, nh, nb_pool, 1, bs)
+    # loop's boundary (a block's scales on the first lanes of a row of whole
+    # 128-lane tiles: the least a kernel may copy), not twice a layer inside
+    # it
+    blocked = {name: (scale_rows(pool, (L, nh, nb_pool, bs, hd))
                       if name.endswith("_scale")
                       else pool.reshape(L, nh, nb_pool, bs, hd))
                for name, pool in pools.items()}
     with jax.named_scope("layers"):
         (x, kv_out), counts = jax.lax.scan(layer, (x, blocked), xs)
-    kv_out = {name: pool.reshape(pools[name].shape)
+    kv_out = {name: (pool[..., :bs] if name.endswith("_scale")
+                     else pool).reshape(pools[name].shape)
               for name, pool in kv_out.items()}
     with jax.named_scope("head"):
         x = _layer_norm(x, params["ln_f"], cfg.layer_norm_eps, rms)

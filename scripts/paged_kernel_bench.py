@@ -1,0 +1,148 @@
+"""The paged kernel alone, on the chip, at the serving cells' shapes (and at
+one shape with heads narrower than 128 lanes, which take the grid kernel).
+
+    chiprun -- python scripts/paged_kernel_bench.py [--pages 1,2,4,8] [--old FILE]
+
+One "step" is what a decode program asks of the kernel: one call a layer
+on the stacked pool, every lane at its own context length (lognormal round
+the cell's mean, some lanes idle at the one token the engine gives them).
+Prints a JSON line a shape and setting: ms a step, the bytes the live pages
+hold (K and V, every stored head), and that over the chip's 819 GB/s as a
+share of the time: the kernel's roofline share. ``--pages`` overrides the
+pages a copy group holds (the program derives it from the shapes:
+``_pages_per_group``); ``--old FILE`` times another version of the kernel's
+module (e.g. ``git show <commit>:deepspeed_tpu/ops/pallas/paged_attention.py``)
+on the same inputs. Parity against the jnp reference is checked on the
+device before anything is timed. Needs a TPU.
+"""
+
+import argparse
+import importlib.util
+import json
+import sys
+import time
+from pathlib import Path
+
+import numpy as np
+
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+
+HBM_GBPS = 819.0     # TPU v5e, Google Cloud "TPU v5e"; benchmark/peaks.json
+
+# layers, stored heads, head_dim, block, pool blocks, lanes, table, live
+# lanes, mean context of a live lane (PERF.md section 5: ~58 k tokens over
+# 52 lanes; ~260 pages over 28 lanes), sliding window
+SHAPES = {
+    "serve-olmoe-1b-7b-l8-gen": (8, 16, 128, 32, 2048, 64, 128, 52, 1100, 0),
+    "serve-mistral-7b-l16-chat": (16, 32, 128, 32, 384, 32, 40, 28, 290,
+                                  4096),
+    # no cell: heads of 64 take the grid kernel (a grid step a table entry)
+    "llama-1.1b": (22, 32, 64, 32, 512, 16, 64, 14, 400, 0),
+}
+
+
+def load(path):
+    if path is None:
+        from deepspeed_tpu.ops.pallas import paged_attention as mod
+        return mod
+    spec = importlib.util.spec_from_file_location(
+        "deepspeed_tpu.ops.pallas._paged_other", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--pages", default="")
+    ap.add_argument("--old", default=None)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--reps", type=int, default=30)
+    args = ap.parse_args()
+    import jax
+    import jax.numpy as jnp
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        sys.exit("paged_kernel_bench needs a TPU")
+    new, old = load(None), load(args.old) if args.old else None
+    derive = new._pages_per_group
+    settings = [("derived", new, None)]
+    settings += [(f"pages={p}", new, int(p))
+                 for p in args.pages.split(",") if p]
+    if old is not None:
+        settings.append((f"old:{Path(args.old).name}", old, None))
+    for name, (L, nh, hd, bs, nb, B, nbk, live, mean, window) in \
+            SHAPES.items():
+        rng = np.random.default_rng(args.seed)
+        ctx = np.ones((B,), np.int32)
+        ctx[:live] = np.clip(rng.lognormal(np.log(mean) - 0.32, 0.8, live),
+                             1, nbk * bs).astype(np.int32)
+        pages = -(-ctx // bs)
+        while pages.sum() > nb - 1:          # the pool holds what it holds
+            ctx[np.argmax(ctx)] //= 2
+            pages = -(-ctx // bs)
+        rng.shuffle(ctx)
+        pages = -(-ctx // bs)
+        bt = np.zeros((B, nbk), np.int32)
+        free = rng.permutation(nb - 1) + 1
+        at = 0
+        for b in range(B):
+            bt[b, :pages[b]] = free[at:at + pages[b]]
+            at += pages[b]
+        key = jax.random.PRNGKey(args.seed)
+        kq, kk, kv = jax.random.split(key, 3)
+        q = jax.random.normal(kq, (B, nh, 1, hd), jnp.bfloat16)
+        kp = jax.random.normal(kk, (L, nh, nb, bs, hd), jnp.bfloat16)
+        vp = jax.random.normal(kv, (L, nh, nb, bs, hd), jnp.bfloat16)
+        bt_d, ctx_d = jnp.asarray(bt), jnp.asarray(ctx)
+        need = int(pages.sum()) * L * nh * bs * hd * 2 * 2
+        ref = new.paged_attention_reference(
+            q, kp, vp, bt_d, ctx_d, layer_idx=jnp.int32(L - 1),
+            window=window)
+        for label, mod, force in settings:
+            new._pages_per_group = (
+                derive if force is None else
+                lambda *a, _p=force, **k: min(_p, a[4]))
+
+            def step(q, kp, vp, bt, ctx, mod=mod):
+                def layer(acc, li):
+                    o = mod.paged_attention(q, kp, vp, bt, ctx, layer_idx=li,
+                                            window=window)
+                    return acc + o.astype(jnp.float32), None
+                return jax.lax.scan(
+                    layer, jnp.zeros(q.shape, jnp.float32), jnp.arange(L))[0]
+
+            one = jax.jit(lambda q, kp, vp, bt, ctx, mod=mod:
+                          mod.paged_attention(q, kp, vp, bt, ctx,
+                                              layer_idx=jnp.int32(L - 1),
+                                              window=window))
+            err = float(jnp.max(jnp.abs(
+                one(q, kp, vp, bt_d, ctx_d).astype(jnp.float32)
+                - ref.astype(jnp.float32))))
+            fn = jax.jit(step)
+            fn(q, kp, vp, bt_d, ctx_d).block_until_ready()
+            best = []
+            for _ in range(3):
+                t = time.perf_counter()
+                for _ in range(args.reps):
+                    out = fn(q, kp, vp, bt_d, ctx_d)
+                out.block_until_ready()
+                best.append((time.perf_counter() - t) / args.reps)
+            ms = min(best) * 1e3
+            P = new._pages_per_group(
+                new._head_group(nh, bs, hd, 2), bs, hd, 2,
+                nbk) if mod is new and hd % 128 == 0 else None
+            print(json.dumps({
+                "shape": name, "setting": label, "pages_per_group": P,
+                "device_kind": dev.device_kind, "ms_per_step": ms,
+                "live_pages_a_layer": int(pages.sum()),
+                "table_pages_a_layer": B * nbk,
+                "live_share": float(pages.sum() / (B * nbk)),
+                "needed_gb": need / 1e9,
+                "roofline_pct": 100 * need / (HBM_GBPS * 1e9) / (ms / 1e3),
+                "max_abs_err_vs_reference": err}), flush=True)
+        new._pages_per_group = derive
+
+
+if __name__ == "__main__":
+    main()

@@ -101,29 +101,65 @@ def test_flash_masked_wrapper_compiles(chip, monkeypatch):
     assert len(names) == 3, names
 
 
+# layers, stored heads, head_dim, block, pool blocks, lanes, table: the
+# serving widths of chip_smoke.py, of the benchmark's two serving cells, and
+# of a preset whose heads are narrower than a lane tile
+_PAGED_SHAPES = {
+    "gpt2-1.3b": (L, NH, HD, BS, NB, B, NBK),
+    "serve-olmoe-1b-7b-l8-gen": (8, 16, 128, 32, 2048, 64, 128),
+    "serve-mistral-7b-l16-chat": (16, 32, 128, 32, 384, 32, 40),
+    "llama-1.1b": (22, 32, 64, 32, 256, 8, 32)}
+
+
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
-def test_paged_attention_stacked_pool_compiles(chip, quant):
+@pytest.mark.parametrize("cell", list(_PAGED_SHAPES))
+def test_paged_attention_stacked_pool_compiles(chip, cell, quant):
     """The serving decode kernel on the stacked [L, nh, blocks, bs, hd] pool
-    with a traced layer index; int8 adds the per-slot scale operands whose
-    (1, block_size) tile the compiler refused before PR 21."""
-    from deepspeed_tpu.ops.pallas.paged_attention import paged_attention
+    with a traced layer index and window, its K/V pages copied by the
+    kernel itself out of the pool where it lies; int8 adds the per-slot
+    scale rows (whole 128-lane tiles: a (1, block_size) row the compiler
+    refused before PR 21 as a block and refuses still as a manual copy).
+    The kernel's grid is lanes x head groups: no axis as long as the table
+    (PR 28: four grid steps in five were dead), and the program round it
+    has no loop and makes no array of a pool's size. Heads narrower than
+    128 lanes keep the table's axis: the compiler refuses a kernel's own
+    copy of part of the 128-lane row it pads such a pool to."""
+    from deepspeed_tpu.ops.pallas.paged_attention import (paged_attention,
+                                                          scale_rows)
+    L, NH, HD, BS, NB, B, NBK = _PAGED_SHAPES[cell]
     q = chip((B, NH, 1, HD), jnp.bfloat16)
     pool = chip((L, NH, NB, BS, HD), jnp.int8 if quant else jnp.bfloat16)
     bt, lens, li = (chip((B, NBK), jnp.int32), chip((B,), jnp.int32),
                     chip((), jnp.int32))
     if quant:
-        # init_pool's scale layout: [L, nh, num_slots, 1]
-        sc = chip((L, NH, NB * BS, 1), jnp.float32)
-        names = _kernels(
-            lambda q, k, v, bt, lens, li, ks, vs: paged_attention(
-                q, k, v, bt, lens, layer_idx=li, k_scale=ks, v_scale=vs),
-            q, pool, pool, bt, lens, li, sc, sc)
+        # the scales as serving.model_runner carries them through its loop
+        sc = chip(jax.eval_shape(
+            lambda: scale_rows(jnp.zeros((L, NH, NB * BS, 1)),
+                               pool.shape)).shape, jnp.float32)
+        fn, args = (lambda q, k, v, bt, lens, li, ks, vs: paged_attention(
+            q, k, v, bt, lens, layer_idx=li, window=li, k_scale=ks,
+            v_scale=vs)), (q, pool, pool, bt, lens, li, sc, sc)
     else:
-        names = _kernels(
-            lambda q, k, v, bt, lens, li: paged_attention(
-                q, k, v, bt, lens, layer_idx=li),
+        fn, args = (lambda q, k, v, bt, lens, li: paged_attention(
+            q, k, v, bt, lens, layer_idx=li, window=li)), (
             q, pool, pool, bt, lens, li)
+    grids = [e.params["grid_mapping"].grid
+             for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    assert len(grids) == 1 and grids[0][0] == B, grids
+    assert grids[0][2:] == (() if HD % 128 == 0 else (NBK,)), grids
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    names = _kernel_scopes(text)
     assert len(names) == 1 and "paged_attention" in names[0], names
+    results = _results(text)
+    assert not [r for r in results if r[1] == "while"], results
+    # nothing as large as one layer of a pool is made (int8: nor its scales).
+    # Narrow heads are not held to it: as a bare call's argument such a pool
+    # is copied whole to the lane-padded layout the kernel takes
+    least = NH * NB * (128 if quant else BS * HD)
+    big = [r for r in results if r[3] >= least and r[1] not in (
+        "parameter", "get-tuple-element", "tuple", "bitcast")]
+    assert not big or HD % 128, big
 
 
 @pytest.mark.parametrize("K,N", [(2048, 6144), (2048, 8192), (8192, 2048)],
@@ -266,13 +302,14 @@ def test_serving_step_updates_the_pool_in_place(chip, monkeypatch, program,
     moved = [r for r in _results(text)
              if r[1] in ("copy", "transpose", "scatter") and r[3] >= at_least]
     assert not moved, moved
-    # the int8 tier's scales reach the kernel with a block's slots on the
-    # lane axis, which is not the layout they have at the jit boundary: they
-    # change layout there (four small conversions a step), never in the loop
-    scales = L * NH * NB * BS
+    # the int8 tier's scales reach the kernel a block a row of whole
+    # 128-lane tiles, which is not the layout they have at the jit boundary:
+    # they change layout there (four small conversions a step), never in the
+    # loop, neither as they come nor as the loop carries them
+    scales = (L * NH * NB * BS, L * NH * NB * 128)
     inside = [r for r in _results(text)
-              if r[0] != "ENTRY" and r[2] == "f32" and r[3] == scales
-              and r[1] in ("copy", "transpose", "scatter", "reshape")]
+              if r[0] != "ENTRY" and r[2] == "f32" and r[3] in scales
+              and r[1] in ("copy", "transpose", "scatter", "reshape", "pad")]
     assert not inside, inside
     pool_bytes = L * layer * (1 if quant else 2)
     # int8: the two scale pools live lane-padded in the loop (2 x 100 MB,

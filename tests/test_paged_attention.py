@@ -1,11 +1,12 @@
 """Pallas paged-attention kernel: parity vs a dense numpy oracle.
 
-The kernel gathers K/V blocks through a per-sequence block table inside
-the pipeline (serving decode path); the oracle materializes each
-sequence's logical K/V by following the table on the host and runs dense
-masked attention. Interpret mode on CPU — the same kernel runs compiled
-on TPU. Covers the acceptance regimes: padding (ragged context lengths,
-dead table entries), ALiBi, softcap, sliding window, stacked layer pools.
+The kernel copies the K/V pages a sequence holds through its block table,
+several a turn of a loop inside one program a sequence (serving decode
+path); the oracle materializes each sequence's logical K/V by following the
+table on the host and runs dense masked attention. Interpret mode on CPU —
+the same kernel runs compiled on TPU. Covers the acceptance regimes: padding
+(ragged context lengths, dead table entries), ALiBi, softcap, sliding
+window, stacked layer pools, the int8 tier, and the loop's edges in each.
 """
 
 import jax
@@ -158,7 +159,7 @@ def test_router_dispatch():
 
 
 # ---------------------------------------------------------------------------
-# int8 KV tier (round 17): the kernel DMAs int8 blocks + per-row scales and
+# int8 KV tier (round 17): the kernel copies int8 pages + per-row scales and
 # dequantizes IN VMEM — parity vs the numpy oracle running on the
 # dequantized pools must be as tight as the f32 tier's, in every routed
 # regime, because the in-kernel dequant reconstructs the identical values.
@@ -201,8 +202,8 @@ def test_paged_int8_parity_all_regimes(regime):
 
 
 def test_paged_int8_stacked_layer_pool():
-    """int8 + layer_idx: per-layer scale slices ride the SAME block-table
-    index map as the values — each layer dequantizes with its own rows."""
+    """int8 + layer_idx: per-layer scale rows are copied through the SAME
+    block table as the values — each layer dequantizes with its own rows."""
     L = 3
     q, kp, vp, bt, lens = _data(B=2, nbk=4, seed=7)
     kpl = np.stack([kp * (l + 1) for l in range(L)])
@@ -230,6 +231,183 @@ def test_paged_int8_guards():
         paged_attention(jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
                         jnp.asarray(bt), jnp.asarray(lens), k_scale=ks,
                         v_scale=vs, interpret=True)
+
+
+# ---------------------------------------------------------------------------
+# The loop's edges (PR 28): the kernel walks grid=(lanes, head groups) and,
+# inside a program, the groups of P pages its lane holds, the next group
+# (or the next lane's first) copied while one is computed. Each edge of
+# that loop, in every regime the kernel has. One small shape throughout
+# (4 lanes, tables of 6 pages of 8 slots, heads of 128: narrower heads take
+# the grid kernel, which the tests above hold), from which the program
+# derives P = 4: two groups, the second half a group (the table is no
+# multiple of P); one jitted call a regime, the edges only change what it is
+# fed.
+# ---------------------------------------------------------------------------
+
+_EB, _ENH, _EHD, _EBS, _ENB, _ENBK, _EP = 4, 2, 128, 8, 40, 6, 4
+_ESLOPES = np.asarray([0.5, 0.125], np.float32)
+_REGIMES = {
+    "plain": {}, "stacked": {"stacked": True}, "window": {"window": 12},
+    "alibi": {"slopes": _ESLOPES}, "softcap": {"softcap": 30.0},
+    "int8": {"quant": True}}
+_EDGES = {
+    # an idle lane first, one between live ones, and a live one after it
+    # that nobody started a copy for
+    "idle_lane": [0, 29, 0, 41],
+    "exactly_one_group": [_EP * _EBS, 17, _EP * _EBS, 9],
+    "one_token_past_a_group": [_EP * _EBS + 1, _EP * _EBS, _EP * _EBS + 1, 1],
+    "one_token": [1, 1, _ENBK * _EBS, 1],
+    "full_table": [_ENBK * _EBS, _ENBK * _EBS, 7, _ENBK * _EBS],
+    "table_no_multiple_of_group": [35, 47, 5, 40],
+    "shared_pages": [30, 27, 44, 12]}
+
+
+def _edge_call(regime, fresh=False):
+    """The jitted kernel call of one regime, built once (the edges share
+    its shapes); ``fresh`` traces a new one (under a forced P)."""
+    r = _REGIMES[regime]
+    kw = {}
+    if "slopes" in r:
+        kw["alibi_slopes"] = jnp.asarray(r["slopes"])
+    if "softcap" in r:
+        kw["softcap"] = r["softcap"]
+
+    def call(q, kp, vp, bt, lens, ks, vs):
+        if "window" in r:
+            kw["window"] = jnp.asarray(r["window"], jnp.int32)
+        if r.get("stacked"):
+            kw["layer_idx"] = jnp.asarray(1, jnp.int32)
+        if r.get("quant"):
+            kw["k_scale"], kw["v_scale"] = ks, vs
+        return paged_attention(q, kp, vp, bt, lens, interpret=True, **kw)
+
+    if fresh:
+        return jax.jit(call)
+    if regime not in _edge_call.cache:
+        _edge_call.cache[regime] = jax.jit(call)
+    return _edge_call.cache[regime]
+
+
+_edge_call.cache = {}
+
+
+def _edge_case(regime, lens, shared=False, seed=11):
+    """Inputs of one edge in one regime, and the oracle's answer (an idle
+    lane's row is zeros by the kernel's contract)."""
+    r = _REGIMES[regime]
+    q, kp, vp, bt, _ = _data(B=_EB, nh=_ENH, hd=_EHD, bs=_EBS,
+                             num_blocks=_ENB, nbk=_ENBK, seed=seed)
+    lens = np.asarray(lens, np.int32)
+    if shared:
+        bt[1, :3] = bt[0, :3]            # a shared prefix of three pages
+    ks = vs = jnp.zeros((1,), jnp.float32)
+    okp, ovp = kp, vp
+    if r.get("quant"):
+        kp, ks, vp, vs, okp, ovp = _int8_pools(kp, vp)
+    if r.get("stacked"):
+        okp, ovp = kp * 2.0, vp * 0.5
+        kp, vp = np.stack([kp, okp]), np.stack([vp, ovp])
+    want = _oracle(q, okp, ovp, bt, np.maximum(lens, 1),
+                   window=r.get("window", 0), slopes=r.get("slopes"),
+                   softcap=r.get("softcap", 0.0))
+    want[lens == 0] = 0.0
+    args = (jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+            jnp.asarray(bt), jnp.asarray(lens), ks, vs)
+    return args, want
+
+
+def test_edge_shape_derives_two_ragged_groups():
+    """The edge cases below mean what their names say only while the
+    program derives 4 pages a group from their shape."""
+    from deepspeed_tpu.ops.pallas.decode_attention import _head_group
+    from deepspeed_tpu.ops.pallas.paged_attention import _pages_per_group
+    for itemsize, quant in ((4, False), (1, True)):
+        hg = _head_group(_ENH, _EBS, _EHD, itemsize)
+        assert hg == _ENH
+        assert _pages_per_group(hg, _EBS, _EHD, itemsize, _ENBK,
+                                quant) == _EP
+
+
+@pytest.mark.parametrize("hd,axes", [(128, 2), (256, 2), (64, 3), (96, 3)])
+def test_kernel_by_head_width(hd, axes):
+    """Heads of whole 128-lane tiles take the loop kernel, whose grid is
+    lanes x head groups; narrower ones (the chip's compiler refuses a
+    kernel's own copy of part of a padded row) the grid kernel, with the
+    table's length as a third axis."""
+    q, kp, vp, bt, lens = _data(B=2, nh=2, hd=hd, bs=8, num_blocks=12, nbk=4)
+    jaxpr = jax.make_jaxpr(lambda *a: paged_attention(*a, interpret=True))(
+        q, kp, vp, bt, lens)
+    grids = [e.params["grid_mapping"].grid for e in jaxpr.jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    assert len(grids) == 1 and len(grids[0]) == axes, grids
+
+
+@pytest.mark.parametrize("edge", list(_EDGES))
+@pytest.mark.parametrize("regime", list(_REGIMES))
+def test_paged_loop_edges(regime, edge):
+    args, want = _edge_case(regime, _EDGES[edge], shared=edge == "shared_pages")
+    out = _edge_call(regime)(*args)
+    np.testing.assert_allclose(np.asarray(out), want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("pages", [1, 2])
+@pytest.mark.parametrize("regime", ["plain", "window", "int8"])
+def test_paged_loop_alternates_slots_over_many_groups(monkeypatch, regime,
+                                                      pages):
+    """Fewer pages a group than the program would pick: three to six turns
+    of the loop a lane, so both buffer slots are reused, a window's first
+    group lies past the table's start, and a lane's last turn starts the
+    next lane's copy into the slot the lane after that continues from."""
+    from deepspeed_tpu.ops.pallas import paged_attention as mod
+    monkeypatch.setattr(mod, "_pages_per_group", lambda *a, **k: pages)
+    args, want = _edge_case(regime, [41, 0, 48, 19], seed=12)
+    out = _edge_call(regime, fresh=True)(*args)
+    np.testing.assert_allclose(np.asarray(out), want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("regime", ["plain", "int8"])
+def test_paged_loop_under_the_tpu_interpreter(regime):
+    """The same kernel under the TPU interpreter, which keeps semaphores
+    and copies apart from the compute as the chip does: every buffer it
+    allocates starts as NaN (a page the loop skipped must not reach the
+    output through a zero probability) and no copy races a read."""
+    from jax._src.pallas.mosaic.interpret import (
+        interpret_pallas_call as tpu_interpreter)
+    from jax.experimental.pallas import tpu as pltpu
+    args, want = _edge_case(regime, [0, 35, 48, 1], seed=13)
+    kw = {}
+    if regime == "int8":
+        kw = dict(k_scale=args[5], v_scale=args[6])
+    out = paged_attention(*args[:5], interpret=pltpu.InterpretParams(
+        detect_races=True, uninitialized_memory="nan"), **kw)
+    np.testing.assert_allclose(np.asarray(out), want, rtol=2e-5, atol=2e-5)
+    assert not tpu_interpreter.races.races_found
+
+
+@pytest.mark.parametrize("shape,want", [
+    # (heads a program, block, head_dim, item size, table, int8 tier)
+    ((16, 32, 128, 2, 128, False), 8),     # serve-olmoe-1b-7b-l8-gen
+    ((32, 32, 128, 2, 40, False), 4),      # serve-mistral-7b-l16-chat
+    ((16, 32, 128, 1, 128, True), 8),      # the same two on the int8 tier
+    ((32, 32, 128, 1, 40, True), 4),
+    ((16, 32, 128, 2, 32, False), 8),      # gpt2-1.3b, chip_smoke.py
+    ((16, 32, 128, 2, 3, False), 2),       # never more than the table holds
+    ((16, 32, 128, 2, 1, False), 1),
+    ((64, 128, 256, 4, 64, False), 1),     # a page over the budget: one
+], ids=["olmoe", "mistral", "olmoe-int8", "mistral-int8", "gpt2",
+        "table-of-3", "table-of-1", "huge-page"])
+def test_pages_per_group_from_shapes(shape, want):
+    from deepspeed_tpu.ops.pallas.paged_attention import (
+        _VMEM_BUDGET, _pages_per_group, _scale_lanes)
+    hg, bs, hd, itemsize, nbk, quant = shape
+    P = _pages_per_group(*shape)
+    assert P == want
+    assert 1 <= P <= nbk
+    held = 2 * 2 * hg * P * bs * hd * itemsize
+    if quant:
+        held += 2 * 2 * hg * P * 8 * _scale_lanes(bs) * 4
+    assert held <= _VMEM_BUDGET or P == 1, (held, _VMEM_BUDGET)
 
 
 # tier-2 (round-17 budget sweep, ~9s): the cheaper tier-1 cousins are

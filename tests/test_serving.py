@@ -722,9 +722,14 @@ def _scatter_write_kv(bt, q_start, ctx, T):
         B, w = new.shape[0], pool.shape[7 - ax]      # hd (ax 3) or 1 (ax 4)
         rows = jnp.moveaxis(new.reshape(B, nh, T, w) if ax == 3
                             else new.reshape(B, nh, T, 1), 1, 2)
-        flat = pool.reshape(L, nh, nb * W_BS, w)
-        return flat.at[li, :, slots].set(
-            rows.reshape(B * T, nh, w)).reshape(pool.shape)
+        # a scale pool (ax 4) holds a block's slots on the first lanes of a
+        # row of whole 128-lane tiles: the lanes past them are not slots
+        held = pool if ax == 3 else pool[..., :W_BS]
+        flat = held.reshape(L, nh, nb * W_BS, w)
+        out = flat.at[li, :, slots].set(
+            rows.reshape(B * T, nh, w)).reshape(held.shape)
+        return out if ax == 3 else jnp.concatenate(
+            [out, pool[..., W_BS:]], axis=-1)
     return write
 
 
