@@ -1,13 +1,19 @@
-"""KV-cache decode + autoregressive generation for the flagship transformer.
+"""The inference decoder over a KV cache, and autoregressive generation.
 
 Capability slot of the reference's inference decode path: the fused
 `softmax_context` attention-with-cache kernels and preallocated KV workspace
 (csrc/transformer/inference/, inference_context.h) and `InferenceEngine.
 generate` (inference/engine.py:537). TPU-native shape: the cache is a
-scan-carried pytree of static-shape buffers ([L, B, heads, max_len, head_dim]),
-the decode step is one jitted function (XLA's compilation cache plays the role
-of CUDA-graph capture/replay), and sampling runs inside `lax.scan` so the
-whole generation loop is a single compiled program.
+scan-carried pytree of static-shape buffers, the decode step is one jitted
+function (XLA's compilation cache plays the role of CUDA-graph capture/replay),
+and sampling runs inside `lax.scan` so the whole generation loop is a single
+compiled program.
+
+:func:`decoder_forward` is the one decoder layer of the inference tier,
+written over a cache object that says where a token's position comes from,
+where its K/V row goes and what attends over the cache: ``generate()`` runs
+it over :class:`DenseCache` ([L, B, heads, max_len, head_dim] buffers), the
+serving loop over a paged block pool (``serving/model_runner.PagedCache``).
 
 All functions are pure: (params, cache, ids) -> (logits, cache). They mirror
 models/transformer.Block numerically (same params pytree, scan-layers layout).
@@ -22,9 +28,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..quant_format import kv_quantize as _kv_quantize  # noqa: F401 (shared
-#   format, round 17 — re-exported: serving/model_runner imports it here)
-from .transformer import TransformerConfig
+from ..ops.attention import apply_softcap, flash_attention_on_mesh
+from ..quant_format import kv_quantize
+from .transformer import (_ACTIVATIONS, TransformerConfig, alibi_slopes,
+                          apply_rotary)
 
 PyTree = Any
 
@@ -58,7 +65,7 @@ def _kernel_of(p, dtype):
     int8 kernels carry either a per-output-channel symmetric scale
     (``kernel_scale``, the inference engine's format) or per-256-element
     blockwise scales along the contraction dim (``kernel_qscale``, the
-    round-17 serving pack — quant_format's wire format on a weight); the
+    serving pack — quant_format's wire format on a weight); the
     convert+multiply fuses into the consuming dot, so the HBM read is
     half the bf16 bytes — the role of the reference's int8 inference
     kernels (csrc/transformer/inference, pt_binding ds_*_int8 entry
@@ -89,7 +96,7 @@ def _kernel_of(p, dtype):
 
 def _dense(x, p, interpret: bool = False):
     if "kernel_qscale" in p:
-        # round 17: blockwise-int8 packed kernel (serving.weight_dtype
+        # blockwise-int8 packed kernel (serving.weight_dtype
         # "int8") — int8 stays int8 until the Pallas kernel's VMEM
         # dequant; no full-weight f32/bf16 copy materializes here
         from ..ops.pallas.quant_matmul import quant_matmul
@@ -102,8 +109,8 @@ def _dense(x, p, interpret: bool = False):
     return y
 
 
-# cache lengths round up to this so the decode kernel always has a >=128
-# block tiling (ops/pallas/decode_attention.py); dead positions are masked
+# cache lengths round up to this: a cache length is a compile bucket, and
+# generations of nearby lengths share one; dead positions are masked
 KV_CACHE_ROUND = 256
 
 
@@ -118,26 +125,21 @@ def init_cache(cfg: TransformerConfig, batch_size: int, max_len: int,
     ``pad_lens`` [B]: per-sample LEFT-pad lengths for ragged batched
     prompts — cache slots [0, pad_i) are dead for sample i (masked in every
     attention) and logical positions are slot - pad_i. Absent for uniform
-    batches (the decode kernel path needs the uniform layout).
+    batches (the flash prefill needs the uniform layout).
 
     ``dtype=jnp.int8``: quantized KV cache — k/v store int8 with a
     per-(layer, batch, head, position) f32 scale (symmetric over the head
     dim), halving the cache's HBM footprint vs bf16 (+~3% for scales):
     2x the context length or batch fits the same workspace. Attention
-    dequantizes on read (jnp path; the block-skip decode kernel needs the
-    bf16 layout and is bypassed). Capability slot of the reference's int8
+    dequantizes on read. Capability slot of the reference's int8
     inference kernel family (csrc/transformer/inference ds_*_int8)."""
     dtype = dtype or cfg.dtype
     shape = (cfg.num_layers, batch_size, cfg.num_heads, max_len, cfg.head_dim)
+    cache = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
+             "pos": jnp.zeros((), jnp.int32)}
     if dtype == jnp.int8:
-        cache = {"k": jnp.zeros(shape, jnp.int8),
-                 "v": jnp.zeros(shape, jnp.int8),
-                 "k_scale": jnp.zeros(shape[:-1] + (1,), jnp.float32),
-                 "v_scale": jnp.zeros(shape[:-1] + (1,), jnp.float32),
-                 "pos": jnp.zeros((), jnp.int32)}
-    else:
-        cache = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
-                 "pos": jnp.zeros((), jnp.int32)}
+        cache["k_scale"] = jnp.zeros(shape[:-1] + (1,), jnp.float32)
+        cache["v_scale"] = jnp.zeros(shape[:-1] + (1,), jnp.float32)
     if pad_lens is not None:
         cache["pad"] = jnp.asarray(pad_lens, jnp.int32)
     return cache
@@ -174,22 +176,21 @@ def split_stacked_experts(cfg: TransformerConfig, blocks):
     return {**blocks, "moe": moe}, blocks["moe"]["experts"]
 
 
-def _moe_mlp(cfg: TransformerConfig, p_moe, h, with_routing: bool = False,
-             interpret: bool = False, layer=None):
-    """Decode-path MoE MLP. A dropless config (``cfg.moe_is_dropless``: OLMoE)
-    runs ``moe/dropless.dropless_moe``, the function its training module
-    runs; ``with_routing`` then returns ``(y, Routing)`` for the caller's
-    counters, and with ``layer`` ``p_moe["experts"]`` is the whole stack
-    (:func:`split_stacked_experts`). Top-1/2 GShard configs keep the gating
-    math of moe/layer.MoE with a no-drop capacity — incremental decode can't
-    see the other timesteps a capacity limit would make it compete with (run
-    eval with a capacity_factor that avoids drops for exact
-    decode/full-forward parity)."""
+def _moe_mlp(cfg: TransformerConfig, p_moe, h, interpret: bool = False,
+             layer=None):
+    """Decode-path MoE MLP, ``(y, Routing or None)``. A dropless config
+    (``cfg.moe_is_dropless``: OLMoE) runs ``moe/dropless.dropless_moe``, the
+    function its training module runs, and returns its routing for the
+    caller's counters; ``p_moe["experts"]`` is then the whole stack and
+    ``layer`` picks from it (:func:`split_stacked_experts`). Top-1/2 GShard
+    configs keep the gating math of moe/layer.MoE with a no-drop capacity —
+    incremental decode can't see the other timesteps a capacity limit would
+    make it compete with (run eval with a capacity_factor that avoids drops
+    for exact decode/full-forward parity)."""
     B, T, H = h.shape
     tokens = h.reshape(B * T, H)
     if cfg.moe_is_dropless:
         from ..moe.dropless import dropless_moe
-        from .transformer import _ACTIVATIONS
         y, routing = dropless_moe(
             tokens, p_moe["gate"]["kernel"], p_moe["experts"], k=cfg.moe_k,
             renorm=cfg.moe_norm_topk,
@@ -197,8 +198,7 @@ def _moe_mlp(cfg: TransformerConfig, p_moe, h, with_routing: bool = False,
                  else jax.nn.gelu),
             kernel_of=lambda p: _kernel_of(p, h.dtype), interpret=interpret,
             layer=layer)
-        y = y.reshape(B, T, H)
-        return (y, routing) if with_routing else y
+        return y.reshape(B, T, H), routing
     from ..moe.sharded_moe import top1_gating, top2_gating
     gate_logits = tokens.astype(jnp.float32) @ p_moe["gate"]["kernel"]
     gating = top1_gating if cfg.moe_k == 1 else top2_gating
@@ -213,7 +213,6 @@ def _moe_mlp(cfg: TransformerConfig, p_moe, h, with_routing: bool = False,
 
     if "gate" in p_moe["experts"]:
         # SwiGLU experts (Mixtral family): proj(act(gate(x)) * fc(x))
-        from .transformer import _ACTIVATIONS
         act = _ACTIVATIONS[cfg.activation]
         g = act(edense(disp, p_moe["experts"]["gate"]))
         hh = g * edense(disp, p_moe["experts"]["fc"])
@@ -221,18 +220,280 @@ def _moe_mlp(cfg: TransformerConfig, p_moe, h, with_routing: bool = False,
         hh = jax.nn.gelu(edense(disp, p_moe["experts"]["fc"]))
     out = edense(hh, p_moe["experts"]["proj"], "ecm,emh->ech")
     y = jnp.einsum("tec,ech->th", combine.astype(h.dtype), out)
-    return y.reshape(B, T, H)
+    return y.reshape(B, T, H), None
+
+
+def attention_constants(cfg: TransformerConfig):
+    """``(softmax scale, ALiBi slopes [nh] or None)`` of a config."""
+    sm_scale = (cfg.attn_scale if cfg.attn_scale is not None
+                else 1.0 / np.sqrt(cfg.head_dim))
+    slopes = (jnp.asarray(alibi_slopes(cfg.num_heads), jnp.float32)
+              if cfg.pos_embed == "alibi" else None)
+    return sm_scale, slopes
+
+
+def decoder_forward(cfg: TransformerConfig, params: PyTree,
+                    input_ids: jnp.ndarray, cache, *,
+                    interpret: bool = False, expert_counts: bool = False):
+    """The inference decoder over a KV cache: ``input_ids`` [B, T] ->
+    ``(logits [B, T, V] f32, the cache as its caller keeps it, expert counts
+    [L, E] or None)``. ``forward_with_cache`` and ``serving.model_runner.
+    paged_forward`` are this function over two caches; a new kind of
+    per-sequence state (a latent cache, a recurrent state) is a third.
+
+    ``cache`` is plain Python closed over at trace time (:class:`DenseCache`,
+    ``model_runner.PagedCache``) and answers what differs between them:
+
+    * ``positions(T)``: the ``[T]`` or ``[B, T]`` logical positions of the
+      call's tokens; ``rope_len``: the length the rotary table covers;
+    * ``plan(T)``: called once before the layer loop, for what is the same
+      in every layer (a mask, where the new rows go);
+    * ``carry()`` / ``finish(carry, T)``: the arrays the loop carries and the
+      caller's cache made of them. The whole ``[L, ...]`` buffers ride in the
+      carry, so a layer's write is an in-place dynamic-update-slice inside
+      the compiled loop (stacked scan outputs were copied whole every layer);
+    * ``write(carry, li, k, v, k_scale, v_scale) -> carry``: layer ``li``'s
+      new rows ``[B, nh, T, hd]`` (``quantized``: int8, with f32 scales);
+    * ``attend(carry, li, q, k, v, window) -> [B, nh, T, hd]``;
+    * ``real_tokens(pos)``: ``[B, T]`` int32, the tokens a request owns
+      (``expert_counts``, a dropless MoE config only: how many of them each
+      layer's router sent to each expert).
+
+    Covers the policy architectures (learned/rotary/alibi positions, GQA,
+    parallel residual, per-layer windows, sandwich norms, softcaps, q/k
+    norms, MoE MLPs, int8 weights: ``interpret`` runs their Pallas matmul
+    interpreted). post_ln (BERT) has no decode path."""
+    if cfg.post_ln:
+        raise NotImplementedError("post-LN encoders (BERT) do not decode")
+    if "blocks" not in params:
+        raise ValueError(
+            "the decoder needs scan-layers params (a 'blocks' subtree "
+            "stacked [L, ...]): models.generation.ensure_scan_layout")
+    if expert_counts and not cfg.moe_is_dropless:
+        raise ValueError("expert_counts needs a dropless MoE config")
+    B, T = input_ids.shape
+    nh, hd, kvh = cfg.num_heads, cfg.head_dim, cfg.kv_heads
+    rms = cfg.norm == "rmsnorm"
+    act = _ACTIVATIONS[cfg.activation]
+    norm = lambda t, p: _layer_norm(t, p, cfg.layer_norm_eps, rms)
+    dense = partial(_dense, interpret=interpret)
+
+    with jax.named_scope("embed"):
+        wte = params["wte"]["embedding"]
+        x = wte.astype(cfg.dtype)[input_ids]
+        if cfg.embed_scale is not None:
+            x = x * jnp.asarray(cfg.embed_scale, x.dtype)
+        pos = cache.positions(T)
+        if cfg.pos_embed == "learned":
+            wpe = params["wpe"]["embedding"].astype(cfg.dtype)
+            # clamped: a paged lane's padding may lie past the table's end
+            x = x + wpe[jnp.minimum(pos, wpe.shape[0] - 1)]
+        if cfg.embed_ln:
+            x = norm(x, params["ln_emb"])
+
+    windows = (jnp.asarray(cfg.layer_windows, jnp.int32)
+               if cfg.layer_windows is not None
+               else jnp.zeros((cfg.num_layers,), jnp.int32))
+    cache.plan(T)
+    real = cache.real_tokens(pos) if expert_counts else None
+
+    def layer(carry, xs):
+        x, kv = carry
+        p, window, li = xs
+        with jax.named_scope("block.attn"):
+            with jax.named_scope("qkv"):
+                h = norm(x, p["ln1"])
+                qkv = dense(h, p["attn_qkv"])
+                q, k, v = jnp.split(qkv, [nh * hd, (nh + kvh) * hd],
+                                    axis=-1)
+                to_heads = lambda t, n: t.reshape(B, T, n, hd).transpose(
+                    0, 2, 1, 3)
+                q, k = _qk_norm(cfg, p, q, k, "projection")  # OLMoE: whole
+                q, k, v = to_heads(q, nh), to_heads(k, kvh), to_heads(v, kvh)
+                q, k = _qk_norm(cfg, p, q, k, "head")        # Qwen3: a head
+                if cfg.pos_embed == "rotary":
+                    # the table covers the cache's capacity (dynamic NTK
+                    # stretches once; a plain-theta table has no length)
+                    rot = partial(apply_rotary, positions=pos,
+                                  rotary_dim=cfg.rotary_dim,
+                                  interleaved=cfg.rotary_interleaved,
+                                  theta=cfg.rope_theta,
+                                  inv_freq=cfg.rope_inv_freq(cache.rope_len))
+                    q, k = rot(q), rot(k)
+            with jax.named_scope("kv_write"):
+                if kvh != nh:
+                    # GQA: repeat kv to full heads BEFORE the write, so the
+                    # paged kernel and the int8 tiers apply unchanged
+                    # (ROADMAP S2: kv_heads would shrink a cache nh/kvh-fold)
+                    k = jnp.repeat(k, nh // kvh, axis=1)
+                    v = jnp.repeat(v, nh // kvh, axis=1)
+                ks = vs = None
+                if cache.quantized:     # on write: one format, every cache
+                    (k, ks), (v, vs) = kv_quantize(k), kv_quantize(v)
+                kv = cache.write(kv, li, k, v, ks, vs)
+            with jax.named_scope("attend"):
+                o = cache.attend(kv, li, q, k, v, window)
+            with jax.named_scope("out"):
+                o = o.transpose(0, 2, 1, 3).reshape(B, T, nh * hd)
+                attn_out = dense(o, p["attn_proj"])
+                if cfg.post_block_norms:
+                    # Gemma-2 sandwich: norm each branch output pre-residual
+                    attn_out = norm(attn_out, p["post_attn_norm"])
+
+        def mlp(hin):
+            """``(the MLP branch, this layer's expert counts or None)``."""
+            if cfg.moe_experts > 0:
+                # a dropless mixture's expert stack stays whole beside the
+                # loop, the kernel picks this layer's
+                p_moe = (p["moe"] if experts is None
+                         else dict(p["moe"], experts=experts))
+                y, routing = _moe_mlp(cfg, p_moe, hin, interpret, layer=li)
+                if not expert_counts:
+                    return y, None
+                with jax.named_scope("route"):
+                    return y, jnp.zeros((cfg.moe_experts,), jnp.int32).at[
+                        routing.experts.reshape(-1)].add(
+                        jnp.repeat(real.reshape(-1), cfg.moe_k))
+            if cfg.gated_mlp:            # SwiGLU (Llama family)
+                g = act(dense(hin, p["mlp_gate"]))
+                return dense(g * dense(hin, p["mlp_fc"]), p["mlp_proj"]), None
+            return dense(act(dense(hin, p["mlp_fc"])), p["mlp_proj"]), None
+
+        with jax.named_scope("block.mlp"):
+            if cfg.parallel_residual:
+                # GPT-NeoX feeds the MLP branch from its own ln2; GPT-J
+                # shares ln1
+                m, counts = mlp(norm(x, p["ln2"])
+                                if cfg.parallel_residual_dual_ln else h)
+                x_out = x + attn_out + m
+            else:
+                x_mid = x + attn_out
+                m, counts = mlp(norm(x_mid, p["ln2"]))
+                if cfg.post_block_norms:
+                    m = norm(m, p["post_mlp_norm"])
+                x_out = x_mid + m
+        return (x_out, kv), counts
+
+    blocks, experts = split_stacked_experts(cfg, params["blocks"])
+    xs = (blocks, windows, jnp.arange(cfg.num_layers))
+    carry = cache.carry()
+    with jax.named_scope("layers"):
+        (x, carry), counts = jax.lax.scan(layer, (x, carry), xs)
+    out = cache.finish(carry, T)
+    with jax.named_scope("head"):
+        x = norm(x, params["ln_f"])
+        if cfg.tie_embeddings:
+            logits = jnp.einsum("bth,vh->btv", x, wte.astype(x.dtype))
+        else:
+            logits = dense(x, params["lm_head"])
+        if cfg.final_logit_softcap:
+            # stays f32 (the return casts to f32 anyway): a bf16 round-trip
+            # of the capped logits could flip near-tie argmaxes
+            logits = apply_softcap(logits, cfg.final_logit_softcap)
+    return logits.astype(jnp.float32), out, counts
+
+
+class DenseCache:
+    """``generate()``'s cache behind :func:`decoder_forward`: ``init_cache``'s
+    ``[L, B, nh, len, hd]`` buffers, the whole batch writing at the one slot
+    ``cache["pos"]``, ragged prompts left-padded (``cache["pad"]``).
+    Attention masks the whole preallocated cache (f32 scores, -1e30), or,
+    where ``prefill_flash`` holds, runs the flash kernel over the fresh K/V."""
+
+    def __init__(self, cfg: TransformerConfig, cache: Dict, prefill_flash):
+        self.cfg, self.cache, self.prefill_flash = cfg, cache, prefill_flash
+        self.quantized = cache["k"].dtype == jnp.int8
+        self.rope_len = cache["k"].shape[3]
+        self.sm_scale, self.slopes = attention_constants(cfg)
+
+    def positions(self, T: int):
+        slots = self.cache["pos"] + jnp.arange(T)
+        pad = self.cache.get("pad")         # ragged: less the left pad, [B, T]
+        return (slots if pad is None
+                else jnp.maximum(slots[None, :] - pad[:, None], 0))
+
+    def plan(self, T: int) -> None:
+        cfg, pad = self.cfg, self.cache.get("pad")
+        # prefill on the flash kernel (empty cache: the caller's contract).
+        # Alibi, softcap and a UNIFORM static window all run in-kernel; mixed
+        # per-layer windows trace through one scan body, and ragged or int8
+        # caches need the masked read: those keep the jnp path
+        self.flash = (bool(self.prefill_flash) and T > 1 and pad is None
+                      and not self.quantized
+                      and cfg.uniform_window() is not None
+                      and cfg.attention_impl in ("auto", "flash")
+                      and (jax.default_backend() == "tpu"
+                           or self.prefill_flash == "interpret"))
+        self.q_slot = q = self.cache["pos"] + jnp.arange(T)        # [T]
+        self.k_slot = k = jnp.arange(self.rope_len)                # [len]
+        # causal-with-cache [T, len]; dead left-pad slots never attend (per
+        # sample): [B, T, len]
+        self.mask = k[None, :] <= q[:, None]
+        if pad is not None:
+            self.mask = self.mask[None] & (k[None, None, :]
+                                           >= pad[:, None, None])
+        self.alibi = None
+        if self.slopes is not None:                         # [nh, T, len]
+            dist = (k[None, :] - q[:, None]).astype(jnp.float32)
+            self.alibi = self.slopes[:, None, None] * dist[None]
+
+    def carry(self):
+        return {n: a for n, a in self.cache.items() if n not in ("pos", "pad")}
+
+    def finish(self, carry, T: int):
+        return {**self.cache, **carry, "pos": self.cache["pos"] + T}
+
+    def write(self, kv, li, k, v, k_scale, v_scale):
+        new = {"k": k, "v": v, "k_scale": k_scale, "v_scale": v_scale}
+        at = (li, 0, 0, self.cache["pos"], 0)
+        return {n: jax.lax.dynamic_update_slice(a, new[n][None], at)
+                for n, a in kv.items()}
+
+    def attend(self, kv, li, q, k, v, window):
+        cfg = self.cfg
+        if self.flash:
+            # empty cache: attention over the FRESH k/v is exactly the
+            # causal prefill (alibi distances from arange positions match
+            # the slots because pos == 0)
+            return flash_attention_on_mesh(
+                q, k, v, causal=True, sm_scale=self.sm_scale,
+                window=cfg.uniform_window(), softcap=cfg.attn_softcap,
+                alibi_slopes=self.slopes,
+                interpret=self.prefill_flash == "interpret")
+        # the slice reads fuse into the attention consumers (no copy)
+        of_layer = lambda a: jax.lax.dynamic_index_in_dim(a, li, 0,
+                                                          keepdims=False)
+        k_all, v_all = of_layer(kv["k"]), of_layer(kv["v"])
+        if self.quantized:
+            # dequantize on read: int8 x f32 per-position scale (the HBM
+            # read is the int8 bytes; the multiply fuses)
+            k_all = (k_all.astype(jnp.float32)
+                     * of_layer(kv["k_scale"])).astype(q.dtype)
+            v_all = (v_all.astype(jnp.float32)
+                     * of_layer(kv["v_scale"])).astype(q.dtype)
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k_all).astype(jnp.float32)
+        s = s * self.sm_scale
+        if cfg.attn_softcap:
+            s = apply_softcap(s, cfg.attn_softcap)
+        if self.alibi is not None:
+            s = s + self.alibi[None]
+        # local sliding window (0 = global); slot distance == logical
+        # distance for valid pairs (the left-pad offset cancels). The mask
+        # is [B, T, len] for ragged batches, [T, len] otherwise
+        m = self.mask & ((self.q_slot[:, None] - self.k_slot[None, :] < window)
+                         | (window <= 0))
+        s = jnp.where(m[:, None] if m.ndim == 3 else m[None, None], s, -1e30)
+        prob = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+        return jnp.einsum("bhqk,bhkd->bhqd", prob, v_all)
 
 
 def forward_with_cache(cfg: TransformerConfig, params: PyTree,
                        input_ids: jnp.ndarray, cache: Dict,
-                       prefer_kernel: Optional[bool] = None,
-                       prefill_flash=False
-                       ) -> Tuple[jnp.ndarray, Dict]:
+                       prefill_flash=False) -> Tuple[jnp.ndarray, Dict]:
     """Run T_new tokens at positions [cache.pos, cache.pos+T_new) against the
-    cache. Returns (logits [B, T_new, V], updated cache). Params must be the
-    scan-layers layout (blocks leaves [L, ...]) — use ensure_scan_layout to
-    restack a per-layer tree.
+    cache: :func:`decoder_forward` over a :class:`DenseCache`. Returns
+    (logits [B, T_new, V], updated cache). Params must be the scan-layers
+    layout (blocks leaves [L, ...]): ensure_scan_layout restacks a tree.
 
     ``prefill_flash``: the caller guarantees the cache is EMPTY (pos == 0) —
     the prefill attention then runs the Pallas flash kernel over the fresh
@@ -240,276 +501,10 @@ def forward_with_cache(cfg: TransformerConfig, params: PyTree,
     window) instead of masking the whole preallocated cache, so prefill cost
     scales with the prompt, not max_len. TPU only (pass "interpret" to force
     the interpreted kernel in tests); ragged (left-padded), int8-cache, and
-    mixed-per-layer-window models keep the jnp path.
-
-    Covers the policy architectures: rotary/alibi positions, parallel
-    residual (GPT-J), per-layer local windows (GPT-Neo), relu/gelu
-    activations, unscaled attention, MoE MLPs. post_ln (BERT) has no decode
-    path — encoders don't generate."""
-    if cfg.post_ln:
-        raise NotImplementedError("post-LN encoders (BERT) do not decode")
-    if "blocks" not in params:
-        raise ValueError(
-            "forward_with_cache needs scan-layers params (a 'blocks' subtree "
-            "stacked [L, ...]); this model was built with scan_layers=False — "
-            "restack with models.generation.ensure_scan_layout(params, L)")
-    B, T_new = input_ids.shape
-    pos = cache["pos"]
-    max_len = cache["k"].shape[3]
-    nh, hd = cfg.num_heads, cfg.head_dim
-    kvh = cfg.kv_heads
-    rms = cfg.norm == "rmsnorm"
-    from .transformer import _ACTIVATIONS, alibi_slopes, apply_rotary
-    act = _ACTIVATIONS[cfg.activation]
-    sm_scale = (cfg.attn_scale if cfg.attn_scale is not None
-                else 1.0 / np.sqrt(hd))
-
-    wte = params["wte"]["embedding"]
-    x = wte.astype(cfg.dtype)[input_ids]
-    if cfg.embed_scale is not None:
-        x = x * jnp.asarray(cfg.embed_scale, x.dtype)
-    q_abs = pos + jnp.arange(T_new)                 # cache-slot positions [T]
-    pad = cache.get("pad")                          # [B] left-pad lengths
-    # logical positions (rotary / learned-wpe / HF position_ids semantics):
-    # slot - pad for left-padded ragged batches, the slot itself otherwise
-    if pad is not None:
-        q_log = jnp.maximum(q_abs[None, :] - pad[:, None], 0)    # [B, T]
-    else:
-        q_log = q_abs
-    if cfg.pos_embed == "learned":
-        wpe = params["wpe"]["embedding"].astype(cfg.dtype)
-        x = x + (wpe[q_log] if pad is not None else wpe[q_log][None])
-    if cfg.embed_ln:
-        x = _layer_norm(x, params["ln_emb"], cfg.layer_norm_eps, rms)
-
-    k_pos = jnp.arange(max_len)                     # [max_len]
-    # causal-with-cache mask [T_new, max_len]
-    mask = k_pos[None, :] <= q_abs[:, None]
-    if pad is not None:
-        # dead left-pad slots never attend (per sample): [B, T, max_len]
-        mask = mask[None] & (k_pos[None, None, :] >= pad[:, None, None])
-    ali = None
-    if cfg.pos_embed == "alibi":
-        slopes = jnp.asarray(alibi_slopes(nh), jnp.float32)
-        dist = (k_pos[None, :] - q_abs[:, None]).astype(jnp.float32)
-        ali = slopes[:, None, None] * dist[None]    # [nh, T_new, max_len]
-
-    windows = (jnp.asarray(cfg.layer_windows, jnp.int32)
-               if cfg.layer_windows is not None
-               else jnp.zeros((cfg.num_layers,), jnp.int32))
-
-    quant_kv = cache["k"].dtype == jnp.int8
-
-    # Pallas decode kernel: visits only the live ceil(cur_len/block_k) K/V
-    # blocks — the slot of the reference's fused softmax_context kernels
-    # (pt_binding.cpp:1703-1779). Regime-aware routing under "auto"
-    # (round-4 measurements, docs/BENCHMARKS.md): the block-skip pays in
-    # BATCHED LONG GENERATION (B>=2, a mostly-dead preallocated cache —
-    # 1.77x at B=4, 128-prompt + 2048-new, gpt2-350m) and LOSES 2-8x at
-    # B=1 / short caches, where per-layer kernel dispatch dominates.
-    # ``prefer_kernel`` (generate passes it from the static prompt/gen
-    # plan) overrides the local B/max_len heuristic. "flash" forces the
-    # kernel. ALiBi slopes and the Gemma-2 softcap run IN-KERNEL (round-8
-    # parity with the flash prefill kernel); ragged (left-padded) batches
-    # need per-sample masks -> jnp path; the int8 cache needs the dequant
-    # read -> jnp path.
-    if prefer_kernel is None:
-        prefer_kernel = B >= 2 and max_len >= 4 * 512
-    use_kernel = ((cfg.attention_impl == "flash"
-                   or (cfg.attention_impl == "auto" and prefer_kernel))
-                  and jax.default_backend() == "tpu")
-    if use_kernel:
-        # the route away from the kernel is decided HERE, from shapes and
-        # regime, and said once — never by catching what the kernel raises
-        from ..ops.pallas.decode_attention import untileable
-        from ..utils.logging import warning_once
-        reason = ("ragged (left-padded) batches need per-sample masks"
-                  if pad is not None else
-                  "the int8 cache needs the dequant read" if quant_kv else
-                  untileable(T_new, max_len, hd))
-        if reason is not None:
-            use_kernel = False
-            # a whole-prompt prefill (T > 64) off the decode kernel is the
-            # documented regime, not news
-            if T_new <= 64:
-                warning_once("decode attention on TPU takes the jnp path: "
-                             f"{reason}")
-
-    # prefill on the flash kernel (empty cache — caller's contract): alibi,
-    # softcap and a UNIFORM static window all run in-kernel; mixed per-layer
-    # windows trace through one scan body, so they stay on the jnp path
-    uw = cfg.uniform_window()
-    uniform_ok = uw is not None
-    uniform_window = uw or 0
-    flash_interp = prefill_flash == "interpret"
-    use_prefill_flash = (bool(prefill_flash) and T_new > 1 and pad is None
-                         and not quant_kv and uniform_ok
-                         and cfg.attention_impl in ("auto", "flash")
-                         and (jax.default_backend() == "tpu" or flash_interp))
-    prefill_slopes = (jnp.asarray(alibi_slopes(nh), jnp.float32)
-                      if cfg.pos_embed == "alibi" else None)
-
-    def layer(carry, xs):
-        # the FULL [L, ...] caches ride in the carry so the per-token write
-        # is an in-place dynamic-update-slice inside the compiled loop — the
-        # stacked-ys layout copied the whole cache every layer (O(L x
-        # max_len) HBM traffic per token, the decode bottleneck)
-        if quant_kv:
-            x, k_all, v_all, ks_all, vs_all = carry
-        else:
-            x, k_all, v_all = carry
-            ks_all = vs_all = None
-        p, window, li = xs
-        h = _layer_norm(x, p["ln1"], cfg.layer_norm_eps, rms)
-        qkv = _dense(h, p["attn_qkv"])
-        q, k, v = jnp.split(qkv, [nh * hd, (nh + kvh) * hd], axis=-1)
-        to_heads = lambda t, n: t.reshape(B, T_new, n, hd).transpose(
-            0, 2, 1, 3)
-        q, k = _qk_norm(cfg, p, q, k, "projection")   # OLMoE: whole vector
-        q, k, v = to_heads(q, nh), to_heads(k, kvh), to_heads(v, kvh)
-        q, k = _qk_norm(cfg, p, q, k, "head")         # Qwen3: per head
-        if cfg.pos_embed == "rotary":
-            # q_log: logical (pad-corrected) positions — [B, T] for ragged
-            # left-padded batches, [T] otherwise (apply_rotary handles both)
-            # table covers the cache capacity (dynamic NTK stretches once;
-            # None = plain-theta table)
-            inv_freq = cfg.rope_inv_freq(max_len)
-            q = apply_rotary(q, q_log, cfg.rotary_dim, cfg.rotary_interleaved,
-                             cfg.rope_theta, inv_freq=inv_freq)
-            k = apply_rotary(k, q_log, cfg.rotary_dim, cfg.rotary_interleaved,
-                             cfg.rope_theta, inv_freq=inv_freq)
-        if kvh != nh:
-            # GQA: repeat kv to full heads BEFORE the cache write — the
-            # cache stays [L, B, nh, len, hd], so the decode kernel and
-            # int8 tiers apply unchanged. (Storing kv heads only would
-            # shrink the cache nh/kvh-fold; future optimization.)
-            k = jnp.repeat(k, nh // kvh, axis=1)
-            v = jnp.repeat(v, nh // kvh, axis=1)
-        if quant_kv:
-            k, k_s = _kv_quantize(k)
-            v, v_s = _kv_quantize(v)
-            ks_all = jax.lax.dynamic_update_slice(ks_all, k_s[None],
-                                                  (li, 0, 0, pos, 0))
-            vs_all = jax.lax.dynamic_update_slice(vs_all, v_s[None],
-                                                  (li, 0, 0, pos, 0))
-        k_all = jax.lax.dynamic_update_slice(k_all, k[None],
-                                             (li, 0, 0, pos, 0))
-        v_all = jax.lax.dynamic_update_slice(v_all, v[None],
-                                             (li, 0, 0, pos, 0))
-        o = None
-        if use_prefill_flash:
-            from ..ops.attention import flash_attention_on_mesh
-            # empty cache: attention over the FRESH k/v is exactly the
-            # causal prefill; alibi distances from arange positions match
-            # q_abs because pos == 0
-            o = flash_attention_on_mesh(
-                q, k, v, causal=True, sm_scale=sm_scale,
-                window=uniform_window, softcap=cfg.attn_softcap,
-                alibi_slopes=prefill_slopes, interpret=flash_interp)
-        if o is None and use_kernel:
-            from ..ops.pallas.decode_attention import decode_attention
-            # stacked form: the kernel indexes layer li out of the
-            # carried [L, ...] cache itself — no materialized slice;
-            # alibi slopes / softcap ride in-kernel. Shapes were tested
-            # above (``untileable``), so an error here is an error
-            o = decode_attention(q, k_all, v_all, pos + T_new,
-                                 window=window, sm_scale=sm_scale,
-                                 layer_idx=li,
-                                 alibi_slopes=prefill_slopes,
-                                 softcap=cfg.attn_softcap)
-        if o is None:
-            # the slice reads fuse into the attention consumers (no copy)
-            k_cache = jax.lax.dynamic_index_in_dim(k_all, li, 0,
-                                                   keepdims=False)
-            v_cache = jax.lax.dynamic_index_in_dim(v_all, li, 0,
-                                                   keepdims=False)
-            if quant_kv:
-                # dequantize on read: int8 x f32 per-position scale (the
-                # HBM read is the int8 bytes; the multiply fuses)
-                k_sc = jax.lax.dynamic_index_in_dim(ks_all, li, 0,
-                                                    keepdims=False)
-                v_sc = jax.lax.dynamic_index_in_dim(vs_all, li, 0,
-                                                    keepdims=False)
-                k_cache = (k_cache.astype(jnp.float32) * k_sc).astype(q.dtype)
-                v_cache = (v_cache.astype(jnp.float32) * v_sc).astype(q.dtype)
-            s = jnp.einsum("bhqd,bhkd->bhqk", q, k_cache).astype(jnp.float32)
-            s = s * sm_scale
-            if cfg.attn_softcap:
-                from ..ops.attention import apply_softcap
-                s = apply_softcap(s, cfg.attn_softcap)
-            if ali is not None:
-                s = s + ali[None]
-            m = mask
-            # local sliding window (0 = global); slot distance == logical
-            # distance for valid pairs (the left-pad offset cancels)
-            win = (q_abs[:, None] - k_pos[None, :] < window) | (window <= 0)
-            m = m & (win[None] if m.ndim == 3 else win)
-            # mask is [B, T, max_len] for ragged batches, [T, max_len] else
-            s = jnp.where(m[:, None] if m.ndim == 3 else m[None, None],
-                          s, -1e30)
-            prob = jax.nn.softmax(s, axis=-1).astype(x.dtype)
-            o = jnp.einsum("bhqk,bhkd->bhqd", prob, v_cache)
-        o = o.transpose(0, 2, 1, 3).reshape(B, T_new, nh * hd)
-        attn_out = _dense(o, p["attn_proj"])
-        if cfg.post_block_norms:
-            # Gemma-2 sandwich: norm each branch output pre-residual
-            attn_out = _layer_norm(attn_out, p["post_attn_norm"],
-                                   cfg.layer_norm_eps, rms)
-
-        def mlp(hin):
-            if cfg.moe_experts > 0:
-                if experts is not None:
-                    return _moe_mlp(cfg, dict(p["moe"], experts=experts),
-                                    hin, layer=li)
-                return _moe_mlp(cfg, p["moe"], hin)
-            if cfg.gated_mlp:            # SwiGLU (Llama family)
-                g = act(_dense(hin, p["mlp_gate"]))
-                return _dense(g * _dense(hin, p["mlp_fc"]), p["mlp_proj"])
-            return _dense(act(_dense(hin, p["mlp_fc"])), p["mlp_proj"])
-
-        if cfg.parallel_residual:
-            # GPT-NeoX feeds the MLP branch from its own ln2; GPT-J shares ln1
-            m_in = (_layer_norm(x, p["ln2"], cfg.layer_norm_eps, rms)
-                    if cfg.parallel_residual_dual_ln else h)
-            x_out = x + attn_out + mlp(m_in)
-        else:
-            x_mid = x + attn_out
-            h2 = _layer_norm(x_mid, p["ln2"], cfg.layer_norm_eps, rms)
-            m = mlp(h2)
-            if cfg.post_block_norms:
-                m = _layer_norm(m, p["post_mlp_norm"],
-                                cfg.layer_norm_eps, rms)
-            x_out = x_mid + m
-        if quant_kv:
-            return (x_out, k_all, v_all, ks_all, vs_all), None
-        return (x_out, k_all, v_all), None
-
-    blocks, experts = split_stacked_experts(cfg, params["blocks"])
-    xs = (blocks, windows, jnp.arange(cfg.num_layers))
-    if quant_kv:
-        (x, k_new, v_new, ks_new, vs_new), _ = jax.lax.scan(
-            layer, (x, cache["k"], cache["v"], cache["k_scale"],
-                    cache["v_scale"]), xs)
-    else:
-        (x, k_new, v_new), _ = jax.lax.scan(
-            layer, (x, cache["k"], cache["v"]), xs)
-    x = _layer_norm(x, params["ln_f"], cfg.layer_norm_eps, rms)
-    if cfg.tie_embeddings:
-        logits = jnp.einsum("bth,vh->btv", x, wte.astype(x.dtype))
-    else:
-        logits = _dense(x, params["lm_head"])
-    if cfg.final_logit_softcap:
-        # stay f32: the return below casts to f32 anyway, and a bf16
-        # round-trip of the capped logits could flip near-tie argmaxes
-        from ..ops.attention import apply_softcap
-        logits = apply_softcap(logits, cfg.final_logit_softcap)
-    new_cache = {"k": k_new, "v": v_new, "pos": pos + T_new}
-    if quant_kv:
-        new_cache["k_scale"] = ks_new
-        new_cache["v_scale"] = vs_new
-    if pad is not None:
-        new_cache["pad"] = pad
-    return logits.astype(jnp.float32), new_cache
+    mixed-per-layer-window models keep the jnp path."""
+    logits, cache, _ = decoder_forward(
+        cfg, params, input_ids, DenseCache(cfg, cache, prefill_flash))
+    return logits, cache
 
 
 def apply_top_p(logits, top_p: float):
@@ -570,14 +565,10 @@ def generate(cfg: TransformerConfig,
     HF tokenizers pad RIGHT by default, and a right-padded mask would
     silently decode garbage (the ragged path assumes pads-first). See
     _generate for the full contract."""
-    if isinstance(attention_mask, jax.core.Tracer):
-        # under an outer jit/vmap/scan the mask is a tracer — host
-        # validation is impossible there; inline the jitted program as the
-        # pre-wrapper generate() did
-        return _generate(cfg, params, input_ids, max_new_tokens,
-                         temperature, rng, top_k, top_p, repetition_penalty,
-                         attention_mask, kv_cache_dtype)
-    if attention_mask is not None:
+    # (under an outer jit/vmap/scan the mask is a tracer: host validation
+    # is impossible there, and the jitted program inlines)
+    if attention_mask is not None and not isinstance(attention_mask,
+                                                     jax.core.Tracer):
         # int cast first: np.diff on a BOOL array is XOR (always >= 0), so
         # a bool right-padded mask would sail through the guard
         mask_np = np.asarray(attention_mask, dtype=np.int32)
@@ -586,12 +577,9 @@ def generate(cfg: TransformerConfig,
                 "generate() requires LEFT-padded prompts: every "
                 "attention_mask row must be non-decreasing (0s then 1s). "
                 "Re-tokenize with padding_side='left'.")
-        if mask_np.all():
-            # uniform batch: dropping the mask keeps the Pallas decode
-            # kernel engaged (per-sample masks force the jnp fallback)
-            attention_mask = None
-        else:
-            attention_mask = jnp.asarray(mask_np)
+        # uniform batch: dropping the mask keeps the flash prefill eligible
+        # (per-sample masks take the masked jnp attention)
+        attention_mask = None if mask_np.all() else jnp.asarray(mask_np)
     return _generate(cfg, params, input_ids, max_new_tokens, temperature,
                      rng, top_k, top_p, repetition_penalty, attention_mask,
                      kv_cache_dtype)
@@ -635,22 +623,17 @@ def _generate(cfg: TransformerConfig,
     if attention_mask is not None:
         pad_lens = (T_in - jnp.sum(attention_mask.astype(jnp.int32), axis=1)
                     ).astype(jnp.int32)
-    # round the workspace up to a decode-kernel-friendly block multiple
-    # (positions past the logical max are masked, never attended).
+    # round the workspace up to its compile bucket (positions past the
+    # logical max are masked, never attended).
     # kv_cache_dtype="int8": half the KV HBM (2x context/batch capacity),
     # dequant-on-read attention — see init_cache.
     kv_dtype = jnp.int8 if kv_cache_dtype == "int8" else None
     padded_len = padded_cache_len(max_len)
     cache = init_cache(cfg, B, padded_len, dtype=kv_dtype,
                        pad_lens=pad_lens)
-    # static routing hint for the decode kernel: batched long generation
-    # (most of the preallocated cache dead through the run) is its regime
-    prefer_kernel = (B >= 2 and padded_len >= 4 * 512
-                     and T_in <= padded_len // 2)
     # the first forward runs against the freshly-initialized (empty) cache:
     # prefill attention rides the flash kernel where eligible
     logits, cache = forward_with_cache(cfg, params, input_ids, cache,
-                                       prefer_kernel=prefer_kernel,
                                        prefill_flash=True)
 
     rep = repetition_penalty is not None and repetition_penalty != 1.0
@@ -680,8 +663,7 @@ def _generate(cfg: TransformerConfig,
 
     def step(carry, _):
         tok, cache, rng, seen = carry
-        logits, cache = forward_with_cache(cfg, params, tok[:, None], cache,
-                                           prefer_kernel=prefer_kernel)
+        logits, cache = forward_with_cache(cfg, params, tok[:, None], cache)
         rng, r = jax.random.split(rng)
         nxt, seen = pick(logits[:, -1], seen, r)
         return (nxt, cache, rng, seen), tok

@@ -1,8 +1,8 @@
 """Dropless top-k mixture of experts by sorted-token dispatch.
 
 The one MoE function behind ``moe/layer.MoE`` (``k > 2`` or a dropless
-config), ``models/generation._moe_mlp`` and ``serving/model_runner.
-paged_forward``: OLMoE-class routing (64 experts, top-8) that the GShard
+config) and ``models/generation._moe_mlp`` (the inference decoder's MLP,
+dense and paged): OLMoE-class routing (64 experts, top-8) that the GShard
 capacity path in ``sharded_moe.py`` cannot carry, because a one-hot over
 ``[tokens, experts, capacity]`` costs ``experts / k`` times the needed
 FLOPs and a capacity drops tokens the architecture never drops.

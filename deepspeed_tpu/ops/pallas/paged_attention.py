@@ -34,12 +34,10 @@ as the table, dead steps clamped to the last live page. Same math
 
 Capability slot of the reference's fused ``softmax_context`` decode kernels
 (csrc/transformer/inference/csrc/pt_binding.cpp:1703-1779) generalized to
-the vLLM-style paged layout; the online-softmax scratch that carries m/l
-across groups is shared in kind with ops/pallas/decode_attention.py, the
-multi-page double-buffered copy with jax's own
-``pallas/ops/tpu/paged_attention`` kernel.
+the vLLM-style paged layout; the multi-page double-buffered copy is shared
+in kind with jax's own ``pallas/ops/tpu/paged_attention`` kernel.
 
-In-kernel score features (parity with the flash/decode kernels): ALiBi via
+In-kernel score features (parity with the flash kernel): ALiBi via
 per-head slopes, Gemma-2 tanh softcap, causal masking by per-sequence
 context length, and a sliding window. The jnp oracle
 :func:`paged_attention_reference` computes the identical math by dense
@@ -68,7 +66,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .decode_attention import _head_group
 from .flash_attention import NEG_INF
 
 __all__ = ["paged_attention", "paged_attention_reference", "scale_rows",
@@ -80,6 +77,12 @@ _QROWS = 8
 
 #: VMEM the K and V group buffers (two slots each) may take together
 _VMEM_BUDGET = 4 << 20
+
+
+def _head_group(nh: int, block_k: int, hd: int, itemsize: int) -> int:
+    """Heads per program: target ~1MB K blocks, largest divisor of nh."""
+    target = max(1, (1 << 20) // (block_k * hd * itemsize))
+    return max(d for d in range(1, min(nh, target) + 1) if nh % d == 0)
 
 
 def _scale_lanes(bs: int) -> int:

@@ -176,19 +176,6 @@ def test_quant_matmul_compiles(chip, monkeypatch, K, N):
     assert len(names) == 1 and "quant_matmul" in names[0], names
 
 
-def test_decode_attention_stacked_cache_compiles(chip):
-    """The dense-cache decode kernel models/generation.py routes to (B 8,
-    cache 2048, traced layer index)."""
-    from deepspeed_tpu.ops.pallas.decode_attention import decode_attention
-    q = chip((B, NH, 1, HD), jnp.bfloat16)
-    cache = chip((L, B, NH, 2048, HD), jnp.bfloat16)
-    names = _kernels(
-        lambda q, k, v, cur, li: decode_attention(q, k, v, cur,
-                                                  layer_idx=li),
-        q, cache, cache, chip((), jnp.int32), chip((), jnp.int32))
-    assert len(names) == 1 and "decode_attention" in names[0], names
-
-
 def test_sliding_window_kernel_compiles(chip):
     """The block-skip layout kernel a pure causal window routes to."""
     from deepspeed_tpu.ops.attention import sliding_window_attention

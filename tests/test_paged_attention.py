@@ -320,8 +320,8 @@ def _edge_case(regime, lens, shared=False, seed=11):
 def test_edge_shape_derives_two_ragged_groups():
     """The edge cases below mean what their names say only while the
     program derives 4 pages a group from their shape."""
-    from deepspeed_tpu.ops.pallas.decode_attention import _head_group
-    from deepspeed_tpu.ops.pallas.paged_attention import _pages_per_group
+    from deepspeed_tpu.ops.pallas.paged_attention import (_head_group,
+                                                          _pages_per_group)
     for itemsize, quant in ((4, False), (1, True)):
         hg = _head_group(_ENH, _EBS, _EHD, itemsize)
         assert hg == _ENH
