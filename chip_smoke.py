@@ -324,17 +324,10 @@ def _serve(srv, prompts, new_tokens, label: str, timeout_s: float = 600.0):
 
 
 def _decode_text(srv) -> str:
-    """Compiled text of the engine's ONE decode step (the shapes and dtypes
-    ``ServingEngine._decode_step`` passes)."""
-    import jax
-    import jax.numpy as jnp
-    B = srv.max_batch
-    return srv._decode_fn.lower(
-        srv.params, srv.pools, jnp.zeros((B,), jnp.int32),
-        jnp.zeros((B, srv.nbk), jnp.int32), jnp.zeros((B,), jnp.int32),
-        jax.random.PRNGKey(0), jnp.zeros((B,), jnp.float32),
-        jnp.zeros((B,), jnp.int32),
-        jnp.ones((B,), jnp.float32)).compile().as_text()
+    """Compiled text of the engine's ONE decode step (the one int32 buffer
+    ``ServingEngine._decode_step`` passes: the lanes' state as it stands)."""
+    return srv._decode_fn.lower(srv.params, srv.pools,
+                                srv._lanes.buf).compile().as_text()
 
 
 def _free_server(srv) -> None:

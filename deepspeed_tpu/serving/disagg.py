@@ -57,14 +57,13 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, List, Optional, Sequence
 
-import numpy as np
 
 from ..runtime.fabric import ChannelTimeout, LocalEndpoint
 from ..testing import chaos
 from ..utils.logging import logger
-from .engine import ServingEngine, _Seq, resolve_kv_dtype
+from .engine import ServingEngine, _Lanes, _Prefilled, resolve_kv_dtype
 from .kv_cache import SharedPagedState
-from .scheduler import HANDOFF, RUNNING, TIMEOUT, Request
+from .scheduler import HANDOFF, TIMEOUT, Request
 
 PyTree = Any
 
@@ -76,17 +75,13 @@ class HandoffFull(RuntimeError):
 
 
 @dataclass
-class HandoffItem:
+class HandoffItem(_Prefilled):
     """One finished prefill crossing the prefill->decode boundary: block
     ownership (IDs into the SHARED pool — zero-copy) plus the sampler
     state decode resumes from (``last_tok`` = the first sampled token,
     already on ``req.output_tokens`` as the emitted prefix; ``ctx`` = the
-    next-token logits position, i.e. the prompt length)."""
-    req: Request
-    blocks: List[int]
-    table: np.ndarray
-    ctx: int
-    last_tok: int
+    next-token logits position, i.e. the prompt length): the engine's
+    ``_Prefilled`` record (req, blocks, table, ctx, last_tok), stamped."""
     enqueue_ts: float = field(default_factory=time.monotonic)
 
 
@@ -200,7 +195,7 @@ class PrefillEngine(ServingEngine):
                  handoff: BlockHandoff, **kw):
         super().__init__(cfg, params, serving=serving, shared=shared, **kw)
         self.handoff = handoff
-        self._ready: Optional[_Seq] = None    # finished, awaiting queue room
+        self._ready: Optional[HandoffItem] = None   # finished, awaiting room
         self._handed: List[Request] = []      # pushed since last take_*
 
     # the prefill role ALWAYS runs the chunk machinery (chunk <= 0 means
@@ -226,22 +221,21 @@ class PrefillEngine(ServingEngine):
         return (self.scheduler.pending == 0 and self._prefilling is None
                 and self._ready is None)
 
-    def _install(self, seq: _Seq) -> None:
-        self._ready = seq
+    def _install(self, seq: _Prefilled) -> None:
+        self._ready = HandoffItem(seq.req, seq.blocks, seq.table, seq.ctx,
+                                  seq.last_tok)
         self._flush_ready()
 
     def _flush_ready(self) -> None:
-        seq = self._ready
-        if seq is None:
+        item = self._ready
+        if item is None:
             return
-        item = HandoffItem(req=seq.req, blocks=seq.blocks, table=seq.table,
-                           ctx=seq.ctx, last_tok=seq.last_tok)
         try:
             self.handoff.push(item)
         except HandoffFull:
             return                        # backpressure: retry next step
         self._ready = None
-        self._handed.append(seq.req)
+        self._handed.append(item.req)
 
     def take_handed_off(self) -> List[Request]:
         """Requests pushed since the last call (the fleet worker's
@@ -279,9 +273,8 @@ class PrefillEngine(ServingEngine):
             self._warming = True
             try:
                 for _ in range(2):
-                    pf = self._start_prefill(Request(prompt=[1] * n,
-                                                     max_new_tokens=1))
-                    self._prefilling = pf
+                    self._set_prefilling(self._start_prefill(
+                        Request(prompt=[1] * n, max_new_tokens=1)))
                     while self._prefilling is not None:
                         self._advance_prefill()
             finally:
@@ -347,9 +340,7 @@ class DecodeEngine(ServingEngine):
         slot = self._free_slot()
         if slot is None:
             return False
-        item.req.state = RUNNING
-        self._slots[slot] = _Seq(item.req, item.blocks, item.table,
-                                 item.ctx, item.last_tok)
+        self._place(slot, item)
         return True
 
     def _pull_handoff(self) -> None:
@@ -385,20 +376,12 @@ class DecodeEngine(ServingEngine):
         a live heartbeat timeout. Runs TWICE so both the fresh-pools and
         the donated-committed-pools specializations are compiled (see
         PrefillEngine.warm)."""
-        import jax
-        import jax.numpy as jnp
-        from .kv_cache import NULL_BLOCK
         with self._lock:
-            B = self.max_batch
             for _ in range(2):
-                self._rng, r = jax.random.split(self._rng)
-                self._run_device(
-                    self._decode_fn, jnp.zeros((B,), jnp.int32),
-                    jnp.full((B, self.nbk), NULL_BLOCK, jnp.int32),
-                    jnp.zeros((B,), jnp.int32), r,
-                    jnp.zeros((B,), jnp.float32),
-                    jnp.zeros((B,), jnp.int32),
-                    jnp.ones((B,), jnp.float32))
+                # the loop's own kind of argument (one numpy buffer), every
+                # lane idle: the ONE decode specialization
+                self._run_device(self._decode_fn, _Lanes(
+                    self._layout, self.max_batch).buf)
 
     def _collect_held(self, blocks, reqs) -> None:
         if self._holding is not None:

@@ -55,7 +55,10 @@ PyTree = Any
 #: the engine's counters; ``ServingEngine.stats`` IS its recorder's dict
 #: (utils/telemetry.py), which the paged state counts into as well
 #: (``kv.alloc`` ..., ``prefix.*``: serving/kv_cache.py). The ``*_sum``
-#: counters add one reading a step, taken on entry to ``step()``.
+#: counters add one reading a step, taken on entry to ``step()``; the two
+#: ``step_inputs.*`` count host arrays handed to the device (one a device
+#: call) and lane rows rewritten (one an install or a freed lane: a decode
+#: step that changes no lane writes none).
 _COUNTERS = (
     "completed", "failed", "timeout", "tokens_generated", "prefill_tokens",
     "prefix_hit_tokens", "preempted",
@@ -63,7 +66,8 @@ _COUNTERS = (
     "admit_blocked.no_lane", "admit_blocked.no_blocks",
     "admit_blocked.prefilling", "compiles",
     "kv.held_blocks_sum", "kv.blocks_reserved_sum", "kv.tokens_written_sum",
-    "prefix.prompt_tokens", "paged.live_pages_sum", "paged.table_pages_sum")
+    "prefix.prompt_tokens", "paged.live_pages_sum", "paged.table_pages_sum",
+    "step_inputs.transfers_sum", "step_inputs.lane_rows_written_sum")
 #: a dropless MoE model's router load, from the [L, E] counts that ride the
 #: tokens' own fetch (``_count_experts``); a dense model has none of these
 _MOE_COUNTERS = ("moe.assignments", "moe.layer_steps",
@@ -119,27 +123,101 @@ def lane_topk_topp(logits: jnp.ndarray, top_k: jnp.ndarray,
         jnp.arange(B)[:, None], order].set(final_sorted)
 
 
-def step_programs(cfg, block_size: int, *, interpret: bool = False,
-                  use_filters: bool = False):
+def _split(buf, *fields):
+    """Consecutive fields of a flat int32 buffer, ``(words, dtype)`` each:
+    views of a numpy buffer (the host writes through them), bit-casts of a
+    traced one (the program reads them)."""
+    host, out, at = isinstance(buf, np.ndarray), [], 0
+    for words, dtype in fields:
+        # lax.slice, not jnp indexing: a tenth of the trace and lowering
+        # work, nine programs at every start
+        x = buf[at:at + words] if host else jax.lax.slice(
+            buf, (at,), (at + words,))
+        if dtype != np.int32:
+            x = x.view(dtype) if host else jax.lax.bitcast_convert_type(
+                x, dtype)
+        out.append(x)
+        at += words
+    assert at == buf.shape[0], (at, buf.shape)
+    return out
+
+
+class StepLayout:
+    """Where each input of a device call lies in the ONE int32 buffer the
+    call gets (one host-to-device transfer a call, made by the jitted call
+    itself). The host fills a numpy buffer through :meth:`decode` /
+    :meth:`prefill`'s views, the program reads the same fields of its traced
+    argument: the layout is written once, here.
+
+    decode, ``B`` lanes:   ``toks[B] | ctx[B] | top_k[B] | tables[B, nbk] |
+    temps[B] | top_p[B] | key``
+    prefill, ``T`` tokens: ``ids[1, T] | table[1, nbk] | q0[1] | ctx[1] |
+    last_idx[1] | top_k[1] | temp[1] | top_p[1] | key``
+
+    ``key`` (the last words of either buffer) is the call's sampling key as
+    raw words, derived on the host (``ServingEngine._call_key``): host data
+    like the rest, so no key is split or folded by a device program of its
+    own between two steps. The table width and the key's width are the
+    layout's own; ``B`` and ``T`` follow from a buffer's length."""
+
+    def __init__(self, table_width: int, key_words: int = 2):
+        self.nbk, self.kw = int(table_width), int(key_words)
+
+    def decode_words(self, lanes: int) -> int:
+        return lanes * (5 + self.nbk) + self.kw
+
+    def prefill_words(self, tokens: int) -> int:
+        return tokens + self.nbk + 6 + self.kw
+
+    def decode(self, buf):
+        """(toks, ctx, top_k, tables, temps, top_p, key) of ``buf``."""
+        B, rest = divmod(buf.shape[0] - self.kw, 5 + self.nbk)
+        assert rest == 0, (buf.shape, self.nbk, self.kw)
+        i32, f32 = np.int32, np.float32
+        toks, ctx, tks, tables, temps, tps, key = _split(
+            buf, (B, i32), (B, i32), (B, i32), (B * self.nbk, i32),
+            (B, f32), (B, f32), (self.kw, np.uint32))
+        return toks, ctx, tks, tables.reshape(B, self.nbk), temps, tps, key
+
+    def prefill(self, buf):
+        """(ids, table, q0, ctx, last_idx, top_k, temp, top_p, key) of
+        ``buf``."""
+        T = buf.shape[0] - self.prefill_words(0)
+        i32, f32 = np.int32, np.float32
+        ids, table, q0, ctx, last_idx, tk, temp, tp, key = _split(
+            buf, (T, i32), (self.nbk, i32), (1, i32), (1, i32), (1, i32),
+            (1, i32), (1, f32), (1, f32), (self.kw, np.uint32))
+        return (ids.reshape(1, T), table.reshape(1, self.nbk), q0, ctx,
+                last_idx, tk, temp, tp, key)
+
+
+def step_programs(cfg, block_size: int, table_width: int, *,
+                  interpret: bool = False, use_filters: bool = False,
+                  key_words: int = 2):
     """The loop's two device programs as plain functions, ``(decode,
-    prefill)``: the engine jits them with the pools donated, and
-    tests/test_chip_compile.py compiles the same two for a described chip.
+    prefill)``, each ``(params, pools, step_in) -> (tokens, pools)`` with
+    ``step_in`` the call's one int32 buffer (:class:`StepLayout`, built
+    from ``table_width`` and ``key_words``): the engine jits them with the
+    pools donated, and tests/test_chip_compile.py compiles the same two for
+    a described chip.
 
     For a dropless MoE config (``cfg.moe_is_dropless``) the int32 token
     vector each returns carries, behind the tokens, the ``[L, E]`` expert
     counts of the call, flattened (``ServingEngine._count_experts`` splits
     them): one array, the fetch the step has already. Every other config
-    gets the programs it always got."""
-    bs = int(block_size)
+    gets the plain token vector."""
+    bs, layout = int(block_size), StepLayout(table_width, key_words)
+    counting = bool(cfg.moe_is_dropless)
 
-    def _pick(logits, r, temps, tks, tps):
+    def _pick(logits, key, temps, tks, tps):
         """Per-lane sampling: greedy lanes take argmax, temperature
         lanes a categorical over logits / temp — one compiled program
-        for any mix. With ``serving.sampling_filters`` (a
-        construction-time constant: the program is still compiled
-        once) the vectorized per-lane top-k/top-p filter runs on the
-        scaled logits first."""
+        for any mix. ``key``: the call's own key, raw words out of its
+        buffer. With ``serving.sampling_filters`` (a construction-time
+        constant: the program is still compiled once) the vectorized
+        per-lane top-k/top-p filter runs on the scaled logits first."""
         with jax.named_scope("sample"):
+            r = jax.random.wrap_key_data(key)
             greedy = jnp.argmax(logits, axis=-1)
             scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
             if use_filters:
@@ -147,60 +225,117 @@ def step_programs(cfg, block_size: int, *, interpret: bool = False,
             sampled = jax.random.categorical(r, scaled, axis=-1)
             return jnp.where(temps <= 0.0, greedy, sampled)
 
-    def _decode(params, pools, toks, bt, ctx, r, temps, tks, tps):
+    def _out(tokens, counts):
+        # a dropless mixture: the call's expert counts behind its tokens
+        if not counting:
+            return tokens
+        return jnp.concatenate([tokens.astype(jnp.int32),
+                                counts[0].reshape(-1)])
+
+    def _decode(params, pools, step_in):
+        toks, ctx, tks, bt, temps, tps, key = layout.decode(step_in)
         # toks [B] sit at logical position ctx[b]; after the write the
         # valid length is ctx + 1
-        logits, pools = paged_forward(
+        logits, pools, *counts = paged_forward(
             cfg, params, toks[:, None], pools, bt, ctx, ctx + 1, bs,
-            interpret=interpret)
-        return _pick(logits[:, -1], r, temps, tks, tps), pools
+            interpret=interpret, expert_counts=counting)
+        return _out(_pick(logits[:, -1], key, temps, tks, tps), counts), pools
 
-    def _prefill(params, pools, ids, bt, q0, ctx, last_idx, r, temps,
-                 tks, tps):
-        logits, pools = paged_forward(
-            cfg, params, ids, pools, bt, q0, ctx, bs, interpret=interpret)
-        last = jax.lax.dynamic_index_in_dim(logits, last_idx, 1,
-                                            keepdims=False)   # [1, V]
-        return _pick(last, r, temps, tks, tps), pools
-
-    if cfg.moe_is_dropless:
-        return _counting_programs(cfg, bs, interpret, _pick)
-    return _decode, _prefill
-
-
-def _counting_programs(cfg, bs: int, interpret: bool, _pick):
-    """``step_programs``' pair for a dropless MoE config: the same two
-    signatures, the call's expert counts packed behind its tokens."""
-    def packed(tokens, counts):
-        return jnp.concatenate([tokens.astype(jnp.int32),
-                                counts.reshape(-1)])
-
-    def _decode(params, pools, toks, bt, ctx, r, temps, tks, tps):
-        logits, pools, counts = paged_forward(
-            cfg, params, toks[:, None], pools, bt, ctx, ctx + 1, bs,
-            interpret=interpret, expert_counts=True)
-        return packed(_pick(logits[:, -1], r, temps, tks, tps), counts), pools
-
-    def _prefill(params, pools, ids, bt, q0, ctx, last_idx, r, temps,
-                 tks, tps):
-        logits, pools, counts = paged_forward(
+    def _prefill(params, pools, step_in):
+        ids, bt, q0, ctx, last_idx, tks, temps, tps, key = \
+            layout.prefill(step_in)
+        logits, pools, *counts = paged_forward(
             cfg, params, ids, pools, bt, q0, ctx, bs, interpret=interpret,
-            expert_counts=True)
-        last = jax.lax.dynamic_index_in_dim(logits, last_idx, 1,
+            expert_counts=counting)
+        last = jax.lax.dynamic_index_in_dim(logits, last_idx[0], 1,
                                             keepdims=False)   # [1, V]
-        return packed(_pick(last, r, temps, tks, tps), counts), pools
+        return _out(_pick(last, key, temps, tks, tps), counts), pools
 
     return _decode, _prefill
 
 
 @dataclass
 class _Seq:
-    """One active lane: a RUNNING request's device-side bookkeeping."""
+    """One active lane's owner record: the request and every block it
+    holds. The lane's context, last token, sampling parameters and block
+    table live in the engine's lane arrays (:class:`_Lanes`), row = the
+    lane's index, and nowhere else."""
     req: Request
     blocks: List[int]                  # every block this seq holds
+
+
+@dataclass
+class _Prefilled:
+    """A prompt whose K/V is whole in the pool and whose first token is
+    sampled, on its way to a decode lane (or, in serving/disagg.py, across
+    the handoff): what decode resumes from."""
+    req: Request
+    blocks: List[int]
     table: np.ndarray                  # [max_blocks_per_seq] i32 physical ids
     ctx: int                           # tokens whose K/V is in the pool
     last_tok: int                      # sampled, not yet written back
+
+
+class _Lanes:
+    """The decode lanes' state on the host, kept between steps IN the
+    buffer the decode program gets (:meth:`StepLayout.decode`'s views of
+    it): a row is written when a sequence is installed and when its lane is
+    freed, the whole is advanced in bulk after a step, and a step's build is
+    one copy of the buffer. An idle lane reads token 0, context 0, greedy,
+    an all-null table (its write sinks into the null block)."""
+
+    def __init__(self, layout: StepLayout, lanes: int):
+        self.buf = np.zeros((layout.decode_words(lanes),), np.int32)
+        (self.toks, self.ctx, self.tks, self.tables, self.temps, self.tps,
+         _) = layout.decode(self.buf)
+        self.tables[:] = NULL_BLOCK
+        self.tps[:] = 1.0
+        self.live = np.zeros((lanes,), bool)
+
+    def write(self, i: int, seq: _Prefilled) -> None:
+        req = seq.req
+        self.toks[i], self.ctx[i] = seq.last_tok, seq.ctx
+        self.tables[i] = seq.table
+        self.temps[i] = req.temperature
+        self.tks[i] = req.top_k or 0
+        self.tps[i] = 1.0 if req.top_p is None else req.top_p
+        self.live[i] = True
+
+    def clear(self, i: int) -> None:
+        self.toks[i] = self.ctx[i] = self.tks[i] = 0
+        self.tables[i] = NULL_BLOCK
+        self.temps[i], self.tps[i] = 0.0, 1.0
+        self.live[i] = False
+
+    def advance(self, fetched: np.ndarray) -> None:
+        """After a decode step: every live lane wrote one token and reads
+        the one just sampled for it next (an idle lane keeps token 0)."""
+        np.add(self.ctx, self.live, out=self.ctx)
+        np.multiply(fetched, self.live, out=self.toks)
+
+
+class _HeldBlocks:
+    """Running counts of the pool blocks held by the decode lanes and the
+    prompt in prefill, kept where a block list joins or leaves them: what a
+    recount over every holder's list would read on entry to a step.
+    ``distinct`` counts a forked prefix once however many lanes read it;
+    ``reserved`` counts it for each holder."""
+
+    def __init__(self, num_blocks: int):
+        self.refs = np.zeros((num_blocks,), np.int32)
+        self.distinct = self.reserved = 0
+
+    def add(self, blocks: List[int]) -> None:
+        idx = np.asarray(blocks, np.intp)      # distinct within one holder
+        self.refs[idx] += 1
+        self.distinct += int(np.count_nonzero(self.refs[idx] == 1))
+        self.reserved += len(blocks)
+
+    def drop(self, blocks: List[int]) -> None:
+        idx = np.asarray(blocks, np.intp)
+        self.refs[idx] -= 1
+        self.distinct -= int(np.count_nonzero(self.refs[idx] == 0))
+        self.reserved -= len(blocks)
 
 
 @dataclass
@@ -300,19 +435,30 @@ class ServingEngine:
                                    .batch_highwater, rec=self.rec)
         self._slots: List[Optional[_Seq]] = [None] * self.max_batch
         self._prefilling: Optional[_Prefilling] = None
+        self._held = _HeldBlocks(self.pool.num_blocks)
         self._warming = False      # role warms: no prefix-cache inserts
         self._chunk = int(serving.prefill_chunk_tokens)
         self._use_filters = bool(serving.sampling_filters)
-        self._rng = rng if rng is not None else jax.random.PRNGKey(
-            serving.seed)
+        # the base sampling key, as raw words on the host: every device
+        # call's own key is derived from it and the call's number HERE
+        # (_call_key) and rides the call's buffer, so the loop dispatches
+        # nothing for a key (the one eager call is this one)
+        if rng is None:
+            rng = jax.random.PRNGKey(serving.seed)
+        self._key = np.asarray(jax.random.key_data(rng),
+                               np.uint32).reshape(-1)
+        self._layout = StepLayout(self.nbk, self._key.size)
+        self._lanes = _Lanes(self._layout, self.max_batch)
+        self._calls = 0                    # device calls made so far
         self._heartbeat = heartbeat
         self._watchdog = None
         self._lock = threading.Lock()
         self.steps = 0                     # decode steps executed
 
         # ---- compiled programs (fixed shapes; ONE decode specialization) ----
-        _decode, _prefill = step_programs(cfg, bs, interpret=self.interpret,
-                                          use_filters=self._use_filters)
+        _decode, _prefill = step_programs(
+            cfg, bs, self.nbk, interpret=self.interpret,
+            use_filters=self._use_filters, key_words=self._key.size)
         # pools are donated: the loop's only live copy moves through the
         # step, so the update is in-place on TPU (no 2x pool HBM)
         self._decode_fn = jax.jit(_decode, donate_argnums=(1,))
@@ -343,6 +489,27 @@ class ServingEngine:
         """One jitted call over the live pool buffers (donation-safe
         under the shared state's device lock)."""
         return self._shared.run(fn, self.params, *args)
+
+    def _call_key(self, call: int) -> np.ndarray:
+        """The sampling key of device call number ``call``, as raw words:
+        numpy's ``SeedSequence`` hashes (base key, call) into them, on the
+        host. Same seed and same calls, same keys, run to run. (Not
+        ``jax.random.fold_in`` inside the programs: lowering its unrolled
+        threefry costs 0.5 s a program at every start on a TPU host,
+        PERF.md, PR 31; and not an eager split: a device program of its own
+        between two steps.)"""
+        return np.random.SeedSequence(
+            self._key.tolist(), spawn_key=(call,)).generate_state(
+                self._key.size, np.uint32)
+
+    def _call_device(self, fn, step_in: np.ndarray):
+        """One device call of the loop: number it, write its key into the
+        buffer's last words (:class:`StepLayout`) and hand the program its
+        one host array as it is, for the jitted call to transfer."""
+        self._calls += 1
+        step_in[-self._key.size:] = self._call_key(self._calls).view(np.int32)
+        self.stats["step_inputs.transfers_sum"] += 1
+        return self._run_device(fn, step_in)
 
     def _count_experts(self, out: np.ndarray) -> None:
         """A dropless MoE model's router load, from a fetched output: the
@@ -411,7 +578,7 @@ class ServingEngine:
 
     @property
     def active(self) -> int:
-        return sum(1 for s in self._slots if s is not None)
+        return int(np.count_nonzero(self._lanes.live))
 
     @property
     def idle(self) -> bool:
@@ -447,12 +614,12 @@ class ServingEngine:
             if self._prefilling is not None:
                 blocks.append(self._prefilling.blocks)
                 reqs.append(self._prefilling.req)
-                self._prefilling = None
+                self._set_prefilling(None)
             for i, s in enumerate(self._slots):
                 if s is not None:
                     blocks.append(s.blocks)
                     reqs.append(s.req)
-                    self._slots[i] = None
+                    self._vacate(i)
             self._collect_held(blocks, reqs)
             return blocks, reqs
         finally:
@@ -479,7 +646,7 @@ class ServingEngine:
         try:
             for i, s in enumerate(self._slots):
                 if s is not None and s.req is req:
-                    self._slots[i] = None
+                    self._vacate(i)
                     self.pool.release(s.blocks)
                     req.state = QUEUED
                     self.stats["preempted"] += 1
@@ -526,22 +693,46 @@ class ServingEngine:
         if queued:
             c["steps_with_queue"] += 1
             c["queue_len_sum"] += queued
-        holders = [s for s in self._slots if s is not None]
-        c["lane_sum"] += len(holders)
-        written = sum(s.ctx for s in holders)
+        c["lane_sum"] += self.active
+        # an idle lane's context reads 0
+        written = int(self._lanes.ctx.sum())
         if self._prefilling is not None:
-            holders.append(self._prefilling)
             written += self._prefilling.done
         # distinct blocks: a forked prefix is held once however many
         # lanes read it; the reservation counts it for each holder, as
-        # ``ctx`` counts its tokens for each
-        held = len(set().union(*(h.blocks for h in holders)))
+        # ``ctx`` counts its tokens for each (running counts: _HeldBlocks)
+        held = self._held.distinct
         c["kv.held_blocks_sum"] += held
-        c["kv.blocks_reserved_sum"] += sum(len(h.blocks) for h in holders)
+        c["kv.blocks_reserved_sum"] += self._held.reserved
         c["kv.tokens_written_sum"] += written
         if held > self.rec.gauges.get("kv.held_blocks_peak", 0):
             self.rec.gauge("kv.held_blocks_peak", held)
         return span
+
+    def _set_prefilling(self, pf: Optional[_Prefilling]) -> None:
+        """The prompt in prefill joins or leaves the holders of blocks."""
+        if self._prefilling is not None:
+            self._held.drop(self._prefilling.blocks)
+        self._prefilling = pf
+        if pf is not None:
+            self._held.add(pf.blocks)
+
+    def _place(self, slot: int, seq: _Prefilled) -> None:
+        """A sequence takes a decode lane: its row of the lane arrays is
+        written once, here, and only advanced after that."""
+        seq.req.state = RUNNING
+        self._slots[slot] = _Seq(seq.req, seq.blocks)
+        self._lanes.write(slot, seq)
+        self._held.add(seq.blocks)
+        self.stats["step_inputs.lane_rows_written_sum"] += 1
+
+    def _vacate(self, slot: int) -> None:
+        """A lane is freed (finished, preempted, collected): its row reads
+        idle again. The blocks' release is the caller's."""
+        self._held.drop(self._slots[slot].blocks)
+        self._slots[slot] = None
+        self._lanes.clear(slot)
+        self.stats["step_inputs.lane_rows_written_sum"] += 1
 
     def telemetry(self) -> Dict[str, Any]:
         """The recorder's snapshot: counters (``stats`` and, with a shared
@@ -644,10 +835,8 @@ class ServingEngine:
     # ------------------------------------------------------------- admission
 
     def _free_slot(self) -> Optional[int]:
-        for i, s in enumerate(self._slots):
-            if s is None:
-                return i
-        return None
+        i = int(np.argmin(self._lanes.live))       # the first idle lane
+        return None if self._lanes.live[i] else i
 
     def _admission_capacity(self) -> bool:
         """Can a new prefill begin? Base engine: a free decode lane (the
@@ -686,7 +875,7 @@ class ServingEngine:
             if req is None:
                 return "no_blocks", admitted, done
             try:
-                self._prefilling = self._start_prefill(req)
+                self._set_prefilling(self._start_prefill(req))
             except (BlockPoolExhausted, chaos.ChaosError) as e:
                 logger.warning("serving: admission of request %d "
                                "deferred (%s)", req.rid, e)
@@ -766,24 +955,35 @@ class ServingEngine:
                            final=int(pf.done + n >= pf.total)):
             return self._prefill_chunk(pf, n)
 
+    def _prefill_inputs(self, req: Request, toks: Sequence[int], table,
+                        q0: int) -> np.ndarray:
+        """A prefill call's one buffer: ``toks`` (padded to a block
+        multiple, so the compile count is bounded by the table's width) at
+        positions ``q0 ..`` of ``req``'s ``table``. A new buffer each call:
+        a middle chunk's call is fetched by nobody, so its transfer may
+        still be in flight when the next is built."""
+        n = len(toks)
+        Tb = -(-n // self.block_size) * self.block_size
+        buf = np.zeros((self._layout.prefill_words(Tb),), np.int32)
+        ids, bt, first, ctx, last_idx, tk, temp, tp, _ = \
+            self._layout.prefill(buf)
+        ids[0, :n] = toks
+        bt[0] = table
+        first[0], ctx[0], last_idx[0] = q0, q0 + n, n - 1
+        temp[0] = req.temperature
+        tk[0] = req.top_k or 0
+        tp[0] = 1.0 if req.top_p is None else req.top_p
+        return buf
+
     def _prefill_chunk(self, pf: _Prefilling, n: int) -> int:
         req, rec = pf.req, self.rec
         with rec.span("serve.prefill.build"):
-            chunk_toks = req.prompt[pf.done:pf.done + n]
-            Tb = -(-n // self.block_size) * self.block_size
-            ids = np.zeros((1, Tb), np.int32)
-            ids[0, :n] = chunk_toks
-            self._rng, r = jax.random.split(self._rng)
-            args = (jnp.asarray(ids), jnp.asarray(pf.table[None]),
-                    jnp.asarray([pf.done], jnp.int32),
-                    jnp.asarray([pf.done + n], jnp.int32),
-                    jnp.asarray(n - 1, jnp.int32), r,
-                    jnp.asarray([req.temperature], jnp.float32),
-                    *self._filter_args(req))
+            step_in = self._prefill_inputs(
+                req, req.prompt[pf.done:pf.done + n], pf.table, pf.done)
         try:
             chaos.failpoint("serve.chunk")
             with rec.span("serve.prefill.dispatch"):
-                tok = self._run_device(self._prefill_fn, *args)
+                tok = self._call_device(self._prefill_fn, step_in)
         except BaseException as e:
             # a failed chunk must not leak the lifetime allocation —
             # release EVERYTHING (partial K/V is recomputed on retry; the
@@ -791,7 +991,7 @@ class ServingEngine:
             # fleet's death ledger). Chaos/interrupt-class escapes leave
             # the request QUEUED for a requeue path; a plain Exception is
             # a deterministic per-request failure
-            self._prefilling = None
+            self._set_prefilling(None)
             self.pool.release(pf.blocks)
             if isinstance(e, Exception) \
                     and not isinstance(e, chaos.ChaosError):
@@ -809,16 +1009,23 @@ class ServingEngine:
             return 0                      # sampled token of a mid-chunk
             #                               call is discarded — only the
             #                               final chunk's is real
-        self._prefilling = None
+        self._set_prefilling(None)
         with rec.span("serve.prefill.fetch"):
-            first = int(np.asarray(tok)[0])
-            if self.cfg.moe_is_dropless:
-                self._count_experts(np.asarray(tok))
-        return self._first_token(_Seq(req, pf.blocks, pf.table, pf.total,
-                                      first),
+            first = int(self._fetch(tok)[0])
+        return self._first_token(_Prefilled(req, pf.blocks, pf.table,
+                                            pf.total, first),
                                  insert=not self._warming)
 
-    def _first_token(self, seq: _Seq, insert: bool = True) -> int:
+    def _fetch(self, out) -> np.ndarray:
+        """A device call's tokens: the step's one fetch (a dropless
+        mixture's expert counts ride behind them and are counted here)."""
+        out = np.asarray(out)
+        if self.cfg.moe_is_dropless:
+            self._count_experts(out)
+            out = out[:len(out) - self.cfg.num_layers * self.cfg.moe_experts]
+        return out
+
+    def _first_token(self, seq: _Prefilled, insert: bool = True) -> int:
         """A prompt's last chunk is in the pool and its first token
         fetched: stamp it, register the prompt's full blocks with the
         prefix cache, and install the sequence (or finish a one-token
@@ -844,20 +1051,10 @@ class ServingEngine:
             self._install(seq)
             return 0
 
-    def _install(self, seq: _Seq) -> None:
+    def _install(self, seq: _Prefilled) -> None:
         """Place a fully-prefilled sequence where decode will find it —
         a free lane here; the disagg prefill role hands it off instead."""
-        seq.req.state = RUNNING
-        self._slots[self._free_slot()] = seq
-
-    def _filter_args(self, *reqs):
-        """(top_k [n] i32, top_p [n] f32) device args for the compiled
-        sampler (0 / 1.0 = off; always passed so the program shape never
-        depends on the traffic)."""
-        tks = np.asarray([r.top_k or 0 for r in reqs], np.int32)
-        tps = np.asarray([r.top_p if r.top_p is not None else 1.0
-                          for r in reqs], np.float32)
-        return jnp.asarray(tks), jnp.asarray(tps)
+        self._place(self._free_slot(), seq)
 
     def _prefill_request(self, req: Request) -> int:
         n_pref, blocks, table = self._reserve(req)
@@ -867,23 +1064,13 @@ class ServingEngine:
 
     def _prefill_whole(self, req: Request, n_pref: int, blocks, table) -> int:
         P, rec = len(req.prompt), self.rec
-        # prefill the suffix, bucket-padded to a block multiple so the
-        # compile count is bounded by max_blocks_per_seq
+        # prefill the suffix the prefix cache does not hold
         with rec.span("serve.prefill.build"):
             suffix = req.prompt[n_pref:]
-            Tb = -(-len(suffix) // self.block_size) * self.block_size
-            ids = np.zeros((1, Tb), np.int32)
-            ids[0, :len(suffix)] = suffix
-            self._rng, r = jax.random.split(self._rng)
-            args = (jnp.asarray(ids), jnp.asarray(table[None]),
-                    jnp.asarray([n_pref], jnp.int32),
-                    jnp.asarray([P], jnp.int32),
-                    jnp.asarray(len(suffix) - 1, jnp.int32), r,
-                    jnp.asarray([req.temperature], jnp.float32),
-                    *self._filter_args(req))
+            step_in = self._prefill_inputs(req, suffix, table, n_pref)
         try:
             with rec.span("serve.prefill.dispatch"):
-                tok = self._run_device(self._prefill_fn, *args)
+                tok = self._call_device(self._prefill_fn, step_in)
         except BaseException as e:
             # a failed forward (device OOM, interrupt) must not leak the
             # refcounted blocks — capacity survives the exception. A
@@ -899,12 +1086,10 @@ class ServingEngine:
                 req.state = QUEUED
             raise
         with rec.span("serve.prefill.fetch"):
-            first = int(np.asarray(tok)[0])
-            if self.cfg.moe_is_dropless:
-                self._count_experts(np.asarray(tok))
+            first = int(self._fetch(tok)[0])
         req.prefill_progress = P
         self.stats["prefill_tokens"] += len(suffix)
-        return self._first_token(_Seq(req, blocks, table, P, first))
+        return self._first_token(_Prefilled(req, blocks, table, P, first))
 
     # ---------------------------------------------------------------- decode
 
@@ -913,54 +1098,34 @@ class ServingEngine:
             return self._decode_lanes()
 
     def _decode_lanes(self) -> int:
-        B, rec = self.max_batch, self.rec
+        B, rec, lanes = self.max_batch, self.rec, self._lanes
         with rec.span("serve.decode.build"):
-            toks = np.zeros((B,), np.int32)
-            ctx = np.zeros((B,), np.int32)
-            temps = np.zeros((B,), np.float32)
-            tks = np.zeros((B,), np.int32)
-            tps = np.ones((B,), np.float32)
-            tables = np.full((B, self.nbk), NULL_BLOCK, np.int32)
-            for i, s in enumerate(self._slots):
-                if s is None:
-                    continue
-                toks[i] = s.last_tok
-                ctx[i] = s.ctx
-                temps[i] = s.req.temperature
-                tks[i] = s.req.top_k or 0
-                tps[i] = s.req.top_p if s.req.top_p is not None else 1.0
-                tables[i] = s.table
             # the pages the paged kernel walks this step (every lane up to
             # the token it writes; an idle lane its one null page) against
             # the tables' full width
             rec.count("paged.live_pages_sum",
-                      int((ctx // self.block_size + 1).sum()))
+                      int((lanes.ctx // self.block_size + 1).sum()))
             rec.count("paged.table_pages_sum", B * self.nbk)
-            self._rng, r = jax.random.split(self._rng)
-            args = (jnp.asarray(toks), jnp.asarray(tables),
-                    jnp.asarray(ctx), r, jnp.asarray(temps),
-                    jnp.asarray(tks), jnp.asarray(tps))
+            # the lanes' state is the step's input as it stands; the copy is
+            # what the device call owns
+            step_in = lanes.buf.copy()
         with rec.span("serve.decode.dispatch"):
-            nxt = self._run_device(self._decode_fn, *args)
+            nxt = self._call_device(self._decode_fn, step_in)
         with rec.span("serve.decode.fetch"):
-            nxt = np.asarray(nxt)
-            if self.cfg.moe_is_dropless:
-                self._count_experts(nxt)
+            nxt = self._fetch(nxt)
         done = 0
         with rec.span("serve.decode.bookkeep"):
-            for i, s in enumerate(self._slots):
-                if s is None:
-                    continue
-                s.ctx += 1
-                tok = int(nxt[i])
-                s.req.output_tokens.append(tok)
-                s.last_tok = tok
-                self.stats["tokens_generated"] += 1
-                eos = (s.req.eos_token_id is not None
-                       and tok == s.req.eos_token_id)
-                if eos or len(s.req.output_tokens) >= s.req.max_new_tokens:
-                    self._slots[i] = None
-                    self._finish(s)
+            live, toks = np.flatnonzero(lanes.live).tolist(), nxt.tolist()
+            lanes.advance(nxt)
+            self.stats["tokens_generated"] += len(live)
+            for i in live:
+                req, tok = self._slots[i].req, toks[i]
+                req.output_tokens.append(tok)
+                if tok == req.eos_token_id \
+                        or len(req.output_tokens) >= req.max_new_tokens:
+                    seq = self._slots[i]
+                    self._vacate(i)
+                    self._finish(seq)
                     done += 1
         return done
 

@@ -62,7 +62,7 @@ def programs(name: str, config, serving, *, int8: bool, chip):
     from deepspeed_tpu.models import TransformerConfig, build_model
     from deepspeed_tpu.models.generation import ensure_scan_layout
     from deepspeed_tpu.ops.pallas.quant_matmul import pack_decode_weights
-    from deepspeed_tpu.serving.engine import step_programs
+    from deepspeed_tpu.serving.engine import StepLayout, step_programs
     from deepspeed_tpu.serving.kv_cache import init_pool
 
     family = harness.load_family(config["family"])
@@ -84,19 +84,13 @@ def programs(name: str, config, serving, *, int8: bool, chip):
     pools = on_chip(jax.eval_shape(lambda: init_pool(
         cfg, serving["pool_blocks"], bs,
         jnp.int8 if int8 else jnp.bfloat16)))
-    i32, f32 = jnp.int32, jnp.float32
-    decode, prefill = step_programs(cfg, bs)
-    sample = lambda n: (chip((2,), jnp.uint32), chip((n,), f32),
-                        chip((n,), i32), chip((n,), f32))
-    calls = {
-        "decode": (decode, (chip((lanes,), i32), chip((lanes, nbk), i32),
-                            chip((lanes,), i32)) + sample(lanes)),
-        f"prefill{chunk}": (prefill, (
-            chip((1, chunk), i32), chip((1, nbk), i32), chip((1,), i32),
-            chip((1,), i32), chip((), i32)) + sample(1)),
-    }
-    for label, (fn, args) in calls.items():
-        lowered = jax.jit(fn, donate_argnums=(1,)).lower(params, pools, *args)
+    decode, prefill = step_programs(cfg, bs, nbk)
+    layout = StepLayout(nbk)
+    calls = {"decode": (decode, layout.decode_words(lanes)),
+             f"prefill{chunk}": (prefill, layout.prefill_words(chunk))}
+    for label, (fn, words) in calls.items():
+        lowered = jax.jit(fn, donate_argnums=(1,)).lower(
+            params, pools, chip((words,), jnp.int32))
         yield (f"{name}{'-int8' if int8 else ''}.{label}",
                lowered.as_text(debug_info=False))
 

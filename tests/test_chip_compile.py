@@ -251,7 +251,7 @@ def test_serving_step_updates_the_pool_in_place(chip, monkeypatch, program,
     (PERF.md, PR 25)."""
     from deepspeed_tpu.models import TransformerConfig, build_model
     from deepspeed_tpu.models.generation import ensure_scan_layout
-    from deepspeed_tpu.serving.engine import step_programs
+    from deepspeed_tpu.serving.engine import StepLayout, step_programs
     from deepspeed_tpu.serving.kv_cache import init_pool
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     L, NH, HD, BS, NB, B, NBK = 16, 32, 128, 32, 384, 32, 40
@@ -269,19 +269,14 @@ def test_serving_step_updates_the_pool_in_place(chip, monkeypatch, program,
             {"input_ids": jnp.zeros((1, 8), jnp.int32)})["params"]), L)))
     pools = on_chip(jax.eval_shape(lambda: init_pool(
         cfg, NB, BS, jnp.int8 if quant else jnp.bfloat16)))
-    i32, f32 = jnp.int32, jnp.float32
     lanes = B if program == "decode" else 1
-    sample = (chip((2,), jnp.uint32), chip((lanes,), f32),
-              chip((lanes,), i32), chip((lanes,), f32))
-    decode, prefill = step_programs(cfg, BS)
+    decode, prefill = step_programs(cfg, BS, NBK)
     if program == "decode":
-        fn, args = decode, (chip((B,), i32), chip((B, NBK), i32),
-                            chip((B,), i32))
+        fn, words = decode, StepLayout(NBK).decode_words(B)
     else:
-        fn, args = prefill, (chip((1, 256), i32), chip((1, NBK), i32),
-                             chip((1,), i32), chip((1,), i32), chip((), i32))
+        fn, words = prefill, StepLayout(NBK).prefill_words(256)
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(
-        params, pools, *args, *sample).compile()
+        params, pools, chip((words,), jnp.int32)).compile()
     text = compiled.as_text()
 
     layer = NH * NB * BS * HD
@@ -322,7 +317,7 @@ def test_olmoe_serving_step_is_dropless_and_in_place(chip, monkeypatch,
     tokens in one int32 vector; and the pool is still updated in place."""
     from deepspeed_tpu.models import TransformerConfig, build_model
     from deepspeed_tpu.models.generation import ensure_scan_layout
-    from deepspeed_tpu.serving.engine import step_programs
+    from deepspeed_tpu.serving.engine import StepLayout, step_programs
     from deepspeed_tpu.serving.kv_cache import init_pool
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     L, NH, HD, BS, NB, B, NBK, E, K, M = 8, 16, 128, 32, 2048, 64, 128, 64, \
@@ -342,19 +337,14 @@ def test_olmoe_serving_step_is_dropless_and_in_place(chip, monkeypatch,
             {"input_ids": jnp.zeros((1, 8), jnp.int32)})["params"]), L)))
     pools = on_chip(jax.eval_shape(lambda: init_pool(cfg, NB, BS,
                                                      jnp.bfloat16)))
-    i32, f32 = jnp.int32, jnp.float32
     lanes = B if program == "decode" else 1
-    sample = (chip((2,), jnp.uint32), chip((lanes,), f32),
-              chip((lanes,), i32), chip((lanes,), f32))
-    decode, prefill = step_programs(cfg, BS)
+    decode, prefill = step_programs(cfg, BS, NBK)
     if program == "decode":
-        fn, args = decode, (chip((B,), i32), chip((B, NBK), i32),
-                            chip((B,), i32))
+        fn, words = decode, StepLayout(NBK).decode_words(B)
     else:
-        fn, args = prefill, (chip((1, 256), i32), chip((1, NBK), i32),
-                             chip((1,), i32), chip((1,), i32), chip((), i32))
+        fn, words = prefill, StepLayout(NBK).prefill_words(256)
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(
-        params, pools, *args, *sample).compile()
+        params, pools, chip((words,), jnp.int32)).compile()
     text = compiled.as_text()
     out, _ = compiled.out_info               # the tokens, then the counts
     assert out.shape == (lanes + L * E,) and out.dtype == jnp.int32

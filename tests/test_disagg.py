@@ -486,3 +486,61 @@ def test_serve_bench_record_discovery_regression(tmp_path):
     os.utime(other, (time.time() + 60, time.time() + 60))
     name2, _ = latest_serve_bench(str(tmp_path), jax.device_count())
     assert name2 == "SERVEBENCH_r01.json"
+
+
+# ---------------------------------------------------------------------------
+# PR 31: the roles follow the loop's one signature and its lane state
+# ---------------------------------------------------------------------------
+
+def test_decode_role_warm_and_served_batch_share_one_program(tiny):
+    """``DecodeEngine.warm()`` hands the decode program the loop's own kind
+    of argument (one numpy int32 buffer, every lane idle), so warm-up and a
+    served batch, greedy and sampled, leave ONE entry in the program's
+    cache; the prefill role's warm leaves no counter behind."""
+    cfg, params = tiny
+    dis = DisaggEngine(cfg, params, serving=dict(
+        SERVE_CFG, prefill_chunk_tokens=10, sampling_filters=True))
+    dis.decode.warm()
+    dis.prefill.warm()
+    assert dis.decode._decode_fn._cache_size() == 1
+    assert dis.prefill.stats["step_inputs.transfers_sum"] == 0
+    assert dis.pool.used_count == 0
+    rng = np.random.default_rng(4)
+    prompts = [rng.integers(1, 64, size=n).tolist() for n in (7, 23, 12)]
+    greedy = [dis.submit(p, 6) for p in prompts]
+    sampled = dis.submit(prompts[0], 6, temperature=0.9, top_k=5)
+    dis.run_until_idle()
+    for p, r in zip(prompts, greedy):
+        assert r.output_tokens == _oracle_tokens(cfg, params, p, 6)
+    assert len(sampled.output_tokens) == 6
+    assert dis.decode._decode_fn._cache_size() == 1
+    assert dis.prefill._prefill_fn._cache_size() <= 2     # chunks of 16, 8
+    dis.close()
+
+
+def test_handoff_writes_the_decode_roles_lane_rows(tiny):
+    """Across the handoff the decode role's lane state is written where a
+    popped item takes a lane and cleared where it finishes: every decode
+    call's one buffer equals, field for field, the per-lane build from the
+    requests and the lanes' block lists (tests/test_serving.py keeps the
+    parent's build), and the held-block counts return to nothing."""
+    from test_serving import _watch_device_calls
+    cfg, params = tiny
+    dis = DisaggEngine(cfg, params, serving=dict(
+        SERVE_CFG, prefill_chunk_tokens=10, sampling_filters=True))
+    calls = _watch_device_calls(dis.decode)
+    rng = np.random.default_rng(8)
+    reqs = [dis.submit(rng.integers(1, 64, size=n).tolist(), m,
+                       temperature=t, top_p=tp)
+            for n, m, t, tp in ((9, 5, 0.0, None), (25, 9, 0.7, 0.9),
+                                (14, 3, 0.0, None), (31, 7, 1.1, None),
+                                (5, 12, 0.0, None), (18, 4, 0.3, 0.5))]
+    dis.run_until_idle()
+    assert all(len(r.output_tokens) == r.max_new_tokens for r in reqs)
+    assert calls and set(calls) == {"decode"}
+    dec = dis.decode
+    assert dec.stats["step_inputs.lane_rows_written_sum"] == 2 * len(reqs)
+    assert dec.stats["step_inputs.transfers_sum"] == len(calls)
+    assert not dec._lanes.live.any() and not dec._lanes.tables.any()
+    assert dec._held.reserved == 0 and dis.prefill._held.reserved == 0
+    dis.close()

@@ -286,18 +286,11 @@ def test_no_capacity_tensor_in_the_serving_programs():
         "block_size": 16, "pool_blocks": 20, "max_batch": 5,
         "max_blocks_per_seq": 4, "prefill_chunk_tokens": 32})
     B, T, E, k = 5, 32, 12, 4
-    r = jax.random.PRNGKey(0)
-    f32, i32 = jnp.float32, jnp.int32
     progs = {
-        "decode": (B, srv._decode_fn, (
-            srv.params, srv.pools, jnp.zeros((B,), i32),
-            jnp.zeros((B, srv.nbk), i32), jnp.zeros((B,), i32), r,
-            jnp.zeros((B,), f32), jnp.zeros((B,), i32), jnp.ones((B,), f32))),
-        "prefill": (T, srv._prefill_fn, (
-            srv.params, srv.pools, jnp.zeros((1, T), i32),
-            jnp.zeros((1, srv.nbk), i32), jnp.zeros((1,), i32),
-            jnp.full((1,), T, i32), jnp.asarray(T - 1, i32), r,
-            jnp.zeros((1,), f32), jnp.zeros((1,), i32), jnp.ones((1,), f32)))}
+        "decode": (B, srv._decode_fn, (srv.params, srv.pools,
+                                       srv._lanes.buf)),
+        "prefill": (T, srv._prefill_fn, (srv.params, srv.pools, np.zeros(
+            (srv._layout.prefill_words(T),), np.int32)))}
     for name, (tok, fn, args) in progs.items():
         text = fn.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
         assert len(re.findall(r"ragged_dot", text)) >= 3, name
@@ -316,9 +309,11 @@ def test_no_capacity_tensor_in_the_serving_programs():
 def test_a_dense_configs_programs_and_counters_are_what_they_were():
     """The fence, as far as the CPU can hold it: for a config that is not a
     dropless mixture ``step_programs`` hands out the two programs under the
-    signatures they had, each returning the tokens ``[lanes]`` and the pools
-    and nothing else; the engine keeps no ``moe.`` counter and no pending
-    counts; and what it counts is the list it counted before this file."""
+    one signature (params, pools, the call's one int32 buffer), each
+    returning the tokens ``[lanes]`` and the pools and nothing else; the
+    engine keeps no ``moe.`` counter and no pending counts; and what it
+    counts is the list it counted before this file, and since PR 31 the two
+    ``step_inputs.`` counters."""
     import inspect
     from deepspeed_tpu.serving import engine as eng
     model, cfg = build_model(TransformerConfig(
@@ -327,29 +322,24 @@ def test_a_dense_configs_programs_and_counters_are_what_they_were():
         pos_embed="rotary", use_bias=False, tie_embeddings=False,
         dtype=jnp.float32, attention_impl="reference"))
     assert not cfg.moe_is_dropless
-    decode, prefill = eng.step_programs(cfg, 16)
+    decode, prefill = eng.step_programs(cfg, 16, 4)
     assert decode.__name__ == "_decode" and prefill.__name__ == "_prefill"
     assert list(inspect.signature(decode).parameters) == [
-        "params", "pools", "toks", "bt", "ctx", "r", "temps", "tks", "tps"]
+        "params", "pools", "step_in"]
     assert list(inspect.signature(prefill).parameters) == [
-        "params", "pools", "ids", "bt", "q0", "ctx", "last_idx", "r", "temps",
-        "tks", "tps"]
+        "params", "pools", "step_in"]
     params = make_params(model, cfg, seed=3, dtype=jnp.float32)
     srv = ServingEngine(cfg, params, serving={
         "block_size": 16, "pool_blocks": 20, "max_batch": 5,
         "max_blocks_per_seq": 4, "prefill_chunk_tokens": 32})
-    B, T, r = 5, 32, jax.random.PRNGKey(0)
-    f32, i32 = jnp.float32, jnp.int32
-    tok, pools = jax.eval_shape(
-        decode, srv.params, srv.pools, jnp.zeros((B,), i32),
-        jnp.zeros((B, srv.nbk), i32), jnp.zeros((B,), i32), r,
-        jnp.zeros((B,), f32), jnp.zeros((B,), i32), jnp.ones((B,), f32))
+    B, T, layout = 5, 32, eng.StepLayout(4)
+    assert srv.nbk == 4 and srv._lanes.buf.shape == (layout.decode_words(B),)
+    tok, pools = jax.eval_shape(decode, srv.params, srv.pools,
+                                srv._lanes.buf)
     assert tok.shape == (B,) and set(pools) == set(srv.pools)
     tok, pools = jax.eval_shape(
-        prefill, srv.params, srv.pools, jnp.zeros((1, T), i32),
-        jnp.zeros((1, srv.nbk), i32), jnp.zeros((1,), i32),
-        jnp.full((1,), T, i32), jnp.asarray(T - 1, i32), r,
-        jnp.zeros((1,), f32), jnp.zeros((1,), i32), jnp.ones((1,), f32))
+        prefill, srv.params, srv.pools,
+        np.zeros((layout.prefill_words(T),), np.int32))
     assert tok.shape == (1,) and set(pools) == set(srv.pools)
     assert eng._COUNTERS == (
         "completed", "failed", "timeout", "tokens_generated",
@@ -359,7 +349,8 @@ def test_a_dense_configs_programs_and_counters_are_what_they_were():
         "admit_blocked.prefilling", "compiles", "kv.held_blocks_sum",
         "kv.blocks_reserved_sum", "kv.tokens_written_sum",
         "prefix.prompt_tokens", "paged.live_pages_sum",
-        "paged.table_pages_sum")
+        "paged.table_pages_sum", "step_inputs.transfers_sum",
+        "step_inputs.lane_rows_written_sum")
     srv.submit(list(tokens(40, 5)), max_new_tokens=4)
     srv.run_until_idle()
     assert srv.stats["completed"] == 1
@@ -369,6 +360,6 @@ def test_a_dense_configs_programs_and_counters_are_what_they_were():
     moe_model, moe_cfg = build_model(TransformerConfig(
         **FAM.model_kwargs(TINY), dtype=jnp.float32,
         attention_impl="reference"))
-    d2, p2 = eng.step_programs(moe_cfg, 16)
+    d2, p2 = eng.step_programs(moe_cfg, 16, 4)
     assert inspect.signature(d2) == inspect.signature(decode)
     assert inspect.signature(p2) == inspect.signature(prefill)

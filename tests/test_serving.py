@@ -21,7 +21,7 @@ from deepspeed_tpu.models.generation import generate
 from deepspeed_tpu.serving.engine import ServingEngine
 from deepspeed_tpu.serving.kv_cache import (BlockPool, BlockPoolExhausted,
                                             PrefixCache)
-from deepspeed_tpu.serving.scheduler import QUEUED
+from deepspeed_tpu.serving.scheduler import QUEUED, RUNNING
 from deepspeed_tpu.testing import chaos
 
 
@@ -809,21 +809,12 @@ def test_step_programs_hold_no_pool_scatter_on_the_cpu(tiny, kv):
     cfg, params = tiny
     eng = ServingEngine(cfg, params, serving=dict(
         SERVE_CFG, **({"kv_cache_dtype": "int8"} if kv == "int8" else {})))
-    B, nbk = eng.max_batch, eng.nbk
-    i32, f32 = jnp.int32, jnp.float32
-
-    def sample(n):
-        return (jax.random.PRNGKey(0), jnp.zeros((n,), f32),
-                jnp.zeros((n,), i32), jnp.ones((n,), f32))
-
     programs = {
-        "decode": eng._decode_fn.lower(
-            eng.params, eng.pools, jnp.zeros((B,), i32),
-            jnp.zeros((B, nbk), i32), jnp.zeros((B,), i32), *sample(B)),
+        "decode": eng._decode_fn.lower(eng.params, eng.pools,
+                                       eng._lanes.buf),
         "prefill": eng._prefill_fn.lower(
-            eng.params, eng.pools, jnp.zeros((1, 32), i32),
-            jnp.zeros((1, nbk), i32), jnp.zeros((1,), i32),
-            jnp.ones((1,), i32), jnp.asarray(0, i32), *sample(1)),
+            eng.params, eng.pools,
+            np.zeros((eng._layout.prefill_words(32),), np.int32)),
     }
     pool_sizes = {math.prod(p.shape) for p in eng.pools.values()}
     for name, lowered in programs.items():
@@ -834,3 +825,290 @@ def test_step_programs_hold_no_pool_scatter_on_the_cpu(tiny, kv):
             in pool_sizes]
         banned = {"scatter"} | ({"copy"} if name == "decode" else set())
         assert not [m for m in made if m[0] in banned], (name, made)
+
+
+# ---------------------------------------------------------------------------
+# PR 31: lane state kept between steps, one transfer a device call
+# ---------------------------------------------------------------------------
+
+LANE_CFG = dict(SERVE_CFG, sampling_filters=True, seed=5)
+
+
+def _lane_ctx(s):
+    """A lane's context as the outside sees it: the prompt and every token
+    emitted but the last, which the next step writes."""
+    return len(s.req.prompt) + len(s.req.output_tokens) - 1
+
+
+def _per_lane_decode_build(srv):
+    """The parent's build of a decode step's inputs (engine.py before PR 31,
+    ``_decode_lanes``): fresh arrays, a Python loop over the lanes, every
+    fact read from the requests and the lanes' block lists."""
+    B = srv.max_batch
+    toks, ctx = np.zeros((B,), np.int32), np.zeros((B,), np.int32)
+    temps, tks = np.zeros((B,), np.float32), np.zeros((B,), np.int32)
+    tps = np.ones((B,), np.float32)
+    tables = np.zeros((B, srv.nbk), np.int32)            # NULL_BLOCK
+    for i, s in enumerate(srv._slots):
+        if s is None:
+            continue
+        toks[i], ctx[i] = s.req.output_tokens[-1], _lane_ctx(s)
+        temps[i] = s.req.temperature
+        tks[i] = s.req.top_k or 0
+        tps[i] = s.req.top_p if s.req.top_p is not None else 1.0
+        tables[i, :len(s.blocks)] = s.blocks
+    return toks, ctx, tks, tables, temps, tps
+
+
+def _per_request_prefill_build(srv, Tb):
+    """The parent's build of a chunk's inputs (``_prefill_chunk``), from the
+    prompt in prefill as it stands before the call."""
+    pf = srv._prefilling
+    n = min(srv._chunk, pf.total - pf.done)
+    ids = np.zeros((1, Tb), np.int32)
+    ids[0, :n] = pf.req.prompt[pf.done:pf.done + n]
+    table = np.zeros((1, srv.nbk), np.int32)
+    table[0, :len(pf.blocks)] = pf.blocks
+    req = pf.req
+    return (ids, table, [pf.done], [pf.done + n], [n - 1],
+            [req.top_k or 0], np.float32([req.temperature]),
+            np.float32([req.top_p if req.top_p is not None else 1.0]))
+
+
+def _watch_device_calls(srv, check=True, keep=None):
+    """Every ``_run_device`` call of ``srv`` recorded as 'decode' /
+    'prefill' (its buffer's fields copied into ``keep``, if given); with
+    ``check`` the buffer is held, field for field, to the per-lane build
+    above."""
+    calls, real = [], srv._run_device
+
+    def spy(fn, *args):
+        assert len(args) == 1 and type(args[0]) is np.ndarray \
+            and args[0].dtype == np.int32 and args[0].ndim == 1
+        kind = "decode" if fn is srv._decode_fn else "prefill"
+        calls.append(kind)
+        if keep is not None:
+            keep.append([np.array(f) for f in getattr(srv._layout, kind)(
+                args[0])])
+        if check:
+            if kind == "decode":
+                *got, key = srv._layout.decode(args[0])
+                want = _per_lane_decode_build(srv)
+            else:
+                *got, key = srv._layout.prefill(args[0])
+                want = _per_request_prefill_build(srv, got[0].shape[1])
+            for g, w in zip(got, want, strict=True):
+                np.testing.assert_array_equal(g, w)
+            # the call's own key: calls are numbered 1, 2, ...
+            np.testing.assert_array_equal(key, srv._call_key(len(calls)))
+        return real(fn, *args)
+
+    srv._run_device = spy
+    return calls
+
+
+def _drive_lane_scenario(srv, before_step=lambda: None):
+    """A seeded run that changes the lanes in every way the engine has:
+    installs and finishes of mixed lengths (idle lanes in between, 4 lanes),
+    chunked prompts that end in mid-block (chunk 24, blocks of 16), a
+    prefix-cache hit, greedy, temperature and filtered lanes, a preempted
+    lane, a cancelled queued request and a cancelled running one."""
+    rng = np.random.default_rng(31)
+    prompt = lambda n: rng.integers(1, 64, size=n).tolist()
+
+    def step(n=1):
+        for _ in range(n):
+            before_step()
+            srv.step()
+
+    shared = prompt(40)
+    first = [srv.submit(prompt(37), 9),
+             srv.submit(shared, 25, temperature=0.8),
+             srv.submit(prompt(21), 12, temperature=1.1, top_k=7, top_p=0.9)]
+    step(6)
+    hit = srv.submit(shared[:32] + prompt(9), 6)         # two cached blocks
+    victim = srv.submit(prompt(50), 30, temperature=0.5, top_p=0.8)
+    step(7)
+    queued = srv.submit(prompt(18), 8)
+    while victim.state != RUNNING:
+        step()
+    step(2)
+    assert srv.preempt_request(victim) and victim.state == QUEUED
+    assert srv.cancel_request(srv.submit(prompt(26), 4))  # still in the queue
+    step(3)
+    runner = srv.submit(prompt(33), 40)
+    while runner.state != RUNNING:
+        step()
+    step(3)
+    assert srv.cancel_request(runner)                    # holds a lane
+    late = srv.submit(prompt(16), 3)                     # a whole block
+    while not srv.idle:
+        step()
+    assert hit.prefix_hit_tokens == 32
+    done = first + [hit, queued, late]
+    assert all(r.done and len(r.output_tokens) == r.max_new_tokens
+               for r in done)
+    srv.prefix_cache.clear()
+    assert srv.pool.used_count == 0       # every path returned its blocks
+    return done + [victim, runner]
+
+
+def test_step_inputs_equal_the_per_lane_build_field_for_field(tiny):
+    """Every device call of the scenario gets ONE numpy int32 buffer whose
+    fields are what the parent's per-lane build gives at that moment and
+    the call's own key; and the two counters say what was
+    reused: one transfer a call, a lane row written per install and per
+    freed lane and none per step."""
+    cfg, params = tiny
+    srv = ServingEngine(cfg, params, serving=dict(
+        LANE_CFG, prefill_chunk_tokens=24))
+    calls = _watch_device_calls(srv)
+    reqs = _drive_lane_scenario(srv)
+    assert calls.count("decode") > 20 and calls.count("prefill") > 12
+    c = srv.telemetry()["counters"]
+    assert c["step_inputs.transfers_sum"] == len(calls)
+    installs = len(reqs)                  # each took a lane once
+    assert c["step_inputs.lane_rows_written_sum"] == 2 * installs
+    assert c["step_inputs.lane_rows_written_sum"] < c["steps"] \
+        < c["steps"] * srv.max_batch      # the parent wrote every row a step
+    assert not srv._lanes.live.any() and not srv._lanes.ctx.any() \
+        and not srv._lanes.tables.any()   # every lane reads idle again
+
+
+def test_whole_prefill_inputs_ride_one_buffer(tiny):
+    """Whole (unchunked) prefill builds its call the same way: the suffix
+    the prefix cache does not hold, padded to a block multiple, at its
+    offset in the request's table."""
+    cfg, params = tiny
+    srv = ServingEngine(cfg, params, serving=LANE_CFG)
+    seen = []
+    calls = _watch_device_calls(srv, check=False, keep=seen)
+    rng = np.random.default_rng(3)
+    shared = rng.integers(1, 64, size=40).tolist()
+    a = srv.submit(shared, 4)
+    srv.step()
+    b = srv.submit(shared[:32] + [7, 8, 9], 4, temperature=0.7, top_k=3)
+    srv.run_until_idle()
+    assert calls.count("prefill") == 2 and len(a.output_tokens) == 4
+    seen = [f for kind, f in zip(calls, seen) if kind == "prefill"]
+    ids, table, q0, ctx, last_idx, tk, temp, tp, key = seen[1]
+    assert ids.shape == (1, 16) and ids[0, :3].tolist() == [7, 8, 9] \
+        and not ids[0, 3:].any()
+    assert (q0[0], ctx[0], last_idx[0], tk[0]) == (32, 35, 2, 3)
+    assert temp[0] == np.float32(0.7) and tp[0] == 1.0
+    np.testing.assert_array_equal(table[0, :2], seen[0][1][0, :2])  # forked
+    assert np.count_nonzero(table) == srv.pool.blocks_for_tokens(35 + 3)
+    assert b.prefix_hit_tokens == 32 and len(b.output_tokens) == 4
+
+
+def test_no_eager_dispatch_between_steps(tiny, monkeypatch):
+    """After warm-up a step makes exactly the device calls it is for, one
+    decode and at most one prefill chunk, and touches jax nowhere else: the
+    engine's, the pool's and the scheduler's modules see a ``jax`` and a
+    ``jnp`` that raise on any use (no ``jnp.asarray`` round an input, no
+    ``jax.random`` on the host), while the jitted programs, compiled in the
+    warm-up, run from their caches."""
+    from deepspeed_tpu.serving import engine, kv_cache, scheduler
+    cfg, params = tiny
+    srv = ServingEngine(cfg, params, serving=dict(
+        LANE_CFG, prefill_chunk_tokens=24))
+    rng = np.random.default_rng(11)
+    prompt = lambda n: rng.integers(1, 64, size=n).tolist()
+    # warm-up: every shape the run below uses (chunks of 32 and 16 padded
+    # tokens, the decode step), greedy and sampled
+    srv.submit(prompt(40), 3, temperature=0.9, top_k=5)
+    srv.submit(prompt(30), 3)
+    srv.run_until_idle()
+
+    class Untouchable:
+        def __getattr__(self, name):
+            raise AssertionError(f"the host path touched jax: .{name}")
+
+    for mod in (engine, kv_cache, scheduler):
+        for name in ("jax", "jnp"):
+            if hasattr(mod, name):
+                monkeypatch.setattr(mod, name, Untouchable())
+    calls = _watch_device_calls(srv, check=False)
+    reqs = [srv.submit(prompt(40), 6, temperature=0.9, top_k=5),
+            srv.submit(prompt(30), 5), srv.submit(prompt(13), 7,
+                                                  temperature=1.2)]
+    while not srv.idle:
+        before, active = len(calls), srv.active
+        srv.step()
+        made = sorted(calls[before:])
+        assert made in ([], ["decode"], ["prefill"], ["decode", "prefill"])
+        assert "decode" in made or not active
+    assert all(len(r.output_tokens) == r.max_new_tokens for r in reqs)
+    assert calls.count("decode") >= 7 and calls.count("prefill") == 5
+    assert srv._decode_fn._cache_size() == 1
+
+
+def test_seeded_temperature_mix_gives_the_same_tokens_twice(tiny):
+    """Same seed, same requests, same tokens, run to run: a call's key is
+    a hash of the base key and the call's number, made on the host, and the
+    calls are a function of the admissions alone. Another seed gives other
+    tokens; a greedy lane never reads the key."""
+    cfg, params = tiny
+
+    def run(seed):
+        srv = ServingEngine(cfg, params, serving=dict(
+            LANE_CFG, seed=seed, prefill_chunk_tokens=24))
+        return [list(r.output_tokens) for r in _drive_lane_scenario(srv)]
+
+    one, two, other = run(5), run(5), run(6)
+    assert one == two
+    assert one != other
+    assert one[0] == other[0]             # the greedy request of the mix
+
+
+def test_held_block_counters_equal_a_recount_at_every_step(tiny):
+    """``kv.held_blocks_sum``, ``kv.blocks_reserved_sum``,
+    ``kv.tokens_written_sum``, ``lane_sum`` and the ``kv.held_blocks_peak``
+    gauge, kept as running counts where blocks are reserved and released,
+    gain at every step of the scenario what a recount over the lanes' and
+    the prefilling prompt's own lists reads on entry to that step (the
+    parent's ``_step_span``, and the benchmark driver's ``held_blocks``)."""
+    cfg, params = tiny
+    srv = ServingEngine(cfg, params, serving=dict(
+        LANE_CFG, prefill_chunk_tokens=24))
+    names = ("kv.held_blocks_sum", "kv.blocks_reserved_sum",
+             "kv.tokens_written_sum", "lane_sum")
+    want, peak, forked = dict.fromkeys(names, 0), [0], [0]
+
+    def recount():
+        assert {k: srv.stats[k] for k in names} == want
+        holders = [s for s in srv._slots if s is not None]
+        want["lane_sum"] += len(holders)
+        written = sum(_lane_ctx(s) for s in holders)
+        if srv._prefilling is not None:
+            holders.append(srv._prefilling)
+            written += srv._prefilling.done
+        held = len(set().union(*(h.blocks for h in holders)))
+        reserved = sum(len(h.blocks) for h in holders)
+        want["kv.held_blocks_sum"] += held
+        want["kv.blocks_reserved_sum"] += reserved
+        want["kv.tokens_written_sum"] += written
+        peak[0] = max(peak[0], held)
+        forked[0] += reserved - held
+
+    _drive_lane_scenario(srv, before_step=recount)
+    recount()
+    assert srv.telemetry()["gauges"]["kv.held_blocks_peak"] == peak[0] > 8
+    assert forked[0] > 0                  # a shared prefix was held twice
+    assert srv._held.distinct == srv._held.reserved == 0 \
+        and not srv._held.refs.any()
+
+
+def test_mixsim_replays_a_window_against_the_engine():
+    """``benchmark/mixsim.py`` drives the engine's scheduler and pool with
+    the device stubbed: ``srv._run_device(fn, *args)`` answered by a numpy
+    vector of ``max_batch`` tokens (one for ``srv._prefill_fn``). A short
+    window of the chat mix replays, step for step, against the loop as it
+    is now."""
+    from benchmark import harness, mixsim
+    cell = harness.load_cell("serve-mistral-7b-l16-chat")
+    out = mixsim.replay(dict(cell.traffic), cell.system["serving"],
+                        seconds=1.5, decode_s=0.016, prefill_s=0.035)
+    assert out["requests"] >= 10
+    assert 600 < out["sim_tokens_per_s"] < 1600
+    assert 35 <= out["sim_itl_p95_ms"] <= 36

@@ -475,12 +475,7 @@ def train_step_program(engine):
 
 
 def decode_program(srv):
-    B = srv.max_batch
-    return srv._decode_fn, (
-        srv.params, srv.pools, jnp.zeros((B,), jnp.int32),
-        jnp.zeros((B, srv.nbk), jnp.int32), jnp.zeros((B,), jnp.int32),
-        jax.random.PRNGKey(0), jnp.zeros((B,), jnp.float32),
-        jnp.zeros((B,), jnp.int32), jnp.ones((B,), jnp.float32))
+    return srv._decode_fn, (srv.params, srv.pools, srv._lanes.buf)
 
 
 def without_scopes(monkeypatch):
