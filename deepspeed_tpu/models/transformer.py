@@ -1120,7 +1120,96 @@ def _fused_causal_lm_loss(x, emb, labels, chunk: int, shift: int = 1):
     most one [B, chunk, V] logits tile; XLA keeps the chunk matmuls on the
     MXU with fp32 accumulation. Replaces the reference's fused CE epilogue
     (csrc/transformer/general_kernels.cu cross-entropy path) the XLA way.
+
+    Where the batch is split over chips the loop runs per shard
+    (``_per_shard_nll_sum``). The only input is the mesh `x` is traced
+    under: its ``BATCH_AXES`` that are wider than 1 and not manual already
+    have to divide B. On one chip, under no mesh, inside a region that is
+    manual over the batch axes, or where they do not divide B, this is the
+    plain loop.
     """
+    mesh = getattr(getattr(jax.typeof(x), "sharding", None), "mesh", None)
+    wide, axes, n = (), (), 1
+    if mesh is not None and not mesh.empty:
+        from ..parallel.mesh import BATCH_AXES
+        # the axes a chip's share can still be split along
+        wide = tuple(a for a, t in zip(mesh.axis_names, mesh.axis_types)
+                     if t != jax.sharding.AxisType.Manual
+                     and mesh.shape[a] > 1)
+        axes = tuple(a for a in wide if a in BATCH_AXES)
+        n = int(np.prod([mesh.shape[a] for a in axes]))
+    if n == 1 or x.shape[0] % n:
+        total, count = _chunked_nll_sum(x, emb, labels, chunk, shift)
+    else:
+        total, count = _per_shard_nll_sum(
+            mesh, axes, axes == wide and x.shape[2] % n == 0, x,
+            emb.astype(x.dtype), labels, chunk, shift)
+    return total / jnp.maximum(count, 1.0)
+
+
+def _per_shard_nll_sum(mesh, axes, scatter: bool, x, emb_c, labels,
+                       chunk: int, shift: int):
+    """``_chunked_nll_sum`` per batch shard under ``jax.shard_map`` over the
+    batch axes ``axes`` of ``mesh``, every other mesh axis left automatic;
+    the two sums ``psum``'d.
+
+    Why: the backward loop carries the [V, H] sum of the chunks' head
+    gradients. Left to sharding propagation that carry takes the layout of
+    the accumulated gradient (ZeRO's ``grad_spec``), and every chunk's
+    product is reduced over the chips INSIDE the loop (PERF.md, PR 34: 32
+    reductions of 412 MB a step of the dp4 cell). Here the carry is each
+    chip's own partial sum and crosses ``axes`` ONCE a call, behind the
+    loop, in the cotangent's dtype (scope ``grad_reduce``).
+
+    The one reduction takes one of two forms, by the shape of the mesh.
+    ``scatter``: ``axes`` are its only axes wider than 1 (and divide H), and
+    it is a reduce-scatter over H: the head matrix goes in twice, whole, as it lies
+    on every chip, for the arithmetic, and split over H, unused, to take
+    the gradient, which leaves H-sharded over ``axes``; where ZeRO shards it
+    so nothing follows, anywhere else the compiler adds the gather that
+    makes it an all-reduce. Where another automatic axis is wider than 1
+    (tensor parallelism: the head may be vocab-parallel over "model", which
+    this code cannot see) it is the all-reduce: that the partitioner splits
+    along such an axis, while it gathers a reduce-scatter's operand whole
+    over it first."""
+    vary = lambda a: jax.lax.pcast(a, axes, to="varying")
+
+    @jax.custom_vjp
+    def head_matrix(whole, split):
+        return vary(whole)
+
+    # XLA's CPU pipeline widens a 16-bit reduction to float32 itself
+    # (AllReducePromotion) and aborts on a reducer that carries this
+    # partly automatic region's sharding annotation: there the widening is
+    # written out
+    wire = jnp.float32 if jax.default_backend() == "cpu" else emb_c.dtype
+
+    def head_matrix_bwd(_, g):
+        with jax.named_scope("grad_reduce"):
+            wide = g.astype(jnp.promote_types(g.dtype, wire))
+            if scatter:
+                return None, jax.lax.psum_scatter(
+                    wide, axes, scatter_dimension=1, tiled=True).astype(g.dtype)
+            return jax.lax.psum(wide, axes).astype(g.dtype), None
+
+    head_matrix.defvjp(lambda whole, split: (vary(whole), None),
+                       head_matrix_bwd)
+
+    def per_shard(x, whole, split, labels):
+        return jax.lax.psum(_chunked_nll_sum(
+            x, head_matrix(whole, split), labels, chunk, shift, vary), axes)
+
+    return jax.shard_map(
+        per_shard, mesh=mesh, out_specs=(P(), P()), axis_names=frozenset(axes),
+        in_specs=(P(axes), P(), P(None, axes) if scatter else None, P(axes)))(
+            x, emb_c, emb_c if scatter else None, labels)
+
+
+def _chunked_nll_sum(x, emb, labels, chunk: int, shift: int,
+                     vary=lambda zero: zero):
+    """(sum of the token NLLs, count of tokens that are not -100) of
+    ``_fused_causal_lm_loss``: the loop over sequence chunks. ``vary``
+    makes the two sums' zeros a shard's own inside ``shard_map``."""
     B, S, H = x.shape
     if shift:
         xs = x[:, :-1]          # causal LM: predict the NEXT token
@@ -1154,10 +1243,9 @@ def _fused_causal_lm_loss(x, emb, labels, chunk: int, shift: int = 1):
         nll, cnt = chunk_nll(xc, tc)
         return (acc[0] + nll, acc[1] + cnt), None
 
-    (total, count), _ = jax.lax.scan(
-        body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
-        (xs, tgt))
-    return total / jnp.maximum(count, 1.0)
+    return jax.lax.scan(
+        body, (vary(jnp.zeros((), jnp.float32)),
+               vary(jnp.zeros((), jnp.float32))), (xs, tgt))[0]
 
 
 def fused_loss_passthrough(outputs, batch):

@@ -240,7 +240,7 @@ def span_times(entries: List[Entry], since_ns: int = 0
 SCOPES = frozenset({
     "embed", "layers", "block.attn", "qkv", "kv_write", "attend", "out",
     "block.mlp", "route", "dispatch", "experts", "combine",
-    "head", "loss", "sample", "grad_accum", "optimizer",
+    "head", "loss", "grad_reduce", "sample", "grad_accum", "optimizer",
     "zero.gather", "zero.scatter"})
 
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s.*?"

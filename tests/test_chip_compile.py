@@ -384,3 +384,75 @@ def test_olmoe_serving_step_is_dropless_and_in_place(chip, monkeypatch,
         # xfail turns into the assertion above.
         pytest.xfail(f"prefill at OLMoE's sizes copies the pool whole: "
                      f"{moved}")
+
+
+@pytest.mark.parametrize("layout,vocab", [("dp4", 50257), ("dp2_tp2", 50304)])
+def test_zero3_step_reduces_the_head_gradient_once_behind_the_loss_loop(
+        topo, chip, monkeypatch, layout, vocab):
+    """The four-chip training cell's own step (``initialize``: ZeRO-3, bf16
+    without master weights, bf16 accumulation, micro 2 x gas 4, seq 1024,
+    remat "dots", the fused loss over the tied [50257, 2048] embedding; two
+    layers for the cell's 24), its engine built on four virtual CPU devices
+    and its shardings moved onto the described 2x2 mesh: the optimized
+    program has no collective over the data-parallel chips in the body of
+    the loss's chunk loop, and the head gradient crosses them in ONE
+    reduction a micro-step, in bf16, behind the loop: a reduce-scatter.
+    Before PR 34 the accumulated gradient's ``[50257, 512]`` layout was
+    propagated into the loop: a float32 ``all-reduce-scatter`` fusion of
+    every chunk's product, 32 a step, 160 ms of the cell's 894 (PERF.md,
+    PR 34). dp2_tp2 is the
+    same step with the embedding vocab-parallel over "model" (a padded
+    vocabulary, so that two divide it): that axis stays automatic inside
+    the per-shard loss and keeps its own sums in the loop, and the one
+    reduction is an all-reduce of each chip's half of the vocabulary (a
+    reduce-scatter's operand the partitioner gathers whole over "model"
+    first: 206 MB a micro-step)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from deepspeed_tpu.models import TransformerConfig, build_model
+    from deepspeed_tpu.parallel.mesh import BATCH_AXES
+    from util import collectives, crosses, in_loss_loop, zero3_engine_on_four
+    dp, tp = (4, 1) if layout == "dp4" else (2, 2)
+    micro, gas, seq = 2, 4, 1024
+    rows = micro * gas * dp
+    model, cfg = build_model(TransformerConfig(
+        vocab_size=vocab, max_seq_len=2048, hidden_size=2048, num_layers=2,
+        num_heads=16, mlp_ratio=4, layer_norm_eps=1e-5, activation="gelu",
+        pos_embed="learned", tie_embeddings=True, use_bias=True,
+        norm="layernorm", remat=True, remat_policy="dots", fused_loss=True))
+    with zero3_engine_on_four(
+            model, cfg, {"input_ids": np.zeros((rows, seq), np.int32)},
+            micro=micro, gas=gas, tp=tp) as engine:
+        mesh = Mesh(np.array(topo.devices).reshape(engine.mesh.devices.shape),
+                    engine.mesh.axis_names)
+        named = lambda x: isinstance(x, NamedSharding)
+        move = lambda s: NamedSharding(mesh, s.spec) if named(s) else s
+        for name in ("param_shardings", "master_shardings", "grad_shardings",
+                     "opt_shardings"):
+            setattr(engine, name,
+                    jax.tree.map(move, getattr(engine, name), is_leaf=named))
+        engine.mesh = engine.mesh_mgr.mesh = engine.zero_policy.mesh = mesh
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        arg = lambda shape, dtype, spec=P(): jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, spec))
+        rng = engine.next_rng()
+        text = engine._make_train_step().lower(
+            jax.tree.map(lambda x: arg(x.shape, x.dtype, x.sharding.spec),
+                         engine.state),
+            {"input_ids": arg((gas, rows // gas, seq), jnp.int32,
+                              P(None, BATCH_AXES))},
+            arg(rng.shape, rng.dtype), arg((), jnp.float32)).compile().as_text()
+
+    over_chips = [c for c in collectives(text)
+                  if crosses(c[3], mesh, BATCH_AXES)]
+    assert not [c for c in over_chips if in_loss_loop(c[2])], over_chips
+    kinds = lambda cs: [(c[0], c[1].split("{")[0]) for c in cs]
+    if tp == 1:
+        head = [c for c in over_chips if "/loss/" in c[2] and "50257" in c[1]]
+        assert kinds(head) == [("reduce-scatter", "bf16[50257,512]")], head
+        assert "grad_reduce" in head[0][2], head
+    else:
+        # the compiler combines or renames an all-reduce: found by its shape
+        assert kinds(over_chips).count(
+            ("all-reduce", f"bf16[{vocab // tp},2048]")) == 1, over_chips
+        assert not [c for c in collectives(text) if f"[{vocab}," in c[1]]

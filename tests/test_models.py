@@ -145,6 +145,105 @@ def test_fused_loss_matches_unfused():
     assert abs(float(l1m - l2m)) < 1e-5
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("shift", [1, 0], ids=["causal", "in_place"])
+@pytest.mark.parametrize("head", ["tied", "untied"])
+@pytest.mark.parametrize("layout", ["dp4", "dp2_tp2"])
+def test_fused_loss_per_shard_matches_one_device_and_unfused(layout, head,
+                                                             shift, dtype):
+    """The fused loss on a mesh of four chips (the chunk loop per batch
+    shard, the head gradient summed on each chip and reduced once behind
+    the loop: dp4 by a reduce-scatter, dp2_tp2, the head vocab-parallel
+    over "model", by an all-reduce) against one device (the plain loop) and
+    against the unfused cross entropy over whole logits: loss, d x and the
+    head's gradient, with -100 labels and a sequence that needs padding to
+    the chunk. The untied head hands in its [H, V] kernel's transpose, as
+    ``Transformer`` does."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from deepspeed_tpu.models.transformer import (_fused_causal_lm_loss,
+                                                  cross_entropy)
+    from deepspeed_tpu.parallel.mesh import BATCH_AXES, MESH_AXES, TP_AXIS
+    B, S, H, V, chunk = 8, 37, 16, 50, 8
+    k = jax.random.split(jax.random.PRNGKey(3), 3)
+    x = jax.random.normal(k[0], (B, S, H), dtype)
+    w = jax.random.normal(k[1], (V, H) if head == "tied" else (H, V), dtype)
+    labels = jax.random.randint(k[2], (B, S), 0, V).at[:, 5:9].set(-100)
+    emb = (lambda w: w) if head == "tied" else (lambda w: w.T)
+
+    def fused(x, w, labels):
+        return _fused_causal_lm_loss(x, emb(w), labels, chunk, shift=shift)
+
+    def unfused(x, w, labels):
+        logits = jnp.einsum("bsh,vh->bsv", x, emb(w),
+                            preferred_element_type=jnp.float32)
+        if shift:
+            return cross_entropy(logits[:, :-1], labels[:, 1:])
+        return cross_entropy(logits, labels)
+
+    grad = lambda f: jax.jit(jax.value_and_grad(f, argnums=(0, 1)))
+    dp, tp = (4, 1) if layout == "dp4" else (2, 2)
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(1, dp, 1, 1, tp),
+                MESH_AXES)
+    rows = NamedSharding(mesh, P(BATCH_AXES))
+    vocab = P() if tp == 1 else \
+        P(TP_AXIS) if head == "tied" else P(None, TP_AXIS)
+    on_mesh = (jax.device_put(x, rows),
+               jax.device_put(w, NamedSharding(mesh, vocab)),
+               jax.device_put(labels, rows))
+    assert "shard_map" in str(jax.make_jaxpr(fused)(*on_mesh))
+    assert "shard_map" not in str(jax.make_jaxpr(fused)(x, w, labels))
+    assert ("reduce_scatter" in str(jax.make_jaxpr(
+        jax.grad(fused, argnums=1))(*on_mesh))) == (tp == 1)
+    got = grad(fused)(*on_mesh)
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    for want in (grad(fused)(x, w, labels), grad(unfused)(x, w, labels)):
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            assert a.dtype == b.dtype and a.shape == b.shape
+            a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+            np.testing.assert_allclose(a, b, rtol=tol,
+                                       atol=tol * np.abs(b).max())
+
+
+@pytest.mark.parametrize("layout", ["dp4", "dp2_tp2"])
+@pytest.mark.parametrize("head", ["tied", "untied"])
+def test_fused_loss_loop_holds_no_collective_in_the_engines_step(head, layout):
+    """The CPU twin of tests/test_chip_compile.py's pin: through
+    ``initialize`` and the engine's own ZeRO-3 step on four virtual devices,
+    the optimized program has no collective inside the loss's chunk loop
+    that crosses the data-parallel chips (before PR 34 the partitioner
+    carried the accumulated gradient's layout into the loop and reduced
+    every chunk's [V, H] product over them), and the head gradient's one
+    reduction over them lies behind it: a reduce-scatter on a mesh of
+    data-parallel chips only."""
+    from deepspeed_tpu.parallel.mesh import BATCH_AXES
+    from util import collectives, crosses, in_loss_loop, zero3_engine_on_four
+    tp = 2 if layout == "dp2_tp2" else 1
+    model, cfg = build_model(
+        "gpt2-tiny", hidden_size=32, num_layers=2, num_heads=2, vocab_size=66,
+        max_seq_len=64, remat=True, remat_policy="dots", fused_loss=True,
+        loss_chunk=8, tie_embeddings=head == "tied",
+        attention_impl="reference")
+    batch = {"input_ids": np.random.default_rng(0).integers(
+        0, 66, size=(2 * 2 * (4 // tp), 32)).astype(np.int32)}
+    with zero3_engine_on_four(model, cfg, batch, micro=2, gas=2, tp=tp,
+                              stage3_param_persistence_threshold=0) as engine:
+        text = engine._active_train_step()[0].lower(
+            engine.state, engine._put_micro_batches(batch),
+            engine.next_rng(), engine._current_lr()).compile().as_text()
+        assert np.isfinite(float(engine.train_batch(batch)["loss"]))
+    # tensor parallelism keeps its own sums over "model" in the loop (the
+    # softmax over a split vocabulary, d x): those are not this test's
+    over_chips = [c for c in collectives(text)
+                  if crosses(c[3], engine.mesh, BATCH_AXES)]
+    assert not [c for c in over_chips if in_loss_loop(c[2])], over_chips
+    reductions = [c[0] for c in over_chips if "grad_reduce" in c[2]]
+    # beside tensor parallelism it is an all-reduce, which the CPU's
+    # compiler combines with others under one of their names
+    assert reductions == ["reduce-scatter"] if tp == 1 else \
+        "reduce-scatter" not in reductions, over_chips
+
+
 @pytest.mark.slow
 def test_remat_policies_agree():
     """dots/full remat and no remat give identical losses AND gradients
