@@ -18,7 +18,7 @@ additive biases are the documented fallbacks to the jnp reference.
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -155,47 +155,58 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
                     q_start=None,
                     impl: str = "auto",
                     interpret: bool = False) -> jnp.ndarray:
-    """Dispatching paged-attention entry point (serving decode path).
+    """Dispatching paged-attention entry point (the serving loop's reads).
 
     q [B, nh, T, hd] against a block-pool K/V ([L?, nh, num_blocks,
     block_size, hd]) through per-sequence ``block_tables`` [B, max_blocks]
     and ``context_lens`` [B]. The Pallas kernel
-    (ops/pallas/paged_attention.py) serves the decode regime (T == 1, TPU
-    or interpret) with ALiBi/softcap/window in-kernel; every other regime
-    — prefill (T > 1, possibly with PADDED trailing queries positioned by
-    ``q_start``), CPU, untileable shapes (warned once on a TPU) — runs the
-    exact jnp gather reference. int8 pools ride both paths via
+    (ops/pallas/paged_attention.py) serves a decode step (T == 1) and a
+    prefill chunk (T > 1 queries at ``q_start + row``, possibly with PADDED
+    trailing ones) alike on a TPU or under ``interpret``, with
+    ALiBi/softcap/window in-kernel; the CPU and shapes the kernel cannot
+    tile (:func:`paged_attention_path` says which and why; warned once on a
+    TPU) run the exact jnp gather reference. int8 pools ride both paths via
     ``k_scale``/``v_scale`` (per-(layer, head, slot) f32, dequantized
     in-kernel / post-gather).
     ``impl="reference"`` forces the oracle.
     """
     kw = dict(sm_scale=sm_scale, alibi_slopes=alibi_slopes, softcap=softcap,
               window=window, layer_idx=layer_idx, k_scale=k_scale,
-              v_scale=v_scale)
-    on_tpu = jax.default_backend() == "tpu"
-    if impl in ("auto", "flash") and (on_tpu or interpret):
+              v_scale=v_scale, q_start=q_start)
+    # the shape test comes BEFORE the call: whatever the kernel itself
+    # raises (the chip's compiler refusing it, a pool without scales) is an
+    # error, never a quiet route to the reference
+    path, reason = paged_attention_path(
+        q.shape, k_pool.shape, stacked=layer_idx is not None,
+        quant=k_scale is not None, impl=impl, interpret=interpret)
+    if path == "kernel":
         from .pallas.paged_attention import paged_attention as _kernel
-        from .pallas.paged_attention import untileable
-        # the shape test comes BEFORE the call: whatever the kernel itself
-        # raises (the chip's compiler refusing it, a pool without scales)
-        # is an error, never a quiet route to the reference
-        reason = untileable(q.shape, k_pool.shape,
-                            stacked=layer_idx is not None,
-                            quant=k_scale is not None, interpret=interpret)
-        if reason is None:
-            # T == 1: the query position is ctx - 1 by the decode contract,
-            # so q_start (== ctx - 1 when given) carries no extra information
-            return _kernel(q, k_pool, v_pool, block_tables, context_lens,
-                           interpret=interpret, **kw)
-        if on_tpu and q.shape[2] == 1:
-            # prefill (T > 1) riding the reference is the documented
-            # regime; a DECODE step doing so on a TPU is said once
-            from ..utils.logging import warning_once
-            warning_once("paged_attention on TPU takes the jnp gather "
-                         f"reference: {reason}")
+        return _kernel(q, k_pool, v_pool, block_tables, context_lens,
+                       interpret=interpret, **kw)
+    if reason and jax.default_backend() == "tpu":
+        from ..utils.logging import warning_once
+        warning_once("paged_attention on TPU takes the jnp gather "
+                     f"reference: {reason}")
     from .pallas.paged_attention import paged_attention_reference
     return paged_attention_reference(q, k_pool, v_pool, block_tables,
-                                     context_lens, q_start=q_start, **kw)
+                                     context_lens, **kw)
+
+
+def paged_attention_path(q_shape, pool_shape, *, stacked: bool, quant: bool,
+                         impl: str = "auto", interpret: bool = False
+                         ) -> Tuple[str, Optional[str]]:
+    """``("kernel", None)`` or ``("reference", why)`` for a
+    :func:`paged_attention` call of these shapes, decided from them alone:
+    ``why`` is the kernel's own ``untileable`` reason on a TPU or under
+    ``interpret``, and None where the reference was asked for
+    (``impl="reference"``) or no TPU is there to run the kernel."""
+    if impl not in ("auto", "flash") or not (
+            jax.default_backend() == "tpu" or interpret):
+        return "reference", None
+    from .pallas.paged_attention import untileable
+    reason = untileable(q_shape, pool_shape, stacked=stacked, quant=quant,
+                        interpret=interpret)
+    return ("kernel", None) if reason is None else ("reference", reason)
 
 
 def flash_attention_on_mesh(q, k, v, *, mask=None, alibi_slopes=None,

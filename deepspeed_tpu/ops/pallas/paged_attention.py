@@ -1,11 +1,13 @@
-"""Pallas paged-attention decode kernel — KV pages copied via block table.
+"""Pallas paged-attention kernel — KV pages copied via block table.
 
 The serving subsystem (deepspeed_tpu/serving/) keeps the KV cache as a POOL
 of fixed-size blocks (pages) shared by every in-flight sequence; a
 per-sequence *block table* maps logical page j to a physical pool block. The
 decode step then needs attention of one fresh query token per sequence
-against a K/V that is physically scattered across the pool. This kernel
-walks only the pages a sequence HOLDS: the grid is (sequences, head groups),
+against a K/V that is physically scattered across the pool, and a prefill
+chunk the same for its T query rows (since PR 37: until then a chunk rode
+the gather reference, which slices a whole layer out of the pool). This
+kernel walks only the pages a sequence HOLDS: the grid is (sequences, head groups),
 the pools stay in HBM, and a program loops over its sequence's
 ``ceil(ctx_len / block_size)`` live pages (from the window's first page when
 a sliding window is set), ``P`` pages a turn. It copies each page's
@@ -23,10 +25,26 @@ with the tokens each sequence has generated, not with the pool or the table.
 the largest power of two whose four group buffers fit
 :data:`_VMEM_BUDGET`, at most the table's length).
 
+A prefill chunk is the same loop under a taller query tile, ``[heads, T,
+head_dim]`` for the eight rows a decode token is broadcast to: row r is the
+query at ``q_start + r``, the causal and window masks are a row's own, the
+pages run from the first query's window to the last real query's page
+(``ctx - 1``: ``cache.write`` has put the chunk's own keys into the pool
+just before), and rows at or past ``ctx`` are bucket padding whose finite
+garbage nobody reads. A chunk's rows are padded to whole tiles of
+:data:`_CHUNK_TILE` and the call is made under ``jax.jit``, so the prefill
+programs of a serving loop (one a chunk shape) trace ONE shape once; heads
+a program and pages a group shrink with the rows (:func:`_head_group`,
+:func:`_pages_per_group`) so that the float32 accumulator, the two running
+rows and one group's scores stay inside the chip's scoped VMEM beside the
+page buffers, and a group's page copies are a loop, not unrolled. At T == 1
+everything is what it was, the decode program's lowered text included.
+
 That is :func:`_loop_kernel`, for heads of whole 128-lane tiles. A pool of
 narrower heads (``head_dim % 128``: 64, 80, 96) is padded to 128 lanes a row
 in HBM by the chip's compiler, which then refuses a kernel's own copy of the
-unpadded part; such pools take :func:`_grid_kernel`, the form every pool
+unpadded part; such pools take :func:`_grid_kernel` (decode only: their
+chunks keep the reference, :func:`untileable`), the form every pool
 took until PR 28: the pages come through the pipeline (the K/V BlockSpec's
 index_map reads the block table), one a grid step along a third axis as long
 as the table, dead steps clamped to the last live page. Same math
@@ -78,10 +96,38 @@ _QROWS = 8
 #: VMEM the K and V group buffers (two slots each) may take together
 _VMEM_BUDGET = 4 << 20
 
+#: query rows a program may hold, heads a program x rows a head: a prefill
+#: chunk's accumulator, its two running rows and its query and output tiles
+#: grow with them (3.5 KB a row of 128-wide heads)
+_CHUNK_ROWS = 1024
 
-def _head_group(nh: int, block_k: int, hd: int, itemsize: int) -> int:
-    """Heads per program: target ~1MB K blocks, largest divisor of nh."""
+#: rows a chunk's query tile is padded to. A serving loop compiles a prefill
+#: program a chunk shape (eight in the benchmark's cells) and each traces
+#: and lowers this kernel anew at every start: padded to one tile they are
+#: one shape, traced once (:func:`paged_attention`), and the rows past a
+#: chunk's own cost a first chunk little, since the loop walks live pages
+_CHUNK_TILE = 256
+
+#: VMEM one group's float32 scores [heads, rows, keys] may take (the mask,
+#: the probabilities and their bf16 copy live beside them, about 4x this)
+_SCORE_BUDGET = 1 << 20
+
+
+def _query_rows(T: int) -> int:
+    """Rows of a program's query tile: a decode token broadcast to the
+    sublane minimum, a chunk's T rows padded to whole :data:`_CHUNK_TILE`s,
+    so that every chunk shape of a serving loop is one call of one shape."""
+    return _QROWS if T == 1 else -(-T // _CHUNK_TILE) * _CHUNK_TILE
+
+
+def _head_group(nh: int, block_k: int, hd: int, itemsize: int,
+                T: int = 1) -> int:
+    """Heads per program: target ~1MB K blocks, largest divisor of nh; a
+    chunk of T > 1 query rows a head takes no more heads than keep the
+    program's rows within :data:`_CHUNK_ROWS`."""
     target = max(1, (1 << 20) // (block_k * hd * itemsize))
+    if T > 1:
+        target = max(1, min(target, _CHUNK_ROWS // _query_rows(T)))
     return max(d for d in range(1, min(nh, target) + 1) if nh % d == 0)
 
 
@@ -109,26 +155,31 @@ def scale_rows(scale, pool_shape) -> jnp.ndarray:
 
 
 def _pages_per_group(hg: int, bs: int, hd: int, itemsize: int, nbk: int,
-                     quant: bool = False) -> int:
+                     quant: bool = False, T: int = 1) -> int:
     """Pages of one copy group, from the operand shapes alone: the largest
     power of two whose K and V group buffers, two slots each, stay inside
     :data:`_VMEM_BUDGET` (the int8 tier's scale rows counted as the padded
     (8, 128) float32 tiles they may take in VMEM), never more than the
-    table holds."""
+    table holds; under a chunk of T > 1 query rows a head also no more than
+    keep one group's scores inside :data:`_SCORE_BUDGET`."""
     page = 4 * hg * bs * hd * itemsize            # K and V, two slots
     if quant:
         page += 4 * hg * 8 * _scale_lanes(bs) * 4
+    scores = hg * _query_rows(T) * bs * 4 if T > 1 else 0
     p = 1
-    while 2 * p * page <= _VMEM_BUDGET and 2 * p <= nbk:
+    while 2 * p * page <= _VMEM_BUDGET and 2 * p <= nbk \
+            and 2 * p * scores <= _SCORE_BUDGET:
         p *= 2
     return p
 
 
 def _attend(q, k, v, ks, vs, k0, ctx, window, slopes_ref, acc, m_scr, l_scr,
-            *, sm_scale, softcap):
-    """One online-softmax update: the query ``q`` [hg, 8, hd] against the
+            *, sm_scale, softcap, q0=None):
+    """One online-softmax update: the query ``q`` [hg, rows, hd] against the
     keys ``k`` / values ``v`` [hg, n, hd] at logical positions
-    ``[k0, k0 + n)``, folded into the running max, sum and output."""
+    ``[k0, k0 + n)``, folded into the running max, sum and output. The rows
+    are one decode token at ``ctx - 1``, broadcast (``q0`` None), or a
+    prefill chunk's queries at ``q0 + row``."""
     if ks is not None:
         # int8 tier (round 17): the copies moved int8 rows + one f32 scale
         # per (head, slot); dequantize HERE, on the keys already in VMEM —
@@ -147,15 +198,28 @@ def _attend(q, k, v, ks, vs, k0, ctx, window, slopes_ref, acc, m_scr, l_scr,
     s = s * sm_scale
     if softcap:
         s = jnp.tanh(s / softcap) * softcap
-    # one real query at absolute (logical) position ctx - 1, broadcast over
-    # the 8 padded rows; the keys' logical positions do not depend on which
-    # PHYSICAL pages the table routed the copies to
-    q_abs = ctx - 1
-    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+    # the keys' logical positions do not depend on which PHYSICAL pages the
+    # table routed the copies to
+    if q0 is None:
+        # one real query at absolute (logical) position ctx - 1, broadcast
+        # over the 8 padded rows
+        q_abs = ctx - 1
+        k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+    else:
+        # a chunk: row r is the query at q0 + r; positions and masks are
+        # worked out once for the heads of the program, [1, rows, n]
+        one = (1,) + s.shape[1:]
+        q_abs = q0 + jax.lax.broadcasted_iota(jnp.int32, one, 1)
+        k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, one, 2)
     if slopes_ref is not None:
         slope = slopes_ref[0][:, :1][:, None, :]            # [hg, 1, 1]
         s = s + slope * (k_pos - q_abs).astype(jnp.float32)
     keep = k_pos <= q_abs                                   # causal + dead tail
+    if q0 is not None:
+        # a row at or past ctx is bucket padding: it sees the live keys and
+        # no page the loop skipped, so that what it gives is finite (nobody
+        # reads it)
+        keep &= k_pos < ctx
     keep &= (q_abs - k_pos < window) | (window <= 0)        # sliding window
     s = jnp.where(keep, s, NEG_INF)
     m_prev = m_scr[:, :, :1]
@@ -215,7 +279,8 @@ def _grid_kernel(bt_ref, lens_ref, misc_ref, q_ref, k_ref, v_ref, *rest, bs,
 
 
 def _loop_kernel(bt_ref, lens_ref, misc_ref, q_ref, k_hbm, v_hbm, *rest, hg,
-                 bs, P, nbk, sm_scale, softcap, has_alibi, stacked, quant):
+                 bs, P, nbk, sm_scale, softcap, has_alibi, stacked, quant,
+                 chunk=False):
     if quant:
         (ks_hbm, vs_hbm, slopes_ref, o_ref, k_buf, v_buf, ks_buf, vs_buf,
          acc, m_scr, l_scr, state, sem) = rest
@@ -227,43 +292,61 @@ def _loop_kernel(bt_ref, lens_ref, misc_ref, q_ref, k_hbm, v_hbm, *rest, hg,
 
     def span(b):
         """Sequence b's first and one-past-last live page, and the groups of
-        P pages that hold them. A window drops the pages wholly before it;
-        an idle lane (ctx 0) has no group at all."""
+        P pages that hold them. A window drops the pages wholly before it
+        (before the window of a chunk's FIRST query, at ``misc[2 + b]``: the
+        last real one, at ctx - 1, sets the last page); an idle lane (ctx 0)
+        has no group at all."""
         ctx = lens_ref[b]
         cnt = jnp.minimum((ctx + bs - 1) // bs, nbk)
-        first = jnp.where(window > 0, jnp.maximum(ctx - window, 0) // bs, 0)
+        first = jnp.where(window > 0, jnp.maximum(
+            (misc_ref[2 + b] + 1 if chunk else ctx) - window, 0) // bs, 0)
         return first, cnt, first // P, (cnt + P - 1) // P
 
-    def copies(b, g, first, cnt, i, slot):
-        """(live, copy) of every page of sequence b's group i into buffer
-        ``slot``: the heads of group g, one physical page of one layer,
+    def page_copies(heads, b, first, cnt, i, slot, p):
+        """(live, its copies) of page p of sequence b's group i into buffer
+        ``slot``: the heads of a head group, one physical page of one layer,
         out of the pool where it lies. Starting and waiting rebuild the
         same descriptors."""
+        page = i * P + p
+        live = (page >= first) & (page < cnt)
+        phys = bt_ref[b, jnp.minimum(page, nbk - 1)]
+        at = (layer, heads, phys) if stacked else (heads, phys)
+        rows = pl.ds(p * bs if isinstance(p, int)
+                     else pl.multiple_of(p * bs, bs), bs)
+        pairs = [(k_hbm, k_buf.at[slot, :, rows], 0),
+                 (v_hbm, v_buf.at[slot, :, rows], 1)]
+        if quant:
+            pairs += [(ks_hbm, ks_buf.at[slot, :, p], 0),
+                      (vs_hbm, vs_buf.at[slot, :, p], 1)]
+        return live, [pltpu.make_async_copy(pool.at[at], dst, sem.at[s, slot])
+                      for pool, dst, s in pairs]
+
+    def each_copy(act, b, g, *group):
+        """``act`` ("start" or "wait") on every live page's copies of group
+        ``i`` of sequence b, head group g (``group``: first, cnt, i, slot).
+        A decode program has them unrolled, P pages a site; a chunk's
+        programs loop over the pages instead: every prefill program of a
+        serving loop lowers this kernel at every start, and the unrolled
+        sites were most of its text (PERF.md, PR 37: ``setup_s``)."""
         heads = pl.ds(g * hg, hg)
-        out = []
-        for p in range(P):
-            page = i * P + p
-            live = (page >= first) & (page < cnt)
-            phys = bt_ref[b, jnp.minimum(page, nbk - 1)]
-            at = (layer, heads, phys) if stacked else (heads, phys)
-            rows = pl.ds(p * bs, bs)
-            pairs = [(k_hbm, k_buf.at[slot, :, rows], 0),
-                     (v_hbm, v_buf.at[slot, :, rows], 1)]
-            if quant:
-                pairs += [(ks_hbm, ks_buf.at[slot, :, p], 0),
-                          (vs_hbm, vs_buf.at[slot, :, p], 1)]
-            out += [(live, pltpu.make_async_copy(pool.at[at], dst,
-                                                 sem.at[s, slot]))
-                    for pool, dst, s in pairs]
-        return out
+        if not chunk:
+            for live, copies in [page_copies(heads, b, *group, p)
+                                 for p in range(P)]:
+                for copy in copies:
+                    pl.when(live)(getattr(copy, act))
+            return
 
-    def start(*group):
-        for live, copy in copies(*group):
-            pl.when(live)(copy.start)
+        def body(p, _):
+            live, copies = page_copies(heads, b, *group, p)
 
-    def wait(*group):
-        for live, copy in copies(*group):
-            pl.when(live)(copy.wait)
+            @pl.when(live)
+            def _():
+                for copy in copies:
+                    getattr(copy, act)()
+
+        jax.lax.fori_loop(0, P, body, None)
+
+    start, wait = partial(each_copy, "start"), partial(each_copy, "wait")
 
     first, cnt, g0, g1 = span(b)
     # the program that runs next: its first group is started from this
@@ -315,7 +398,8 @@ def _loop_kernel(bt_ref, lens_ref, misc_ref, q_ref, k_hbm, v_hbm, *rest, hg,
                 for buf in (ks_buf, vs_buf))
         _attend(q_ref[0, 0], k_buf[slot], v_buf[slot], ks, vs, i * (P * bs),
                 lens_ref[b], window, slopes_ref if has_alibi else None, acc, m_scr,
-                l_scr, sm_scale=sm_scale, softcap=softcap)
+                l_scr, sm_scale=sm_scale, softcap=softcap,
+                q0=misc_ref[2 + b] if chunk else None)
 
     jax.lax.fori_loop(g0, g1, group, None)
     state[0] = (slot0 + g1 - g0) % 2
@@ -329,9 +413,6 @@ def untileable(q_shape, pool_shape, *, stacked: bool, quant: bool,
     route on this instead of catching the kernel's errors, so a refusal by
     the chip's compiler can never be read as "shapes don't tile"."""
     T, hd = q_shape[2], q_shape[3]
-    if T != 1:
-        return (f"paged_attention decodes 1 token/seq (got T={T}); "
-                "prefill rides the gather reference/flash paths")
     if interpret:
         return None
     bs = pool_shape[3 if stacked else 2]
@@ -343,6 +424,13 @@ def untileable(q_shape, pool_shape, *, stacked: bool, quant: bool,
     if quant and bs % 32 != 0:
         return (f"block_size {bs} does not tile the int8 KV tier (int8 "
                 "sublane multiple of 32 required)")
+    if T > 1 and hd % 128 != 0:
+        return (f"head_dim {hd} is no multiple of 128 lanes: such a pool's "
+                "pages come through the pipeline a grid step each, for one "
+                f"query row a sequence (got T={T})")
+    if T > _CHUNK_ROWS:              # whole tiles: the padded rows too
+        return (f"{T} query rows a sequence are more than one program "
+                f"holds ({_CHUNK_ROWS}): chunk the prefill")
     return None
 
 
@@ -359,11 +447,18 @@ def paged_attention(q: jnp.ndarray,
                     layer_idx=None,
                     k_scale=None,
                     v_scale=None,
+                    q_start=None,
                     interpret: bool = False) -> jnp.ndarray:
-    """One decode token per sequence against a paged KV pool.
+    """T query tokens per sequence against a paged KV pool: a decode step's
+    one, or a prefill chunk's T > 1, whose own keys the pool holds already.
 
-    q: [B, nh, 1, hd] — each sequence's fresh query, at logical position
-       ``context_lens[b] - 1`` (context_lens INCLUDES the new token).
+    q: [B, nh, T, hd]. T == 1: each sequence's fresh query, at logical
+       position ``context_lens[b] - 1`` (context_lens INCLUDES the new
+       token). T > 1: queries at ``q_start[b] + row`` (``q_start`` [B];
+       ``context_lens - T`` when not given), causal among themselves; rows
+       at or past ``context_lens[b]`` are bucket padding, whose finite
+       garbage the caller discards. Heads a program and pages a group
+       shrink with T (:func:`_head_group`, :func:`_pages_per_group`).
     k_pool/v_pool: [nh, num_blocks, block_size, hd]; with ``layer_idx``
        (traced i32 ok) the stacked [L, nh, num_blocks, block_size, hd]
        layout — the kernel's copies pick the layer straight out of the
@@ -385,37 +480,69 @@ def paged_attention(q: jnp.ndarray,
        bias slope * (k_pos - q_pos)). ``softcap``: Gemma-2 tanh cap
        (STATIC float — it changes the compiled math).
 
-    Returns [B, nh, 1, hd]. Raises ValueError (the :func:`untileable`
+    Returns [B, nh, T, hd]. Raises ValueError (the :func:`untileable`
     reason) when shapes can't tile — callers ask :func:`untileable` FIRST
     and route to :func:`paged_attention_reference` on a reason, so an
     error out of this function is never mistaken for one.
     """
-    B, nh, T, hd = q.shape
+    T = q.shape[2]
     stacked = layer_idx is not None
     quant = k_scale is not None
     reason = untileable(q.shape, k_pool.shape, stacked=stacked, quant=quant,
                         interpret=interpret)
     if reason is not None:
         raise ValueError(reason)
-    bs = k_pool.shape[3 if stacked else 2]
     if quant:
         if k_pool.dtype != jnp.int8:
             raise ValueError("k_scale/v_scale given but the pool dtype is "
                              f"{k_pool.dtype} — scales pair with int8 pools")
-        ks_pool = scale_rows(k_scale, k_pool.shape)
-        vs_pool = scale_rows(v_scale, v_pool.shape)
+        k_scale = scale_rows(k_scale, k_pool.shape)
+        v_scale = scale_rows(v_scale, v_pool.shape)
     elif k_pool.dtype == jnp.int8:
         raise ValueError("int8 KV pool needs k_scale/v_scale "
                          "(quant_format.kv_quantize layout)")
-    nbk = block_tables.shape[1]
-    hg = _head_group(nh, bs, hd, k_pool.dtype.itemsize)
-    ng = nh // hg
-    scale = sm_scale if sm_scale is not None else 1.0 / np.sqrt(hd)
-    softcap = float(softcap) if softcap else 0.0
+    kw = dict(sm_scale=sm_scale, alibi_slopes=alibi_slopes,
+              softcap=float(softcap) if softcap else 0.0, window=window,
+              layer_idx=layer_idx, k_scale=k_scale, v_scale=v_scale,
+              interpret=interpret)
+    if T == 1:
+        return _paged_attention(q, k_pool, v_pool, block_tables,
+                                context_lens, q_start=None, **kw)
+    # a chunk: its rows padded to whole tiles, each lane's first position
+    # made explicit, and the call shared by every program of these shapes
+    # (the interpreter's parameter object is not hashable: called as it is)
+    lens = jnp.asarray(context_lens, jnp.int32).reshape(q.shape[0])
+    q0 = lens - T if q_start is None else jnp.asarray(
+        q_start, jnp.int32).reshape(q.shape[0])
+    q = jnp.pad(q, [(0, 0), (0, 0), (0, _query_rows(T) - T), (0, 0)])
+    call = _shared_chunk_call if isinstance(interpret, bool) \
+        else _paged_attention
+    return call(q, k_pool, v_pool, block_tables, lens, q_start=q0,
+                **kw)[:, :, :T]
 
-    # broadcast the single query row to the sublane minimum (all 8 rows are
-    # the real query; row 0 is read back)
-    qf = jnp.broadcast_to(q.reshape(B, ng, hg, 1, hd), (B, ng, hg, _QROWS, hd))
+
+def _paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
+                     sm_scale, alibi_slopes, softcap, window, layer_idx,
+                     k_scale, v_scale, q_start, interpret):
+    """:func:`paged_attention`, its shapes found tileable, on a query of
+    whole tiles (one row, or a chunk's rows padded to :func:`_query_rows`)
+    and scales in :func:`scale_rows`' layout."""
+    B, nh, T, hd = q.shape
+    stacked = layer_idx is not None
+    quant = k_scale is not None
+    bs = k_pool.shape[3 if stacked else 2]
+    ks_pool, vs_pool = k_scale, v_scale
+    nbk = block_tables.shape[1]
+    hg = _head_group(nh, bs, hd, k_pool.dtype.itemsize, T)
+    ng = nh // hg
+    rows = _query_rows(T)
+    scale = sm_scale if sm_scale is not None else 1.0 / np.sqrt(hd)
+
+    qf = q.reshape(B, ng, hg, T, hd)
+    if T == 1:
+        # broadcast the single query row to the sublane minimum (all 8 rows
+        # are the real query; row 0 is read back)
+        qf = jnp.broadcast_to(qf, (B, ng, hg, rows, hd))
 
     bt = jnp.asarray(block_tables, jnp.int32)
     lens = jnp.asarray(context_lens, jnp.int32).reshape(B)
@@ -423,19 +550,25 @@ def paged_attention(q: jnp.ndarray,
     li = jnp.asarray(0 if layer_idx is None else layer_idx,
                      jnp.int32).reshape(())
     misc = jnp.stack([win, li])
+    if T > 1:
+        # a chunk: each lane's first position, behind the window and layer
+        misc = jnp.concatenate([misc, q_start])
 
-    qo_spec = pl.BlockSpec((1, 1, hg, _QROWS, hd),
+    qo_spec = pl.BlockSpec((1, 1, hg, rows, hd),
                            lambda b, g, *_: (b, g, 0, 0, 0))
-    online = [pltpu.VMEM((hg, _QROWS, hd), jnp.float32),
-              pltpu.VMEM((hg, _QROWS, 128), jnp.float32),
-              pltpu.VMEM((hg, _QROWS, 128), jnp.float32)]
+    online = [pltpu.VMEM((hg, rows, hd), jnp.float32),
+              pltpu.VMEM((hg, rows, 128), jnp.float32),
+              pltpu.VMEM((hg, rows, 128), jnp.float32)]
     static = dict(bs=bs, nbk=nbk, sm_scale=scale, softcap=softcap,
                   has_alibi=alibi_slopes is not None, stacked=stacked,
                   quant=quant)
-    if hd % 128 == 0:
+    if hd % 128 == 0 or T > 1:
         # the pools stay in HBM: the kernel copies the pages a lane holds
-        P = _pages_per_group(hg, bs, hd, k_pool.dtype.itemsize, nbk, quant)
-        kernel = partial(_loop_kernel, hg=hg, P=P, **static)
+        # (a chunk of narrower heads comes here under the interpreter only:
+        # :func:`untileable`)
+        P = _pages_per_group(hg, bs, hd, k_pool.dtype.itemsize, nbk, quant,
+                             T)
+        kernel = partial(_loop_kernel, hg=hg, P=P, chunk=T > 1, **static)
         grid = (B, ng)
         kv_specs = [pl.BlockSpec(memory_space=pl.ANY)] * (4 if quant else 2)
         scratch = [pltpu.VMEM((2, hg, P * bs, hd), k_pool.dtype)] * 2
@@ -479,10 +612,17 @@ def paged_attention(q: jnp.ndarray,
     with jax.named_scope("paged_attention"):
         out = pl.pallas_call(
             kernel, grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((B, ng, hg, _QROWS, hd), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((B, ng, hg, rows, hd), q.dtype),
             interpret=interpret,
         )(bt, lens, misc, *operands)
-    return out[:, :, :, :1].reshape(B, nh, 1, hd)
+    return out[:, :, :, :T].reshape(B, nh, T, hd)
+
+
+#: a chunk's call under ``jax.jit``: the prefill programs of a serving loop
+#: all make it at ONE shape (:data:`_CHUNK_TILE`), so the kernel is traced
+#: for the first and found for the rest (lowering is still a program's own)
+_shared_chunk_call = jax.jit(
+    _paged_attention, static_argnames=("sm_scale", "softcap", "interpret"))
 
 
 def paged_attention_reference(q: jnp.ndarray,
@@ -503,7 +643,7 @@ def paged_attention_reference(q: jnp.ndarray,
     then exactly the decode-path attention math (f32 scores, softcap
     before the ALiBi bias before the -1e30 masks, f32 softmax).
 
-    Generalizes over the kernel: q may carry T > 1 query tokens (the
+    Like the kernel, q may carry T > 1 query tokens (the
     PREFILL of a paged sequence — queries at logical positions
     [ctx - T, ctx), or [q_start, q_start + T) when ``q_start`` [B] is
     given: a bucket-PADDED prefill carries trailing garbage queries past

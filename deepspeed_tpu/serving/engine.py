@@ -45,7 +45,7 @@ from ..testing import chaos
 from ..utils import telemetry
 from ..utils.logging import log_dist, logger
 from .kv_cache import (NULL_BLOCK, BlockPoolExhausted, SharedPagedState)
-from .model_runner import paged_forward
+from .model_runner import attention_impl, paged_forward
 from .scheduler import (BATCH, FAILED, FINISHED, PREFILL, PRIORITY_TIERS,
                         QUEUED, RUNNING, STANDARD, TIER_RANK, TIMEOUT,
                         Request, Scheduler)
@@ -58,7 +58,9 @@ PyTree = Any
 #: counters add one reading a step, taken on entry to ``step()``; the two
 #: ``step_inputs.*`` count host arrays handed to the device (one a device
 #: call) and lane rows rewritten (one an install or a freed lane: a decode
-#: step that changes no lane writes none).
+#: step that changes no lane writes none). The two ``paged.chunk_*`` add one
+#: reading a PREFILL call: the pages the call's attention walks (up to its
+#: last real token) and the table's width, which the gather reference reads.
 _COUNTERS = (
     "completed", "failed", "timeout", "tokens_generated", "prefill_tokens",
     "prefix_hit_tokens", "preempted",
@@ -67,6 +69,7 @@ _COUNTERS = (
     "admit_blocked.prefilling", "compiles",
     "kv.held_blocks_sum", "kv.blocks_reserved_sum", "kv.tokens_written_sum",
     "prefix.prompt_tokens", "paged.live_pages_sum", "paged.table_pages_sum",
+    "paged.chunk_live_pages_sum", "paged.chunk_table_pages_sum",
     "step_inputs.transfers_sum", "step_inputs.lane_rows_written_sum")
 #: a dropless MoE model's router load, from the [L, E] counts that ride the
 #: tokens' own fetch (``_count_experts``); a dense model has none of these
@@ -450,6 +453,7 @@ class ServingEngine:
         self._layout = StepLayout(self.nbk, self._key.size)
         self._lanes = _Lanes(self._layout, self.max_batch)
         self._calls = 0                    # device calls made so far
+        self._prefill_shapes: set = set()  # query rows of the prefill calls
         self._heartbeat = heartbeat
         self._watchdog = None
         self._lock = threading.Lock()
@@ -964,6 +968,11 @@ class ServingEngine:
         still be in flight when the next is built."""
         n = len(toks)
         Tb = -(-n // self.block_size) * self.block_size
+        self.rec.count("paged.chunk_live_pages_sum",
+                       -(-(q0 + n) // self.block_size))
+        self.rec.count("paged.chunk_table_pages_sum", self.nbk)
+        if Tb not in self._prefill_shapes:
+            self._note_prefill_path(Tb)
         buf = np.zeros((self._layout.prefill_words(Tb),), np.int32)
         ids, bt, first, ctx, last_idx, tk, temp, tp, _ = \
             self._layout.prefill(buf)
@@ -974,6 +983,23 @@ class ServingEngine:
         tk[0] = req.top_k or 0
         tp[0] = 1.0 if req.top_p is None else req.top_p
         return buf
+
+    def _note_prefill_path(self, Tb: int) -> None:
+        """Gauge ``paged.prefill_path``: which way the attention of the
+        prefill programs goes, ``{"kernel" | "reference[: why]": [query rows
+        of the programs that went it]}``, from the shapes alone, as the
+        dispatcher decides it when a program is traced."""
+        from ..ops.attention import paged_attention_path
+        cfg, pool = self.cfg, self.pools["k"]
+        path, why = paged_attention_path(
+            (1, cfg.num_heads, Tb, cfg.head_dim),
+            pool.shape[:2] + (pool.shape[2] // self.block_size,
+                              self.block_size, cfg.head_dim),
+            stacked=True, quant="k_scale" in self.pools,
+            impl=attention_impl(cfg), interpret=self.interpret)
+        self._prefill_shapes.add(Tb)
+        paths = self.rec.gauges.setdefault("paged.prefill_path", {})
+        paths.setdefault(f"{path}: {why}" if why else path, []).append(Tb)
 
     def _prefill_chunk(self, pf: _Prefilling, n: int) -> int:
         req, rec = pf.req, self.rec

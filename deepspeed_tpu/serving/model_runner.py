@@ -17,9 +17,11 @@ makes different: a token's position comes from its lane's ``q_start``;
 K/V land in the pool's blocks through the block table, in place, by
 unrolled dynamic-update-slices in the layout the paged kernel reads
 (:func:`_write_kv` says why not by a scatter); and attention reads ride
-``ops.attention.paged_attention``: the Pallas block-table kernel on TPU
-decode, the exact jnp gather reference elsewhere (the dense cache's f32
-score path and -1e30 masking).
+``ops.attention.paged_attention``: the Pallas block-table kernel on a TPU,
+for a decode token and for a prefill's T rows alike (the write comes
+first, so a chunk's own keys are read from the pool with the older ones),
+the exact jnp gather reference on the CPU and for shapes the kernel cannot
+tile (the dense cache's f32 score path and -1e30 masking).
 
 Inactive / padded lanes are harmless by construction: their block tables
 are all-NULL, their writes land in the null block, and their outputs are
@@ -96,6 +98,13 @@ def _write_kv(pool, li, new, ax, plan: _WritePlan):
     return pool
 
 
+def attention_impl(cfg: TransformerConfig) -> str:
+    """``ops.attention.paged_attention``'s ``impl`` for this model: one built
+    with ``attention_impl="reference"`` serves on the gather oracle (the
+    twin a kernel-routed serve is compared against)."""
+    return "reference" if cfg.attention_impl == "reference" else "auto"
+
+
 class PagedCache:
     """The serving loop's cache behind ``models.generation.decoder_forward``:
     ``init_pool``'s ``[L, nh, slots, hd]`` pools, reached through each lane's
@@ -126,10 +135,7 @@ class PagedCache:
         # so this matches generate()'s cache-capacity table exactly)
         self.rope_len = nbk * bs
         self.sm_scale, self.slopes = attention_constants(cfg)
-        # a model built with attention_impl="reference" serves on the gather
-        # oracle (the twin a kernel-routed serve is compared against)
-        self.impl = ("reference" if cfg.attention_impl == "reference"
-                     else "auto")
+        self.impl = attention_impl(cfg)
 
     def positions(self, T: int):
         return self.q_start[:, None] + jnp.arange(T)[None, :]      # [B, T]
@@ -197,9 +203,9 @@ class PagedCache:
         return new
 
     def attend(self, kv, li, q, k, v, window):
-        # attention through the block table (kernel on TPU decode, exact jnp
-        # gather elsewhere); the int8 tier passes the pool AS int8 with its
-        # scales — dequant happens in-kernel / post-gather, O(attended
+        # attention through the block table (the kernel on a TPU, the exact
+        # jnp gather elsewhere); the int8 tier passes the pool AS int8 with
+        # its scales — dequant happens in-kernel / post-gather, O(attended
         # blocks), never a pool-slice copy
         scale_kw = (dict(k_scale=kv["k_scale"], v_scale=kv["v_scale"])
                     if self.quantized else {})

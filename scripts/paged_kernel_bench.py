@@ -14,6 +14,15 @@ pages a copy group holds (the program derives it from the shapes:
 module (e.g. ``git show <commit>:deepspeed_tpu/ops/pallas/paged_attention.py``)
 on the same inputs. Parity against the jnp reference is checked on the
 device before anything is timed. Needs a TPU.
+
+Since PR 37 the kernel takes a prefill chunk's T > 1 query rows a lane:
+after the decode rows, one row a chunk shape of :data:`CHUNKS` at the two
+cells' widths (one lane, as the serving loop prefills): ms for the
+layers' calls, the bytes of the pages walked and the FLOPs of the causal
+scores and values, each over the chip's peak as a share of the time (the
+larger is the kernel's roofline share and ``bound`` says which), the heads
+a program and pages a group the shapes gave, and the jnp reference's time
+on the same inputs (what the prefill programs ran until then).
 """
 
 import argparse
@@ -28,6 +37,11 @@ import numpy as np
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 HBM_GBPS = 819.0     # TPU v5e, Google Cloud "TPU v5e"; benchmark/peaks.json
+BF16_TFLOPS = 197.0
+
+#: (query rows, first position): a prompt's first chunk at the smallest and
+#: the largest shape, and a late chunk of a long prompt
+CHUNKS = ((32, 0), (256, 0), (256, 768))
 
 # layers, stored heads, head_dim, block, pool blocks, lanes, table, live
 # lanes, mean context of a live lane (PERF.md section 5: ~58 k tokens over
@@ -39,6 +53,20 @@ SHAPES = {
     # no cell: heads of 64 take the grid kernel (a grid step a table entry)
     "llama-1.1b": (22, 32, 64, 32, 512, 16, 64, 14, 400, 0),
 }
+
+
+def best_ms(fn, reps, *operands):
+    """(the least ms a call over three rounds of ``reps`` back-to-back
+    calls, the last result); the first call, which compiles, is not timed."""
+    fn(*operands).block_until_ready()
+    best = []
+    for _ in range(3):
+        t = time.perf_counter()
+        for _ in range(reps):
+            out = fn(*operands)
+        out.block_until_ready()
+        best.append((time.perf_counter() - t) / reps)
+    return min(best) * 1e3, out
 
 
 def load(path):
@@ -119,16 +147,7 @@ def main():
             err = float(jnp.max(jnp.abs(
                 one(q, kp, vp, bt_d, ctx_d).astype(jnp.float32)
                 - ref.astype(jnp.float32))))
-            fn = jax.jit(step)
-            fn(q, kp, vp, bt_d, ctx_d).block_until_ready()
-            best = []
-            for _ in range(3):
-                t = time.perf_counter()
-                for _ in range(args.reps):
-                    out = fn(q, kp, vp, bt_d, ctx_d)
-                out.block_until_ready()
-                best.append((time.perf_counter() - t) / args.reps)
-            ms = min(best) * 1e3
+            ms, _ = best_ms(jax.jit(step), args.reps, q, kp, vp, bt_d, ctx_d)
             P = new._pages_per_group(
                 new._head_group(nh, bs, hd, 2), bs, hd, 2,
                 nbk) if mod is new and hd % 128 == 0 else None
@@ -142,6 +161,61 @@ def main():
                 "roofline_pct": 100 * need / (HBM_GBPS * 1e9) / (ms / 1e3),
                 "max_abs_err_vs_reference": err}), flush=True)
         new._pages_per_group = derive
+        if hd % 128 == 0 and not args.old:
+            chunk_rows(new, name, dev, args, L, nh, hd, bs, nb, nbk, window,
+                       kp, vp)
+
+
+def chunk_rows(mod, name, dev, args, L, nh, hd, bs, nb, nbk, window, kp, vp):
+    """One line a chunk shape: the kernel at T > 1 against the reference."""
+    import jax
+    import jax.numpy as jnp
+    rng = np.random.default_rng(args.seed + 1)
+    for T, q0 in CHUNKS:
+        ctx = min(q0 + T, nbk * bs)
+        pages = -(-ctx // bs)
+        bt = np.zeros((1, nbk), np.int32)
+        bt[0, :pages] = rng.permutation(nb - 1)[:pages] + 1
+        q = jax.random.normal(jax.random.PRNGKey(args.seed + T),
+                              (1, nh, T, hd), jnp.bfloat16)
+        bt_d = jnp.asarray(bt)
+        ctx_d, q0_d = jnp.asarray([ctx], jnp.int32), jnp.asarray([q0],
+                                                                 jnp.int32)
+
+        def layers(fn):
+            def step(q, kp, vp):
+                def layer(acc, li):
+                    o = fn(q, kp, vp, bt_d, ctx_d, layer_idx=li,
+                           window=window, q_start=q0_d)
+                    return acc + o.astype(jnp.float32), None
+                return jax.lax.scan(
+                    layer, jnp.zeros(q.shape, jnp.float32), jnp.arange(L))[0]
+            return jax.jit(step)
+
+        ms, out = best_ms(layers(mod.paged_attention), args.reps, q, kp, vp)
+        ref_ms, ref = best_ms(layers(mod.paged_attention_reference),
+                              args.reps, q, kp, vp)
+        n = ctx - q0                                     # the real rows
+        err = float(jnp.max(jnp.abs(out[:, :, :n] - ref[:, :, :n]))) / L
+        need = pages * L * nh * bs * hd * 2 * 2
+        # a real row at position p sees p + 1 keys: QK^T and PV, 2 FLOPs each
+        keys = sum(min(q0 + r + 1, window or ctx) for r in range(n))
+        flops = 4 * keys * hd * nh * L
+        mem, mxu = need / (HBM_GBPS * 1e9), flops / (BF16_TFLOPS * 1e12)
+        hg = mod._head_group(nh, bs, hd, 2, T)
+        print(json.dumps({
+            "shape": name, "setting": f"chunk T={T} q0={q0}",
+            "heads_per_program": hg,
+            "pages_per_group": mod._pages_per_group(hg, bs, hd, 2, nbk,
+                                                    False, T),
+            "device_kind": dev.device_kind, "ms_per_step": ms,
+            "us_per_layer": 1e3 * ms / L, "reference_ms_per_step": ref_ms,
+            "pages_walked_a_layer": pages, "needed_gb": need / 1e9,
+            "needed_gflop": flops / 1e9,
+            "bound": "memory" if mem >= mxu else "compute",
+            "memory_roofline_pct": 100 * mem / (ms / 1e3),
+            "compute_roofline_pct": 100 * mxu / (ms / 1e3),
+            "max_abs_err_of_the_layers_mean_vs_reference": err}), flush=True)
 
 
 if __name__ == "__main__":

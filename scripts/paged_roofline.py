@@ -20,7 +20,18 @@ V, a layer; the kernel is bound by reading them (eight query rows a head: the
 FLOPs are nothing). The last line is one JSON object: ``paged_kernel_
 roofline_pct`` with the kernel's and the least milliseconds a step,
 ``paged_live_page_share`` over the traced steps and over the untraced window
-after them. A program without the counters (the parent of the PR that
+after them.
+
+Since PR 37 a prefill chunk's attention is the same kernel at T > 1 query
+rows (its custom call makes ``[1, groups, heads, T, head_dim]``, a decode
+call ``[lanes, groups, heads, 8, head_dim]``: the trace tells them apart by
+the rows): ``paged_chunk_kernel_roofline_pct`` holds the chunk calls' time in
+the steps that carry one against the bytes of the pages they walk
+(``paged.chunk_live_pages_sum``, counted in ``engine.py:_prefill_inputs``),
+beside the decode calls' share, and ``paged_chunk_live_page_share`` those
+pages over the table's width, which the gather reference read until then.
+The line before it lists the device's operations by the kind of step they
+started in (:func:`ops_by_step_kind`). A program without the counters (the parent of the PR that
 brought them) prints an object with no metric and exits 0. Lives outside
 ``benchmark/`` until a ``benchmark`` PR folds it in (ROADMAP B1).
 """
@@ -51,20 +62,45 @@ def paged_metrics(cell, obs):
     # the pool stores every query head (GQA is expanded before the write)
     page = (serving["block_size"] * dims["heads"] * dims["head_dim"] * 2
             * ITEMSIZE[cell.system["dtype"]] * dims["layers"])
-    kernel_s = moe_roofline.kernel_seconds_by_step(obs["program"]["trace"],
-                                                   KERNEL)
+    pt = obs["program"]["trace"]
+    # a chunk call's result has its query rows where a decode call's has
+    # the eight a token is broadcast to
+    chunk_calls = _chunk_calls(pt)
+    all_s = moe_roofline.kernel_seconds_by_step(pt, KERNEL)
+    chunk_s = moe_roofline.kernel_seconds_by_step(chunk_calls, KERNEL)
     live = table = secs = n = 0
+    c_live = c_table = c_secs = c_n = 0
     for s in spans.steps_of(obs["program"]["ring"], "serve"):
         d = s["entry"][4].get("d", {})
-        if kernel_s.get(s["n"]) and d.get("paged.live_pages_sum"):
+        decode_s = all_s.get(s["n"], 0.0) - chunk_s.get(s["n"], 0.0)
+        if decode_s and d.get("paged.live_pages_sum"):
             live += d["paged.live_pages_sum"]
             table += d["paged.table_pages_sum"]
-            secs += kernel_s[s["n"]]
+            secs += decode_s
             n += 1
+        if chunk_s.get(s["n"]) and d.get("paged.chunk_live_pages_sum"):
+            c_live += d["paged.chunk_live_pages_sum"]
+            c_table += d["paged.chunk_table_pages_sum"]
+            c_secs += chunk_s[s["n"]]
+            c_n += 1
     if not n or peaks is None:
         return {}
     least = live * page / (peaks["hbm_gb_per_s"] * 1e9)
+    chunk = {}
+    if c_n:
+        c_least = c_live * page / (peaks["hbm_gb_per_s"] * 1e9)
+        chunk = {
+            "paged_chunk_kernel_roofline_pct": {
+                "value": 100.0 * c_least / c_secs, "unit": "%",
+                "bound": "memory", "steps": c_n,
+                "kernel_ms_per_chunk_step": 1e3 * c_secs / c_n,
+                "least_ms_per_chunk_step": 1e3 * c_least / c_n,
+                "needed_gb_per_chunk_step": c_live * page / c_n / 1e9},
+            "paged_chunk_live_page_share": {
+                "value": c_live / c_table, "unit": "share", "steps": c_n,
+                "live_pages_per_call": c_live / c_n}}
     metrics = {
+        **chunk,
         "paged_kernel_roofline_pct": {
             "value": 100.0 * least / secs, "unit": "%", "bound": "memory",
             "steps": n, "kernel_ms_per_step": 1e3 * secs / n,
@@ -82,6 +118,72 @@ def paged_metrics(cell, obs):
     return metrics
 
 
+def _chunk_calls(pt):
+    """``pt`` with only the ``paged_attention`` calls of a prefill chunk on
+    its device lines: the calls whose result has other than the eight query
+    rows a decode token is broadcast to."""
+    import dataclasses
+
+    def rows(result):
+        dims = result[result.find("[") + 1:result.find("]")].split(",")
+        return int(dims[-2]) if len(dims) >= 2 and dims[-2].isdigit() else 8
+
+    devices = {name: [o for o in ops if KERNEL in o[0] and rows(o[1]) != 8]
+               for name, ops in pt.trace.devices.items()}
+    return dataclasses.replace(pt, trace=dataclasses.replace(
+        pt.trace, devices=devices, cache={}))
+
+
+def ops_by_step_kind(obs, top: int = 40):
+    """``{"decode" | "chunk": {"steps": n, "device_ms_per_step": ...,
+    "ops": [[label, self ms a step, instances a step], ...]}}``: the first
+    device's operations of the traced window by the ``serve.step`` they
+    started in, a step that ran a ``serve.prefill`` being a chunk step. The
+    label is the instruction's name less its number and the shape it
+    makes, so a layer loop's instances add up."""
+    import re
+    from collections import defaultdict
+    from benchmark import reduce
+    pt = obs["program"]["trace"]
+    step_name, attr = spans.STEP["serve"]
+    steps = sorted((s[1], s[1] + s[2], int(s[3][attr]))
+                   for s in spans.window_spans(pt) if s[0] == step_name)
+    kinds = {s["n"]: ("chunk" if any(e[0] == "serve.prefill"
+                                     for e in s["inside"]) else "decode")
+             for s in spans.steps_of(obs["program"]["ring"], "serve")}
+    if not steps or not pt.trace.devices:
+        return {}
+    ops = pt.trace.devices[sorted(pt.trace.devices)[0]]
+    selfs = reduce.self_times(ops)
+    rows = sorted((o[2], f"{re.sub(r'[.][0-9]+', '', o[0])} {o[1]}", s[2])
+                  for o, s in zip(ops, selfs))
+    total = {k: defaultdict(lambda: [0.0, 0]) for k in ("decode", "chunk")}
+    seen = {k: set() for k in total}
+    i = 0
+    for start, label, ns in rows:
+        while i < len(steps) and steps[i][1] < start:
+            i += 1
+        if i < len(steps) and steps[i][0] <= start \
+                and steps[i][2] in kinds:
+            kind = kinds[steps[i][2]]
+            seen[kind].add(steps[i][2])
+            acc = total[kind][label]
+            acc[0] += ns
+            acc[1] += 1
+    out = {}
+    for kind, table in total.items():
+        n = len(seen[kind])
+        if n:
+            ranked = sorted(table.items(), key=lambda kv: -kv[1][0])
+            out[kind] = {
+                "steps": n,
+                "device_ms_per_step": sum(v[0] for v in table.values())
+                / n / 1e6,
+                "ops": [[label, ns / n / 1e6, cnt / n]
+                        for label, (ns, cnt) in ranked[:top]]}
+    return out
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -96,6 +198,9 @@ def main(argv=None) -> int:
         trace_dir=harness.TRACE_DIR)
     obs = spans.program_obs(cell, out, harness.TRACE_DIR)
     print(spans.finish(cell, out, obs), flush=True)
+    print(json.dumps({"workload": cell.name,
+                      "ops_by_step_kind": ops_by_step_kind(obs)}),
+          flush=True)
     print(json.dumps({"workload": cell.name,
                       "metrics": paged_metrics(cell, obs),
                       "device": out["devices"][0].device_kind}), flush=True)

@@ -162,6 +162,49 @@ def test_paged_attention_stacked_pool_compiles(chip, cell, quant):
     assert not big or HD % 128, big
 
 
+@pytest.mark.parametrize("T", range(32, 257, 32))
+@pytest.mark.parametrize("cell", ["serve-olmoe-1b-7b-l8-gen",
+                                  "serve-mistral-7b-l16-chat"])
+def test_paged_attention_prefill_chunk_compiles(chip, cell, T):
+    """The same kernel under a prefill chunk's T query rows a lane, at both
+    serving cells' widths and every chunk shape their loops send (the
+    multiples of the block up to ``prefill_chunk_tokens``, each padded to the
+    one tile of 256 rows the kernel is traced at): heads a program and
+    pages a group shrink with the rows so that the accumulator, the running
+    rows and one group's scores fit the chip's scoped VMEM beside the page
+    buffers, which only this compiler can say. One kernel, one lane, no
+    loop round it and nothing as large as a layer of the pool."""
+    from deepspeed_tpu.ops.pallas.paged_attention import (
+        _head_group, _pages_per_group, _query_rows, paged_attention)
+    L, NH, HD, BS, NB, _, NBK = _PAGED_SHAPES[cell]
+    q = chip((1, NH, T, HD), jnp.bfloat16)
+    pool = chip((L, NH, NB, BS, HD), jnp.bfloat16)
+    bt, lens, li = (chip((1, NBK), jnp.int32), chip((1,), jnp.int32),
+                    chip((), jnp.int32))
+    fn = lambda q, k, v, bt, lens, q0, li: paged_attention(
+        q, k, v, bt, lens, layer_idx=li, window=li, q_start=q0)
+    args = (q, pool, pool, bt, lens, lens, li)
+    hg, rows = _head_group(NH, BS, HD, 2, T), _query_rows(T)
+    assert rows == 256 and hg * rows <= 1024 and NH % hg == 0
+    assert _pages_per_group(hg, BS, HD, 2, NBK, False, T) * BS * hg * rows \
+        * 4 <= 1 << 20
+    # the call is a jitted one, shared by the chunk shapes: one level down
+    calls = [e for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns
+             if e.primitive.name == "jit"]
+    grids = [e.params["grid_mapping"].grid
+             for c in calls for e in c.params["jaxpr"].jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    assert grids == [(1, NH // hg)], grids
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    names = _kernel_scopes(text)
+    assert len(names) == 1 and "paged_attention" in names[0], names
+    results = _results(text)
+    assert not [r for r in results if r[1] == "while"], results
+    big = [r for r in results if r[3] >= NH * NB * BS * HD and r[1] not in (
+        "parameter", "get-tuple-element", "tuple", "bitcast")]
+    assert not big, big
+
+
 @pytest.mark.parametrize("K,N", [(2048, 6144), (2048, 8192), (8192, 2048)],
                          ids=["qkv", "mlp_fc", "mlp_proj"])
 def test_quant_matmul_compiles(chip, monkeypatch, K, N):
@@ -243,9 +286,10 @@ def test_serving_step_updates_the_pool_in_place(chip, monkeypatch, program,
     """The serving loop's two programs (``serving.engine.step_programs``, as
     the engine jits them: pools donated) at the benchmark cell's widths,
     mistral-7b-l16 with 32 lanes over a 384 x 32 pool: no instruction makes
-    a whole K/V pool by ``copy``, ``transpose`` or ``scatter`` (the decode
-    step: nor a whole layer of one), and the program's temporaries are a
-    fraction of one pool. Before PR 25 the K/V scatter left the pool in a
+    a whole K/V pool, or a whole layer of one, by ``copy``, ``transpose``,
+    ``scatter`` or ``dynamic-slice`` (a prefill chunk sliced both pools' layer
+    out for the gather reference until PR 37: 5.7 ms of a chunk step), and
+    the program's temporaries are a fraction of one pool. Before PR 25 the K/V scatter left the pool in a
     layout the paged kernel does not read: six whole-pool copies a decode
     step, 2 x 16 of them inside the layer loop, ``temp_size`` of two pools
     (PERF.md, PR 25)."""
@@ -280,10 +324,14 @@ def test_serving_step_updates_the_pool_in_place(chip, monkeypatch, program,
     text = compiled.as_text()
 
     layer = NH * NB * BS * HD
-    at_least = layer if program == "decode" else L * layer
     moved = [r for r in _results(text)
-             if r[1] in ("copy", "transpose", "scatter") and r[3] >= at_least]
+             if r[1] in ("copy", "transpose", "scatter") and r[3] >= layer]
     assert not moved, moved
+    # nor is a layer of a pool sliced out of it: since PR 37 a chunk's
+    # attention reads the pool through the block table, as a decode step's
+    sliced = [r for r in _results(text)
+              if r[1] == "dynamic-slice" and r[3] == layer]
+    assert not sliced, sliced
     # the int8 tier's scales reach the kernel a block a row of whole
     # 128-lane tiles, which is not the layout they have at the jit boundary:
     # they change layout there (four small conversions a step), never in the
@@ -300,8 +348,7 @@ def test_serving_step_updates_the_pool_in_place(chip, monkeypatch, program,
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < share * pool_bytes, (temp, pool_bytes)
     names = _kernel_scopes(text)
-    if program == "decode":
-        assert len(names) == 1 and "paged_attention" in names[0], names
+    assert len(names) == 1 and "paged_attention" in names[0], names
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill256"])
@@ -352,7 +399,7 @@ def test_olmoe_serving_step_is_dropless_and_in_place(chip, monkeypatch,
     grouped = [k for k in kernels if "jit(gmm)" in k]
     assert len(grouped) == 3, kernels
     assert all("block.mlp/experts" in k for k in grouped), grouped
-    assert len(kernels) == 3 + (program == "decode"), kernels   # + paged
+    assert len(kernels) == 4, kernels                            # + paged
     rows = (B if program == "decode" else 256) * K
     made = [r for r in _results(text) if r[1] not in (
         "parameter", "get-tuple-element", "while", "tuple", "bitcast")]
@@ -369,21 +416,16 @@ def test_olmoe_serving_step_is_dropless_and_in_place(chip, monkeypatch,
            if r[3] >= rows * E * M and r[3] != 50304 * NH * HD]   # the head
     layer = NH * NB * BS * HD
     assert not [r for r in big if r[3] < layer], big
-    moved = [r for r in big if r[1] in ("copy", "transpose", "scatter")
-             and r[3] >= (layer if program == "decode" else L * layer)]
-    if program == "decode":
-        assert not moved, moved
-    elif moved:
-        # FOUND by this test (PR 26, kept by PR 27), not repaired: at these sizes (MHA, a
-        # 2048-block pool, 128 blocks a sequence) the chip's compiler carries
-        # the pool through the PREFILL loop blocks-major, {4,3,1,2,0}, the
-        # layout the reference gather of ``paged_attention_reference`` likes,
-        # and copies both pools whole on the way in and out: four copies of
-        # 2.1 GB a chunk, ~21 ms of a chunk step. At mistral's sizes it does
-        # not (the test above). ROADMAP S-queue; when it is repaired this
-        # xfail turns into the assertion above.
-        pytest.xfail(f"prefill at OLMoE's sizes copies the pool whole: "
-                     f"{moved}")
+    # the pool keeps the one layout it has at the jit boundary in BOTH
+    # programs. Until PR 37 the prefill's gather reference had the chip's
+    # compiler carry it through the layer loop blocks-major, {4,3,1,2,0},
+    # and copy both pools whole on the way in and out (found here by PR 26
+    # and kept as an xfail: four copies of 2.1 GB and sixteen layer slices,
+    # 39 ms of a 61 ms chunk step, PERF.md section 6, PR 37)
+    moved = [r for r in big if r[3] >= layer and (
+        r[1] in ("copy", "transpose", "scatter")
+        or r[1] == "dynamic-slice" and r[3] == layer)]
+    assert not moved, moved
 
 
 @pytest.mark.parametrize("layout,vocab", [("dp4", 50257), ("dp2_tp2", 50304)])

@@ -122,7 +122,9 @@ def test_paged_stacked_layer_pool():
 
 def test_paged_reference_matches_kernel_and_serves_prefill():
     """The jnp reference (the CPU/serving fallback) agrees with the numpy
-    oracle for T=1 AND for the prefill regime (T>1) the kernel refuses."""
+    oracle for T=1 AND for the prefill regime (T>1), and so does the kernel
+    since PR 37: T query rows a lane ending at lens[b], an odd T padded to
+    whole sublanes inside the call."""
     q, kp, vp, bt, lens = _data(seed=4)
     ref = paged_attention_reference(
         jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(bt),
@@ -134,28 +136,56 @@ def test_paged_reference_matches_kernel_and_serves_prefill():
     B, nh, _, hd = q.shape
     lens5 = np.maximum(lens, 5)
     q5 = rng.standard_normal((B, nh, 5, hd)).astype(np.float32)
-    ref5 = paged_attention_reference(
-        jnp.asarray(q5), jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(bt),
-        jnp.asarray(lens5))
+    args = (jnp.asarray(q5), jnp.asarray(kp), jnp.asarray(vp),
+            jnp.asarray(bt), jnp.asarray(lens5))
+    want = _oracle(q5, kp, vp, bt, lens5)
+    np.testing.assert_allclose(np.asarray(paged_attention_reference(*args)),
+                               want, rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(
-        np.asarray(ref5), _oracle(q5, kp, vp, bt, lens5), rtol=2e-5,
+        np.asarray(paged_attention(*args, interpret=True)), want, rtol=2e-5,
         atol=2e-5)
-    with pytest.raises(ValueError, match="1 token"):
-        from deepspeed_tpu.ops.pallas.paged_attention import (
-            paged_attention as kern)
-        kern(jnp.asarray(q5), jnp.asarray(kp), jnp.asarray(vp),
-             jnp.asarray(bt), jnp.asarray(lens5), interpret=True)
 
 
-def test_router_dispatch():
-    """ops.attention.paged_attention: kernel for T=1 under interpret,
-    reference for prefill — same numerics either way."""
-    from deepspeed_tpu.ops.attention import paged_attention as router
+def test_router_dispatch(monkeypatch):
+    """ops.attention.paged_attention: the kernel for a decode token AND a
+    prefill chunk under interpret (one pallas_call either way, the same
+    numerics as the reference), the reference where it is asked for or no
+    TPU is there; on a TPU ``untileable`` alone routes, from the shapes,
+    and says why once."""
+    from deepspeed_tpu.ops import attention as ops
+    from deepspeed_tpu.ops.pallas.paged_attention import untileable
     q, kp, vp, bt, lens = _data(seed=5)
-    out = router(jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-                 jnp.asarray(bt), jnp.asarray(lens), interpret=True)
-    np.testing.assert_allclose(np.asarray(out), _oracle(q, kp, vp, bt, lens),
-                               rtol=2e-5, atol=2e-5)
+    rng = np.random.default_rng(5)
+    q4 = rng.standard_normal(q.shape[:2] + (4, q.shape[3])).astype(np.float32)
+    lens4 = np.maximum(lens, 4)
+    for qq, ll in ((q, lens), (q4, lens4)):
+        args = (jnp.asarray(qq), jnp.asarray(kp), jnp.asarray(vp),
+                jnp.asarray(bt), jnp.asarray(ll))
+        want = _oracle(qq, kp, vp, bt, ll)
+        for kw, kernels in ((dict(interpret=True), 1), ({}, 0),
+                            (dict(interpret=True, impl="reference"), 0)):
+            fn = lambda *a: ops.paged_attention(*a, **kw)
+            assert str(jax.make_jaxpr(fn)(*args)).count(
+                "pallas_call") == kernels, kw
+            np.testing.assert_allclose(np.asarray(fn(*args)), want,
+                                       rtol=2e-5, atol=2e-5)
+    # the routing as a TPU sees it: shapes in, a path and a reason out
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    path = lambda T, hd, bs=32, quant=False: ops.paged_attention_path(
+        (1, 4, T, hd), (2, 4, 16, bs, hd), stacked=True, quant=quant)
+    assert path(1, 128) == path(256, 128) == path(1, 64) == ("kernel", None)
+    assert path(256, 128, quant=True) == ("kernel", None)
+    for shape, why in (((256, 64), "128 lanes"), ((2048, 128), "chunk"),
+                       ((1, 128, 12), "block_size 12"),
+                       ((32, 128, 16, True), "int8")):
+        got = path(*shape)
+        assert got[0] == "reference" and why in got[1], got
+    assert ops.paged_attention_path(
+        (1, 4, 32, 128), (2, 4, 16, 32, 128), stacked=True, quant=False,
+        impl="reference") == ("reference", None)
+    # under the interpreter nothing is refused (no tile to fit)
+    assert untileable((1, 4, 5, 64), (4, 16, 12, 64), stacked=False,
+                      quant=False, interpret=True) is None
 
 
 # ---------------------------------------------------------------------------
@@ -366,23 +396,141 @@ def test_paged_loop_alternates_slots_over_many_groups(monkeypatch, regime,
     np.testing.assert_allclose(np.asarray(out), want, rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("regime", ["plain", "int8"])
+@pytest.mark.parametrize("regime", ["plain", "int8", "chunk", "chunk-int8"])
 def test_paged_loop_under_the_tpu_interpreter(regime):
     """The same kernel under the TPU interpreter, which keeps semaphores
     and copies apart from the compute as the chip does: every buffer it
     allocates starts as NaN (a page the loop skipped must not reach the
-    output through a zero probability) and no copy races a read."""
+    output through a zero probability) and no copy races a read. A chunk's
+    programs loop over a group's pages where a decode program has them
+    unrolled, and their padded rows must come out finite too."""
     from jax._src.pallas.mosaic.interpret import (
         interpret_pallas_call as tpu_interpreter)
     from jax.experimental.pallas import tpu as pltpu
-    args, want = _edge_case(regime, [0, 35, 48, 1], seed=13)
-    kw = {}
-    if regime == "int8":
-        kw = dict(k_scale=args[5], v_scale=args[6])
-    out = paged_attention(*args[:5], interpret=pltpu.InterpretParams(
-        detect_races=True, uninitialized_memory="nan"), **kw)
-    np.testing.assert_allclose(np.asarray(out), want, rtol=2e-5, atol=2e-5)
+    quant = regime.endswith("int8")
+    args, want = _edge_case("int8" if quant else "plain", [0, 35, 48, 1],
+                            seed=13)
+    kw = dict(k_scale=args[5], v_scale=args[6]) if quant else {}
+    args = list(args[:5])
+    if regime.startswith("chunk"):
+        # 16 rows a lane: an idle lane, a chunk in mid-block over two
+        # groups, one ending with the table, one of a single real row
+        q0 = np.asarray([0, 24, 32, 0], np.int32)
+        args[0] = jnp.asarray(np.random.default_rng(14).standard_normal(
+            (_EB, _ENH, 16, _EHD)).astype(np.float32))
+        kw["q_start"] = jnp.asarray(q0)
+        want = np.asarray(paged_attention_reference(*args, **kw))
+    out = np.asarray(paged_attention(*args, interpret=pltpu.InterpretParams(
+        detect_races=True, uninitialized_memory="nan"), **kw))
+    assert np.isfinite(out).all()
+    if regime.startswith("chunk"):
+        for b, n in enumerate(np.asarray(args[4]) - q0):
+            np.testing.assert_allclose(out[b, :, :min(n, 16)],
+                                       want[b, :, :min(n, 16)], rtol=2e-5,
+                                       atol=2e-5)
+    else:
+        np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-5)
     assert not tpu_interpreter.races.races_found
+
+
+# ---------------------------------------------------------------------------
+# A prefill chunk (PR 37): T > 1 query rows a lane at positions q_start + row
+# against the pool the chunk's own keys are in already. Same small shape as
+# the loop's edges (tables of 6 pages of 8 slots, heads of 128), P = 4 pages
+# a group; parity with the jnp reference on every REAL row (a row at or past
+# ctx is bucket padding: finite, and read by nobody).
+# ---------------------------------------------------------------------------
+
+_CHUNKS = {
+    # name: (T, q_start a lane, ctx a lane, regime)
+    "from_zero": (16, [0], [16], "plain"),
+    "block_aligned_start": (16, [16], [32], "plain"),
+    "mid_block_start": (16, [13], [29], "plain"),
+    "padded_past_ctx": (16, [8], [13], "plain"),
+    "ends_in_mid_block": (24, [16], [35], "plain"),
+    "one_real_row": (8, [40], [41], "plain"),
+    "odd_rows": (10, [3], [13], "plain"),
+    "window_drops_leading_pages": (16, [30], [46], "window"),
+    "window_inside_the_chunk": (24, [0], [24], "window"),
+    "alibi": (16, [13], [27], "alibi"),
+    "softcap": (16, [13], [29], "softcap"),
+    "stacked_traced_layer": (16, [21], [37], "stacked"),
+    "int8": (16, [13], [29], "int8"),
+    "lanes_of_their_own": (16, [13, 0, 32, 5], [29, 11, 48, 5], "plain"),
+    "lanes_window_stacked": (16, [30, 0, 7, 20], [44, 16, 23, 20],
+                             "window"),
+    "full_table": (16, [32], [48], "plain"),
+}
+
+
+@pytest.mark.parametrize("case", list(_CHUNKS))
+def test_paged_prefill_chunk_matches_reference(case):
+    T, q0, ctx, regime = _CHUNKS[case]
+    r = _REGIMES[regime]
+    B = len(q0)
+    q1, kp, vp, bt, _ = _data(B=B, nh=_ENH, hd=_EHD, bs=_EBS,
+                              num_blocks=_ENB, nbk=_ENBK, seed=21)
+    q = np.random.default_rng(22).standard_normal(
+        (B, _ENH, T, _EHD)).astype(np.float32)
+    kw = {}
+    if "window" in r:
+        kw["window"] = jnp.asarray(r["window"], jnp.int32)
+    if "slopes" in r:
+        kw["alibi_slopes"] = jnp.asarray(r["slopes"])
+    if "softcap" in r:
+        kw["softcap"] = r["softcap"]
+    if r.get("quant"):
+        kp, kw["k_scale"], vp, kw["v_scale"], _, _ = _int8_pools(kp, vp)
+    if r.get("stacked") or case == "lanes_window_stacked":
+        kp, vp = np.stack([kp, kp * 2.0]), np.stack([vp, vp * 0.5])
+        kw["layer_idx"] = 1
+    args = (jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+            jnp.asarray(bt), jnp.asarray(ctx, jnp.int32))
+    kw["q_start"] = jnp.asarray(q0, jnp.int32)
+
+    @jax.jit
+    def both(*args):
+        traced = dict(kw)
+        if "layer_idx" in kw:                       # traced, as in the scan
+            traced["layer_idx"] = args[-1]
+            args = args[:-1]
+        return (paged_attention(*args, interpret=True, **traced),
+                paged_attention_reference(*args, **traced))
+
+    out, ref = both(*args, *([jnp.asarray(1, jnp.int32)]
+                             if "layer_idx" in kw else []))
+    out, ref = np.asarray(out), np.asarray(ref)
+    assert np.isfinite(out).all()
+    assert max(c - s for c, s in zip(ctx, q0)) > 0
+    for b in range(B):
+        n = min(T, ctx[b] - q0[b])       # 0: a lane with no real row
+        np.testing.assert_allclose(out[b, :, :n], ref[b, :, :n], rtol=2e-5,
+                                   atol=2e-5)
+
+
+@pytest.mark.parametrize("T,nh,want", [
+    (1, 32, (32, 4)), (32, 32, (4, 8)), (96, 32, (4, 8)),
+    (256, 32, (4, 8)), (256, 16, (4, 8)), (257, 32, (2, 8)),
+    (1, 16, (16, 8)), (1024, 16, (1, 8)), (5, 4, (4, 8))],
+    ids=lambda v: str(v))
+def test_heads_and_pages_shrink_with_the_chunk(T, nh, want):
+    """Heads a program and pages a group at the serving cells' widths
+    (block 32, heads of 128, bf16, a table of 40): what a decode token gets
+    is what it got before the kernel took chunks; a chunk's rows are padded
+    to whole tiles of 256 (every chunk shape of a serving loop is ONE
+    shape to the kernel) and keep a program's accumulator and one group's
+    scores inside their budgets."""
+    from deepspeed_tpu.ops.pallas.paged_attention import (
+        _CHUNK_ROWS, _SCORE_BUDGET, _head_group, _pages_per_group,
+        _query_rows)
+    hg = _head_group(nh, 32, 128, 2, T)
+    P = _pages_per_group(hg, 32, 128, 2, 40, False, T)
+    assert (hg, P) == want
+    assert (hg, P) == (_head_group(nh, 32, 128, 2),
+                       _pages_per_group(hg, 32, 128, 2, 40)) or T > 1
+    if T > 1:
+        assert hg * _query_rows(T) <= _CHUNK_ROWS or hg == 1
+        assert hg * _query_rows(T) * P * 32 * 4 <= _SCORE_BUDGET or P == 1
 
 
 @pytest.mark.parametrize("shape,want", [
@@ -460,7 +608,8 @@ def _gqa_decode(cfg, params, ids, bt, bs, nbk, interpret):
     B, T = ids.shape
     pools = init_pool(cfg, 8, bs, dtype=jnp.int8)
     zeros = jnp.zeros((B,), jnp.int32)
-    # prefill (reference attention path for T>1) populates the int8 pool
+    # prefill (T > 1: the same routing as the decode step) populates the
+    # int8 pool
     _, pools = paged_forward(cfg, params, jnp.asarray(ids), pools,
                              jnp.asarray(bt), zeros,
                              jnp.full((B,), T, jnp.int32), bs,

@@ -613,6 +613,46 @@ def paged_kernel_traces(monkeypatch):
     return traces
 
 
+@pytest.mark.parametrize("mode", ["chunked", "prefix_hit", "whole"])
+def test_prefill_through_the_kernel_is_token_exact(tiny, monkeypatch, mode):
+    """A prefill's attention rides the paged kernel (PR 37; interpreted
+    here), its chunk's own keys read from the pool it has just written:
+    greedy streams stay token-exact with sequential ``generate()`` under
+    chunked prefill (chunks of 10 pad to one block and start and end in
+    mid-block), after a prefix-cache hit (the suffix's first query sits at
+    the hit's end) and under whole prefill; the kernel is traced at the
+    prefill's rows, and the engine says so."""
+    import deepspeed_tpu.ops.pallas.paged_attention as paged_mod
+    cfg, params = tiny
+    cfg = dataclasses.replace(cfg, attention_impl="auto")
+    rows, real = set(), paged_mod.paged_attention
+
+    def spy(q, *args, **kw):
+        rows.add(q.shape[2])
+        return real(q, *args, **kw)
+
+    monkeypatch.setattr(paged_mod, "paged_attention", spy)
+    chunk = 0 if mode == "whole" else 10 if mode == "chunked" else 32
+    eng = ServingEngine(cfg, params, interpret=True, serving=dict(
+        SERVE_CFG, prefill_chunk_tokens=chunk))
+    rng = np.random.default_rng(41)
+    shared = list(rng.integers(1, 64, size=37))
+    prompts = [shared + list(rng.integers(1, 64, size=n)) for n in (9, 4)] \
+        if mode == "prefix_hit" else \
+        [list(rng.integers(1, 64, size=n)) for n in (37, 23)]
+    outs = [eng.generate_batch([p], max_new_tokens=5)[0] for p in prompts]
+    for p, o in zip(prompts, outs):
+        assert o == _oracle_tokens(cfg, params, p, 5)
+    if mode == "prefix_hit":
+        assert eng.stats["prefix_hit_tokens"] == 32     # two whole blocks
+    want = {"chunked": {1, 16}, "prefix_hit": {1, 16, 32},
+            "whole": {1, 32, 48}}[mode]
+    assert rows == want, rows
+    paths = eng.telemetry()["gauges"]["paged.prefill_path"]
+    assert {k: sorted(v) for k, v in paths.items()} == \
+        {"kernel": sorted(want - {1})}
+
+
 def test_int8_kv_pool_parity_jnp_and_kernel(tiny, paged_kernel_traces):
     """The quantized pool tier (serving.kv_cache_dtype='int8'):
     quantize-on-write, dequantize IN-kernel (round 17 — the round-12

@@ -233,6 +233,46 @@ def test_inside_and_outside_count_the_same_thing_step_for_step(served):
         srv.stats["prefix.evicted_entries"]
 
 
+def test_prefill_calls_count_the_pages_they_walk_and_name_their_path(
+        served, tiny_lm):
+    """``paged.chunk_live_pages_sum`` / ``paged.chunk_table_pages_sum`` (PR
+    37) grow by one reading a PREFILL call, the pages up to the call's last
+    real token and the table's width, and not with decode steps; the gauge
+    ``paged.prefill_path`` says which way the prefill programs' attention
+    went, by the query rows of each program."""
+    srv, reqs, outside = served
+    steps = [e for e in srv.rec.ring if e[0] == "serve.step"]
+    calls = [e for e in srv.rec.ring if e[0] == "serve.prefill.dispatch"]
+    gains = [e[4]["d"].get("paged.chunk_table_pages_sum", 0) for e in steps]
+    assert sum(gains) == srv.stats["paged.chunk_table_pages_sum"] == \
+        len(calls) * srv.nbk
+    assert 0 < gains.count(0) < len(steps)          # decode-only steps: none
+    assert all(g in (0, srv.nbk) for g in gains)    # one chunk a step
+    assert len(calls) <= srv.stats["paged.chunk_live_pages_sum"] <= \
+        srv.stats["paged.chunk_table_pages_sum"]
+    for e in steps:
+        d = e[4]["d"]
+        assert bool(d.get("paged.chunk_live_pages_sum")) == \
+            bool(d.get("paged.chunk_table_pages_sum"))
+    # interpret=True: every chunk shape rode the kernel
+    assert srv.telemetry()["gauges"]["paged.prefill_path"] == \
+        {"kernel": [32, 16]}
+    # exactly: 40 tokens in chunks of 32 over blocks of 16 are the calls
+    # (q0 0, 32 tokens: 2 pages) and (q0 32, 8 tokens: 3 pages); on the CPU
+    # without the interpreter the reference serves, and no reason is owed
+    cfg, params = tiny_lm
+    one = ServingEngine(cfg, params, serving={
+        "block_size": 16, "pool_blocks": 24, "max_batch": 3,
+        "max_blocks_per_seq": 8, "prefill_chunk_tokens": 32})
+    one.submit(list(range(1, 41)), max_new_tokens=6)
+    one.run_until_idle()
+    assert one.stats["paged.chunk_live_pages_sum"] == 2 + 3
+    assert one.stats["paged.chunk_table_pages_sum"] == 2 * 8
+    assert one.stats["paged.live_pages_sum"] > 0
+    assert one.telemetry()["gauges"]["paged.prefill_path"] == \
+        {"reference": [32, 16]}
+
+
 def test_a_blocked_step_counts_exactly_one_cause(served):
     srv, reqs, outside = served
     causes = ("admit_blocked.no_lane", "admit_blocked.no_blocks",
