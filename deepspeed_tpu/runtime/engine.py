@@ -239,6 +239,22 @@ class DeepSpeedEngine:
                  optimizer: Optional[Optimizer] = None,
                  lr_scheduler=None,
                  mpu=None):
+        # the one recorder (utils/telemetry.py), made first: train.* spans,
+        # counters, and what wall_clock_breakdown, the monitor and the
+        # autotuner read; all the constructor does lies in train.init, so
+        # set-up and its compiles are recorded like any step
+        self.rec = telemetry.Recorder("train")
+        self.rec.counters.update({"train.h2d_bytes": 0, "compiles": 0})
+        with self.rec.span("train.init"):
+            self._init(model, config, model_parameters, loss_fn, apply_fn,
+                       example_batch, rng, sharding_rules, mesh_manager,
+                       optimizer, lr_scheduler, mpu)
+        # the per-step report of train.* spans starts at the first step
+        self._spans_reported_ns = time.monotonic_ns()
+
+    def _init(self, model, config, model_parameters, loss_fn, apply_fn,
+              example_batch, rng, sharding_rules, mesh_manager, optimizer,
+              lr_scheduler, mpu) -> None:
         self.config = load_config(config)
         # sparse_attention / sequence_parallel.mode consume their config
         # sections by rewiring the model's attention_impl (VERDICT: the two
@@ -354,7 +370,8 @@ class DeepSpeedEngine:
         if model_parameters is None:
             if example_batch is None:
                 raise ValueError("need model_parameters or example_batch to initialize")
-            model_parameters = self._init_params(example_batch)
+            with self.rec.span("train.init.params"):
+                model_parameters = self._init_params(example_batch)
         params_f32 = jax.tree.map(lambda p: jnp.asarray(p, jnp.float32), model_parameters)
 
         # sharding policy ----------------------------------------------------
@@ -551,40 +568,42 @@ class DeepSpeedEngine:
         # device placement of state -----------------------------------------
         # fp32 training: params ARE the master copy — TrainState.master is kept
         # empty so the same buffers aren't donated twice through the pytree.
-        if self.onebit is not None:
-            # fp32 params, replicated (pure DP); runner casts for compute
-            params = jax.device_put(params_f32,
-                                    NamedSharding(self.mesh, P()))
-            master = ()
-        elif self.offload is not None:
-            params = (() if self._transient_params
-                      else self.offload.current_params_device())
-            master = ()
-        elif self.keep_master:
-            master = jax.device_put(params_f32, self.master_shardings)
-            params = jax.jit(  # graftlint: disable=TPU002 (engine init: one trace per engine)
-                lambda m: jax.tree.map(lambda x: x.astype(self.compute_dtype), m),
-                out_shardings=self.param_shardings)(master)
-        else:
-            # fp32 (params are f32 already — no transient host copy) or
-            # pure-bf16 (cast down; no master)
-            cast = (params_f32 if self.compute_dtype == jnp.float32
-                    else jax.tree.map(
-                        lambda x: x.astype(self.compute_dtype), params_f32))
-            params = jax.device_put(cast, self.param_shardings)
-            master = ()
+        with self.rec.span("train.init.place"):
+            if self.onebit is not None:
+                # fp32 params, replicated (pure DP); runner casts for compute
+                params = jax.device_put(params_f32,
+                                        NamedSharding(self.mesh, P()))
+                master = ()
+            elif self.offload is not None:
+                params = (() if self._transient_params
+                          else self.offload.current_params_device())
+                master = ()
+            elif self.keep_master:
+                master = jax.device_put(params_f32, self.master_shardings)
+                params = jax.jit(  # graftlint: disable=TPU002 (engine init: one trace per engine)
+                    lambda m: jax.tree.map(lambda x: x.astype(self.compute_dtype), m),
+                    out_shardings=self.param_shardings)(master)
+            else:
+                # fp32 (params are f32 already — no transient host copy) or
+                # pure-bf16 (cast down; no master)
+                cast = (params_f32 if self.compute_dtype == jnp.float32
+                        else jax.tree.map(
+                            lambda x: x.astype(self.compute_dtype), params_f32))
+                params = jax.device_put(cast, self.param_shardings)
+                master = ()
         opt_state = {}
-        if self.onebit is not None:
-            opt_state = {"onebit": self.onebit.init_state(params_f32)}
-            self.opt_shardings = jax.tree.map(lambda x: x.sharding, opt_state)
-        elif self.offload is not None:
-            self.opt_shardings = {}
-        else:
-            self.opt_shardings = self._opt_state_shardings(params_f32)
-            if self.optimizer is not None:
-                opt_state = jax.jit(self.optimizer.init,  # graftlint: disable=TPU002 (engine init: one trace per engine)
-                                    out_shardings=self.opt_shardings)(
-                                        master if self.keep_master else params)
+        with self.rec.span("train.init.opt_state"):
+            if self.onebit is not None:
+                opt_state = {"onebit": self.onebit.init_state(params_f32)}
+                self.opt_shardings = jax.tree.map(lambda x: x.sharding, opt_state)
+            elif self.offload is not None:
+                self.opt_shardings = {}
+            else:
+                self.opt_shardings = self._opt_state_shardings(params_f32)
+                if self.optimizer is not None:
+                    opt_state = jax.jit(self.optimizer.init,  # graftlint: disable=TPU002 (engine init: one trace per engine)
+                                        out_shardings=self.opt_shardings)(
+                                            master if self.keep_master else params)
         # scalars placed REPLICATED ON THE MESH, matching the canonical
         # sharding the compiled step emits for its outputs — a
         # SingleDeviceSharding here is a different jit cache key and cost a
@@ -627,11 +646,6 @@ class DeepSpeedEngine:
 
         # observability ------------------------------------------------------
         self.monitor = MonitorMaster(self.config)
-        # the one recorder (utils/telemetry.py): train.* spans, counters,
-        # and what wall_clock_breakdown, the monitor and the autotuner read
-        self.rec = telemetry.Recorder("train")
-        self.rec.counters.update({"train.h2d_bytes": 0, "compiles": 0})
-        self._spans_reported_ns = 0
         self.global_steps = 0
         self.micro_steps = 0
 
@@ -1490,7 +1504,13 @@ class DeepSpeedEngine:
                 batch = self._prepare_batch(batch)
             with rec.span("train.h2d"):
                 micros = self._put_micro_batches(batch)
-            with rec.span("train.dispatch"):
+            # the fused step is one program, named as a device trace's
+            # "XLA Modules" line names its runs (the 1-bit and offload
+            # paths launch several)
+            fused = self._train_step
+            launch = {} if fused is None else {
+                "program": "jit_" + fused.__name__}
+            with rec.span("train.dispatch", **launch):
                 metrics = self._dispatch_step(micros)
             # the engine blocks on the loss every step: the watchdog's
             # liveness rule (_after_step: "a wedged collective never

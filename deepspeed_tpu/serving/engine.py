@@ -377,6 +377,17 @@ class ServingEngine:
                  rng: Optional[jax.Array] = None,
                  interpret: bool = False,
                  shared: Optional[SharedPagedState] = None):
+        # the recorder first: all the constructor does lies in serve.init,
+        # so set-up and its compiles are recorded like any step
+        self.rec = telemetry.Recorder("serve")
+        self.stats: Dict[str, int] = self.rec.counters
+        self.stats.update(dict.fromkeys(_COUNTERS, 0))
+        with self.rec.span("serve.init"):
+            self._init(cfg, params, serving, heartbeat, rng, interpret,
+                       shared)
+
+    def _init(self, cfg, params, serving, heartbeat, rng, interpret,
+              shared) -> None:
         from ..config.config import ServingConfig
         if serving is None:
             serving = ServingConfig()
@@ -416,19 +427,16 @@ class ServingEngine:
             # never materialize a full-precision weight copy
             from ..ops.pallas.quant_matmul import pack_decode_weights
             self.params = pack_decode_weights(self.params)
-        # the paged-KV state: PRIVATE by default, SHARED when a
-        # disaggregated pair (serving/disagg.py) passes one in — block
-        # IDs then mean the same pool slots to both roles, which is what
-        # makes the prefill->decode handoff zero-copy
-        self.rec = telemetry.Recorder("serve")
-        self.stats: Dict[str, int] = self.rec.counters
-        self.stats.update(dict.fromkeys(_COUNTERS, 0))
         # a dropless MoE model only: the outputs of calls whose tokens
         # nobody fetched (a prompt's middle chunks) stay on the device with
         # their expert counts and ride the next fetch
         self._moe_pending: List[Any] = []
         if cfg.moe_is_dropless:
             self.stats.update(dict.fromkeys(_MOE_COUNTERS, 0))
+        # the paged-KV state: PRIVATE by default, SHARED when a
+        # disaggregated pair (serving/disagg.py) passes one in — block
+        # IDs then mean the same pool slots to both roles, which is what
+        # makes the prefill->decode handoff zero-copy
         self._shared = shared if shared is not None else SharedPagedState(
             cfg, serving, dtype=kv_dtype, counters=self.stats)
         self.scheduler = Scheduler(self.pool, serving.max_queue,
@@ -467,6 +475,10 @@ class ServingEngine:
         # step, so the update is in-place on TPU (no 2x pool HBM)
         self._decode_fn = jax.jit(_decode, donate_argnums=(1,))
         self._prefill_fn = jax.jit(_prefill, donate_argnums=(1,))
+        # the names a device trace's "XLA Modules" line gives their runs:
+        # a dispatch span carries its program's, to be paired with the run
+        self._decode_program = "jit_" + _decode.__name__
+        self._prefill_program = "jit_" + _prefill.__name__
         log_dist(
             f"ServingEngine: pool={serving.pool_blocks}x{bs} tokens "
             f"(~{(serving.pool_blocks - 1) * bs} cacheable), "
@@ -1008,7 +1020,8 @@ class ServingEngine:
                 req, req.prompt[pf.done:pf.done + n], pf.table, pf.done)
         try:
             chaos.failpoint("serve.chunk")
-            with rec.span("serve.prefill.dispatch"):
+            with rec.span("serve.prefill.dispatch",
+                          program=self._prefill_program):
                 tok = self._call_device(self._prefill_fn, step_in)
         except BaseException as e:
             # a failed chunk must not leak the lifetime allocation —
@@ -1095,7 +1108,8 @@ class ServingEngine:
             suffix = req.prompt[n_pref:]
             step_in = self._prefill_inputs(req, suffix, table, n_pref)
         try:
-            with rec.span("serve.prefill.dispatch"):
+            with rec.span("serve.prefill.dispatch",
+                          program=self._prefill_program):
                 tok = self._call_device(self._prefill_fn, step_in)
         except BaseException as e:
             # a failed forward (device OOM, interrupt) must not leak the
@@ -1135,7 +1149,8 @@ class ServingEngine:
             # the lanes' state is the step's input as it stands; the copy is
             # what the device call owns
             step_in = lanes.buf.copy()
-        with rec.span("serve.decode.dispatch"):
+        with rec.span("serve.decode.dispatch",
+                      program=self._decode_program):
             nxt = self._call_device(self._decode_fn, step_in)
         with rec.span("serve.decode.fetch"):
             nxt = self._fetch(nxt)

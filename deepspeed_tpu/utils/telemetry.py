@@ -1,4 +1,4 @@
-"""The program's one recorder: host spans, counters, and scope paths.
+"""The program's one recorder: host spans, counters, compiles by phase.
 
 Both engines (``runtime/engine.py``, ``serving/engine.py``) hold one
 :class:`Recorder` and record through it; there is no switch.
@@ -6,11 +6,15 @@ Both engines (``runtime/engine.py``, ``serving/engine.py``) hold one
 * :meth:`Recorder.span` is a context manager. It enters a
   ``jax.profiler.TraceAnnotation("ds/" + name, **attrs)``, so that whenever
   a profiler session is active (``engine.profile_trace``, any
-  ``jax.profiler.trace``) the span lies in the ``/host:CPU`` plane on the
-  DEVICE TRACE'S CLOCK, and it appends ``(name, parent, start_ns, end_ns,
-  attrs)`` to a bounded ring (oldest dropped, the drop counted). With no
-  session the ring append and one ``TraceMe.is_enabled()`` are the whole
-  cost (~1.2 us a span on a 2026 server core).
+  ``jax.profiler.trace``) the span lies in the ``/host:CPU`` plane, on the
+  HOST plane's clock: in a TPU profile the device planes' clock runs about
+  a millisecond apart from it, so a reader that sets a span against device
+  ops aligns the two first (``benchmark/clock.py`` does, from causality:
+  no program runs before the span that launched it began). It also appends
+  ``(name, parent, start_ns, end_ns, attrs)`` to a bounded ring (oldest
+  dropped, the drop counted). With no session the ring append and one
+  ``TraceMe.is_enabled()`` are the whole cost (~1.2 us a span on a 2026
+  server core).
   The ring's clock is ``time.monotonic_ns()``, the clock of the ``Request``
   stamps; ring and profiler trace are joined by the ``step`` /
   ``step_num`` attribute that step spans carry in both, never by
@@ -23,15 +27,23 @@ Both engines (``runtime/engine.py``, ``serving/engine.py``) hold one
 * No span adds a device sync. A span round a wait the program already
   makes is named ``*.fetch`` / ``*.sync`` and is the only kind that holds
   device time.
-* A ``jax.monitoring`` listener counts the programs handed to the backend
-  compiler while a span of a recorder is open on the compiling thread:
-  counter ``compiles``, and a ring entry ``compile`` whose parent is the
-  span it fell in.
+* One ``jax.monitoring`` listener books what JAX spends on a program
+  while a span of a recorder is open on the compiling thread, by phase
+  (:data:`COMPILE_PHASES`): counters ``compile.trace_us``,
+  ``compile.lower_us``, ``compile.backend_us``, ``compile.cache_load_us``
+  (cumulative microseconds) beside ``compiles`` (programs compiled by the
+  backend or loaded from the persistent cache), and a ring entry
+  ``compile`` with ``phase``, ``fun_name`` and, inside a step span, the
+  step's number under ``step``; its parent is the span it fell in. The
+  listener runs only when JAX compiles: nothing on the hot path.
+* Both engines make their recorder first and wrap their constructor in
+  ``serve.init`` / ``train.init``, so set-up and its compiles are recorded
+  like any step.
 * The module keeps the recorders of the last few engines
   (:func:`recent`): rings and counters, not the engines, for a reader that
   never held the engine.
-* :func:`scope_paths` reads ``{HLO instruction: jax.named_scope path}``
-  out of a compiled program's text, for a trace reader to group device
+* :func:`scope_of` reads a ``jax.named_scope`` path out of a device op's
+  ``op_name`` (a profile carries it), for a trace reader to group device
   ops by the source scope that made them.
 
 ``jax.profiler`` is imported on the first span and nothing else is.
@@ -65,7 +77,7 @@ _listen_lock = threading.Lock()
 
 
 def _stack() -> list:
-    """Open spans of this thread, innermost last: (recorder, name)."""
+    """Open spans of this thread, innermost last."""
     try:
         return _tls.stack
     except AttributeError:
@@ -80,15 +92,63 @@ def _load_annotations():
     return _annotations
 
 
+_CACHE_LOAD = ("cache_load", "compile.cache_load_us")
+#: ``jax.monitoring`` duration event -> (phase of a ``compile`` ring entry,
+#: the counter its microseconds go to). The first three carry ``fun_name``.
+COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": ("trace", "compile.trace_us"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        ("lower", "compile.lower_us"),
+    "/jax/core/compile/backend_compile_duration":
+        ("backend", "compile.backend_us"),
+    "/jax/compilation_cache/cache_retrieval_time_sec": _CACHE_LOAD,
+}
+#: a program's trace reports every jitted helper traced inside it (thousands
+#: of sub-millisecond events a serving engine): the counter takes them all,
+#: the ring those at least this long
+TRACE_ENTRY_NS = 1_000_000
+
+
 def _on_compile(event: str, secs: float, **kw) -> None:
-    if not event.endswith("backend_compile_duration"):
-        return
+    phase_counter = COMPILE_PHASES.get(event)
     stack = _stack()
-    if stack:
-        rec, parent = stack[-1]
+    if phase_counter is None or not stack:
+        return
+    state = vars(_tls)
+    if phase_counter is _CACHE_LOAD:
+        # JAX reports a persistent-cache hit INSIDE the backend event of
+        # the same program, which follows at once and holds this time
+        state["loaded"] = True
+        return
+    phase, counter = phase_counter
+    now = time.monotonic_ns()
+    start = now - int(secs * 1e9)
+    self_ns = now - start
+    top = stack[-1]
+    rec = top.rec
+    if phase == "trace":
+        # a jitted function traced inside another's trace reports first:
+        # the outer one's counter takes its own part only
+        inner = state.setdefault("traces", [])
+        while inner and inner[-1][0] >= start:
+            a, b = inner.pop()
+            self_ns -= b - a
+        inner.append((start, now))
+    elif phase == "backend":
+        state.pop("traces", None)
+        if state.pop("loaded", False):
+            phase, counter = _CACHE_LOAD
         rec.count("compiles")
-        now = time.monotonic_ns()
-        rec._append(("compile", parent, now - int(secs * 1e9), now, {}))
+    rec.count(counter, max(self_ns, 0) // 1000)
+    if phase == "trace" and now - start < TRACE_ENTRY_NS:
+        return
+    attrs = {"phase": phase, "fun_name": str(kw.get("fun_name", ""))}
+    for span in reversed(stack):
+        if span.rec is rec and span._before is not None:
+            attrs["step"] = span.attrs.get("step",
+                                           span.attrs.get("step_num"))
+            break
+    rec._append(("compile", top.name, start, now, attrs))
 
 
 def _listen() -> None:
@@ -124,8 +184,8 @@ class _Span:
 
     def __enter__(self) -> "_Span":
         stack = self._stack = _stack()
-        self.parent = stack[-1][1] if stack else None
-        stack.append((self.rec, self.name))
+        self.parent = stack[-1].name if stack else None
+        stack.append(self)
         if self._ann is not None:
             self._ann.__enter__()
         self.start_ns = time.monotonic_ns()
@@ -232,7 +292,7 @@ def span_times(entries: List[Entry], since_ns: int = 0
     return out
 
 
-# --------------------------------------------------------------- scope paths
+# -------------------------------------------------------------- device scopes
 
 #: the ``jax.named_scope`` names the program puts on its device work, at
 #: layer boundaries only (``models/transformer.py``, ``serving/
@@ -243,16 +303,7 @@ SCOPES = frozenset({
     "head", "loss", "grad_reduce", "sample", "grad_accum", "optimizer",
     "zero.gather", "zero.scatter"})
 
-_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s.*?"
-                    r"metadata=\{[^}]*?op_name=\"([^\"]*)\"", re.M)
 _SEGMENT = re.compile(r"[^/()]+")
-
-
-def hlo_op_names(hlo_text: str) -> Dict[str, str]:
-    """``{instruction name: op_name}`` of an HLO module's text. A fusion
-    carries the ``op_name`` the compiler wrote on the fusion instruction
-    itself (that of its root)."""
-    return {m.group(1): m.group(2) for m in _INSTR.finditer(hlo_text)}
 
 
 def scope_of(op_name: str) -> str:
@@ -276,14 +327,3 @@ def scope_of(op_name: str) -> str:
     if "transpose(" in op_name:
         return "backward:" + path
     return path
-
-
-def scope_paths(jitted_fn, *args, **kwargs) -> Dict[str, str]:
-    """``{HLO instruction name: scope path}`` (:func:`scope_of`) of the
-    program ``jitted_fn`` compiles for these arguments (arrays or
-    ``ShapeDtypeStruct``s), read from the optimized module's
-    ``metadata={op_name=...}``: for a trace reader whose trace does not
-    carry ``op_name`` itself."""
-    text = jitted_fn.lower(*args, **kwargs).compile().as_text()
-    return {instr: scope_of(op_name)
-            for instr, op_name in hlo_op_names(text).items()}

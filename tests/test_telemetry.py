@@ -1,8 +1,8 @@
 """The program's one recorder (utils/telemetry.py) and what the two engines
 record through it: span nesting and self time, the ring's bound, the
-``serve.*`` / ``train.*`` spans and counters, the request stamps, the device
-scopes (metadata only: the optimized HLO keeps its instructions) and
-``scope_paths``.
+``serve.*`` / ``train.*`` spans and counters, the request stamps, compiles by
+phase and program, the constructors' ``*.init`` spans and the device scopes
+(metadata only: the optimized HLO keeps its instructions).
 """
 
 import contextlib
@@ -34,11 +34,15 @@ SERVE_SPANS = {
     "serve.prefill.install", "serve.decode", "serve.decode.build",
     "serve.decode.dispatch", "serve.decode.fetch", "serve.decode.bookkeep",
     "serve.heartbeat", "serve.req.admitted", "serve.req.first_token",
-    "serve.req.finished"}
+    "serve.req.finished", "serve.init"}
 TRAIN_SPANS = {
     "train.step", "train.prepare", "train.h2d", "train.dispatch",
     "train.sync", "train.after_step", "train.after_step.heartbeat",
-    "train.after_step.pull", "train.after_step.monitor"}
+    "train.after_step.pull", "train.after_step.monitor",
+    "train.init", "train.init.params", "train.init.place",
+    "train.init.opt_state"}
+PHASE_COUNTERS = {"trace": "compile.trace_us", "lower": "compile.lower_us",
+                  "backend": "compile.backend_us"}
 
 
 # ------------------------------------------------------------- the recorder
@@ -101,20 +105,116 @@ def test_the_module_keeps_the_last_few_recorders_only():
     assert telemetry.recent() == made[-telemetry.KEPT:]
 
 
-def test_a_compile_inside_a_span_is_counted_with_the_span_it_fell_in():
+def slow_to_trace(n: int):
+    """A jitted function whose trace takes well over
+    ``telemetry.TRACE_ENTRY_NS`` (a few hundred equations)."""
+    def f(x):
+        for i in range(n):
+            x = jnp.sin(x) * (i + 1.5)
+        return x
+    f.__name__ = f"slow_{n}"
+    return jax.jit(f)
+
+
+def test_a_compile_inside_a_span_is_counted_in_each_phase_with_its_name():
     rec = telemetry.Recorder("t")
-    f = jax.jit(lambda x: x * 3 + 1)
+    f, x = slow_to_trace(300), jnp.ones((3,))
     with rec.step_span("s", step=0):
         with rec.span("s.work"):
-            f(jnp.ones((3,))).block_until_ready()
+            f(x).block_until_ready()
     with rec.step_span("s", step=1):
-        f(jnp.ones((3,))).block_until_ready()        # cached: no compile
-    assert rec.counters["compiles"] >= 1
+        f(x).block_until_ready()                     # cached: no compile
+    assert rec.counters["compiles"] == 1
     compiles = [e for e in rec.ring if e[0] == "compile"]
-    assert compiles and all(e[1] == "s.work" for e in compiles)
+    assert all(e[1] == "s.work" and e[4]["step"] == 0 for e in compiles)
+    # (on a busy machine a helper traced inside f may pass TRACE_ENTRY_NS
+    # and be written too: f's own trace is the last and the longest)
+    traces = [e for e in compiles if e[4]["phase"] == "trace"]
+    assert [e[4]["phase"] for e in compiles[len(traces) - 1:]] == [
+        "trace", "lower", "backend"]
+    assert traces[-1] is max(traces, key=lambda e: e[3] - e[2])
+    compiles = compiles[len(traces) - 1:]
+    for name, parent, start, end, attrs in compiles:
+        assert "slow_300" in attrs["fun_name"]
+        # the counter of the phase holds the entry's microseconds (a
+        # trace's less the helpers traced inside it, counted on their own)
+        assert rec.counters[PHASE_COUNTERS[attrs["phase"]]] == \
+            pytest.approx((end - start) / 1e3, rel=0.05, abs=1.0)
+    assert "compile.cache_load_us" not in rec.counters
     steps = [e for e in rec.ring if e[0] == "s"]
-    assert steps[0][4]["d"]["compiles"] == rec.counters["compiles"]
-    assert "compiles" not in steps[1][4]["d"]
+    assert steps[0][4]["d"]["compiles"] == 1
+    assert set(PHASE_COUNTERS.values()) <= set(steps[0][4]["d"])
+    assert not any(k.startswith("compile") for k in steps[1][4]["d"])
+
+
+def test_a_recompile_in_a_late_step_is_found_by_one_ring_query():
+    rec = telemetry.Recorder("t")
+    f, usual, other = slow_to_trace(200), jnp.ones((3,)), jnp.ones((5,))
+    for step in range(6):
+        with rec.step_span("serve.step", step=step):
+            with rec.span("serve.decode.dispatch"):
+                # step 4 meets a shape the program was not compiled for
+                f(other if step == 4 else usual).block_until_ready()
+    late = [(e[4]["step"], e[4]["phase"], e[1])
+            for e in rec.ring if e[0] == "compile" and e[4]["step"] > 0
+            and "slow_200" in e[4]["fun_name"]]
+    assert late == [(4, ph, "serve.decode.dispatch")
+                    for ph in ("trace", "lower", "backend")]
+
+
+def test_a_jit_traced_inside_another_is_counted_once():
+    rec = telemetry.Recorder("t", keep=False)
+    telemetry._listen()
+    inner = slow_to_trace(150)
+    outer, x = jax.jit(lambda x: inner(x) + inner(x * 2.0)), jnp.ones((4,))
+    with rec.span("s"):
+        outer(x).block_until_ready()
+    traces = [e for e in rec.ring
+              if e[0] == "compile" and e[4]["phase"] == "trace"]
+    assert len(traces) >= 2                    # the inner one and the outer
+    # summed, the entries count the inner trace twice; the counter does not
+    held = sum(e[3] - e[2] for e in traces) / 1e3
+    outermost = max(e[3] - e[2] for e in traces) / 1e3
+    assert rec.counters["compile.trace_us"] < held
+    assert rec.counters["compile.trace_us"] == pytest.approx(outermost,
+                                                             rel=0.05)
+    wall_us = (rec.ring[-1][3] - rec.ring[-1][2]) / 1e3
+    assert sum(rec.counters[c] for c in PHASE_COUNTERS.values()) <= wall_us
+
+
+def test_the_listener_books_a_cache_load_apart_and_keeps_short_traces_out():
+    rec = telemetry.Recorder("t", keep=False)
+    base = "/jax/core/compile/"
+    with rec.step_span("train.step", step_num=9):
+        with rec.span("train.dispatch"):
+            # a helper's trace, too short for the ring; the counter has it
+            telemetry._on_compile(base + "jaxpr_trace_duration", 0.0002,
+                                  fun_name="helper")
+            telemetry._on_compile(base + "jaxpr_to_mlir_module_duration",
+                                  0.003, fun_name="jit(step)")
+            # a persistent-cache hit reports inside the backend event
+            telemetry._on_compile(
+                "/jax/compilation_cache/cache_retrieval_time_sec", 0.004)
+            telemetry._on_compile(base + "backend_compile_duration", 0.005,
+                                  fun_name="jit(step)")
+            # the next program misses the cache
+            telemetry._on_compile(base + "backend_compile_duration", 0.007,
+                                  fun_name="jit(other)")
+            telemetry._on_compile("/jax/some/other_duration", 1.0)
+    assert rec.counters == {
+        "compile.trace_us": 200, "compile.lower_us": 3000,
+        "compile.cache_load_us": 5000, "compile.backend_us": 7000,
+        "compiles": 2}
+    entries = [(e[4]["phase"], e[4]["fun_name"], e[4]["step"], e[1])
+               for e in rec.ring if e[0] == "compile"]
+    assert entries == [
+        ("lower", "jit(step)", 9, "train.dispatch"),
+        ("cache_load", "jit(step)", 9, "train.dispatch"),
+        ("backend", "jit(other)", 9, "train.dispatch")]
+    # outside every span of a recorder nothing is booked anywhere
+    telemetry._on_compile(base + "backend_compile_duration", 0.5,
+                          fun_name="jit(nobody)")
+    assert rec.counters["compiles"] == 2
 
 
 def test_spans_appear_in_a_profiler_trace_under_the_ds_prefix(tmp_path):
@@ -201,6 +301,41 @@ def test_a_served_run_yields_every_serve_span(served):
     assert snap["counters"] == srv.stats
     assert snap["spans"]["serve.step"]["count"] == len(outside)
     assert snap["ring_dropped"] == 0
+
+
+def test_serve_init_is_the_first_entry_and_holds_the_constructors_compiles(
+        served):
+    srv, reqs, outside = served
+    ring = list(srv.rec.ring)
+    spans_only = [e for e in ring if e[0] != "compile"]
+    assert spans_only[0][0] == "serve.init" and spans_only[0][1] is None
+    init = spans_only[0]
+    # what the constructor compiles (the pool's zeros, the sampling key)
+    # lies inside the span, is booked to it and is written before it ends
+    inside = ring[:ring.index(init)]
+    assert inside and all(e[0] == "compile" and e[1] == "serve.init"
+                          and "step" not in e[4] for e in inside)
+    assert all(init[2] <= e[2] <= e[3] <= init[3] for e in inside)
+    assert {e[4]["phase"] for e in inside} >= {"lower", "backend"}
+    assert [e[0] for e in ring].count("serve.init") == 1
+    # no serve.submit or step began before the constructor ended
+    assert all(e[2] >= init[3] for e in spans_only[1:])
+
+
+def test_the_dispatch_spans_name_the_program_they_launch(served):
+    srv, reqs, outside = served
+    programs = {(e[0], e[4].get("program")) for e in srv.rec.ring
+                if e[0].endswith(".dispatch")}
+    assert programs == {("serve.decode.dispatch", "jit__decode"),
+                        ("serve.prefill.dispatch", "jit__prefill")}
+    # the names a device trace gives their runs: "jit_" + the function's
+    assert srv._decode_fn.__name__ == "_decode"
+    assert srv._prefill_fn.__name__ == "_prefill"
+    # nothing else was added to any span of a step
+    assert {k for e in srv.rec.ring for k in e[4]
+            if e[0] not in ("compile", "serve.decode.dispatch",
+                            "serve.prefill.dispatch")} == {
+        "step", "d", "rid", "tokens", "final", "lanes", "arrival_ts", "ts"}
 
 
 def test_inside_and_outside_count_the_same_thing_step_for_step(served):
@@ -441,6 +576,40 @@ def test_three_train_batches_yield_the_train_spans():
     assert engine.samples_per_sec() > 0            # step 2 is past warm-up
 
 
+def test_train_init_is_first_holds_its_parts_and_the_constructors_compiles():
+    engine = lm_engine()
+    engine.train_batch(lm_batch(engine, 0))
+    ring = list(engine.rec.ring)
+    init = next(e for e in ring if e[0] == "train.init")
+    before = ring[:ring.index(init)]
+    # everything recorded before the constructor ended lies inside it: its
+    # three parts, each once, and compiles booked to it or to a part
+    parts = [e for e in before if e[0] != "compile"]
+    assert [e[0] for e in parts] == ["train.init.params", "train.init.place",
+                                     "train.init.opt_state"]
+    assert all(e[1] == "train.init" for e in parts)
+    compiles = [e for e in before if e[0] == "compile"]
+    assert compiles and all(e[1].startswith("train.init") and
+                            "step" not in e[4] for e in compiles)
+    assert any(e[1] == "train.init.params" and e[4]["phase"] == "backend"
+               for e in compiles)                # the model's init program
+    assert all(init[2] <= e[2] <= e[3] <= init[3] for e in before)
+    # the counters count set-up's compiles like any other: the first
+    # step's gains are what came after the constructor
+    counted = engine.rec.counters["compiles"]
+    in_init = sum(1 for e in compiles
+                  if e[4]["phase"] in ("backend", "cache_load"))
+    step0 = next(e for e in ring if e[0] == "train.step")
+    assert counted == in_init + step0[4]["d"]["compiles"]
+    # the step's launch names its program
+    dispatch = next(e for e in ring if e[0] == "train.dispatch")
+    assert dispatch[4] == {"program": "jit_train_step"}
+    assert any(e[0] == "compile" and e[1] == "train.dispatch"
+               and e[4] == {"phase": "backend",
+                            "fun_name": "jit(train_step)", "step": 0}
+               for e in ring)
+
+
 def test_wall_clock_breakdown_logs_parts_and_the_monitor_gets_them(tmp_path):
     class Capture(logging.Handler):
         def __init__(self):
@@ -556,12 +725,19 @@ def test_scopes_leave_the_decode_steps_instructions_alone(tiny_lm,
     assert instructions(scoped) == instructions(bare)
 
 
-def test_scope_paths_names_every_scope_of_the_train_step():
+def compiled_scopes(fn, *args) -> set:
+    """The scope paths (``telemetry.scope_of``) of the ``op_name`` of every
+    instruction of the program ``fn`` compiles for ``args``."""
+    text = fn.lower(*args).compile().as_text()
+    return {telemetry.scope_of(op)
+            for op in re.findall(r'op_name="([^"]*)"', text)}
+
+
+def test_every_scope_of_the_train_step_is_in_its_compiled_program():
     fn, args = train_step_program(lm_engine(
         {"zero_optimization": {"stage": 3, "zero_quantized_weights": True,
                                "stage3_param_persistence_threshold": 0}}))
-    paths = telemetry.scope_paths(fn, *args)
-    found = set(paths.values())
+    found = compiled_scopes(fn, *args)
     for scope in ("embed", "layers", "block.attn", "block.mlp", "head",
                   "loss", "grad_accum", "optimizer", "zero.scatter",
                   "backward:block.attn", "backward:block.mlp",
@@ -574,13 +750,13 @@ def test_scope_paths_names_every_scope_of_the_train_step():
                                   for s in telemetry.SCOPES), p
 
 
-def test_scope_paths_names_every_scope_of_the_decode_step(tiny_lm):
+def test_every_scope_of_the_decode_step_is_in_its_compiled_program(tiny_lm):
     cfg, params = tiny_lm
     srv = ServingEngine(cfg, params, serving={
         "block_size": 16, "pool_blocks": 24, "max_batch": 3,
         "max_blocks_per_seq": 8})
     fn, args = decode_program(srv)
-    found = set(telemetry.scope_paths(fn, *args).values())
+    found = compiled_scopes(fn, *args)
     for scope in ("embed", "layers", "block.attn.qkv", "block.attn.kv_write",
                   "block.attn.attend", "block.attn.out", "block.mlp",
                   "head", "sample"):
@@ -607,12 +783,5 @@ def test_scope_of_reads_jaxs_op_names():
         "jit(_decode)/layers/while/body/closed_call/block.mlp/add") == \
         "block.mlp"
     assert telemetry.scope_of("jit(_decode)/jit(clip)/min") == ""
-    assert telemetry.hlo_op_names(
-        '  %copy.39.remat = bf16[16,32]{1,0} copy(bf16[16,32]{0,1} %x), '
-        'metadata={op_name="jit(_decode)/while/body/block.attn/kv_write/'
-        'scatter" source_file="a.py" source_line=3}\n'
-        '  ROOT %fusion.2 = f32[] fusion(f32[] %y), kind=kLoop, '
-        'metadata={op_name="jit(f)/optimizer/add"}\n') == {
-        "copy.39.remat": "jit(_decode)/while/body/block.attn/kv_write/"
-                         "scatter",
-        "fusion.2": "jit(f)/optimizer/add"}
+    assert not hasattr(telemetry, "scope_paths")
+    assert not hasattr(telemetry, "hlo_op_names")
