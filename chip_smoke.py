@@ -326,8 +326,9 @@ def _serve(srv, prompts, new_tokens, label: str, timeout_s: float = 600.0):
 def _decode_text(srv) -> str:
     """Compiled text of the engine's ONE decode step (the one int32 buffer
     ``ServingEngine._decode_step`` passes: the lanes' state as it stands)."""
-    return srv._decode_fn.lower(srv.params, srv.pools,
-                                srv._lanes.buf).compile().as_text()
+    return srv._decode_fn.lower(srv.params, srv.pools, srv._lanes.buf,
+                                srv._dec_out, srv._pre_out
+                                ).compile().as_text()
 
 
 def _free_server(srv) -> None:

@@ -221,6 +221,17 @@ class PrefillEngine(ServingEngine):
         return (self.scheduler.pending == 0 and self._prefilling is None
                 and self._ready is None)
 
+    def _mid_chunk(self, tok) -> None:
+        """No lane waits on this role's steps: a middle chunk is not waited
+        for, and the next is launched while it runs (a dropless mixture's
+        counts ride the last chunk's fetch)."""
+        if self.cfg.moe_is_dropless:
+            self._moe_pending.append((self._calls, tok))
+
+    def _stage(self, seq: _Prefilled) -> None:
+        """Nothing is staged: no decode call runs here, and the item that
+        crosses the handoff carries a token the host has read."""
+
     def _install(self, seq: _Prefilled) -> None:
         self._ready = HandoffItem(seq.req, seq.blocks, seq.table, seq.ctx,
                                   seq.last_tok)
@@ -247,7 +258,8 @@ class PrefillEngine(ServingEngine):
         with self._lock, self._step_span():
             self._flush_ready()           # a backpressured item first
             done = self._admit()
-            done += self._advance_prefill()
+            self._advance_prefill()
+            done += self._take_chunk()
             self.steps += 1
             self.stats["timeout"] = self.scheduler.timed_out
             self._stamp_heartbeat()
@@ -277,6 +289,7 @@ class PrefillEngine(ServingEngine):
                         Request(prompt=[1] * n, max_new_tokens=1)))
                     while self._prefilling is not None:
                         self._advance_prefill()
+                    self._take_chunk()
             finally:
                 self._warming = False
                 self.stats.update(saved)
@@ -315,11 +328,13 @@ class DecodeEngine(ServingEngine):
     @property
     def idle(self) -> bool:
         return (self.active == 0 and self._holding is None
+                and self._flight is None
                 and (not self._auto_pull or self.handoff.pending == 0))
 
     @property
     def has_work(self) -> bool:
-        return bool(self.active or self._holding is not None)
+        return bool(self.active or self._holding is not None
+                    or self._flight is not None)
 
     @property
     def wants_dispatch(self) -> bool:
@@ -332,7 +347,10 @@ class DecodeEngine(ServingEngine):
     def install_item(self, item: HandoffItem) -> bool:
         """Install a popped item into a free lane (fleet dispatch path —
         caller holds the replica lock; we take the engine lock so a
-        concurrent death-path collection can't interleave)."""
+        concurrent death-path collection can't interleave). A decode call
+        may be in flight: a free lane is none of its lanes, and the item
+        joins the next call with the token it carries, as a prompt's last
+        chunk joins it inside a step."""
         with self._lock:
             return self._install_locked(item)
 
@@ -364,7 +382,7 @@ class DecodeEngine(ServingEngine):
             if self._auto_pull:
                 self.handoff.shed_expired()
                 self._pull_handoff()
-            done = self._decode_step() if self.active else 0
+            done = self._decode_step()
             self.steps += 1
             self._stamp_heartbeat()
             return done
@@ -375,13 +393,17 @@ class DecodeEngine(ServingEngine):
         — a restarted decode replica must not pay its XLA compile under
         a live heartbeat timeout. Runs TWICE so both the fresh-pools and
         the donated-committed-pools specializations are compiled (see
-        PrefillEngine.warm)."""
+        PrefillEngine.warm). A call in flight is retired first; the warm
+        calls read the loop's own two device arrays and leave them be."""
         with self._lock:
+            self._retire()
             for _ in range(2):
-                # the loop's own kind of argument (one numpy buffer), every
-                # lane idle: the ONE decode specialization
+                # the loop's own kinds of argument (one numpy buffer, the
+                # two token vectors on the device), every lane idle: the ONE
+                # decode specialization
                 self._run_device(self._decode_fn, _Lanes(
-                    self._layout, self.max_batch).buf)
+                    self._layout, self.max_batch).buf, self._dec_out,
+                    self._pre_out)
 
     def _collect_held(self, blocks, reqs) -> None:
         if self._holding is not None:

@@ -29,6 +29,7 @@ across staggered arrivals and mixed lengths.
 
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from dataclasses import dataclass, field
@@ -61,6 +62,11 @@ PyTree = Any
 #: step that changes no lane writes none). The two ``paged.chunk_*`` add one
 #: reading a PREFILL call: the pages the call's attention walks (up to its
 #: last real token) and the table's width, which the gather reference reads.
+#: The four ``decode_ahead.*`` say how the launch of a decode call before the
+#: fetch of the one in flight went (``ServingEngine._decode_step``): calls
+#: launched that way, lane inputs the device found for itself (the previous
+#: call's output or a prefill's), lanes computed once more after their end
+#: (an EOS is seen one call late), calls in flight retired outside a step.
 _COUNTERS = (
     "completed", "failed", "timeout", "tokens_generated", "prefill_tokens",
     "prefix_hit_tokens", "preempted",
@@ -70,7 +76,9 @@ _COUNTERS = (
     "kv.held_blocks_sum", "kv.blocks_reserved_sum", "kv.tokens_written_sum",
     "prefix.prompt_tokens", "paged.live_pages_sum", "paged.table_pages_sum",
     "paged.chunk_live_pages_sum", "paged.chunk_table_pages_sum",
-    "step_inputs.transfers_sum", "step_inputs.lane_rows_written_sum")
+    "step_inputs.transfers_sum", "step_inputs.lane_rows_written_sum",
+    "decode_ahead.launched", "decode_ahead.device_lane_tokens_sum",
+    "decode_ahead.wasted_lane_tokens", "decode_ahead.retired_unread")
 #: a dropless MoE model's router load, from the [L, E] counts that ride the
 #: tokens' own fetch (``_count_experts``); a dense model has none of these
 _MOE_COUNTERS = ("moe.assignments", "moe.layer_steps",
@@ -153,7 +161,11 @@ class StepLayout:
     argument: the layout is written once, here.
 
     decode, ``B`` lanes:   ``toks[B] | ctx[B] | top_k[B] | tables[B, nbk] |
-    temps[B] | top_p[B] | key``
+    temps[B] | top_p[B] | key``; a lane's ``toks`` word is its token, or
+    below zero the place the device finds it in (:meth:`lane_tokens`):
+    :data:`FROM_DECODE`, the previous decode call's output at the lane's own
+    index, or :data:`FROM_PREFILL`, the first word of the last final prefill
+    call's output. The host need not have seen either.
     prefill, ``T`` tokens: ``ids[1, T] | table[1, nbk] | q0[1] | ctx[1] |
     last_idx[1] | top_k[1] | temp[1] | top_p[1] | key``
 
@@ -163,8 +175,23 @@ class StepLayout:
     own between two steps. The table width and the key's width are the
     layout's own; ``B`` and ``T`` follow from a buffer's length."""
 
+    FROM_DECODE, FROM_PREFILL = -1, -2
+
     def __init__(self, table_width: int, key_words: int = 2):
         self.nbk, self.kw = int(table_width), int(key_words)
+
+    @classmethod
+    def lane_tokens(cls, toks, prev, first):
+        """The decode lanes' input tokens, on the device: ``toks`` where the
+        host wrote a token, else the lane's word of ``prev`` (the previous
+        decode call's output) or the first word of ``first`` (a prefill
+        call's), as they came off the device, whatever rides behind their
+        tokens. One select over ``[B]``."""
+        B = toks.shape[0]
+        return jax.lax.select_n(
+            jnp.clip(-toks, 0, -cls.FROM_PREFILL), toks,
+            jax.lax.slice(prev, (0,), (B,)),
+            jnp.broadcast_to(jax.lax.slice(first, (0,), (1,)), (B,)))
 
     def decode_words(self, lanes: int) -> int:
         return lanes * (5 + self.nbk) + self.kw
@@ -198,11 +225,15 @@ def step_programs(cfg, block_size: int, table_width: int, *,
                   interpret: bool = False, use_filters: bool = False,
                   key_words: int = 2):
     """The loop's two device programs as plain functions, ``(decode,
-    prefill)``, each ``(params, pools, step_in) -> (tokens, pools)`` with
+    prefill)``: ``prefill(params, pools, step_in)`` and ``decode(params,
+    pools, step_in, prev, first)``, each ``-> (tokens, pools)``, with
     ``step_in`` the call's one int32 buffer (:class:`StepLayout`, built
-    from ``table_width`` and ``key_words``): the engine jits them with the
-    pools donated, and tests/test_chip_compile.py compiles the same two for
-    a described chip.
+    from ``table_width`` and ``key_words``) and ``prev`` / ``first`` the
+    previous decode call's and the last final prefill call's ``tokens`` as
+    they came off the device (:func:`token_words` long), for the lanes whose
+    token the host never held (:meth:`StepLayout.lane_tokens`). The engine
+    jits them with the pools donated, and tests/test_chip_compile.py
+    compiles the same two for a described chip.
 
     For a dropless MoE config (``cfg.moe_is_dropless``) the int32 token
     vector each returns carries, behind the tokens, the ``[L, E]`` expert
@@ -235,8 +266,9 @@ def step_programs(cfg, block_size: int, table_width: int, *,
         return jnp.concatenate([tokens.astype(jnp.int32),
                                 counts[0].reshape(-1)])
 
-    def _decode(params, pools, step_in):
+    def _decode(params, pools, step_in, prev, first):
         toks, ctx, tks, bt, temps, tps, key = layout.decode(step_in)
+        toks = layout.lane_tokens(toks, prev, first)
         # toks [B] sit at logical position ctx[b]; after the write the
         # valid length is ctx + 1
         logits, pools, *counts = paged_forward(
@@ -255,6 +287,25 @@ def step_programs(cfg, block_size: int, table_width: int, *,
         return _out(_pick(last, key, temps, tks, tps), counts), pools
 
     return _decode, _prefill
+
+
+def token_words(cfg, lanes: int) -> int:
+    """Length of the int32 vector a device call over ``lanes`` lanes returns
+    (``step_programs``): its tokens, and behind them a dropless mixture's
+    ``[L, E]`` expert counts."""
+    return lanes + (cfg.num_layers * cfg.moe_experts
+                    if cfg.moe_is_dropless else 0)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _blank_outputs(weight, decode_words: int, prefill_words: int):
+    """Zeros in the shapes of a decode and a prefill call's outputs, made by
+    a program that reads a weight (and adds nothing of it): what a serving
+    program's own outputs are on that weight's device and under its kind of
+    sharding, before there is one."""
+    return (jnp.zeros((decode_words,), jnp.int32)
+            + 0 * weight.ravel()[:1].astype(jnp.int32),
+            jnp.zeros((prefill_words,), jnp.int32))
 
 
 @dataclass
@@ -276,45 +327,91 @@ class _Prefilled:
     blocks: List[int]
     table: np.ndarray                  # [max_blocks_per_seq] i32 physical ids
     ctx: int                           # tokens whose K/V is in the pool
-    last_tok: int                      # sampled, not yet written back
+    #: sampled, not yet written back; ``StepLayout.FROM_PREFILL`` while the
+    #: prefill call's output is all that holds it
+    last_tok: int
 
 
 class _Lanes:
     """The decode lanes' state on the host, kept between steps IN the
     buffer the decode program gets (:meth:`StepLayout.decode`'s views of
     it): a row is written when a sequence is installed and when its lane is
-    freed, the whole is advanced in bulk after a step, and a step's build is
-    one copy of the buffer. An idle lane reads token 0, context 0, greedy,
-    an all-null table (its write sinks into the null block)."""
+    freed, the whole is advanced in bulk when a call is launched, and a
+    call's build is one copy of the buffer. An idle lane reads token 0,
+    context 0, greedy, an all-null table (its write sinks into the null
+    block). ``left`` counts, a lane, the tokens that calls not yet launched
+    still owe it: the host knows a lane's last call before it is made."""
 
     def __init__(self, layout: StepLayout, lanes: int):
+        self.layout = layout
         self.buf = np.zeros((layout.decode_words(lanes),), np.int32)
         (self.toks, self.ctx, self.tks, self.tables, self.temps, self.tps,
          _) = layout.decode(self.buf)
         self.tables[:] = NULL_BLOCK
         self.tps[:] = 1.0
         self.live = np.zeros((lanes,), bool)
+        self.left = np.zeros((lanes,), np.int32)
 
-    def write(self, i: int, seq: _Prefilled) -> None:
+    def write(self, i: int, seq: _Prefilled, left: int) -> None:
         req = seq.req
         self.toks[i], self.ctx[i] = seq.last_tok, seq.ctx
         self.tables[i] = seq.table
         self.temps[i] = req.temperature
         self.tks[i] = req.top_k or 0
         self.tps[i] = 1.0 if req.top_p is None else req.top_p
-        self.live[i] = True
+        self.live[i], self.left[i] = True, left
 
     def clear(self, i: int) -> None:
-        self.toks[i] = self.ctx[i] = self.tks[i] = 0
+        self.toks[i] = self.ctx[i] = self.tks[i] = self.left[i] = 0
         self.tables[i] = NULL_BLOCK
         self.temps[i], self.tps[i] = 0.0, 1.0
         self.live[i] = False
 
-    def advance(self, fetched: np.ndarray) -> None:
-        """After a decode step: every live lane wrote one token and reads
-        the one just sampled for it next (an idle lane keeps token 0)."""
-        np.add(self.ctx, self.live, out=self.ctx)
-        np.multiply(fetched, self.live, out=self.toks)
+    def next_call(self) -> np.ndarray:
+        """The lanes the next decode call computes: those a token is still
+        owed that no launched call produces. A lane whose last token is in
+        flight keeps its slot and stays out."""
+        return self.live & (self.left > 0)
+
+    def launch(self, go: np.ndarray) -> np.ndarray:
+        """The buffer of a decode call over the lanes ``go`` (the copy is
+        what the device call owns; a live lane left out reads idle in it),
+        and the lanes' state moved past that call: each lane of ``go`` will
+        have written one token more and reads its next from the call's own
+        output, which only the device holds yet."""
+        step_in = self.buf.copy()
+        out = self.live & ~go
+        if out.any():
+            toks, ctx, tks, tables, temps, tps, _ = self.layout.decode(
+                step_in)
+            toks[out] = ctx[out] = tks[out] = 0
+            tables[out] = NULL_BLOCK
+            temps[out], tps[out] = 0.0, 1.0
+        np.add(self.ctx, go, out=self.ctx)
+        np.subtract(self.left, go, out=self.left)
+        self.toks[go] = StepLayout.FROM_DECODE
+        return step_in
+
+
+@dataclass
+class _ChunkOut:
+    """The prefill chunk a step launched, until the step fetches it: its
+    output on the device and its call's number; of a prompt's LAST chunk
+    also the sequence, and the lane it was staged in (None: none)."""
+    out: Any
+    call: int
+    seq: Optional[_Prefilled] = None
+    slot: Optional[int] = None
+
+
+@dataclass
+class _InFlight:
+    """A decode call launched and not yet fetched: its output on the device,
+    the lanes whose token of it is still wanted (a lane freed meanwhile is
+    struck out), and the call's number."""
+    out: Any
+    go: np.ndarray
+    call: int
 
 
 class _HeldBlocks:
@@ -427,9 +524,10 @@ class ServingEngine:
             # never materialize a full-precision weight copy
             from ..ops.pallas.quant_matmul import pack_decode_weights
             self.params = pack_decode_weights(self.params)
-        # a dropless MoE model only: the outputs of calls whose tokens
-        # nobody fetched (a prompt's middle chunks) stay on the device with
-        # their expert counts and ride the next fetch
+        # a dropless MoE model only: the outputs of calls nobody fetched
+        # (the disagg prefill role's middle chunks) stay on the device with
+        # their expert counts, (call number, output), and ride the next
+        # fetch of a later call
         self._moe_pending: List[Any] = []
         if cfg.moe_is_dropless:
             self.stats.update(dict.fromkeys(_MOE_COUNTERS, 0))
@@ -461,6 +559,22 @@ class ServingEngine:
         self._layout = StepLayout(self.nbk, self._key.size)
         self._lanes = _Lanes(self._layout, self.max_batch)
         self._calls = 0                    # device calls made so far
+        # the decode call launched and not yet fetched, and this step's
+        # chunk until it is fetched: both behind the launch of the next
+        # decode call
+        self._flight: Optional[_InFlight] = None
+        self._chunk_out: Optional[_ChunkOut] = None
+        # what the decode program reads its lanes' tokens from when the host
+        # never held them: the outputs of the last decode call and of the
+        # last final prefill call, as they came off the device. Until there
+        # is one, zeros that a program made FROM THE WEIGHTS, as those two
+        # make their outputs: placed and committed the way theirs will be
+        # (on the weights' device, under the weights' kind of sharding), so
+        # that the first decode call and every later one are ONE
+        # specialization
+        self._dec_out, self._pre_out = _blank_outputs(
+            jax.tree_util.tree_leaves(self.params)[0],
+            token_words(cfg, self.max_batch), token_words(cfg, 1))
         self._prefill_shapes: set = set()  # query rows of the prefill calls
         self._heartbeat = heartbeat
         self._watchdog = None
@@ -518,23 +632,28 @@ class ServingEngine:
             self._key.tolist(), spawn_key=(call,)).generate_state(
                 self._key.size, np.uint32)
 
-    def _call_device(self, fn, step_in: np.ndarray):
+    def _call_device(self, fn, step_in: np.ndarray, *on_device):
         """One device call of the loop: number it, write its key into the
         buffer's last words (:class:`StepLayout`) and hand the program its
-        one host array as it is, for the jitted call to transfer."""
+        one host array as it is, for the jitted call to transfer
+        (``on_device``: what the decode program reads where it lies)."""
         self._calls += 1
         step_in[-self._key.size:] = self._call_key(self._calls).view(np.int32)
         self.stats["step_inputs.transfers_sum"] += 1
-        return self._run_device(fn, step_in)
+        return self._run_device(fn, step_in, *on_device)
 
-    def _count_experts(self, out: np.ndarray) -> None:
-        """A dropless MoE model's router load, from a fetched output: the
-        call's ``[L, E]`` expert counts sit behind its tokens
-        (``step_programs``). Those of the calls nobody fetched are counted
-        with it: they were made before ``out``, so fetching them waits for
-        nothing more."""
-        got = [out] + [np.asarray(a) for a in self._moe_pending]
-        self._moe_pending = []
+    def _count_experts(self, out: np.ndarray, call: int) -> None:
+        """A dropless MoE model's router load, from the fetched output of
+        device call number ``call``: its ``[L, E]`` expert counts sit behind
+        its tokens (``step_programs``). Those of the calls nobody fetched
+        that were launched before it are counted with it: the device runs
+        calls in the order of their launch, so fetching them waits for
+        nothing more (a chunk launched behind a decode call in flight waits
+        for the next fetch)."""
+        pending = self._moe_pending
+        n = sum(1 for at, _ in pending if at < call)
+        got = [out] + [np.asarray(a) for _, a in pending[:n]]
+        del pending[:n]
         E, c = self.cfg.moe_experts, self.stats
         for a in got:
             counts = a[len(a) - self.cfg.num_layers * E:].reshape(-1, E)
@@ -598,14 +717,16 @@ class ServingEngine:
 
     @property
     def idle(self) -> bool:
+        # a call in flight is work: the step that fetches it is still due
         return (self.active == 0 and self.scheduler.pending == 0
-                and self._prefilling is None)
+                and self._prefilling is None and self._flight is None)
 
     @property
     def has_work(self) -> bool:
         """Would a :meth:`step` make progress? (fleet worker pacing)."""
         return bool(self.active or self.scheduler.pending
-                    or self._prefilling is not None)
+                    or self._prefilling is not None
+                    or self._flight is not None)
 
     @property
     def wants_dispatch(self) -> bool:
@@ -625,8 +746,20 @@ class ServingEngine:
         if not self._lock.acquire(timeout=timeout):
             return None
         try:
+            # the call in flight is dropped unread: the requests resume from
+            # prompt + emitted. Its K/V rows fall into blocks listed below;
+            # whoever gets them next launches later (_finish)
+            self._retire(book=False)
             blocks: List[List[int]] = []
             reqs: List[Request] = []
+            chunk, self._chunk_out = self._chunk_out, None
+            # (a step died between a chunk's launch and its fetch) a staged
+            # lane is among the slots, a middle chunk's prompt is the one in
+            # prefill
+            if chunk is not None and chunk.seq is not None \
+                    and chunk.slot is None:
+                blocks.append(chunk.seq.blocks)
+                reqs.append(chunk.seq.req)
             if self._prefilling is not None:
                 blocks.append(self._prefilling.blocks)
                 reqs.append(self._prefilling.req)
@@ -654,12 +787,15 @@ class ServingEngine:
         contract (tokens decoded but never synced are dropped and
         regenerated identically under greedy). Only a RUNNING lane is
         preemptible: an in-flight prefill is about to finish paying for
-        its blocks and evicting it frees no lane. Returns False when the
+        its blocks and evicting it frees no lane. The decode call in
+        flight is retired first, its tokens booked: the request may finish
+        by them, and then holds no lane. Returns False when the
         request holds no lane here or the lock cannot be taken within
         ``timeout`` (a step in flight — the caller retries next poll)."""
         if not self._lock.acquire(timeout=timeout):
             return False
         try:
+            self._retire()
             for i, s in enumerate(self._slots):
                 if s is not None and s.req is req:
                     self._vacate(i)
@@ -686,12 +822,15 @@ class ServingEngine:
         then one fixed-shape decode step over the active set — so with
         ``serving.prefill_chunk_tokens > 0`` running lanes emit a token
         every iteration even while a long prompt prefills (the fairness
-        bound tests pin). Returns requests completed this iteration."""
+        bound tests pin). The decode step LAUNCHES the next call before it
+        fetches the one the last iteration launched
+        (:meth:`_decode_step`): an iteration returns with the tokens of the
+        call it retired appended, and with one call in flight wherever a
+        lane goes on. Returns requests completed this iteration."""
         with self._lock, self._step_span():
             done = self._admit()
-            done += self._advance_prefill()
-            if self.active:
-                done += self._decode_step()
+            self._advance_prefill()
+            done += self._decode_step()
             self.steps += 1
             self.stats["timeout"] = self.scheduler.timed_out
             self._stamp_heartbeat()
@@ -710,8 +849,11 @@ class ServingEngine:
             c["steps_with_queue"] += 1
             c["queue_len_sum"] += queued
         c["lane_sum"] += self.active
-        # an idle lane's context reads 0
+        # an idle lane's context reads 0; a lane of the call in flight is
+        # one ahead of what the host has booked
         written = int(self._lanes.ctx.sum())
+        if self._flight is not None:
+            written -= int(np.count_nonzero(self._flight.go))
         if self._prefilling is not None:
             written += self._prefilling.done
         # distinct blocks: a forked prefix is held once however many
@@ -735,20 +877,30 @@ class ServingEngine:
 
     def _place(self, slot: int, seq: _Prefilled) -> None:
         """A sequence takes a decode lane: its row of the lane arrays is
-        written once, here, and only advanced after that."""
-        seq.req.state = RUNNING
-        self._slots[slot] = _Seq(seq.req, seq.blocks)
-        self._lanes.write(slot, seq)
+        written once, here, and only advanced after that. Its first token
+        is among its outputs already, or still on the device
+        (``FROM_PREFILL``) and owed like the rest."""
+        req = seq.req
+        req.state = RUNNING
+        self._slots[slot] = _Seq(req, seq.blocks)
+        self._lanes.write(slot, seq, req.max_new_tokens
+                          - len(req.output_tokens) - (seq.last_tok < 0))
         self._held.add(seq.blocks)
         self.stats["step_inputs.lane_rows_written_sum"] += 1
 
     def _vacate(self, slot: int) -> None:
         """A lane is freed (finished, preempted, collected): its row reads
-        idle again. The blocks' release is the caller's."""
+        idle again. The blocks' release is the caller's. A lane of the call
+        in flight (an end the host could not count ahead: EOS) is struck
+        out of it: the call computes the lane once more and nobody reads
+        that token."""
         self._held.drop(self._slots[slot].blocks)
         self._slots[slot] = None
         self._lanes.clear(slot)
         self.stats["step_inputs.lane_rows_written_sum"] += 1
+        if self._flight is not None and self._flight.go[slot]:
+            self._flight.go[slot] = False
+            self.stats["decode_ahead.wasted_lane_tokens"] += 1
 
     def telemetry(self) -> Dict[str, Any]:
         """The recorder's snapshot: counters (``stats`` and, with a shared
@@ -761,7 +913,8 @@ class ServingEngine:
         return snap
 
     def run_until_idle(self, max_steps: int = 100_000) -> None:
-        """Drive the loop until queue and lanes drain (tests, batch use)."""
+        """Drive the loop until queue and lanes drain (tests, batch use)
+        and no call is in flight (``idle``)."""
         for _ in range(max_steps):
             if self.idle:
                 return
@@ -817,6 +970,13 @@ class ServingEngine:
         return self._watchdog
 
     def close(self) -> None:
+        # the call in flight is dropped unread (a loop wedged inside a step
+        # keeps its lock and its call)
+        if self._lock.acquire(timeout=1.0):
+            try:
+                self._retire(book=False)
+            finally:
+                self._lock.release()
         if self._watchdog is not None:
             self._watchdog.stop()
             self._watchdog = None
@@ -955,21 +1115,22 @@ class ServingEngine:
         return _Prefilling(req, blocks, table, done=n_pref,
                            total=len(req.prompt))
 
-    def _advance_prefill(self) -> int:
+    def _advance_prefill(self) -> None:
         """Run AT MOST one chunk of the in-flight chunked prefill (the
         ``serve.chunk`` failpoint fires per chunk). On the final chunk
         the next token is sampled from the last real position's logits
-        and the sequence is installed — into a decode lane here, into
-        the block handoff for a disagg prefill role."""
+        and the sequence is staged (:meth:`_stage`: a decode lane here,
+        its token still on the device); the token is fetched behind the
+        launch of the next decode call (:meth:`_take_chunk`)."""
         pf = self._prefilling
         if pf is None:
-            return 0
+            return
         req = pf.req
         n = (pf.total - pf.done if self._chunk <= 0
              else min(self._chunk, pf.total - pf.done))
         with self.rec.span("serve.prefill", rid=req.rid, tokens=n,
                            final=int(pf.done + n >= pf.total)):
-            return self._prefill_chunk(pf, n)
+            self._prefill_chunk(pf, n)
 
     def _prefill_inputs(self, req: Request, toks: Sequence[int], table,
                         q0: int) -> np.ndarray:
@@ -1013,7 +1174,7 @@ class ServingEngine:
         paths = self.rec.gauges.setdefault("paged.prefill_path", {})
         paths.setdefault(f"{path}: {why}" if why else path, []).append(Tb)
 
-    def _prefill_chunk(self, pf: _Prefilling, n: int) -> int:
+    def _prefill_chunk(self, pf: _Prefilling, n: int) -> None:
         req, rec = pf.req, self.rec
         with rec.span("serve.prefill.build"):
             step_in = self._prefill_inputs(
@@ -1043,32 +1204,76 @@ class ServingEngine:
         req.prefill_progress = pf.done
         self.stats["prefill_tokens"] += n
         if pf.done < pf.total:
-            if self.cfg.moe_is_dropless:
-                self._moe_pending.append(tok)
-            return 0                      # sampled token of a mid-chunk
-            #                               call is discarded — only the
+            self._mid_chunk(tok)          # sampled token of a mid-chunk
+            return                        # call is discarded — only the
             #                               final chunk's is real
         self._set_prefilling(None)
-        with rec.span("serve.prefill.fetch"):
-            first = int(self._fetch(tok)[0])
-        return self._first_token(_Prefilled(req, pf.blocks, pf.table,
-                                            pf.total, first),
+        seq = _Prefilled(req, pf.blocks, pf.table, pf.total,
+                         StepLayout.FROM_PREFILL)
+        self._pre_out = tok
+        with rec.span("serve.prefill.install"):
+            self._chunk_out = _ChunkOut(tok, self._calls, seq,
+                                        self._stage(seq))
+
+    def _mid_chunk(self, tok) -> None:
+        """A prompt's middle chunk is launched: the step waits for it behind
+        the launch of the next decode call, as it waits for a last chunk's
+        first token. A step then returns when all it launched but that
+        decode call has run, and the tokens of one step come a decode call,
+        or a decode call and ONE chunk, after those of the step before:
+        never two chunks, which a step that returned at the decode call's
+        end followed by one that waits for its chunk would put between them
+        (PERF.md, PR 40). The host has the next call's time for the next
+        step's work either way. (The disagg prefill role waits for no
+        middle chunk: it has no lane to keep in step.)"""
+        self._chunk_out = _ChunkOut(tok, self._calls)
+
+    def _stage(self, seq: _Prefilled) -> Optional[int]:
+        """A prompt's last chunk is launched and its first token is on the
+        device: a request that goes on takes its decode lane now, its token
+        "from the prefill call", and is in the decode call this step
+        launches. Returns the lane (None: nothing staged; the disagg prefill
+        role hands over a token the host has read)."""
+        if seq.req.max_new_tokens <= 1:
+            return None
+        slot = self._free_slot()
+        self._place(slot, seq)
+        return slot
+
+    def _take_chunk(self) -> int:
+        """Fetch what this step's chunk left on the device, behind the
+        launch of the next decode call: a last chunk's first token, which
+        is booked; of a middle chunk (:meth:`_mid_chunk`) its end. Returns
+        requests finished."""
+        chunk, self._chunk_out = self._chunk_out, None
+        if chunk is None:
+            return 0
+        with self.rec.span("serve.prefill.fetch"):
+            first = int(self._fetch(chunk.out, chunk.call)[0])
+        if chunk.seq is None:
+            return 0
+        chunk.seq.last_tok = first
+        return self._first_token(chunk.seq, chunk.slot,
                                  insert=not self._warming)
 
-    def _fetch(self, out) -> np.ndarray:
-        """A device call's tokens: the step's one fetch (a dropless
-        mixture's expert counts ride behind them and are counted here)."""
+    def _fetch(self, out, call: int) -> np.ndarray:
+        """The tokens of device call number ``call`` or of one before it
+        (a dropless mixture's expert counts ride behind them and are
+        counted here)."""
         out = np.asarray(out)
         if self.cfg.moe_is_dropless:
-            self._count_experts(out)
+            self._count_experts(out, call)
             out = out[:len(out) - self.cfg.num_layers * self.cfg.moe_experts]
         return out
 
-    def _first_token(self, seq: _Prefilled, insert: bool = True) -> int:
+    def _first_token(self, seq: _Prefilled, slot: Optional[int] = None,
+                     insert: bool = True) -> int:
         """A prompt's last chunk is in the pool and its first token
         fetched: stamp it, register the prompt's full blocks with the
-        prefix cache, and install the sequence (or finish a one-token
-        request). Returns requests finished."""
+        prefix cache, and install the sequence unless ``slot`` says it holds
+        a lane already (or finish a request that ends with this token; a
+        staged one gives its lane back and is computed once for nothing).
+        Returns requests finished."""
         req, rec = seq.req, self.rec
         req.first_token_ts = time.monotonic()
         rec.event("serve.req.first_token", rid=req.rid,
@@ -1081,18 +1286,25 @@ class ServingEngine:
             with rec.span("serve.prefill.prefix_insert"):
                 self.prefix_cache.insert(
                     req.prompt, seq.blocks[:seq.ctx // self.block_size])
+        ended = req.max_new_tokens <= 1 or (
+            req.eos_token_id is not None
+            and seq.last_tok == req.eos_token_id)
+        if slot is not None:
+            if ended:
+                self._vacate(slot)
+                self._finish(seq)
+            return int(ended)
         with rec.span("serve.prefill.install"):
-            if req.max_new_tokens <= 1 or (req.eos_token_id is not None
-                                           and seq.last_tok
-                                           == req.eos_token_id):
+            if ended:
                 self._finish(seq)
                 return 1
             self._install(seq)
             return 0
 
     def _install(self, seq: _Prefilled) -> None:
-        """Place a fully-prefilled sequence where decode will find it —
-        a free lane here; the disagg prefill role hands it off instead."""
+        """Place a fully-prefilled sequence whose first token the host has
+        read where decode will find it — a free lane here (whole prefill);
+        the disagg prefill role hands it off instead."""
         self._place(self._free_slot(), seq)
 
     def _prefill_request(self, req: Request) -> int:
@@ -1125,8 +1337,10 @@ class ServingEngine:
             else:
                 req.state = QUEUED
             raise
+        # whole prefill keeps its fetch inside admission: the lane gets a
+        # token the host has read
         with rec.span("serve.prefill.fetch"):
-            first = int(self._fetch(tok)[0])
+            first = int(self._fetch(tok, self._calls)[0])
         req.prefill_progress = P
         self.stats["prefill_tokens"] += len(suffix)
         return self._first_token(_Prefilled(req, blocks, table, P, first))
@@ -1134,43 +1348,93 @@ class ServingEngine:
     # ---------------------------------------------------------------- decode
 
     def _decode_step(self) -> int:
+        """Launch the next decode call, THEN fetch what is owed to the host:
+        the first token of a prompt whose last chunk this step launched, and
+        the tokens of the decode call the last step launched. The device
+        finds the new call queued when the old one ends, and the completion
+        of the old one, this step's bookkeeping and all of the next step's
+        host path up to its own launch run beside it. Nothing the new call
+        needs is on the host: a lane that goes on reads its token from the
+        old call's output, a staged lane from the prefill call's
+        (:meth:`StepLayout.lane_tokens`), and the host has counted which
+        lanes get their last token from the call in flight
+        (:meth:`_Lanes.next_call`). Returns requests finished."""
+        go, prev = self._lanes.next_call(), self._flight
+        launch = bool(go.any())
+        if prev is None and not launch:
+            return self._take_chunk()
         with self.rec.span("serve.decode", lanes=self.active):
-            return self._decode_lanes()
+            if launch:
+                self._launch(go, ahead=prev is not None)
+            else:
+                self._flight = None
+            done = self._take_chunk()
+            if prev is not None:
+                done += self._book(prev)
+        return done
 
-    def _decode_lanes(self) -> int:
+    def _launch(self, go: np.ndarray, ahead: bool) -> None:
+        """Build and dispatch one decode call over the lanes ``go``; it is
+        in flight from here."""
         B, rec, lanes = self.max_batch, self.rec, self._lanes
         with rec.span("serve.decode.build"):
-            # the pages the paged kernel walks this step (every lane up to
+            # the pages the paged kernel walks this call (every lane up to
             # the token it writes; an idle lane its one null page) against
             # the tables' full width
             rec.count("paged.live_pages_sum",
-                      int((lanes.ctx // self.block_size + 1).sum()))
+                      int((lanes.ctx * go // self.block_size + 1).sum()))
             rec.count("paged.table_pages_sum", B * self.nbk)
-            # the lanes' state is the step's input as it stands; the copy is
-            # what the device call owns
-            step_in = lanes.buf.copy()
+            rec.count("decode_ahead.device_lane_tokens_sum",
+                      int(np.count_nonzero(lanes.toks[go] < 0)))
+            step_in = lanes.launch(go)
         with rec.span("serve.decode.dispatch",
                       program=self._decode_program):
-            nxt = self._call_device(self._decode_fn, step_in)
+            out = self._call_device(self._decode_fn, step_in, self._dec_out,
+                                    self._pre_out)
+        if ahead:
+            self.stats["decode_ahead.launched"] += 1
+        self._dec_out = out
+        self._flight = _InFlight(out, go, self._calls)
+
+    def _book(self, call: _InFlight) -> int:
+        """Fetch a decode call's tokens and book them: a token a lane, and
+        the end of every lane that ends by it."""
+        rec, done = self.rec, 0
         with rec.span("serve.decode.fetch"):
-            nxt = self._fetch(nxt)
-        done = 0
+            toks = self._fetch(call.out, call.call).tolist()
         with rec.span("serve.decode.bookkeep"):
-            live, toks = np.flatnonzero(lanes.live).tolist(), nxt.tolist()
-            lanes.advance(nxt)
+            live = np.flatnonzero(call.go).tolist()
             self.stats["tokens_generated"] += len(live)
             for i in live:
-                req, tok = self._slots[i].req, toks[i]
+                seq = self._slots[i]
+                req, tok = seq.req, toks[i]
                 req.output_tokens.append(tok)
                 if tok == req.eos_token_id \
                         or len(req.output_tokens) >= req.max_new_tokens:
-                    seq = self._slots[i]
                     self._vacate(i)
                     self._finish(seq)
                     done += 1
         return done
 
+    def _retire(self, book: bool = True) -> int:
+        """Whatever reads or moves the lanes outside a step's own order
+        retires the call in flight first: its tokens booked as a step would
+        (``book``), or dropped unread where the lanes are given up anyway
+        (without a wait: the device may be why they are). Returns requests
+        finished."""
+        call, self._flight = self._flight, None
+        if call is None:
+            return 0
+        self.stats["decode_ahead.retired_unread"] += 1
+        return self._book(call) if book else 0
+
     def _finish(self, seq: _Seq) -> None:
+        # the blocks go back at once, though a decode call launched before
+        # the host saw this end (an EOS) may still write the lane's next
+        # K/V row: into a block the sequence held when that call was
+        # launched. Every later writer of that block is launched later, and
+        # the donated pools chain the calls in that order; a decode row
+        # never falls into the full prompt blocks the prefix cache retains
         self.pool.release(seq.blocks)
         self.stats["completed"] += 1
         seq.req._finish(FINISHED)

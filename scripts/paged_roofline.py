@@ -13,7 +13,7 @@ for the experts:
   ran inside each ``ds/serve.step``;
 * the program's ring: the step's gains of ``paged.live_pages_sum`` (the pages
   the kernel walks) and ``paged.table_pages_sum`` (what the tables could
-  hold), counted in ``serving/engine.py:_decode_lanes``.
+  hold), counted in ``serving/engine.py:_launch``.
 
 A live page is ``block_size x stored heads x head_dim`` elements of K and of
 V, a layer; the kernel is bound by reading them (eight query rows a head: the

@@ -24,7 +24,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from deepspeed_tpu.utils import telemetry  # noqa: E402
 
-#: the counters a serving engine of a dropless mixture holds (40)
+#: the counters a serving engine of a dropless mixture holds (44)
 COUNTERS = (
     "completed failed timeout tokens_generated prefill_tokens "
     "prefix_hit_tokens preempted steps steps_with_queue queue_len_sum "
@@ -33,7 +33,9 @@ COUNTERS = (
     "kv.blocks_reserved_sum kv.tokens_written_sum prefix.prompt_tokens "
     "paged.live_pages_sum paged.table_pages_sum paged.chunk_live_pages_sum "
     "paged.chunk_table_pages_sum step_inputs.transfers_sum "
-    "step_inputs.lane_rows_written_sum kv.alloc kv.release kv.exhausted "
+    "step_inputs.lane_rows_written_sum decode_ahead.launched "
+    "decode_ahead.device_lane_tokens_sum decode_ahead.wasted_lane_tokens "
+    "decode_ahead.retired_unread kv.alloc kv.release kv.exhausted "
     "prefix.lookups prefix.inserted_entries prefix.evicted_entries "
     "prefix.evict_scanned_entries moe.assignments moe.layer_steps "
     "moe.load_max_over_mean_sum moe.experts_idle_sum compile.trace_us "
@@ -96,19 +98,23 @@ def one_step(rec, step: int, chunk: bool) -> None:
                 with rec.span("serve.prefill.dispatch",
                               program="jit__prefill"):
                     c["step_inputs.transfers_sum"] += 1
-                with rec.span("serve.prefill.fetch"):
-                    pass
-                rec.event("serve.req.first_token", rid=step, ts=3.0)
-                with rec.span("serve.prefill.prefix_insert"):
-                    c["prefix.inserted_entries"] += 8
                 with rec.span("serve.prefill.install"):
                     c["step_inputs.lane_rows_written_sum"] += 1
         with rec.span("serve.decode", lanes=28):
             with rec.span("serve.decode.build"):
                 rec.count("paged.live_pages_sum", 277)
                 rec.count("paged.table_pages_sum", 1280)
+                rec.count("decode_ahead.device_lane_tokens_sum", 28)
             with rec.span("serve.decode.dispatch", program="jit__decode"):
                 c["step_inputs.transfers_sum"] += 1
+            c["decode_ahead.launched"] += 1
+            if chunk:
+                # the chunk's first token, behind the launch (PR 40)
+                with rec.span("serve.prefill.fetch"):
+                    pass
+                rec.event("serve.req.first_token", rid=step, ts=3.0)
+                with rec.span("serve.prefill.prefix_insert"):
+                    c["prefix.inserted_entries"] += 8
             with rec.span("serve.decode.fetch"):
                 pass
             with rec.span("serve.decode.bookkeep"):

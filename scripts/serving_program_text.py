@@ -62,7 +62,8 @@ def programs(name: str, config, serving, *, int8: bool, chip):
     from deepspeed_tpu.models import TransformerConfig, build_model
     from deepspeed_tpu.models.generation import ensure_scan_layout
     from deepspeed_tpu.ops.pallas.quant_matmul import pack_decode_weights
-    from deepspeed_tpu.serving.engine import StepLayout, step_programs
+    from deepspeed_tpu.serving.engine import (StepLayout, step_programs,
+                                              token_words)
     from deepspeed_tpu.serving.kv_cache import init_pool
 
     family = harness.load_family(config["family"])
@@ -86,11 +87,14 @@ def programs(name: str, config, serving, *, int8: bool, chip):
         jnp.int8 if int8 else jnp.bfloat16)))
     decode, prefill = step_programs(cfg, bs, nbk)
     layout = StepLayout(nbk)
-    calls = {"decode": (decode, layout.decode_words(lanes)),
-             f"prefill{chunk}": (prefill, layout.prefill_words(chunk))}
-    for label, (fn, words) in calls.items():
+    # the decode program reads, beside its buffer, the previous decode
+    # call's and the last final prefill call's outputs where they lie
+    calls = {"decode": (decode, layout.decode_words(lanes),
+                        [token_words(cfg, lanes), token_words(cfg, 1)]),
+             f"prefill{chunk}": (prefill, layout.prefill_words(chunk), [])}
+    for label, (fn, words, fed) in calls.items():
         lowered = jax.jit(fn, donate_argnums=(1,)).lower(
-            params, pools, chip((words,), jnp.int32))
+            params, pools, *(chip((n,), jnp.int32) for n in [words] + fed))
         yield (f"{name}{'-int8' if int8 else ''}.{label}",
                lowered.as_text(debug_info=False))
 

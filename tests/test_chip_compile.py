@@ -295,7 +295,8 @@ def test_serving_step_updates_the_pool_in_place(chip, monkeypatch, program,
     (PERF.md, PR 25)."""
     from deepspeed_tpu.models import TransformerConfig, build_model
     from deepspeed_tpu.models.generation import ensure_scan_layout
-    from deepspeed_tpu.serving.engine import StepLayout, step_programs
+    from deepspeed_tpu.serving.engine import (StepLayout, step_programs,
+                                              token_words)
     from deepspeed_tpu.serving.kv_cache import init_pool
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     L, NH, HD, BS, NB, B, NBK = 16, 32, 128, 32, 384, 32, 40
@@ -317,10 +318,13 @@ def test_serving_step_updates_the_pool_in_place(chip, monkeypatch, program,
     decode, prefill = step_programs(cfg, BS, NBK)
     if program == "decode":
         fn, words = decode, StepLayout(NBK).decode_words(B)
+        # the previous decode call's and the last prefill call's outputs,
+        # where the program finds the tokens the host never held (PR 40)
+        fed = [chip((token_words(cfg, n),), jnp.int32) for n in (B, 1)]
     else:
-        fn, words = prefill, StepLayout(NBK).prefill_words(256)
+        fn, words, fed = prefill, StepLayout(NBK).prefill_words(256), []
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(
-        params, pools, chip((words,), jnp.int32)).compile()
+        params, pools, chip((words,), jnp.int32), *fed).compile()
     text = compiled.as_text()
 
     layer = NH * NB * BS * HD
@@ -364,7 +368,8 @@ def test_olmoe_serving_step_is_dropless_and_in_place(chip, monkeypatch,
     tokens in one int32 vector; and the pool is still updated in place."""
     from deepspeed_tpu.models import TransformerConfig, build_model
     from deepspeed_tpu.models.generation import ensure_scan_layout
-    from deepspeed_tpu.serving.engine import StepLayout, step_programs
+    from deepspeed_tpu.serving.engine import (StepLayout, step_programs,
+                                              token_words)
     from deepspeed_tpu.serving.kv_cache import init_pool
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     L, NH, HD, BS, NB, B, NBK, E, K, M = 8, 16, 128, 32, 2048, 64, 128, 64, \
@@ -388,10 +393,13 @@ def test_olmoe_serving_step_is_dropless_and_in_place(chip, monkeypatch,
     decode, prefill = step_programs(cfg, BS, NBK)
     if program == "decode":
         fn, words = decode, StepLayout(NBK).decode_words(B)
+        # the previous decode call's and the last prefill call's outputs,
+        # where the program finds the tokens the host never held (PR 40)
+        fed = [chip((token_words(cfg, n),), jnp.int32) for n in (B, 1)]
     else:
-        fn, words = prefill, StepLayout(NBK).prefill_words(256)
+        fn, words, fed = prefill, StepLayout(NBK).prefill_words(256), []
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(
-        params, pools, chip((words,), jnp.int32)).compile()
+        params, pools, chip((words,), jnp.int32), *fed).compile()
     text = compiled.as_text()
     out, _ = compiled.out_info               # the tokens, then the counts
     assert out.shape == (lanes + L * E,) and out.dtype == jnp.int32

@@ -518,6 +518,46 @@ def test_decode_role_warm_and_served_batch_share_one_program(tiny):
     dis.close()
 
 
+def test_decode_role_launches_ahead_and_a_warm_retires_the_call(tiny):
+    """The decode role launches its next call before it fetches the one in
+    flight, over lanes that arrive by handoff with a token the host has read
+    (they join the next call as they are: a free lane is none of the call in
+    flight's); a ``warm()`` in mid-run retires the call in flight, its
+    tokens booked, and leaves the loop's two device arrays alone, so the
+    lanes go on token for token; ONE decode program through all of it."""
+    cfg, params = tiny
+    dis = DisaggEngine(cfg, params, serving=dict(SERVE_CFG,
+                                                 prefill_chunk_tokens=10))
+    dec = dis.decode
+    rng = np.random.default_rng(12)
+    sizes = ((9, 14), (25, 9), (14, 11), (31, 7), (5, 12), (18, 4))
+    prompts = [rng.integers(1, 64, size=n).tolist() for n, _ in sizes]
+    reqs = [dis.submit(p, m) for p, (_, m) in zip(prompts, sizes)]
+    while dec.active < 2 or dec._flight is None:
+        dis.step()
+    booked = sum(len(r.output_tokens) for r in reqs)
+    fed = dec._dec_out
+    dec.warm()
+    assert dec._flight is None and dec._dec_out is fed
+    assert sum(len(r.output_tokens) for r in reqs) == booked + dec.active
+    assert dec.stats["decode_ahead.retired_unread"] == 1
+    dis.run_until_idle()
+    for p, (_, m), r in zip(prompts, sizes, reqs):
+        assert r.output_tokens == _oracle_tokens(cfg, params, p, m)
+    calls = sum(1 for e in dec.rec.ring if e[0] == "serve.decode.dispatch")
+    c = dec.stats
+    # all but the first call, the one behind the warm, and one wherever the
+    # lanes ran dry before the next handoff came
+    assert calls - 4 <= c["decode_ahead.launched"] <= calls - 2
+    assert c["decode_ahead.wasted_lane_tokens"] == 0
+    # every lane's FIRST input is the handoff's host token, the rest come
+    # off the device
+    assert c["decode_ahead.device_lane_tokens_sum"] == \
+        c["tokens_generated"] - len(reqs)
+    assert dec._flight is None and dec._decode_fn._cache_size() == 1
+    dis.close()
+
+
 def test_handoff_writes_the_decode_roles_lane_rows(tiny):
     """Across the handoff the decode role's lane state is written where a
     popped item takes a lane and cleared where it finishes: every decode

@@ -288,7 +288,8 @@ def test_no_capacity_tensor_in_the_serving_programs():
     B, T, E, k = 5, 32, 12, 4
     progs = {
         "decode": (B, srv._decode_fn, (srv.params, srv.pools,
-                                       srv._lanes.buf)),
+                                       srv._lanes.buf, srv._dec_out,
+                                       srv._pre_out)),
         "prefill": (T, srv._prefill_fn, (srv.params, srv.pools, np.zeros(
             (srv._layout.prefill_words(T),), np.int32)))}
     for name, (tok, fn, args) in progs.items():
@@ -309,7 +310,8 @@ def test_no_capacity_tensor_in_the_serving_programs():
 def test_a_dense_configs_programs_and_counters_are_what_they_were():
     """The fence, as far as the CPU can hold it: for a config that is not a
     dropless mixture ``step_programs`` hands out the two programs under the
-    one signature (params, pools, the call's one int32 buffer), each
+    one signature (params, pools, the call's one int32 buffer; the decode
+    program since PR 40 the two token vectors it reads on the device), each
     returning the tokens ``[lanes]`` and the pools and nothing else; the
     engine keeps no ``moe.`` counter and no pending counts; and what it
     counts is the list it counted before this file, and since PR 31 the two
@@ -325,7 +327,7 @@ def test_a_dense_configs_programs_and_counters_are_what_they_were():
     decode, prefill = eng.step_programs(cfg, 16, 4)
     assert decode.__name__ == "_decode" and prefill.__name__ == "_prefill"
     assert list(inspect.signature(decode).parameters) == [
-        "params", "pools", "step_in"]
+        "params", "pools", "step_in", "prev", "first"]
     assert list(inspect.signature(prefill).parameters) == [
         "params", "pools", "step_in"]
     params = make_params(model, cfg, seed=3, dtype=jnp.float32)
@@ -334,8 +336,10 @@ def test_a_dense_configs_programs_and_counters_are_what_they_were():
         "max_blocks_per_seq": 4, "prefill_chunk_tokens": 32})
     B, T, layout = 5, 32, eng.StepLayout(4)
     assert srv.nbk == 4 and srv._lanes.buf.shape == (layout.decode_words(B),)
+    assert srv._dec_out.shape == (eng.token_words(cfg, B),) == (B,)
+    assert srv._pre_out.shape == (eng.token_words(cfg, 1),) == (1,)
     tok, pools = jax.eval_shape(decode, srv.params, srv.pools,
-                                srv._lanes.buf)
+                                srv._lanes.buf, srv._dec_out, srv._pre_out)
     assert tok.shape == (B,) and set(pools) == set(srv.pools)
     tok, pools = jax.eval_shape(
         prefill, srv.params, srv.pools,
@@ -351,7 +355,9 @@ def test_a_dense_configs_programs_and_counters_are_what_they_were():
         "prefix.prompt_tokens", "paged.live_pages_sum",
         "paged.table_pages_sum", "paged.chunk_live_pages_sum",
         "paged.chunk_table_pages_sum", "step_inputs.transfers_sum",
-        "step_inputs.lane_rows_written_sum")
+        "step_inputs.lane_rows_written_sum", "decode_ahead.launched",
+        "decode_ahead.device_lane_tokens_sum",
+        "decode_ahead.wasted_lane_tokens", "decode_ahead.retired_unread")
     srv.submit(list(tokens(40, 5)), max_new_tokens=4)
     srv.run_until_idle()
     assert srv.stats["completed"] == 1

@@ -851,7 +851,8 @@ def test_step_programs_hold_no_pool_scatter_on_the_cpu(tiny, kv):
         SERVE_CFG, **({"kv_cache_dtype": "int8"} if kv == "int8" else {})))
     programs = {
         "decode": eng._decode_fn.lower(eng.params, eng.pools,
-                                       eng._lanes.buf),
+                                       eng._lanes.buf, eng._dec_out,
+                                       eng._pre_out),
         "prefill": eng._prefill_fn.lower(
             eng.params, eng.pools,
             np.zeros((eng._layout.prefill_words(32),), np.int32)),
@@ -880,19 +881,33 @@ def _lane_ctx(s):
     return len(s.req.prompt) + len(s.req.output_tokens) - 1
 
 
-def _per_lane_decode_build(srv):
+def _per_lane_decode_build(srv, fed):
     """The parent's build of a decode step's inputs (engine.py before PR 31,
     ``_decode_lanes``): fresh arrays, a Python loop over the lanes, every
-    fact read from the requests and the lanes' block lists."""
-    B = srv.max_batch
+    fact read from the requests and the lanes' block lists. Since PR 40 the
+    call is launched before the one in flight (``srv._flight`` at that
+    moment) is booked: a lane of that call stands one token further than its
+    request's outputs say, a lane staged by a prompt's last chunk has no
+    output yet, a lane whose last token either of them brings is left out,
+    and a lane reads its token from the device if the host never held it:
+    ``fed`` are the ``(lane, rid)`` of the previous decode call."""
+    B, layout = srv.max_batch, srv._layout
     toks, ctx = np.zeros((B,), np.int32), np.zeros((B,), np.int32)
     temps, tks = np.zeros((B,), np.float32), np.zeros((B,), np.int32)
     tps = np.ones((B,), np.float32)
     tables = np.zeros((B, srv.nbk), np.int32)            # NULL_BLOCK
+    flying = srv._flight.go if srv._flight is not None else np.zeros(B, bool)
     for i, s in enumerate(srv._slots):
         if s is None:
             continue
-        toks[i], ctx[i] = s.req.output_tokens[-1], _lane_ctx(s)
+        staged = not s.req.output_tokens
+        unbooked = int(staged) + int(flying[i])
+        if s.req.max_new_tokens - len(s.req.output_tokens) - unbooked <= 0:
+            continue                       # its last token is on its way
+        toks[i] = (layout.FROM_DECODE if (i, s.req.rid) in fed
+                   else layout.FROM_PREFILL if staged
+                   else s.req.output_tokens[-1])
+        ctx[i] = _lane_ctx(s) + unbooked
         temps[i] = s.req.temperature
         tks[i] = s.req.top_k or 0
         tps[i] = s.req.top_p if s.req.top_p is not None else 1.0
@@ -922,10 +937,17 @@ def _watch_device_calls(srv, check=True, keep=None):
     above."""
     calls, real = [], srv._run_device
 
+    fed = set()                # (lane, rid) of the previous decode call
+
     def spy(fn, *args):
-        assert len(args) == 1 and type(args[0]) is np.ndarray \
+        assert type(args[0]) is np.ndarray \
             and args[0].dtype == np.int32 and args[0].ndim == 1
         kind = "decode" if fn is srv._decode_fn else "prefill"
+        # ONE host array a call; the decode program reads beside it the two
+        # token vectors the engine keeps on the device
+        assert len(args) == (3 if kind == "decode" else 1)
+        assert kind == "prefill" or (args[1] is srv._dec_out
+                                     and args[2] is srv._pre_out)
         calls.append(kind)
         if keep is not None:
             keep.append([np.array(f) for f in getattr(srv._layout, kind)(
@@ -933,7 +955,7 @@ def _watch_device_calls(srv, check=True, keep=None):
         if check:
             if kind == "decode":
                 *got, key = srv._layout.decode(args[0])
-                want = _per_lane_decode_build(srv)
+                want = _per_lane_decode_build(srv, fed)
             else:
                 *got, key = srv._layout.prefill(args[0])
                 want = _per_request_prefill_build(srv, got[0].shape[1])
@@ -941,6 +963,11 @@ def _watch_device_calls(srv, check=True, keep=None):
                 np.testing.assert_array_equal(g, w)
             # the call's own key: calls are numbered 1, 2, ...
             np.testing.assert_array_equal(key, srv._call_key(len(calls)))
+        if kind == "decode":
+            ctx = srv._layout.decode(args[0])[1]
+            fed.clear()
+            fed.update((i, s.req.rid) for i, s in enumerate(srv._slots)
+                       if s is not None and ctx[i] > 0)
         return real(fn, *args)
 
     srv._run_device = spy
@@ -1073,11 +1100,12 @@ def test_no_eager_dispatch_between_steps(tiny, monkeypatch):
             srv.submit(prompt(30), 5), srv.submit(prompt(13), 7,
                                                   temperature=1.2)]
     while not srv.idle:
-        before, active = len(calls), srv.active
+        before, owed = len(calls), srv._lanes.next_call().any()
         srv.step()
         made = sorted(calls[before:])
         assert made in ([], ["decode"], ["prefill"], ["decode", "prefill"])
-        assert "decode" in made or not active
+        # a lane still owed a token no launched call brings gets its call
+        assert "decode" in made or not owed
     assert all(len(r.output_tokens) == r.max_new_tokens for r in reqs)
     assert calls.count("decode") >= 7 and calls.count("prefill") == 5
     assert srv._decode_fn._cache_size() == 1
@@ -1152,3 +1180,212 @@ def test_mixsim_replays_a_window_against_the_engine():
     assert out["requests"] >= 10
     assert 600 < out["sim_tokens_per_s"] < 1600
     assert 35 <= out["sim_itl_p95_ms"] <= 36
+
+
+# ---------------------------------------------------------------------------
+# PR 40: the next decode call is launched before the one in flight is fetched
+# ---------------------------------------------------------------------------
+
+AHEAD_MIX = [(37, 9), (21, 5), (50, 12), (18, 3), (33, 7), (26, 4), (37, 9)]
+
+
+def _decode_calls(srv):
+    return sum(1 for e in srv.rec.ring if e[0] == "serve.decode.dispatch")
+
+
+def _steps_with(srv, *names):
+    """How many ``serve.step`` spans of the ring hold a span of every name
+    (a pair ``(name, attrs)`` also asks for those attributes)."""
+    steps = [e for e in srv.rec.ring if e[0] == "serve.step"]
+    n = 0
+    for _, _, start, end, _ in steps:
+        inside = [e for e in srv.rec.ring if start <= e[2] and e[3] <= end]
+        n += all(any(e[0] == (want if isinstance(want, str) else want[0])
+                     and (isinstance(want, str)
+                          or want[1].items() <= e[4].items())
+                     for e in inside) for want in names)
+    return n
+
+
+@pytest.mark.parametrize("mode", ["chunked", "whole", "disagg"])
+def test_launch_ahead_is_token_exact_in_every_mode(tiny, mode):
+    """Greedy output is token for token ``generate()``'s with the decode
+    call launched one step ahead, in the three modes (chunked prefill, whole
+    prefill, the disaggregated pair): more requests than lanes, prompts
+    whose last chunk lands in a step in which other lanes finish, a warm-up,
+    a ramp and a drain; ONE decode specialization through all of it, nothing
+    in flight at the end, and no lane computed after its end (no EOS)."""
+    from deepspeed_tpu.serving.disagg import DisaggEngine
+    cfg, params = tiny
+    rng = np.random.default_rng(40)
+    prompts = [rng.integers(1, 64, size=n).tolist() for n, _ in AHEAD_MIX]
+    if mode == "disagg":
+        srv = DisaggEngine(cfg, params, serving=dict(
+            SERVE_CFG, prefill_chunk_tokens=16))
+        loop, dec = srv, srv.decode
+    else:
+        srv = loop = dec = ServingEngine(cfg, params, serving=dict(
+            SERVE_CFG, prefill_chunk_tokens=16 if mode == "chunked" else 0))
+    srv.generate_batch([prompts[0][:20]], max_new_tokens=3)     # warm-up
+    reqs = [srv.submit(p, m) for p, (_, m) in zip(prompts[:4], AHEAD_MIX)]
+    for _ in range(5):
+        loop.step()
+    reqs += [srv.submit(p, m) for p, (_, m) in zip(prompts[4:],
+                                                   AHEAD_MIX[4:])]
+    loop.run_until_idle()
+    for p, (_, m), r in zip(prompts, AHEAD_MIX, reqs):
+        assert r.output_tokens == _oracle_tokens(cfg, params, p, m), r.rid
+    assert dec._flight is None and dec._chunk_out is None
+    assert dec._decode_fn._cache_size() == 1
+    c = dec.stats
+    assert c["decode_ahead.wasted_lane_tokens"] == 0
+    assert c["decode_ahead.retired_unread"] == 0
+    assert 0 < c["decode_ahead.launched"] < _decode_calls(dec)
+    if mode == "chunked":
+        # the case the mix was made for: a prompt's last chunk in a step
+        # that also retires the last token of another lane
+        assert _steps_with(srv, ("serve.prefill", {"final": 1}),
+                           "serve.req.finished") >= 2
+        # every lane input but none comes off the device: the first token
+        # from the prefill call, the others from the previous decode call
+        assert c["decode_ahead.device_lane_tokens_sum"] == \
+            c["tokens_generated"] - c["completed"]
+
+
+def test_decode_ahead_counters_of_a_steady_run(tiny):
+    """While a lane goes on, every decode call but the first is launched
+    before the call in flight is fetched (``decode_ahead.launched`` = decode
+    calls - 1 - retirements), a step returns with one call in flight, the
+    tokens booked are the calls' live lanes, and a retirement from outside
+    (here ``preempt_request`` on a lane) costs one launch ahead."""
+    cfg, params = tiny
+    srv = ServingEngine(cfg, params, serving=dict(
+        SERVE_CFG, prefill_chunk_tokens=16, prefix_cache=False))
+    rng = np.random.default_rng(41)
+    long = srv.submit(rng.integers(1, 64, size=20).tolist(), 40)
+    others = [srv.submit(rng.integers(1, 64, size=n).tolist(), m)
+              for n, m in ((30, 6), (12, 9), (25, 4))]
+    while long.state != RUNNING:
+        srv.step()
+    victim = srv.submit(rng.integers(1, 64, size=9).tolist(), 30)
+    for _ in range(12):
+        srv.step()
+        assert srv._flight is not None        # a call in flight between steps
+        assert not srv.idle and srv.has_work
+    assert victim.state == RUNNING and srv.preempt_request(victim)
+    assert srv._flight is None and victim.state == QUEUED
+    srv.run_until_idle()
+    c = srv.stats
+    assert c["decode_ahead.retired_unread"] == 1
+    assert c["decode_ahead.launched"] == _decode_calls(srv) - 1 - 1
+    assert c["decode_ahead.wasted_lane_tokens"] == 0
+    assert long.output_tokens == _oracle_tokens(cfg, params, long.prompt, 40)
+    assert all(len(r.output_tokens) == r.max_new_tokens for r in others)
+    # the preempted request kept what was booked and resumes from it exactly
+    kept = list(victim.output_tokens)
+    assert 0 < len(kept) < 30
+    rest = srv.generate_batch([victim.prompt + kept], 30 - len(kept))[0]
+    assert kept + rest == _oracle_tokens(cfg, params, victim.prompt, 30)
+    assert srv.pool.used_count == 0
+
+
+@pytest.mark.parametrize("at", [0, 3], ids=["first_token", "later_token"])
+def test_an_eos_finish_is_seen_one_call_late_and_costs_one_lane_token(
+        tiny, at):
+    """A lane that ends by EOS (its first token, or a later one) is in the
+    call launched ahead once more: exactly one computed token is dropped,
+    its blocks go back at once, the request holds what ``generate()`` gives
+    up to the EOS, and the next owner of those blocks (a request that could
+    not be admitted before: the pool holds one of them at a time) is exact."""
+    cfg, params = tiny
+    rng = np.random.default_rng(42 + at)
+    prompt = rng.integers(1, 64, size=40).tolist()
+    want = _oracle_tokens(cfg, params, prompt, 12)
+    assert want[at] not in want[:at]           # the EOS is its first sighting
+    srv = ServingEngine(cfg, params, serving=dict(
+        SERVE_CFG, pool_blocks=6, prefill_chunk_tokens=16,
+        prefix_cache=False))
+    ends = srv.submit(prompt, 12, eos_token_id=want[at])
+    nxt_prompt = rng.integers(1, 64, size=40).tolist()
+    nxt = srv.submit(nxt_prompt, 12)           # 4 of the 5 blocks: must wait
+    while not ends.done:
+        srv.step()
+        assert nxt.state == QUEUED or ends.done
+    assert ends.output_tokens == want[:at + 1]
+    assert srv.stats["decode_ahead.wasted_lane_tokens"] == 1
+    assert srv.pool.used_count == 0 or nxt.state != QUEUED
+    srv.run_until_idle()
+    assert srv._flight is None                 # the dropped call was read too
+    assert nxt.output_tokens == _oracle_tokens(cfg, params, nxt_prompt, 12)
+    assert srv.stats["decode_ahead.wasted_lane_tokens"] == 1
+    assert srv.stats["tokens_generated"] == at + 1 + 12
+    assert srv.pool.used_count == 0
+
+
+@pytest.mark.parametrize("how", ["cancel", "preempt", "held_state", "close"])
+def test_lane_state_is_moved_only_behind_the_call_in_flight(tiny, how):
+    """``cancel_request``, ``preempt_request``, ``held_state`` and ``close``
+    meet a call in flight (there is one between any two steps of a busy
+    loop) and retire it first: the first two book its tokens, the last two
+    drop them; none leaves a call in flight or a block unaccounted for, and
+    what the requests hold is a prefix of what ``generate()`` gives."""
+    cfg, params = tiny
+    srv = ServingEngine(cfg, params, serving=dict(
+        SERVE_CFG, prefill_chunk_tokens=16, prefix_cache=False))
+    rng = np.random.default_rng(43)
+    prompts = [rng.integers(1, 64, size=n).tolist() for n in (22, 35)]
+    a, b = (srv.submit(p, 20) for p in prompts)
+    while b.state != RUNNING or len(b.output_tokens) < 3:
+        srv.step()
+    assert srv._flight is not None and srv._flight.go.sum() == 2
+    booked = (len(a.output_tokens), len(b.output_tokens))
+    if how in ("cancel", "preempt"):
+        act = srv.cancel_request if how == "cancel" else srv.preempt_request
+        assert act(b) and b.state == QUEUED
+        # the call in flight was booked before the lane was given up
+        assert (len(a.output_tokens), len(b.output_tokens)) == \
+            (booked[0] + 1, booked[1] + 1)
+        srv.run_until_idle()
+        assert len(a.output_tokens) == 20
+    elif how == "held_state":
+        blocks, reqs = srv.held_state()
+        assert {r.rid for r in reqs} == {a.rid, b.rid} and len(blocks) == 2
+        assert (len(a.output_tokens), len(b.output_tokens)) == booked
+        assert not srv._lanes.live.any() and srv.idle
+        for held in blocks:
+            srv.pool.release(held)
+    else:
+        srv.close()
+        assert (len(a.output_tokens), len(b.output_tokens)) == booked
+        for s in filter(None, srv._slots):
+            srv.pool.release(s.blocks)
+    assert srv._flight is None
+    assert srv.stats["decode_ahead.retired_unread"] == 1
+    assert srv.stats["decode_ahead.wasted_lane_tokens"] == 0
+    assert srv.pool.used_count == 0
+    for p, r in zip(prompts, (a, b)):
+        want = _oracle_tokens(cfg, params, p, 20)
+        assert r.output_tokens == want[:len(r.output_tokens)]
+
+
+def test_run_until_idle_reads_a_call_that_holds_dropped_lanes_only(tiny):
+    """The last lane of a batch ends by EOS: the call launched ahead for it
+    holds no lane anyone waits for, and the loop is not idle until a step
+    has read it (``idle`` counts the call in flight)."""
+    cfg, params = tiny
+    rng = np.random.default_rng(44)
+    prompt = rng.integers(1, 64, size=19).tolist()
+    want = _oracle_tokens(cfg, params, prompt, 9)
+    at = next(i for i in range(1, 8) if want[i] not in want[:i])
+    srv = ServingEngine(cfg, params, serving=dict(SERVE_CFG,
+                                                  prefill_chunk_tokens=16))
+    req = srv.submit(prompt, 9, eos_token_id=want[at])
+    while not req.done:
+        srv.step()
+    assert srv._flight is not None and not srv._flight.go.any()
+    assert srv.active == 0 and not srv.idle and srv.has_work
+    srv.run_until_idle()
+    assert srv._flight is None and srv.idle
+    assert req.output_tokens == want[:at + 1]
+    assert srv.generate_batch([prompt], 9)[0] == want      # and again, whole
+    assert srv._flight is None

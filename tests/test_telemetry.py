@@ -294,8 +294,12 @@ def test_a_served_run_yields_every_serve_span(served):
     assert parents["serve.admit.peek"] == "serve.admit"
     assert parents["serve.admit.alloc"] == "serve.admit"
     assert parents["serve.decode.fetch"] == "serve.decode"
-    assert parents["serve.prefill.prefix_insert"] == "serve.prefill"
-    assert parents["serve.req.first_token"] == "serve.prefill"
+    # a prompt's first token is fetched and booked behind the launch of
+    # the decode call its lane joins (PR 40): inside serve.decode
+    assert parents["serve.prefill.install"] == "serve.prefill"
+    assert parents["serve.prefill.fetch"] == "serve.decode"
+    assert parents["serve.prefill.prefix_insert"] == "serve.decode"
+    assert parents["serve.req.first_token"] == "serve.decode"
     assert parents["serve.submit"] is None
     snap = srv.telemetry()
     assert snap["counters"] == srv.stats
@@ -465,7 +469,8 @@ def served_moe():
         "max_blocks_per_seq": 8, "prefill_chunk_tokens": 32})
     fetched = []
     count = srv._count_experts
-    srv._count_experts = lambda out: fetched.append(out) or count(out)
+    srv._count_experts = lambda out, call: fetched.append(out) or count(
+        out, call)
     rng = np.random.default_rng(3)
     sizes = [(40, 6), (70, 9), (20, 5)]
     reqs = [srv.submit(list(rng.integers(1, 64, size=n)), max_new_tokens=k)
@@ -503,11 +508,13 @@ def test_moe_counts_ride_the_tokens_own_fetch(served_moe):
     for out in fetched:
         assert out.dtype == np.int32
         assert out.size - L * E in (1, srv.max_batch)   # a prompt's, a step's
-    # as many fetches as a dense engine makes: one a decode step, one a
-    # prompt's LAST chunk; the middle chunks' counts waited on the device
+    # as many fetches as a dense engine makes: one a decode call, one a
+    # chunk (since PR 40 a step waits for its chunk, a middle one too,
+    # behind the launch of the next decode call: nothing is left pending)
     spans = [e[0] for e in srv.rec.ring if e[0].endswith(".fetch")]
     assert len(fetched) == len(spans)
-    assert spans.count("serve.prefill.fetch") == len(reqs)
+    chunks = sum(1 for e in srv.rec.ring if e[0] == "serve.prefill")
+    assert spans.count("serve.prefill.fetch") == chunks > len(reqs)
     assert not srv._moe_pending
     assert all(r.state == "FINISHED" or r.done for r in reqs)
 
@@ -684,7 +691,8 @@ def train_step_program(engine):
 
 
 def decode_program(srv):
-    return srv._decode_fn, (srv.params, srv.pools, srv._lanes.buf)
+    return srv._decode_fn, (srv.params, srv.pools, srv._lanes.buf,
+                            srv._dec_out, srv._pre_out)
 
 
 def without_scopes(monkeypatch):
