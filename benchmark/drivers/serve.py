@@ -5,9 +5,13 @@ its reply needs none).
 
 Set-up: weights made on the device from the seed in one jitted call, in the
 type they are served in; the engine; warm-up of the decode program and of
-every prefill shape the mix can produce, together with the requests that the
-reference checks; then the ramp: the closed loop runs until as many requests
-as there are clients have had their first token. The window starts there.
+every prefill shape the mix can produce, together with the requests that
+``correct`` is decided on (served beside each other on the loop's own
+programs); then the ramp: the closed loop runs until as many requests as
+there are clients have had their first token. The window starts there. The
+plain reference reads the checked requests once the window has closed, the
+peak of the device's memory has been read and the engine is dropped: its time
+is no part of ``setup_s``.
 
 Every token is stamped by this file after the ``srv.step()`` that produced
 it (the step ends in the fetch of the tokens; the program keeps no per-token
@@ -19,11 +23,15 @@ mark count for nothing.
 
 from __future__ import annotations
 
+import gc
+import inspect
 import json
 import os
 import tempfile
 import time
-from typing import Any, Dict, List
+from typing import Any, Dict, List, Optional, Sequence, Tuple
+
+import numpy as np
 
 from benchmark import harness, reduce, reference, trace as tracing
 from benchmark.traffic import request_stream, seeded_tokens
@@ -64,6 +72,120 @@ def make_params(model, mcfg, seed: int, dtype):
     return jax.jit(make)(jax.random.PRNGKey(harness.jax_seed(seed)))
 
 
+def cell_model(cell: harness.Cell, family):
+    """``(model, its config, the parameter type)`` of the cell's
+    configuration, as it is served."""
+    import jax.numpy as jnp
+    from deepspeed_tpu.models import TransformerConfig, build_model
+    dtype = {"bfloat16": jnp.bfloat16,
+             "float32": jnp.float32}[cell.system["dtype"]]
+    model, mcfg = build_model(TransformerConfig(
+        **family.model_kwargs(cell.config), dtype=dtype))
+    return model, mcfg, dtype
+
+
+def start_engine(cell: harness.Cell, model, params, rehearsal: bool):
+    """``init_inference(model, <the cell's inference config>.json,
+    model_parameters=params).serve()``: the user's entry point."""
+    import deepspeed_tpu as ds
+    with tempfile.TemporaryDirectory(prefix="bench_serve_") as workdir:
+        cfg_path = os.path.join(workdir, "inference_config.json")
+        with open(cfg_path, "w") as f:
+            json.dump({"dtype": cell.system["dtype"],
+                       "serving": cell.system["serving"]}, f)
+        return ds.init_inference(model, cfg_path,
+                                 model_parameters=params).serve(
+            **({"interpret": True} if rehearsal else {}))
+
+
+def submit_checked(srv, check: Dict[str, Any], vocab: int, seed: int) -> list:
+    """The requests ``correct`` is decided on: seeded prompts of the cell's
+    ``check.prompt_lens``, longest first, ``check.new_tokens`` greedy tokens
+    each. A mixture's picks are asked for where the program hands them out
+    to a request that asks (``submit(..., keep_routing=True)`` ->
+    ``Request.routed_experts``): the reference then routes by them."""
+    ask = ({"keep_routing": True} if "keep_routing" in
+           inspect.signature(srv.submit).parameters else {})
+    return [srv.submit(seeded_tokens(vocab, seed, 1000 + i, n),
+                       max_new_tokens=int(check["new_tokens"]), **ask)
+            for i, n in enumerate(sorted(check["prompt_lens"], reverse=True))]
+
+
+def served_of(requests) -> List[Tuple[list, list, Optional[np.ndarray]]]:
+    """``(prompt, served tokens, picks or None)`` of each finished request,
+    as plain data: what the check needs once the engine is gone."""
+    return [(list(r.prompt), list(r.output_tokens),
+             getattr(r, "routed_experts", None)) for r in requests]
+
+
+def check_served(family, cell: harness.Cell, params, served,
+                 emitted: Optional[Sequence[Sequence[int]]] = None
+                 ) -> Dict[str, Any]:
+    """The plain reference over every checked request, one at a time:
+    ``gaps`` (a request: how far each served token's reference logit lies
+    under the reference's best, ``reference.served_token_gaps``) and
+    ``deficits`` (a mixture whose program handed out its picks: how far each
+    lies under the reference's own k-th best score; else empty). ``emitted``
+    (the control): the tokens judged in the served ones' place."""
+    check = cell.system["check"]
+    # (params, ids) or, for a mixture's picks, (params, ids, picks)
+    logits_fn = lambda p, *seq: family.reference_logits(cell.config, p, *seq)
+    longest = max(check["prompt_lens"]) + int(check["new_tokens"])
+    gaps, deficits = [], []
+    for i, (prompt, tokens, picks) in enumerate(served):
+        got = reference.served_token_gaps(
+            logits_fn, params, prompt, tokens,
+            reference.padded_len(len(prompt) + len(tokens), longest),
+            picks=picks, emitted=None if emitted is None else emitted[i])
+        if picks is not None:
+            got, d = got
+            deficits.append(d[np.asarray(picks) >= 0])
+        gaps.append(got)
+    return {"gaps": gaps, "deficits": deficits}
+
+
+def judge(got: Dict[str, Any], picks_needed: bool
+          ) -> Tuple[Dict[str, bool], Dict[str, Dict[str, float]]]:
+    """``(checks, compared)`` of :func:`check_served`'s readings: what of
+    them decides ``correct``, and each number compared beside its limit.
+    ``picks_needed``: the mixture renormalises its picks' weights, so a
+    reference that routes by itself compares another model (one flipped
+    pick is 1/k of the routed branch: PERF.md, section 6, PR 42) and the
+    check refuses a program that hands none out."""
+    gaps = np.concatenate(got["gaps"])
+    worst_gap, mean_gap = float(gaps.max()), float(gaps.mean())
+    print(f"[serve] reference check: {int((gaps == 0).sum())} of {gaps.size} "
+          f"served tokens are the reference's argmax; largest logit gap "
+          f"{worst_gap:.4f} (margin {reference.SERVE_LOGIT_MARGIN}), mean "
+          f"{mean_gap:.5f} (limit {reference.SERVE_MEAN_GAP_LIMIT})",
+          flush=True)
+    checks = {"served tokens within the margin of the reference's best":
+              worst_gap <= reference.SERVE_LOGIT_MARGIN,
+              "served tokens' mean gap within its limit":
+              mean_gap <= reference.SERVE_MEAN_GAP_LIMIT}
+    compared = {"served_logit_gap": {"value": worst_gap,
+                                     "limit": reference.SERVE_LOGIT_MARGIN},
+                "served_logit_gap_mean": {
+                    "value": mean_gap,
+                    "limit": reference.SERVE_MEAN_GAP_LIMIT}}
+    if got["deficits"]:
+        d = np.concatenate(got["deficits"])
+        worst = float(d.max())
+        print(f"[serve] picks: {int((d == 0).sum())} of {d.size} the "
+              f"reference's own, largest deficit {worst:.4f} (tolerance "
+              f"{reference.ROUTE_TIE_TOL})", flush=True)
+        checks["every pick within the tie tolerance of the reference's "
+               "scores"] = worst <= reference.ROUTE_TIE_TOL
+        compared["pick_deficit"] = {"value": worst,
+                                    "limit": reference.ROUTE_TIE_TOL}
+    elif picks_needed:
+        print("[serve] picks: the program hands none out (no keep_routing "
+              "in submit) and the mixture renormalises its picks' weights: "
+              "a reference that routes by itself cannot judge it", flush=True)
+        checks["a renormalised mixture's program hands out its picks"] = False
+    return checks, compared
+
+
 class Client:
     """One outstanding request of the closed loop, and its stamps."""
     __slots__ = ("req", "max_new", "submitted", "seen", "last")
@@ -89,25 +211,19 @@ def run(cell: harness.Cell, *, seed: int, seconds: float, trace: bool,
         ) -> Dict[str, Any]:
     devices = harness.take_devices(cell.chips, rehearsal)
     import jax
-    import jax.numpy as jnp
 
     if not rehearsal:
         harness.configure_compile_cache()
     watch = harness.CompileWatch(t0)
     watch.report("devices taken")
 
-    import deepspeed_tpu as ds
-    from deepspeed_tpu.models import TransformerConfig, build_model
     from deepspeed_tpu.serving.scheduler import FAILED, FINISHED, SHED, \
         TIMEOUT
 
     watch.report("program imported")
     family = harness.load_family(cell.config["family"])
     mix, serving = cell.traffic, cell.system["serving"]
-    dtype = {"bfloat16": jnp.bfloat16,
-             "float32": jnp.float32}[cell.system["dtype"]]
-    model, mcfg = build_model(TransformerConfig(
-        **family.model_kwargs(cell.config), dtype=dtype))
+    model, mcfg, dtype = cell_model(cell, family)
     params = make_params(model, mcfg, seed, dtype)
     jax.block_until_ready(params)
     print(f"[serve] {cell.config['name']}: {mcfg.num_params() / 1e9:.3f}B "
@@ -117,48 +233,26 @@ def run(cell: harness.Cell, *, seed: int, seconds: float, trace: bool,
           flush=True)
     watch.report("weights")
 
-    with tempfile.TemporaryDirectory(prefix="bench_serve_") as workdir:
-        cfg_path = os.path.join(workdir, "inference_config.json")
-        with open(cfg_path, "w") as f:
-            json.dump({"dtype": cell.system["dtype"], "serving": serving}, f)
-        srv = ds.init_inference(model, cfg_path,
-                                model_parameters=params).serve(
-            **({"interpret": True} if rehearsal else {}))
+    srv = start_engine(cell, model, params, rehearsal)
     watch.report("engine")
     bs, usable = srv.block_size, srv.pool.num_blocks - 1
     vocab = mcfg.vocab_size
 
     # ---- warm-up: the decode program, every prefill shape, the checked
-    # requests (served before the window, held against the reference after)
+    # requests (served here, held against the reference behind the window)
     chunk = int(serving.get("prefill_chunk_tokens", 0))
     longest = int(mix["prompt_len"]["max"])
     shapes = range(bs, (min(chunk, longest) if chunk else longest) + 1, bs)
     # the checked requests first, longest first: their decode steps then
     # run beside the other shapes' prefills and warm-up is over sooner
-    check = cell.system["check"]
-    checked = [srv.submit(seeded_tokens(vocab, seed, 1000 + i, n),
-                          max_new_tokens=int(check["new_tokens"]))
-               for i, n in enumerate(sorted(check["prompt_lens"],
-                                            reverse=True))]
+    checked = submit_checked(srv, cell.system["check"], vocab, seed)
     warm = [srv.submit(seeded_tokens(vocab, seed, i, n), max_new_tokens=2)
             for i, n in enumerate(shapes)]
     srv.run_until_idle()
     warm_ok = all(r.state == FINISHED for r in warm + checked)
+    served = served_of(checked)
+    del warm, checked
     watch.report("warm-up")
-
-    logits_fn = lambda p, ids: family.reference_logits(cell.config, p, ids)
-    pad_to = -(-(max(check["prompt_lens"]) + int(check["new_tokens"]))
-               // 128) * 128
-    gaps = [reference.served_token_gaps(logits_fn, params, r.prompt,
-                                        list(r.output_tokens), pad_to)
-            for r in checked]
-    worst_gap = max(float(g.max()) for g in gaps)
-    exact = sum(int((g == 0).sum()) for g in gaps)
-    print(f"[serve] reference check: {exact} of {sum(len(g) for g in gaps)} "
-          f"served tokens are the reference's argmax; largest logit gap "
-          f"{worst_gap:.4f} (margin {reference.SERVE_LOGIT_MARGIN})",
-          flush=True)
-    watch.report("reference check")
 
     # ---- the closed loop ---------------------------------------------------
     stream = request_stream(mix, vocab, seed)
@@ -265,22 +359,33 @@ def run(cell: harness.Cell, *, seed: int, seconds: float, trace: bool,
           f" {len(itl)} token gaps, {failed} failed; compiles inside the "
           f"window: {compiles_in_window}; stats {srv.stats}", flush=True)
 
+    # ---- the check: the plain reference over the checked requests, with
+    # the peak read and the engine (its pool, its programs' buffers) dropped
+    one_decode_program = srv._decode_fn._cache_size() == 1
+    memory_peak = harness.memory_peak_bytes(devices)
+    srv = None
+    clients.clear()
+    gc.collect()
+    got = check_served(family, cell, params, served)
+    watch.report("reference check")
+
     end_to_end = {
         "serve_tokens_per_s": {"value": win["tokens"] / wall,
                                "unit": "tokens/s"},
         "itl_p95_ms": {"value": 1e3 * reduce.p95(itl), "unit": "ms"},
         "setup_s": {"value": setup_s, "unit": "s"}}
-    checks = {
-        "warm-up and checked requests finished": warm_ok,
-        "served tokens within the margin of the reference's best":
-            worst_gap <= reference.SERVE_LOGIT_MARGIN,
+    checks = {"warm-up and checked requests finished": warm_ok}
+    judged, compared = judge(got, picks_needed=bool(
+        mcfg.moe_experts and mcfg.moe_norm_topk))
+    checks.update(judged)
+    checks.update({
         "no request failed": failed == 0,
-        "one decode program": srv._decode_fn._cache_size() == 1,
-        "no compile inside the window": compiles_in_window == 0}
+        "one decode program": one_decode_program,
+        "no compile inside the window": compiles_in_window == 0})
     obs = {"clocks": {"decode_step": o["decode_step"],
                       "prefill_step": o["prefill_step"], "ttft": ttft},
            "counters": counters, "trace": the_trace,
            "context": harness.context(cell, family, devices, rehearsal)}
-    return {"checks": checks, "attempted": attempted, "failed": failed,
-            "end_to_end": end_to_end, "obs": obs, "devices": devices,
-            "memory_peak": harness.memory_peak_bytes(devices)}
+    return {"checks": checks, "compared": compared, "attempted": attempted,
+            "failed": failed, "end_to_end": end_to_end, "obs": obs,
+            "devices": devices, "memory_peak": memory_peak}

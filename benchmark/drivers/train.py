@@ -145,6 +145,9 @@ def run(cell: harness.Cell, *, seed: int, seconds: float, trace: bool,
         "train_tokens_per_s_per_chip": {"value": tokens_per_s_per_chip,
                                         "unit": "tokens/s/chip"},
         "setup_s": {"value": setup_s, "unit": "s"}}
-    return {"checks": checks, "attempted": len(all_losses),
+    compared = {"first_step_loss_diff": {"value": abs(loss0 - ref_loss),
+                                         "limit": reference.TRAIN_LOSS_TOL}}
+    return {"checks": checks, "compared": compared,
+            "attempted": len(all_losses),
             "failed": finite.count(False), "end_to_end": end_to_end,
             "obs": obs, "devices": devices, "memory_peak": memory_peak}

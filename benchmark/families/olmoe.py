@@ -82,24 +82,37 @@ def dims(c: Dict[str, Any]) -> Dict[str, Any]:
                 experts_per_token=c["num_experts_per_tok"])
 
 
-def reference_router(gate_kernel, h, k: int, renorm: bool):
-    """``(probs [S, E], weights [S, k], picks [S, k])`` in float32."""
-    probs = jax.nn.softmax(h @ gate_kernel, axis=-1)
-    weights, picks = jax.lax.top_k(probs, k)
+def reference_router(gate_kernel, h, k: int, renorm: bool, picks=None):
+    """``(probs [S, E], weights [S, k], picks [S, k], deficit [S, k])`` in
+    float32. With ``picks`` (the program's, -1 where it has none) the layer
+    routes by them: the weights are THIS router's probabilities at those
+    experts, renormalised over them if ``renorm``, and ``deficit`` is how far
+    each pick's logit lies under this router's own k-th best
+    (``reference.pick_deficit``; the softmax ranks as the logits do)."""
+    logits = h @ gate_kernel
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, own = jax.lax.top_k(probs, k)
+    deficit = jnp.zeros_like(weights)
+    if picks is not None:
+        deficit = ref.pick_deficit(logits, picks)
+        own = ref.pinned_picks(own, picks)
+        weights = jnp.take_along_axis(probs, own, axis=-1)
     if renorm:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
-    return probs, weights, picks
+    return probs, weights, own, deficit
 
 
-def reference_moe(moe, h, k: int, renorm: bool, leave_out: int = -1):
+def reference_moe(moe, h, k: int, renorm: bool, leave_out: int = -1,
+                  picks=None):
     """The mixture of one layer on ``h [S, hidden]`` from the program's
     ``moe`` subtree (``gate/kernel [hidden, E]``, ``experts/{gate,fc,proj}/
     kernel [E, in, out]``), all float32: every expert on every token, kept
-    where the router picked it. ``leave_out`` j drops every token's j-th
+    where the router picked it (or, with ``picks``, where the program did:
+    :func:`reference_router`). ``leave_out`` j drops every token's j-th
     pick (what a parity tolerance has to notice). Returns ``(y, probs,
-    picks)``."""
-    probs, weights, picks = reference_router(moe["gate"]["kernel"], h, k,
-                                             renorm)
+    picks, deficit)``."""
+    probs, weights, picks, deficit = reference_router(
+        moe["gate"]["kernel"], h, k, renorm, picks)
     if leave_out >= 0:
         weights = weights.at[:, leave_out].set(0.0)
     ex = moe["experts"]
@@ -109,13 +122,13 @@ def reference_moe(moe, h, k: int, renorm: bool, leave_out: int = -1):
         out = (ref.silu(h @ ex["gate"]["kernel"][e])
                * (h @ ex["fc"]["kernel"][e])) @ ex["proj"]["kernel"][e]
         y = y + w_e[:, None] * out
-    return y, probs, picks
+    return y, probs, picks, deficit
 
 
 @functools.lru_cache(maxsize=None)
 def _steps(heads: int, kv_heads: int, head_dim: int, eps: float,
            theta: float, k: int, renorm: bool):
-    def block(p, x):
+    def block(p, x, picks=None):
         S = x.shape[0]
         h = ref.rms_norm(x, p["ln1"]["scale"], eps)
         qkv = h @ p["attn_qkv"]["kernel"]
@@ -128,8 +141,9 @@ def _steps(heads: int, kv_heads: int, head_dim: int, eps: float,
         a = ref.causal_attention(q, kk, v.reshape(S, kv_heads, head_dim))
         x = x + a @ p["attn_proj"]["kernel"]
         h2 = ref.rms_norm(x, p["ln2"]["scale"], eps)
-        y, probs, picks = reference_moe(p["moe"], h2, k, renorm)
-        return x + y, (probs, picks)
+        y, probs, picks, deficit = reference_moe(p["moe"], h2, k, renorm,
+                                                 picks=picks)
+        return x + y, (probs, picks, deficit)
 
     @jax.jit
     def embed(params, ids):
@@ -151,9 +165,18 @@ def _steps_of(c: Dict[str, Any]):
                      d["experts_per_token"], bool(c["norm_topk_prob"]))
 
 
-def reference_logits(c: Dict[str, Any], params, ids) -> jnp.ndarray:
+def reference_logits(c: Dict[str, Any], params, ids, picks=None):
     """``[S, vocab]`` float32 logits of one sequence ``ids [S]``, from the
-    program's parameter tree (scan layout: ``blocks`` stacked by layer)."""
+    program's parameter tree (scan layout: ``blocks`` stacked by layer).
+
+    With ``picks [S, layers, k]`` (the experts the PROGRAM picked for each
+    token in each layer, -1 where it has none) every layer routes by them
+    and the result is ``(logits, deficits [S, layers, k])``: what the
+    program computed is then held to the reference token for token, and its
+    picks to the reference's own scores (``reference.ROUTE_TIE_TOL``)."""
+    if picks is not None:
+        logits, routing = reference_logits_and_routing(c, params, ids, picks)
+        return logits, jnp.stack([r[2] for r in routing], axis=1)
     d, (embed, step, _, head) = _steps_of(c)
     with jax.default_matmul_precision("highest"):
         x = ref.walk_layers(step, params["blocks"], embed(params, ids),
@@ -161,15 +184,17 @@ def reference_logits(c: Dict[str, Any], params, ids) -> jnp.ndarray:
         return head(params, x)
 
 
-def reference_logits_and_routing(c: Dict[str, Any], params, ids
+def reference_logits_and_routing(c: Dict[str, Any], params, ids, picks=None
                                  ) -> Tuple[jnp.ndarray, list]:
-    """The logits, and each layer's ``(probs [S, E], picks [S, k])``."""
+    """The logits, and each layer's ``(probs [S, E], picks [S, k], deficit
+    [S, k])``; the layers route by ``picks [S, layers, k]`` where given."""
     d, (embed, _, step, head) = _steps_of(c)
     routing = []
     with jax.default_matmul_precision("highest"):
         x = embed(params, ids)
         for li in range(d["layers"]):
-            x, r = step(jax.tree.map(lambda a: a[li], params["blocks"]), x)
+            x, r = step(jax.tree.map(lambda a: a[li], params["blocks"]), x,
+                        None if picks is None else picks[:, li])
             routing.append(r)
         return head(params, x), routing
 
@@ -184,8 +209,8 @@ def reference_train_loss(c: Dict[str, Any], params, batch) -> jnp.ndarray:
         logits, routing = reference_logits_and_routing(c, params,
                                                        jnp.asarray(ids))
         rows.append(ref.next_token_nll(logits, jnp.asarray(ids)))
-        probs += [p for p, _ in routing]
-        picks += [e for _, e in routing]
+        probs += [r[0] for r in routing]
+        picks += [r[1] for r in routing]
     probs, picks = jnp.concatenate(probs), jnp.concatenate(picks)
     f = jnp.mean(jax.nn.one_hot(picks, E, dtype=jnp.float32), axis=0)  # [k,E]
     aux = E * jnp.sum(f * jnp.mean(probs, axis=0)[None, :])

@@ -58,8 +58,13 @@ def load_cell(name: str, base: str = HERE) -> Cell:
                 expect_kernels=tuple(w.get("expect_kernels", ())))
 
 
-def load_layer_metrics(kind: str, base: str = HERE) -> List[Dict[str, Any]]:
-    """Every ``layer_metrics/*.json`` whose ``kinds`` holds ``kind``."""
+def load_layer_metrics(kind: str, base: str = HERE,
+                       cell: Optional[str] = None) -> List[Dict[str, Any]]:
+    """Every ``layer_metrics/*.json`` that cell ``cell`` of kind ``kind``
+    reports. A file lists either ``kinds`` (every cell of those kinds, the
+    ones later PRs add too) or ``workloads`` (the cells it names and no
+    other: the metric of a mechanism that only they have, as a mixture's
+    experts)."""
     d = os.path.join(base, "layer_metrics")
     out = []
     for fn in sorted(os.listdir(d)):
@@ -68,7 +73,12 @@ def load_layer_metrics(kind: str, base: str = HERE) -> List[Dict[str, Any]]:
             if m["name"] + ".json" != fn:
                 raise ValueError(f"layer_metrics/{fn} names itself "
                                  f"{m['name']!r}")
-            if kind in m["kinds"]:
+            if ("kinds" in m) == ("workloads" in m):
+                raise ValueError(f"layer_metrics/{fn} lists either kinds or "
+                                 f"workloads, not both and not neither")
+            reports = cell in m["workloads"] if "workloads" in m \
+                else kind in m["kinds"]
+            if reports:
                 out.append(m)
     return out
 
@@ -192,7 +202,10 @@ def result_line(*, correct: bool, attempted: int, failed: int,
                 metrics: Dict[str, Dict[str, Any]], devices,
                 memory_peak: int, busy_s: Optional[float] = None,
                 window_s: Optional[float] = None,
-                breakdown: Optional[Dict[str, Any]] = None) -> str:
+                breakdown: Optional[Dict[str, Any]] = None,
+                compared: Optional[Dict[str, Any]] = None) -> str:
+    """``compared``: every number the check held against a limit, under a
+    short name, ``{"value", "limit"}``; the line's last key."""
     device = {"platform": devices[0].platform,
               "kind": devices[0].device_kind, "count": len(devices),
               "memory_peak_bytes": int(memory_peak)}
@@ -203,4 +216,6 @@ def result_line(*, correct: bool, attempted: int, failed: int,
            "failed": int(failed), "metrics": metrics, "device": device}
     if breakdown is not None:
         out["breakdown"] = breakdown
+    if compared is not None:
+        out["compared"] = compared
     return json.dumps(out)

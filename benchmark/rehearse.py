@@ -86,8 +86,8 @@ def rehearse(kind: str, trace: bool) -> bool:
         out = harness.load_driver(kind).run(
             cell, seed=ARGS.seed, seconds=ARGS.seconds, trace=trace, t0=T0,
             trace_dir=tdir, rehearsal=True)
-    layer = reduce.layer_metrics(harness.load_layer_metrics(kind),
-                                 out["obs"])
+    layer = reduce.layer_metrics(
+        harness.load_layer_metrics(kind, cell=real.name), out["obs"])
     print(f"   end-to-end metrics filled: {sorted(out['end_to_end'])}")
     print(f"   per-layer metrics filled (trace- and peak-sourced ones need "
           f"the chip): {sorted(layer)}")

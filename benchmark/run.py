@@ -7,7 +7,8 @@ Finds ``workloads/<cell>.json``, its configuration and traffic mix, and the
 driver of the cell's ``kind``; runs it in THIS process (a chip belongs to one
 process; no child is started, nothing is left behind) and prints, as the last
 line of stdout, one JSON object: ``correct``, ``attempted``, ``failed``,
-``metrics``, ``device`` and, traced, ``breakdown``. ``--trace 0`` reports the
+``metrics``, ``device``, traced ``breakdown``, and last ``compared`` (every
+number the check held against a limit, beside it). ``--trace 0`` reports the
 cell's end-to-end metrics, ``--trace 1`` its per-layer metrics
 (``layer_metrics/``). Without a TPU, or with another number of chips than
 the cell asks for, it exits non-zero and prints no result.
@@ -33,7 +34,7 @@ def finish(cell, out, trace: bool) -> str:
     kw = {}
     if trace:
         metrics = reduce.layer_metrics(
-            harness.load_layer_metrics(cell.kind), obs)
+            harness.load_layer_metrics(cell.kind, cell=cell.name), obs)
         busy = reduce.busy_and_window_s(obs["trace"])
         checks["the trace holds device operations"] = bool(busy and busy[0] > 0)
         for kernel in cell.expect_kernels:
@@ -47,7 +48,11 @@ def finish(cell, out, trace: bool) -> str:
         metrics = out["end_to_end"]
     for what, ok in checks.items():
         print(f"[bench] {'ok  ' if ok else 'FAIL'} {what}", flush=True)
+    for what, c in out["compared"].items():     # stderr's last lines
+        print(f"[bench] compared {what}: {c['value']!r} (limit "
+              f"{c['limit']!r})", file=sys.stderr, flush=True)
     return harness.result_line(
+        compared=out["compared"],
         correct=all(checks.values()), attempted=out["attempted"],
         failed=out["failed"], metrics=metrics, devices=out["devices"],
         memory_peak=out["memory_peak"], **kw)

@@ -675,8 +675,8 @@ def finish(cell: harness.Cell, out: Dict[str, Any], obs: Dict[str, Any]
     outside, the metrics of this file and the two breakdowns."""
     pt = obs["program"]["trace"]
     checks = dict(out["checks"])
-    outside = reduce.layer_metrics(harness.load_layer_metrics(cell.kind),
-                                   obs)
+    outside = reduce.layer_metrics(
+        harness.load_layer_metrics(cell.kind, cell=cell.name), obs)
     agree = agreement(cell.kind, obs, outside)
     for name, a in agree.items():
         checks[f"inside agrees with outside on {name} (to "

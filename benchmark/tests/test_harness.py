@@ -49,8 +49,8 @@ def test_benchmark_json_agrees_with_the_files():
         assert w["config"] in configs and len(w["why"]) <= 200
         kinds.setdefault(cell.kind, set()).add(w["name"])
     listed = {m["name"]: m for m in BENCH["per_layer"]}
-    on_disk = {m["name"]: m for k in kinds
-               for m in harness.load_layer_metrics(k)}
+    on_disk = {m["name"]: m for k, cells in kinds.items() for c in cells
+               for m in harness.load_layer_metrics(k, cell=c)}
     assert set(listed) == set(on_disk)
     for name, m in on_disk.items():
         b = listed[name]
@@ -58,8 +58,17 @@ def test_benchmark_json_agrees_with_the_files():
                                   "moves")} == \
             {k: b[k] for k in ("unit", "better", "source", "layer", "moves")}
         assert b["moves"] in e2e and m["reducer"] in reduce.REDUCERS
-        cells = set().union(*(kinds[k] for k in m["kinds"] if k in kinds))
-        assert set(b["workloads"]) == cells
+        # a metric is held to the cells it names, or to every cell of its
+        # kinds; every one of them reports the end-to-end metric it moves
+        cells = set(m["workloads"]) if "workloads" in m else \
+            set().union(*(kinds[k] for k in m["kinds"] if k in kinds))
+        assert set(b["workloads"]) == cells <= set().union(*kinds.values())
+        moved = next(e for e in BENCH["end_to_end"] if e["name"] == b["moves"])
+        assert cells <= set(moved.get("workloads", cells))
+        for k, named in kinds.items():
+            for c in named:
+                assert (name in [x["name"] for x in harness.load_layer_metrics(
+                    k, cell=c)]) == (c in cells)
 
 
 def test_peaks_table_refuses_an_unknown_device():
@@ -99,6 +108,21 @@ def test_config_cell_and_metric_are_added_as_new_files_only(tmp_path):
          "args": {"match": ["chunk_nll"], "per": "traced_steps",
                   "scale": 1000.0}})
 
+    # a per-layer metric of a mechanism only some cells have names them
+    add("layer_metrics", "gmm_ms_per_step",
+        {"name": "gmm_ms_per_step", "unit": "ms", "better": "lower",
+         "layer": "paged forward and kernel", "source": "device_trace",
+         "moves": "itl_p95_ms", "workloads": ["serve-olmoe-1b-7b-l8-gen"],
+         "reducer": "scope_time",
+         "args": {"match": ["gmm"], "per": "traced_steps", "scale": 1000.0}})
+    for cell_name, there in (("serve-olmoe-1b-7b-l8-gen", True),
+                             ("serve-mistral-7b-l16-chat", False),
+                             (None, False)):
+        got = [m["name"] for m in harness.load_layer_metrics(
+            "serve", base, cell=cell_name)]
+        assert ("gmm_ms_per_step" in got) == there
+        assert "paged_kernel_ms_per_step" in got
+
     cell = harness.load_cell("train-gpt2-2.7b-z3-dp4", base=base)
     assert cell.chips == 4 and cell.kind == "train"
     assert cell.config["hidden_size"] == 2560
@@ -131,3 +155,26 @@ def test_a_cell_file_with_four_chips_loads_and_a_wrong_one_does_not(tmp_path):
         else:
             with pytest.raises(ValueError, match="not 1 or 4"):
                 harness.load_cell(name, base=base)
+
+
+@pytest.mark.parametrize("keys", [{}, {"kinds": ["serve"],
+                                       "workloads": ["serve-x"]}],
+                         ids=["neither", "both"])
+def test_a_metric_lists_kinds_or_workloads_and_not_both(tmp_path, keys):
+    d = tmp_path / "benchmark" / "layer_metrics"
+    d.mkdir(parents=True)
+    (d / "m.json").write_text(json.dumps(dict({"name": "m"}, **keys)))
+    with pytest.raises(ValueError, match="either kinds or workloads"):
+        harness.load_layer_metrics("serve", str(tmp_path / "benchmark"),
+                                   cell="serve-x")
+
+
+def test_result_line_ends_in_the_numbers_compared():
+    line = json.loads(harness.result_line(
+        correct=True, attempted=3, failed=0, metrics={},
+        devices=[FakeDevice()], memory_peak=1,
+        breakdown={"device_ops": [], "idle_gaps": []},
+        compared={"served_logit_gap": {"value": 0.04, "limit": 0.15}}))
+    assert list(line)[-1] == "compared"
+    assert line["compared"]["served_logit_gap"] == {"value": 0.04,
+                                                    "limit": 0.15}
