@@ -191,13 +191,24 @@ def _moe_mlp(cfg: TransformerConfig, p_moe, h, interpret: bool = False,
     tokens = h.reshape(B * T, H)
     if cfg.moe_is_dropless:
         from ..moe.dropless import dropless_moe
+        act = _ACTIVATIONS[cfg.activation] if cfg.gated_mlp else jax.nn.gelu
         y, routing = dropless_moe(
             tokens, p_moe["gate"]["kernel"], p_moe["experts"], k=cfg.moe_k,
-            renorm=cfg.moe_norm_topk,
-            act=(_ACTIVATIONS[cfg.activation] if cfg.gated_mlp
-                 else jax.nn.gelu),
+            renorm=cfg.moe_norm_topk, act=act,
             kernel_of=lambda p: _kernel_of(p, h.dtype), interpret=interpret,
-            layer=layer)
+            layer=layer, scores=cfg.moe_scores,
+            select_bias=p_moe["gate"].get("bias"),
+            scale=cfg.moe_routed_scale, held=cfg.moe_held)
+        if cfg.moe_shared_dim:
+            # the shared expert: the experts' body on every token, unweighted
+            with jax.named_scope("shared"):
+                sh = p_moe["shared"]
+                up = _dense(tokens, sh["fc"], interpret)
+                if cfg.gated_mlp:
+                    up = act(_dense(tokens, sh["gate"], interpret)) * up
+                else:
+                    up = act(up)
+                y = y + _dense(up, sh["proj"], interpret)
         return y.reshape(B, T, H), routing
     from ..moe.sharded_moe import top1_gating, top2_gating
     gate_logits = tokens.astype(jnp.float32) @ p_moe["gate"]["kernel"]
@@ -234,10 +245,17 @@ def attention_constants(cfg: TransformerConfig):
 
 def decoder_forward(cfg: TransformerConfig, params: PyTree,
                     input_ids: jnp.ndarray, cache, *,
-                    interpret: bool = False, expert_counts: bool = False):
+                    interpret: bool = False, expert_counts: bool = False,
+                    expert_picks: bool = False):
     """The inference decoder over a KV cache: ``input_ids`` [B, T] ->
     ``(logits [B, T, V] f32, the cache as its caller keeps it, expert counts
-    [L, E] or None)``. ``forward_with_cache`` and ``serving.model_runner.
+    [L, E] or None)`` and, with ``expert_picks`` (a dropless MoE config
+    only), ``[L, B x T, k]`` int32: the experts every layer's router picked
+    for every row of the call, best first (``Routing.experts``; padding rows
+    are routed like any row). ``L`` there is the SPARSE layers
+    (``cfg.sparse_layers``: a mixture's leading dense layers have no router)
+    and ``E`` the router's outputs, held here or not (``cfg.moe_held``).
+    ``forward_with_cache`` and ``serving.model_runner.
     paged_forward`` are this function over two caches; a new kind of
     per-sequence state (a latent cache, a recurrent state) is a third.
 
@@ -269,8 +287,13 @@ def decoder_forward(cfg: TransformerConfig, params: PyTree,
         raise ValueError(
             "the decoder needs scan-layers params (a 'blocks' subtree "
             "stacked [L, ...]): models.generation.ensure_scan_layout")
-    if expert_counts and not cfg.moe_is_dropless:
-        raise ValueError("expert_counts needs a dropless MoE config")
+    if cfg.dense_layers and "dense_blocks" not in params:
+        raise ValueError(
+            f"dense_layers {cfg.dense_layers}: the leading dense layers' "
+            "parameters are a 'dense_blocks' subtree stacked beside 'blocks'")
+    if (expert_counts or expert_picks) and not cfg.moe_is_dropless:
+        raise ValueError("expert_counts and expert_picks need a dropless "
+                         "MoE config")
     B, T = input_ids.shape
     nh, hd, kvh = cfg.num_heads, cfg.head_dim, cfg.kv_heads
     rms = cfg.norm == "rmsnorm"
@@ -297,12 +320,14 @@ def decoder_forward(cfg: TransformerConfig, params: PyTree,
     cache.plan(T)
     real = cache.real_tokens(pos) if expert_counts else None
 
-    def layer(carry, xs):
+    def layer(carry, xs, dense_mlp=False):
+        """One layer; ``dense_mlp``: one of a mixture's leading dense
+        layers (its MLP is ``p``'s own, whatever the width)."""
         x, kv = carry
-        p, window, li = xs
+        p, window, rope, li = xs
         with jax.named_scope("block.attn"):
             with jax.named_scope("qkv"):
-                h = norm(x, p["ln1"])
+                h = norm(x, p["ln1"]) if cfg.pre_norm else x
                 qkv = dense(h, p["attn_qkv"])
                 q, k, v = jnp.split(qkv, [nh * hd, (nh + kvh) * hd],
                                     axis=-1)
@@ -319,7 +344,11 @@ def decoder_forward(cfg: TransformerConfig, params: PyTree,
                                   interleaved=cfg.rotary_interleaved,
                                   theta=cfg.rope_theta,
                                   inv_freq=cfg.rope_inv_freq(cache.rope_len))
-                    q, k = rot(q), rot(k)
+                    if rope is None:
+                        q, k = rot(q), rot(k)
+                    else:   # a hybrid: this layer may carry no positions
+                        q = jnp.where(rope, rot(q), q)
+                        k = jnp.where(rope, rot(k), k)
             with jax.named_scope("kv_write"):
                 if kvh != nh:
                     # GQA: repeat kv to full heads BEFORE the write, so the
@@ -341,23 +370,30 @@ def decoder_forward(cfg: TransformerConfig, params: PyTree,
                     attn_out = norm(attn_out, p["post_attn_norm"])
 
         def mlp(hin):
-            """``(the MLP branch, this layer's expert counts or None)``."""
-            if cfg.moe_experts > 0:
+            """``(the MLP branch, (this layer's expert counts or None, its
+            picks or None))``."""
+            if cfg.moe_experts > 0 and not dense_mlp:
                 # a dropless mixture's expert stack stays whole beside the
-                # loop, the kernel picks this layer's
+                # loop, the kernel picks this layer's: the stack is indexed
+                # by the sparse layer's number, the cache by the model's
                 p_moe = (p["moe"] if experts is None
                          else dict(p["moe"], experts=experts))
-                y, routing = _moe_mlp(cfg, p_moe, hin, interpret, layer=li)
+                y, routing = _moe_mlp(
+                    cfg, p_moe, hin, interpret,
+                    layer=li - cfg.dense_layers if cfg.dense_layers else li)
+                picks = routing.experts if expert_picks else None
                 if not expert_counts:
-                    return y, None
+                    return y, (None, picks)
                 with jax.named_scope("route"):
-                    return y, jnp.zeros((cfg.moe_experts,), jnp.int32).at[
+                    return y, (jnp.zeros((cfg.moe_experts,), jnp.int32).at[
                         routing.experts.reshape(-1)].add(
-                        jnp.repeat(real.reshape(-1), cfg.moe_k))
+                        jnp.repeat(real.reshape(-1), cfg.moe_k)), picks)
             if cfg.gated_mlp:            # SwiGLU (Llama family)
                 g = act(dense(hin, p["mlp_gate"]))
-                return dense(g * dense(hin, p["mlp_fc"]), p["mlp_proj"]), None
-            return dense(act(dense(hin, p["mlp_fc"])), p["mlp_proj"]), None
+                return (dense(g * dense(hin, p["mlp_fc"]), p["mlp_proj"]),
+                        (None, None))
+            return (dense(act(dense(hin, p["mlp_fc"])), p["mlp_proj"]),
+                    (None, None))
 
         with jax.named_scope("block.mlp"):
             if cfg.parallel_residual:
@@ -368,17 +404,30 @@ def decoder_forward(cfg: TransformerConfig, params: PyTree,
                 x_out = x + attn_out + m
             else:
                 x_mid = x + attn_out
-                m, counts = mlp(norm(x_mid, p["ln2"]))
+                m, counts = mlp(norm(x_mid, p["ln2"]) if cfg.pre_norm
+                                else x_mid)
                 if cfg.post_block_norms:
                     m = norm(m, p["post_mlp_norm"])
                 x_out = x_mid + m
         return (x_out, kv), counts
 
     blocks, experts = split_stacked_experts(cfg, params["blocks"])
-    xs = (blocks, windows, jnp.arange(cfg.num_layers))
-    carry = cache.carry()
+    ropes = (jnp.asarray(cfg.layer_rope) if cfg.layer_rope is not None
+             else None)
+    n_dense = cfg.dense_layers
+    per_layer = (windows, ropes, jnp.arange(cfg.num_layers))
+    carry = (x, cache.carry())
     with jax.named_scope("layers"):
-        (x, carry), counts = jax.lax.scan(layer, (x, carry), xs)
+        if n_dense:
+            # a mixture's leading dense layers: their own stack first, the
+            # same layer body, the pools in the one carry
+            carry, _ = jax.lax.scan(
+                partial(layer, dense_mlp=True), carry,
+                (params["dense_blocks"],)
+                + jax.tree.map(lambda a: a[:n_dense], per_layer))
+            per_layer = jax.tree.map(lambda a: a[n_dense:], per_layer)
+        (x, carry), (counts, picks) = jax.lax.scan(layer, carry,
+                                                   (blocks,) + per_layer)
     out = cache.finish(carry, T)
     with jax.named_scope("head"):
         x = norm(x, params["ln_f"])
@@ -390,6 +439,8 @@ def decoder_forward(cfg: TransformerConfig, params: PyTree,
             # stays f32 (the return casts to f32 anyway): a bf16 round-trip
             # of the capped logits could flip near-tie argmaxes
             logits = apply_softcap(logits, cfg.final_logit_softcap)
+    if expert_picks:
+        return logits.astype(jnp.float32), out, counts, picks
     return logits.astype(jnp.float32), out, counts
 
 

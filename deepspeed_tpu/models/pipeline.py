@@ -74,6 +74,14 @@ class PipelinedTransformer:
             raise NotImplementedError(
                 "pipelined model does not support dropless MoE (moe_k > 2 "
                 "or moe_dropless); run it on the non-pipelined engine")
+        for knob, why in (("layer_rope", "per-layer rotary flags"),
+                          ("dense_layers", "a second stack of leading dense "
+                           "layers")):
+            # the stage body runs ONE kind of block over equal stage slices
+            if getattr(cfg, knob):
+                raise NotImplementedError(
+                    f"pipelined model does not thread {why} ({knob}); run "
+                    "this architecture on the non-pipelined engine")
         for knob in ("embed_ln", "token_type_vocab", "mlm_head",
                      "no_lm_head"):
             # same fail-loud contract: the pipelined embed/head plumbing

@@ -166,6 +166,35 @@ class TransformerConfig:
     # renormalise the moe_k router weights (HF norm_topk_prob; the GShard
     # top-2 path always does). OLMoE: False
     moe_norm_topk: bool = True
+    # -- a dropless mixture's router and share (moe/dropless.py) -------------
+    # "softmax" (OLMoE) | "sigmoid" (the DeepSeek-V3 router: independent
+    # scores; with moe_norm_topk the picks' scores are divided by their sum)
+    moe_scores: str = "softmax"
+    # a per-expert bias (``moe/gate/bias`` [E]) added to the scores for the
+    # top-k SELECTION only, never to the weights (e_score_correction_bias)
+    moe_select_bias: bool = False
+    # the picks' weights are multiplied by this (routed_scaling_factor)
+    moe_routed_scale: float = 1.0
+    # (first, count): this program HOLDS experts first .. first + count - 1
+    # of the moe_experts the router ranks (a chip's share of an
+    # expert-parallel layer, run without its exchange): the expert stack has
+    # ``count`` experts, a pick of an absent one keeps its weight in the
+    # renormalisation and computes nothing. None = all of them
+    moe_held: Optional[Tuple[int, int]] = None
+    # width of a shared expert (``moe/shared``: a SwiGLU / MLP of the
+    # experts' kind on EVERY token, added unweighted); 0 = none
+    moe_shared_dim: int = 0
+    # the first dense_layers layers keep a dense MLP of width dense_mlp_dim
+    # (first_k_dense_replace); their parameters are ``dense_blocks``
+    # [dense_layers, ...], scanned before ``blocks`` [the rest, ...]
+    dense_layers: int = 0
+    dense_mlp_dim: Optional[int] = None
+    # per-layer rotary: False = that layer carries no positions (a hybrid's
+    # global layers); None = every layer (pos_embed "rotary" only)
+    layer_rope: Optional[Tuple[bool, ...]] = None
+    # False: no norm on a branch's INPUT (no ln1 / ln2); the branch outputs
+    # are normed instead, so post_block_norms must be set (EXAONE 4.0)
+    pre_norm: bool = True
     # block-sparse attention layout (ds_config "sparse_attention" section;
     # the engine wires it here and sets attention_impl="sparse"): a hashable
     # tuple of (key, value) items — lists as tuples — so the frozen config
@@ -198,6 +227,52 @@ class TransformerConfig:
             raise ValueError(
                 f"activation_quant {self.activation_quant!r}: expected "
                 "'int8', 'fp8' or None")
+        if self.moe_scores not in ("softmax", "sigmoid"):
+            raise ValueError(f"moe_scores {self.moe_scores!r}: expected "
+                             "'softmax' or 'sigmoid'")
+        routed = [n for n, on in (
+            ("moe_scores", self.moe_scores != "softmax"),
+            ("moe_select_bias", self.moe_select_bias),
+            ("moe_routed_scale", self.moe_routed_scale != 1.0),
+            ("moe_held", self.moe_held is not None),
+            ("moe_shared_dim", self.moe_shared_dim > 0)) if on]
+        if routed and not self.moe_is_dropless:
+            raise ValueError(
+                f"{', '.join(routed)}: only the dropless mixture "
+                "(moe_experts > 0 with moe_dropless or moe_k > 2) carries "
+                "them; the GShard capacity path (moe_k <= 2) has a softmax "
+                "router over experts it holds whole, and no shared expert")
+        if self.moe_held is not None:
+            first, count = self.moe_held
+            if not (0 <= first and count > 0
+                    and first + count <= self.moe_experts):
+                raise ValueError(
+                    f"moe_held {self.moe_held}: (first, count) inside the "
+                    f"router's {self.moe_experts} experts")
+        if self.dense_layers:
+            if not (0 < self.dense_layers < self.num_layers
+                    and self.moe_experts > 0 and self.dense_mlp_dim):
+                raise ValueError(
+                    "dense_layers: leading dense layers of a mixture "
+                    "(0 < dense_layers < num_layers, moe_experts > 0, "
+                    "dense_mlp_dim their width)")
+            if not self.scan_layers or self.pld or self.ltd_tokens:
+                raise ValueError(
+                    "dense_layers needs scan_layers (dense_blocks and "
+                    "blocks are two scanned stacks), without progressive "
+                    "layer drop or random-LTD")
+        if self.layer_rope is not None and (
+                self.pos_embed != "rotary" or self.pld
+                or len(self.layer_rope) != self.num_layers):
+            raise ValueError("layer_rope: one flag a layer, with "
+                             "pos_embed='rotary' and without progressive "
+                             "layer drop")
+        if not self.pre_norm and (not self.post_block_norms or self.post_ln
+                                  or self.parallel_residual):
+            raise ValueError(
+                "pre_norm=False leaves a branch's input unnormed: its "
+                "output must be (post_block_norms=True; not post_ln, not "
+                "parallel_residual)")
 
     @property
     def qk_norm_kind(self) -> Optional[str]:
@@ -207,6 +282,16 @@ class TransformerConfig:
     @property
     def moe_is_dropless(self) -> bool:
         return self.moe_experts > 0 and (self.moe_dropless or self.moe_k > 2)
+
+    @property
+    def moe_held_count(self) -> int:
+        """Experts in the stack: the share held, or all the router ranks."""
+        return self.moe_held[1] if self.moe_held else self.moe_experts
+
+    @property
+    def sparse_layers(self) -> int:
+        """Layers whose MLP is the mixture (all of them, or none)."""
+        return self.num_layers - self.dense_layers if self.moe_experts else 0
 
     @property
     def head_dim(self) -> int:
@@ -301,15 +386,25 @@ class TransformerConfig:
         return (self.num_heads + 2 * self.kv_heads) * self.head_dim * h \
             + self.num_heads * self.head_dim * h   # qkv (GQA) + out proj
 
-    def _mlp_params(self) -> int:
-        return (3 if self.gated_mlp else 2) * self.mlp_dim * self.hidden_size
+    def _mlp_params(self, width: Optional[int] = None) -> int:
+        """One MLP of ``width`` (default: the model's, an expert's)."""
+        return (3 if self.gated_mlp else 2) * self.hidden_size \
+            * (self.mlp_dim if width is None else width)
+
+    def _dense_layer_params(self) -> int:
+        """The leading dense layers' (``dense_layers`` of them)."""
+        return self.dense_layers * (
+            self._attn_params() + self._mlp_params(self.dense_mlp_dim or 0))
 
     def num_params(self) -> int:
+        """Parameters this program holds (``moe_held``: the share's)."""
         per_layer = self._attn_params() \
-            + self._mlp_params() * max(self.moe_experts, 1)
+            + self._mlp_params() * max(self.moe_held_count, 1)
         if self.moe_experts > 0:
             per_layer += self.hidden_size * self.moe_experts  # router
-        return self._embed_params() + self.num_layers * per_layer
+            per_layer += self._mlp_params(self.moe_shared_dim)
+        return self._embed_params() + self._dense_layer_params() \
+            + (self.num_layers - self.dense_layers) * per_layer
 
     def num_active_params(self) -> int:
         """Params touched per token (== num_params for dense; MoE routes each
@@ -318,8 +413,10 @@ class TransformerConfig:
         if self.moe_experts <= 0:
             return self.num_params()
         per_layer = (self._attn_params() + self._mlp_params() * self.moe_k
-                     + self.hidden_size * self.moe_experts)
-        return self._embed_params() + self.num_layers * per_layer
+                     + self.hidden_size * self.moe_experts
+                     + self._mlp_params(self.moe_shared_dim))
+        return self._embed_params() + self._dense_layer_params() \
+            + (self.num_layers - self.dense_layers) * per_layer
 
     # -- tensor-parallel sharding rules (regex on param path -> PartitionSpec) --
     def tp_rules(self) -> Dict[str, P]:
@@ -647,10 +744,16 @@ class Block(nn.Module):
     alibi positions, per-layer local windows (GPT-Neo), activations.
     """
     cfg: TransformerConfig
+    # one of a mixture's leading dense layers (``cfg.dense_layers``): a dense
+    # MLP of width ``cfg.dense_mlp_dim`` in the mixture's place
+    dense_mlp: bool = False
 
     @nn.compact
     def __call__(self, x, attn_mask=None, train: bool = False, window=None,
-                 positions=None):
+                 positions=None, rope=None):
+        """``rope`` (``cfg.layer_rope``): whether THIS layer rotates q and k,
+        a Python bool or a traced one under the layer scan; None = the
+        model's ``pos_embed`` alone decides."""
         cfg = self.cfg
         # entry constraint pairs with the exit constraints below: its
         # TRANSPOSE pins the block-input cotangent — the backward layer-scan
@@ -697,7 +800,7 @@ class Block(nn.Module):
             if nh % kv != 0:
                 raise ValueError(f"num_heads {nh} not divisible by "
                                  f"num_kv_heads {kv}")
-            h = x if cfg.post_ln else ln("ln1")(x)
+            h = x if cfg.post_ln or not cfg.pre_norm else ln("ln1")(x)
             # one fused qkv matmul even under GQA: [H, (nh + 2*kv) * hd]
             qkv = dense((nh + 2 * kv) * hd, "attn_qkv", bias=cfg.qkv_bias)(h)
             q, k, v = jnp.split(qkv, [nh * hd, (nh + kv) * hd], axis=-1)
@@ -714,13 +817,16 @@ class Block(nn.Module):
                 # Qwen3: RMSNorm over head_dim on q/k, before rotary (HF
                 # Qwen3Attention.q_norm/k_norm — per-head, scale-only)
                 q, k = qk_ln("q_norm")(q), qk_ln("k_norm")(k)
-            if cfg.pos_embed == "rotary":
+            if cfg.pos_embed == "rotary" and rope is not False:
                 pos = positions if positions is not None else jnp.arange(S)
                 inv_freq = cfg.rope_inv_freq(S)     # None = plain-theta table
-                q = apply_rotary(q, pos, cfg.rotary_dim, cfg.rotary_interleaved,
-                                 cfg.rope_theta, inv_freq=inv_freq)
-                k = apply_rotary(k, pos, cfg.rotary_dim, cfg.rotary_interleaved,
-                                 cfg.rope_theta, inv_freq=inv_freq)
+                rot = lambda t: apply_rotary(
+                    t, pos, cfg.rotary_dim, cfg.rotary_interleaved,
+                    cfg.rope_theta, inv_freq=inv_freq)
+                if rope is None or rope is True:
+                    q, k = rot(q), rot(k)
+                else:       # traced: a hybrid's layers share one scan body
+                    q, k = jnp.where(rope, rot(q), q), jnp.where(rope, rot(k), k)
             if kv != nh:
                 # grouped-query: each k/v head serves nh/kv query heads
                 k = jnp.repeat(k, nh // kv, axis=1)
@@ -788,7 +894,7 @@ class Block(nn.Module):
                 return _mlp(h)
 
         def _mlp(h):
-            if cfg.moe_experts > 0:
+            if cfg.moe_experts > 0 and not self.dense_mlp:
                 from ..moe.layer import MoE
                 # gated_mlp: SwiGLU experts (Mixtral, OLMoE); else the
                 # fc -> gelu -> proj expert
@@ -803,15 +909,20 @@ class Block(nn.Module):
                     dropless=cfg.moe_is_dropless,
                     norm_topk=cfg.moe_norm_topk,
                     aux_stats=cfg.moe_is_dropless,
+                    scores=cfg.moe_scores,
+                    select_bias=cfg.moe_select_bias,
+                    routed_scale=cfg.moe_routed_scale, held=cfg.moe_held,
+                    shared_dim=cfg.moe_shared_dim,
                     dtype=cfg.dtype,
                     name="moe")(h, train=train)
+            width = cfg.dense_mlp_dim if self.dense_mlp else cfg.mlp_dim
             if cfg.gated_mlp:
                 # SwiGLU (Llama family): down(act(gate(x)) * up(x)); the
                 # gate/up matmuls fuse side by side on the MXU
-                g = act(dense(cfg.mlp_dim, "mlp_gate", bias=cfg.mlp_bias)(h))
-                h = g * dense(cfg.mlp_dim, "mlp_fc", bias=cfg.mlp_bias)(h)
+                g = act(dense(width, "mlp_gate", bias=cfg.mlp_bias)(h))
+                h = g * dense(width, "mlp_fc", bias=cfg.mlp_bias)(h)
                 return dense(H, "mlp_proj", bias=cfg.mlp_bias)(h), aux
-            h = dense(cfg.mlp_dim, "mlp_fc")(h)
+            h = dense(width, "mlp_fc")(h)
             h = act(h)
             h = dense(H, "mlp_proj")(h)
             return h, aux
@@ -837,7 +948,7 @@ class Block(nn.Module):
             # Gemma-2 sandwich: norm each branch OUTPUT before its residual
             out = ln("post_attn_norm")(out)
         x = _batch_constraint(x + out)
-        m, aux = mlp(x, "ln2")
+        m, aux = mlp(x, "ln2" if cfg.pre_norm else None)
         if cfg.post_block_norms:
             m = ln("post_mlp_norm")(m)
         if cfg.dropout > 0.0 and train:
@@ -937,6 +1048,8 @@ class Transformer(nn.Module):
         static_window = uw or None
         windows = (jnp.asarray(cfg.layer_windows, jnp.int32)
                    if uw is None else None)
+        ropes = (jnp.asarray(cfg.layer_rope) if cfg.layer_rope is not None
+                 else None)
         pld_on = cfg.pld and train and self.has_rng("pld")
         theta = jnp.asarray(1.0, jnp.float32)
         if pld_on and isinstance(batch, dict) and \
@@ -969,24 +1082,37 @@ class Transformer(nn.Module):
                 split = {"params": True, "dropout": True, "gating": True,
                          "pld": True}
             else:
-                def body(mdl, carry, w):
+                def body(mdl, carry, xs):
+                    w, rope = xs if ropes is not None else (xs, None)
                     return mdl(carry, attn_mask, train,
                                static_window if w is None else w,
-                               user_positions)
+                               user_positions, rope)
 
-                xs = windows
+                xs = windows if ropes is None else (windows, ropes)
                 split = {"params": True, "dropout": True, "gating": True}
+
+            def stack(x, name, lo, hi, **kind):
+                """Layers lo .. hi - 1: one scanned stack of parameters."""
+                part = xs if (lo, hi) == (0, L) else jax.tree.map(
+                    lambda a: a[lo:hi], xs)
+                return nn.scan(
+                    body,
+                    variable_axes={"params": 0},
+                    split_rngs=split,
+                    length=hi - lo,
+                    metadata_params={nn.PARTITION_NAME: "layers"},
+                )(block(cfg, name=name, **kind), x, part)
+
             # "layers" names what the loop itself costs (slicing the
             # stacked parameters, stacking residuals and gradients); the
             # blocks' own scopes lie inside it
             with jax.named_scope("layers"):
-                x, auxes = nn.scan(
-                    body,
-                    variable_axes={"params": 0},
-                    split_rngs=split,
-                    length=cfg.num_layers,
-                    metadata_params={nn.PARTITION_NAME: "layers"},
-                )(block(cfg, name="blocks"), x, xs)
+                if cfg.dense_layers:
+                    # a mixture's leading dense layers, a stack of their
+                    # own (no router: nothing for the auxiliary loss)
+                    x, _ = stack(x, "dense_blocks", 0, cfg.dense_layers,
+                                 dense_mlp=True)
+                x, auxes = stack(x, "blocks", cfg.dense_layers, L)
             aux_total = jnp.sum(auxes, axis=0)
         else:
             aux_total = jnp.zeros((), jnp.float32)
@@ -1021,7 +1147,9 @@ class Transformer(nn.Module):
                                    jnp.take(position_ids, idx, axis=1))
                     x = x.at[:, idx].set(out)
                 else:
-                    x, aux = blk(x, attn_mask, train, w, user_positions)
+                    x, aux = blk(x, attn_mask, train, w, user_positions,
+                                 None if cfg.layer_rope is None
+                                 else bool(cfg.layer_rope[i]))
                 if pld_on:
                     x, aux = pld_gate(self.make_rng("pld"), x_in, x, aux,
                                       float(i))
@@ -1032,7 +1160,7 @@ class Transformer(nn.Module):
             # load_balancing_loss_func takes the means over ALL layers'
             # tokens before the product (a layer PLD dropped adds zeros)
             from ..moe.dropless import balance_loss
-            aux_total = balance_loss(aux_total / cfg.num_layers)
+            aux_total = balance_loss(aux_total / cfg.sparse_layers)
         with jax.named_scope("head"):
             if not cfg.post_ln:
                 # post-LN stacks (BERT) end already normalized by each block's ln2

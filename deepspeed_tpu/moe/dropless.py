@@ -7,10 +7,13 @@ capacity path in ``sharded_moe.py`` cannot carry, because a one-hot over
 ``[tokens, experts, capacity]`` costs ``experts / k`` times the needed
 FLOPs and a capacity drops tokens the architecture never drops.
 
-    route     float32 softmax over the router's logits, top-k (optionally
-              renormalised over the k picks)
+    route     float32 scores of the router's logits (a softmax, or
+              independent sigmoids with a selection bias), top-k (optionally
+              renormalised over the k picks, and scaled)
     dispatch  sort the ``tokens x k`` assignments by expert; gather the
               token rows in that order; ``group_sizes [E]`` = rows an expert
+              (``held``: over the experts this program holds; the picks of
+              absent experts sort behind the last group and compute nothing)
     experts   the expert MLP as grouped matmuls over the ragged groups
               (:func:`grouped_matmul`: on TPU the Pallas ``megablox`` kernel,
               ``gmm`` in a trace; elsewhere ``jax.lax.ragged_dot``) -- cost
@@ -27,7 +30,7 @@ trains.
 from __future__ import annotations
 
 import functools
-from typing import Any, Callable, Dict, NamedTuple, Optional
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -35,7 +38,7 @@ import jax.numpy as jnp
 
 class Routing(NamedTuple):
     """What the router decided, for the caller's loss or counters."""
-    probs: jnp.ndarray         # [T, E] float32 softmax of the router
+    probs: jnp.ndarray         # [T, E] float32 scores of the router
     experts: jnp.ndarray       # [T, k] int32 the picks, best first
     weights: jnp.ndarray       # [T, k] float32 their combine weights
     group_sizes: jnp.ndarray   # [E] int32 rows routed to each expert
@@ -49,9 +52,31 @@ def route_topk(logits: jnp.ndarray, k: int, renorm: bool) -> Routing:
     weights, experts = jax.lax.top_k(probs, k)
     if renorm:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
-    sizes = jnp.zeros((logits.shape[-1],), jnp.int32).at[
-        experts.reshape(-1)].add(1)
-    return Routing(probs, experts.astype(jnp.int32), weights, sizes)
+    return Routing(probs, experts.astype(jnp.int32), weights,
+                   _group_sizes(experts, logits.shape[-1]))
+
+
+def route_sigmoid_topk(logits: jnp.ndarray, k: int, renorm: bool,
+                       bias: Optional[jnp.ndarray] = None,
+                       scale: float = 1.0) -> Routing:
+    """The DeepSeek-V3 router without groups: every expert's score is its
+    own float32 sigmoid; the k largest of ``score + bias`` are picked (the
+    bias steers the SELECTION and never weighs); the picks' weights are their
+    scores, with ``renorm`` over their sum + 1e-20, times ``scale``."""
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    select = scores if bias is None else scores + bias.astype(jnp.float32)
+    _, experts = jax.lax.top_k(select, k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if renorm:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    if scale != 1.0:
+        weights = weights * scale
+    return Routing(scores, experts.astype(jnp.int32), weights,
+                   _group_sizes(experts, logits.shape[-1]))
+
+
+def _group_sizes(experts: jnp.ndarray, n: int) -> jnp.ndarray:
+    return jnp.zeros((n,), jnp.int32).at[experts.reshape(-1)].add(1)
 
 
 def balance_stats(r: Routing) -> jnp.ndarray:
@@ -161,8 +186,21 @@ def dropless_moe(tokens: jnp.ndarray, router_kernel: jnp.ndarray,
                  experts: Dict[str, Any], *, k: int, renorm: bool,
                  act: Callable, kernel_of: Optional[Callable] = None,
                  interpret: bool = False,
-                 layer: Optional[jnp.ndarray] = None):
+                 layer: Optional[jnp.ndarray] = None,
+                 scores: str = "softmax",
+                 select_bias: Optional[jnp.ndarray] = None,
+                 scale: float = 1.0,
+                 held: Optional[Tuple[int, int]] = None):
     """``tokens [T, H]`` through a router and ``E`` expert MLPs, k a token.
+
+    ``scores``: "softmax" (:func:`route_topk`) or "sigmoid"
+    (:func:`route_sigmoid_topk`, with ``select_bias [E]`` and ``scale``).
+    ``held = (first, count)``: the router ranks all its ``E`` outputs and
+    ``experts`` holds ``count`` of them, ``first ..``: a chip's share of an
+    expert-parallel layer, run without its exchange. The groups are the held
+    experts'; a pick of an absent expert keeps its weight in the
+    renormalisation, sorts behind the last group (rows the grouped matmul
+    never walks) and adds nothing. ``Routing`` is over the router's ``E``.
 
     ``experts``: ``{"fc", "proj"[, "gate"]}``, each ``{"kernel" [E, in,
     out][, "bias" [E, out]]}`` -- the repo's stacked expert tree. With
@@ -183,22 +221,36 @@ def dropless_moe(tokens: jnp.ndarray, router_kernel: jnp.ndarray,
         logits = jnp.dot(tokens.astype(jnp.float32),
                          router_kernel.astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
-        r = route_topk(logits, k, renorm)
+        if scores == "sigmoid":
+            r = route_sigmoid_topk(logits, k, renorm, select_bias, scale)
+        else:
+            r = route_topk(logits, k, renorm)
     with jax.named_scope("dispatch"):
-        picks = r.experts.reshape(T * k)
+        picks, sizes, weights = r.experts.reshape(T * k), r.group_sizes, \
+            r.weights
+        if held is not None:
+            first, count = held
+            here = (r.experts >= first) & (r.experts < first + count)
+            # the stack's own numbering; an absent pick behind its last group
+            picks = jnp.where(here, r.experts - first, count).reshape(T * k)
+            sizes = jax.lax.dynamic_slice_in_dim(sizes, first, count)
+            weights = jnp.where(here, weights, 0.0)
         order = jnp.argsort(picks, stable=True)       # rows, by expert
         expert_of_row = picks[order]
         rows = tokens[order // k]                      # [T * k, H]
 
     def dense(x, p):
         if layer is None:
-            y = grouped_matmul(x, kernel_of(p), r.group_sizes, interpret)
+            y = grouped_matmul(x, kernel_of(p), sizes, interpret)
         else:
-            y = grouped_matmul_of_layer(x, kernel_of(p), r.group_sizes,
-                                        layer, interpret)
+            y = grouped_matmul_of_layer(x, kernel_of(p), sizes, layer,
+                                        interpret)
         if "bias" in p:
             bias = p["bias"] if layer is None else p["bias"][layer]
-            y = y + bias.astype(y.dtype)[expert_of_row]
+            # (an absent pick's row reads the last expert's: never kept)
+            y = y + bias.astype(y.dtype)[expert_of_row if held is None else
+                                         jnp.minimum(expert_of_row,
+                                                     held[1] - 1)]
         return y
 
     with jax.named_scope("experts"):
@@ -211,6 +263,9 @@ def dropless_moe(tokens: jnp.ndarray, router_kernel: jnp.ndarray,
     with jax.named_scope("combine"):
         back = jnp.argsort(order)                      # row of pick (t, j)
         picked = out[back].reshape(T, k, H)
-        y = jnp.einsum("tk,tkh->th", r.weights,
+        if held is not None:
+            # rows no group walked hold whatever the kernel's buffer held
+            picked = jnp.where(here[:, :, None], picked, 0)
+        y = jnp.einsum("tk,tkh->th", weights,
                        picked.astype(jnp.float32)).astype(tokens.dtype)
     return y, r

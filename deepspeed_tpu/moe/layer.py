@@ -77,12 +77,19 @@ class _Gate(nn.Module):
     moe reshape. Gathering the (tiny) kernel whole instead keeps tokens on
     their batch sharding. Param path stays gate/kernel (nn.Dense parity)."""
     experts: int
+    # a dropless mixture's selection bias, ``bias [experts]`` (zeros; its
+    # owner updates it outside the gradient): ``kernel_only`` then returns
+    # ``(kernel, bias)``
+    select_bias: bool = False
 
     @nn.compact
     def __call__(self, x, kernel_only: bool = False):
         k = self.param("kernel", nn.initializers.lecun_normal(),
                        (x.shape[-1], self.experts), jnp.float32)
         k = _constrain(k, None, None)
+        if self.select_bias:
+            return k, self.param("bias", nn.initializers.zeros_init(),
+                                 (self.experts,), jnp.float32)
         return k if kernel_only else x @ k
 
 
@@ -156,6 +163,14 @@ class MoE(nn.Module):
     # dropless only: return the ``[2, E]`` balance statistics in place of
     # this layer's scalar loss, for a model that averages them over layers
     aux_stats: bool = False
+    # dropless only (``moe/dropless.dropless_moe`` says what each means):
+    # the router's scores, its selection bias, the picks' scale, the share
+    # of the ``num_experts`` this module holds, a shared expert's width
+    scores: str = "softmax"
+    select_bias: bool = False
+    routed_scale: float = 1.0
+    held: Optional[Tuple[int, int]] = None
+    shared_dim: int = 0
 
     @property
     def is_dropless(self) -> bool:
@@ -313,16 +328,32 @@ class MoE(nn.Module):
         B, S, H = x.shape
         width = self.expert_width
         tokens = _constrain(x.reshape(B * S, H), TOKEN_AXES, None)
-        router_kernel = _Gate(self.num_experts, name="gate")(
-            tokens, kernel_only=True)
+        router_kernel = _Gate(self.num_experts, self.select_bias,
+                              name="gate")(tokens, kernel_only=True)
+        select_bias = None
+        if self.select_bias:
+            router_kernel, select_bias = router_kernel
         names = (("gate", H, width),) if self.gated else ()
         names += (("fc", H, width), ("proj", width, H))
-        experts = _ExpertStack(self.num_experts, names, self.use_bias,
-                               name="experts")()
+        experts = _ExpertStack(
+            self.held[1] if self.held else self.num_experts, names,
+            self.use_bias, name="experts")()
         y, r = dropless_moe(
             tokens.astype(self.dtype), router_kernel, experts,
             k=self.k, renorm=self.norm_topk,
-            act=_ACTIVATIONS[self.activation] if self.gated else nn.gelu)
+            act=_ACTIVATIONS[self.activation] if self.gated else nn.gelu,
+            scores=self.scores, select_bias=select_bias,
+            scale=self.routed_scale, held=self.held)
+        if self.shared_dim:
+            # the shared expert: the experts' body on every token, unweighted
+            with jax.named_scope("shared"):
+                body = (GatedExpertMLP(H, self.shared_dim, dtype=self.dtype,
+                                       use_bias=self.use_bias,
+                                       activation=self.activation,
+                                       name="shared") if self.gated else
+                        ExpertMLP(H, self.shared_dim, dtype=self.dtype,
+                                  use_bias=self.use_bias, name="shared"))
+                y = y + body(tokens.astype(self.dtype))
         y = _constrain(y, TOKEN_AXES, None).reshape(B, S, H)
         stats = balance_stats(r)
         return y, (stats if self.aux_stats else balance_loss(stats))

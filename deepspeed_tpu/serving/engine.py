@@ -75,13 +75,17 @@ _COUNTERS = (
     "admit_blocked.prefilling", "compiles",
     "kv.held_blocks_sum", "kv.blocks_reserved_sum", "kv.tokens_written_sum",
     "prefix.prompt_tokens", "paged.live_pages_sum", "paged.table_pages_sum",
+    "paged.window_pages_sum",
     "paged.chunk_live_pages_sum", "paged.chunk_table_pages_sum",
     "step_inputs.transfers_sum", "step_inputs.lane_rows_written_sum",
     "decode_ahead.launched", "decode_ahead.device_lane_tokens_sum",
     "decode_ahead.wasted_lane_tokens", "decode_ahead.retired_unread")
-#: a dropless MoE model's router load, from the [L, E] counts that ride the
-#: tokens' own fetch (``_count_experts``); a dense model has none of these
-_MOE_COUNTERS = ("moe.assignments", "moe.layer_steps",
+#: a dropless MoE model's router load, from the [sparse layers, E] counts
+#: that ride the tokens' own fetch (``_count_experts``); a dense model has
+#: none of these. ``moe.held_assignments``: the assignments whose expert this
+#: program holds (``cfg.moe_held``; all of them without a share): over
+#: ``moe.assignments`` the share's load, 1/8 for an eighth under even routing
+_MOE_COUNTERS = ("moe.assignments", "moe.held_assignments", "moe.layer_steps",
                  "moe.load_max_over_mean_sum", "moe.experts_idle_sum")
 
 _KV_DTYPES = {"bf16": jnp.bfloat16, "bfloat16": jnp.bfloat16,
@@ -237,9 +241,13 @@ def step_programs(cfg, block_size: int, table_width: int, *,
 
     For a dropless MoE config (``cfg.moe_is_dropless``) the int32 token
     vector each returns carries, behind the tokens, the ``[L, E]`` expert
-    counts of the call, flattened (``ServingEngine._count_experts`` splits
-    them): one array, the fetch the step has already. Every other config
-    gets the plain token vector."""
+    counts of the call (``L`` the sparse layers, ``E`` the router's
+    outputs), flattened (``ServingEngine._count_experts`` splits
+    them): one array, the fetch the step has already; and beside that vector,
+    as a second output, the call's picks ``[L, lanes x T, k]`` int32
+    (``decoder_forward``'s ``expert_picks``), which stay on the device
+    unless a request of the call asked for them (``submit``'s
+    ``keep_routing``). Every other config gets the plain token vector."""
     bs, layout = int(block_size), StepLayout(table_width, key_words)
     counting = bool(cfg.moe_is_dropless)
 
@@ -259,32 +267,35 @@ def step_programs(cfg, block_size: int, table_width: int, *,
             sampled = jax.random.categorical(r, scaled, axis=-1)
             return jnp.where(temps <= 0.0, greedy, sampled)
 
-    def _out(tokens, counts):
-        # a dropless mixture: the call's expert counts behind its tokens
+    def _out(tokens, routed):
+        # a dropless mixture: the call's expert counts behind its tokens,
+        # and its picks beside them
         if not counting:
             return tokens
+        counts, picks = routed
         return jnp.concatenate([tokens.astype(jnp.int32),
-                                counts[0].reshape(-1)])
+                                counts.reshape(-1)]), picks
 
     def _decode(params, pools, step_in, prev, first):
         toks, ctx, tks, bt, temps, tps, key = layout.decode(step_in)
         toks = layout.lane_tokens(toks, prev, first)
         # toks [B] sit at logical position ctx[b]; after the write the
         # valid length is ctx + 1
-        logits, pools, *counts = paged_forward(
+        logits, pools, *routed = paged_forward(
             cfg, params, toks[:, None], pools, bt, ctx, ctx + 1, bs,
-            interpret=interpret, expert_counts=counting)
-        return _out(_pick(logits[:, -1], key, temps, tks, tps), counts), pools
+            interpret=interpret, expert_counts=counting,
+            expert_picks=counting)
+        return _out(_pick(logits[:, -1], key, temps, tks, tps), routed), pools
 
     def _prefill(params, pools, step_in):
         ids, bt, q0, ctx, last_idx, tks, temps, tps, key = \
             layout.prefill(step_in)
-        logits, pools, *counts = paged_forward(
+        logits, pools, *routed = paged_forward(
             cfg, params, ids, pools, bt, q0, ctx, bs, interpret=interpret,
-            expert_counts=counting)
+            expert_counts=counting, expert_picks=counting)
         last = jax.lax.dynamic_index_in_dim(logits, last_idx[0], 1,
                                             keepdims=False)   # [1, V]
-        return _out(_pick(last, key, temps, tks, tps), counts), pools
+        return _out(_pick(last, key, temps, tks, tps), routed), pools
 
     return _decode, _prefill
 
@@ -293,7 +304,7 @@ def token_words(cfg, lanes: int) -> int:
     """Length of the int32 vector a device call over ``lanes`` lanes returns
     (``step_programs``): its tokens, and behind them a dropless mixture's
     ``[L, E]`` expert counts."""
-    return lanes + (cfg.num_layers * cfg.moe_experts
+    return lanes + (cfg.sparse_layers * cfg.moe_experts
                     if cfg.moe_is_dropless else 0)
 
 
@@ -408,10 +419,12 @@ class _ChunkOut:
 class _InFlight:
     """A decode call launched and not yet fetched: its output on the device,
     the lanes whose token of it is still wanted (a lane freed meanwhile is
-    struck out), and the call's number."""
+    struck out), and the call's number; of a dropless mixture also the
+    call's picks ``[L, lanes, k]``, on the device."""
     out: Any
     go: np.ndarray
     call: int
+    picks: Any = None
 
 
 class _HeldBlocks:
@@ -499,6 +512,9 @@ class ServingEngine:
                                  cfg.max_seq_len)
         self.nbk = -(-self.max_model_len // bs)      # table width
         self.interpret = interpret
+        # the windows of the layers that have one (``paged.window_pages_sum``)
+        self._windows = np.asarray(
+            [w for w in cfg.layer_windows or () if w > 0], np.int64)
         if cfg.rope_scaling_type == "dynamic":
             # dynamic NTK derives its table from the cache capacity, which
             # differs between the pool (max_blocks_per_seq * block_size)
@@ -640,7 +656,11 @@ class ServingEngine:
         self._calls += 1
         step_in[-self._key.size:] = self._call_key(self._calls).view(np.int32)
         self.stats["step_inputs.transfers_sum"] += 1
-        return self._run_device(fn, step_in, *on_device)
+        out = self._run_device(fn, step_in, *on_device)
+        if self.cfg.moe_is_dropless:
+            # the call's picks stay on the device, for a request that asked
+            out, self._picks_out = out
+        return out
 
     def _count_experts(self, out: np.ndarray, call: int) -> None:
         """A dropless MoE model's router load, from the fetched output of
@@ -655,15 +675,21 @@ class ServingEngine:
         got = [out] + [np.asarray(a) for _, a in pending[:n]]
         del pending[:n]
         E, c = self.cfg.moe_experts, self.stats
+        first, held_n = self.cfg.moe_held or (0, E)
         for a in got:
-            counts = a[len(a) - self.cfg.num_layers * E:].reshape(-1, E)
+            counts = a[len(a) - self.cfg.sparse_layers * E:].reshape(-1, E)
             routed = counts.sum(axis=1)
             live = routed > 0               # a call of padding only: nothing
             c["moe.assignments"] += int(routed.sum())
             c["moe.layer_steps"] += int(live.sum())
+            # load and idleness are of the experts HELD: the kernel's groups
+            held = counts[:, first:first + held_n]
+            rows = held.sum(axis=1)
+            c["moe.held_assignments"] += int(rows.sum())
+            fed = rows > 0
             c["moe.load_max_over_mean_sum"] += float(
-                (counts.max(axis=1)[live] * E / routed[live]).sum())
-            c["moe.experts_idle_sum"] += int((counts[live] == 0).sum())
+                (held.max(axis=1)[fed] * held_n / rows[fed]).sum())
+            c["moe.experts_idle_sum"] += int((held[live] == 0).sum())
 
     # ------------------------------------------------------------- submission
 
@@ -671,7 +697,8 @@ class ServingEngine:
                temperature: float = 0.0, eos_token_id: Optional[int] = None,
                on_finish=None, top_k=None, top_p=None,
                deadline_s: Optional[float] = None,
-               priority: str = STANDARD) -> Request:
+               priority: str = STANDARD,
+               keep_routing: bool = False) -> Request:
         """Enqueue a generation request (thread-safe); returns the live
         :class:`Request` whose ``output_tokens``/``state`` the caller (or
         ``on_finish``) observes. ``deadline_s`` is a queue-wait TTL: a
@@ -681,6 +708,17 @@ class ServingEngine:
         (round 19) picks the latency/standard/batch tier — dispatch
         order and the overload ladder's shed order; see
         docs/SERVING.md §Priority.
+
+        ``keep_routing`` (a dropless mixture; a dense model ignores it):
+        the finished request carries ``routed_experts``, an int32 array
+        ``[len(prompt) + len(output_tokens) - 1, layers, k]``: for every
+        token the model was FED (the prompt, then every generated token but
+        the last) the experts each mixture layer picked, best first. A
+        prompt position whose K/V came from the prefix cache was never
+        computed and reads -1. Costs one small fetch a device call that
+        carries the request (``routing.fetches``); a request that does not
+        ask costs none. Not carried through a fleet's requeue or a disagg
+        handoff.
 
         ``top_k``/``top_p`` (round 12) require
         ``serving.sampling_filters`` — the vectorized per-lane filter
@@ -703,7 +741,9 @@ class ServingEngine:
                       top_k=int(top_k) if top_k is not None else None,
                       top_p=float(top_p) if top_p is not None else None,
                       eos_token_id=eos_token_id, on_finish=on_finish,
-                      priority=priority)
+                      priority=priority,
+                      keep_routing=bool(keep_routing)
+                      and self.cfg.moe_is_dropless)
         if deadline_s is not None:
             req.deadline_ts = req.arrival_ts + float(deadline_s)
         with self.rec.span("serve.submit", rid=req.rid):
@@ -1184,6 +1224,8 @@ class ServingEngine:
             with rec.span("serve.prefill.dispatch",
                           program=self._prefill_program):
                 tok = self._call_device(self._prefill_fn, step_in)
+            if req.keep_routing:
+                req._routing.append((pf.done, n, self._picks_out))
         except BaseException as e:
             # a failed chunk must not leak the lifetime allocation —
             # release EVERYTHING (partial K/V is recomputed on retry; the
@@ -1263,7 +1305,8 @@ class ServingEngine:
         out = np.asarray(out)
         if self.cfg.moe_is_dropless:
             self._count_experts(out, call)
-            out = out[:len(out) - self.cfg.num_layers * self.cfg.moe_experts]
+            out = out[:len(out)
+                      - self.cfg.sparse_layers * self.cfg.moe_experts]
         return out
 
     def _first_token(self, seq: _Prefilled, slot: Optional[int] = None,
@@ -1323,6 +1366,8 @@ class ServingEngine:
             with rec.span("serve.prefill.dispatch",
                           program=self._prefill_program):
                 tok = self._call_device(self._prefill_fn, step_in)
+            if req.keep_routing:
+                req._routing.append((n_pref, len(suffix), self._picks_out))
         except BaseException as e:
             # a failed forward (device OOM, interrupt) must not leak the
             # refcounted blocks — capacity survives the exception. A
@@ -1384,6 +1429,14 @@ class ServingEngine:
             rec.count("paged.live_pages_sum",
                       int((lanes.ctx * go // self.block_size + 1).sum()))
             rec.count("paged.table_pages_sum", B * self.nbk)
+            if self._windows.size:
+                # of those, what the window layers' calls walk, summed over
+                # those layers: from the page of a lane's first key in reach
+                n = lanes.ctx * go + 1
+                reach = np.maximum(n - self._windows[:, None], 0)
+                rec.count("paged.window_pages_sum", int(
+                    (-(-n // self.block_size)
+                     - reach // self.block_size).sum()))
             rec.count("decode_ahead.device_lane_tokens_sum",
                       int(np.count_nonzero(lanes.toks[go] < 0)))
             step_in = lanes.launch(go)
@@ -1394,7 +1447,8 @@ class ServingEngine:
         if ahead:
             self.stats["decode_ahead.launched"] += 1
         self._dec_out = out
-        self._flight = _InFlight(out, go, self._calls)
+        self._flight = _InFlight(out, go, self._calls,
+                                 getattr(self, "_picks_out", None))
 
     def _book(self, call: _InFlight) -> int:
         """Fetch a decode call's tokens and book them: a token a lane, and
@@ -1405,9 +1459,18 @@ class ServingEngine:
         with rec.span("serve.decode.bookkeep"):
             live = np.flatnonzero(call.go).tolist()
             self.stats["tokens_generated"] += len(live)
+            picks = None
+            if any(self._slots[i].req.keep_routing for i in live):
+                # the call fed each live lane one token: its picks, fetched
+                # with the call's tokens and only for a lane that asked
+                picks = np.asarray(call.picks)          # [L, lanes, k]
+                self.stats["routing.fetches"] = \
+                    self.stats.get("routing.fetches", 0) + 1
             for i in live:
                 seq = self._slots[i]
                 req, tok = seq.req, toks[i]
+                if req.keep_routing:
+                    req._routing.append(picks[:, i])
                 req.output_tokens.append(tok)
                 if tok == req.eos_token_id \
                         or len(req.output_tokens) >= req.max_new_tokens:
@@ -1428,6 +1491,25 @@ class ServingEngine:
         self.stats["decode_ahead.retired_unread"] += 1
         return self._book(call) if book else 0
 
+    def _gather_routing(self, req: Request) -> None:
+        """``req.routed_experts`` from what its calls left: a prompt chunk's
+        picks still on the device (every call before the one whose tokens
+        ended the request has run), a decode call's row as it was booked."""
+        rows = np.full((len(req.prompt), self.cfg.sparse_layers,
+                        self.cfg.moe_k), -1, np.int32)
+        fed = []
+        for part in req._routing:
+            if isinstance(part, tuple):
+                q0, n, picks = part
+                rows[q0:q0 + n] = np.asarray(picks)[:, :n].transpose(1, 0, 2)
+                self.stats["routing.fetches"] = \
+                    self.stats.get("routing.fetches", 0) + 1
+            else:
+                fed.append(part)
+        req.routed_experts = np.concatenate([rows, np.stack(fed)]) \
+            if fed else rows
+        req._routing = []
+
     def _finish(self, seq: _Seq) -> None:
         # the blocks go back at once, though a decode call launched before
         # the host saw this end (an EOS) may still write the lane's next
@@ -1437,6 +1519,8 @@ class ServingEngine:
         # never falls into the full prompt blocks the prefix cache retains
         self.pool.release(seq.blocks)
         self.stats["completed"] += 1
+        if seq.req.keep_routing:
+            self._gather_routing(seq.req)
         seq.req._finish(FINISHED)
         self.rec.event("serve.req.finished", rid=seq.req.rid,
                        ts=seq.req.finish_ts)

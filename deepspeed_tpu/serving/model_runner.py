@@ -228,14 +228,17 @@ def paged_forward(cfg: TransformerConfig,
                   block_size: int,
                   *,
                   interpret: bool = False,
-                  expert_counts: bool = False
+                  expert_counts: bool = False,
+                  expert_picks: bool = False
                   ) -> Tuple[jnp.ndarray, ...]:
     """Run T tokens per lane at logical positions [q_start, q_start + T)
     against the paged pool. Returns (logits [B, T, V] f32, updated pools)
     and, with ``expert_counts`` (a dropless MoE config only), ``[L, E]``
     int32: how many of this call's REAL tokens each layer's router sent to
     each expert (padding positions and lanes with no sequence are routed and
-    computed like any row, and not counted).
+    computed like any row, and not counted); with ``expert_picks`` behind
+    that ``[L, B x T, k]`` int32, the experts each layer picked for every row
+    (``decoder_forward``).
 
     input_ids: [B, T]. pools: {"k","v"} [L, nh, num_slots, hd]
     (``serving.kv_cache.init_pool`` layout; ``num_slots`` = pool blocks x
@@ -269,9 +272,9 @@ def paged_forward(cfg: TransformerConfig,
     """
     cache = PagedCache(cfg, pools, block_tables, q_start, context_lens,
                        block_size, interpret)
-    logits, pools, counts = decoder_forward(
+    logits, pools, counts, *picks = decoder_forward(
         cfg, params, input_ids, cache, interpret=interpret,
-        expert_counts=expert_counts)
+        expert_counts=expert_counts, expert_picks=expert_picks)
     if expert_counts:
-        return logits, pools, counts
-    return logits, pools
+        return (logits, pools, counts, *picks)
+    return (logits, pools, *picks)

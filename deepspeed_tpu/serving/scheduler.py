@@ -55,7 +55,7 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Callable, Dict, Iterator, List, Optional
+from typing import Any, Callable, Dict, Iterator, List, Optional
 
 from ..testing import chaos
 from ..utils import telemetry
@@ -142,6 +142,9 @@ class Request:
     deadline_ts: Optional[float] = None
     #: priority tier (round 19): latency | standard | batch
     priority: str = STANDARD
+    #: a dropless mixture: keep the experts every layer picked for every
+    #: token the model was fed (``ServingEngine.submit``)
+    keep_routing: bool = False
     rid: int = field(default_factory=lambda: next(_rid))
     # -- filled by the engine -------------------------------------------------
     state: str = QUEUED
@@ -159,6 +162,13 @@ class Request:
     first_token_ts: Optional[float] = None
     finish_ts: Optional[float] = None
     error: Optional[str] = None
+    #: ``keep_routing``: int32 ``[len(prompt) + len(output_tokens) - 1,
+    #: sparse layers, k]`` once FINISHED, ids over the router's outputs (-1:
+    #: a prompt position the prefix cache served); None for a request that
+    #: did not ask and for a dense model. Set on the instance by the engine
+    #: at the request's end, so no field of the constructor
+    routed_experts = None
+    _routing: List[Any] = field(default_factory=list, repr=False)
 
     @property
     def tokens(self) -> List[int]:

@@ -299,7 +299,7 @@ def span_times(entries: List[Entry], since_ns: int = 0
 #: model_runner.py``, the step functions of ``runtime/engine.py``)
 SCOPES = frozenset({
     "embed", "layers", "block.attn", "qkv", "kv_write", "attend", "out",
-    "block.mlp", "route", "dispatch", "experts", "combine",
+    "block.mlp", "route", "dispatch", "experts", "combine", "shared",
     "head", "loss", "grad_reduce", "sample", "grad_accum", "optimizer",
     "zero.gather", "zero.scatter"})
 

@@ -68,7 +68,12 @@ def paged_metrics(cell, obs):
     chunk_calls = _chunk_calls(pt)
     all_s = moe_roofline.kernel_seconds_by_step(pt, KERNEL)
     chunk_s = moe_roofline.kernel_seconds_by_step(chunk_calls, KERNEL)
-    live = table = secs = n = 0
+    # layers with a sliding window (a family's ``dims`` says how many) walk
+    # the pages of their window only: the program counts those apart
+    # (``paged.window_pages_sum``, summed over those layers), and the other
+    # layers walk every live page
+    windowed = dims.get("window_layers", 0)
+    live = table = win = secs = n = 0
     c_live = c_table = c_secs = c_n = 0
     for s in spans.steps_of(obs["program"]["ring"], "serve"):
         d = s["entry"][4].get("d", {})
@@ -76,6 +81,7 @@ def paged_metrics(cell, obs):
         if decode_s and d.get("paged.live_pages_sum"):
             live += d["paged.live_pages_sum"]
             table += d["paged.table_pages_sum"]
+            win += d.get("paged.window_pages_sum", 0)
             secs += decode_s
             n += 1
         if chunk_s.get(s["n"]) and d.get("paged.chunk_live_pages_sum"):
@@ -85,7 +91,11 @@ def paged_metrics(cell, obs):
             c_n += 1
     if not n or peaks is None:
         return {}
-    least = live * page / (peaks["hbm_gb_per_s"] * 1e9)
+    needed = live * page
+    if windowed and win:
+        needed = page // dims["layers"] * (
+            live * (dims["layers"] - windowed) + win)
+    least = needed / (peaks["hbm_gb_per_s"] * 1e9)
     chunk = {}
     if c_n:
         c_least = c_live * page / (peaks["hbm_gb_per_s"] * 1e9)
@@ -105,7 +115,7 @@ def paged_metrics(cell, obs):
             "value": 100.0 * least / secs, "unit": "%", "bound": "memory",
             "steps": n, "kernel_ms_per_step": 1e3 * secs / n,
             "least_ms_per_step": 1e3 * least / n,
-            "needed_gb_per_step": live * page / n / 1e9},
+            "needed_gb_per_step": needed / n / 1e9},
         "paged_live_page_share": {
             "value": live / table, "unit": "share", "steps": n,
             "live_pages_per_step": live / n}}

@@ -401,8 +401,11 @@ def test_olmoe_serving_step_is_dropless_and_in_place(chip, monkeypatch,
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(
         params, pools, chip((words,), jnp.int32), *fed).compile()
     text = compiled.as_text()
-    out, _ = compiled.out_info               # the tokens, then the counts
+    (out, picks), _ = compiled.out_info      # the tokens, then the counts
     assert out.shape == (lanes + L * E,) and out.dtype == jnp.int32
+    # ... and beside them the call's picks, left on the device
+    assert picks.shape == (L, B if program == "decode" else 256, K) \
+        and picks.dtype == jnp.int32
     kernels = _kernel_scopes(text)
     grouped = [k for k in kernels if "jit(gmm)" in k]
     assert len(grouped) == 3, kernels
@@ -434,6 +437,87 @@ def test_olmoe_serving_step_is_dropless_and_in_place(chip, monkeypatch,
         r[1] in ("copy", "transpose", "scatter")
         or r[1] == "dynamic-slice" and r[3] == layer)]
     assert not moved, moved
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill256"])
+def test_k_exaone_serving_step_holds_a_share_in_place(chip, monkeypatch,
+                                                      program):
+    """The serving loop's two programs at the K-EXAONE cell's widths, read
+    from the benchmark's own files (k-exaone-236b-ep8-l5: hidden 6144, 64
+    heads stored, 16 of 128 experts of 2048 held, top-8, a shared expert, a
+    leading dense layer of 18432; 32 lanes over a 1024 x 32 pool): the held
+    experts' matmuls are the megablox kernel, three in the sparse stack's
+    layer body; each of the two stacks' bodies has its paged-attention call;
+    the counts and picks are over the 4 SPARSE layers and the router's 128
+    outputs; nothing copies the expert stack or slices a layer's experts
+    out of it (the leading stack's scan must not either); the pool is
+    updated in place; and weights, pool and temporaries fit the chip."""
+    from benchmark import harness
+    from deepspeed_tpu.models import TransformerConfig, build_model
+    from deepspeed_tpu.serving.engine import (StepLayout, step_programs,
+                                              token_words)
+    from deepspeed_tpu.serving.kv_cache import init_pool
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cell = harness.load_cell("serve-k-exaone-236b-ep8-l5-mixed")
+    serving = cell.system["serving"]
+    BS, NB, B, NBK = (serving[k] for k in (
+        "block_size", "pool_blocks", "max_batch", "max_blocks_per_seq"))
+    model, cfg = build_model(TransformerConfig(
+        **harness.load_family("exaone_moe").model_kwargs(cell.config),
+        dtype=jnp.bfloat16))
+    H, NH, HD, M, K = cfg.hidden_size, cfg.num_heads, cfg.head_dim, \
+        cfg.mlp_dim, cfg.moe_k
+    LS, E, HELD = cfg.sparse_layers, cfg.moe_experts, cfg.moe_held_count
+    assert (cfg.num_layers, LS, E, HELD, H, M) == (5, 4, 128, 16, 6144, 2048)
+    on_chip = lambda tree: jax.tree.map(lambda x: chip(x.shape, x.dtype), tree)
+    params = on_chip(jax.eval_shape(lambda: jax.tree.map(
+        lambda x: x.astype(jnp.bfloat16), model.init(
+            jax.random.PRNGKey(0),
+            {"input_ids": jnp.zeros((1, 8), jnp.int32)})["params"])))
+    assert params["blocks"]["moe"]["experts"]["fc"]["kernel"].shape == \
+        (LS, HELD, H, M)
+    pools = on_chip(jax.eval_shape(lambda: init_pool(cfg, NB, BS,
+                                                     jnp.bfloat16)))
+    lanes = B if program == "decode" else 1
+    decode, prefill = step_programs(cfg, BS, NBK)
+    if program == "decode":
+        fn, words = decode, StepLayout(NBK).decode_words(B)
+        fed = [chip((token_words(cfg, n),), jnp.int32) for n in (B, 1)]
+    else:
+        fn, words, fed = prefill, StepLayout(NBK).prefill_words(256), []
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, pools, chip((words,), jnp.int32), *fed).compile()
+    text = compiled.as_text()
+    (out, picks), _ = compiled.out_info
+    assert out.shape == (lanes + LS * E,) and out.dtype == jnp.int32
+    assert picks.shape == (LS, B if program == "decode" else 256, K)
+    kernels = _kernel_scopes(text)
+    grouped = [k for k in kernels if "jit(gmm)" in k]
+    assert len(grouped) == 3, kernels
+    assert all("block.mlp/experts" in k for k in grouped), grouped
+    paged = [k for k in kernels if "paged_attention" in k]
+    assert len(paged) == 2 and len(kernels) == 5, kernels
+    # the expert stack goes to the kernel whole, from both loops: nothing
+    # makes the stack [4, 16, 6144, 2048] or a layer's experts of it
+    import re
+    sliced = [line.strip()[:160] for line in text.splitlines() if re.search(
+        r"= bf16\[(?:%d,)?%d,(?:%d,%d|%d,%d)\]\S* (?!parameter|bitcast|"
+        r"get-tuple-element)" % (LS, HELD, H, M, M, H), line)]
+    assert not sliced, sliced
+    made = [r for r in _results(text) if r[1] not in (
+        "parameter", "get-tuple-element", "while", "tuple", "bitcast")]
+    assert not [r for r in made if r[3] >= HELD * H * M
+                and r[2] == "bf16" and r[3] % (HELD * H * M) == 0
+                and r[3] <= LS * HELD * H * M], made
+    layer = NH * NB * BS * HD
+    moved = [r for r in made if r[3] >= layer and (
+        r[1] in ("copy", "transpose", "scatter")
+        or r[1] == "dynamic-slice" and r[3] == layer)]
+    assert not moved, moved
+    mem = compiled.memory_analysis()
+    held_bytes = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert held_bytes < 15.0e9, (mem.argument_size_in_bytes,
+                                 mem.temp_size_in_bytes)
 
 
 @pytest.mark.parametrize("layout,vocab", [("dp4", 50257), ("dp2_tp2", 50304)])

@@ -55,6 +55,18 @@ FEATURES = {
                               moe_dropless=True, moe_norm_topk=False),
     "int8_kv": dict(**_ROTARY),
     "int8_weights_per_channel": dict(**_ROTARY, tie_embeddings=False),
+    # K-EXAONE's layer: a chip's share of the experts (2 of 16 held), a
+    # sigmoid router with a selection bias, renormalised and scaled picks, a
+    # shared expert, a leading dense layer, no input norms, rotary on the
+    # window layers only
+    "share_of_sigmoid_mixture_behind_a_dense_layer": dict(
+        **_ROTARY, **dict(_SWIGLU, mlp_dim_override=24), num_layers=5,
+        num_kv_heads=2, tie_embeddings=False, qk_norm=True, pre_norm=False,
+        post_block_norms=True, layer_windows=(6, 6, 6, 0, 6),
+        layer_rope=(True, True, True, False, True), dense_layers=1,
+        dense_mlp_dim=80, moe_experts=16, moe_k=4, moe_held=(4, 2),
+        moe_dropless=True, moe_scores="sigmoid", moe_select_bias=True,
+        moe_routed_scale=2.5, moe_shared_dim=24),
 }
 
 
@@ -70,12 +82,16 @@ def one_device_mesh():
 
 @pytest.mark.parametrize("feature", sorted(FEATURES))
 def test_dense_and_paged_forward_agree(feature):
-    model, cfg = build_model(
-        "gpt2-tiny", hidden_size=32, num_layers=2, num_heads=4,
-        vocab_size=VOCAB, max_seq_len=64, attention_impl="reference",
-        dtype=jnp.float32, **FEATURES[feature])
+    model, cfg = build_model("gpt2-tiny", **dict(dict(
+        hidden_size=32, num_layers=2, num_heads=4, vocab_size=VOCAB,
+        max_seq_len=64, attention_impl="reference", dtype=jnp.float32),
+        **FEATURES[feature]))
     params = model.init(jax.random.PRNGKey(3),
                         {"input_ids": np.zeros((1, 8), np.int32)})["params"]
+    if cfg.moe_select_bias:         # drawn zero: give it something to select
+        gate = params["blocks"]["moe"]["gate"]
+        gate["bias"] = 0.3 * jax.random.normal(jax.random.PRNGKey(4),
+                                               gate["bias"].shape)
     if feature == "int8_weights_per_channel":
         params = quantize_weights_int8(params)
     kv_dtype = jnp.int8 if feature == "int8_kv" else jnp.float32
@@ -124,8 +140,9 @@ def test_dense_and_paged_forward_agree(feature):
 
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
     for counts, real in counted:
-        # every real token reaches moe_k experts in every layer; padding and
-        # the idle lane are routed like any row, and not counted
-        assert counts.shape == (cfg.num_layers, cfg.moe_experts)
+        # every real token reaches moe_k experts in every sparse layer, held
+        # here or not; padding and the idle lane are routed like any row,
+        # and not counted
+        assert counts.shape == (cfg.sparse_layers, cfg.moe_experts)
         assert (counts.sum(axis=1) == real * cfg.moe_k).all(), counts
     assert len(counted) == (2 + STEPS if counting else 0)

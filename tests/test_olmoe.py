@@ -353,7 +353,8 @@ def test_a_dense_configs_programs_and_counters_are_what_they_were():
         "admit_blocked.prefilling", "compiles", "kv.held_blocks_sum",
         "kv.blocks_reserved_sum", "kv.tokens_written_sum",
         "prefix.prompt_tokens", "paged.live_pages_sum",
-        "paged.table_pages_sum", "paged.chunk_live_pages_sum",
+        "paged.table_pages_sum", "paged.window_pages_sum",
+        "paged.chunk_live_pages_sum",
         "paged.chunk_table_pages_sum", "step_inputs.transfers_sum",
         "step_inputs.lane_rows_written_sum", "decode_ahead.launched",
         "decode_ahead.device_lane_tokens_sum",
