@@ -412,7 +412,7 @@ def serve_phase(sz: Sizes, workdir: str, params, watch: CompileWatch) -> None:
     with open(cfg_path, "w") as f:
         json.dump({"dtype": "bfloat16", "serving": serving}, f, indent=1)
     prompts = _make_requests(sz, cfg.vocab_size)
-    pool_gb = (2 * cfg.num_layers * cfg.num_heads * cfg.head_dim
+    pool_gb = (2 * cfg.num_layers * cfg.kv_heads * cfg.head_dim
                * sz.pool_blocks * sz.block_size * 2) / 1e9
     print(f"[serve] {len(prompts)} greedy requests, prompt lengths "
           f"{[len(p) for p in prompts]}, new tokens {list(sz.new_tokens)}; "

@@ -271,8 +271,10 @@ def decoder_forward(cfg: TransformerConfig, params: PyTree,
       carry, so a layer's write is an in-place dynamic-update-slice inside
       the compiled loop (stacked scan outputs were copied whole every layer);
     * ``write(carry, li, k, v, k_scale, v_scale) -> carry``: layer ``li``'s
-      new rows ``[B, nh, T, hd]`` (``quantized``: int8, with f32 scales);
-    * ``attend(carry, li, q, k, v, window) -> [B, nh, T, hd]``;
+      new rows ``[B, kv_heads, T, hd]`` (``quantized``: int8, with f32
+      scales);
+    * ``attend(carry, li, q, k, v, window) -> [B, nh, T, hd]`` for the
+      queries ``[B, nh, T, hd]``, ``nh // kv_heads`` of them a K/V head;
     * ``real_tokens(pos)``: ``[B, T]`` int32, the tokens a request owns
       (``expert_counts``, a dropless MoE config only: how many of them each
       layer's router sent to each expert).
@@ -350,12 +352,9 @@ def decoder_forward(cfg: TransformerConfig, params: PyTree,
                         q = jnp.where(rope, rot(q), q)
                         k = jnp.where(rope, rot(k), k)
             with jax.named_scope("kv_write"):
-                if kvh != nh:
-                    # GQA: repeat kv to full heads BEFORE the write, so the
-                    # paged kernel and the int8 tiers apply unchanged
-                    # (ROADMAP S2: kv_heads would shrink a cache nh/kvh-fold)
-                    k = jnp.repeat(k, nh // kvh, axis=1)
-                    v = jnp.repeat(v, nh // kvh, axis=1)
+                # GQA: K/V go to the cache at the model's kv heads; what a
+                # cache stores a query head it repeats itself (DenseCache),
+                # the paged pool stores the kv heads (ROADMAP S2)
                 ks = vs = None
                 if cache.quantized:     # on write: one format, every cache
                     (k, ks), (v, vs) = kv_quantize(k), kv_quantize(v)
@@ -494,11 +493,20 @@ class DenseCache:
     def finish(self, carry, T: int):
         return {**self.cache, **carry, "pos": self.cache["pos"] + T}
 
+    def _per_query_head(self, t):
+        """GQA: the buffers hold a row a QUERY head, so K/V (and the int8
+        tier's scales) are repeated to full heads before they are stored
+        or, on the flash prefill, attended (storing them at the kv heads is
+        ROADMAP S2's other half, the paged pool's only so far)."""
+        nh, kvh = self.cfg.num_heads, self.cfg.kv_heads
+        return t if kvh == nh else jnp.repeat(t, nh // kvh, axis=1)
+
     def write(self, kv, li, k, v, k_scale, v_scale):
         new = {"k": k, "v": v, "k_scale": k_scale, "v_scale": v_scale}
         at = (li, 0, 0, self.cache["pos"], 0)
-        return {n: jax.lax.dynamic_update_slice(a, new[n][None], at)
-                for n, a in kv.items()}
+        return {n: jax.lax.dynamic_update_slice(
+            a, self._per_query_head(new[n])[None], at)
+            for n, a in kv.items()}
 
     def attend(self, kv, li, q, k, v, window):
         cfg = self.cfg
@@ -507,7 +515,8 @@ class DenseCache:
             # causal prefill (alibi distances from arange positions match
             # the slots because pos == 0)
             return flash_attention_on_mesh(
-                q, k, v, causal=True, sm_scale=self.sm_scale,
+                q, self._per_query_head(k), self._per_query_head(v),
+                causal=True, sm_scale=self.sm_scale,
                 window=cfg.uniform_window(), softcap=cfg.attn_softcap,
                 alibi_slopes=self.slopes,
                 interpret=self.prefill_flash == "interpret")

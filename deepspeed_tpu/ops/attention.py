@@ -157,8 +157,9 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
                     interpret: bool = False) -> jnp.ndarray:
     """Dispatching paged-attention entry point (the serving loop's reads).
 
-    q [B, nh, T, hd] against a block-pool K/V ([L?, nh, num_blocks,
-    block_size, hd]) through per-sequence ``block_tables`` [B, max_blocks]
+    q [B, nh, T, hd] against a block-pool K/V ([L?, kvh, num_blocks,
+    block_size, hd], ``kvh`` the model's KV heads: ``nh // kvh`` query heads
+    read each) through per-sequence ``block_tables`` [B, max_blocks]
     and ``context_lens`` [B]. The Pallas kernel
     (ops/pallas/paged_attention.py) serves a decode step (T == 1) and a
     prefill chunk (T > 1 queries at ``q_start + row``, possibly with PADDED

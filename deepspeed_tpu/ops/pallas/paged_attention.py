@@ -11,9 +11,9 @@ kernel walks only the pages a sequence HOLDS: the grid is (sequences, head group
 the pools stay in HBM, and a program loops over its sequence's
 ``ceil(ctx_len / block_size)`` live pages (from the window's first page when
 a sliding window is set), ``P`` pages a turn. It copies each page's
-``[heads, block_size, head_dim]`` out of the pool itself (the block table is
-a prefetched scalar array), into one of two VMEM buffers: while a group of
-``P * block_size`` keys is computed the next group is in flight, and a
+``[stored heads, block_size, head_dim]`` out of the pool itself (the block
+table is a prefetched scalar array), into one of two VMEM buffers: while a
+group of ``P * block_size`` keys is computed the next group is in flight, and a
 sequence's last turn starts the next sequence's first group, so only the
 call's very first copy is waited for with nothing to do. No materialized
 per-sequence contiguous copy, no step for a table entry no sequence uses
@@ -22,12 +22,28 @@ sequence cost as many grid steps as a full one), and per-token cost scales
 with the tokens each sequence has generated, not with the pool or the table.
 
 ``P`` is a function of the operand shapes alone (:func:`_pages_per_group`:
-the largest power of two whose four group buffers fit
-:data:`_VMEM_BUDGET`, at most the table's length).
+the largest power of two at which four group buffers of the program's QUERY
+heads fit :data:`_VMEM_BUDGET`, at most the table's length).
 
-A prefill chunk is the same loop under a taller query tile, ``[heads, T,
-head_dim]`` for the eight rows a decode token is broadcast to: row r is the
-query at ``q_start + r``, the causal and window masks are a row's own, the
+**The tile is a stored head's query-head group** (PR 44). The pool holds
+the model's KV heads, ``q`` comes at its ``nh`` query heads, and ``group =
+nh // kvh`` is read from the two shapes: the ``group`` query heads that
+share a stored head are ROWS of that head's query tile, row r the query of
+head ``kv * group + r``, so one copy of a page serves them all and one pass
+of the matrix unit scores them all (mistral-7b: 4 query heads a stored
+head, a quarter of the bytes and of the passes of a pool that held a row a
+query head; K-EXAONE: 8, an eighth). A decode token's tile is ``max(8,
+group rounded up to 8)`` rows; at ``group == 1`` it is the one query
+broadcast over the sublane minimum, and everything, the lowered text
+included, is what it was when the pool's heads were the query's. ALiBi
+slopes are a row's own.
+
+A prefill chunk is the same loop under taller query tiles, ``[query heads,
+T, head_dim]`` with a stored head's group one head after another: row r of
+each is the query at ``q_start + r``, so positions and masks are worked out
+once for all the program's heads, and each query head's two matmuls run
+against the one copy of the stored head it shares (:func:`_attend`). The
+causal and window masks are a row's own, the
 pages run from the first query's window to the last real query's page
 (``ctx - 1``: ``cache.write`` has put the chunk's own keys into the pool
 just before), and rows at or past ``ctx`` are bucket padding whose finite
@@ -35,10 +51,12 @@ garbage nobody reads. A chunk's rows are padded to whole tiles of
 :data:`_CHUNK_TILE` and the call is made under ``jax.jit``, so the prefill
 programs of a serving loop (one a chunk shape) trace ONE shape once; heads
 a program and pages a group shrink with the rows (:func:`_head_group`,
-:func:`_pages_per_group`) so that the float32 accumulator, the two running
-rows and one group's scores stay inside the chip's scoped VMEM beside the
-page buffers, and a group's page copies are a loop, not unrolled. At T == 1
-everything is what it was, the decode program's lowered text included.
+:func:`_pages_per_group`, counted in QUERY heads' rows) so that the float32
+accumulator, the two running rows and one group's scores stay inside the
+chip's scoped VMEM beside the page buffers, and a group's page copies are a
+loop, not unrolled. Where a whole group's rows are more than a program
+holds (:data:`_CHUNK_ROWS`: K-EXAONE's 8 x 256), the group's query heads are
+split over programs that each copy the stored head (:func:`_program_heads`).
 
 That is :func:`_loop_kernel`, for heads of whole 128-lane tiles. A pool of
 narrower heads (``head_dim % 128``: 64, 80, 96) is padded to 128 lanes a row
@@ -62,12 +80,12 @@ context length, and a sliding window. The jnp oracle
 gather — the CPU fallback and the parity target for the interpret-mode
 tests.
 
-The pool operand is row-major ``[L?, nh, num_blocks, block_size, hd]`` and
-the kernel reads it where it lies. Whoever writes the pool has to leave it
-so: ``serving.model_runner`` updates it in place with dynamic-update-slices
-because the layout the chip's compiler gives a scatter's operand (slots
+The pool operand is row-major ``[L?, kvh, num_blocks, block_size, hd]``
+(``kvh``: the model's KV heads) and the kernel reads it where it lies.
+Whoever writes the pool has to leave it so: ``serving.model_runner`` updates
+it in place with dynamic-update-slices because the layout the chip's compiler gives a scatter's operand (slots
 major) had the whole pool copied to this one before every call. The int8
-tier's scales are read as ``[L?, nh, num_blocks, 1, lanes]``
+tier's scales are read as ``[L?, kvh, num_blocks, 1, lanes]``
 (:func:`scale_rows`), a page's slots on the first lanes of whole 128-lane
 tiles: the chip pads a ``(1, block_size)`` float32 row in HBM to that anyway
 and lets a kernel copy no less.
@@ -89,16 +107,18 @@ from .flash_attention import NEG_INF
 __all__ = ["paged_attention", "paged_attention_reference", "scale_rows",
            "untileable"]
 
-#: query rows per program — a single decode token is broadcast to the
-#: sublane minimum so every operand is a legal (>=8)x128 tile
+#: least query rows a stored head's tile holds — the sublane minimum, so
+#: every operand is a legal (>=8)x128 tile: a decode token of a head nobody
+#: shares is broadcast over them, a group of up to eight query heads fills
+#: them a row each
 _QROWS = 8
 
 #: VMEM the K and V group buffers (two slots each) may take together
 _VMEM_BUDGET = 4 << 20
 
-#: query rows a program may hold, heads a program x rows a head: a prefill
-#: chunk's accumulator, its two running rows and its query and output tiles
-#: grow with them (3.5 KB a row of 128-wide heads)
+#: query rows a program may hold, QUERY heads a program x rows a head: a
+#: prefill chunk's accumulator, its two running rows and its query and output
+#: tiles grow with them (3.5 KB a row of 128-wide heads)
 _CHUNK_ROWS = 1024
 
 #: rows a chunk's query tile is padded to. A serving loop compiles a prefill
@@ -113,22 +133,47 @@ _CHUNK_TILE = 256
 _SCORE_BUDGET = 1 << 20
 
 
-def _query_rows(T: int) -> int:
-    """Rows of a program's query tile: a decode token broadcast to the
-    sublane minimum, a chunk's T rows padded to whole :data:`_CHUNK_TILE`s,
-    so that every chunk shape of a serving loop is one call of one shape."""
-    return _QROWS if T == 1 else -(-T // _CHUNK_TILE) * _CHUNK_TILE
+def _query_rows(T: int, group: int = 1) -> int:
+    """Rows of a query tile. A decode token: a stored head's tile holds the
+    ``group`` query heads that share it, a row each, at least the sublane
+    minimum and whole sublane tiles (``group`` 1: the one token broadcast).
+    A chunk: a QUERY head's T rows padded to whole :data:`_CHUNK_TILE`s, so
+    that every chunk shape of a serving loop is one call of one shape."""
+    if T == 1:
+        return max(_QROWS, -(-group // 8) * 8)
+    return -(-T // _CHUNK_TILE) * _CHUNK_TILE
 
 
-def _head_group(nh: int, block_k: int, hd: int, itemsize: int,
-                T: int = 1) -> int:
-    """Heads per program: target ~1MB K blocks, largest divisor of nh; a
-    chunk of T > 1 query rows a head takes no more heads than keep the
-    program's rows within :data:`_CHUNK_ROWS`."""
+def _divisor(n: int, most: int) -> int:
+    """The largest divisor of ``n`` that is at most ``most`` (at least 1)."""
+    return max(d for d in range(1, max(1, min(n, most)) + 1) if n % d == 0)
+
+
+def _head_group(kvh: int, block_k: int, hd: int, itemsize: int,
+                T: int = 1, group: int = 1) -> int:
+    """Stored heads per program: target ~1MB K blocks, largest divisor of
+    ``kvh``; a chunk of T > 1 query rows a query head takes no more stored
+    heads than keep the program's rows (``group`` query heads each) within
+    :data:`_CHUNK_ROWS`."""
     target = max(1, (1 << 20) // (block_k * hd * itemsize))
     if T > 1:
-        target = max(1, min(target, _CHUNK_ROWS // _query_rows(T)))
-    return max(d for d in range(1, min(nh, target) + 1) if nh % d == 0)
+        target = min(target, _CHUNK_ROWS // (group * _query_rows(T)))
+    return _divisor(kvh, target)
+
+
+def _program_heads(nh: int, kvh: int, block_k: int, hd: int, itemsize: int,
+                   T: int = 1):
+    """``(hg, gq)``: the stored heads a program takes and the query heads of
+    each of their groups it serves. A decode token: :func:`_head_group`'s
+    stored heads, their whole groups (rows of one tile). A chunk of T > 1
+    rows a head: as many whole groups as keep the program's rows within
+    :data:`_CHUNK_ROWS`; where one group alone passes it, ONE stored head
+    and the largest divisor of its group that fits, the group's other query
+    heads going to further programs that copy the same stored head."""
+    group = nh // kvh
+    gq = group if T == 1 else _divisor(group, _CHUNK_ROWS // _query_rows(T))
+    hg = _head_group(kvh, block_k, hd, itemsize, T, gq) if gq == group else 1
+    return hg, gq
 
 
 def _scale_lanes(bs: int) -> int:
@@ -154,18 +199,29 @@ def scale_rows(scale, pool_shape) -> jnp.ndarray:
     return jnp.pad(scale, [(0, 0)] * (scale.ndim - 1) + [(0, rows[-1] - bs)])
 
 
-def _pages_per_group(hg: int, bs: int, hd: int, itemsize: int, nbk: int,
+def _pages_per_group(hq: int, bs: int, hd: int, itemsize: int, nbk: int,
                      quant: bool = False, T: int = 1) -> int:
-    """Pages of one copy group, from the operand shapes alone: the largest
-    power of two whose K and V group buffers, two slots each, stay inside
+    """Pages of one copy group, from the operand shapes alone, for a program
+    that serves ``hq`` QUERY heads: the largest power of two at which K and V
+    group buffers of ``hq`` heads, two slots each, stay inside
     :data:`_VMEM_BUDGET` (the int8 tier's scale rows counted as the padded
     (8, 128) float32 tiles they may take in VMEM), never more than the
     table holds; under a chunk of T > 1 query rows a head also no more than
-    keep one group's scores inside :data:`_SCORE_BUDGET`."""
-    page = 4 * hg * bs * hd * itemsize            # K and V, two slots
+    keep one group's scores inside :data:`_SCORE_BUDGET`.
+
+    The buffers hold the STORED heads, ``hq // group`` of them, so a
+    grouped-query model's take ``1 / group`` of the budget: the pages are
+    counted by the query heads all the same, as they were when the pool held
+    a row for each. A decode program's copies are unrolled, a page a
+    descriptor at four sites, and each descriptor is 25-35 ms of tracing and
+    lowering at every start: at the 16 pages 8 stored heads would allow,
+    ``setup_s`` rose 3.3 s (mistral-7b, 4 pages before) and 5.7 s
+    (K-EXAONE, 2), and the kernel was no faster than at 4 or 8 (PERF.md,
+    PR 44)."""
+    page = 4 * hq * bs * hd * itemsize            # K and V, two slots
     if quant:
-        page += 4 * hg * 8 * _scale_lanes(bs) * 4
-    scores = hg * _query_rows(T) * bs * 4 if T > 1 else 0
+        page += 4 * hq * 8 * _scale_lanes(bs) * 4
+    scores = hq * _query_rows(T) * bs * 4 if T > 1 else 0
     p = 1
     while 2 * p * page <= _VMEM_BUDGET and 2 * p <= nbk \
             and 2 * p * scores <= _SCORE_BUDGET:
@@ -175,11 +231,32 @@ def _pages_per_group(hg: int, bs: int, hd: int, itemsize: int, nbk: int,
 
 def _attend(q, k, v, ks, vs, k0, ctx, window, slopes_ref, acc, m_scr, l_scr,
             *, sm_scale, softcap, q0=None):
-    """One online-softmax update: the query ``q`` [hg, rows, hd] against the
-    keys ``k`` / values ``v`` [hg, n, hd] at logical positions
-    ``[k0, k0 + n)``, folded into the running max, sum and output. The rows
-    are one decode token at ``ctx - 1``, broadcast (``q0`` None), or a
-    prefill chunk's queries at ``q0 + row``."""
+    """One online-softmax update: the query tile ``q`` of ``hg`` stored
+    heads against their keys ``k`` / values ``v`` [hg, n, hd] at logical
+    positions ``[k0, k0 + n)``, folded into the running max, sum and output.
+
+    A decode token (``q0`` None): ``q`` [hg, rows, hd], a stored head's rows
+    the query heads that share it, their one token at ``ctx - 1`` each (a
+    lone head's token broadcast). A prefill chunk: ``q`` [heads, rows, hd]
+    for the program's ``heads = hg x gq`` QUERY heads, ``gq`` to a stored
+    head, row r the query at ``q0 + r``: a tile a query head, so positions
+    and masks are worked out once for all of them, and a matmul a query
+    head against the stored head it shares (ONE matmul over a stored head's
+    ``gq x rows`` queries ran a third slower on the chip: PERF.md, PR 44)."""
+    hg, heads = k.shape[0], q.shape[0]
+    gq = heads // hg
+
+    def matmul(a, b, contract):
+        """``a`` [heads, ., .] against ``b`` [hg, ., .], query head h against
+        stored head ``h // gq``."""
+        dot = lambda a, b: jax.lax.dot_general(
+            a, b, (contract, ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+        if gq == 1:
+            return dot(a, b)
+        return jnp.concatenate([dot(a[h:h + 1], b[h // gq:h // gq + 1])
+                                for h in range(heads)], axis=0)
+
     if ks is not None:
         # int8 tier (round 17): the copies moved int8 rows + one f32 scale
         # per (head, slot); dequantize HERE, on the keys already in VMEM —
@@ -191,8 +268,9 @@ def _attend(q, k, v, ks, vs, k0, ctx, window, slopes_ref, acc, m_scr, l_scr,
         # q.dtype convert is exact (|int8| <= 127)
         k = k.astype(jnp.float32).astype(q.dtype)
         v = v.astype(jnp.float32).astype(q.dtype)
-    s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
-                            preferred_element_type=jnp.float32)
+        if gq > 1:      # a stored head's scales for each of its query heads
+            ks, vs = jnp.repeat(ks, gq, axis=0), jnp.repeat(vs, gq, axis=0)
+    s = matmul(q, k, ((2,), (2,)))
     if ks is not None:
         s = s * ks
     s = s * sm_scale
@@ -201,8 +279,8 @@ def _attend(q, k, v, ks, vs, k0, ctx, window, slopes_ref, acc, m_scr, l_scr,
     # the keys' logical positions do not depend on which PHYSICAL pages the
     # table routed the copies to
     if q0 is None:
-        # one real query at absolute (logical) position ctx - 1, broadcast
-        # over the 8 padded rows
+        # one real query a row (a lone head's: broadcast over the 8 padded
+        # rows), all at absolute (logical) position ctx - 1
         q_abs = ctx - 1
         k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
     else:
@@ -212,7 +290,10 @@ def _attend(q, k, v, ks, vs, k0, ctx, window, slopes_ref, acc, m_scr, l_scr,
         q_abs = q0 + jax.lax.broadcasted_iota(jnp.int32, one, 1)
         k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, one, 2)
     if slopes_ref is not None:
-        slope = slopes_ref[0][:, :1][:, None, :]            # [hg, 1, 1]
+        # a slope a head of the tile, [heads, 1, 1]; a decode tile of a
+        # group has a query head a row: [hg, rows, 1]
+        slope = (slopes_ref[0][:, :, :1] if len(slopes_ref.shape) == 4
+                 else slopes_ref[0][:, :1][:, None, :])
         s = s + slope * (k_pos - q_abs).astype(jnp.float32)
     keep = k_pos <= q_abs                                   # causal + dead tail
     if q0 is not None:
@@ -229,9 +310,8 @@ def _attend(q, k, v, ks, vs, k0, ctx, window, slopes_ref, acc, m_scr, l_scr,
     l_scr[:, :, :1] = l_scr[:, :, :1] * alpha + jnp.sum(p, axis=2,
                                                         keepdims=True)
     pv = p * vs if vs is not None else p
-    acc[...] = acc[...] * alpha + jax.lax.dot_general(
-        pv.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
+    acc[...] = acc[...] * alpha + matmul(pv.astype(v.dtype), v,
+                                         ((2,), (1,)))
     m_scr[:, :, :1] = m_cur
 
 
@@ -280,7 +360,7 @@ def _grid_kernel(bt_ref, lens_ref, misc_ref, q_ref, k_ref, v_ref, *rest, bs,
 
 def _loop_kernel(bt_ref, lens_ref, misc_ref, q_ref, k_hbm, v_hbm, *rest, hg,
                  bs, P, nbk, sm_scale, softcap, has_alibi, stacked, quant,
-                 chunk=False):
+                 splits=1, chunk=False):
     if quant:
         (ks_hbm, vs_hbm, slopes_ref, o_ref, k_buf, v_buf, ks_buf, vs_buf,
          acc, m_scr, l_scr, state, sem) = rest
@@ -323,12 +403,15 @@ def _loop_kernel(bt_ref, lens_ref, misc_ref, q_ref, k_hbm, v_hbm, *rest, hg,
 
     def each_copy(act, b, g, *group):
         """``act`` ("start" or "wait") on every live page's copies of group
-        ``i`` of sequence b, head group g (``group``: first, cnt, i, slot).
+        ``i`` of sequence b, program g (``group``: first, cnt, i, slot): the
+        ``hg`` stored heads of head group g, or, where a stored head's query
+        heads are split over ``splits`` programs, the one they share.
         A decode program has them unrolled, P pages a site; a chunk's
         programs loop over the pages instead: every prefill program of a
         serving loop lowers this kernel at every start, and the unrolled
         sites were most of its text (PERF.md, PR 37: ``setup_s``)."""
-        heads = pl.ds(g * hg, hg)
+        heads = pl.ds(g * hg if splits == 1
+                       else jax.lax.div(g, jnp.int32(splits)), hg)
         if not chunk:
             for live, copies in [page_copies(heads, b, *group, p)
                                  for p in range(P)]:
@@ -406,6 +489,16 @@ def _loop_kernel(bt_ref, lens_ref, misc_ref, q_ref, k_hbm, v_hbm, *rest, hg,
     _finish(o_ref, acc, l_scr)
 
 
+def _stored_heads(nh: int, pool_shape, stacked: bool) -> int:
+    """The heads the pool stores (the model's KV heads), which the query's
+    ``nh`` heads share ``nh // kvh`` each: read from the two shapes."""
+    kvh = pool_shape[1 if stacked else 0]
+    if nh % kvh:
+        raise ValueError(f"{nh} query heads do not share the pool's {kvh} "
+                         "stored heads evenly")
+    return kvh
+
+
 def untileable(q_shape, pool_shape, *, stacked: bool, quant: bool,
                interpret: bool = False) -> Optional[str]:
     """The kernel's tiling rules as a test made BEFORE the call: the reason
@@ -452,23 +545,26 @@ def paged_attention(q: jnp.ndarray,
     """T query tokens per sequence against a paged KV pool: a decode step's
     one, or a prefill chunk's T > 1, whose own keys the pool holds already.
 
-    q: [B, nh, T, hd]. T == 1: each sequence's fresh query, at logical
+    q: [B, nh, T, hd], ``nh`` a multiple of the pool's ``kvh`` stored heads
+       (query head h reads stored head ``h // (nh // kvh)``; the group is a
+       stored head's query tile, module docstring).
+       T == 1: each sequence's fresh query, at logical
        position ``context_lens[b] - 1`` (context_lens INCLUDES the new
        token). T > 1: queries at ``q_start[b] + row`` (``q_start`` [B];
        ``context_lens - T`` when not given), causal among themselves; rows
        at or past ``context_lens[b]`` are bucket padding, whose finite
        garbage the caller discards. Heads a program and pages a group
        shrink with T (:func:`_head_group`, :func:`_pages_per_group`).
-    k_pool/v_pool: [nh, num_blocks, block_size, hd]; with ``layer_idx``
-       (traced i32 ok) the stacked [L, nh, num_blocks, block_size, hd]
+    k_pool/v_pool: [kvh, num_blocks, block_size, hd]; with ``layer_idx``
+       (traced i32 ok) the stacked [L, kvh, num_blocks, block_size, hd]
        layout — the kernel's copies pick the layer straight out of the
        scan-carried pool, no materialized per-layer slice.
     k_scale/v_scale: the int8 tier (round 17) — pools are int8 in the
        ``quant_format.kv_quantize`` layout and these carry the f32
-       per-(layer, head, slot) scales: :func:`scale_rows`' layout (taken
-       as it is) or any shape that reshapes to the pool's
+       per-(layer, stored head, slot) scales: :func:`scale_rows`' layout
+       (taken as it is) or any shape that reshapes to the pool's
        [..., num_blocks, block_size], e.g. init_pool's
-       [L, nh, num_slots, 1] (padded here: a copy of the scale pool a
+       [L, kvh, num_slots, 1] (padded here: a copy of the scale pool a
        call). The scale rows are copied through the SAME block table
        beside k/v and the dequant happens in-kernel, so the HBM read is
        int8 + one padded scale row a page — no pool-slice f32 copy exists.
@@ -530,19 +626,34 @@ def _paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
     B, nh, T, hd = q.shape
     stacked = layer_idx is not None
     quant = k_scale is not None
+    kvh = _stored_heads(nh, k_pool.shape, stacked)
     bs = k_pool.shape[3 if stacked else 2]
     ks_pool, vs_pool = k_scale, v_scale
     nbk = block_tables.shape[1]
-    hg = _head_group(nh, bs, hd, k_pool.dtype.itemsize, T)
-    ng = nh // hg
-    rows = _query_rows(T)
+    # the tile of one stored head: the ``group`` query heads that share it,
+    # ``gq`` of them a program (all but for a chunk too tall for one). A
+    # decode token's tile is [hg stored heads, a row a query head]; a
+    # chunk's [heads = hg x gq query heads, a head's rows], as the output
+    group = nh // kvh
+    hg, gq = _program_heads(nh, kvh, bs, hd, k_pool.dtype.itemsize, T)
+    splits = group // gq
+    ng = kvh // hg * splits
+    heads, rows = (hg, _query_rows(T, group)) if T == 1 else (
+        hg * gq, _query_rows(T))
     scale = sm_scale if sm_scale is not None else 1.0 / np.sqrt(hd)
 
-    qf = q.reshape(B, ng, hg, T, hd)
-    if T == 1:
-        # broadcast the single query row to the sublane minimum (all 8 rows
-        # are the real query; row 0 is read back)
-        qf = jnp.broadcast_to(qf, (B, ng, hg, rows, hd))
+    if T > 1 or group == 1:
+        qf = q.reshape(B, ng, heads, T, hd)
+        if T == 1:
+            # broadcast the single query row to the sublane minimum (all 8
+            # rows are the real query; row 0 is read back)
+            qf = jnp.broadcast_to(qf, (B, ng, heads, rows, hd))
+    else:
+        # a decode token of every query head of the group, a row each (the
+        # rows past them, where the group fills no whole tile, are zeros
+        # nobody reads)
+        qf = jnp.pad(q.reshape(B, ng, hg, group, hd),
+                     [(0, 0)] * 3 + [(0, rows - group), (0, 0)])
 
     bt = jnp.asarray(block_tables, jnp.int32)
     lens = jnp.asarray(context_lens, jnp.int32).reshape(B)
@@ -554,11 +665,11 @@ def _paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
         # a chunk: each lane's first position, behind the window and layer
         misc = jnp.concatenate([misc, q_start])
 
-    qo_spec = pl.BlockSpec((1, 1, hg, rows, hd),
+    qo_spec = pl.BlockSpec((1, 1, heads, rows, hd),
                            lambda b, g, *_: (b, g, 0, 0, 0))
-    online = [pltpu.VMEM((hg, rows, hd), jnp.float32),
-              pltpu.VMEM((hg, rows, 128), jnp.float32),
-              pltpu.VMEM((hg, rows, 128), jnp.float32)]
+    online = [pltpu.VMEM((heads, rows, hd), jnp.float32),
+              pltpu.VMEM((heads, rows, 128), jnp.float32),
+              pltpu.VMEM((heads, rows, 128), jnp.float32)]
     static = dict(bs=bs, nbk=nbk, sm_scale=scale, softcap=softcap,
                   has_alibi=alibi_slopes is not None, stacked=stacked,
                   quant=quant)
@@ -566,9 +677,10 @@ def _paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
         # the pools stay in HBM: the kernel copies the pages a lane holds
         # (a chunk of narrower heads comes here under the interpreter only:
         # :func:`untileable`)
-        P = _pages_per_group(hg, bs, hd, k_pool.dtype.itemsize, nbk, quant,
-                             T)
-        kernel = partial(_loop_kernel, hg=hg, P=P, chunk=T > 1, **static)
+        P = _pages_per_group(hg * gq, bs, hd, k_pool.dtype.itemsize, nbk,
+                             quant, T)
+        kernel = partial(_loop_kernel, hg=hg, P=P, splits=splits,
+                         chunk=T > 1, **static)
         grid = (B, ng)
         kv_specs = [pl.BlockSpec(memory_space=pl.ANY)] * (4 if quant else 2)
         scratch = [pltpu.VMEM((2, hg, P * bs, hd), k_pool.dtype)] * 2
@@ -596,10 +708,17 @@ def _paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
                 lead + (hg, 1, 1, _scale_lanes(bs)), page)] * 2
         scratch = online
     operands = [qf, k_pool, v_pool] + ([ks_pool, vs_pool] if quant else [])
-    if alibi_slopes is not None:
-        sl = jnp.asarray(alibi_slopes, jnp.float32).reshape(ng, hg)
-        operands.append(jnp.broadcast_to(sl[:, :, None], (ng, hg, 128)))
-        slopes_spec = pl.BlockSpec((1, hg, 128), lambda b, g, *_: (g, 0, 0))
+    if alibi_slopes is not None and (T > 1 or group == 1):
+        sl = jnp.asarray(alibi_slopes, jnp.float32).reshape(ng, heads)
+        operands.append(jnp.broadcast_to(sl[:, :, None], (ng, heads, 128)))
+        slopes_spec = pl.BlockSpec((1, heads, 128), lambda b, g, *_: (g, 0, 0))
+    elif alibi_slopes is not None:
+        # a decode tile of a group: a slope a row, on sublanes as the rows
+        sl = jnp.asarray(alibi_slopes, jnp.float32).reshape(ng, hg, group)
+        sl = jnp.pad(sl, [(0, 0), (0, 0), (0, rows - group)])
+        operands.append(jnp.broadcast_to(sl[..., None], sl.shape + (128,)))
+        slopes_spec = pl.BlockSpec((1, hg, rows, 128),
+                                   lambda b, g, *_: (g, 0, 0, 0))
     else:
         # constant placeholder so the kernel arity is static
         operands.append(jnp.zeros((1, 1, 128), jnp.float32))
@@ -612,10 +731,10 @@ def _paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
     with jax.named_scope("paged_attention"):
         out = pl.pallas_call(
             kernel, grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((B, ng, hg, rows, hd), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((B, ng, heads, rows, hd), q.dtype),
             interpret=interpret,
         )(bt, lens, misc, *operands)
-    return out[:, :, :, :T].reshape(B, nh, T, hd)
+    return out[:, :, :, :group if T == 1 else T].reshape(B, nh, T, hd)
 
 
 #: a chunk's call under ``jax.jit``: the prefill programs of a serving loop
@@ -641,7 +760,9 @@ def paged_attention_reference(q: jnp.ndarray,
                               q_start=None) -> jnp.ndarray:
     """jnp oracle / CPU fallback: dense gather through the block table,
     then exactly the decode-path attention math (f32 scores, softcap
-    before the ALiBi bias before the -1e30 masks, f32 softmax).
+    before the ALiBi bias before the -1e30 masks, f32 softmax). Grouped as
+    the kernel is: the ``nh // kvh`` query heads of a stored head are rows
+    of one query against that head's gathered K/V, which is never repeated.
 
     Like the kernel, q may carry T > 1 query tokens (the
     PREFILL of a paged sequence — queries at logical positions
@@ -657,6 +778,8 @@ def paged_attention_reference(q: jnp.ndarray,
     greedy decodes are token-for-token unchanged.
     """
     B, nh, T, hd = q.shape
+    kvh = _stored_heads(nh, k_pool.shape, layer_idx is not None)
+    group = nh // kvh
     quant = k_scale is not None
     if quant:
         bs = k_pool.shape[-2]
@@ -678,16 +801,16 @@ def paged_attention_reference(q: jnp.ndarray,
     bt = jnp.asarray(block_tables, jnp.int32)
     lens = jnp.asarray(context_lens, jnp.int32).reshape(B)
 
-    # gather [nh, B, nbk, bs, hd] -> [B, nh, K, hd], K = nbk * bs logical
+    # gather [kvh, B, nbk, bs, hd] -> [B, kvh, K, hd], K = nbk * bs logical
     k = jnp.transpose(k_pool[:, bt], (1, 0, 2, 3, 4)).reshape(
-        B, nh, nbk * bs, hd)
+        B, kvh, nbk * bs, hd)
     v = jnp.transpose(v_pool[:, bt], (1, 0, 2, 3, 4)).reshape(
-        B, nh, nbk * bs, hd)
+        B, kvh, nbk * bs, hd)
     if quant:
         ks = jnp.transpose(k_scale[:, bt], (1, 0, 2, 3)).reshape(
-            B, nh, nbk * bs)
+            B, kvh, nbk * bs)
         vs = jnp.transpose(v_scale[:, bt], (1, 0, 2, 3)).reshape(
-            B, nh, nbk * bs)
+            B, kvh, nbk * bs)
         k = (k.astype(jnp.float32) * ks[..., None]).astype(q.dtype)
         v = (v.astype(jnp.float32) * vs[..., None]).astype(q.dtype)
 
@@ -697,19 +820,25 @@ def paged_attention_reference(q: jnp.ndarray,
     else:
         q_abs = (lens[:, None] - T + jnp.arange(T))        # [B, T]
     k_pos = jnp.arange(nbk * bs)                           # [K]
+    # a stored head's group of query heads as rows of ITS query, head after
+    # head: [B, kvh, group x T, hd] against the one stored head (no copy of
+    # K/V a query head)
+    q = q.reshape(B, kvh, group * T, hd)
+    q_abs = jnp.tile(q_abs, (1, group))                    # [B, group x T]
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
     if softcap:
         from ..attention import apply_softcap
         s = apply_softcap(s, softcap)
     if alibi_slopes is not None:
-        sl = jnp.asarray(alibi_slopes, jnp.float32).reshape(nh)
+        sl = jnp.repeat(jnp.asarray(alibi_slopes, jnp.float32).reshape(
+            kvh, group), T, axis=1)                        # [kvh, group x T]
         dist = (k_pos[None, None, :] - q_abs[:, :, None]).astype(jnp.float32)
-        s = s + sl[None, :, None, None] * dist[:, None]
-    keep = k_pos[None, None, :] <= q_abs[:, :, None]       # [B, T, K]
+        s = s + sl[None, :, :, None] * dist[:, None]
+    keep = k_pos[None, None, :] <= q_abs[:, :, None]       # [B, rows, K]
     if window is not None:
         win = jnp.asarray(window, jnp.int32)
         keep = keep & ((q_abs[:, :, None] - k_pos[None, None, :] < win)
                        | (win <= 0))
     s = jnp.where(keep[:, None], s, NEG_INF)
     prob = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bhkd->bhqd", prob, v)
+    return jnp.einsum("bhqk,bhkd->bhqd", prob, v).reshape(B, nh, T, hd)

@@ -553,6 +553,14 @@ class ServingEngine:
         # makes the prefill->decode handoff zero-copy
         self._shared = shared if shared is not None else SharedPagedState(
             cfg, serving, dtype=kv_dtype, counters=self.stats)
+        # what a token costs the pool, from the pool itself (a grouped-query
+        # model's is stored at its KV heads): 2 x layers x stored heads x
+        # head_dim x item size, the int8 tier's scales with it
+        pool_k = self.pools["k"]
+        self.rec.gauge("kv.stored_heads", int(pool_k.shape[1]))
+        self.rec.gauge("kv.bytes_per_token", sum(
+            a.size * a.dtype.itemsize for a in self.pools.values())
+            // pool_k.shape[2])
         self.scheduler = Scheduler(self.pool, serving.max_queue,
                                    self.max_model_len, self.prefix_cache,
                                    aging_s=serving.fleet.priority_aging_s,
@@ -611,7 +619,9 @@ class ServingEngine:
         self._prefill_program = "jit_" + _prefill.__name__
         log_dist(
             f"ServingEngine: pool={serving.pool_blocks}x{bs} tokens "
-            f"(~{(serving.pool_blocks - 1) * bs} cacheable), "
+            f"(~{(serving.pool_blocks - 1) * bs} cacheable, "
+            f"{self.rec.gauges['kv.stored_heads']} stored heads, "
+            f"{self.rec.gauges['kv.bytes_per_token']} bytes a token), "
             f"max_batch={self.max_batch}, max_model_len="
             f"{self.max_model_len}, prefix_cache={serving.prefix_cache}, "
             f"prefill_chunk={self._chunk or 'whole'}",

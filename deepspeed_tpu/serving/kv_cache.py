@@ -7,8 +7,11 @@ whole batch drains. For a long-lived serving loop that is the capacity
 bottleneck, not FLOPs. This module replaces it with the vLLM-style paged
 layout:
 
-* one preallocated device pool ``[L, nh, num_blocks * block_size, hd]``
-  (per k and v) shared by every in-flight sequence;
+* one preallocated device pool ``[L, kv_heads, num_blocks * block_size,
+  hd]`` (per k and v) shared by every in-flight sequence: a token costs
+  ``2 x L x kv_heads x hd x item size`` bytes, so a grouped-query model's
+  costs ``num_heads // kv_heads`` times less than a row a query head would
+  (gauges ``kv.stored_heads`` / ``kv.bytes_per_token``);
 * a host-side :class:`BlockPool` allocator handing out fixed-size blocks
   with REFERENCE COUNTS — ``fork`` shares blocks between sequences
   (prefix-cache reuse for common system prompts) and a block returns to
@@ -50,11 +53,15 @@ class BlockPoolExhausted(RuntimeError):
 
 def init_pool(cfg, num_blocks: int, block_size: int,
               dtype=None) -> Dict[str, jnp.ndarray]:
-    """Device-side paged pool: k/v ``[L, nh, num_blocks*block_size, hd]``.
+    """Device-side paged pool: k/v ``[L, kvh, num_blocks*block_size, hd]``,
+    ``kvh = cfg.kv_heads``: K and V are stored at the model's KV heads (a
+    grouped-query model's ``num_heads // kv_heads`` query heads read one
+    stored head; the paged kernel makes them rows of one tile), so a token
+    takes ``2 x L x kvh x hd x item size`` bytes of the pool.
 
     Flat slot layout (slot = block * block_size + offset), row-major: the
     paged forward and the paged-attention kernel both view the same buffer
-    as ``[L, nh, num_blocks, block_size, hd]`` (a free reshape), the kernel
+    as ``[L, kvh, num_blocks, block_size, hd]`` (a free reshape), the kernel
     to DMA whole blocks through the block table, the forward to write new
     K/V into it in place with ``lax.dynamic_update_slice`` (one slot a lane
     in a decode step, a block at a time in a prefill). Not with a scatter:
@@ -63,14 +70,14 @@ def init_pool(cfg, num_blocks: int, block_size: int,
     (``serving.model_runner._write_kv``).
 
     ``dtype=jnp.int8`` (round 12): the quantized pool tier — k/v store
-    int8 with a per-(layer, head, slot) f32 scale (symmetric over the
+    int8 with a per-(layer, KV head, slot) f32 scale (symmetric over the
     head dim, ``quant_format.kv_quantize`` — the single-sourced format),
     halving pool HBM vs bf16. The paged forward quantizes on write;
     reads dequantize IN-kernel (round 17): the Pallas paged-attention
     kernel takes the int8 blocks plus scales and dequantizes per block
     in VMEM, so int8 is what crosses HBM (no pool-slice f32 copy)."""
     dtype = dtype or cfg.dtype
-    shape = (cfg.num_layers, cfg.num_heads, num_blocks * block_size,
+    shape = (cfg.num_layers, cfg.kv_heads, num_blocks * block_size,
              cfg.head_dim)
     if dtype == jnp.int8:
         return {"k": jnp.zeros(shape, jnp.int8),

@@ -56,8 +56,9 @@ class _WritePlan(NamedTuple):
 
 
 def _write_kv(pool, li, new, ax, plan: _WritePlan):
-    """``new`` [B, nh, 1, ., .] with its T positions on axis ``ax`` into
-    layer ``li`` of ``pool`` [L, nh, blocks, ., .], whose blocks have their
+    """``new`` [B, kvh, 1, ., .] with its T positions on axis ``ax`` into
+    layer ``li`` of ``pool`` [L, kvh, blocks, ., .] (``kvh``: the model's KV
+    heads, the heads the pool stores), whose blocks have their
     ``bs`` slots on axis ``ax``: ``lax.dynamic_update_slice`` unrolled in
     Python over lanes and touched blocks. A decode step (T == 1) writes one
     slot a lane; a prefill reads each touched block, merges the real
@@ -107,9 +108,12 @@ def attention_impl(cfg: TransformerConfig) -> str:
 
 class PagedCache:
     """The serving loop's cache behind ``models.generation.decoder_forward``:
-    ``init_pool``'s ``[L, nh, slots, hd]`` pools, reached through each lane's
-    block table (:func:`paged_forward` documents the arguments). Built and
-    used inside one trace."""
+    ``init_pool``'s ``[L, kv_heads, slots, hd]`` pools, reached through each
+    lane's block table (:func:`paged_forward` documents the arguments). The
+    layer hands ``write`` K/V at the model's KV heads and ``attend`` the
+    queries at all its heads: the kernel reads one stored head for the
+    ``num_heads // kv_heads`` query heads that share it. Everything here
+    follows the pool's own shape. Built and used inside one trace."""
 
     def __init__(self, cfg: TransformerConfig, pools: Dict[str, jnp.ndarray],
                  block_tables, q_start, context_lens, block_size: int,
@@ -125,8 +129,8 @@ class PagedCache:
         if num_slots % bs:
             raise ValueError(f"pool slots {num_slots} not divisible by "
                              f"block_size {bs}")
-        self.blocked_shape = (cfg.num_layers, cfg.num_heads, num_slots // bs,
-                              bs, cfg.head_dim)
+        self.blocked_shape = pools["k"].shape[:2] + (
+            num_slots // bs, bs, cfg.head_dim)
         self.bt = jnp.asarray(block_tables, jnp.int32)
         B, nbk = self.bt.shape
         self.q_start = jnp.asarray(q_start, jnp.int32).reshape(B)
@@ -190,8 +194,8 @@ class PagedCache:
         new, plan = dict(kv), self.write_plan
         if self.quantized:
             # a scale block keeps its slots on the last axis
-            B, nh, T, _ = k.shape
-            to_lanes = lambda s: s.reshape(B, nh, 1, 1, T)
+            B, kvh, T, _ = k.shape
+            to_lanes = lambda s: s.reshape(B, kvh, 1, 1, T)
             new["k_scale"] = _write_kv(kv["k_scale"], li, to_lanes(k_scale),
                                        4, plan)
             new["v_scale"] = _write_kv(kv["v_scale"], li, to_lanes(v_scale),
@@ -240,9 +244,10 @@ def paged_forward(cfg: TransformerConfig,
     that ``[L, B x T, k]`` int32, the experts each layer picked for every row
     (``decoder_forward``).
 
-    input_ids: [B, T]. pools: {"k","v"} [L, nh, num_slots, hd]
+    input_ids: [B, T]. pools: {"k","v"} [L, kv_heads, num_slots, hd]
     (``serving.kv_cache.init_pool`` layout; ``num_slots`` = pool blocks x
-    ``block_size``). block_tables: [B, max_blocks_per_seq] i32 — logical
+    ``block_size``; a token's bytes = 2 x L x kv_heads x hd x item size).
+    block_tables: [B, max_blocks_per_seq] i32 — logical
     block j of lane b is physical pool block ``block_tables[b, j]``.
     q_start: [B] i32 — first query's logical position (tokens already in
     the cache below it are attended: a prefix-cache hit prefills only the
@@ -260,7 +265,7 @@ def paged_forward(cfg: TransformerConfig,
 
     int8 KV pools: when ``pools`` carries ``k_scale`` / ``v_scale``
     (``init_pool(dtype=jnp.int8)``), K/V rows are QUANTIZED ON WRITE
-    (``quant_format.kv_quantize``: one f32 scale per layer, head and slot;
+    (``quant_format.kv_quantize``: one f32 scale per layer, KV head and slot;
     error per element within that row's absmax / 254) and the int8 pool
     plus scales go STRAIGHT to attention: the Pallas decode kernel
     dequantizes the blocks it DMAs in VMEM, the jnp reference after its

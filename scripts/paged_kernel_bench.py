@@ -7,12 +7,16 @@ One "step" is what a decode program asks of the kernel: one call a layer
 on the stacked pool, every lane at its own context length (lognormal round
 the cell's mean, some lanes idle at the one token the engine gives them).
 Prints a JSON line a shape and setting: ms a step, the bytes the live pages
-hold (K and V, every stored head), and that over the chip's 819 GB/s as a
-share of the time: the kernel's roofline share. ``--pages`` overrides the
-pages a copy group holds (the program derives it from the shapes:
-``_pages_per_group``); ``--old FILE`` times another version of the kernel's
-module (e.g. ``git show <commit>:deepspeed_tpu/ops/pallas/paged_attention.py``)
-on the same inputs. Parity against the jnp reference is checked on the
+hold (K and V, every STORED head, read from the pool's own shape: a
+grouped-query model's pool holds its KV heads since PR 44; a layer with a
+sliding window walks its window's pages only), and that over the chip's
+819 GB/s as a share of the time: the kernel's roofline share. ``--pages``
+overrides the pages a copy group holds (the program derives it from the
+shapes: ``_pages_per_group``); ``--old FILE`` times another version of the
+kernel's module (e.g. ``git show <commit>:deepspeed_tpu/ops/pallas/
+paged_attention.py``) on the same queries and keys; ``--old-expanded`` gives
+that module the pool as it was stored before PR 44, a row a QUERY head (its
+bytes counted as such). Parity against the jnp reference is checked on the
 device before anything is timed. Needs a TPU.
 
 Since PR 37 the kernel takes a prefill chunk's T > 1 query rows a lane:
@@ -43,16 +47,28 @@ BF16_TFLOPS = 197.0
 #: the largest shape, and a late chunk of a long prompt
 CHUNKS = ((32, 0), (256, 0), (256, 768))
 
-# layers, stored heads, head_dim, block, pool blocks, lanes, table, live
-# lanes, mean context of a live lane (PERF.md section 5: ~58 k tokens over
-# 52 lanes; ~260 pages over 28 lanes), sliding window
+# layers, query heads, stored (KV) heads, head_dim, block, pool blocks, lanes,
+# table, live lanes, mean context of a live lane (PERF.md section 5: ~58 k
+# tokens over 52 lanes; ~260 pages over 28 lanes; ~510 over 24), the layers'
+# sliding windows (one for all, or one a layer; 0: none)
 SHAPES = {
-    "serve-olmoe-1b-7b-l8-gen": (8, 16, 128, 32, 2048, 64, 128, 52, 1100, 0),
-    "serve-mistral-7b-l16-chat": (16, 32, 128, 32, 384, 32, 40, 28, 290,
+    "serve-olmoe-1b-7b-l8-gen": (8, 16, 16, 128, 32, 2048, 64, 128, 52, 1100,
+                                 0),
+    "serve-mistral-7b-l16-chat": (16, 32, 8, 128, 32, 384, 32, 40, 28, 290,
                                   4096),
+    "serve-k-exaone-236b-ep8-l5-mixed": (5, 64, 8, 128, 32, 1024, 32, 128, 24,
+                                         680, (128, 128, 128, 0, 128)),
     # no cell: heads of 64 take the grid kernel (a grid step a table entry)
-    "llama-1.1b": (22, 32, 64, 32, 512, 16, 64, 14, 400, 0),
+    "llama-1.1b": (22, 32, 4, 64, 32, 512, 16, 64, 14, 400, 0),
 }
+
+
+def walked_pages(ctx, q0, bs, windows):
+    """Pages the kernel walks for one lane, summed over the layers: from
+    the page of the first query's window to the last real query's."""
+    last = -(-ctx // bs)
+    return sum(last - (max(q0 + 1 - w, 0) // bs if w else 0)
+               for w in windows)
 
 
 def best_ms(fn, reps, *operands):
@@ -84,6 +100,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--pages", default="")
     ap.add_argument("--old", default=None)
+    ap.add_argument("--old-expanded", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--reps", type=int, default=30)
     args = ap.parse_args()
@@ -99,8 +116,10 @@ def main():
                  for p in args.pages.split(",") if p]
     if old is not None:
         settings.append((f"old:{Path(args.old).name}", old, None))
-    for name, (L, nh, hd, bs, nb, B, nbk, live, mean, window) in \
+    for name, (L, nh, kvh, hd, bs, nb, B, nbk, live, mean, window) in \
             SHAPES.items():
+        windows = (window,) * L if isinstance(window, int) else window
+        window = jnp.asarray(windows, jnp.int32)
         rng = np.random.default_rng(args.seed)
         ctx = np.ones((B,), np.int32)
         ctx[:live] = np.clip(rng.lognormal(np.log(mean) - 0.32, 0.8, live),
@@ -120,14 +139,22 @@ def main():
         key = jax.random.PRNGKey(args.seed)
         kq, kk, kv = jax.random.split(key, 3)
         q = jax.random.normal(kq, (B, nh, 1, hd), jnp.bfloat16)
-        kp = jax.random.normal(kk, (L, nh, nb, bs, hd), jnp.bfloat16)
-        vp = jax.random.normal(kv, (L, nh, nb, bs, hd), jnp.bfloat16)
+        kp = jax.random.normal(kk, (L, kvh, nb, bs, hd), jnp.bfloat16)
+        vp = jax.random.normal(kv, (L, kvh, nb, bs, hd), jnp.bfloat16)
         bt_d, ctx_d = jnp.asarray(bt), jnp.asarray(ctx)
-        need = int(pages.sum()) * L * nh * bs * hd * 2 * 2
+        walked = sum(walked_pages(int(c), int(c) - 1, bs, windows)
+                     for c in ctx)
         ref = new.paged_attention_reference(
             q, kp, vp, bt_d, ctx_d, layer_idx=jnp.int32(L - 1),
-            window=window)
+            window=window[L - 1])
+        stored = expanded = (kp, vp)
+        if old is not None and args.old_expanded and nh != kvh:
+            expanded = tuple(jnp.repeat(p, nh // kvh, axis=1)
+                             for p in stored)
+        pools_of = lambda mod: expanded if mod is old else stored
         for label, mod, force in settings:
+            kp, vp = pools_of(mod)
+            need = walked * kp.shape[1] * bs * hd * 2 * 2
             new._pages_per_group = (
                 derive if force is None else
                 lambda *a, _p=force, **k: min(_p, a[4]))
@@ -135,7 +162,7 @@ def main():
             def step(q, kp, vp, bt, ctx, mod=mod):
                 def layer(acc, li):
                     o = mod.paged_attention(q, kp, vp, bt, ctx, layer_idx=li,
-                                            window=window)
+                                            window=window[li])
                     return acc + o.astype(jnp.float32), None
                 return jax.lax.scan(
                     layer, jnp.zeros(q.shape, jnp.float32), jnp.arange(L))[0]
@@ -143,30 +170,33 @@ def main():
             one = jax.jit(lambda q, kp, vp, bt, ctx, mod=mod:
                           mod.paged_attention(q, kp, vp, bt, ctx,
                                               layer_idx=jnp.int32(L - 1),
-                                              window=window))
+                                              window=window[L - 1]))
             err = float(jnp.max(jnp.abs(
                 one(q, kp, vp, bt_d, ctx_d).astype(jnp.float32)
                 - ref.astype(jnp.float32))))
             ms, _ = best_ms(jax.jit(step), args.reps, q, kp, vp, bt_d, ctx_d)
             P = new._pages_per_group(
-                new._head_group(nh, bs, hd, 2), bs, hd, 2,
-                nbk) if mod is new and hd % 128 == 0 else None
+                int(np.prod(new._program_heads(nh, kvh, bs, hd, 2))), bs, hd,
+                2, nbk) if mod is new and hd % 128 == 0 else None
             print(json.dumps({
                 "shape": name, "setting": label, "pages_per_group": P,
+                "stored_heads": kp.shape[1], "query_heads": nh,
                 "device_kind": dev.device_kind, "ms_per_step": ms,
                 "live_pages_a_layer": int(pages.sum()),
+                "walked_pages_a_step": walked,
                 "table_pages_a_layer": B * nbk,
                 "live_share": float(pages.sum() / (B * nbk)),
                 "needed_gb": need / 1e9,
                 "roofline_pct": 100 * need / (HBM_GBPS * 1e9) / (ms / 1e3),
                 "max_abs_err_vs_reference": err}), flush=True)
         new._pages_per_group = derive
-        if hd % 128 == 0 and not args.old:
-            chunk_rows(new, name, dev, args, L, nh, hd, bs, nb, nbk, window,
-                       kp, vp)
+        if hd % 128 == 0:
+            for mod in [new] + ([old] if old is not None else []):
+                chunk_rows(mod, name, dev, args, L, nh, hd, bs, nb, nbk,
+                           windows, *pools_of(mod))
 
 
-def chunk_rows(mod, name, dev, args, L, nh, hd, bs, nb, nbk, window, kp, vp):
+def chunk_rows(mod, name, dev, args, L, nh, hd, bs, nb, nbk, windows, kp, vp):
     """One line a chunk shape: the kernel at T > 1 against the reference."""
     import jax
     import jax.numpy as jnp
@@ -181,12 +211,13 @@ def chunk_rows(mod, name, dev, args, L, nh, hd, bs, nb, nbk, window, kp, vp):
         bt_d = jnp.asarray(bt)
         ctx_d, q0_d = jnp.asarray([ctx], jnp.int32), jnp.asarray([q0],
                                                                  jnp.int32)
+        window = jnp.asarray(windows, jnp.int32)
 
         def layers(fn):
             def step(q, kp, vp):
                 def layer(acc, li):
                     o = fn(q, kp, vp, bt_d, ctx_d, layer_idx=li,
-                           window=window, q_start=q0_d)
+                           window=window[li], q_start=q0_d)
                     return acc + o.astype(jnp.float32), None
                 return jax.lax.scan(
                     layer, jnp.zeros(q.shape, jnp.float32), jnp.arange(L))[0]
@@ -197,20 +228,28 @@ def chunk_rows(mod, name, dev, args, L, nh, hd, bs, nb, nbk, window, kp, vp):
                               args.reps, q, kp, vp)
         n = ctx - q0                                     # the real rows
         err = float(jnp.max(jnp.abs(out[:, :, :n] - ref[:, :, :n]))) / L
-        need = pages * L * nh * bs * hd * 2 * 2
-        # a real row at position p sees p + 1 keys: QK^T and PV, 2 FLOPs each
-        keys = sum(min(q0 + r + 1, window or ctx) for r in range(n))
-        flops = 4 * keys * hd * nh * L
+        walked = walked_pages(ctx, q0, bs, windows)
+        need = walked * kp.shape[1] * bs * hd * 2 * 2
+        # a real row at position p sees p + 1 keys (its window's at most):
+        # QK^T and PV, 2 FLOPs each
+        keys = sum(min(q0 + r + 1, w or ctx) for r in range(n)
+                   for w in windows)
+        flops = 4 * keys * hd * nh
         mem, mxu = need / (HBM_GBPS * 1e9), flops / (BF16_TFLOPS * 1e12)
-        hg = mod._head_group(nh, bs, hd, 2, T)
+        plan = {}
+        if hasattr(mod, "_program_heads"):
+            hg, gq = mod._program_heads(nh, kp.shape[1], bs, hd, 2, T)
+            plan = {"stored_heads_per_program": hg,
+                    "query_heads_of_a_stored_head_per_program": gq,
+                    "pages_per_group": mod._pages_per_group(
+                        hg * gq, bs, hd, 2, nbk, False, T)}
         print(json.dumps({
-            "shape": name, "setting": f"chunk T={T} q0={q0}",
-            "heads_per_program": hg,
-            "pages_per_group": mod._pages_per_group(hg, bs, hd, 2, nbk,
-                                                    False, T),
+            "shape": name, "setting": f"chunk T={T} q0={q0}"
+            + ("" if mod.__name__.endswith(".paged_attention") else " old"),
+            "stored_heads": kp.shape[1], "query_heads": nh, **plan,
             "device_kind": dev.device_kind, "ms_per_step": ms,
             "us_per_layer": 1e3 * ms / L, "reference_ms_per_step": ref_ms,
-            "pages_walked_a_layer": pages, "needed_gb": need / 1e9,
+            "pages_walked_a_step": walked, "needed_gb": need / 1e9,
             "needed_gflop": flops / 1e9,
             "bound": "memory" if mem >= mxu else "compute",
             "memory_roofline_pct": 100 * mem / (ms / 1e3),

@@ -16,16 +16,19 @@ for the experts:
   hold), counted in ``serving/engine.py:_launch``.
 
 A live page is ``block_size x stored heads x head_dim`` elements of K and of
-V, a layer; the kernel is bound by reading them (eight query rows a head: the
-FLOPs are nothing). The last line is one JSON object: ``paged_kernel_
+V, a layer; the kernel is bound by reading them (eight query rows a stored
+head: the FLOPs are nothing). The stored heads are the program's own gauge
+``kv.stored_heads`` (PR 44: a grouped-query model's pool holds its KV heads;
+a program from before the gauge stored every query head, the config's
+``heads``), and the line says which it read. The last line is one JSON object: ``paged_kernel_
 roofline_pct`` with the kernel's and the least milliseconds a step,
 ``paged_live_page_share`` over the traced steps and over the untraced window
 after them.
 
 Since PR 37 a prefill chunk's attention is the same kernel at T > 1 query
-rows (its custom call makes ``[1, groups, heads, T, head_dim]``, a decode
-call ``[lanes, groups, heads, 8, head_dim]``: the trace tells them apart by
-the rows): ``paged_chunk_kernel_roofline_pct`` holds the chunk calls' time in
+rows (its custom call makes ``[1, programs, query heads a program, rows of
+whole 256-row tiles, head_dim]``, a decode call ``[lanes, programs, stored
+heads, 8 or 16, head_dim]``: the trace tells them apart by the rows): ``paged_chunk_kernel_roofline_pct`` holds the chunk calls' time in
 the steps that carry one against the bytes of the pages they walk
 (``paged.chunk_live_pages_sum``, counted in ``engine.py:_prefill_inputs``),
 beside the decode calls' share, and ``paged_chunk_live_page_share`` those
@@ -59,8 +62,10 @@ def paged_metrics(cell, obs):
     or the trace no such kernel."""
     dims, peaks = obs["context"]["dims"], obs["context"]["peaks"]
     serving = cell.system["serving"]
-    # the pool stores every query head (GQA is expanded before the write)
-    page = (serving["block_size"] * dims["heads"] * dims["head_dim"] * 2
+    # what the pool stores a token and layer, from the program itself
+    stored = obs["program"]["snapshot"].get("gauges", {}).get(
+        "kv.stored_heads", dims["heads"])
+    page = (serving["block_size"] * stored * dims["head_dim"] * 2
             * ITEMSIZE[cell.system["dtype"]] * dims["layers"])
     pt = obs["program"]["trace"]
     # a chunk call's result has its query rows where a decode call's has
@@ -113,6 +118,7 @@ def paged_metrics(cell, obs):
         **chunk,
         "paged_kernel_roofline_pct": {
             "value": 100.0 * least / secs, "unit": "%", "bound": "memory",
+            "stored_heads": stored,
             "steps": n, "kernel_ms_per_step": 1e3 * secs / n,
             "least_ms_per_step": 1e3 * least / n,
             "needed_gb_per_step": needed / n / 1e9},
@@ -130,15 +136,17 @@ def paged_metrics(cell, obs):
 
 def _chunk_calls(pt):
     """``pt`` with only the ``paged_attention`` calls of a prefill chunk on
-    its device lines: the calls whose result has other than the eight query
-    rows a decode token is broadcast to."""
+    its device lines: the calls whose result has whole 256-row tiles of
+    query rows (``_CHUNK_TILE``), where a decode call's has the 8 or 16 of
+    one token a query head."""
     import dataclasses
 
     def rows(result):
         dims = result[result.find("[") + 1:result.find("]")].split(",")
         return int(dims[-2]) if len(dims) >= 2 and dims[-2].isdigit() else 8
 
-    devices = {name: [o for o in ops if KERNEL in o[0] and rows(o[1]) != 8]
+    devices = {name: [o for o in ops
+                      if KERNEL in o[0] and rows(o[1]) % 256 == 0]
                for name, ops in pt.trace.devices.items()}
     return dataclasses.replace(pt, trace=dataclasses.replace(
         pt.trace, devices=devices, cache={}))

@@ -101,21 +101,24 @@ def test_flash_masked_wrapper_compiles(chip, monkeypatch):
     assert len(names) == 3, names
 
 
-# layers, stored heads, head_dim, block, pool blocks, lanes, table: the
-# serving widths of chip_smoke.py, of the benchmark's two serving cells, and
-# of a preset whose heads are narrower than a lane tile
+# layers, query heads, stored (KV) heads, head_dim, block, pool blocks, lanes,
+# table: the serving widths of chip_smoke.py, of the benchmark's three
+# serving cells (two of them grouped-query models: four and eight query heads
+# to a stored head), and of a preset whose heads are narrower than a lane tile
 _PAGED_SHAPES = {
-    "gpt2-1.3b": (L, NH, HD, BS, NB, B, NBK),
-    "serve-olmoe-1b-7b-l8-gen": (8, 16, 128, 32, 2048, 64, 128),
-    "serve-mistral-7b-l16-chat": (16, 32, 128, 32, 384, 32, 40),
-    "llama-1.1b": (22, 32, 64, 32, 256, 8, 32)}
+    "gpt2-1.3b": (L, NH, NH, HD, BS, NB, B, NBK),
+    "serve-olmoe-1b-7b-l8-gen": (8, 16, 16, 128, 32, 2048, 64, 128),
+    "serve-mistral-7b-l16-chat": (16, 32, 8, 128, 32, 384, 32, 40),
+    "serve-k-exaone-236b-ep8-l5-mixed": (5, 64, 8, 128, 32, 1024, 32, 128),
+    "llama-1.1b": (22, 32, 4, 64, 32, 256, 8, 32)}
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
 @pytest.mark.parametrize("cell", list(_PAGED_SHAPES))
 def test_paged_attention_stacked_pool_compiles(chip, cell, quant):
-    """The serving decode kernel on the stacked [L, nh, blocks, bs, hd] pool
-    with a traced layer index and window, its K/V pages copied by the
+    """The serving decode kernel on the stacked [L, kv heads, blocks, bs, hd]
+    pool (a grouped-query model's query heads are rows of their stored
+    head's tile) with a traced layer index and window, its K/V pages copied by the
     kernel itself out of the pool where it lies; int8 adds the per-slot
     scale rows (whole 128-lane tiles: a (1, block_size) row the compiler
     refused before PR 21 as a block and refuses still as a manual copy).
@@ -126,15 +129,15 @@ def test_paged_attention_stacked_pool_compiles(chip, cell, quant):
     copy of part of the 128-lane row it pads such a pool to."""
     from deepspeed_tpu.ops.pallas.paged_attention import (paged_attention,
                                                           scale_rows)
-    L, NH, HD, BS, NB, B, NBK = _PAGED_SHAPES[cell]
+    L, NH, KVH, HD, BS, NB, B, NBK = _PAGED_SHAPES[cell]
     q = chip((B, NH, 1, HD), jnp.bfloat16)
-    pool = chip((L, NH, NB, BS, HD), jnp.int8 if quant else jnp.bfloat16)
+    pool = chip((L, KVH, NB, BS, HD), jnp.int8 if quant else jnp.bfloat16)
     bt, lens, li = (chip((B, NBK), jnp.int32), chip((B,), jnp.int32),
                     chip((), jnp.int32))
     if quant:
         # the scales as serving.model_runner carries them through its loop
         sc = chip(jax.eval_shape(
-            lambda: scale_rows(jnp.zeros((L, NH, NB * BS, 1)),
+            lambda: scale_rows(jnp.zeros((L, KVH, NB * BS, 1)),
                                pool.shape)).shape, jnp.float32)
         fn, args = (lambda q, k, v, bt, lens, li, ks, vs: paged_attention(
             q, k, v, bt, lens, layer_idx=li, window=li, k_scale=ks,
@@ -156,51 +159,60 @@ def test_paged_attention_stacked_pool_compiles(chip, cell, quant):
     # nothing as large as one layer of a pool is made (int8: nor its scales).
     # Narrow heads are not held to it: as a bare call's argument such a pool
     # is copied whole to the lane-padded layout the kernel takes
-    least = NH * NB * (128 if quant else BS * HD)
+    least = KVH * NB * (128 if quant else BS * HD)
     big = [r for r in results if r[3] >= least and r[1] not in (
         "parameter", "get-tuple-element", "tuple", "bitcast")]
     assert not big or HD % 128, big
 
 
-@pytest.mark.parametrize("T", range(32, 257, 32))
-@pytest.mark.parametrize("cell", ["serve-olmoe-1b-7b-l8-gen",
-                                  "serve-mistral-7b-l16-chat"])
+@pytest.mark.parametrize("cell,T", [
+    (cell, T) for cell in ("serve-olmoe-1b-7b-l8-gen",
+                           "serve-mistral-7b-l16-chat")
+    for T in range(32, 257, 32)] + [
+    # every chunk shape pads to the one tile: the smallest and the largest
+    ("serve-k-exaone-236b-ep8-l5-mixed", 32),
+    ("serve-k-exaone-236b-ep8-l5-mixed", 256)])
 def test_paged_attention_prefill_chunk_compiles(chip, cell, T):
     """The same kernel under a prefill chunk's T query rows a lane, at both
     serving cells' widths and every chunk shape their loops send (the
     multiples of the block up to ``prefill_chunk_tokens``, each padded to the
-    one tile of 256 rows the kernel is traced at): heads a program and
+    one tile of 256 rows the kernel is traced at; a grouped-query model's
+    tile is its stored head's whole group, mistral's 4 x 256 rows, or half
+    of K-EXAONE's 8 x 256): heads a program and
     pages a group shrink with the rows so that the accumulator, the running
     rows and one group's scores fit the chip's scoped VMEM beside the page
     buffers, which only this compiler can say. One kernel, one lane, no
     loop round it and nothing as large as a layer of the pool."""
     from deepspeed_tpu.ops.pallas.paged_attention import (
-        _head_group, _pages_per_group, _query_rows, paged_attention)
-    L, NH, HD, BS, NB, _, NBK = _PAGED_SHAPES[cell]
+        _pages_per_group, _program_heads, _query_rows, paged_attention)
+    L, NH, KVH, HD, BS, NB, _, NBK = _PAGED_SHAPES[cell]
     q = chip((1, NH, T, HD), jnp.bfloat16)
-    pool = chip((L, NH, NB, BS, HD), jnp.bfloat16)
+    pool = chip((L, KVH, NB, BS, HD), jnp.bfloat16)
     bt, lens, li = (chip((1, NBK), jnp.int32), chip((1,), jnp.int32),
                     chip((), jnp.int32))
     fn = lambda q, k, v, bt, lens, q0, li: paged_attention(
         q, k, v, bt, lens, layer_idx=li, window=li, q_start=q0)
     args = (q, pool, pool, bt, lens, lens, li)
-    hg, rows = _head_group(NH, BS, HD, 2, T), _query_rows(T)
-    assert rows == 256 and hg * rows <= 1024 and NH % hg == 0
-    assert _pages_per_group(hg, BS, HD, 2, NBK, False, T) * BS * hg * rows \
-        * 4 <= 1 << 20
+    group = NH // KVH
+    hg, gq = _program_heads(NH, KVH, BS, HD, 2, T)
+    rows = gq * _query_rows(T)              # of one stored head's tile
+    assert rows == gq * 256 and hg * rows <= 1024 and KVH % hg == 0
+    assert (hg, gq) == {1: (4, 1), 4: (1, 4), 8: (1, 4)}[group]
+    assert _pages_per_group(hg * gq, BS, HD, 2, NBK, False, T) * BS * hg \
+        * rows * 4 <= 1 << 20
     # the call is a jitted one, shared by the chunk shapes: one level down
     calls = [e for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns
              if e.primitive.name == "jit"]
     grids = [e.params["grid_mapping"].grid
              for c in calls for e in c.params["jaxpr"].jaxpr.eqns
              if e.primitive.name == "pallas_call"]
-    assert grids == [(1, NH // hg)], grids
+    assert grids == [(1, KVH // hg * (group // gq))], grids
     text = jax.jit(fn).lower(*args).compile().as_text()
     names = _kernel_scopes(text)
     assert len(names) == 1 and "paged_attention" in names[0], names
     results = _results(text)
     assert not [r for r in results if r[1] == "while"], results
-    big = [r for r in results if r[3] >= NH * NB * BS * HD and r[1] not in (
+    big = [r for r in results if r[3] >= KVH * NB * BS * HD and r[1] not in (
         "parameter", "get-tuple-element", "tuple", "bitcast")]
     assert not big, big
 
@@ -279,13 +291,28 @@ def _results(text):
     return out
 
 
+def _prefill_rides_the_kernel(cfg, pools, bs, chunk=256):
+    """Gauge ``paged.prefill_path`` as the engine works it out
+    (``serving/engine.py::_note_prefill_path``): every prefill program of
+    the loop, a chunk shape each, goes the kernel's way."""
+    from deepspeed_tpu.ops.attention import paged_attention_path
+    pool = pools["k"]
+    for T in range(bs, chunk + 1, bs):
+        assert paged_attention_path(
+            (1, cfg.num_heads, T, cfg.head_dim),
+            pool.shape[:2] + (pool.shape[2] // bs, bs, cfg.head_dim),
+            stacked=True, quant="k_scale" in pools) == ("kernel", None)
+
+
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
 @pytest.mark.parametrize("program", ["decode", "prefill256"])
 def test_serving_step_updates_the_pool_in_place(chip, monkeypatch, program,
                                                 quant):
     """The serving loop's two programs (``serving.engine.step_programs``, as
     the engine jits them: pools donated) at the benchmark cell's widths,
-    mistral-7b-l16 with 32 lanes over a 384 x 32 pool: no instruction makes
+    mistral-7b-l16 with 32 lanes over a 384 x 32 pool stored at the model's
+    8 KV heads (``[16, 8, slots, 128]``, a quarter of what 32 query heads
+    took until PR 44): no instruction makes
     a whole K/V pool, or a whole layer of one, by ``copy``, ``transpose``,
     ``scatter`` or ``dynamic-slice`` (a prefill chunk sliced both pools' layer
     out for the gather reference until PR 37: 5.7 ms of a chunk step), and
@@ -299,10 +326,10 @@ def test_serving_step_updates_the_pool_in_place(chip, monkeypatch, program,
                                               token_words)
     from deepspeed_tpu.serving.kv_cache import init_pool
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    L, NH, HD, BS, NB, B, NBK = 16, 32, 128, 32, 384, 32, 40
+    L, NH, KVH, HD, BS, NB, B, NBK = 16, 32, 8, 128, 32, 384, 32, 40
     model, cfg = build_model(TransformerConfig(
         vocab_size=32000, max_seq_len=32768, hidden_size=NH * HD,
-        num_layers=L, num_heads=NH, num_kv_heads=8, mlp_dim_override=14336,
+        num_layers=L, num_heads=NH, num_kv_heads=KVH, mlp_dim_override=14336,
         layer_norm_eps=1e-5, norm="rmsnorm", gated_mlp=True,
         activation="silu", pos_embed="rotary", rotary_interleaved=False,
         use_bias=False, tie_embeddings=False, layer_windows=(4096,) * L,
@@ -314,6 +341,9 @@ def test_serving_step_updates_the_pool_in_place(chip, monkeypatch, program,
             {"input_ids": jnp.zeros((1, 8), jnp.int32)})["params"]), L)))
     pools = on_chip(jax.eval_shape(lambda: init_pool(
         cfg, NB, BS, jnp.int8 if quant else jnp.bfloat16)))
+    assert pools["k"].shape == (L, KVH, NB * BS, HD)
+    assert not quant or pools["k_scale"].shape == (L, KVH, NB * BS, 1)
+    _prefill_rides_the_kernel(cfg, pools, BS)
     lanes = B if program == "decode" else 1
     decode, prefill = step_programs(cfg, BS, NBK)
     if program == "decode":
@@ -326,8 +356,11 @@ def test_serving_step_updates_the_pool_in_place(chip, monkeypatch, program,
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(
         params, pools, chip((words,), jnp.int32), *fed).compile()
     text = compiled.as_text()
+    # the pools go in and come out where they lie (donated, aliased)
+    assert compiled.memory_analysis().alias_size_in_bytes >= sum(
+        p.size * p.dtype.itemsize for p in pools.values())
 
-    layer = NH * NB * BS * HD
+    layer = KVH * NB * BS * HD
     moved = [r for r in _results(text)
              if r[1] in ("copy", "transpose", "scatter") and r[3] >= layer]
     assert not moved, moved
@@ -340,7 +373,7 @@ def test_serving_step_updates_the_pool_in_place(chip, monkeypatch, program,
     # 128-lane tiles, which is not the layout they have at the jit boundary:
     # they change layout there (four small conversions a step), never in the
     # loop, neither as they come nor as the loop carries them
-    scales = (L * NH * NB * BS, L * NH * NB * 128)
+    scales = (L * KVH * NB * BS, L * KVH * NB * 128)
     inside = [r for r in _results(text)
               if r[0] != "ENTRY" and r[2] == "f32" and r[3] in scales
               and r[1] in ("copy", "transpose", "scatter", "reshape", "pad")]
@@ -444,7 +477,7 @@ def test_k_exaone_serving_step_holds_a_share_in_place(chip, monkeypatch,
                                                       program):
     """The serving loop's two programs at the K-EXAONE cell's widths, read
     from the benchmark's own files (k-exaone-236b-ep8-l5: hidden 6144, 64
-    heads stored, 16 of 128 experts of 2048 held, top-8, a shared expert, a
+    query heads over 8 stored, 16 of 128 experts of 2048 held, top-8, a shared expert, a
     leading dense layer of 18432; 32 lanes over a 1024 x 32 pool): the held
     experts' matmuls are the megablox kernel, three in the sparse stack's
     layer body; each of the two stacks' bodies has its paged-attention call;
@@ -478,6 +511,10 @@ def test_k_exaone_serving_step_holds_a_share_in_place(chip, monkeypatch,
         (LS, HELD, H, M)
     pools = on_chip(jax.eval_shape(lambda: init_pool(cfg, NB, BS,
                                                      jnp.bfloat16)))
+    KVH = cfg.kv_heads
+    assert (NH, KVH) == (64, 8)
+    assert pools["k"].shape == (cfg.num_layers, KVH, NB * BS, HD)
+    _prefill_rides_the_kernel(cfg, pools, BS)
     lanes = B if program == "decode" else 1
     decode, prefill = step_programs(cfg, BS, NBK)
     if program == "decode":
@@ -509,12 +546,14 @@ def test_k_exaone_serving_step_holds_a_share_in_place(chip, monkeypatch,
     assert not [r for r in made if r[3] >= HELD * H * M
                 and r[2] == "bf16" and r[3] % (HELD * H * M) == 0
                 and r[3] <= LS * HELD * H * M], made
-    layer = NH * NB * BS * HD
+    layer = KVH * NB * BS * HD
     moved = [r for r in made if r[3] >= layer and (
         r[1] in ("copy", "transpose", "scatter")
         or r[1] == "dynamic-slice" and r[3] == layer)]
     assert not moved, moved
     mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(
+        p.size * p.dtype.itemsize for p in pools.values())
     held_bytes = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert held_bytes < 15.0e9, (mem.argument_size_in_bytes,
                                  mem.temp_size_in_bytes)

@@ -54,6 +54,14 @@ FEATURES = {
     "moe_dropless_top8": dict(**_ROTARY, **_SWIGLU, moe_experts=16, moe_k=8,
                               moe_dropless=True, moe_norm_topk=False),
     "int8_kv": dict(**_ROTARY),
+    # grouped-query models (PR 44): the pool holds the KV heads, the dense
+    # cache a row a query head; the int8 tier's scales follow each; ALiBi
+    # slopes stay a query head's own; one KV head for all is multi-query
+    "gqa_int8_kv": dict(**_ROTARY, num_kv_heads=2),
+    "gqa_alibi_softcap_window": dict(pos_embed="alibi", num_kv_heads=2,
+                                     attn_softcap=20.0,
+                                     layer_windows=(0, 5)),
+    "multi_query": dict(**_ROTARY, num_kv_heads=1),
     "int8_weights_per_channel": dict(**_ROTARY, tie_embeddings=False),
     # K-EXAONE's layer: a chip's share of the experts (2 of 16 held), a
     # sigmoid router with a selection bias, renormalised and scaled picks, a
@@ -94,7 +102,7 @@ def test_dense_and_paged_forward_agree(feature):
                                                gate["bias"].shape)
     if feature == "int8_weights_per_channel":
         params = quantize_weights_int8(params)
-    kv_dtype = jnp.int8 if feature == "int8_kv" else jnp.float32
+    kv_dtype = jnp.int8 if feature.endswith("int8_kv") else jnp.float32
     counting = cfg.moe_is_dropless
     ids = np.random.default_rng(7).integers(
         1, VOCAB, size=(1, PROMPT + STEPS)).astype(np.int32)
@@ -117,6 +125,10 @@ def test_dense_and_paged_forward_agree(feature):
     table = np.full((1, NBK), NULL_BLOCK, np.int32)
     table[0, :4] = (7, 2, 9, 4)                        # 24 of 32 slots used
     pools = init_pool(cfg, BLOCKS, BS, kv_dtype)
+    # the pool holds the model's KV heads, generate()'s cache every query head
+    assert pools["k"].shape == (cfg.num_layers, cfg.kv_heads, BLOCKS * BS,
+                                cfg.head_dim)
+    assert cache["k"].shape[2] == cfg.num_heads
     got, counted = [], []
 
     def call(tokens, bt, q0, ctx, real):

@@ -396,7 +396,8 @@ def test_paged_loop_alternates_slots_over_many_groups(monkeypatch, regime,
     np.testing.assert_allclose(np.asarray(out), want, rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("regime", ["plain", "int8", "chunk", "chunk-int8"])
+@pytest.mark.parametrize("regime", ["plain", "int8", "chunk", "chunk-int8",
+                                    "grouped", "grouped-chunk"])
 def test_paged_loop_under_the_tpu_interpreter(regime):
     """The same kernel under the TPU interpreter, which keeps semaphores
     and copies apart from the compute as the chip does: every buffer it
@@ -412,12 +413,21 @@ def test_paged_loop_under_the_tpu_interpreter(regime):
                             seed=13)
     kw = dict(k_scale=args[5], v_scale=args[6]) if quant else {}
     args = list(args[:5])
+    if regime.startswith("grouped"):
+        # four query heads to each of the pool's two: rows of one tile (a
+        # decode token), and 4 x 256 rows of ONE program a stored head
+        args[0] = jnp.asarray(np.random.default_rng(15).standard_normal(
+            (_EB, 4 * _ENH, 1, _EHD)).astype(np.float32))
+        want = np.array(paged_attention_reference(*args))
+        want[np.asarray(args[4]) == 0] = 0.0
+        regime = regime[len("grouped-"):]
+    heads = args[0].shape[1]
     if regime.startswith("chunk"):
         # 16 rows a lane: an idle lane, a chunk in mid-block over two
         # groups, one ending with the table, one of a single real row
         q0 = np.asarray([0, 24, 32, 0], np.int32)
         args[0] = jnp.asarray(np.random.default_rng(14).standard_normal(
-            (_EB, _ENH, 16, _EHD)).astype(np.float32))
+            (_EB, heads, 16, _EHD)).astype(np.float32))
         kw["q_start"] = jnp.asarray(q0)
         want = np.asarray(paged_attention_reference(*args, **kw))
     out = np.asarray(paged_attention(*args, interpret=pltpu.InterpretParams(
@@ -556,6 +566,154 @@ def test_pages_per_group_from_shapes(shape, want):
     if quant:
         held += 2 * 2 * hg * P * 8 * _scale_lanes(bs) * 4
     assert held <= _VMEM_BUDGET or P == 1, (held, _VMEM_BUDGET)
+
+
+# ---------------------------------------------------------------------------
+# Grouped-query models (PR 44): the pool stores the model's KV heads, and the
+# ``group = nh // kvh`` query heads that share a stored head are ROWS of that
+# head's query tile: a decode token of each on a row of its own (a group of
+# 16 takes two sublane tiles), a chunk's rows head after head, a row's
+# position ``q_start + row % tile``; where ``group x tile`` is more than a
+# program holds (8 and 16 heads of 256 rows) the group's heads are split over
+# programs that copy the same stored head. The kernel against the gather
+# reference, and the reference against itself over the pool expanded to a
+# row a query head (``group`` 1: what the pool held before).
+# ---------------------------------------------------------------------------
+
+_GKVH = 2
+_GSHAPES = {
+    # name: (T, q_start a lane, ctx a lane)
+    "decode": (1, [28, 0, 47, 8], [29, 0, 48, 9]),
+    "chunk_aligned": (16, [16, 0], [32, 16]),
+    "chunk_mid_block": (16, [13, 5], [29, 21]),
+    "chunk_padded_rows": (16, [8, 40], [13, 41]),
+}
+
+
+def _grouped_case(group, shape, regime, seed=31):
+    """``(args, kw)`` of one grouped call: ``_GKVH`` stored heads, ``group``
+    query heads each, on the loop's edge shape (pages of 8 slots, heads of
+    128, a table of 6)."""
+    T, q0, ctx = _GSHAPES[shape]
+    r = _REGIMES[regime]
+    B, nh = len(q0), _GKVH * group
+    _, kp, vp, bt, _ = _data(B=B, nh=_GKVH, hd=_EHD, bs=_EBS, num_blocks=_ENB,
+                             nbk=_ENBK, seed=seed)
+    q = np.random.default_rng(seed + 1).standard_normal(
+        (B, nh, T, _EHD)).astype(np.float32)
+    kw = {}
+    if "window" in r:
+        kw["window"] = jnp.asarray(r["window"], jnp.int32)
+    if "slopes" in r:
+        kw["alibi_slopes"] = jnp.asarray(
+            [2.0 ** -(1 + 8 * i / nh) for i in range(nh)], jnp.float32)
+    if "softcap" in r:
+        kw["softcap"] = r["softcap"]
+    if r.get("quant"):
+        kp, kw["k_scale"], vp, kw["v_scale"], _, _ = _int8_pools(kp, vp)
+    if r.get("stacked"):
+        kp, vp = np.stack([kp, kp * 2.0]), np.stack([vp, vp * 0.5])
+        kw["layer_idx"] = jnp.asarray(1, jnp.int32)
+    if T > 1:
+        kw["q_start"] = jnp.asarray(q0, jnp.int32)
+    args = (jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+            jnp.asarray(bt), jnp.asarray(ctx, jnp.int32))
+    return args, kw
+
+
+def _expanded(args, kw, group):
+    """The same call over a pool that holds a row a QUERY head."""
+    rep = lambda a: jnp.repeat(a, group, axis=a.ndim - 4)
+    wide = dict(kw)
+    if "k_scale" in kw:             # [(L,) kvh, blocks, slots, 1]
+        wide["k_scale"], wide["v_scale"] = rep(kw["k_scale"]), rep(
+            kw["v_scale"])
+    return (args[0], rep(args[1]), rep(args[2])) + args[3:], wide
+
+
+def _real_rows(out, ref, shape):
+    """Every real row of every lane (an idle decode lane gives zeros; a
+    chunk's rows at or past ctx are padding nobody reads)."""
+    T, q0, ctx = _GSHAPES[shape]
+    for b, (s, c) in enumerate(zip(q0, ctx)):
+        n = min(T, c - s) if T > 1 else int(c > 0)
+        yield out[b, :, :n], ref[b, :, :n]
+
+
+#: every group against every shape with nothing else on; each other regime
+#: on a decode tile filled, a chunk of one program a stored head and a chunk
+#: split over programs (the whole product is 120 interpreted kernels, three
+#: times this file's other tests together, and finds no more)
+_GCASES = [(g, s, "plain") for g in (1, 2, 4, 8, 16) for s in _GSHAPES] + [
+    (g, s, r) for r in _REGIMES if r != "plain"
+    for g, s in ((4, "chunk_mid_block"), (8, "decode"),
+                 (16, "chunk_padded_rows"))]
+
+
+@pytest.mark.parametrize("group,shape,regime", _GCASES,
+                         ids=["-".join(map(str, c)) for c in _GCASES])
+def test_grouped_query_heads_share_a_stored_head(group, shape, regime):
+    args, kw = _grouped_case(group, shape, regime)
+    static = {n: kw.pop(n) for n in ("softcap",) if n in kw}    # a float
+
+    @jax.jit
+    def both(args, kw):
+        kw = {**kw, **static}
+        wide_args, wide_kw = _expanded(args, kw, group)
+        return (paged_attention(*args, interpret=True, **kw),
+                paged_attention_reference(*args, **kw),
+                paged_attention_reference(*wide_args, **wide_kw))
+
+    out, ref, wide = (np.asarray(a) for a in both(args, kw))
+    assert out.shape == args[0].shape and np.isfinite(out).all()
+    for got, want in _real_rows(out, ref, shape):
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    for got, want in _real_rows(ref, wide, shape):
+        np.testing.assert_allclose(got, want, rtol=2e-6, atol=2e-6)
+
+
+@pytest.mark.parametrize("nh,kvh,T,want", [
+    # (query heads, stored heads, rows a head) -> (stored heads a program,
+    # query heads of a stored head a program, programs a lane, rows, pages)
+    (32, 8, 1, (8, 4, 1, 8, 4)),            # mistral: a decode token
+    (32, 8, 256, (1, 4, 8, 1024, 8)),       # ... a chunk: one tile a group
+    (64, 8, 1, (8, 8, 1, 8, 2)),            # K-EXAONE: the tile filled
+    (64, 8, 256, (1, 4, 16, 1024, 8)),      # ... 8 x 256 rows: two programs
+    (64, 8, 512, (1, 2, 32, 1024, 8)),
+    (32, 4, 1, (4, 8, 1, 8, 4)),            # llama-1.1b's heads at 128 wide
+    (32, 2, 1, (2, 16, 1, 16, 4)),          # a group of 16: two sublane tiles
+    (16, 16, 1, (16, 1, 1, 8, 8)),          # OLMoE, gpt2: nothing to group
+    (16, 16, 256, (4, 1, 4, 256, 8)),
+    (32, 32, 256, (4, 1, 8, 256, 8)),       # the pool the parent stored
+], ids=lambda v: str(v))
+def test_group_tile_from_shapes(nh, kvh, T, want):
+    """The tile of one stored head at the serving cells' widths (block 32,
+    heads of 128, bf16, a table of 128): what a model with nothing to group
+    gets is what it got, a chunk's programs keep their rows within
+    ``_CHUNK_ROWS`` by splitting a group's query heads over programs, and
+    the pages a group are what the QUERY heads got when the pool stored a
+    row for each (more of them cost set-up and no time: PERF.md, PR 44)."""
+    from deepspeed_tpu.ops.pallas.paged_attention import (
+        _CHUNK_ROWS, _pages_per_group, _program_heads, _query_rows)
+    group = nh // kvh
+    hg, gq = _program_heads(nh, kvh, 32, 128, 2, T)
+    P = _pages_per_group(hg * gq, 32, 128, 2, 128, False, T)
+    # a decode tile: a row a query head of the group; a chunk's: a tile of
+    # 256-row multiples a query head, hg x gq of them a program
+    rows = _query_rows(T, group) if T == 1 else gq * _query_rows(T)
+    assert (hg, gq, kvh // hg * (group // gq), rows, P) == want
+    assert hg * rows <= _CHUNK_ROWS
+    if group > 1 and T > 1:
+        assert group * _query_rows(T) > _CHUNK_ROWS or gq == group
+
+
+def test_query_heads_must_share_the_stored_heads_evenly():
+    args, kw = _grouped_case(3, "decode", "plain")
+    bad = (args[0][:, :5],) + args[1:]
+    for fn in (lambda *a: paged_attention(*a, interpret=True),
+               paged_attention_reference):
+        with pytest.raises(ValueError, match="stored heads"):
+            fn(*bad)
 
 
 # tier-2 (round-17 budget sweep, ~9s): the cheaper tier-1 cousins are

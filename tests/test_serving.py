@@ -9,6 +9,7 @@ arrivals, mixed lengths, admissions and evictions.
 """
 
 import dataclasses
+import functools
 
 import numpy as np
 import pytest
@@ -651,6 +652,78 @@ def test_prefill_through_the_kernel_is_token_exact(tiny, monkeypatch, mode):
     paths = eng.telemetry()["gauges"]["paged.prefill_path"]
     assert {k: sorted(v) for k, v in paths.items()} == \
         {"kernel": sorted(want - {1})}
+
+
+#: grouped-query models at a tiny size: query heads, KV heads, head_dim.
+#: Heads of 128 take the kernel that copies its own pages, narrower ones
+#: come through the pipeline a page a grid step (decode; a chunk of theirs
+#: rides the loop kernel under the interpreter only)
+_GQA = {"llama-1.1b-narrow-heads": ("llama-1.1b", 4, 2, 16),
+        "mistral-like-128-wide": ("llama-1.1b", 4, 1, 128),
+        "multi-query": ("gpt2-tiny", 2, 1, 16)}
+
+
+@functools.lru_cache(maxsize=None)
+def _gqa_model(name):
+    preset, nh, kvh, hd = _GQA[name]
+    model, cfg = build_model(
+        preset, hidden_size=nh * hd, num_layers=2, num_heads=nh,
+        num_kv_heads=kvh, mlp_dim_override=64, vocab_size=64,
+        max_seq_len=256, dtype=jnp.float32)
+    assert cfg.attention_impl == "auto" and cfg.kv_heads == kvh
+    params = model.init(jax.random.PRNGKey(2),
+                        {"input_ids": np.zeros((1, 8), np.int32)})["params"]
+    return cfg, params
+
+
+@pytest.mark.parametrize("model,mode,kv", [
+    ("mistral-like-128-wide", "chunked", "model_dtype"),
+    ("mistral-like-128-wide", "prefix_hit", "model_dtype"),
+    ("mistral-like-128-wide", "whole", "model_dtype"),
+    ("mistral-like-128-wide", "chunked", "int8"),
+    ("llama-1.1b-narrow-heads", "chunked", "model_dtype"),
+    ("multi-query", "prefix_hit", "model_dtype")])
+def test_grouped_query_serve_is_token_exact_at_kv_heads(model, mode, kv):
+    """A grouped-query model's pool holds its KV heads (PR 44), the paged
+    kernel (interpreted here) reads one stored head for the query heads that
+    share it, and greedy streams stay token-exact with sequential
+    ``generate()``, whose dense cache still holds a row a query head: under
+    chunked prefill (chunks of 10 start and end in mid-block), across a
+    prefix-cache hit and under whole prefill. The int8 tier stores its
+    scales at the KV heads too and agrees with its own gather reference."""
+    cfg, params = _gqa_model(model)
+    chunk = 0 if mode == "whole" else 10 if mode == "chunked" else 32
+    serving = dict(SERVE_CFG, prefill_chunk_tokens=chunk,
+                   **({"kv_cache_dtype": "int8"} if kv == "int8" else {}))
+    eng = ServingEngine(cfg, params, interpret=True, serving=serving)
+    L, slots = cfg.num_layers, SERVE_CFG["pool_blocks"] * 16
+    assert eng.pools["k"].shape == (L, cfg.kv_heads, slots, cfg.head_dim)
+    per_token = 2 * L * cfg.kv_heads * cfg.head_dim * 4
+    if kv == "int8":
+        assert eng.pools["k_scale"].shape == (L, cfg.kv_heads, slots, 1)
+        per_token = 2 * L * cfg.kv_heads * (cfg.head_dim + 4)
+    gauges = eng.telemetry()["gauges"]
+    assert gauges["kv.stored_heads"] == cfg.kv_heads < cfg.num_heads
+    assert gauges["kv.bytes_per_token"] == per_token
+    rng = np.random.default_rng(43)
+    shared = list(rng.integers(1, 64, size=37))
+    prompts = [shared + list(rng.integers(1, 64, size=n)) for n in (9, 4)] \
+        if mode == "prefix_hit" else \
+        [list(rng.integers(1, 64, size=n)) for n in (37, 23)]
+    outs = [eng.generate_batch([p], max_new_tokens=5)[0] for p in prompts]
+    if mode == "prefix_hit":
+        assert eng.stats["prefix_hit_tokens"] == 32     # two whole blocks
+    paths = eng.telemetry()["gauges"]["paged.prefill_path"]
+    assert list(paths) == ["kernel"], paths
+    if kv == "int8":
+        twin = ServingEngine(
+            dataclasses.replace(cfg, attention_impl="reference"), params,
+            serving=serving)
+        assert outs == [twin.generate_batch([p], max_new_tokens=5)[0]
+                        for p in prompts]
+        return
+    for p, o in zip(prompts, outs):
+        assert o == _oracle_tokens(cfg, params, p, 5)
 
 
 def test_int8_kv_pool_parity_jnp_and_kernel(tiny, paged_kernel_traces):
