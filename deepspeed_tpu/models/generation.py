@@ -29,6 +29,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.attention import apply_softcap, flash_attention_on_mesh
+from ..ops.pallas.sparse_select import (index_scores_reference, select,
+                                        selected, selection_bits)
 from ..quant_format import kv_quantize
 from .transformer import (_ACTIVATIONS, TransformerConfig, alibi_slopes,
                           apply_rotary)
@@ -140,6 +142,10 @@ def init_cache(cfg: TransformerConfig, batch_size: int, max_len: int,
     if dtype == jnp.int8:
         cache["k_scale"] = jnp.zeros(shape[:-1] + (1,), jnp.float32)
         cache["v_scale"] = jnp.zeros(shape[:-1] + (1,), jnp.float32)
+    if cfg.index_heads:
+        # a layer with an indexer caches its one key a token beside K/V
+        cache["ki"] = jnp.zeros((cfg.num_layers, batch_size, 1, max_len,
+                                 cfg.index_head_dim), cfg.dtype)
     if pad_lens is not None:
         cache["pad"] = jnp.asarray(pad_lens, jnp.int32)
     return cache
@@ -255,6 +261,10 @@ def decoder_forward(cfg: TransformerConfig, params: PyTree,
     are routed like any row). ``L`` there is the SPARSE layers
     (``cfg.sparse_layers``: a mixture's leading dense layers have no router)
     and ``E`` the router's outputs, held here or not (``cfg.moe_held``).
+    A model with an indexer hands its selection out with the picks:
+    ``[L, B x T, k + Kp / 32]``, behind a row's experts the keys it attends
+    as bits (``sparse_select.selection_bits``: bit r of word w is the key at
+    position ``32 w + r``; ``Kp`` the cache's key capacity in whole 128s).
     ``forward_with_cache`` and ``serving.model_runner.
     paged_forward`` are this function over two caches; a new kind of
     per-sequence state (a latent cache, a recurrent state) is a third.
@@ -273,8 +283,17 @@ def decoder_forward(cfg: TransformerConfig, params: PyTree,
     * ``write(carry, li, k, v, k_scale, v_scale) -> carry``: layer ``li``'s
       new rows ``[B, kv_heads, T, hd]`` (``quantized``: int8, with f32
       scales);
-    * ``attend(carry, li, q, k, v, window) -> [B, nh, T, hd]`` for the
-      queries ``[B, nh, T, hd]``, ``nh // kv_heads`` of them a K/V head;
+    * ``attend(carry, li, q, k, v, window, select=None) -> [B, nh, T, hd]``
+      for the queries ``[B, nh, T, hd]``, ``nh // kv_heads`` of them a K/V
+      head;
+    * a model with an indexer (``cfg.index_heads``; ``ops/pallas/
+      sparse_select.py``): ``write_index(carry, li, ki) -> carry`` stores
+      the layer's indexer keys ``[B, 1, T, width]`` beside K/V, and
+      ``select(carry, li, qi, wi, window) -> Selection`` answers which
+      cached keys each of the call's rows attends (among those its causal,
+      context and window masks leave), from its indexer queries ``[B, heads,
+      T, width]`` and head weights ``[B, T, heads]``; ``attend`` takes that
+      answer as ``select``;
     * ``real_tokens(pos)``: ``[B, T]`` int32, the tokens a request owns
       (``expert_counts``, a dropless MoE config only: how many of them each
       layer's router sent to each expert).
@@ -338,19 +357,43 @@ def decoder_forward(cfg: TransformerConfig, params: PyTree,
                 q, k = _qk_norm(cfg, p, q, k, "projection")  # OLMoE: whole
                 q, k, v = to_heads(q, nh), to_heads(k, kvh), to_heads(v, kvh)
                 q, k = _qk_norm(cfg, p, q, k, "head")        # Qwen3: a head
+                def rotated(rot, *ts):
+                    if rope is None:
+                        return [rot(t) for t in ts]
+                    # a hybrid: this layer may carry no positions
+                    return [jnp.where(rope, rot(t), t) for t in ts]
+
                 if cfg.pos_embed == "rotary":
                     # the table covers the cache's capacity (dynamic NTK
                     # stretches once; a plain-theta table has no length)
-                    rot = partial(apply_rotary, positions=pos,
-                                  rotary_dim=cfg.rotary_dim,
-                                  interleaved=cfg.rotary_interleaved,
-                                  theta=cfg.rope_theta,
-                                  inv_freq=cfg.rope_inv_freq(cache.rope_len))
-                    if rope is None:
-                        q, k = rot(q), rot(k)
-                    else:   # a hybrid: this layer may carry no positions
-                        q = jnp.where(rope, rot(q), q)
-                        k = jnp.where(rope, rot(k), k)
+                    q, k = rotated(partial(
+                        apply_rotary, positions=pos,
+                        rotary_dim=cfg.rotary_dim,
+                        interleaved=cfg.rotary_interleaved,
+                        theta=cfg.rope_theta,
+                        inv_freq=cfg.rope_inv_freq(cache.rope_len)), q, k)
+            sel = None
+            if cfg.index_heads:
+                # the indexer: its queries and head weights from this token,
+                # its ONE key a token cached beside K/V; which cached keys a
+                # row attends is the cache's to answer
+                with jax.named_scope("index"):
+                    Hi, Di = cfg.index_heads, cfg.index_head_dim
+                    qi = dense(h, p["index_q"]).reshape(
+                        B, T, Hi, Di).transpose(0, 2, 1, 3)
+                    ki = _layer_norm(dense(h, p["index_k"]),
+                                     p["index_k_norm"],
+                                     cfg.layer_norm_eps)[:, None]
+                    wi = dense(h, p["index_w"])
+                    if cfg.pos_embed == "rotary":
+                        # the whole indexer head turns, at the model's theta
+                        qi, ki = rotated(partial(
+                            apply_rotary, positions=pos, rotary_dim=None,
+                            interleaved=cfg.rotary_interleaved,
+                            theta=cfg.rope_theta), qi, ki)
+                with jax.named_scope("index_write"):
+                    kv = cache.write_index(kv, li, ki)
+                sel = cache.select(kv, li, qi, wi, window)
             with jax.named_scope("kv_write"):
                 # GQA: K/V go to the cache at the model's kv heads; what a
                 # cache stores a query head it repeats itself (DenseCache),
@@ -360,7 +403,7 @@ def decoder_forward(cfg: TransformerConfig, params: PyTree,
                     (k, ks), (v, vs) = kv_quantize(k), kv_quantize(v)
                 kv = cache.write(kv, li, k, v, ks, vs)
             with jax.named_scope("attend"):
-                o = cache.attend(kv, li, q, k, v, window)
+                o = cache.attend(kv, li, q, k, v, window, select=sel)
             with jax.named_scope("out"):
                 o = o.transpose(0, 2, 1, 3).reshape(B, T, nh * hd)
                 attn_out = dense(o, p["attn_proj"])
@@ -370,7 +413,16 @@ def decoder_forward(cfg: TransformerConfig, params: PyTree,
 
         def mlp(hin):
             """``(the MLP branch, (this layer's expert counts or None, its
-            picks or None))``."""
+            picks or None))``; behind a row's picks the keys it attends, as
+            bits, where the layer has an indexer."""
+            y, (counts, picks) = routed_mlp(hin)
+            if picks is not None and sel is not None:
+                bits = selection_bits(sel)
+                picks = jnp.concatenate(
+                    [picks, bits.reshape(B * T, bits.shape[-1])], axis=1)
+            return y, (counts, picks)
+
+        def routed_mlp(hin):
             if cfg.moe_experts > 0 and not dense_mlp:
                 # a dropless mixture's expert stack stays whole beside the
                 # loop, the kernel picks this layer's: the stack is indexed
@@ -469,7 +521,7 @@ class DenseCache:
         # per-layer windows trace through one scan body, and ragged or int8
         # caches need the masked read: those keep the jnp path
         self.flash = (bool(self.prefill_flash) and T > 1 and pad is None
-                      and not self.quantized
+                      and not self.quantized and not cfg.index_heads
                       and cfg.uniform_window() is not None
                       and cfg.attention_impl in ("auto", "flash")
                       and (jax.default_backend() == "tpu"
@@ -490,6 +542,31 @@ class DenseCache:
     def carry(self):
         return {n: a for n, a in self.cache.items() if n not in ("pos", "pad")}
 
+    def write_index(self, kv, li, ki):
+        return {**kv, "ki": jax.lax.dynamic_update_slice(
+            kv["ki"], ki.astype(kv["ki"].dtype)[None],
+            (li, 0, 0, self.cache["pos"], 0))}
+
+    def select(self, kv, li, qi, wi, window):
+        """Which of the cache's slots each row attends, by the indexer's
+        scores (the dense-masked form: the jnp twins of ``sparse_select``'s
+        kernels). A ragged batch's left pad is dead to the indexer as it is
+        to attention, and so is a key outside the layer's ``window``."""
+        B, T = qi.shape[0], qi.shape[2]
+        keys = jax.lax.dynamic_index_in_dim(kv["ki"], li, 0,
+                                            keepdims=False)[:, 0]
+        q0 = jnp.broadcast_to(self.cache["pos"], (B,))
+        with jax.named_scope("index"):
+            scores = index_scores_reference(qi, wi, keys, q0, q0 + T,
+                                            window)
+            pad = self.cache.get("pad")
+            if pad is not None:
+                slots = jnp.arange(scores.shape[-1])
+                scores = jnp.where(slots[None, None, :] >= pad[:, None, None],
+                                   scores, -jnp.inf)
+        with jax.named_scope("select"):
+            return select(scores, self.cfg.index_topk, kernel=False)
+
     def finish(self, carry, T: int):
         return {**self.cache, **carry, "pos": self.cache["pos"] + T}
 
@@ -504,11 +581,11 @@ class DenseCache:
     def write(self, kv, li, k, v, k_scale, v_scale):
         new = {"k": k, "v": v, "k_scale": k_scale, "v_scale": v_scale}
         at = (li, 0, 0, self.cache["pos"], 0)
-        return {n: jax.lax.dynamic_update_slice(
-            a, self._per_query_head(new[n])[None], at)
+        return {n: (jax.lax.dynamic_update_slice(
+            a, self._per_query_head(new[n])[None], at) if n in new else a)
             for n, a in kv.items()}
 
-    def attend(self, kv, li, q, k, v, window):
+    def attend(self, kv, li, q, k, v, window, select=None):
         cfg = self.cfg
         if self.flash:
             # empty cache: attention over the FRESH k/v is exactly the
@@ -542,6 +619,10 @@ class DenseCache:
         # is [B, T, len] for ragged batches, [T, len] otherwise
         m = self.mask & ((self.q_slot[:, None] - self.k_slot[None, :] < window)
                          | (window <= 0))
+        if select is not None:      # a row's top-k keys by its indexer
+            m = m & selected(select.scores[:, :, :self.rope_len],
+                             select.thr[..., None], select.tie[..., None],
+                             self.k_slot)
         s = jnp.where(m[:, None] if m.ndim == 3 else m[None, None], s, -1e30)
         prob = jax.nn.softmax(s, axis=-1).astype(q.dtype)
         return jnp.einsum("bhqk,bhkd->bhqd", prob, v_all)

@@ -195,6 +195,16 @@ class TransformerConfig:
     # False: no norm on a branch's INPUT (no ln1 / ln2); the branch outputs
     # are normed instead, so post_block_norms must be set (EXAONE 4.0)
     pre_norm: bool = True
+    # a learned indexer picks each query's keys (DeepSeek-Sparse-Attention's
+    # index score, ops/pallas/sparse_select.py): index_heads query heads of
+    # index_head_dim against ONE indexer key a token (``index_q``,
+    # ``index_k`` + ``index_k_norm``, ``index_w`` in the layer's tree); query
+    # t attends the index_topk keys s <= t of largest score, all of them
+    # while it sees no more. 0 = none. The inference decoder only (ROADMAP
+    # M7): a training Block with these set refuses
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
     # block-sparse attention layout (ds_config "sparse_attention" section;
     # the engine wires it here and sets attention_impl="sparse"): a hashable
     # tuple of (key, value) items — lists as tuples — so the frozen config
@@ -267,6 +277,19 @@ class TransformerConfig:
             raise ValueError("layer_rope: one flag a layer, with "
                              "pos_embed='rotary' and without progressive "
                              "layer drop")
+        if self.index_heads or self.index_head_dim or self.index_topk:
+            if min(self.index_heads, self.index_head_dim,
+                   self.index_topk) <= 0:
+                raise ValueError(
+                    f"index_heads {self.index_heads}, index_head_dim "
+                    f"{self.index_head_dim}, index_topk {self.index_topk}: "
+                    "an indexer has all three, each > 0")
+            if not self.causal or self.post_ln or self.index_head_dim % 2 \
+                    or self.rope_scaling_type not in (None, "default"):
+                raise ValueError(
+                    "an indexer selects the keys of a causal decoder (not "
+                    "post_ln), its head width is even (rotary), and its "
+                    "rotary table is the plain one (no rope_scaling_type)")
         if not self.pre_norm and (not self.post_block_norms or self.post_ln
                                   or self.parallel_residual):
             raise ValueError(
@@ -383,8 +406,15 @@ class TransformerConfig:
 
     def _attn_params(self) -> int:
         h = self.hidden_size
-        return (self.num_heads + 2 * self.kv_heads) * self.head_dim * h \
+        n = (self.num_heads + 2 * self.kv_heads) * self.head_dim * h \
             + self.num_heads * self.head_dim * h   # qkv (GQA) + out proj
+        if self.index_heads:
+            # the indexer: its queries, its one key (and that key's
+            # LayerNorm), its head weights
+            n += h * (self.index_heads * self.index_head_dim
+                      + self.index_head_dim + self.index_heads) \
+                + 2 * self.index_head_dim
+        return n
 
     def _mlp_params(self, width: Optional[int] = None) -> int:
         """One MLP of ``width`` (default: the model's, an expert's)."""
@@ -827,6 +857,21 @@ class Block(nn.Module):
                     q, k = rot(q), rot(k)
                 else:       # traced: a hybrid's layers share one scan body
                     q, k = jnp.where(rope, rot(q), q), jnp.where(rope, rot(k), k)
+            if cfg.index_heads:
+                # the indexer's four leaves are made here so that a model's
+                # tree holds them; the selection itself lives in the
+                # inference decoder (models/generation.decoder_forward)
+                Hi, Di = cfg.index_heads, cfg.index_head_dim
+                dense(Hi * Di, "index_q", bias=False)(h)
+                nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
+                             param_dtype=jnp.float32, name="index_k_norm")(
+                    dense(Di, "index_k", bias=False)(h))
+                dense(Hi, "index_w", bias=False)(h)
+                if not self.is_initializing():
+                    raise NotImplementedError(
+                        "index_heads: a learned indexer's selection is "
+                        "served (init_inference / generate) and not "
+                        "trained or run by Block: ROADMAP M7")
             if kv != nh:
                 # grouped-query: each k/v head serves nh/kv query heads
                 k = jnp.repeat(k, nh // kv, axis=1)

@@ -153,6 +153,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
                     k_scale=None,
                     v_scale=None,
                     q_start=None,
+                    select=None,
                     impl: str = "auto",
                     interpret: bool = False) -> jnp.ndarray:
     """Dispatching paged-attention entry point (the serving loop's reads).
@@ -169,17 +170,20 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
     TPU) run the exact jnp gather reference. int8 pools ride both paths via
     ``k_scale``/``v_scale`` (per-(layer, head, slot) f32, dequantized
     in-kernel / post-gather).
-    ``impl="reference"`` forces the oracle.
+    ``select`` (a layer with an indexer: ``ops.pallas.sparse_select.
+    Selection``): each query row attends its selected keys only, on both
+    paths. ``impl="reference"`` forces the oracle.
     """
     kw = dict(sm_scale=sm_scale, alibi_slopes=alibi_slopes, softcap=softcap,
               window=window, layer_idx=layer_idx, k_scale=k_scale,
-              v_scale=v_scale, q_start=q_start)
+              v_scale=v_scale, q_start=q_start, select=select)
     # the shape test comes BEFORE the call: whatever the kernel itself
     # raises (the chip's compiler refusing it, a pool without scales) is an
     # error, never a quiet route to the reference
     path, reason = paged_attention_path(
         q.shape, k_pool.shape, stacked=layer_idx is not None,
-        quant=k_scale is not None, impl=impl, interpret=interpret)
+        quant=k_scale is not None, impl=impl, interpret=interpret,
+        select=select is not None)
     if path == "kernel":
         from .pallas.paged_attention import paged_attention as _kernel
         return _kernel(q, k_pool, v_pool, block_tables, context_lens,
@@ -194,8 +198,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
 
 
 def paged_attention_path(q_shape, pool_shape, *, stacked: bool, quant: bool,
-                         impl: str = "auto", interpret: bool = False
-                         ) -> Tuple[str, Optional[str]]:
+                         impl: str = "auto", interpret: bool = False,
+                         select: bool = False) -> Tuple[str, Optional[str]]:
     """``("kernel", None)`` or ``("reference", why)`` for a
     :func:`paged_attention` call of these shapes, decided from them alone:
     ``why`` is the kernel's own ``untileable`` reason on a TPU or under
@@ -206,7 +210,7 @@ def paged_attention_path(q_shape, pool_shape, *, stacked: bool, quant: bool,
         return "reference", None
     from .pallas.paged_attention import untileable
     reason = untileable(q_shape, pool_shape, stacked=stacked, quant=quant,
-                        interpret=interpret)
+                        interpret=interpret, select=select)
     return ("kernel", None) if reason is None else ("reference", reason)
 
 

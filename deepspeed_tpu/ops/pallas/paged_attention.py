@@ -73,6 +73,20 @@ Capability slot of the reference's fused ``softmax_context`` decode kernels
 the vLLM-style paged layout; the multi-page double-buffered copy is shared
 in kind with jax's own ``pallas/ops/tpu/paged_attention`` kernel.
 
+**A learned indexer's selection** (PR 45, ``select=``; ``sparse_select.py``):
+a layer whose queries attend their top-k keys by an index score hands the
+kernel those scores for every key of the lane and, a query row, the value of
+its k-th best and the position up to which equal scores count. The scores
+ride in HBM as ``[lanes, groups, rows, P * block_size]``, a copy group's keys
+a row of whole 128-lane tiles (so a selecting call takes at least ``128 /
+block_size`` pages a group), copied beside the group's pages under a third
+semaphore; the threshold and tie position are a lane's VMEM block. A key
+the row did not select is masked beside the causal, context and window
+masks: the loop still walks every live page (with seeded weights the
+selected keys scatter over all of them; reading fewer is ROADMAP M7 (a)).
+Decode rows of one token share its selection; a row that selected
+everything it sees runs the dense arithmetic.
+
 In-kernel score features (parity with the flash kernel): ALiBi via
 per-head slopes, Gemma-2 tanh softcap, causal masking by per-sequence
 context length, and a sliding window. The jnp oracle
@@ -103,6 +117,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import NEG_INF
+from .sparse_select import selected as _selected
+from .sparse_select import untileable as _select_untileable
 
 __all__ = ["paged_attention", "paged_attention_reference", "scale_rows",
            "untileable"]
@@ -230,7 +246,7 @@ def _pages_per_group(hq: int, bs: int, hd: int, itemsize: int, nbk: int,
 
 
 def _attend(q, k, v, ks, vs, k0, ctx, window, slopes_ref, acc, m_scr, l_scr,
-            *, sm_scale, softcap, q0=None):
+            *, sm_scale, softcap, q0=None, sel=None):
     """One online-softmax update: the query tile ``q`` of ``hg`` stored
     heads against their keys ``k`` / values ``v`` [hg, n, hd] at logical
     positions ``[k0, k0 + n)``, folded into the running max, sum and output.
@@ -242,7 +258,13 @@ def _attend(q, k, v, ks, vs, k0, ctx, window, slopes_ref, acc, m_scr, l_scr,
     head, row r the query at ``q0 + r``: a tile a query head, so positions
     and masks are worked out once for all of them, and a matmul a query
     head against the stored head it shares (ONE matmul over a stored head's
-    ``gq x rows`` queries ran a third slower on the chip: PERF.md, PR 44)."""
+    ``gq x rows`` queries ran a third slower on the chip: PERF.md, PR 44).
+
+    ``sel`` (a layer with an indexer, ``sparse_select``): ``(scores [R, n],
+    thr [R, 1], tie [R, 1])`` of these n keys for the tile's query rows (R
+    1 for a decode token: its query heads share the token's selection); a
+    key that is not one of its row's top k is masked like one the causal
+    mask hides."""
     hg, heads = k.shape[0], q.shape[0]
     gq = heads // hg
 
@@ -302,6 +324,10 @@ def _attend(q, k, v, ks, vs, k0, ctx, window, slopes_ref, acc, m_scr, l_scr,
         # reads it)
         keep &= k_pos < ctx
     keep &= (q_abs - k_pos < window) | (window <= 0)        # sliding window
+    if sel is not None:
+        sc, thr, tie = sel
+        at = k0 + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+        s = s + jnp.where(_selected(sc, thr, tie, at), 0.0, NEG_INF)[None]
     s = jnp.where(keep, s, NEG_INF)
     m_prev = m_scr[:, :, :1]
     m_cur = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
@@ -360,12 +386,19 @@ def _grid_kernel(bt_ref, lens_ref, misc_ref, q_ref, k_ref, v_ref, *rest, bs,
 
 def _loop_kernel(bt_ref, lens_ref, misc_ref, q_ref, k_hbm, v_hbm, *rest, hg,
                  bs, P, nbk, sm_scale, softcap, has_alibi, stacked, quant,
-                 splits=1, chunk=False):
-    if quant:
-        (ks_hbm, vs_hbm, slopes_ref, o_ref, k_buf, v_buf, ks_buf, vs_buf,
-         acc, m_scr, l_scr, state, sem) = rest
-    else:
-        slopes_ref, o_ref, k_buf, v_buf, acc, m_scr, l_scr, state, sem = rest
+                 splits=1, chunk=False, select=False):
+    rest = list(rest)
+    ks_hbm, vs_hbm = (rest.pop(0), rest.pop(0)) if quant else (None, None)
+    slopes_ref = rest.pop(0)
+    # a layer with an indexer: the index scores of the lane's keys, a group's
+    # a row of tiles ``[groups, R, P * bs]`` copied beside its pages, and each
+    # query row's threshold and tie position (``sparse_select.selected``)
+    sel_hbm, thr_ref, tie_ref = (rest.pop(0), rest.pop(0), rest.pop(0)) \
+        if select else (None, None, None)
+    o_ref, k_buf, v_buf = rest.pop(0), rest.pop(0), rest.pop(0)
+    ks_buf, vs_buf = (rest.pop(0), rest.pop(0)) if quant else (None, None)
+    sel_buf = rest.pop(0) if select else None
+    acc, m_scr, l_scr, state, sem = rest
     b, g = pl.program_id(0), pl.program_id(1)
     nb, ng = pl.num_programs(0), pl.num_programs(1)
     window, layer = misc_ref[0], misc_ref[1]
@@ -412,6 +445,10 @@ def _loop_kernel(bt_ref, lens_ref, misc_ref, q_ref, k_hbm, v_hbm, *rest, hg,
         sites were most of its text (PERF.md, PR 37: ``setup_s``)."""
         heads = pl.ds(g * hg if splits == 1
                        else jax.lax.div(g, jnp.int32(splits)), hg)
+        if select:
+            i, slot = group[2], group[3]
+            getattr(pltpu.make_async_copy(sel_hbm.at[b, i], sel_buf.at[slot],
+                                          sem.at[2, slot]), act)()
         if not chunk:
             for live, copies in [page_copies(heads, b, *group, p)
                                  for p in range(P)]:
@@ -479,10 +516,12 @@ def _loop_kernel(bt_ref, lens_ref, misc_ref, q_ref, k_hbm, v_hbm, *rest, hg,
             ks, vs = (jnp.concatenate(
                 [buf[slot, :, p, :, :bs] for p in range(P)], -1)
                 for buf in (ks_buf, vs_buf))
+        sel = (sel_buf[slot], thr_ref[0][:, :1], tie_ref[0][:, :1]) \
+            if select else None
         _attend(q_ref[0, 0], k_buf[slot], v_buf[slot], ks, vs, i * (P * bs),
                 lens_ref[b], window, slopes_ref if has_alibi else None, acc, m_scr,
                 l_scr, sm_scale=sm_scale, softcap=softcap,
-                q0=misc_ref[2 + b] if chunk else None)
+                q0=misc_ref[2 + b] if chunk else None, sel=sel)
 
     jax.lax.fori_loop(g0, g1, group, None)
     state[0] = (slot0 + g1 - g0) % 2
@@ -500,7 +539,8 @@ def _stored_heads(nh: int, pool_shape, stacked: bool) -> int:
 
 
 def untileable(q_shape, pool_shape, *, stacked: bool, quant: bool,
-               interpret: bool = False) -> Optional[str]:
+               interpret: bool = False, select: bool = False
+               ) -> Optional[str]:
     """The kernel's tiling rules as a test made BEFORE the call: the reason
     these shapes cannot ride the kernel, or None when they can. Dispatchers
     route on this instead of catching the kernel's errors, so a refusal by
@@ -521,6 +561,11 @@ def untileable(q_shape, pool_shape, *, stacked: bool, quant: bool,
         return (f"head_dim {hd} is no multiple of 128 lanes: such a pool's "
                 "pages come through the pipeline a grid step each, for one "
                 f"query row a sequence (got T={T})")
+    if select and hd % 128 != 0:
+        return (f"head_dim {hd} is no multiple of 128 lanes: such a pool's "
+                "pages come through the pipeline, which carries no selection")
+    if select and _select_untileable(bs):     # whole pages a 128-lane row
+        return _select_untileable(bs)
     if T > _CHUNK_ROWS:              # whole tiles: the padded rows too
         return (f"{T} query rows a sequence are more than one program "
                 f"holds ({_CHUNK_ROWS}): chunk the prefill")
@@ -541,6 +586,7 @@ def paged_attention(q: jnp.ndarray,
                     k_scale=None,
                     v_scale=None,
                     q_start=None,
+                    select=None,
                     interpret: bool = False) -> jnp.ndarray:
     """T query tokens per sequence against a paged KV pool: a decode step's
     one, or a prefill chunk's T > 1, whose own keys the pool holds already.
@@ -575,6 +621,12 @@ def paged_attention(q: jnp.ndarray,
        means global. ``alibi_slopes``: [nh] per-head slopes (in-kernel
        bias slope * (k_pos - q_pos)). ``softcap``: Gemma-2 tanh cap
        (STATIC float — it changes the compiled math).
+    select: a layer with an indexer (``sparse_select.Selection``: the index
+       scores ``[B, T, Kp]`` of every key of the lane for every query row,
+       and each row's ``thr`` / ``tie`` ``[B, T]``): a row attends the keys
+       that ``sparse_select.selected`` keeps, beside the causal, context and
+       window masks. The kernel walks every live page and masks: the scores
+       of a group's keys are copied beside its pages.
 
     Returns [B, nh, T, hd]. Raises ValueError (the :func:`untileable`
     reason) when shapes can't tile — callers ask :func:`untileable` FIRST
@@ -585,7 +637,7 @@ def paged_attention(q: jnp.ndarray,
     stacked = layer_idx is not None
     quant = k_scale is not None
     reason = untileable(q.shape, k_pool.shape, stacked=stacked, quant=quant,
-                        interpret=interpret)
+                        interpret=interpret, select=select is not None)
     if reason is not None:
         raise ValueError(reason)
     if quant:
@@ -600,7 +652,7 @@ def paged_attention(q: jnp.ndarray,
     kw = dict(sm_scale=sm_scale, alibi_slopes=alibi_slopes,
               softcap=float(softcap) if softcap else 0.0, window=window,
               layer_idx=layer_idx, k_scale=k_scale, v_scale=v_scale,
-              interpret=interpret)
+              select=select, interpret=interpret)
     if T == 1:
         return _paged_attention(q, k_pool, v_pool, block_tables,
                                 context_lens, q_start=None, **kw)
@@ -619,7 +671,7 @@ def paged_attention(q: jnp.ndarray,
 
 def _paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
                      sm_scale, alibi_slopes, softcap, window, layer_idx,
-                     k_scale, v_scale, q_start, interpret):
+                     k_scale, v_scale, q_start, interpret, select=None):
     """:func:`paged_attention`, its shapes found tileable, on a query of
     whole tiles (one row, or a chunk's rows padded to :func:`_query_rows`)
     and scales in :func:`scale_rows`' layout."""
@@ -673,22 +725,31 @@ def _paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
     static = dict(bs=bs, nbk=nbk, sm_scale=scale, softcap=softcap,
                   has_alibi=alibi_slopes is not None, stacked=stacked,
                   quant=quant)
-    if hd % 128 == 0 or T > 1:
+    sel_ops, sel_specs = [], []
+    if hd % 128 == 0 or T > 1 or select is not None:
         # the pools stay in HBM: the kernel copies the pages a lane holds
         # (a chunk of narrower heads comes here under the interpreter only:
         # :func:`untileable`)
         P = _pages_per_group(hg * gq, bs, hd, k_pool.dtype.itemsize, nbk,
                              quant, T)
+        if select is not None:
+            # a group's keys fill whole 128-lane rows of the index scores
+            P = max(P, -(-128 // bs))
+            sel_ops, sel_specs = _selection_operands(select, B, T, rows,
+                                                     -(-nbk // P), P * bs)
         kernel = partial(_loop_kernel, hg=hg, P=P, splits=splits,
-                         chunk=T > 1, **static)
+                         chunk=T > 1, select=select is not None, **static)
         grid = (B, ng)
         kv_specs = [pl.BlockSpec(memory_space=pl.ANY)] * (4 if quant else 2)
         scratch = [pltpu.VMEM((2, hg, P * bs, hd), k_pool.dtype)] * 2
         if quant:
             scratch += [pltpu.VMEM((2, hg, P, 1, _scale_lanes(bs)),
                                    jnp.float32)] * 2
+        if select is not None:
+            scratch += [pltpu.VMEM((2,) + sel_ops[0].shape[2:], jnp.float32)]
         scratch += online + [pltpu.SMEM((2,), jnp.int32),
-                             pltpu.SemaphoreType.DMA((2, 2))]
+                             pltpu.SemaphoreType.DMA(
+                                 (3 if select is not None else 2, 2))]
     else:
         # narrow heads (:func:`_grid_kernel`): a page a grid step through
         # the pipeline, a dead step clamped to the sequence's last live
@@ -724,10 +785,11 @@ def _paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
         operands.append(jnp.zeros((1, 1, 128), jnp.float32))
         slopes_spec = pl.BlockSpec((1, 1, 128), lambda b, g, *_: (0, 0, 0))
 
+    operands += sel_ops
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3, grid=grid,
-        in_specs=[qo_spec] + kv_specs + [slopes_spec], out_specs=qo_spec,
-        scratch_shapes=scratch)
+        in_specs=[qo_spec] + kv_specs + [slopes_spec] + sel_specs,
+        out_specs=qo_spec, scratch_shapes=scratch)
     with jax.named_scope("paged_attention"):
         out = pl.pallas_call(
             kernel, grid_spec=grid_spec,
@@ -735,6 +797,30 @@ def _paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
             interpret=interpret,
         )(bt, lens, misc, *operands)
     return out[:, :, :, :group if T == 1 else T].reshape(B, nh, T, hd)
+
+
+def _selection_operands(select, B: int, T: int, rows: int, groups: int,
+                        n: int):
+    """``(operands, their specs)`` of a call's selection: the index scores as
+    ``[B, groups, R, n]`` (a copy group's ``n`` keys a row of tiles, R the
+    query rows: 1 for a decode token, a chunk's padded rows), left in HBM for
+    the kernel's own copies; ``thr`` and ``tie`` ``[B, R, 128]``, a row's on
+    every lane, a lane's block in VMEM. Rows and keys past the call's own
+    read ``-inf``: nothing of them is selected."""
+    R = 1 if T == 1 else rows
+    T, Kp = select.scores.shape[1:]     # the call's own rows, before padding
+    keys = min(Kp, groups * n)
+    sc = jnp.pad(select.scores[:, :, :keys].astype(jnp.float32),
+                 [(0, 0), (0, R - T), (0, groups * n - keys)],
+                 constant_values=-jnp.inf)
+    sc = sc.reshape(B, R, groups, n).transpose(0, 2, 1, 3)
+    on_lanes = lambda a, fill: jnp.broadcast_to(jnp.pad(
+        a, [(0, 0), (0, R - T)], constant_values=fill)[:, :, None],
+        (B, R, 128))
+    row_spec = pl.BlockSpec((1, R, 128), lambda b, g, *_: (b, 0, 0))
+    return ([sc, on_lanes(select.thr.astype(jnp.float32), jnp.inf),
+             on_lanes(select.tie.astype(jnp.int32), -1)],
+            [pl.BlockSpec(memory_space=pl.ANY), row_spec, row_spec])
 
 
 #: a chunk's call under ``jax.jit``: the prefill programs of a serving loop
@@ -757,7 +843,8 @@ def paged_attention_reference(q: jnp.ndarray,
                               layer_idx=None,
                               k_scale=None,
                               v_scale=None,
-                              q_start=None) -> jnp.ndarray:
+                              q_start=None,
+                              select=None) -> jnp.ndarray:
     """jnp oracle / CPU fallback: dense gather through the block table,
     then exactly the decode-path attention math (f32 scores, softcap
     before the ALiBi bias before the -1e30 masks, f32 softmax). Grouped as
@@ -839,6 +926,15 @@ def paged_attention_reference(q: jnp.ndarray,
         win = jnp.asarray(window, jnp.int32)
         keep = keep & ((q_abs[:, :, None] - k_pos[None, None, :] < win)
                        | (win <= 0))
+    if select is not None:
+        # a layer with an indexer: a row attends its top-k keys only (a
+        # query head's rows are its token's)
+        K = nbk * bs
+        sc = jnp.pad(select.scores[:, :, :K], [(0, 0), (0, 0), (0, max(
+            0, K - select.scores.shape[2]))], constant_values=-jnp.inf)
+        pick = _selected(sc, select.thr[..., None], select.tie[..., None],
+                         k_pos)                            # [B, T, K]
+        keep = keep & jnp.tile(pick, (1, group, 1))
     s = jnp.where(keep[:, None], s, NEG_INF)
     prob = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bhkd->bhqd", prob, v).reshape(B, nh, T, hd)

@@ -1,0 +1,402 @@
+"""Learned sparse attention's selection: an indexer's scores over a lane's
+keys and the exact top-k of each query row (ROADMAP M7, serving).
+
+A layer with an indexer (DeepSeek-Sparse-Attention's; ``TransformerConfig.
+index_heads``) lets query ``t`` attend the ``index_topk`` keys ``s <= t`` of
+largest index score::
+
+    I(t, s) = sum_j w(t, j) * relu(qI(t, j) . kI(s))        float32
+
+with ``qI [heads, width]`` and ``w [heads]`` from the query token and ONE
+indexer key ``kI [width]`` a token, cached beside K/V. Equal scores go to the
+lower position; a row with at most ``index_topk`` visible keys attends them
+all.
+
+Two kernels, each with its jnp twin (the CPU path and the kernel's oracle):
+
+* :func:`index_scores` (``sparse_index_scores``): the scores of every query
+  row against its lane's indexer keys, ``-inf`` where a key is not visible
+  (``s > t`` or past the lane's context). The kernel copies a tile's pages
+  out of the pool through the block table, as the paged kernel does (an
+  XLA gather had the chip's compiler keep the pool in another layout and
+  copy it whole every layer); a matmul a head over the tile, ReLU and the
+  head's weight folded in place: the ``[rows, heads, keys]`` products never
+  leave VMEM. Tiles wholly invisible copy and compute nothing. The operands are the model's dtype and the products accumulate in
+  float32: bf16 x bf16 products are exact in float32, so a bf16 model's
+  scores are what float32 at ``highest`` gives on the same rounded operands.
+* :func:`topk_threshold` (``sparse_topk``): per row the value of its k-th
+  largest score and the position up to which scores EQUAL to it are taken,
+  ``(thr, tie)``: key ``s`` is selected iff ``I > thr or (I == thr and s <=
+  tie)`` (:func:`selected`). Exact, by bisection: 32 passes over the float's
+  bits (as a sortable integer) find the k-th largest value, ``ceil(log2
+  keys)`` passes over the positions break its ties; every pass is a compare
+  and a count over a row tile that stays in VMEM. No sort, no index list:
+  the paged kernel applies ``(thr, tie)`` to the scores of the keys it holds
+  (``ops/pallas/paged_attention.py``), beside its causal, context and window
+  masks. A row tile none of whose rows sees more than k keys does no pass.
+
+:func:`selection_bits` packs a call's selection 32 keys a word, for a
+request that asked for its routing (``serving.engine``: ``submit(...,
+keep_routing=True)``); :func:`positions_of_bits` (a prompt chunk's rows, on
+the device) and :func:`bits_to_positions` (a decode call's row, on the host)
+are its inverse.
+"""
+
+from __future__ import annotations
+
+from functools import partial
+from typing import NamedTuple, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+__all__ = ["Selection", "index_scores", "index_scores_reference",
+           "topk_threshold", "topk_threshold_reference", "selected",
+           "select", "selection_bits", "bits_to_positions",
+           "positions_of_bits", "padded_keys",
+           "untileable"]
+
+_INT_MIN = -2 ** 31
+#: query rows a score program takes (a decode token: the sublane minimum)
+_SCORE_ROWS = 256
+#: rows a top-k program holds in VMEM with their sortable keys
+_TOPK_ROWS = 8
+
+
+class Selection(NamedTuple):
+    """Which keys each query row of a call attends: ``scores [B, T, Kp]``
+    float32 (``-inf`` where a key is not visible; ``Kp``:
+    :func:`padded_keys` of the lane's key capacity), and per row ``thr [B,
+    T]`` float32 / ``tie [B, T]`` int32 (:func:`selected`)."""
+    scores: jnp.ndarray
+    thr: jnp.ndarray
+    tie: jnp.ndarray
+
+
+def padded_keys(K: int) -> int:
+    """A lane's key capacity rounded up to whole 128-lane tiles."""
+    return -(-K // 128) * 128
+
+
+def _key_tile(Kp: int) -> int:
+    return max(t for t in (1024, 512, 256, 128) if Kp % t == 0)
+
+
+def selected(scores, thr, tie, k_pos):
+    """Key at position ``k_pos`` with index score ``scores`` is one of its
+    row's top k (operands broadcast against each other)."""
+    return (scores > thr) | ((scores == thr) & (k_pos <= tie))
+
+
+# ---------------------------------------------------------------- scores
+
+
+def _visible(q0, ctx, window, rows0, keys0, shape):
+    """Row r of a tile sees key c: causal, inside the lane's context, and
+    inside the layer's sliding window (``window <= 0``: none)."""
+    q_abs = q0 + rows0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    k_pos = keys0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return (k_pos <= q_abs) & (k_pos < ctx) \
+        & ((q_abs - k_pos < window) | (window <= 0))
+
+
+def _score_kernel(bt_ref, q0_ref, ctx_ref, misc_ref, q_ref, w_ref, pool_hbm,
+                  o_ref, k_buf, sem, *, heads, tr, tk, bs, nbk, precision):
+    b, it, ik = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    q0, ctx = q0_ref[b], ctx_ref[b]
+    rows0, keys0 = it * tr, ik * tk
+    # keys at or past ``seen`` are visible to no row of the tile: a tile
+    # that starts there computes nothing, and of a live tile only the pages
+    # below it are copied (what the buffer holds of the rest is masked)
+    seen = jnp.minimum(q0 + rows0 + tr, ctx)
+    live = keys0 < seen
+
+    @pl.when(live)
+    def _compute():
+        pages = jnp.minimum((seen - keys0 + bs - 1) // bs, tk // bs)
+
+        def copy(p):
+            phys = bt_ref[b, jnp.minimum(ik * (tk // bs) + p, nbk - 1)]
+            return pltpu.make_async_copy(
+                pool_hbm.at[misc_ref[0], 0, phys],
+                k_buf.at[pl.ds(pl.multiple_of(p * bs, bs), bs)], sem.at[0])
+
+        jax.lax.fori_loop(0, pages, lambda p, _: copy(p).start(), None)
+        jax.lax.fori_loop(0, pages, lambda p, _: copy(p).wait(), None)
+        k = k_buf[...]
+        w = w_ref[0]
+        acc = jnp.zeros((tr, tk), jnp.float32)
+        for j in range(heads):
+            s = jax.lax.dot_general(
+                q_ref[0, j], k, (((1,), (1,)), ((), ())),
+                precision=precision, preferred_element_type=jnp.float32)
+            acc = acc + w[:, j:j + 1] * jnp.maximum(s, 0.0)
+        o_ref[0] = jnp.where(
+            _visible(q0, ctx, misc_ref[1], rows0, keys0, (tr, tk)), acc,
+            -jnp.inf)
+
+    @pl.when(jnp.logical_not(live))
+    def _dead():
+        o_ref[0] = jnp.full((tr, tk), -jnp.inf, jnp.float32)
+
+
+def _precision(dtype):
+    # float32 operands: every pass; bf16 products are exact in float32
+    return (jax.lax.Precision.HIGHEST if dtype == jnp.float32
+            else jax.lax.Precision.DEFAULT)
+
+
+def index_scores(qi, w, ki_pool, block_tables, layer_idx, q_start,
+                 context_lens, *, window=None, interpret=False):
+    """``[B, T, Kp]`` float32 index scores, ``Kp`` = :func:`padded_keys` of
+    the table's ``nbk x block_size`` keys. ``qi [B, heads, T, lanes]`` the
+    indexer's queries (rotated, on the pool's lanes), ``w [B, T, heads]``
+    their head weights, ``ki_pool [L, 1, blocks, block_size, lanes]`` the
+    indexer-key pool where it lies (the kernel copies a lane's pages out of
+    it through ``block_tables [B, nbk]``, layer ``layer_idx``: no gathered
+    copy of a lane's keys exists, and the pool keeps the one layout it has
+    at the jit boundary), ``q_start [B]`` the position of row 0,
+    ``context_lens [B]`` the lane's valid keys, the call's own included,
+    ``window`` the layer's sliding window (None or <= 0: none): a key
+    outside it is not visible, so the selection is among the keys the
+    row's attention can see."""
+    B, H, T, D = qi.shape
+    bs, nbk = ki_pool.shape[3], block_tables.shape[1]
+    Kp = padded_keys(nbk * bs)
+    tr = _SCORE_ROWS if T > 8 else 8
+    Tp = -(-T // tr) * tr
+    tk = _key_tile(Kp)
+    if tk % bs:
+        raise ValueError(untileable(bs))
+    qi = jnp.pad(qi, [(0, 0), (0, 0), (0, Tp - T), (0, 0)])
+    w = jnp.pad(w.astype(jnp.float32), [(0, 0), (0, Tp - T), (0, 0)])
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4, grid=(B, Tp // tr, Kp // tk),
+        in_specs=[pl.BlockSpec((1, H, tr, D), lambda b, i, j, *_: (b, 0, i, 0)),
+                  pl.BlockSpec((1, tr, H), lambda b, i, j, *_: (b, i, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, tr, tk), lambda b, i, j, *_: (b, i, j)),
+        scratch_shapes=[pltpu.VMEM((tk, D), ki_pool.dtype),
+                        pltpu.SemaphoreType.DMA((1,))])
+    with jax.named_scope("sparse_index_scores"):
+        out = pl.pallas_call(
+            partial(_score_kernel, heads=H, tr=tr, tk=tk, bs=bs, nbk=nbk,
+                    precision=_precision(qi.dtype)),
+            grid_spec=grid_spec, name="sparse_index_scores",
+            out_shape=jax.ShapeDtypeStruct((B, Tp, Kp), jnp.float32),
+            interpret=interpret,
+        )(jnp.asarray(block_tables, jnp.int32),
+          jnp.asarray(q_start, jnp.int32).reshape(B),
+          jnp.asarray(context_lens, jnp.int32).reshape(B),
+          jnp.stack([jnp.asarray(layer_idx, jnp.int32).reshape(()),
+                     jnp.asarray(0 if window is None else window,
+                                 jnp.int32).reshape(())]), qi, w, ki_pool)
+    return out[:, :T]
+
+
+def untileable(block_size: int):
+    """Why a pool of these blocks cannot ride :func:`index_scores` (a tile
+    of keys is whole pages), or None."""
+    if 128 % block_size and block_size % 128:
+        return (f"block_size {block_size}: a tile of index scores is whole "
+                "pages (a power of two up to 128, or a multiple of 128)")
+    return None
+
+
+def index_scores_reference(qi, w, ki, q_start, context_lens, window=None):
+    """:func:`index_scores` in plain jnp (the CPU path, the kernel's
+    oracle) on a lane's keys ``ki [B, K, width]`` in logical order, gathered
+    by the caller."""
+    B, H, T, D = qi.shape
+    K = ki.shape[1]
+    s = jnp.einsum("bhtd,bkd->bthk", qi, ki.astype(qi.dtype),
+                   precision=_precision(qi.dtype),
+                   preferred_element_type=jnp.float32)
+    acc = jnp.zeros((B, T, K), jnp.float32)
+    for j in range(H):      # the kernel's order of accumulation
+        acc = acc + w[:, :, j, None].astype(jnp.float32) * jnp.maximum(
+            s[:, :, j], 0.0)
+    q_abs = jnp.asarray(q_start, jnp.int32).reshape(B, 1, 1) \
+        + jnp.arange(T)[None, :, None]
+    k_pos = jnp.arange(K)[None, None, :]
+    vis = (k_pos <= q_abs) & (k_pos < jnp.asarray(
+        context_lens, jnp.int32).reshape(B, 1, 1))
+    if window is not None:
+        win = jnp.asarray(window, jnp.int32)
+        vis = vis & ((q_abs - k_pos < win) | (win <= 0))
+    out = jnp.where(vis, acc, -jnp.inf)
+    return jnp.pad(out, [(0, 0), (0, 0), (0, padded_keys(K) - K)],
+                   constant_values=-jnp.inf)
+
+
+# ----------------------------------------------------------------- top-k
+
+
+def _sortable(bits):
+    """A float32's bits as an int32 that orders as the float does (its own
+    inverse)."""
+    return bits ^ ((bits >> 31) & 0x7FFFFFFF)
+
+
+def _topk_kernel(busy_ref, s_ref, thr_ref, tie_ref, key_ref, *, k, pos_bits):
+    rows, K = s_ref.shape
+    count = lambda m: jnp.sum(m.astype(jnp.float32), axis=1, keepdims=True)
+    kf = jnp.float32(k)
+    lanes = thr_ref.shape[1]
+    busy = busy_ref[pl.program_id(0)]
+
+    @pl.when(busy == 0)
+    def _all():
+        # no row of the tile sees more than k keys: every visible key is
+        # selected
+        thr_ref[...] = jnp.full((rows, lanes), -jnp.inf, jnp.float32)
+        tie_ref[...] = jnp.full((rows, lanes), K, jnp.int32)
+
+    @pl.when(busy != 0)
+    def _bisect():
+        # -0.0 (a negative weight times a zero) ranks as +0.0: the passes
+        # compare bit patterns, and equal scores are ties
+        s = s_ref[...]
+        key_ref[...] = _sortable(jax.lax.bitcast_convert_type(
+            jnp.where(s == 0.0, 0.0, s), jnp.int32))
+        zero = jnp.zeros((rows, 1), jnp.int32)
+
+        def value_bit(i, ans):
+            # ``ans``: the k-th largest key so far, as an UNSIGNED number
+            # (its sign bit flipped): the largest v with count(key >= v) >= k
+            cand = ans | jnp.left_shift(jnp.int32(1), 31 - i)
+            ok = count(key_ref[...] >= (cand ^ _INT_MIN)) >= kf
+            return jnp.where(ok, cand, ans)
+
+        kth = jax.lax.fori_loop(0, 32, value_bit, zero) ^ _INT_MIN
+        key = key_ref[...]
+        need = kf - count(key > kth)                 # >= 1 of the equal ones
+        pos = jax.lax.broadcasted_iota(jnp.int32, key.shape, 1)
+        # the equal keys' positions, K where a key is not equal
+        key_ref[...] = jnp.where(key == kth, pos, jnp.int32(2 ** pos_bits))
+
+        def pos_bit(i, p):
+            # the largest p with fewer than ``need`` equal keys below it:
+            # the position of the need-th
+            cand = p | jnp.left_shift(jnp.int32(1), pos_bits - 1 - i)
+            ok = count(key_ref[...] < cand) < need
+            return jnp.where(ok, cand, p)
+
+        tie = jax.lax.fori_loop(0, pos_bits, pos_bit, zero)
+        thr = jax.lax.bitcast_convert_type(_sortable(kth), jnp.float32)
+        thr_ref[...] = jnp.broadcast_to(thr, (rows, lanes))
+        tie_ref[...] = jnp.broadcast_to(tie, (rows, lanes))
+
+
+def topk_threshold(scores, k: int, seen=None, *, interpret=False
+                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``(thr [R] float32, tie [R] int32)`` of ``scores [R, Kp]`` (``Kp`` whole
+    128-lane tiles): row r's k-th largest score and the position up to which
+    scores equal to it are taken (lower positions first), so that
+    :func:`selected` keeps exactly k keys, or every key of a row of at most
+    k (``-inf`` entries counted: a caller masks what is not visible).
+    ``seen [R]``: the keys each row sees (more than ``-inf``), where the
+    caller knows them from the rows' positions; counted here otherwise. A
+    tile of rows none of which sees more than k does no pass."""
+    R, Kp = scores.shape
+    rows = _TOPK_ROWS
+    Rp = -(-R // rows) * rows
+    scores = jnp.pad(scores.astype(jnp.float32), [(0, Rp - R), (0, 0)],
+                     constant_values=-jnp.inf)
+    if seen is None:
+        seen = jnp.sum(scores[:R] > -jnp.inf, axis=1)
+    busy = jnp.max(jnp.pad(jnp.asarray(seen, jnp.int32).reshape(R),
+                           [(0, Rp - R)]).reshape(-1, rows), axis=1) > k
+    pos_bits = max(1, int(np.ceil(np.log2(Kp))))
+    with jax.named_scope("sparse_topk"):
+        thr, tie = pl.pallas_call(
+            partial(_topk_kernel, k=int(k), pos_bits=pos_bits),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(Rp // rows,),
+                in_specs=[pl.BlockSpec((rows, Kp), lambda r, *_: (r, 0))],
+                out_specs=[pl.BlockSpec((rows, 128),
+                                        lambda r, *_: (r, 0))] * 2,
+                scratch_shapes=[pltpu.VMEM((rows, Kp), jnp.int32)]),
+            name="sparse_topk",
+            out_shape=[jax.ShapeDtypeStruct((Rp, 128), jnp.float32),
+                       jax.ShapeDtypeStruct((Rp, 128), jnp.int32)],
+            interpret=interpret,
+        )(busy.astype(jnp.int32), scores)
+    return thr[:R, 0], tie[:R, 0]
+
+
+def topk_threshold_reference(scores, k: int):
+    """:func:`topk_threshold` by ``lax.top_k`` (equal elements: the lower
+    index first), the kernel's oracle and the CPU path."""
+    R, Kp = scores.shape
+    if Kp <= k:
+        return (jnp.full((R,), -jnp.inf, jnp.float32),
+                jnp.full((R,), Kp, jnp.int32))
+    # top_k ranks -0.0 under +0.0; as scores they are equal
+    scores = scores.astype(jnp.float32)
+    vals, idx = jax.lax.top_k(jnp.where(scores == 0.0, 0.0, scores), k)
+    thr = vals[:, -1]
+    tie = jnp.max(jnp.where(vals == thr[:, None], idx, -1), axis=1)
+    return thr, tie.astype(jnp.int32)
+
+
+def select(scores, k: int, seen=None, *, kernel: bool, interpret=False
+           ) -> Selection:
+    """The :class:`Selection` of ``scores [B, T, Kp]`` (``seen [B, T]``:
+    :func:`topk_threshold`)."""
+    B, T, Kp = scores.shape
+    flat = scores.reshape(B * T, Kp)
+    seen = None if seen is None else seen.reshape(B * T)
+    thr, tie = (topk_threshold(flat, k, seen, interpret=interpret) if kernel
+                else topk_threshold_reference(flat, k))
+    return Selection(scores, thr.reshape(B, T), tie.reshape(B, T))
+
+
+# ------------------------------------------------------------- hand-out
+
+
+def selection_bits(sel: Selection) -> jnp.ndarray:
+    """``[B, T, Kp // 32]`` int32: bit r of word w is set iff the row attends
+    the key at position ``32 w + r`` (selected AND visible)."""
+    B, T, Kp = sel.scores.shape
+    on = selected(sel.scores, sel.thr[..., None], sel.tie[..., None],
+                  jnp.arange(Kp)) & (sel.scores > -jnp.inf)
+    words = on.reshape(B, T, Kp // 32, 32).astype(jnp.uint32) \
+        << jnp.arange(32, dtype=jnp.uint32)
+    return jax.lax.bitcast_convert_type(jnp.sum(words, axis=-1,
+                                                dtype=jnp.uint32), jnp.int32)
+
+
+@partial(jax.jit, static_argnames="k")
+def positions_of_bits(bits, k: int):
+    """The inverse of :func:`selection_bits` on the device: ``bits [...,
+    words]`` int32 -> ``[..., k]`` int32, a row's selected positions in
+    rising order, -1 behind its own count (a sort of the set bits' positions:
+    a long prompt's chunks are hundreds of thousands of rows, a minute of the
+    host's time by :func:`bits_to_positions`)."""
+    K = bits.shape[-1] * 32
+    on = (bits[..., None] >> jnp.arange(32, dtype=jnp.int32)) & 1
+    rank = jnp.where(on.reshape(bits.shape[:-1] + (K,)) != 0,
+                     K - jnp.arange(K, dtype=jnp.int32), 0)
+    best = jax.lax.top_k(rank, k)[0]        # falling ranks: rising positions
+    return jnp.where(best > 0, K - best, -1)
+
+
+def bits_to_positions(bits: np.ndarray, k: int) -> np.ndarray:
+    """:func:`positions_of_bits` on the host, for a few rows: ``bits [...,
+    words]`` int32 -> ``[..., k]`` int32, a row's selected positions in
+    rising order, -1 behind its own count."""
+    bits = np.ascontiguousarray(bits, np.int32)
+    lead, words = bits.shape[:-1], bits.shape[-1]
+    flat = bits.reshape(-1, words)
+    out = np.full((flat.shape[0], k), -1, np.int32)
+    for r0 in range(0, flat.shape[0], 512):     # 13 MB of bits at a time
+        on = np.unpackbits(flat[r0:r0 + 512].view(np.uint8), axis=1,
+                           bitorder="little")
+        r, c = np.nonzero(on)
+        counts = on.sum(axis=1, dtype=np.int64)
+        out[r0 + r, np.arange(r.size) - (np.cumsum(counts) - counts)[r]] = c
+    return out.reshape(lead + (k,))

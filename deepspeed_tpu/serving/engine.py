@@ -45,6 +45,7 @@ from ..runtime.heartbeat import PHASE_SERVE
 from ..testing import chaos
 from ..utils import telemetry
 from ..utils.logging import log_dist, logger
+from ..ops.pallas.sparse_select import bits_to_positions, positions_of_bits
 from .kv_cache import (NULL_BLOCK, BlockPoolExhausted, SharedPagedState)
 from .model_runner import attention_impl, paged_forward
 from .scheduler import (BATCH, FAILED, FINISHED, PREFILL, PRIORITY_TIERS,
@@ -87,6 +88,15 @@ _COUNTERS = (
 #: ``moe.assignments`` the share's load, 1/8 for an eighth under even routing
 _MOE_COUNTERS = ("moe.assignments", "moe.held_assignments", "moe.layer_steps",
                  "moe.load_max_over_mean_sum", "moe.experts_idle_sum")
+#: a model with an indexer (``cfg.index_heads``), counted on the host from
+#: the positions of a call's rows, summed over the layers: query rows (a
+#: decode lane's token, a chunk's tokens), the keys the indexer scored for
+#: them (every key a row sees), the keys it selected (all of them up to
+#: ``index_topk``), and the pages the paged kernel walked to attend those
+#: (every live page: it masks, it does not skip; beside
+#: ``paged.live_pages_sum``, which counts a call's pages once)
+_SPARSE_COUNTERS = ("sparse.rows_sum", "sparse.keys_scored_sum",
+                    "sparse.keys_selected_sum", "sparse.pages_walked_sum")
 
 _KV_DTYPES = {"bf16": jnp.bfloat16, "bfloat16": jnp.bfloat16,
               "f32": jnp.float32, "float32": jnp.float32,
@@ -547,6 +557,8 @@ class ServingEngine:
         self._moe_pending: List[Any] = []
         if cfg.moe_is_dropless:
             self.stats.update(dict.fromkeys(_MOE_COUNTERS, 0))
+        if cfg.index_heads:
+            self.stats.update(dict.fromkeys(_SPARSE_COUNTERS, 0))
         # the paged-KV state: PRIVATE by default, SHARED when a
         # disaggregated pair (serving/disagg.py) passes one in — block
         # IDs then mean the same pool slots to both roles, which is what
@@ -555,7 +567,8 @@ class ServingEngine:
             cfg, serving, dtype=kv_dtype, counters=self.stats)
         # what a token costs the pool, from the pool itself (a grouped-query
         # model's is stored at its KV heads): 2 x layers x stored heads x
-        # head_dim x item size, the int8 tier's scales with it
+        # head_dim x item size, the int8 tier's scales and an indexer's one
+        # key a layer (the ``ki`` leaf) with it
         pool_k = self.pools["k"]
         self.rec.gauge("kv.stored_heads", int(pool_k.shape[1]))
         self.rec.gauge("kv.bytes_per_token", sum(
@@ -613,6 +626,9 @@ class ServingEngine:
         # step, so the update is in-place on TPU (no 2x pool HBM)
         self._decode_fn = jax.jit(_decode, donate_argnums=(1,))
         self._prefill_fn = jax.jit(_prefill, donate_argnums=(1,))
+        # a third, for a request that asked for its routing of a model with
+        # an indexer: a prompt chunk's selection from bits to positions
+        self._positions_fn = positions_of_bits
         # the names a device trace's "XLA Modules" line gives their runs:
         # a dispatch span carries its program's, to be paired with the run
         self._decode_program = "jit_" + _decode.__name__
@@ -621,7 +637,10 @@ class ServingEngine:
             f"ServingEngine: pool={serving.pool_blocks}x{bs} tokens "
             f"(~{(serving.pool_blocks - 1) * bs} cacheable, "
             f"{self.rec.gauges['kv.stored_heads']} stored heads, "
-            f"{self.rec.gauges['kv.bytes_per_token']} bytes a token), "
+            f"{self.rec.gauges['kv.bytes_per_token']} bytes a token"
+            + (f", an indexer key of {cfg.index_head_dim} on "
+               f"{self.pools['ki'].shape[-1]} lanes a layer among them"
+               if cfg.index_heads else "") + "), "
             f"max_batch={self.max_batch}, max_model_len="
             f"{self.max_model_len}, prefix_cache={serving.prefix_cache}, "
             f"prefill_chunk={self._chunk or 'whole'}",
@@ -671,6 +690,20 @@ class ServingEngine:
             # the call's picks stay on the device, for a request that asked
             out, self._picks_out = out
         return out
+
+    def _count_selection(self, seen: np.ndarray, pages: int) -> None:
+        """A model with an indexer: a call's query rows, each seeing
+        ``seen`` keys (its own included), and the ``pages`` its attention
+        walks, in every layer (:data:`_SPARSE_COUNTERS`)."""
+        cfg = self.cfg
+        if not cfg.index_heads:
+            return
+        L, c = cfg.num_layers, self.stats
+        c["sparse.rows_sum"] += L * int(seen.size)
+        c["sparse.keys_scored_sum"] += L * int(seen.sum())
+        c["sparse.keys_selected_sum"] += L * int(
+            np.minimum(seen, cfg.index_topk).sum())
+        c["sparse.pages_walked_sum"] += L * int(pages)
 
     def _count_experts(self, out: np.ndarray, call: int) -> None:
         """A dropless MoE model's router load, from the fetched output of
@@ -725,10 +758,17 @@ class ServingEngine:
         token the model was FED (the prompt, then every generated token but
         the last) the experts each mixture layer picked, best first. A
         prompt position whose K/V came from the prefix cache was never
-        computed and reads -1. Costs one small fetch a device call that
-        carries the request (``routing.fetches``); a request that does not
-        ask costs none. Not carried through a fleet's requeue or a disagg
-        handoff.
+        computed and reads -1. For a model with an indexer
+        (``cfg.index_heads``) the array is ``[..., layers, k + index_topk]``:
+        a token's routing, its experts and then the positions of the keys it
+        attended in that layer, rising, -1 behind the row's own count (a row
+        that sees no more than ``index_topk`` keys lists them all). The
+        device hands the selection out as bits, 32 keys a word, beside the
+        picks of every call; the positions are made from them when the
+        request finishes (a prompt chunk's on the device, by a sort). Costs
+        one small fetch a device call that carries the request
+        (``routing.fetches``); a request that does not ask costs none. Not
+        carried through a fleet's requeue or a disagg handoff.
 
         ``top_k``/``top_p`` (round 12) require
         ``serving.sampling_filters`` — the vectorized per-lane filter
@@ -1194,6 +1234,8 @@ class ServingEngine:
         self.rec.count("paged.chunk_live_pages_sum",
                        -(-(q0 + n) // self.block_size))
         self.rec.count("paged.chunk_table_pages_sum", self.nbk)
+        self._count_selection(q0 + 1 + np.arange(n),
+                              -(-(q0 + n) // self.block_size))
         if Tb not in self._prefill_shapes:
             self._note_prefill_path(Tb)
         buf = np.zeros((self._layout.prefill_words(Tb),), np.int32)
@@ -1439,6 +1481,8 @@ class ServingEngine:
             rec.count("paged.live_pages_sum",
                       int((lanes.ctx * go // self.block_size + 1).sum()))
             rec.count("paged.table_pages_sum", B * self.nbk)
+            self._count_selection(lanes.ctx[go] + 1, int(
+                (lanes.ctx[go] // self.block_size + 1).sum()))
             if self._windows.size:
                 # of those, what the window layers' calls walk, summed over
                 # those layers: from the page of a lane's first key in reach
@@ -1505,19 +1549,32 @@ class ServingEngine:
         """``req.routed_experts`` from what its calls left: a prompt chunk's
         picks still on the device (every call before the one whose tokens
         ended the request has run), a decode call's row as it was booked."""
-        rows = np.full((len(req.prompt), self.cfg.sparse_layers,
-                        self.cfg.moe_k), -1, np.int32)
+        cfg = self.cfg
+        k, topk = cfg.moe_k, cfg.index_topk
+
+        def handed(picks, on_device: bool):
+            """A call's picks as they are handed out: behind a row's experts
+            the POSITIONS of the keys it attended, from the device's bits
+            (a chunk's rows turned on the device, a decode row here)."""
+            if not cfg.index_heads:
+                return np.asarray(picks)
+            keys = np.asarray(self._positions_fn(picks[..., k:], topk)) \
+                if on_device else bits_to_positions(picks[..., k:], topk)
+            return np.concatenate([np.asarray(picks[..., :k]), keys], axis=-1)
+
+        rows = np.full((len(req.prompt), cfg.sparse_layers, k + topk), -1,
+                       np.int32)
         fed = []
         for part in req._routing:
             if isinstance(part, tuple):
                 q0, n, picks = part
-                rows[q0:q0 + n] = np.asarray(picks)[:, :n].transpose(1, 0, 2)
+                rows[q0:q0 + n] = handed(picks, True)[:, :n].transpose(1, 0, 2)
                 self.stats["routing.fetches"] = \
                     self.stats.get("routing.fetches", 0) + 1
             else:
                 fed.append(part)
-        req.routed_experts = np.concatenate([rows, np.stack(fed)]) \
-            if fed else rows
+        req.routed_experts = np.concatenate(
+            [rows, handed(np.stack(fed), False)]) if fed else rows
         req._routing = []
 
     def _finish(self, seq: _Seq) -> None:

@@ -57,7 +57,20 @@ def init_pool(cfg, num_blocks: int, block_size: int,
     ``kvh = cfg.kv_heads``: K and V are stored at the model's KV heads (a
     grouped-query model's ``num_heads // kv_heads`` query heads read one
     stored head; the paged kernel makes them rows of one tile), so a token
-    takes ``2 x L x kvh x hd x item size`` bytes of the pool.
+    takes ``2 x L x kvh x hd x item size`` bytes of the pool. A model with an
+    indexer (``cfg.index_heads``) has a third leaf, ``ki`` ``[L, 1,
+    num_blocks*block_size, index_head_dim rounded up to 128]`` in the
+    model's dtype: the indexer's one key a token and layer, no heads, on the
+    first ``index_head_dim`` lanes of a row of whole 128-lane tiles (the
+    rest zeros), so ``L x 128 x item size`` bytes more a token for a 64-wide
+    key. The chip pads a narrower row to 128 lanes in HBM anyway (ROADMAP
+    S12 (b)) and then wants the whole pool in another layout for every
+    layer's write and gather (a 64-wide leaf was copied whole several times
+    a step, ``tests/test_chip_compile.py``); stored at the padded width the
+    leaf is laid out as K and V are, written by the same in-place updates,
+    and the zeros add nothing to a score. It lives in the SAME blocks as
+    K/V, slot for slot: block tables, ``fork``, the prefix cache and a
+    preemption's release carry it with no allocator of its own.
 
     Flat slot layout (slot = block * block_size + offset), row-major: the
     paged forward and the paged-attention kernel both view the same buffer
@@ -79,12 +92,17 @@ def init_pool(cfg, num_blocks: int, block_size: int,
     dtype = dtype or cfg.dtype
     shape = (cfg.num_layers, cfg.kv_heads, num_blocks * block_size,
              cfg.head_dim)
+    index = ({"ki": jnp.zeros((shape[0], 1, shape[2],
+                               -(-cfg.index_head_dim // 128) * 128),
+                              cfg.dtype)} if cfg.index_heads else {})
     if dtype == jnp.int8:
         return {"k": jnp.zeros(shape, jnp.int8),
                 "v": jnp.zeros(shape, jnp.int8),
                 "k_scale": jnp.zeros(shape[:-1] + (1,), jnp.float32),
-                "v_scale": jnp.zeros(shape[:-1] + (1,), jnp.float32)}
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+                "v_scale": jnp.zeros(shape[:-1] + (1,), jnp.float32),
+                **index}
+    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
+            **index}
 
 
 class BlockPool:
@@ -212,7 +230,14 @@ class PrefixCache:
                     "prefix.evicted_entries",
                     "prefix.evict_scanned_entries"):
             self.counters.setdefault(key, 0)
-        # key -> (tokens ref, n_blocks, blocks, last_used)
+        # key -> (tokens ref, n_blocks, blocks, last_used), KEPT IN ORDER
+        # OF LAST USE (a dict keeps insertion order; a touch re-inserts):
+        # the least recently used entry is the first, so an eviction reads
+        # one entry. (Until PR 45 each eviction took the minimum over every
+        # entry: a long prompt makes an entry a block and frees its blocks
+        # with its LAST entry, so admitting an 8k-token prompt into a full
+        # pool evicted ~256 entries at ~8 000 reads each: 86 million reads a
+        # run of the Keye cell, seconds of the host's time inside steps.)
         self._entries: Dict[str, Tuple[Tuple[int, ...], int, List[int],
                                        int]] = {}
         self._clock = 0
@@ -263,10 +288,14 @@ class PrefixCache:
             n, key, blocks = self._lookup(tokens)
             if key is None:
                 return 0, []
-            self._clock += 1
-            ent = self._entries[key]
-            self._entries[key] = (ent[0], ent[1], ent[2], self._clock)
+            self._touch(key)
             return n, self.pool.fork(blocks)
+
+    def _touch(self, key: str) -> None:
+        """Entry ``key`` was used now: the last to be evicted."""
+        self._clock += 1
+        ent = self._entries.pop(key)
+        self._entries[key] = (ent[0], ent[1], ent[2], self._clock)
 
     def insert(self, tokens: Sequence[int], blocks: Sequence[int]) -> None:
         """Register every full-block prefix of a prefilled prompt. The
@@ -281,13 +310,14 @@ class PrefixCache:
         with self._mu:
             for k in range(1, nfull + 1):
                 key = keys[k - 1]
-                self._clock += 1
                 ent = self._entries.get(key)
                 if ent is not None and ent[1] == k \
                         and ent[0][:k * bs] == shared[:k * bs]:
-                    self._entries[key] = (ent[0], ent[1], ent[2],
-                                          self._clock)
+                    self._touch(key)
                     continue
+                self._clock += 1
+                if ent is not None:             # a collision's entry goes
+                    self.pool.release(self._entries.pop(key)[2])
                 held = self.pool.fork(list(blocks[:k]))
                 self._entries[key] = (shared, k, held, self._clock)
                 self.counters["prefix.inserted_entries"] += 1
@@ -304,12 +334,18 @@ class PrefixCache:
         evicted = 0
         with self._mu:
             while self.pool.free_count < need_blocks:
-                victims = [k for k in self._entries if k != protect]
-                if not victims:
+                # the first entry that is not the protected one: the least
+                # recently used (the entries lie in that order)
+                key, read = None, 0
+                for key in self._entries:
+                    read += 1
+                    if key != protect:
+                        break
+                else:
+                    key = None
+                self.counters["prefix.evict_scanned_entries"] += read
+                if key is None:
                     break
-                self.counters["prefix.evict_scanned_entries"] += \
-                    len(victims)
-                key = min(victims, key=lambda k: self._entries[k][3])
                 _, _, blocks, _ = self._entries.pop(key)
                 self.pool.release(blocks)
                 evicted += 1

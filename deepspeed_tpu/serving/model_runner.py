@@ -40,6 +40,7 @@ from ..models.generation import attention_constants, decoder_forward
 from ..models.transformer import TransformerConfig
 from ..ops.attention import paged_attention
 from ..ops.pallas.paged_attention import scale_rows
+from ..ops.pallas import sparse_select
 from .kv_cache import NULL_BLOCK
 
 PyTree = Any
@@ -113,7 +114,12 @@ class PagedCache:
     layer hands ``write`` K/V at the model's KV heads and ``attend`` the
     queries at all its heads: the kernel reads one stored head for the
     ``num_heads // kv_heads`` query heads that share it. Everything here
-    follows the pool's own shape. Built and used inside one trace."""
+    follows the pool's own shape. A model with an indexer has a third leaf,
+    ``ki`` ``[L, 1, slots, 128s]``: the indexer's one key a token on the
+    first lanes of whole 128-lane tiles (``init_pool`` says why), in the
+    same blocks through the same tables and written by the same plan, so whatever carries a block (a prefix-cache hit, a fork, a
+    preemption's release) carries its indexer keys. Built and used inside
+    one trace."""
 
     def __init__(self, cfg: TransformerConfig, pools: Dict[str, jnp.ndarray],
                  block_tables, q_start, context_lens, block_size: int,
@@ -180,9 +186,10 @@ class PagedCache:
         # here, at the loop's boundary (a block's scales on the first lanes
         # of a row of whole 128-lane tiles: the least a kernel may copy), not
         # twice a layer inside it
+        blocked = lambda pool: pool.reshape(
+            pool.shape[:2] + self.blocked_shape[2:4] + pool.shape[3:])
         return {name: (scale_rows(pool, self.blocked_shape)
-                       if name.endswith("_scale")
-                       else pool.reshape(self.blocked_shape))
+                       if name.endswith("_scale") else blocked(pool))
                 for name, pool in self.pools.items()}
 
     def finish(self, carry, T: int):
@@ -206,7 +213,49 @@ class PagedCache:
                              v.astype(kv["v"].dtype)[:, :, None], 3, plan)
         return new
 
-    def attend(self, kv, li, q, k, v, window):
+    def write_index(self, kv, li, ki):
+        """The indexer's keys ``[B, 1, T, width]`` into layer ``li`` of the
+        ``ki`` pool: one more head-less row a token under the K/V plan,
+        zeros on the lanes past its width."""
+        lanes = kv["ki"].shape[-1]
+        ki = jnp.pad(ki.astype(kv["ki"].dtype),
+                     [(0, 0)] * 3 + [(0, lanes - ki.shape[-1])])
+        return {**kv, "ki": _write_kv(kv["ki"], li, ki[:, :, None], 3,
+                                      self.write_plan)}
+
+    def select(self, kv, li, qi, wi, window):
+        """Which of its lane's keys each row attends: the indexer's scores
+        over the lane's cached indexer keys (read through the block table;
+        the call's own are written already; a key outside the layer's
+        ``window`` is not a candidate), then each row's exact top
+        ``cfg.index_topk`` (``ops/pallas/sparse_select.py``: the kernels on
+        a TPU or under ``interpret``, their jnp twins elsewhere)."""
+        B, T = qi.shape[0], qi.shape[2]
+        L, _, nb, bs, width = kv["ki"].shape
+        kernel = self.impl != "reference" and (
+            jax.default_backend() == "tpu" or bool(self.interpret)) \
+            and sparse_select.untileable(bs) is None
+        # the queries on the pool's lanes: zeros against the keys' zeros
+        qi = jnp.pad(qi, [(0, 0)] * 3 + [(0, width - qi.shape[-1])])
+        with jax.named_scope("index"):
+            if kernel:
+                scores = sparse_select.index_scores(
+                    qi, wi, kv["ki"], self.bt, li, self.q_start, self.ctx,
+                    window=window, interpret=self.interpret)
+            else:
+                keys = kv["ki"].reshape(L * nb, bs, width)[li * nb + self.bt]
+                scores = sparse_select.index_scores_reference(
+                    qi, wi, keys.reshape(B, -1, width), self.q_start,
+                    self.ctx, window)
+        with jax.named_scope("select"):
+            # the keys a row sees, from where it stands
+            seen = jnp.minimum(self.positions(T) + 1, self.ctx[:, None])
+            seen = jnp.where(window > 0, jnp.minimum(seen, window), seen)
+            return sparse_select.select(
+                scores, self.cfg.index_topk, seen, kernel=kernel,
+                interpret=self.interpret)
+
+    def attend(self, kv, li, q, k, v, window, select=None):
         # attention through the block table (the kernel on a TPU, the exact
         # jnp gather elsewhere); the int8 tier passes the pool AS int8 with
         # its scales — dequant happens in-kernel / post-gather, O(attended
@@ -218,8 +267,8 @@ class PagedCache:
                                alibi_slopes=self.slopes,
                                softcap=self.cfg.attn_softcap, window=window,
                                layer_idx=li, q_start=self.q_start,
-                               impl=self.impl, interpret=self.interpret,
-                               **scale_kw)
+                               select=select, impl=self.impl,
+                               interpret=self.interpret, **scale_kw)
 
 
 def paged_forward(cfg: TransformerConfig,
@@ -246,7 +295,9 @@ def paged_forward(cfg: TransformerConfig,
 
     input_ids: [B, T]. pools: {"k","v"} [L, kv_heads, num_slots, hd]
     (``serving.kv_cache.init_pool`` layout; ``num_slots`` = pool blocks x
-    ``block_size``; a token's bytes = 2 x L x kv_heads x hd x item size).
+    ``block_size``; a token's bytes = 2 x L x kv_heads x hd x item size, and
+    L x 128 x item size more for a model with an indexer of up to 128 lanes:
+    its ``ki`` leaf ``[L, 1, num_slots, 128]``).
     block_tables: [B, max_blocks_per_seq] i32 — logical
     block j of lane b is physical pool block ``block_tables[b, j]``.
     q_start: [B] i32 — first query's logical position (tokens already in

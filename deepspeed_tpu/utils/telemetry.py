@@ -298,7 +298,8 @@ def span_times(entries: List[Entry], since_ns: int = 0
 #: layer boundaries only (``models/transformer.py``, ``serving/
 #: model_runner.py``, the step functions of ``runtime/engine.py``)
 SCOPES = frozenset({
-    "embed", "layers", "block.attn", "qkv", "kv_write", "attend", "out",
+    "embed", "layers", "block.attn", "qkv", "kv_write", "index",
+    "index_write", "select", "attend", "out",
     "block.mlp", "route", "dispatch", "experts", "combine", "shared",
     "head", "loss", "grad_reduce", "sample", "grad_accum", "optimizer",
     "zero.gather", "zero.scatter"})

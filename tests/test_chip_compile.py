@@ -559,6 +559,77 @@ def test_k_exaone_serving_step_holds_a_share_in_place(chip, monkeypatch,
                                  mem.temp_size_in_bytes)
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill256"])
+def test_keye_serving_step_selects_keys_in_place(chip, monkeypatch, program):
+    """The serving loop's two programs at the Keye-VL-2.0 cell's widths,
+    read from the benchmark's own files (keye-vl2-30b-ep8-l8: hidden 2048,
+    32 query heads over 4 stored, a 16 x 64 indexer with top-k 2048, 16 of
+    128 experts of 768 held; 16 lanes over an 8192 x 32 pool, tables of 800
+    blocks): the layer body holds the indexer's two kernels, the paged
+    kernel and the held experts' three grouped matmuls; the pool's third
+    leaf (the indexer's keys) is updated in place with K and V; the picks
+    carry the selection as bits behind the experts; and weights, pool and
+    temporaries fit the chip."""
+    from benchmark import harness
+    from deepspeed_tpu.models import TransformerConfig, build_model
+    from deepspeed_tpu.serving.engine import (StepLayout, step_programs,
+                                              token_words)
+    from deepspeed_tpu.serving.kv_cache import init_pool
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cell = harness.load_cell("serve-keye-vl2-30b-ep8-l8-longdoc")
+    serving = cell.system["serving"]
+    BS, NB, B, NBK = (serving[k] for k in (
+        "block_size", "pool_blocks", "max_batch", "max_blocks_per_seq"))
+    model, cfg = build_model(TransformerConfig(
+        **harness.load_family("keye_vl2").model_kwargs(cell.config),
+        dtype=jnp.bfloat16))
+    L, E, K = cfg.num_layers, cfg.moe_experts, cfg.moe_k
+    assert (L, E, cfg.moe_held_count, cfg.hidden_size, cfg.mlp_dim,
+            cfg.num_heads, cfg.kv_heads, cfg.index_heads, cfg.index_head_dim,
+            cfg.index_topk) == (8, 128, 16, 2048, 768, 32, 4, 16, 64, 2048)
+    on_chip = lambda tree: jax.tree.map(lambda x: chip(x.shape, x.dtype), tree)
+    params = on_chip(jax.eval_shape(lambda: jax.tree.map(
+        lambda x: x.astype(jnp.bfloat16), model.init(
+            jax.random.PRNGKey(0),
+            {"input_ids": jnp.zeros((1, 8), jnp.int32)})["params"])))
+    assert params["blocks"]["index_q"]["kernel"].shape == (L, 2048, 16 * 64)
+    pools = on_chip(jax.eval_shape(lambda: init_pool(cfg, NB, BS,
+                                                     jnp.bfloat16)))
+    assert pools["ki"].shape == (L, 1, NB * BS, 128)    # 64 on 128 lanes
+    _prefill_rides_the_kernel(cfg, pools, BS)
+    rows = B if program == "decode" else 256
+    decode, prefill = step_programs(cfg, BS, NBK)
+    if program == "decode":
+        fn, words = decode, StepLayout(NBK).decode_words(B)
+        fed = [chip((token_words(cfg, n),), jnp.int32) for n in (B, 1)]
+    else:
+        fn, words, fed = prefill, StepLayout(NBK).prefill_words(256), []
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, pools, chip((words,), jnp.int32), *fed).compile()
+    text = compiled.as_text()
+    (out, picks), _ = compiled.out_info
+    assert out.shape == ((B if program == "decode" else 1) + L * E,)
+    assert picks.shape == (L, rows, K + NBK * BS // 32)
+    kernels = _kernel_scopes(text)
+    for name, n in (("jit(gmm)", 3), ("paged_attention", 1),
+                    ("sparse_index_scores", 1), ("sparse_topk", 1)):
+        assert len([k for k in kernels if name in k]) == n, (name, kernels)
+    assert len(kernels) == 6, kernels
+    made = [r for r in _results(text) if r[1] not in (
+        "parameter", "get-tuple-element", "while", "tuple", "bitcast")]
+    layer = cfg.kv_heads * NB * BS * cfg.head_dim
+    moved = [r for r in made if r[3] >= layer and (
+        r[1] in ("copy", "transpose", "scatter")
+        or r[1] == "dynamic-slice" and r[3] == layer)]
+    assert not moved, moved
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(
+        p.size * p.dtype.itemsize for p in pools.values())
+    held_bytes = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 6.0e9 < held_bytes < 12.0e9, (mem.argument_size_in_bytes,
+                                         mem.temp_size_in_bytes)
+
+
 @pytest.mark.parametrize("layout,vocab", [("dp4", 50257), ("dp2_tp2", 50304)])
 def test_zero3_step_reduces_the_head_gradient_once_behind_the_loss_loop(
         topo, chip, monkeypatch, layout, vocab):

@@ -75,6 +75,14 @@ FEATURES = {
         dense_mlp_dim=80, moe_experts=16, moe_k=4, moe_held=(4, 2),
         moe_dropless=True, moe_scores="sigmoid", moe_select_bias=True,
         moe_routed_scale=2.5, moe_shared_dim=24),
+    # Keye-VL-2.0's attention: a learned indexer picks each query's 6 keys
+    # (DeepSeek-Sparse-Attention's index score), alone and beside a window
+    "indexer_selects_keys": dict(**_ROTARY, num_kv_heads=2, qk_norm=True,
+                                 index_heads=2, index_head_dim=8,
+                                 index_topk=6),
+    "indexer_beside_a_window": dict(**_ROTARY, layer_windows=(0, 9),
+                                    index_heads=3, index_head_dim=4,
+                                    index_topk=5),
 }
 
 

@@ -140,18 +140,44 @@ def test_the_bias_selects_and_never_weighs(tiny):
         w, 2.5 * at / at.sum(-1, keepdims=True), rtol=1e-6)
 
 
-def test_the_shares_add_up_to_the_uncut_layer():
-    """Eight chips' routed parts, with the shared expert (which every chip
-    computes alike) counted once, are the uncut reference's mixture."""
-    _, cfg_all, params = built(UNCUT)
+def _keye_uncut():
+    """Keye-VL-2.0's mixture (softmax top-4 renormalised, no shared expert)
+    at the sizes of ``tests/test_keye_vl2.py``, uncut: 16 experts held."""
+    from test_keye_vl2 import TINY as cut
+    return harness.load_family("keye_vl2"), dict(
+        {k: v for k, v in cut.items() if k != "deployment"},
+        num_experts=16, num_local_experts=16), cut
+
+
+@pytest.mark.parametrize("family", ["exaone_moe", "keye_vl2"])
+def test_the_shares_add_up_to_the_uncut_layer(family):
+    """Eight chips' routed parts, with what every chip computes alike (a
+    shared expert, where the family has one) counted once, are the uncut
+    reference's mixture."""
+    if family == "exaone_moe":
+        fam, uncut, cut = FAM, UNCUT, TINY
+        mixture = lambda moe, h, **kw: fam.reference_moe(
+            moe, h, k=4, renorm=True, scale=2.5, **kw)[0]
+        routed_only = dict(shared=False)
+    else:
+        fam, uncut, cut = _keye_uncut()
+        mixture = lambda moe, h, **kw: fam.held_mixture(
+            moe, h, k=4, renorm=True, **kw)[0]
+        routed_only = {}
+    if family == "exaone_moe":
+        _, cfg_all, params = built(uncut)       # with its selection bias
+    else:
+        model, cfg_all = build_model(TransformerConfig(
+            **fam.model_kwargs(uncut), dtype=jnp.float32))
+        params = make_params(model, cfg_all, 11, jnp.float32)
     moe = jax.tree.map(lambda a: a[1], params["blocks"]["moe"])
     h = jax.random.normal(jax.random.PRNGKey(5), (1, 40, 32))
-    want, *_ = FAM.reference_moe(moe, h[0], k=4, renorm=True, scale=2.5)
+    want = mixture(moe, h[0])
     total = 0.0
     for chip in range(8):
-        config = dict(TINY, deployment={"router_outputs": 16,
-                                        "experts_held": [2 * chip, 2]})
-        cfg = TransformerConfig(**FAM.model_kwargs(config),
+        config = dict(cut, deployment={"router_outputs": 16,
+                                       "experts_held": [2 * chip, 2]})
+        cfg = TransformerConfig(**fam.model_kwargs(config),
                                 dtype=jnp.float32)
         assert cfg.moe_held == (2 * chip, 2)
         held = dict(moe, experts=jax.tree.map(
@@ -160,10 +186,10 @@ def test_the_shares_add_up_to_the_uncut_layer():
         shared, _ = _moe_mlp(cfg, dict(held, experts=jax.tree.map(
             jnp.zeros_like, held["experts"])), h)
         # this chip's routed part alone; the reference's share is the same
-        mine, *_ = FAM.reference_moe(held, h[0], k=4, renorm=True, scale=2.5,
-                                     first=2 * chip, shared=False)
+        mine = mixture(held, h[0], first=2 * chip, **routed_only)
         np.testing.assert_allclose((y - shared)[0], mine, atol=1e-5)
         total = total + (y - shared)[0]
+    assert (family == "exaone_moe") == bool(np.abs(shared).max() > 0)
     np.testing.assert_allclose(total + shared[0], want, atol=1e-5)
     # and the uncut program is the uncut reference
     y, _ = _moe_mlp(cfg_all, moe, h)
