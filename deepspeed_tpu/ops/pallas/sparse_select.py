@@ -24,16 +24,24 @@ Two kernels, each with its jnp twin (the CPU path and the kernel's oracle):
   leave VMEM. Tiles wholly invisible copy and compute nothing. The operands are the model's dtype and the products accumulate in
   float32: bf16 x bf16 products are exact in float32, so a bf16 model's
   scores are what float32 at ``highest`` gives on the same rounded operands.
-* :func:`topk_threshold` (``sparse_topk``): per row the value of its k-th
-  largest score and the position up to which scores EQUAL to it are taken,
-  ``(thr, tie)``: key ``s`` is selected iff ``I > thr or (I == thr and s <=
-  tie)`` (:func:`selected`). Exact, by bisection: 32 passes over the float's
-  bits (as a sortable integer) find the k-th largest value, ``ceil(log2
-  keys)`` passes over the positions break its ties; every pass is a compare
-  and a count over a row tile that stays in VMEM. No sort, no index list:
-  the paged kernel applies ``(thr, tie)`` to the scores of the keys it holds
-  (``ops/pallas/paged_attention.py``), beside its causal, context and window
-  masks. A row tile none of whose rows sees more than k keys does no pass.
+* :func:`topk_threshold` (``sparse_topk``): per row a threshold and a tie
+  position ``(thr, tie)``: key ``s`` is selected iff ``I > thr or (I == thr
+  and s <= tie)`` (:func:`selected`). Exact, by bisection over the float's
+  bits (as a sortable integer): every pass is a compare and a count over a
+  row tile that stays in VMEM, and the work follows what the tile can see
+  and what is still undecided. A tile passes over the columns up to its
+  rows' last visible position only (the extent its caller hands it, in
+  column steps of :func:`topk_columns`); it stops once every row has a
+  candidate with EXACTLY k scores at or above it (the lower bits cannot
+  change which: rows of index scores separate after 17-25 of the 32 passes),
+  and ``thr`` is then that candidate, a float in the gap under the k-th
+  score, with ``tie`` = every position; only a row whose equal scores
+  straddle the k-th runs out of bits, and ``ceil(log2 extent)`` passes over
+  the positions then find up to where they are taken. No sort, no index
+  list: the paged kernel applies ``(thr, tie)`` to the scores of the keys it
+  holds (``ops/pallas/paged_attention.py``), beside its causal, context and
+  window masks. A row tile none of whose rows sees more than k keys does no
+  pass; a row of at most k beside rows of more keeps every visible key.
 
 :func:`selection_bits` packs a call's selection 32 keys a word, for a
 request that asked for its routing (``serving.engine``: ``submit(...,
@@ -45,7 +53,7 @@ are its inverse.
 from __future__ import annotations
 
 from functools import partial
-from typing import NamedTuple, Tuple
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -56,21 +64,33 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["Selection", "index_scores", "index_scores_reference",
            "topk_threshold", "topk_threshold_reference", "selected",
            "select", "selection_bits", "bits_to_positions",
-           "positions_of_bits", "padded_keys",
+           "positions_of_bits", "padded_keys", "topk_tiles", "topk_columns",
            "untileable"]
 
 _INT_MIN = -2 ** 31
 #: query rows a score program takes (a decode token: the sublane minimum)
 _SCORE_ROWS = 256
-#: rows a top-k program holds in VMEM with their sortable keys
-_TOPK_ROWS = 8
+#: rows a top-k program holds in VMEM with their sortable keys. A pass ends
+#: in a chain of a lane reduction, a few one-lane operations and a broadcast
+#: (~110 cycles on a v5e, whatever the rows): two sublane groups share it
+#: (PERF.md, PR 46: 8 / 16 / 32 rows read 1.29 / 0.87 / 0.75 ms a chunk step
+#: of eight layers at 8k keys; 32 lose it again on a decode call's one
+#: padded tile, 0.18 for 0.10)
+_TOPK_ROWS = 16
+#: value passes of a top-k tile between two tests of whether every row has
+#: separated its k keys (1 / 4 / 8: 0.98 / 0.87 / 0.87 ms at the same shape)
+_TOPK_GROUP = 4
+#: ``_sortable`` of -inf's bits: what a column no row sees ranks as
+_NEG_INF_KEY = -2 ** 31 + 0x7FFFFF
 
 
 class Selection(NamedTuple):
     """Which keys each query row of a call attends: ``scores [B, T, Kp]``
     float32 (``-inf`` where a key is not visible; ``Kp``:
     :func:`padded_keys` of the lane's key capacity), and per row ``thr [B,
-    T]`` float32 / ``tie [B, T]`` int32 (:func:`selected`)."""
+    T]`` float32 / ``tie [B, T]`` int32, to be read through
+    :func:`selected` alone: ``thr`` separates the row's k best scores from
+    the rest and need not be one of them (:func:`topk_threshold`)."""
     scores: jnp.ndarray
     thr: jnp.ndarray
     tie: jnp.ndarray
@@ -241,81 +261,166 @@ def _sortable(bits):
     return bits ^ ((bits >> 31) & 0x7FFFFFFF)
 
 
-def _topk_kernel(busy_ref, s_ref, thr_ref, tie_ref, key_ref, *, k, pos_bits):
+def _topk_kernel(ext_ref, bits_ref, s_ref, thr_ref, tie_ref, key_ref, *, k,
+                 cb):
+    # no branch: a tile that makes no pass (extent 0) and a tile without a
+    # partial tie run the loops below zero times (and every serving program
+    # traces and lowers this text at every start: PERF.md, PR 46)
     rows, K = s_ref.shape
-    count = lambda m: jnp.sum(m.astype(jnp.float32), axis=1, keepdims=True)
-    kf = jnp.float32(k)
     lanes = thr_ref.shape[1]
-    busy = busy_ref[pl.program_id(0)]
+    kf = jnp.float32(k)
+    ext, pos_bits = ext_ref[pl.program_id(0)], bits_ref[pl.program_id(0)]
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, cb), 1)
+    zeros = jnp.zeros((rows, cb), jnp.float32)
+    steps = (ext + cb - 1) // cb
+    per_row = lambda acc: jnp.sum(acc, axis=1, keepdims=True)
 
-    @pl.when(busy == 0)
-    def _all():
-        # no row of the tile sees more than k keys: every visible key is
-        # selected
-        thr_ref[...] = jnp.full((rows, lanes), -jnp.inf, jnp.float32)
-        tie_ref[...] = jnp.full((rows, lanes), K, jnp.int32)
+    def over_columns(step, carry, steps=steps):
+        """``step(at, first column, carry)`` over the tile's live columns,
+        ``cb`` at a time: what lies past the extent is never read."""
+        def body(j, c):
+            c0 = pl.multiple_of(j * cb, cb)
+            return step(pl.ds(c0, cb), c0, c)
+        return jax.lax.fori_loop(0, steps, body, carry)
 
-    @pl.when(busy != 0)
-    def _bisect():
-        # -0.0 (a negative weight times a zero) ranks as +0.0: the passes
-        # compare bit patterns, and equal scores are ties
-        s = s_ref[...]
-        key_ref[...] = _sortable(jax.lax.bitcast_convert_type(
+    def count(cmp, bound):
+        """Per row, the live columns whose sortable key stands ``cmp`` to the
+        row's ``bound [rows, 1]``: partial counts a lane, reduced across the
+        lanes once."""
+        bound = jnp.broadcast_to(bound, (rows, cb))
+        return per_row(over_columns(
+            lambda at, c0, acc: acc + cmp(key_ref[:, at], bound).astype(
+                jnp.float32), zeros))
+
+    def sortable_keys(at, c0, seen):
+        # a column at or past the extent is visible to no row, whatever the
+        # buffer holds there; -0.0 (a negative weight times a zero) ranks as
+        # +0.0: the passes compare bit patterns, and equal scores are ties
+        s = jnp.where(col + c0 < ext, s_ref[:, at], -jnp.inf)
+        key_ref[:, at] = _sortable(jax.lax.bitcast_convert_type(
             jnp.where(s == 0.0, 0.0, s), jnp.int32))
-        zero = jnp.zeros((rows, 1), jnp.int32)
+        return seen + (s > -jnp.inf).astype(jnp.float32)
 
-        def value_bit(i, ans):
-            # ``ans``: the k-th largest key so far, as an UNSIGNED number
-            # (its sign bit flipped): the largest v with count(key >= v) >= k
-            cand = ans | jnp.left_shift(jnp.int32(1), 31 - i)
-            ok = count(key_ref[...] >= (cand ^ _INT_MIN)) >= kf
-            return jnp.where(ok, cand, ans)
+    # a row of at most k keys (every row of a tile that makes no pass, or
+    # one beside rows of more) keeps every visible key: closed from the
+    # start at -inf
+    more = per_row(over_columns(sortable_keys, zeros)) > kf
 
-        kth = jax.lax.fori_loop(0, 32, value_bit, zero) ^ _INT_MIN
-        key = key_ref[...]
-        need = kf - count(key > kth)                 # >= 1 of the equal ones
-        pos = jax.lax.broadcasted_iota(jnp.int32, key.shape, 1)
-        # the equal keys' positions, K where a key is not equal
-        key_ref[...] = jnp.where(key == kth, pos, jnp.int32(2 ** pos_bits))
+    def value_bit(i, c):
+        # ``ans``: the k-th largest key so far, as an UNSIGNED number (its
+        # sign bit flipped): the largest v with count(key >= v) >= k. A row
+        # is closed at the first candidate that leaves EXACTLY k keys at or
+        # above it: the lower bits cannot change which. (An open row counts
+        # more than k live columns, so its count is never k at a pattern
+        # under -inf's: no closed ``ans`` is a NaN.)
+        ans, open_ = c
+        cand = ans | jnp.left_shift(jnp.int32(1), 31 - i)
+        n = count(jnp.greater_equal, cand ^ _INT_MIN)
+        return (jnp.where((open_ > 0) & (n >= kf), cand, ans),
+                jnp.where(n == kf, 0, open_))
 
-        def pos_bit(i, p):
-            # the largest p with fewer than ``need`` equal keys below it:
-            # the position of the need-th
-            cand = p | jnp.left_shift(jnp.int32(1), pos_bits - 1 - i)
-            ok = count(key_ref[...] < cand) < need
-            return jnp.where(ok, cand, p)
+    passes, ans, open_ = jax.lax.while_loop(
+        lambda c: (c[0] < 32) & (jnp.max(c[2]) > 0),
+        lambda c: (c[0] + _TOPK_GROUP,) + jax.lax.fori_loop(
+            c[0], c[0] + _TOPK_GROUP, value_bit, c[1:]),
+        (jnp.int32(0), jnp.where(more, 0, _NEG_INF_KEY ^ _INT_MIN),
+         more.astype(jnp.int32)))
+    kth = ans ^ _INT_MIN
 
-        tie = jax.lax.fori_loop(0, pos_bits, pos_bit, zero)
-        thr = jax.lax.bitcast_convert_type(_sortable(kth), jnp.float32)
-        thr_ref[...] = jnp.broadcast_to(thr, (rows, lanes))
-        tie_ref[...] = jnp.broadcast_to(tie, (rows, lanes))
+    # a row still open ran out of bits: ``kth`` is its k-th largest key and
+    # MORE than k keys stand at or above it; of the equal ones the lower
+    # positions are taken. Every other row's equal scores are among its k.
+    partial = jnp.max(open_) > 0
+    pos_bits = jnp.where(partial, pos_bits, 0)
+
+    def equal_positions(at, c0, above):
+        key = key_ref[:, at]
+        key_ref[:, at] = jnp.where(key == kth, col + c0,
+                                   jnp.int32(2 ** 31 - 1))
+        return above + (key > kth).astype(jnp.float32)
+
+    need = kf - per_row(over_columns(         # >= 1 of the equal ones
+        equal_positions, zeros, jnp.where(partial, steps, 0)))
+
+    def pos_bit(i, p):
+        # the largest p with fewer than ``need`` equal keys below it: the
+        # position of the need-th
+        cand = p | jnp.left_shift(jnp.int32(1), pos_bits - 1 - i)
+        return jnp.where(count(jnp.less, cand) < need, cand, p)
+
+    tie = jax.lax.fori_loop(0, pos_bits, pos_bit,
+                            jnp.zeros((rows, 1), jnp.int32))
+    thr_ref[...] = jnp.broadcast_to(jax.lax.bitcast_convert_type(
+        _sortable(kth), jnp.float32), (rows, lanes))
+    # lane 0 the tie position, lanes 1 and 2 the passes the tile made
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, lanes), 1)
+    tie_ref[...] = jnp.where(
+        lane == 0, jnp.where(open_ > 0, tie, K),
+        jnp.where(lane == 1, passes, pos_bits))
 
 
-def topk_threshold(scores, k: int, seen=None, *, interpret=False
-                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+def topk_tiles(seen, extent, k: int, Kp: int, xp=jnp):
+    """Per tile of the top-k kernel's rows, the column extent it is handed:
+    the largest ``extent`` of its rows (one past a row's last visible
+    position), 0 for a tile none of whose rows sees more than ``k`` keys
+    (``seen``: a row's count of them). ``xp``: numpy for a host that counts
+    what the kernel will do (``serving.engine``)."""
+    def of_tiles(a):
+        a = xp.asarray(a, xp.int32).reshape(-1)
+        return xp.pad(a, (0, -a.shape[0] % _TOPK_ROWS)).reshape(
+            -1, _TOPK_ROWS).max(axis=1)
+    return xp.where(of_tiles(seen) > k, xp.clip(of_tiles(extent), 0, Kp), 0)
+
+
+def topk_columns(tiles, Kp: int):
+    """The columns the top-k kernel's passes take over tiles of these
+    extents (:func:`topk_tiles`): whole column steps."""
+    cb = _key_tile(Kp)
+    return -(-tiles // cb) * cb
+
+
+def topk_threshold(scores, k: int, seen=None, extent=None, *,
+                   return_passes=False, interpret=False):
     """``(thr [R] float32, tie [R] int32)`` of ``scores [R, Kp]`` (``Kp`` whole
-    128-lane tiles): row r's k-th largest score and the position up to which
-    scores equal to it are taken (lower positions first), so that
-    :func:`selected` keeps exactly k keys, or every key of a row of at most
-    k (``-inf`` entries counted: a caller masks what is not visible).
+    128-lane tiles) such that :func:`selected` keeps exactly row r's k
+    largest scores, equal ones to the lower position, or every key of a row
+    of at most k (``-inf`` entries counted: a caller masks what is not
+    visible). ``thr`` is a value that separates them, NOT always a score of
+    the row: a row whose k-th and (k+1)-th largest differ gets any float in
+    the gap with ``tie = Kp``; only where equal scores straddle the k-th is
+    ``thr`` that score and ``tie`` the position up to which it is taken.
+    Read both through :func:`selected` alone.
+
     ``seen [R]``: the keys each row sees (more than ``-inf``), where the
-    caller knows them from the rows' positions; counted here otherwise. A
-    tile of rows none of which sees more than k does no pass."""
+    caller knows them from the rows' positions; counted here otherwise.
+    ``extent [R]``: one past each row's last visible position (a layer's
+    window clips ``seen``, not this); ``Kp`` otherwise. The work follows
+    them: a tile of ``_TOPK_ROWS`` rows none of which sees more than k makes
+    no pass; another passes over the columns below its largest extent only
+    (what the buffer holds past it is not read), stops its value passes
+    once every row has separated exactly k keys (tested every
+    ``_TOPK_GROUP`` passes; 32 at most), and breaks ties by position, in
+    ``ceil(log2 extent)`` passes more, only if a row ran out of bits.
+    ``return_passes``: a third result ``[tiles, 2]`` int32, the value and
+    position passes each tile made (known on the device only)."""
     R, Kp = scores.shape
     rows = _TOPK_ROWS
     Rp = -(-R // rows) * rows
-    scores = jnp.pad(scores.astype(jnp.float32), [(0, Rp - R), (0, 0)],
-                     constant_values=-jnp.inf)
+    scores = scores.astype(jnp.float32)
     if seen is None:
-        seen = jnp.sum(scores[:R] > -jnp.inf, axis=1)
-    busy = jnp.max(jnp.pad(jnp.asarray(seen, jnp.int32).reshape(R),
-                           [(0, Rp - R)]).reshape(-1, rows), axis=1) > k
-    pos_bits = max(1, int(np.ceil(np.log2(Kp))))
+        seen = jnp.sum(scores > -jnp.inf, axis=1)
+    if Rp != R:
+        scores = jnp.pad(scores, [(0, Rp - R), (0, 0)],
+                         constant_values=-jnp.inf)
+    tiles = topk_tiles(seen, jnp.full((R,), Kp) if extent is None else extent,
+                       int(k), Kp)
+    # the bits of a tile's positions: ceil(log2 extent)
+    pos_bits = 32 - jax.lax.clz(jnp.maximum(tiles, 1) - 1)
     with jax.named_scope("sparse_topk"):
         thr, tie = pl.pallas_call(
-            partial(_topk_kernel, k=int(k), pos_bits=pos_bits),
+            partial(_topk_kernel, k=int(k), cb=_key_tile(Kp)),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1, grid=(Rp // rows,),
+                num_scalar_prefetch=2, grid=(Rp // rows,),
                 in_specs=[pl.BlockSpec((rows, Kp), lambda r, *_: (r, 0))],
                 out_specs=[pl.BlockSpec((rows, 128),
                                         lambda r, *_: (r, 0))] * 2,
@@ -324,7 +429,9 @@ def topk_threshold(scores, k: int, seen=None, *, interpret=False
             out_shape=[jax.ShapeDtypeStruct((Rp, 128), jnp.float32),
                        jax.ShapeDtypeStruct((Rp, 128), jnp.int32)],
             interpret=interpret,
-        )(busy.astype(jnp.int32), scores)
+        )(tiles, pos_bits, scores)
+    if return_passes:
+        return thr[:R, 0], tie[:R, 0], tie[::rows, 1:3]
     return thr[:R, 0], tie[:R, 0]
 
 
@@ -343,14 +450,15 @@ def topk_threshold_reference(scores, k: int):
     return thr, tie.astype(jnp.int32)
 
 
-def select(scores, k: int, seen=None, *, kernel: bool, interpret=False
-           ) -> Selection:
-    """The :class:`Selection` of ``scores [B, T, Kp]`` (``seen [B, T]``:
-    :func:`topk_threshold`)."""
+def select(scores, k: int, seen=None, extent=None, *, kernel: bool,
+           interpret=False) -> Selection:
+    """The :class:`Selection` of ``scores [B, T, Kp]`` (``seen``, ``extent``
+    ``[B, T]``: :func:`topk_threshold`)."""
     B, T, Kp = scores.shape
     flat = scores.reshape(B * T, Kp)
-    seen = None if seen is None else seen.reshape(B * T)
-    thr, tie = (topk_threshold(flat, k, seen, interpret=interpret) if kernel
+    rows = lambda a: None if a is None else a.reshape(B * T)
+    thr, tie = (topk_threshold(flat, k, rows(seen), rows(extent),
+                               interpret=interpret) if kernel
                 else topk_threshold_reference(flat, k))
     return Selection(scores, thr.reshape(B, T), tie.reshape(B, T))
 

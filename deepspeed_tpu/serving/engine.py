@@ -29,6 +29,7 @@ across staggered arrivals and mixed lengths.
 
 from __future__ import annotations
 
+import collections
 import functools
 import threading
 import time
@@ -45,6 +46,7 @@ from ..runtime.heartbeat import PHASE_SERVE
 from ..testing import chaos
 from ..utils import telemetry
 from ..utils.logging import log_dist, logger
+from ..ops.pallas import sparse_select
 from ..ops.pallas.sparse_select import bits_to_positions, positions_of_bits
 from .kv_cache import (NULL_BLOCK, BlockPoolExhausted, SharedPagedState)
 from .model_runner import attention_impl, paged_forward
@@ -96,7 +98,10 @@ _MOE_COUNTERS = ("moe.assignments", "moe.held_assignments", "moe.layer_steps",
 #: (every live page: it masks, it does not skip; beside
 #: ``paged.live_pages_sum``, which counts a call's pages once)
 _SPARSE_COUNTERS = ("sparse.rows_sum", "sparse.keys_scored_sum",
-                    "sparse.keys_selected_sum", "sparse.pages_walked_sum")
+                    "sparse.keys_selected_sum", "sparse.pages_walked_sum",
+                    "sparse.topk_tiles_sum", "sparse.topk_tiles_idle_sum",
+                    "sparse.topk_columns_sum",
+                    "sparse.topk_columns_table_sum")
 
 _KV_DTYPES = {"bf16": jnp.bfloat16, "bfloat16": jnp.bfloat16,
               "f32": jnp.float32, "float32": jnp.float32,
@@ -525,6 +530,9 @@ class ServingEngine:
         # the windows of the layers that have one (``paged.window_pages_sum``)
         self._windows = np.asarray(
             [w for w in cfg.layer_windows or () if w > 0], np.int64)
+        # window (0: none) -> the layers that have it
+        self._index_windows = collections.Counter(
+            max(int(w), 0) for w in cfg.layer_windows or (0,) * cfg.num_layers)
         if cfg.rope_scaling_type == "dynamic":
             # dynamic NTK derives its table from the cache capacity, which
             # differs between the pool (max_blocks_per_seq * block_size)
@@ -691,19 +699,36 @@ class ServingEngine:
             out, self._picks_out = out
         return out
 
-    def _count_selection(self, seen: np.ndarray, pages: int) -> None:
-        """A model with an indexer: a call's query rows, each seeing
-        ``seen`` keys (its own included), and the ``pages`` its attention
-        walks, in every layer (:data:`_SPARSE_COUNTERS`)."""
+    def _count_selection(self, extent: np.ndarray, real, pages: int) -> None:
+        """A model with an indexer: a call's query rows as its kernels tile
+        them (padding rows and idle lanes too), ``extent`` one past each
+        row's last visible position; ``real`` indexes the rows that are fed
+        a token, each seeing that many keys, its own included; the ``pages``
+        the call's attention walks; in every layer
+        (:data:`_SPARSE_COUNTERS`)."""
         cfg = self.cfg
         if not cfg.index_heads:
             return
-        L, c = cfg.num_layers, self.stats
+        L, c, seen = cfg.num_layers, self.stats, extent[real]
         c["sparse.rows_sum"] += L * int(seen.size)
         c["sparse.keys_scored_sum"] += L * int(seen.sum())
         c["sparse.keys_selected_sum"] += L * int(
             np.minimum(seen, cfg.index_topk).sum())
         c["sparse.pages_walked_sum"] += L * int(pages)
+        # what ``sparse_topk`` does with them, by its own rule: a layer's
+        # window clips the keys a row counts, not where its last one lies
+        Kp = sparse_select.padded_keys(self.nbk * self.block_size)
+        for window, layers in self._index_windows.items():
+            tiles = sparse_select.topk_tiles(
+                np.minimum(extent, window) if window else extent, extent,
+                cfg.index_topk, Kp, np)
+            busy = tiles[tiles > 0]
+            c["sparse.topk_tiles_sum"] += layers * tiles.size
+            c["sparse.topk_tiles_idle_sum"] += layers * (tiles.size
+                                                         - busy.size)
+            c["sparse.topk_columns_sum"] += layers * int(
+                sparse_select.topk_columns(busy, Kp).sum())
+            c["sparse.topk_columns_table_sum"] += layers * busy.size * Kp
 
     def _count_experts(self, out: np.ndarray, call: int) -> None:
         """A dropless MoE model's router load, from the fetched output of
@@ -1234,8 +1259,8 @@ class ServingEngine:
         self.rec.count("paged.chunk_live_pages_sum",
                        -(-(q0 + n) // self.block_size))
         self.rec.count("paged.chunk_table_pages_sum", self.nbk)
-        self._count_selection(q0 + 1 + np.arange(n),
-                              -(-(q0 + n) // self.block_size))
+        self._count_selection(np.minimum(q0 + 1 + np.arange(Tb), q0 + n),
+                              slice(n), -(-(q0 + n) // self.block_size))
         if Tb not in self._prefill_shapes:
             self._note_prefill_path(Tb)
         buf = np.zeros((self._layout.prefill_words(Tb),), np.int32)
@@ -1481,7 +1506,7 @@ class ServingEngine:
             rec.count("paged.live_pages_sum",
                       int((lanes.ctx * go // self.block_size + 1).sum()))
             rec.count("paged.table_pages_sum", B * self.nbk)
-            self._count_selection(lanes.ctx[go] + 1, int(
+            self._count_selection(lanes.ctx * go + 1, go, int(
                 (lanes.ctx[go] // self.block_size + 1).sum()))
             if self._windows.size:
                 # of those, what the window layers' calls walk, summed over

@@ -248,11 +248,12 @@ class PagedCache:
                     qi, wi, keys.reshape(B, -1, width), self.q_start,
                     self.ctx, window)
         with jax.named_scope("select"):
-            # the keys a row sees, from where it stands
-            seen = jnp.minimum(self.positions(T) + 1, self.ctx[:, None])
-            seen = jnp.where(window > 0, jnp.minimum(seen, window), seen)
+            # from where a row stands: one past its last visible position,
+            # and the keys it sees, which a window clips
+            extent = jnp.minimum(self.positions(T) + 1, self.ctx[:, None])
+            seen = jnp.where(window > 0, jnp.minimum(extent, window), extent)
             return sparse_select.select(
-                scores, self.cfg.index_topk, seen, kernel=kernel,
+                scores, self.cfg.index_topk, seen, extent, kernel=kernel,
                 interpret=self.interpret)
 
     def attend(self, kv, li, q, k, v, window, select=None):

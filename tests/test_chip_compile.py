@@ -17,6 +17,8 @@ and see the CPU, so the tests call the kernel entries or steer the wrapper
 with monkeypatch — the program has no option for it.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -615,6 +617,20 @@ def test_keye_serving_step_selects_keys_in_place(chip, monkeypatch, program):
                     ("sparse_index_scores", 1), ("sparse_topk", 1)):
         assert len([k for k in kernels if name in k]) == n, (name, kernels)
     assert len(kernels) == 6, kernels
+    # the top-k reads the scores where the score kernel wrote them: a chunk's
+    # [256, 25600] is that buffer itself (a bitcast), never a padded or
+    # sliced copy; a decode call's is the 16 lanes' one real row each out of
+    # the score kernel's 8-row tiles (ROADMAP S17 b)
+    call = next(line for line in text.splitlines()
+                if "custom-call(" in line and "/sparse_topk" in line)
+    fed = re.search(r"custom-call\(%[\w.\-]+, %[\w.\-]+, %([\w.\-]+)\)",
+                    call).group(1)
+    source = next(line for line in text.splitlines()
+                  if re.match(r"\s*%%%s = " % re.escape(fed), line))
+    assert f"f32[{rows},{NBK * BS}]" in source, source
+    if program == "prefill256":
+        assert re.search(r" bitcast\(%sparse_index_scores[\w.\-]*\)", source), \
+            source
     made = [r for r in _results(text) if r[1] not in (
         "parameter", "get-tuple-element", "while", "tuple", "bitcast")]
     layer = cfg.kv_heads * NB * BS * cfg.head_dim

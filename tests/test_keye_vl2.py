@@ -285,6 +285,43 @@ def test_the_hand_out_follows_the_reference(tiny):
     srv.close()
 
 
+@pytest.mark.parametrize("table_blocks", [12, 48])
+def test_the_topk_counters_follow_the_tiles(table_blocks):
+    """``sparse.topk_*``: the row tiles the top-k kernel is handed, those
+    that make no pass, and the columns the others pass over beside the whole
+    table's: a prompt under top-k keys leaves only idle tiles, and a table
+    sized beyond what the requests use costs the kernel nothing (the share
+    of its columns read falls with its length)."""
+    cfg, params = built({**TINY, "max_position_embeddings": 512})
+    srv = _engine(cfg, params, max_blocks_per_seq=table_blocks,
+                  pool_blocks=120)
+    rng = np.random.default_rng(9)
+    counters = lambda: dict(srv.telemetry()["counters"])
+    tiles = lambda rows: -(-rows // ss._TOPK_ROWS)
+    srv.submit(rng.integers(1, 64, size=TOPK - 4).tolist(), max_new_tokens=2)
+    srv.run_until_idle()
+    c = counters()
+    # a chunk's 16 rows and a decode call's 4 lanes, in each of 3 layers
+    assert c["sparse.topk_tiles_sum"] == c["sparse.topk_tiles_idle_sum"] \
+        == 3 * (tiles(16) + tiles(4))
+    assert c["sparse.topk_columns_sum"] == 0 \
+        == c["sparse.topk_columns_table_sum"]
+    srv.submit(rng.integers(1, 64, size=70).tolist(), max_new_tokens=6)
+    srv.run_until_idle()
+    c = counters()
+    Kp = ss.padded_keys(table_blocks * 8)
+    busy = (c["sparse.topk_tiles_sum"] - c["sparse.topk_tiles_idle_sum"]) // 3
+    # chunks 2 to 4 (rows 16-63: the first tile holds row 16, which sees 17
+    # keys), the last chunk's 6 rows on one tile, and five decode calls
+    assert busy == 3 * tiles(16) + 1 + 5
+    assert c["sparse.topk_columns_table_sum"] == 3 * busy * Kp
+    # contexts of at most 76 keys lie inside the first column step of 128
+    assert c["sparse.topk_columns_sum"] == 3 * busy * 128
+    assert (c["sparse.topk_columns_sum"] < c["sparse.topk_columns_table_sum"]
+            ) == (table_blocks > 16)
+    srv.close()
+
+
 def test_the_indexers_keys_travel_with_the_blocks(tiny):
     """A prefix-cache hit and a lane preempted and resumed give a cold run's
     tokens and selection: the ``ki`` leaf is in the blocks the prefix cache
