@@ -19,11 +19,25 @@ Two kernels, each with its jnp twin (the CPU path and the kernel's oracle):
   (``s > t`` or past the lane's context). The kernel copies a tile's pages
   out of the pool through the block table, as the paged kernel does (an
   XLA gather had the chip's compiler keep the pool in another layout and
-  copy it whole every layer); a matmul a head over the tile, ReLU and the
-  head's weight folded in place: the ``[rows, heads, keys]`` products never
-  leave VMEM. Tiles wholly invisible copy and compute nothing. The operands are the model's dtype and the products accumulate in
-  float32: bf16 x bf16 products are exact in float32, so a bf16 model's
-  scores are what float32 at ``highest`` gives on the same rounded operands.
+  copy it whole every layer). One algorithm, its tile shaped by the call:
+  a call of several rows a lane (a prefill chunk) runs a program a ``(lane,
+  256 rows, 1024 keys)`` tile, a matmul a head over the tile, ReLU and the
+  head's weight folded in place (the ``[rows, heads, keys]`` products never
+  leave VMEM; a tile wholly invisible copies and computes nothing). A call
+  of ONE row a lane (the decode call) runs a program a group of 8 lanes:
+  a lane's ``[heads, width]`` query is the ROWS of one matmul against a key
+  tile, ReLU, the heads' weights and the sum over the heads follow on the
+  vector unit, and the program walks only the lane's own key tiles
+  (:func:`score_tiles`: up to its context, from its layer's window), the
+  next tile's pages in flight while one multiplies; its result is a row a
+  lane, ``[B, Kp]``, as the top-k reads it. The operands are the model's
+  dtype and the products accumulate in float32: bf16 x bf16 products are
+  exact in float32, so a bf16 model's scores are what float32 at
+  ``highest`` gives on the same rounded operands. The sixteen float32
+  terms of a score are added head after head by the chunk's program and
+  by a reduction over the matmul's rows by the decode call's: another
+  ORDER of the same additions, a bit or two of a float32 apart (2e-5 on
+  scores of 150), and :func:`index_scores_reference` takes the chunk's.
 * :func:`topk_threshold` (``sparse_topk``): per row a threshold and a tie
   position ``(thr, tie)``: key ``s`` is selected iff ``I > thr or (I == thr
   and s <= tie)`` (:func:`selected`). Exact, by bisection over the float's
@@ -64,12 +78,19 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["Selection", "index_scores", "index_scores_reference",
            "topk_threshold", "topk_threshold_reference", "selected",
            "select", "selection_bits", "bits_to_positions",
-           "positions_of_bits", "padded_keys", "topk_tiles", "topk_columns",
-           "untileable"]
+           "positions_of_bits", "padded_keys", "score_tiles", "topk_tiles",
+           "topk_columns", "untileable"]
 
 _INT_MIN = -2 ** 31
-#: query rows a score program takes (a decode token: the sublane minimum)
+#: query rows a score program takes (a call of 2 to 8 rows: the sublane
+#: minimum; of one row a lane: :func:`_decode_score_kernel`)
 _SCORE_ROWS = 256
+#: lanes a decode-shaped score program holds, one query row each: the
+#: sublanes of its result's block
+_DECODE_LANES = 8
+#: the longest table a decode-shaped score program takes: its lanes' rows
+#: stay in VMEM (two buffers of ``_DECODE_LANES x Kp`` float32, 8 MB here)
+_DECODE_KEYS = 131072
 #: rows a top-k program holds in VMEM with their sortable keys. A pass ends
 #: in a chain of a lane reduction, a few one-lane operations and a broadcast
 #: (~110 cycles on a v5e, whatever the rows): two sublane groups share it
@@ -169,6 +190,147 @@ def _precision(dtype):
             else jax.lax.Precision.DEFAULT)
 
 
+def score_tiles(q_start, context_lens, window, Kp: int, xp=jnp):
+    """``(first, end)`` per lane of a call of ONE query row a lane: the key
+    tiles its score program copies and multiplies, from the tile of the
+    first key inside the layer's ``window`` (<= 0: none) up to the tile of
+    the last key the row sees (of the table's ``Kp`` at most); ``first ==
+    end`` for a lane of no key. The kernel's own rule; ``xp``: numpy for a
+    host that counts what the kernel will do (``serving.engine``)."""
+    tk = _key_tile(Kp)
+    seen = xp.minimum(q_start + 1, context_lens)
+    end = xp.minimum((seen + tk - 1) // tk, Kp // tk)
+    low = xp.where(window > 0, xp.maximum(q_start + 1 - window, 0), 0)
+    return xp.minimum(low // tk, end), end
+
+
+def _decode_score_kernel(bt_ref, q0_ref, ctx_ref, misc_ref, q_ref, w_ref,
+                         pool_hbm, o_ref, k_buf, sem, *, tiles, Kp, tk, bs,
+                         nbk, precision):
+    # a program holds ``lanes`` lanes of one query row each and walks each
+    # lane's own key tiles, the next tile's pages (the next lane's first
+    # tile behind a lane's last) in flight while one multiplies. ``tiles``
+    # is :func:`score_tiles`, handed in: the serving loop calls it on the
+    # host under its lock, and the package's linter takes every function a
+    # traced body names for device work
+    lanes = o_ref.shape[0]
+    lane0 = pl.program_id(0) * lanes
+    layer, window = misc_ref[0], misc_ref[1]
+    row_of = jax.lax.broadcasted_iota(jnp.int32, (lanes, tk), 0)
+    key_of = jax.lax.broadcasted_iota(jnp.int32, (1, tk), 1)
+
+    def span(b):
+        return tiles(q0_ref[b], ctx_ref[b], window, Kp)
+
+    def pages(act, b, i, slot):
+        """``act`` ("start" or "wait") on the copies of lane b's tile i into
+        buffer ``slot``: the pages that hold a key the row sees (what the
+        buffer keeps of the rest is masked), no page past the table's end.
+        A page's descriptor is the loop's whole cost (PERF.md, PR 47: a
+        tile's 32 starts are 0.9 us, its matmul 0.2), so the table is read
+        as one row of words, and a full tile is waited for at once, by the
+        bytes of its buffer."""
+        seen = jnp.minimum(jnp.minimum(q0_ref[b] + 1, ctx_ref[b]), nbk * bs)
+        n = jnp.minimum((seen - i * tk + bs - 1) // bs, tk // bs)
+        word = b * nbk + i * (tk // bs)
+        if act == "wait":
+            full = n == tk // bs
+
+            @pl.when(full)
+            def _whole_tile():
+                pltpu.make_async_copy(k_buf.at[slot], k_buf.at[slot],
+                                      sem.at[slot]).wait()
+
+            n = jnp.where(full, 0, n)
+
+        def page(p, _):
+            # waiting takes a descriptor of the same size, whatever its source
+            phys = 0 if act == "wait" else bt_ref[word + p]
+            getattr(pltpu.make_async_copy(
+                pool_hbm.at[layer, 0, phys],
+                k_buf.at[slot, pl.ds(pl.multiple_of(p * bs, bs), bs)],
+                sem.at[slot]), act)()
+
+        jax.lax.fori_loop(0, n, page, None)
+
+    o_ref[...] = jnp.full(o_ref.shape, -jnp.inf, jnp.float32)
+
+    def lane(r, carry):
+        slot, started = carry
+        b = lane0 + r
+        q0, ctx = q0_ref[b], ctx_ref[b]
+        first, end = span(b)
+        nxt = jnp.minimum(r + 1, lanes - 1)
+        nxt_first, nxt_end = span(lane0 + nxt)
+        has_nxt = (r + 1 < lanes) & (nxt_first < nxt_end)
+
+        @pl.when((first < end) & (started == 0))
+        def _own_first_tile():
+            pages("start", b, first, slot)
+
+        def tile(i, slot):
+            last = i + 1 == end
+
+            @pl.when(jnp.logical_not(last) | has_nxt)
+            def _next_tile():
+                pages("start", jnp.where(last, lane0 + nxt, b),
+                      jnp.where(last, nxt_first, i + 1), 1 - slot)
+
+            pages("wait", b, i, slot)
+            # the lane's heads are the rows of one matmul; ReLU, the heads'
+            # weights and the sum over them on the vector unit
+            s = jax.lax.dot_general(
+                q_ref[r], k_buf[slot], (((1,), (1,)), ((), ())),
+                precision=precision, preferred_element_type=jnp.float32)
+            acc = jnp.sum(w_ref[r] * jnp.maximum(s, 0.0), axis=0,
+                          keepdims=True)
+            k_pos = i * tk + key_of
+            vis = (k_pos <= q0) & (k_pos < ctx) \
+                & ((q0 - k_pos < window) | (window <= 0))
+            at = pl.ds(pl.multiple_of(i * tk, tk), tk)
+            o_ref[:, at] = jnp.where(
+                row_of == r, jnp.where(vis, acc, -jnp.inf), o_ref[:, at])
+            return 1 - slot
+
+        return (jax.lax.fori_loop(first, end, tile, slot),
+                ((first < end) & has_nxt).astype(jnp.int32))
+
+    jax.lax.fori_loop(0, lanes, lane, (jnp.int32(0), jnp.int32(0)))
+
+
+def _decode_index_scores(qi, w, ki_pool, scalars, *, tk, interpret):
+    """:func:`index_scores` of a call of one query row a lane: ``[B, Kp]``,
+    a lane a row, as the top-k reads it."""
+    B, H, _, D = qi.shape
+    bs, nbk = ki_pool.shape[3], scalars[0].shape[1]
+    Kp = padded_keys(nbk * bs)
+    lanes = _DECODE_LANES
+    pad = -B % lanes
+    # a padding lane holds no key: its program fills its row and no more
+    grown = lambda a: jnp.pad(
+        a, [(0, pad)] + [(0, 0)] * (a.ndim - 1)) if pad else a
+    bt, q0, ctx = map(grown, scalars[:3])
+    qi = grown(qi.reshape(B, H, D))
+    w = grown(w.astype(jnp.float32).reshape(B, H, 1))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4, grid=((B + pad) // lanes,),
+        in_specs=[pl.BlockSpec((lanes, H, D), lambda g, *_: (g, 0, 0)),
+                  pl.BlockSpec((lanes, H, 1), lambda g, *_: (g, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((lanes, Kp), lambda g, *_: (g, 0)),
+        scratch_shapes=[pltpu.VMEM((2, tk, D), ki_pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,))])
+    with jax.named_scope("sparse_index_scores"):
+        out = pl.pallas_call(
+            partial(_decode_score_kernel, tiles=score_tiles, Kp=Kp, tk=tk,
+                    bs=bs, nbk=nbk, precision=_precision(qi.dtype)),
+            grid_spec=grid_spec, name="sparse_index_scores",
+            out_shape=jax.ShapeDtypeStruct((B + pad, Kp), jnp.float32),
+            interpret=interpret,
+        )(bt.reshape(-1), q0, ctx, scalars[3], qi, w, ki_pool)
+    return out[:B] if pad else out
+
+
 def index_scores(qi, w, ki_pool, block_tables, layer_idx, q_start,
                  context_lens, *, window=None, interpret=False):
     """``[B, T, Kp]`` float32 index scores, ``Kp`` = :func:`padded_keys` of
@@ -182,7 +344,9 @@ def index_scores(qi, w, ki_pool, block_tables, layer_idx, q_start,
     ``context_lens [B]`` the lane's valid keys, the call's own included,
     ``window`` the layer's sliding window (None or <= 0: none): a key
     outside it is not visible, so the selection is among the keys the
-    row's attention can see."""
+    row's attention can see. The call's shape picks the program: one row a
+    lane (``T == 1``, a decode call) a lane's heads as the rows of one
+    matmul over the lane's own key tiles, more rows the tiles of a chunk."""
     B, H, T, D = qi.shape
     bs, nbk = ki_pool.shape[3], block_tables.shape[1]
     Kp = padded_keys(nbk * bs)
@@ -191,6 +355,18 @@ def index_scores(qi, w, ki_pool, block_tables, layer_idx, q_start,
     tk = _key_tile(Kp)
     if tk % bs:
         raise ValueError(untileable(bs))
+    # traced where the chunk's program has always had them: its text is the
+    # parent's (``scripts/serving_program_text.py``)
+    scalars = lambda: (
+        jnp.asarray(block_tables, jnp.int32),
+        jnp.asarray(q_start, jnp.int32).reshape(B),
+        jnp.asarray(context_lens, jnp.int32).reshape(B),
+        jnp.stack([jnp.asarray(layer_idx, jnp.int32).reshape(()),
+                   jnp.asarray(0 if window is None else window,
+                               jnp.int32).reshape(())]))
+    if T == 1 and Kp <= _DECODE_KEYS:
+        return _decode_index_scores(qi, w, ki_pool, scalars(), tk=tk,
+                                    interpret=interpret)[:, None]
     qi = jnp.pad(qi, [(0, 0), (0, 0), (0, Tp - T), (0, 0)])
     w = jnp.pad(w.astype(jnp.float32), [(0, 0), (0, Tp - T), (0, 0)])
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -208,12 +384,7 @@ def index_scores(qi, w, ki_pool, block_tables, layer_idx, q_start,
             grid_spec=grid_spec, name="sparse_index_scores",
             out_shape=jax.ShapeDtypeStruct((B, Tp, Kp), jnp.float32),
             interpret=interpret,
-        )(jnp.asarray(block_tables, jnp.int32),
-          jnp.asarray(q_start, jnp.int32).reshape(B),
-          jnp.asarray(context_lens, jnp.int32).reshape(B),
-          jnp.stack([jnp.asarray(layer_idx, jnp.int32).reshape(()),
-                     jnp.asarray(0 if window is None else window,
-                                 jnp.int32).reshape(())]), qi, w, ki_pool)
+        )(*scalars(), qi, w, ki_pool)
     return out[:, :T]
 
 

@@ -101,7 +101,8 @@ _SPARSE_COUNTERS = ("sparse.rows_sum", "sparse.keys_scored_sum",
                     "sparse.keys_selected_sum", "sparse.pages_walked_sum",
                     "sparse.topk_tiles_sum", "sparse.topk_tiles_idle_sum",
                     "sparse.topk_columns_sum",
-                    "sparse.topk_columns_table_sum")
+                    "sparse.topk_columns_table_sum",
+                    "sparse.score_tiles_sum", "sparse.score_tiles_table_sum")
 
 _KV_DTYPES = {"bf16": jnp.bfloat16, "bfloat16": jnp.bfloat16,
               "f32": jnp.float32, "float32": jnp.float32,
@@ -699,13 +700,15 @@ class ServingEngine:
             out, self._picks_out = out
         return out
 
-    def _count_selection(self, extent: np.ndarray, real, pages: int) -> None:
+    def _count_selection(self, extent: np.ndarray, real, pages: int,
+                         decode: bool = False) -> None:
         """A model with an indexer: a call's query rows as its kernels tile
         them (padding rows and idle lanes too), ``extent`` one past each
         row's last visible position; ``real`` indexes the rows that are fed
         a token, each seeing that many keys, its own included; the ``pages``
         the call's attention walks; in every layer
-        (:data:`_SPARSE_COUNTERS`)."""
+        (:data:`_SPARSE_COUNTERS`). ``decode``: a call of one row a lane,
+        whose score programs walk the lanes' own key tiles."""
         cfg = self.cfg
         if not cfg.index_heads:
             return
@@ -729,6 +732,17 @@ class ServingEngine:
             c["sparse.topk_columns_sum"] += layers * int(
                 sparse_select.topk_columns(busy, Kp).sum())
             c["sparse.topk_columns_table_sum"] += layers * busy.size * Kp
+            if decode:
+                # ``sparse_index_scores`` by its own rule: the key tiles a
+                # lane's program copies and multiplies, beside lanes x the
+                # table's tiles (what a grid over the table launched)
+                first, end = sparse_select.score_tiles(
+                    extent - 1, extent, window, Kp, np)
+                c["sparse.score_tiles_sum"] += layers * int(
+                    (end - first).sum())
+                c["sparse.score_tiles_table_sum"] += layers * int(
+                    extent.size * sparse_select.score_tiles(
+                        Kp - 1, Kp, 0, Kp, np)[1])
 
     def _count_experts(self, out: np.ndarray, call: int) -> None:
         """A dropless MoE model's router load, from the fetched output of
@@ -1507,7 +1521,7 @@ class ServingEngine:
                       int((lanes.ctx * go // self.block_size + 1).sum()))
             rec.count("paged.table_pages_sum", B * self.nbk)
             self._count_selection(lanes.ctx * go + 1, go, int(
-                (lanes.ctx[go] // self.block_size + 1).sum()))
+                (lanes.ctx[go] // self.block_size + 1).sum()), decode=True)
             if self._windows.size:
                 # of those, what the window layers' calls walk, summed over
                 # those layers: from the page of a lane's first key in reach

@@ -215,28 +215,64 @@ def test_the_kernel_masks_what_the_reference_masks(shape, window):
     assert float(jnp.max(jnp.abs(want - dense))) > 0.1
 
 
-@pytest.mark.parametrize("window", [None, 20])
-def test_the_score_kernel_reads_its_pages_through_the_table(window):
+#: a lane's tokens before a decode call's own, over a table of 25 pages of 8
+#: (``Kp`` 256: two key tiles of 128); the last lane is idle (no key at all)
+_HELD = {"mid_page": 100, "mid_tile": 52, "on_a_tile": 127,
+         "first_of_a_tile": 128, "one_key": 0, "fills_the_table": 199,
+         "idle": 0}
+#: call -> (index heads, rows a lane, the lanes' first positions, contexts)
+_SCORE_CALLS = {
+    "chunk": (4, 12, [100, 30], [112, 37]),
+    # one row a lane: the decode-shaped program, 16 heads the rows of one
+    # matmul, a lane a row of ``[B, Kp]``, its own key tiles only
+    "a_row_a_lane": (16, 1, list(_HELD.values()),
+                     [h + 1 for h in _HELD.values()][:-1] + [0]),
+    # 2 to 8 rows a lane keep the chunk kernel's 8-row tiles
+    "five_rows_a_lane": (16, 5, [max(h - 4, 0) for h in _HELD.values()],
+                         [h + 1 for h in _HELD.values()][:-1] + [0]),
+}
+
+
+@pytest.mark.parametrize("window", [None, 20, 150])
+@pytest.mark.parametrize("call", list(_SCORE_CALLS))
+def test_the_score_kernel_reads_its_pages_through_the_table(call, window):
+    """Scores through a permuted block table, ``-inf`` exactly where the
+    reference has it (an idle lane and a padding lane everywhere), whatever
+    program the call's shape picks."""
     rng = np.random.default_rng(1)
-    B, H, T, D, bs, nbk, blocks, L = 2, 4, 12, 128, 8, 25, 60, 3
+    H, T, q0, ctx = _SCORE_CALLS[call]
+    B, D, bs, nbk, blocks, L = len(q0), 128, 8, 25, 200, 3
     qi = jnp.asarray(rng.standard_normal((B, H, T, D)), jnp.float32)
     w = jnp.asarray(rng.standard_normal((B, T, H)), jnp.float32)
     pool = jnp.asarray(rng.standard_normal((L, 1, blocks, bs, D)),
                        jnp.float32)
     bt = jnp.asarray(rng.permutation(np.arange(1, blocks))[:B * nbk].reshape(
         B, nbk), jnp.int32)
-    q0 = jnp.asarray([100, 30])
-    ctx = q0 + jnp.asarray([12, 7])
+    q0, ctx = jnp.asarray(q0), jnp.asarray(ctx)
     got = ss.index_scores(qi, w, pool, bt, 2, q0, ctx, window=window,
                           interpret=True)
     keys = pool.reshape(L * blocks, bs, D)[2 * blocks + bt].reshape(B, -1, D)
     want = ss.index_scores_reference(qi, w, keys, q0, ctx, window)
-    assert int(np.isfinite(want[0, 0]).sum()) == (101 if window is None
-                                                  else window)
+    Kp = ss.padded_keys(nbk * bs)
+    assert got.shape == want.shape == (B, T, Kp)
+    # each lane's first row sees the keys up to its own, inside the window
+    seen = np.minimum(np.minimum(q0 + 1, ctx), window or Kp)
+    assert np.array_equal(np.isfinite(want[:, 0]).sum(-1), seen)
     assert np.array_equal(np.isfinite(got), np.isfinite(want))
     np.testing.assert_allclose(np.where(np.isfinite(want), got, 0),
                                np.where(np.isfinite(want), want, 0),
                                rtol=1e-4, atol=1e-4)
+    if T == 1:
+        # the rule the host counts by: a lane's tiles hold every key it
+        # sees and no tile more than its context and window need
+        first, end = ss.score_tiles(np.asarray(q0), np.asarray(ctx),
+                                    window or 0, Kp, np)
+        tk = ss._key_tile(Kp)
+        cols = np.arange(Kp)[None]
+        inside = (cols >= first[:, None] * tk) & (cols < end[:, None] * tk)
+        assert not (np.isfinite(got[:, 0]) & ~inside).any()
+        assert (end - first <= -(-seen // tk) + 1).all() \
+            and end[-1] == first[-1]
 
 
 def _engine(cfg, params, **serving):
@@ -319,6 +355,39 @@ def test_the_topk_counters_follow_the_tiles(table_blocks):
     assert c["sparse.topk_columns_sum"] == 3 * busy * 128
     assert (c["sparse.topk_columns_sum"] < c["sparse.topk_columns_table_sum"]
             ) == (table_blocks > 16)
+    srv.close()
+
+
+def test_the_score_counters_follow_the_lanes_tiles():
+    """``sparse.score_tiles_*``: the key tiles a decode call's score
+    programs copy and multiply, by the kernel's own rule, beside lanes x
+    the table's tiles; a model without an indexer counts neither."""
+    cfg, params = built({**TINY, "max_position_embeddings": 512})
+    srv = _engine(cfg, params, max_blocks_per_seq=48, pool_blocks=120)
+    Kp, prompt, new = ss.padded_keys(48 * 8), 120, 12    # three tiles of 128
+    srv.submit(np.random.default_rng(5).integers(1, 64, size=prompt).tolist(),
+               max_new_tokens=new)
+    srv.run_until_idle()
+    c = dict(srv.telemetry()["counters"])
+    srv.close()
+    # the prefill's last chunk gives the first token, a decode call each of
+    # the others: one lane of 120 to 130 tokens beside three idle ones
+    held = np.zeros((new - 1, 4), np.int64)
+    held[:, 0] = np.arange(prompt, prompt + new - 1)
+    first, end = ss.score_tiles(held, held + 1, 0, Kp, np)
+    assert not first.any() and np.array_equal(
+        end[:, 0], 1 + (held[:, 0] >= 128)) and (end[:, 1:] == 1).all()
+    assert c["sparse.score_tiles_sum"] == 3 * int((end - first).sum())
+    assert c["sparse.score_tiles_table_sum"] == 3 * held.size * 3
+    model, plain = build_model(TransformerConfig(**{
+        **FAM.model_kwargs(TINY), "dtype": jnp.float32,
+        "attention_impl": "reference", "index_heads": 0, "index_head_dim": 0,
+        "index_topk": 0}))
+    srv = _engine(plain, make_params(model, plain, 11, jnp.float32))
+    srv.submit([3, 4, 5], max_new_tokens=3)
+    srv.run_until_idle()
+    assert not [k for k in srv.telemetry()["counters"]
+                if k.startswith("sparse.")]
     srv.close()
 
 
