@@ -1,8 +1,8 @@
-"""Shared newest-recorded-sweep discovery for the bench regression
-gates (COMMBENCH / SERVEBENCH / dryrun-timings convention): find the
-most recent JSON report in a directory whose ``{"n": device_count,
-"rows": [...]}`` document matches the current topology — sweeps from a
-different device count are skipped, their numbers aren't comparable."""
+"""Newest-recorded-sweep discovery for the collective sweep's regression
+gate (the COMMBENCH convention): find the most recent JSON report in a
+directory whose ``{"n": device_count, "rows": [...]}`` document matches the
+current topology — sweeps from a different device count are skipped, their
+numbers aren't comparable."""
 
 from __future__ import annotations
 

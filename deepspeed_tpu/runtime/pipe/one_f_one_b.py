@@ -15,7 +15,7 @@ activation memory ∝ stages, not microbatches. Two backward modes:
     jax.vjp and the residuals ride slot rings to the backward tick — no
     recompute (the reference's own store-outputs design,
     engine.py:630-781), at the cost of holding ~pp ticks of stage-internal
-    residuals live (benchmarks/pipeline_bench.py measures the trade).
+    residuals live.
 
 Generality (round-3 Missing #3 closed): per-micro side inputs (attention
 masks, dropout rng keys) ride along via ``extras``; MoE's load-balance aux

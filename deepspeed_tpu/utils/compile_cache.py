@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives — placed from OUTSIDE.
 
-Called by the measurement entry points (``chip_smoke.py``, ``bench.py``,
-the ``benchmarks/*`` mains) — never at package import and never in tests.
+Called by the measurement entry points (``chip_smoke.py``, the collective
+sweep's main) — never at package import and never in tests.
 Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX itself reads it and this
 sets nothing. Where it is not, the cache goes to ONE fixed directory inside
 the checkout: the directory is part of the cache key, so a temp name, a pid

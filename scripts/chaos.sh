@@ -22,7 +22,7 @@
 # the shared heartbeat channel, aborts rc 117, is struck and blacklisted
 # by DSElasticAgent with the degraded world resuming training; a
 # serve.replica_slow-degraded replica is drained exactly-once token-exact
-# and blacklisted on repeat, with the poisson_fleet_slow bench row.
+# and blacklisted on repeat.
 # Round 17 adds the low-precision training leg (tests/test_low_precision.py):
 # chaos grad spike on a sentinel-gated int8 fake-quant engine -> in-jit
 # skip + loss parity with the uninjected low-precision twin — the

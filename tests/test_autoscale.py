@@ -647,34 +647,3 @@ def test_procfleet_preempt_cancel_token_exact(tiny, tmp_path):
             assert req.state == FINISHED and req.output_tokens == oracle
     finally:
         flt.close()
-
-
-# tier-2 (round-19 budget): the cheaper tier-1 cousins are the thread
-# autoscale leg above and test_serving.test_inference_bench_poisson_line
-# (row plumbing); scripts/chaos.sh runs this leg
-@pytest.mark.slow
-def test_inference_bench_trace_autoscale_row(capsys):
-    """--poisson --trace prints the machine-readable poisson_autoscale
-    row: scale events, per-tier p99, and a clean drain back to the
-    floor."""
-    from deepspeed_tpu.benchmarks.inference_bench import (
-        parse_trace, run_poisson_autoscale)
-    trace = parse_trace("2@1.5,8@2,2@1.5")
-    row = run_poisson_autoscale(
-        "gpt2-tiny", trace, prompt_len=8, new_tokens=8,
-        serving={"block_size": 16, "pool_blocks": 64, "max_batch": 2,
-                 "max_blocks_per_seq": 8},
-        max_replicas=2,
-        model_kwargs={"hidden_size": 32, "num_layers": 2, "num_heads": 2,
-                      "vocab_size": 64, "attention_impl": "reference",
-                      "dtype": jnp.float32})
-    line = next(ln for ln in capsys.readouterr().out.splitlines()
-                if ln.startswith("inference_bench poisson_autoscale: "))
-    parsed = json.loads(line.split(": ", 1)[1])
-    assert parsed == row
-    assert row["mode"] == "poisson_autoscale"
-    assert row["burst_rate"] == 8.0 and row["rate"] == 2.0
-    assert row["completed"] == row["requests"] > 0
-    assert row["failed"] == 0 and row["timeout"] == 0
-    assert row["clean_drain"] is True
-    assert set(row["p99_by_tier"]) <= {"latency", "standard", "batch"}

@@ -11,7 +11,6 @@ or FAILED-within-retry-budget while the shared pool's free+refcounted
 accounting balances after recovery.
 """
 
-import json
 import time
 
 import numpy as np
@@ -456,36 +455,6 @@ def test_fleet_rejects_one_sided_disagg(tiny):
         model_parameters=params)
     with pytest.raises(ValueError):
         eng.serve()
-
-
-# ---------------------------------------------------------------------------
-# serving-bench record / newest-recorded-sweep regression units
-# ---------------------------------------------------------------------------
-
-def test_serve_bench_record_discovery_regression(tmp_path):
-    from deepspeed_tpu.benchmarks.inference_bench import (
-        check_serve_regression, latest_serve_bench, record_serve_bench)
-    rows = [{"mode": "poisson", "preset": "gpt2-125m", "rate": 4.0,
-             "prompt": 64, "new_tokens": 24, "chunk": 0,
-             "p50_s": 0.5, "p99_s": 0.9, "tokens_per_s": 120.0}]
-    path = tmp_path / "SERVEBENCH_r01.json"
-    record_serve_bench(rows, str(path))
-    name, base = latest_serve_bench(str(tmp_path), jax.device_count())
-    assert name == "SERVEBENCH_r01.json" and len(base) == 1
-    # p50 blow-up and tokens/s collapse both flag; a mild change doesn't
-    assert check_serve_regression([dict(rows[0], p50_s=2.0)], base)
-    assert check_serve_regression([dict(rows[0], tokens_per_s=10.0)], base)
-    assert not check_serve_regression([dict(rows[0], p50_s=0.6)], base)
-    # a different-rate row is a different cell: not compared
-    assert not check_serve_regression([dict(rows[0], rate=8.0,
-                                            p50_s=5.0)], base)
-    # sweeps from another device count are skipped
-    other = tmp_path / "SERVEBENCH_r02.json"
-    other.write_text(json.dumps({"n": 4096, "rows": rows}))
-    import os
-    os.utime(other, (time.time() + 60, time.time() + 60))
-    name2, _ = latest_serve_bench(str(tmp_path), jax.device_count())
-    assert name2 == "SERVEBENCH_r01.json"
 
 
 # ---------------------------------------------------------------------------

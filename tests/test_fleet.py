@@ -223,37 +223,6 @@ def test_fleet_supervisor_verdict_units():
     assert sup0._verdict(rep, stale, now) is None
 
 
-# tier-2 (round-17 budget sweep, ~10s): the cheaper tier-1 cousin is
-# test_serving.test_inference_bench_poisson_line (same row plumbing,
-# single engine); the slow-replica fleet row rides
-# test_inference_bench_poisson_fleet_slow_replica_row in tier2
-@pytest.mark.slow
-def test_inference_bench_poisson_fleet_line(capsys):
-    """--poisson --fleet N failure-injection leg prints the
-    machine-readable degraded-throughput row (tokens/s before / during /
-    after a replica loss) in the poisson:/comm_bench: convention."""
-    import json
-    from deepspeed_tpu.benchmarks.inference_bench import run_poisson_fleet
-    row = run_poisson_fleet(
-        "gpt2-tiny", rate=100.0, num_requests=10, prompt_len=24,
-        new_tokens=5, replicas=2,
-        serving={"block_size": 16, "pool_blocks": 32, "max_batch": 2,
-                 "max_blocks_per_seq": 8,
-                 "fleet": {"heartbeat_timeout": 60.0}},
-        model_kwargs=dict(hidden_size=32, num_layers=2, num_heads=2,
-                          vocab_size=64, attention_impl="reference"))
-    line = [ln for ln in capsys.readouterr().out.splitlines()
-            if ln.startswith("inference_bench poisson_fleet: ")]
-    assert line, "machine-readable poisson_fleet line missing"
-    parsed = json.loads(line[0].split("inference_bench poisson_fleet: ",
-                                      1)[1])
-    for key in ("tps_before", "tps_during", "tps_after", "deaths",
-                "requeues", "p50_s", "p99_s", "replicas"):
-        assert key in parsed and parsed[key] == row[key]
-    assert row["deaths"] == 1 and row["completed"] == 10
-    assert row["failed"] == 0 and row["replicas"] == 2
-
-
 @pytest.mark.slow
 def test_fleet_straggler_drain_requeues_token_exact(tiny):
     """Acceptance (round 15): a serve.replica_slow-DEGRADED replica —
@@ -492,38 +461,6 @@ def test_fleet_straggler_blacklist_flag_health_visible(tiny):
         assert "STRAGGLER" in rec["flags"]
     finally:
         flt.close()
-
-
-@pytest.mark.slow
-def test_inference_bench_poisson_fleet_slow_replica_row(capsys):
-    """--poisson --fleet N --slow-replica: the degraded-throughput row
-    (tps before/during/after + drain/recovery stamps) in the SERVEBENCH
-    newest-recorded-sweep convention."""
-    import json
-    from deepspeed_tpu.benchmarks.inference_bench import run_poisson_fleet
-    # enough queued work that the victim provably holds lanes when the
-    # slowness lands AND while detection converges (a too-small run
-    # finishes before a 150ms-degraded replica ever shows in the gauges)
-    row = run_poisson_fleet(
-        "gpt2-tiny", rate=200.0, num_requests=48, prompt_len=24,
-        new_tokens=6, replicas=2, slow_replica=True, slow_ms=150,
-        serving={"block_size": 16, "pool_blocks": 32, "max_batch": 2,
-                 "max_blocks_per_seq": 8,
-                 "fleet": {"heartbeat_timeout": 60.0}},
-        model_kwargs=dict(hidden_size=32, num_layers=2, num_heads=2,
-                          vocab_size=64, attention_impl="reference"))
-    line = [ln for ln in capsys.readouterr().out.splitlines()
-            if ln.startswith("inference_bench poisson_fleet_slow: ")]
-    assert line, "machine-readable poisson_fleet_slow line missing"
-    parsed = json.loads(
-        line[0].split("inference_bench poisson_fleet_slow: ", 1)[1])
-    for key in ("tps_before", "tps_during", "tps_after", "slow_at_s",
-                "drained_at_s", "recovered_at_s", "deaths", "requeues"):
-        assert key in parsed and parsed[key] == row[key]
-    assert row["mode"] == "poisson_fleet_slow"
-    assert row["deaths"] == 1 and row["completed"] == 48
-    assert row["failed"] == 0 and row["kill_at_s"] is None
-    assert row["drained_at_s"] >= row["slow_at_s"]
 
 
 @pytest.mark.slow

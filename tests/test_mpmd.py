@@ -10,6 +10,8 @@ import json
 import os
 import re
 import sys
+import time
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -492,11 +494,37 @@ def _collect_losses(log_path):
     return losses
 
 
+def _start_last_stage_first(sup):
+    """Start the stages from the last to the first, each after the one
+    downstream of it has said hello. The driver's router drops a frame
+    whose destination is not connected (``driver._serve_conn``), so a
+    stage 0 that reaches its first send before stage 1's hello loses
+    micro 0's activation: both sit out the 60 s transfer barrier, stage 0
+    exits 117 and the run ends rc 0 with ``restarts == [1, 0]``. Which
+    worker wins is the machine's load (ROADMAP D13); this makes the wait
+    explicit. No stage sends upstream before a frame from there reached
+    it, so the other direction cannot race."""
+    with mock.patch.object(sup, "_spawn"):
+        sup.start()                 # the server and its router, no worker
+    try:
+        for s in reversed(range(sup.pp)):
+            sup._spawn(s)
+            deadline = time.monotonic() + 180.0
+            while s not in sup.conns:
+                assert sup.procs[s].poll() is None, f"stage {s} exited"
+                assert time.monotonic() < deadline, f"stage {s}: no hello"
+                time.sleep(0.02)
+    except BaseException:
+        sup._teardown()
+        raise
+
+
 def _run_driver(workdir, steps=6, specs=None, **kw):
     sup = MPMDStageSupervisor(2, workdir=os.path.join(workdir, "wd"),
                               steps=steps, n_micro=4, schedule="1f1b",
                               log_dir=os.path.join(workdir, "logs"),
                               specs=specs, **kw)
+    _start_last_stage_first(sup)
     rc = sup.run()
     losses = _collect_losses(os.path.join(workdir, "logs", "stage1.log"))
     return rc, losses, sup

@@ -672,48 +672,3 @@ def test_pipelined_llama_family_gpipe_and_1f1b():
             "gpt2-tiny", pp=2, n_micro=2, hidden_size=64, num_layers=2,
             num_heads=4, vocab_size=256, max_seq_len=64,
             layer_windows=(8, 8))
-
-
-# -- pipe_bench placement rows (round 13) -------------------------------------
-
-def test_pipe_bench_discovery_and_regression(tmp_path):
-    """The pipe_bench rows ride the shared newest-recorded-sweep
-    convention: device-count-filtered discovery, per-cell >2x wall
-    regression detection, null SPMD cells (shard_map-less hosts)
-    compared only when both sweeps carry one."""
-    import json
-    from deepspeed_tpu.benchmarks.pipeline_bench import (
-        check_pipe_regression, latest_pipe_bench)
-
-    row = {"pp": 2, "n_micro": 4, "hidden": 64, "layers": 4, "seq": 64,
-           "mb": 2, "spmd_step_s": None, "mpmd_step_s": 0.2,
-           "bubble_theory": 0.2, "bubble_1f1b_measured": 0.43}
-    (tmp_path / "PIPEBENCH_r01.json").write_text(
-        json.dumps({"n": 8, "rows": [row]}))
-    # other-device-count sweeps are skipped
-    (tmp_path / "PIPEBENCH_r02.json").write_text(
-        json.dumps({"n": 2, "rows": [dict(row, mpmd_step_s=9.9)]}))
-    name, rows = latest_pipe_bench(str(tmp_path), n_devices=8)
-    assert name == "PIPEBENCH_r01.json" and rows == [row]
-
-    ok = dict(row, mpmd_step_s=0.3)
-    assert check_pipe_regression([ok], rows) == []
-    bad = dict(row, mpmd_step_s=0.5)
-    msgs = check_pipe_regression([bad], rows)
-    assert len(msgs) == 1 and "mpmd_step_s" in msgs[0]
-    # a null spmd cell on either side never trips the gate
-    both_null = dict(row, spmd_step_s=None)
-    assert check_pipe_regression([both_null], [row]) == []
-    # unknown cells (new config) are not regressions
-    assert check_pipe_regression([dict(row, pp=4)], rows) == []
-
-
-def test_repo_has_recorded_pipe_sweep():
-    """PIPEBENCH_r01 anchors the convention (a CPU-host record whose SPMD
-    cell is null; the regression gate ignores a null cell)."""
-    import os
-    from deepspeed_tpu.benchmarks.pipeline_bench import latest_pipe_bench
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    name, rows = latest_pipe_bench(repo)
-    assert name and rows
-    assert all("mpmd_step_s" in r for r in rows)

@@ -456,27 +456,6 @@ def test_init_inference_serve_entry(tiny):
     assert out[0] == _oracle_tokens(cfg, params, [3, 1, 4, 1, 5], 4)
 
 
-def test_inference_bench_poisson_line(capsys):
-    """The Poisson load leg drives the serving loop and prints the
-    machine-readable p50/p99 line (acceptance criterion)."""
-    import json
-    from deepspeed_tpu.benchmarks.inference_bench import run_poisson
-    row = run_poisson(
-        "gpt2-tiny", rate=200.0, num_requests=5, prompt_len=24,
-        new_tokens=4,
-        serving={"block_size": 16, "pool_blocks": 32, "max_batch": 4,
-                 "max_blocks_per_seq": 8},
-        model_kwargs=dict(hidden_size=32, num_layers=2, num_heads=2,
-                          vocab_size=64, attention_impl="reference"))
-    line = [ln for ln in capsys.readouterr().out.splitlines()
-            if ln.startswith("inference_bench poisson: ")]
-    assert line, "machine-readable poisson line missing"
-    parsed = json.loads(line[0].split("inference_bench poisson: ", 1)[1])
-    for key in ("p50_s", "p99_s", "tokens_per_s_per_chip", "rate"):
-        assert key in parsed and parsed[key] == row[key]
-    assert 0 < row["p50_s"] <= row["p99_s"]
-
-
 @pytest.mark.slow
 def test_serving_arch_matrix_token_exact():
     """Heavier matrix: ALiBi+softcap (Gemma/BLOOM-class), sliding window,
