@@ -136,6 +136,19 @@ def init_cache(cfg: TransformerConfig, batch_size: int, max_len: int,
     dequantizes on read. Capability slot of the reference's int8
     inference kernel family (csrc/transformer/inference ds_*_int8)."""
     dtype = dtype or cfg.dtype
+    if cfg.kv_lora_rank:
+        # a latent model: ONE row a token and layer, the normed latent and
+        # the rotated shared key, no heads and no k / v
+        if dtype == jnp.int8:
+            raise ValueError(
+                "an int8 KV cache with latent attention (kv_lora_rank): the "
+                "latent row has no quantized format (ROADMAP M4)")
+        cache = {"ckv": jnp.zeros((cfg.num_layers, batch_size, 1, max_len,
+                                   cfg.latent_width), dtype),
+                 "pos": jnp.zeros((), jnp.int32)}
+        if pad_lens is not None:
+            cache["pad"] = jnp.asarray(pad_lens, jnp.int32)
+        return cache
     shape = (cfg.num_layers, batch_size, cfg.num_heads, max_len, cfg.head_dim)
     cache = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
              "pos": jnp.zeros((), jnp.int32)}
@@ -204,7 +217,8 @@ def _moe_mlp(cfg: TransformerConfig, p_moe, h, interpret: bool = False,
             kernel_of=lambda p: _kernel_of(p, h.dtype), interpret=interpret,
             layer=layer, scores=cfg.moe_scores,
             select_bias=p_moe["gate"].get("bias"),
-            scale=cfg.moe_routed_scale, held=cfg.moe_held)
+            scale=cfg.moe_routed_scale, held=cfg.moe_held,
+            groups=cfg.moe_group_limit)
         if cfg.moe_shared_dim:
             # the shared expert: the experts' body on every token, unweighted
             with jax.named_scope("shared"):
@@ -240,10 +254,37 @@ def _moe_mlp(cfg: TransformerConfig, p_moe, h, interpret: bool = False,
     return y.reshape(B, T, H), None
 
 
+def latent_weights(cfg: TransformerConfig, p_kv_b, dtype):
+    """``attn_kv_b`` ``[rank, heads x (nope + v)]`` as ``(wk [heads, rank,
+    nope], wv [heads, rank, v])``: what expands a latent to a head's
+    unrotated key and its value, or folds into the query and the output."""
+    w = _kernel_of(p_kv_b, dtype).reshape(
+        cfg.kv_lora_rank, cfg.num_heads,
+        cfg.qk_nope_head_dim + cfg.v_head_dim).transpose(1, 0, 2)
+    return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+
+
+def absorb_query(q_nope, q_pe, wk, lanes: int):
+    """The absorbed query ``[B, heads, T, lanes]``: ``[q_nope Wk[h] | q_pe |
+    zeros]``, a row as the cache stores a token's (``q_nope . (c Wk[h]) ==
+    (q_nope Wk[h]^T) . c``)."""
+    q_abs = jnp.einsum("bhtd,hcd->bhtc", q_nope, wk)
+    q = jnp.concatenate([q_abs, q_pe], axis=-1)
+    return jnp.pad(q, [(0, 0)] * 3 + [(0, lanes - q.shape[-1])])
+
+
+def absorb_output(o_latent, wv):
+    """``[B, heads, T, rank]`` attended latents through each head's value
+    matrix: ``(a c) Wv[h] == a (c Wv[h])``."""
+    return jnp.einsum("bhtc,hcd->bhtd", o_latent, wv)
+
+
 def attention_constants(cfg: TransformerConfig):
     """``(softmax scale, ALiBi slopes [nh] or None)`` of a config."""
     sm_scale = (cfg.attn_scale if cfg.attn_scale is not None
                 else 1.0 / np.sqrt(cfg.head_dim))
+    if cfg.rope_scaling_type == "yarn":
+        sm_scale = cfg.softmax_scale        # YaRN's mscale squared in it
     slopes = (jnp.asarray(alibi_slopes(cfg.num_heads), jnp.float32)
               if cfg.pos_embed == "alibi" else None)
     return sm_scale, slopes
@@ -341,11 +382,10 @@ def decoder_forward(cfg: TransformerConfig, params: PyTree,
     cache.plan(T)
     real = cache.real_tokens(pos) if expert_counts else None
 
-    def layer(carry, xs, dense_mlp=False):
-        """One layer; ``dense_mlp``: one of a mixture's leading dense
-        layers (its MLP is ``p``'s own, whatever the width)."""
-        x, kv = carry
-        p, window, rope, li = xs
+    def heads_attention(x, kv, p, window, rope, li):
+        """The attention branch over per-head K/V: ``(the cache, the
+        branch's output, the normed input, the layer's selection or
+        None)``."""
         with jax.named_scope("block.attn"):
             with jax.named_scope("qkv"):
                 h = norm(x, p["ln1"]) if cfg.pre_norm else x
@@ -410,6 +450,50 @@ def decoder_forward(cfg: TransformerConfig, params: PyTree,
                 if cfg.post_block_norms:
                     # Gemma-2 sandwich: norm each branch output pre-residual
                     attn_out = norm(attn_out, p["post_attn_norm"])
+        return kv, attn_out, h, sel
+
+    def latent_attention(x, kv, p, li):
+        """The attention branch of a latent model (``cfg.kv_lora_rank``):
+        the token's one latent row goes to the cache, and what attends over
+        the cached rows, absorbed, is the cache's to answer
+        (``attend_latent``; ``ops/pallas/latent_attention.py``). ``(the
+        cache, the branch's output)``."""
+        rank, nope, vw = cfg.kv_lora_rank, cfg.qk_nope_head_dim, \
+            cfg.v_head_dim
+        rms_ = lambda t, q: _layer_norm(t, q, cfg.layer_norm_eps, rms=True)
+        with jax.named_scope("block.attn"):
+            with jax.named_scope("latent_q"):
+                h = norm(x, p["ln1"])
+                q = dense(rms_(dense(h, p["attn_q_a"]), p["q_a_norm"]),
+                          p["attn_q_b"]).reshape(B, T, nh, hd).transpose(
+                    0, 2, 1, 3)
+                ckv = dense(h, p["attn_kv_a"])               # [B, T, rank+rope]
+                c = rms_(ckv[..., :rank], p["kv_a_norm"])
+                rot = partial(apply_rotary, positions=pos, rotary_dim=None,
+                              interleaved=cfg.rotary_interleaved,
+                              theta=cfg.rope_theta,
+                              inv_freq=cfg.rope_inv_freq(cache.rope_len))
+                q_nope, q_pe = q[..., :nope], rot(q[..., nope:])
+                k_pe = rot(ckv[:, None, :, rank:])[:, 0]     # ONE head
+            with jax.named_scope("latent_write"):
+                kv = cache.write_latent(
+                    kv, li, jnp.concatenate([c, k_pe], axis=-1))
+            wk, wv = latent_weights(cfg, p["attn_kv_b"], x.dtype)
+            o = cache.attend_latent(kv, li, q_nope, q_pe, wk, wv)
+            with jax.named_scope("out"):
+                attn_out = dense(o.transpose(0, 2, 1, 3).reshape(
+                    B, T, nh * vw), p["attn_proj"])
+        return kv, attn_out
+
+    def layer(carry, xs, dense_mlp=False):
+        """One layer; ``dense_mlp``: one of a mixture's leading dense
+        layers (its MLP is ``p``'s own, whatever the width)."""
+        x, kv = carry
+        p, window, rope, li = xs
+        if cfg.kv_lora_rank:
+            (kv, attn_out), h, sel = latent_attention(x, kv, p, li), None, None
+        else:
+            kv, attn_out, h, sel = heads_attention(x, kv, p, window, rope, li)
 
         def mlp(hin):
             """``(the MLP branch, (this layer's expert counts or None, its
@@ -436,9 +520,16 @@ def decoder_forward(cfg: TransformerConfig, params: PyTree,
                 if not expert_counts:
                     return y, (None, picks)
                 with jax.named_scope("route"):
-                    return y, (jnp.zeros((cfg.moe_experts,), jnp.int32).at[
+                    counts = jnp.zeros((cfg.moe_experts,), jnp.int32).at[
                         routing.experts.reshape(-1)].add(
-                        jnp.repeat(real.reshape(-1), cfg.moe_k)), picks)
+                        jnp.repeat(real.reshape(-1), cfg.moe_k))
+                    if routing.groups is not None:
+                        # a grouped router: behind the experts' counts, how
+                        # many real rows kept each group
+                        counts = jnp.concatenate([counts, jnp.sum(
+                            routing.groups * real.reshape(-1, 1), axis=0,
+                            dtype=jnp.int32)])
+                    return y, (counts, picks)
             if cfg.gated_mlp:            # SwiGLU (Llama family)
                 g = act(dense(hin, p["mlp_gate"]))
                 return (dense(g * dense(hin, p["mlp_fc"]), p["mlp_proj"]),
@@ -504,8 +595,9 @@ class DenseCache:
 
     def __init__(self, cfg: TransformerConfig, cache: Dict, prefill_flash):
         self.cfg, self.cache, self.prefill_flash = cfg, cache, prefill_flash
-        self.quantized = cache["k"].dtype == jnp.int8
-        self.rope_len = cache["k"].shape[3]
+        rows = cache["ckv" if cfg.kv_lora_rank else "k"]
+        self.quantized = rows.dtype == jnp.int8
+        self.rope_len = rows.shape[3]
         self.sm_scale, self.slopes = attention_constants(cfg)
 
     def positions(self, T: int):
@@ -522,6 +614,7 @@ class DenseCache:
         # caches need the masked read: those keep the jnp path
         self.flash = (bool(self.prefill_flash) and T > 1 and pad is None
                       and not self.quantized and not cfg.index_heads
+                      and not cfg.kv_lora_rank
                       and cfg.uniform_window() is not None
                       and cfg.attention_impl in ("auto", "flash")
                       and (jax.default_backend() == "tpu"
@@ -569,6 +662,31 @@ class DenseCache:
 
     def finish(self, carry, T: int):
         return {**self.cache, **carry, "pos": self.cache["pos"] + T}
+
+    def write_latent(self, kv, li, row):
+        """A latent model's one row a token, ``[B, T, latent_width]``."""
+        return {**kv, "ckv": jax.lax.dynamic_update_slice(
+            kv["ckv"], row.astype(kv["ckv"].dtype)[None, :, None],
+            (li, 0, 0, self.cache["pos"], 0))}
+
+    def attend_latent(self, kv, li, q_nope, q_pe, wk, wv):
+        """Latent attention over the whole preallocated buffer, absorbed
+        (the dense-masked form; ``attend``'s f32 scores and -1e30 masks):
+        ``[B, heads, T, v_head_dim]``."""
+        rank = self.cfg.kv_lora_rank
+        rows = jax.lax.dynamic_index_in_dim(kv["ckv"], li, 0,
+                                            keepdims=False)[:, 0]
+        with jax.named_scope("absorb"):
+            q = absorb_query(q_nope, q_pe, wk, rows.shape[-1])
+        with jax.named_scope("attend"):
+            s = jnp.einsum("bhtw,bkw->bhtk", q, rows).astype(jnp.float32)
+            m = self.mask
+            s = jnp.where(m[:, None] if m.ndim == 3 else m[None, None],
+                          s * self.sm_scale, -1e30)
+            prob = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+            o = jnp.einsum("bhtk,bkc->bhtc", prob, rows[..., :rank])
+        with jax.named_scope("absorb"):
+            return absorb_output(o, wv)
 
     def _per_query_head(self, t):
         """GQA: the buffers hold a row a QUERY head, so K/V (and the int8

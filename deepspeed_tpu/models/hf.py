@@ -887,14 +887,19 @@ def _load_hf_llama_family(model_or_state_dict, config,
     # scaled RoPE (Llama-3.1+ / linear PI / dynamic NTK): mapped onto the
     # static rope_scaling_* config knobs (TransformerConfig.rope_inv_freq
     # mirrors HF modeling_rope_utils token-exactly). Genuinely unsupported
-    # geometries (yarn / longrope) still fail HERE, not decode garbage.
+    # geometries still fail HERE, not decode garbage: longrope (per-dimension
+    # factor lists) has no table, and yarn (TransformerConfig has its table
+    # and softmax scale since PR 49, for models built from a config: the
+    # benchmark's deepseek_v2 family) is not mapped by this importer yet,
+    # whose policies carry no latent-attention checkpoint layout.
     scaling = getattr(config, "rope_scaling", None) or {}
     rope_type = scaling.get("rope_type", scaling.get("type", "default"))
     if rope_type not in ("default", "linear", "dynamic", "llama3"):
         raise NotImplementedError(
-            f"rope_scaling type {rope_type!r} is not implemented "
-            "(yarn / longrope): loading with plain rope_theta would "
-            "produce wrong frequencies")
+            f"rope_scaling type {rope_type!r} is not imported (longrope "
+            "has no table; yarn's is TransformerConfig.rope_scaling_type="
+            "'yarn', which no import policy maps yet): loading with plain "
+            "rope_theta would produce wrong frequencies")
     rope_kwargs = {}
     if rope_type != "default":
         # "factor" is mandatory for every scaled type (HF raises KeyError

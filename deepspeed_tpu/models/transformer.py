@@ -126,6 +126,18 @@ class TransformerConfig:
     rope_low_freq_factor: float = 1.0         # llama3 only
     rope_high_freq_factor: float = 4.0        # llama3 only
     rope_original_max_position: int = 0       # 0 = max_seq_len
+    # "yarn" (HF _compute_yarn_parameters / DeepSeek-V2's
+    # DeepseekV2YarnRotaryEmbedding): frequencies above beta_fast turns of
+    # the original window keep theirs, below beta_slow are divided by the
+    # factor, a linear ramp between; the softmax scale is multiplied by
+    # mscale(factor, rope_mscale_all_dim)^2, mscale(s, m) = 0.1 m ln s + 1
+    # (0 = none). cos/sin are scaled by mscale(factor, rope_mscale) /
+    # mscale(factor, rope_mscale_all_dim), which no path carries unless it
+    # is 1 (the two equal: DeepSeek-V2's 0.707 / 0.707)
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 0.0
+    rope_mscale_all_dim: float = 0.0
     # decoupled head_dim (Mistral-Nemo/Gemma style): attention head width
     # independent of hidden_size/num_heads; qkv projects to
     # (nh + 2*kv) * head_dim and attn_proj maps nh*head_dim back to H
@@ -181,6 +193,13 @@ class TransformerConfig:
     # ``count`` experts, a pick of an absent one keeps its weight in the
     # renormalisation and computes nothing. None = all of them
     moe_held: Optional[Tuple[int, int]] = None
+    # group-limited selection (DeepSeek-V2's ``group_limited_greedy``): the
+    # router's outputs lie in moe_groups groups of equal size, a token keeps
+    # the moe_topk_groups groups whose best score is largest and picks its
+    # moe_k among their experts only. 1 = no groups. ``moe_held`` is then
+    # whole groups (device-limited routing: a group a device)
+    moe_groups: int = 1
+    moe_topk_groups: int = 1
     # width of a shared expert (``moe/shared``: a SwiGLU / MLP of the
     # experts' kind on EVERY token, added unweighted); 0 = none
     moe_shared_dim: int = 0
@@ -205,6 +224,23 @@ class TransformerConfig:
     index_heads: int = 0
     index_head_dim: int = 0
     index_topk: int = 0
+    # multi-head latent attention (DeepSeek-V2's MLA; kv_lora_rank 0 = none).
+    # A token's keys and values are ONE normed latent of kv_lora_rank and one
+    # rotated key of qk_rope_head_dim shared by all heads (``attn_kv_a`` +
+    # ``kv_a_norm``); ``attn_kv_b`` [kv_lora_rank, heads x (qk_nope_head_dim +
+    # v_head_dim)] expands the latent to a head's unrotated key and its value.
+    # The query is ``attn_q_b(rms(attn_q_a(h)))`` through q_lora_rank, a head
+    # qk_nope_head_dim | qk_rope_head_dim wide. The caches store the latent
+    # row and nothing a head (``latent_lanes``); attention runs absorbed
+    # (``attn_kv_b`` folded into the query and the output: the heads are rows
+    # over the one stored row; models/generation.py,
+    # ops/pallas/latent_attention.py). The inference decoder only: a training
+    # Block with these set refuses (ROADMAP M4)
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # block-sparse attention layout (ds_config "sparse_attention" section;
     # the engine wires it here and sets attention_impl="sparse"): a hashable
     # tuple of (key, value) items — lists as tuples — so the frozen config
@@ -245,6 +281,7 @@ class TransformerConfig:
             ("moe_select_bias", self.moe_select_bias),
             ("moe_routed_scale", self.moe_routed_scale != 1.0),
             ("moe_held", self.moe_held is not None),
+            ("moe_groups", self.moe_groups != 1),
             ("moe_shared_dim", self.moe_shared_dim > 0)) if on]
         if routed and not self.moe_is_dropless:
             raise ValueError(
@@ -259,6 +296,27 @@ class TransformerConfig:
                 raise ValueError(
                     f"moe_held {self.moe_held}: (first, count) inside the "
                     f"router's {self.moe_experts} experts")
+        if self.moe_groups != 1 or self.moe_topk_groups != 1:
+            size = self.moe_experts // max(self.moe_groups, 1)
+            if not (0 < self.moe_topk_groups <= self.moe_groups
+                    and self.moe_experts % self.moe_groups == 0
+                    and self.moe_topk_groups * size >= self.moe_k):
+                raise ValueError(
+                    f"moe_groups {self.moe_groups}, moe_topk_groups "
+                    f"{self.moe_topk_groups}: the router's {self.moe_experts}"
+                    " outputs in equal groups, of which a token keeps enough "
+                    f"to hold its {self.moe_k} picks")
+            if self.moe_select_bias:
+                raise ValueError(
+                    "moe_groups with moe_select_bias: the grouped router "
+                    "ranks a group by its best SCORE (group_limited_greedy);"
+                    " a bias on the selection (DeepSeek-V3's noaux_tc, "
+                    "groups by their two best biased scores) is not carried")
+            if self.moe_held is not None and (self.moe_held[0] % size
+                                              or self.moe_held[1] % size):
+                raise ValueError(
+                    f"moe_held {self.moe_held}: with moe_groups the share "
+                    f"held is whole groups of {size} experts")
         if self.dense_layers:
             if not (0 < self.dense_layers < self.num_layers
                     and self.moe_experts > 0 and self.dense_mlp_dim):
@@ -290,6 +348,53 @@ class TransformerConfig:
                     "an indexer selects the keys of a causal decoder (not "
                     "post_ln), its head width is even (rotary), and its "
                     "rotary table is the plain one (no rope_scaling_type)")
+        if self.rope_scaling_type == "yarn":
+            if self.rope_scaling_factor < 1.0 \
+                    or self.rope_beta_fast <= self.rope_beta_slow:
+                raise ValueError(
+                    "rope_scaling_type 'yarn': rope_scaling_factor >= 1 and "
+                    "rope_beta_fast > rope_beta_slow")
+            if self.rope_mscale != self.rope_mscale_all_dim:
+                raise ValueError(
+                    f"rope_mscale {self.rope_mscale} != rope_mscale_all_dim "
+                    f"{self.rope_mscale_all_dim}: YaRN then scales cos and "
+                    "sin by mscale(factor, rope_mscale) / mscale(factor, "
+                    "rope_mscale_all_dim), which no rotary path carries "
+                    "(equal, the ratio is 1 and only the softmax scale "
+                    "moves)")
+        latent = (self.kv_lora_rank, self.q_lora_rank, self.qk_nope_head_dim,
+                  self.qk_rope_head_dim, self.v_head_dim)
+        if any(latent):
+            if min(latent) <= 0 or self.qk_rope_head_dim % 2:
+                raise ValueError(
+                    f"latent attention has all five sizes, each > 0 "
+                    f"(kv_lora_rank, q_lora_rank, qk_nope_head_dim, "
+                    f"qk_rope_head_dim (even), v_head_dim): got {latent}")
+            refused = [why for why, on in (
+                ("rotary positions (pos_embed='rotary'; ALiBi and learned "
+                 "positions have no rotated shared key)",
+                 self.pos_embed != "rotary"),
+                ("no sliding window (layer_windows)",
+                 self.layer_windows is not None),
+                ("no indexer beside it (index_heads)", self.index_heads > 0),
+                ("one stored row a token, no KV heads (num_kv_heads)",
+                 self.num_kv_heads not in (None, self.num_heads)),
+                ("a causal pre-norm decoder without q/k norms, softcap, "
+                 "parallel residual or per-layer rotary",
+                 not self.causal or self.post_ln or not self.pre_norm
+                 or bool(self.qk_norm) or bool(self.attn_softcap)
+                 or self.parallel_residual or self.layer_rope is not None),
+                ("RMSNorm and no biases (its latent norms are RMSNorms)",
+                 self.norm != "rmsnorm" or self.use_bias
+                 or bool(self.qkv_bias) or bool(self.attn_out_bias)),
+                ("rotary_dim 0 or qk_rope_head_dim",
+                 self.rotary_dim not in (0, self.qk_rope_head_dim)),
+                ("head_dim_override unset (a head is qk_nope_head_dim + "
+                 "qk_rope_head_dim wide)",
+                 self.head_dim_override is not None)) if on]
+            if refused:
+                raise ValueError("latent attention (kv_lora_rank) needs "
+                                 + "; ".join(refused))
         if not self.pre_norm and (not self.post_block_norms or self.post_ln
                                   or self.parallel_residual):
             raise ValueError(
@@ -312,6 +417,12 @@ class TransformerConfig:
         return self.moe_held[1] if self.moe_held else self.moe_experts
 
     @property
+    def moe_group_limit(self) -> Optional[Tuple[int, int]]:
+        """``(moe_groups, moe_topk_groups)`` of a grouped router, else None."""
+        return (self.moe_groups, self.moe_topk_groups) \
+            if self.moe_groups > 1 else None
+
+    @property
     def sparse_layers(self) -> int:
         """Layers whose MLP is the mixture (all of them, or none)."""
         return self.num_layers - self.dense_layers if self.moe_experts else 0
@@ -320,7 +431,35 @@ class TransformerConfig:
     def head_dim(self) -> int:
         if self.head_dim_override is not None:
             return self.head_dim_override
+        if self.kv_lora_rank:       # a latent model's query and key head
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.hidden_size // self.num_heads
+
+    @property
+    def latent_width(self) -> int:
+        """What a latent model caches a token and layer: the normed latent
+        and the rotated shared key (0: no latent attention)."""
+        return self.kv_lora_rank + self.qk_rope_head_dim \
+            if self.kv_lora_rank else 0
+
+    @property
+    def latent_lanes(self) -> int:
+        """:attr:`latent_width` in whole 128-lane rows, as the paged pool
+        stores it (``serving/kv_cache.init_pool`` says why)."""
+        return -(-self.latent_width // 128) * 128
+
+    @property
+    def softmax_scale(self) -> float:
+        """``attn_scale`` or ``head_dim ** -0.5``, times YaRN's
+        ``mscale(factor, rope_mscale_all_dim) ** 2``."""
+        scale = (self.attn_scale if self.attn_scale is not None
+                 else 1.0 / float(np.sqrt(self.head_dim)))
+        if self.rope_scaling_type == "yarn" and self.rope_mscale_all_dim \
+                and self.rope_scaling_factor > 1.0:
+            m = 0.1 * self.rope_mscale_all_dim * float(
+                np.log(self.rope_scaling_factor)) + 1.0
+            scale = scale * m * m
+        return scale
 
     def uniform_window(self) -> Optional[int]:
         """The single static window every layer shares, when layer_windows
@@ -353,7 +492,7 @@ class TransformerConfig:
             return None
         # float32 arithmetic end-to-end: HF computes these tables in
         # torch.float32, and parity is checked token-exact
-        rd = self.rotary_dim or self.head_dim
+        rd = self.qk_rope_head_dim or self.rotary_dim or self.head_dim
         inv = 1.0 / (self.rope_theta ** (np.arange(0, rd, 2,
                                                    dtype=np.float32) / rd))
         f = self.rope_scaling_factor
@@ -379,10 +518,22 @@ class TransformerConfig:
             smoothed = (1.0 - smooth) * inv_l / f + smooth * inv_l
             is_medium = (wavelen >= high_wl) & (wavelen <= low_wl)
             inv = np.where(is_medium, smoothed, inv_l)
+        elif t == "yarn":
+            # the dimension whose wavelength makes `turns` turns over the
+            # original window; between the two corrections a linear ramp
+            # from the model's own frequency to the interpolated one
+            at = lambda turns: rd * np.log(orig / (turns * 2.0 * np.pi)) / (
+                2.0 * np.log(self.rope_theta))
+            low = max(int(np.floor(at(self.rope_beta_fast))), 0)
+            high = min(int(np.ceil(at(self.rope_beta_slow))), rd - 1)
+            ramp = np.clip((np.arange(rd // 2, dtype=np.float32) - low)
+                           / max(high - low, 1e-3), 0.0, 1.0)
+            inv = inv / f * ramp + inv * (1.0 - ramp)
         else:
             raise NotImplementedError(
-                f"rope_scaling type {t!r} is not implemented "
-                "(yarn / longrope are out of scope)")
+                f"rope_scaling type {t!r} is not implemented (linear, "
+                "dynamic, llama3 and yarn are; longrope's per-dimension "
+                "factor lists are not)")
         return inv.astype(np.float32)
 
     @property
@@ -406,6 +557,13 @@ class TransformerConfig:
 
     def _attn_params(self) -> int:
         h = self.hidden_size
+        if self.kv_lora_rank:
+            # q_a + its norm, q_b, kv_a + its norm, kv_b, the output
+            qr, kr, nh = self.q_lora_rank, self.kv_lora_rank, self.num_heads
+            return (h * qr + qr + qr * nh * self.head_dim
+                    + h * self.latent_width + kr
+                    + kr * nh * (self.qk_nope_head_dim + self.v_head_dim)
+                    + nh * self.v_head_dim * h)
         n = (self.num_heads + 2 * self.kv_heads) * self.head_dim * h \
             + self.num_heads * self.head_dim * h   # qkv (GQA) + out proj
         if self.index_heads:
@@ -831,99 +989,123 @@ class Block(nn.Module):
                 raise ValueError(f"num_heads {nh} not divisible by "
                                  f"num_kv_heads {kv}")
             h = x if cfg.post_ln or not cfg.pre_norm else ln("ln1")(x)
-            # one fused qkv matmul even under GQA: [H, (nh + 2*kv) * hd]
-            qkv = dense((nh + 2 * kv) * hd, "attn_qkv", bias=cfg.qkv_bias)(h)
-            q, k, v = jnp.split(qkv, [nh * hd, (nh + kv) * hd], axis=-1)
-            to_heads = lambda t, n: t.reshape(B, S, n, hd).transpose(0, 2, 1, 3)
-            qk_ln = lambda name: nn.RMSNorm(
-                epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
-                param_dtype=jnp.float32, name=name)
-            if cfg.qk_norm_kind == "projection":
-                # OLMoE: RMSNorm over the whole projected q / k vector,
-                # before the head split (HF OlmoeAttention.q_norm/k_norm)
-                q, k = qk_ln("q_norm")(q), qk_ln("k_norm")(k)
-            q, k, v = to_heads(q, nh), to_heads(k, kv), to_heads(v, kv)
-            if cfg.qk_norm_kind == "head":
-                # Qwen3: RMSNorm over head_dim on q/k, before rotary (HF
-                # Qwen3Attention.q_norm/k_norm — per-head, scale-only)
-                q, k = qk_ln("q_norm")(q), qk_ln("k_norm")(k)
-            if cfg.pos_embed == "rotary" and rope is not False:
-                pos = positions if positions is not None else jnp.arange(S)
-                inv_freq = cfg.rope_inv_freq(S)     # None = plain-theta table
-                rot = lambda t: apply_rotary(
-                    t, pos, cfg.rotary_dim, cfg.rotary_interleaved,
-                    cfg.rope_theta, inv_freq=inv_freq)
-                if rope is None or rope is True:
-                    q, k = rot(q), rot(k)
-                else:       # traced: a hybrid's layers share one scan body
-                    q, k = jnp.where(rope, rot(q), q), jnp.where(rope, rot(k), k)
-            if cfg.index_heads:
-                # the indexer's four leaves are made here so that a model's
-                # tree holds them; the selection itself lives in the
-                # inference decoder (models/generation.decoder_forward)
-                Hi, Di = cfg.index_heads, cfg.index_head_dim
-                dense(Hi * Di, "index_q", bias=False)(h)
-                nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
-                             param_dtype=jnp.float32, name="index_k_norm")(
-                    dense(Di, "index_k", bias=False)(h))
-                dense(Hi, "index_w", bias=False)(h)
+            if cfg.kv_lora_rank:
+                # latent attention's seven leaves are made here so that a
+                # model's tree holds them; the attention itself, absorbed
+                # over a latent cache, lives in the inference decoder
+                # (models/generation.decoder_forward)
+                rms = lambda name: nn.RMSNorm(
+                    epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
+                    param_dtype=jnp.float32, name=name)
+                rms("q_a_norm")(dense(cfg.q_lora_rank, "attn_q_a",
+                                      bias=False)(h))
+                dense(nh * hd, "attn_q_b", bias=False)(
+                    jnp.zeros((B, S, cfg.q_lora_rank), cfg.dtype))
+                dense(cfg.latent_width, "attn_kv_a", bias=False)(h)
+                latent = rms("kv_a_norm")(
+                    jnp.zeros((B, S, cfg.kv_lora_rank), cfg.dtype))
+                dense(nh * (cfg.qk_nope_head_dim + cfg.v_head_dim),
+                      "attn_kv_b", bias=False)(latent)
                 if not self.is_initializing():
                     raise NotImplementedError(
-                        "index_heads: a learned indexer's selection is "
-                        "served (init_inference / generate) and not "
-                        "trained or run by Block: ROADMAP M7")
-            if kv != nh:
-                # grouped-query: each k/v head serves nh/kv query heads
-                k = jnp.repeat(k, nh // kv, axis=1)
-                v = jnp.repeat(v, nh // kv, axis=1)
-            bias = None
-            slopes = None
-            if cfg.pos_embed == "alibi":
-                if positions is None:
-                    # default arange positions: pass the per-head slopes so the
-                    # flash kernel rebuilds the bias from block indices — no
-                    # [B, H, S, S] materialization on the kernel path
-                    slopes = jnp.asarray(alibi_slopes(nh), jnp.float32)
-                else:
-                    # packed / per-sample position ids: the distance matrix is
-                    # genuinely data-dependent, materialize it
-                    bias = alibi_bias(nh, positions, positions)
-            mask = attn_mask
-            win = 0
-            if window is not None:
-                # local sliding window (GPT-Neo): q attends k in (q-window, q].
-                # attention() routes this to the block-skip sliding-window kernel
-                # on TPU (compute scales with the window); with a user mask or
-                # under tracing where `window` is dynamic, it composes into the
-                # dense mask (exact either way)
-                if isinstance(window, (int, np.integer)):
-                    win = max(int(window), 0)          # <=0 means global
-                else:
-                    q_pos = jnp.arange(S)[:, None]
-                    k_pos = jnp.arange(S)[None, :]
-                    wmask = (q_pos - k_pos < window) | (window <= 0)
-                    mask = (wmask[None, None] if mask is None
-                            else mask & wmask[None, None])
-            drop_rng = (self.make_rng("dropout")
-                        if train and cfg.dropout > 0.0 else None)
-            if cfg.attention_impl == "sparse":
-                out = _sparse_block_attention(
-                    cfg, q, k, v, mask=mask, bias=bias, slopes=slopes,
-                    window=win, sm_scale=cfg.attn_scale,
-                    dropout_rate=cfg.dropout if train else 0.0,
-                    dropout_rng=drop_rng)
+                        "kv_lora_rank: latent attention is served "
+                        "(init_inference / generate) and not trained or run "
+                        "by Block: ROADMAP M4")
+                out = jnp.zeros((B, S, nh * cfg.v_head_dim), cfg.dtype)
             else:
-                out = attention(q, k, v, causal=cfg.causal, mask=mask, bias=bias,
-                                alibi_slopes=slopes, sm_scale=cfg.attn_scale,
-                                dropout_rate=cfg.dropout if train else 0.0,
-                                dropout_rng=drop_rng, impl=cfg.attention_impl,
-                                window=win, softcap=cfg.attn_softcap)
-            # tag so the "dots" remat policy keeps it: the Pallas kernel output is
-            # not a dot_general, and recomputing flash fwd in bwd costs ~2ms/layer
-            from jax.ad_checkpoint import checkpoint_name
-            out = checkpoint_name(out, "attn_out")
-            # nh*hd == H unless head_dim_override decouples them (Mistral-Nemo)
-            out = out.transpose(0, 2, 1, 3).reshape(B, S, nh * hd)
+                # one fused qkv matmul even under GQA: [H, (nh + 2*kv) * hd]
+                qkv = dense((nh + 2 * kv) * hd, "attn_qkv", bias=cfg.qkv_bias)(h)
+                q, k, v = jnp.split(qkv, [nh * hd, (nh + kv) * hd], axis=-1)
+                to_heads = lambda t, n: t.reshape(B, S, n, hd).transpose(0, 2, 1, 3)
+                qk_ln = lambda name: nn.RMSNorm(
+                    epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
+                    param_dtype=jnp.float32, name=name)
+                if cfg.qk_norm_kind == "projection":
+                    # OLMoE: RMSNorm over the whole projected q / k vector,
+                    # before the head split (HF OlmoeAttention.q_norm/k_norm)
+                    q, k = qk_ln("q_norm")(q), qk_ln("k_norm")(k)
+                q, k, v = to_heads(q, nh), to_heads(k, kv), to_heads(v, kv)
+                if cfg.qk_norm_kind == "head":
+                    # Qwen3: RMSNorm over head_dim on q/k, before rotary (HF
+                    # Qwen3Attention.q_norm/k_norm — per-head, scale-only)
+                    q, k = qk_ln("q_norm")(q), qk_ln("k_norm")(k)
+                if cfg.pos_embed == "rotary" and rope is not False:
+                    pos = positions if positions is not None else jnp.arange(S)
+                    inv_freq = cfg.rope_inv_freq(S)     # None = plain-theta table
+                    rot = lambda t: apply_rotary(
+                        t, pos, cfg.rotary_dim, cfg.rotary_interleaved,
+                        cfg.rope_theta, inv_freq=inv_freq)
+                    if rope is None or rope is True:
+                        q, k = rot(q), rot(k)
+                    else:       # traced: a hybrid's layers share one scan body
+                        q, k = jnp.where(rope, rot(q), q), jnp.where(rope, rot(k), k)
+                if cfg.index_heads:
+                    # the indexer's four leaves are made here so that a model's
+                    # tree holds them; the selection itself lives in the
+                    # inference decoder (models/generation.decoder_forward)
+                    Hi, Di = cfg.index_heads, cfg.index_head_dim
+                    dense(Hi * Di, "index_q", bias=False)(h)
+                    nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
+                                 param_dtype=jnp.float32, name="index_k_norm")(
+                        dense(Di, "index_k", bias=False)(h))
+                    dense(Hi, "index_w", bias=False)(h)
+                    if not self.is_initializing():
+                        raise NotImplementedError(
+                            "index_heads: a learned indexer's selection is "
+                            "served (init_inference / generate) and not "
+                            "trained or run by Block: ROADMAP M7")
+                if kv != nh:
+                    # grouped-query: each k/v head serves nh/kv query heads
+                    k = jnp.repeat(k, nh // kv, axis=1)
+                    v = jnp.repeat(v, nh // kv, axis=1)
+                bias = None
+                slopes = None
+                if cfg.pos_embed == "alibi":
+                    if positions is None:
+                        # default arange positions: pass the per-head slopes so the
+                        # flash kernel rebuilds the bias from block indices — no
+                        # [B, H, S, S] materialization on the kernel path
+                        slopes = jnp.asarray(alibi_slopes(nh), jnp.float32)
+                    else:
+                        # packed / per-sample position ids: the distance matrix is
+                        # genuinely data-dependent, materialize it
+                        bias = alibi_bias(nh, positions, positions)
+                mask = attn_mask
+                win = 0
+                if window is not None:
+                    # local sliding window (GPT-Neo): q attends k in (q-window, q].
+                    # attention() routes this to the block-skip sliding-window kernel
+                    # on TPU (compute scales with the window); with a user mask or
+                    # under tracing where `window` is dynamic, it composes into the
+                    # dense mask (exact either way)
+                    if isinstance(window, (int, np.integer)):
+                        win = max(int(window), 0)          # <=0 means global
+                    else:
+                        q_pos = jnp.arange(S)[:, None]
+                        k_pos = jnp.arange(S)[None, :]
+                        wmask = (q_pos - k_pos < window) | (window <= 0)
+                        mask = (wmask[None, None] if mask is None
+                                else mask & wmask[None, None])
+                drop_rng = (self.make_rng("dropout")
+                            if train and cfg.dropout > 0.0 else None)
+                if cfg.attention_impl == "sparse":
+                    out = _sparse_block_attention(
+                        cfg, q, k, v, mask=mask, bias=bias, slopes=slopes,
+                        window=win, sm_scale=cfg.attn_scale,
+                        dropout_rate=cfg.dropout if train else 0.0,
+                        dropout_rng=drop_rng)
+                else:
+                    out = attention(q, k, v, causal=cfg.causal, mask=mask, bias=bias,
+                                    alibi_slopes=slopes, sm_scale=cfg.attn_scale,
+                                    dropout_rate=cfg.dropout if train else 0.0,
+                                    dropout_rng=drop_rng, impl=cfg.attention_impl,
+                                    window=win, softcap=cfg.attn_softcap)
+                # tag so the "dots" remat policy keeps it: the Pallas kernel output is
+                # not a dot_general, and recomputing flash fwd in bwd costs ~2ms/layer
+                from jax.ad_checkpoint import checkpoint_name
+                out = checkpoint_name(out, "attn_out")
+                # nh*hd == H unless head_dim_override decouples them (Mistral-Nemo)
+                out = out.transpose(0, 2, 1, 3).reshape(B, S, nh * hd)
             out = dense(H, "attn_proj", bias=cfg.attn_out_bias)(out)
             if cfg.dropout > 0.0 and train:
                 out = nn.Dropout(cfg.dropout)(out, deterministic=False)
@@ -957,6 +1139,7 @@ class Block(nn.Module):
                     scores=cfg.moe_scores,
                     select_bias=cfg.moe_select_bias,
                     routed_scale=cfg.moe_routed_scale, held=cfg.moe_held,
+                    groups=cfg.moe_group_limit,
                     shared_dim=cfg.moe_shared_dim,
                     dtype=cfg.dtype,
                     name="moe")(h, train=train)

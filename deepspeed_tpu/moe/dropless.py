@@ -42,18 +42,46 @@ class Routing(NamedTuple):
     experts: jnp.ndarray       # [T, k] int32 the picks, best first
     weights: jnp.ndarray       # [T, k] float32 their combine weights
     group_sizes: jnp.ndarray   # [E] int32 rows routed to each expert
+    #: [T, groups] bool, the routing groups each row kept (a grouped router
+    #: only: :func:`kept_groups`)
+    groups: Optional[jnp.ndarray] = None
 
 
-def route_topk(logits: jnp.ndarray, k: int, renorm: bool) -> Routing:
+def kept_groups(scores: jnp.ndarray, groups: Tuple[int, int]) -> jnp.ndarray:
+    """``[T, n]`` bool: of the ``n`` equal groups the ``E`` scores lie in,
+    the ``keep`` whose BEST score is largest (DeepSeek-V2's
+    ``group_limited_greedy``; of equal maxima the lower group, as
+    ``lax.top_k`` orders them)."""
+    n, keep = groups
+    T, E = scores.shape
+    best = jnp.max(scores.reshape(T, n, E // n), axis=-1)
+    _, which = jax.lax.top_k(best, keep)
+    return jnp.zeros((T, n), bool).at[jnp.arange(T)[:, None], which].set(True)
+
+
+def route_topk(logits: jnp.ndarray, k: int, renorm: bool,
+               groups: Optional[Tuple[int, int]] = None,
+               scale: float = 1.0) -> Routing:
     """Softmax in float32, then the k largest. ``renorm`` divides the k
     weights by their sum (HF ``norm_topk_prob``); OLMoE keeps the raw
-    probabilities."""
+    probabilities. ``groups = (n, keep)``: the k are picked among the
+    experts of the row's :func:`kept_groups` only (the others' scores read 0
+    for the selection); ``scale`` multiplies the weights
+    (``routed_scaling_factor``)."""
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    weights, experts = jax.lax.top_k(probs, k)
+    kept = None
+    if groups is None:
+        weights, experts = jax.lax.top_k(probs, k)
+    else:
+        kept = kept_groups(probs, groups)
+        weights, experts = jax.lax.top_k(jnp.where(jnp.repeat(
+            kept, probs.shape[-1] // groups[0], axis=1), probs, 0.0), k)
     if renorm:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if scale != 1.0:
+        weights = weights * scale
     return Routing(probs, experts.astype(jnp.int32), weights,
-                   _group_sizes(experts, logits.shape[-1]))
+                   _group_sizes(experts, logits.shape[-1]), kept)
 
 
 def route_sigmoid_topk(logits: jnp.ndarray, k: int, renorm: bool,
@@ -190,10 +218,12 @@ def dropless_moe(tokens: jnp.ndarray, router_kernel: jnp.ndarray,
                  scores: str = "softmax",
                  select_bias: Optional[jnp.ndarray] = None,
                  scale: float = 1.0,
-                 held: Optional[Tuple[int, int]] = None):
+                 held: Optional[Tuple[int, int]] = None,
+                 groups: Optional[Tuple[int, int]] = None):
     """``tokens [T, H]`` through a router and ``E`` expert MLPs, k a token.
 
-    ``scores``: "softmax" (:func:`route_topk`) or "sigmoid"
+    ``scores``: "softmax" (:func:`route_topk`, with ``scale`` and the
+    group-limited selection ``groups = (n, keep)``) or "sigmoid"
     (:func:`route_sigmoid_topk`, with ``select_bias [E]`` and ``scale``).
     ``held = (first, count)``: the router ranks all its ``E`` outputs and
     ``experts`` holds ``count`` of them, ``first ..``: a chip's share of an
@@ -224,7 +254,7 @@ def dropless_moe(tokens: jnp.ndarray, router_kernel: jnp.ndarray,
         if scores == "sigmoid":
             r = route_sigmoid_topk(logits, k, renorm, select_bias, scale)
         else:
-            r = route_topk(logits, k, renorm)
+            r = route_topk(logits, k, renorm, groups, scale)
     with jax.named_scope("dispatch"):
         picks, sizes, weights = r.experts.reshape(T * k), r.group_sizes, \
             r.weights

@@ -170,6 +170,8 @@ class MoE(nn.Module):
     select_bias: bool = False
     routed_scale: float = 1.0
     held: Optional[Tuple[int, int]] = None
+    # group-limited selection (n groups, keep), moe/dropless.kept_groups
+    groups: Optional[Tuple[int, int]] = None
     shared_dim: int = 0
 
     @property
@@ -343,7 +345,7 @@ class MoE(nn.Module):
             k=self.k, renorm=self.norm_topk,
             act=_ACTIVATIONS[self.activation] if self.gated else nn.gelu,
             scores=self.scores, select_bias=select_bias,
-            scale=self.routed_scale, held=self.held)
+            scale=self.routed_scale, held=self.held, groups=self.groups)
         if self.shared_dim:
             # the shared expert: the experts' body on every token, unweighted
             with jax.named_scope("shared"):

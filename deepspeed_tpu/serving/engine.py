@@ -47,6 +47,7 @@ from ..testing import chaos
 from ..utils import telemetry
 from ..utils.logging import log_dist, logger
 from ..ops.pallas import sparse_select
+from ..ops.pallas.latent_attention import path as latent_path
 from ..ops.pallas.sparse_select import bits_to_positions, positions_of_bits
 from .kv_cache import (NULL_BLOCK, BlockPoolExhausted, SharedPagedState)
 from .model_runner import attention_impl, paged_forward
@@ -90,6 +91,24 @@ _COUNTERS = (
 #: ``moe.assignments`` the share's load, 1/8 for an eighth under even routing
 _MOE_COUNTERS = ("moe.assignments", "moe.held_assignments", "moe.layer_steps",
                  "moe.load_max_over_mean_sum", "moe.experts_idle_sum")
+#: a grouped router (``cfg.moe_groups``), from the kept-group counts behind
+#: the experts' in the same fetch: the real rows, over the sparse layers,
+#: whose kept groups include one this program holds (``cfg.moe_held``; every
+#: row without a share). Over rows x layers: the share of the traffic this
+#: device sees under device-limited routing, ``topk_groups / groups`` (3/8)
+#: under even routing; the rest compute nothing here
+_GROUP_COUNTERS = ("moe.group_rows_sum",)
+#: a latent model (``cfg.kv_lora_rank``), counted on the host from the
+#: positions of a call's rows, summed over the layers: query rows (a decode
+#: lane's token, a chunk's tokens), the cached tokens they attend (a row's
+#: own included) and the pages the latent kernel walks for them (a lane's
+#: live pages once a call, whatever its head programs copy again). The two
+#: ``mla.chunk_*`` are the prefill calls' part: the cached tokens their rows
+#: attend (of ``mla.ctx_tokens_sum``) and the cached tokens a call sees, once
+#: a call (what a chunk reads; what an expanded form would put through
+#: ``attn_kv_b`` again, so ``benchmark/mla_cost.py`` can price both)
+_MLA_COUNTERS = ("mla.rows_sum", "mla.ctx_tokens_sum", "mla.pages_walked_sum",
+                 "mla.chunk_ctx_tokens_sum", "mla.chunk_keys_sum")
 #: a model with an indexer (``cfg.index_heads``), counted on the host from
 #: the positions of a call's rows, summed over the layers: query rows (a
 #: decode lane's token, a chunk's tokens), the keys the indexer scored for
@@ -319,9 +338,15 @@ def step_programs(cfg, block_size: int, table_width: int, *,
 def token_words(cfg, lanes: int) -> int:
     """Length of the int32 vector a device call over ``lanes`` lanes returns
     (``step_programs``): its tokens, and behind them a dropless mixture's
-    ``[L, E]`` expert counts."""
-    return lanes + (cfg.sparse_layers * cfg.moe_experts
+    ``[L, E]`` expert counts (``[L, E + groups]`` of a grouped router)."""
+    return lanes + (cfg.sparse_layers * _count_words(cfg)
                     if cfg.moe_is_dropless else 0)
+
+
+def _count_words(cfg) -> int:
+    """Counts a sparse layer hands out: one a router output and, behind
+    them, one a routing group of a grouped router."""
+    return cfg.moe_experts + (cfg.moe_groups if cfg.moe_groups > 1 else 0)
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2))
@@ -568,6 +593,10 @@ class ServingEngine:
             self.stats.update(dict.fromkeys(_MOE_COUNTERS, 0))
         if cfg.index_heads:
             self.stats.update(dict.fromkeys(_SPARSE_COUNTERS, 0))
+        if cfg.moe_is_dropless and cfg.moe_groups > 1:
+            self.stats.update(dict.fromkeys(_GROUP_COUNTERS, 0))
+        if cfg.kv_lora_rank:
+            self.stats.update(dict.fromkeys(_MLA_COUNTERS, 0))
         # the paged-KV state: PRIVATE by default, SHARED when a
         # disaggregated pair (serving/disagg.py) passes one in — block
         # IDs then mean the same pool slots to both roles, which is what
@@ -578,11 +607,15 @@ class ServingEngine:
         # model's is stored at its KV heads): 2 x layers x stored heads x
         # head_dim x item size, the int8 tier's scales and an indexer's one
         # key a layer (the ``ki`` leaf) with it
-        pool_k = self.pools["k"]
+        pool_k = self.pools["ckv" if cfg.kv_lora_rank else "k"]
         self.rec.gauge("kv.stored_heads", int(pool_k.shape[1]))
         self.rec.gauge("kv.bytes_per_token", sum(
             a.size * a.dtype.itemsize for a in self.pools.values())
             // pool_k.shape[2])
+        if cfg.kv_lora_rank:
+            # a latent model's one row a token and layer, as the pool lays
+            # it out (its width rounded up to whole 128-lane tiles)
+            self.rec.gauge("kv.latent_lanes", int(pool_k.shape[3]))
         self.scheduler = Scheduler(self.pool, serving.max_queue,
                                    self.max_model_len, self.prefix_cache,
                                    aging_s=serving.fleet.priority_aging_s,
@@ -649,7 +682,10 @@ class ServingEngine:
             f"{self.rec.gauges['kv.bytes_per_token']} bytes a token"
             + (f", an indexer key of {cfg.index_head_dim} on "
                f"{self.pools['ki'].shape[-1]} lanes a layer among them"
-               if cfg.index_heads else "") + "), "
+               if cfg.index_heads else "")
+            + (f": one latent row of {cfg.latent_width} on "
+               f"{pool_k.shape[3]} lanes a layer"
+               if cfg.kv_lora_rank else "") + "), "
             f"max_batch={self.max_batch}, max_model_len="
             f"{self.max_model_len}, prefix_cache={serving.prefix_cache}, "
             f"prefill_chunk={self._chunk or 'whole'}",
@@ -744,6 +780,23 @@ class ServingEngine:
                     extent.size * sparse_select.score_tiles(
                         Kp - 1, Kp, 0, Kp, np)[1])
 
+    def _count_latent(self, extent: np.ndarray, pages: int,
+                      chunk: bool = False) -> None:
+        """A latent model: a call's REAL query rows, ``extent`` the cached
+        tokens each attends (its own included), and the ``pages`` the call's
+        attention walks; in every layer (:data:`_MLA_COUNTERS`). ``chunk``: a
+        prefill call."""
+        cfg = self.cfg
+        if not cfg.kv_lora_rank:
+            return
+        L, c = cfg.num_layers, self.stats
+        c["mla.rows_sum"] += L * int(extent.size)
+        c["mla.ctx_tokens_sum"] += L * int(extent.sum())
+        c["mla.pages_walked_sum"] += L * int(pages)
+        if chunk:
+            c["mla.chunk_ctx_tokens_sum"] += L * int(extent.sum())
+            c["mla.chunk_keys_sum"] += L * int(extent.max())
+
     def _count_experts(self, out: np.ndarray, call: int) -> None:
         """A dropless MoE model's router load, from the fetched output of
         device call number ``call``: its ``[L, E]`` expert counts sit behind
@@ -758,8 +811,17 @@ class ServingEngine:
         del pending[:n]
         E, c = self.cfg.moe_experts, self.stats
         first, held_n = self.cfg.moe_held or (0, E)
+        words, size = _count_words(self.cfg), E // self.cfg.moe_groups
         for a in got:
-            counts = a[len(a) - self.cfg.sparse_layers * E:].reshape(-1, E)
+            counts = a[len(a) - self.cfg.sparse_layers * words:].reshape(
+                -1, words)
+            if words > E:
+                # a grouped router: the (row, group) pairs whose row kept a
+                # group held here (a share is whole groups; one group a
+                # device, so a row counts once)
+                counts, kept = counts[:, :E], counts[:, E:]
+                c["moe.group_rows_sum"] += int(
+                    kept[:, first // size:(first + held_n) // size].sum())
             routed = counts.sum(axis=1)
             live = routed > 0               # a call of padding only: nothing
             c["moe.assignments"] += int(routed.sum())
@@ -1275,6 +1337,8 @@ class ServingEngine:
         self.rec.count("paged.chunk_table_pages_sum", self.nbk)
         self._count_selection(np.minimum(q0 + 1 + np.arange(Tb), q0 + n),
                               slice(n), -(-(q0 + n) // self.block_size))
+        self._count_latent(q0 + 1 + np.arange(n),
+                           -(-(q0 + n) // self.block_size), chunk=True)
         if Tb not in self._prefill_shapes:
             self._note_prefill_path(Tb)
         buf = np.zeros((self._layout.prefill_words(Tb),), np.int32)
@@ -1294,16 +1358,30 @@ class ServingEngine:
         of the programs that went it]}``, from the shapes alone, as the
         dispatcher decides it when a program is traced."""
         from ..ops.attention import paged_attention_path
-        cfg, pool = self.cfg, self.pools["k"]
-        path, why = paged_attention_path(
-            (1, cfg.num_heads, Tb, cfg.head_dim),
-            pool.shape[:2] + (pool.shape[2] // self.block_size,
-                              self.block_size, cfg.head_dim),
-            stacked=True, quant="k_scale" in self.pools,
-            impl=attention_impl(cfg), interpret=self.interpret)
+        cfg = self.cfg
+        if cfg.kv_lora_rank:
+            path, why = self._latent_prefill_path(Tb)
+        else:
+            pool = self.pools["k"]
+            path, why = paged_attention_path(
+                (1, cfg.num_heads, Tb, cfg.head_dim),
+                pool.shape[:2] + (pool.shape[2] // self.block_size,
+                                  self.block_size, cfg.head_dim),
+                stacked=True, quant="k_scale" in self.pools,
+                impl=attention_impl(cfg), interpret=self.interpret)
         self._prefill_shapes.add(Tb)
         paths = self.rec.gauges.setdefault("paged.prefill_path", {})
         paths.setdefault(f"{path}: {why}" if why else path, []).append(Tb)
+
+    def _latent_prefill_path(self, Tb: int):
+        """``PagedCache.attend_latent``'s way for a chunk of ``Tb`` rows, as
+        ``paged_attention_path`` answers for K/V pools."""
+        pool = self.pools["ckv"]
+        return latent_path(
+            (1, self.cfg.num_heads, Tb, pool.shape[3]),
+            (pool.shape[0], 1, pool.shape[2] // self.block_size,
+             self.block_size, pool.shape[3]),
+            attention_impl(self.cfg), self.interpret)
 
     def _prefill_chunk(self, pf: _Prefilling, n: int) -> None:
         req, rec = pf.req, self.rec
@@ -1522,6 +1600,8 @@ class ServingEngine:
             rec.count("paged.table_pages_sum", B * self.nbk)
             self._count_selection(lanes.ctx * go + 1, go, int(
                 (lanes.ctx[go] // self.block_size + 1).sum()), decode=True)
+            self._count_latent(lanes.ctx[go] + 1, int(
+                (lanes.ctx[go] // self.block_size + 1).sum()))
             if self._windows.size:
                 # of those, what the window layers' calls walk, summed over
                 # those layers: from the page of a lane's first key in reach

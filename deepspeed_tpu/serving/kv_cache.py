@@ -72,6 +72,19 @@ def init_pool(cfg, num_blocks: int, block_size: int,
     K/V, slot for slot: block tables, ``fork``, the prefix cache and a
     preemption's release carry it with no allocator of its own.
 
+    A LATENT model (``cfg.kv_lora_rank``: DeepSeek-V2's MLA) has ONE leaf
+    and no ``k`` / ``v``: ``ckv`` ``[L, 1, num_blocks*block_size,
+    latent_lanes]``, a row a token and layer holding ``[the normed latent
+    (kv_lora_rank) | the rotated key all heads share (qk_rope_head_dim) |
+    zeros]`` on whole 128-lane tiles: 576 of 640 lanes at DeepSeek-V2's
+    widths, ``L x 640 x item size`` bytes a token (an eleventh more than the
+    576 it needs; a row a head would be 57 times that). At its own width the
+    row is 4.5 lane tiles and the chip's compiler handles such a leaf as it
+    handled a 64-wide one (above; PERF.md section 6, PR 49 has the readings);
+    the zeros add nothing to a score against a query padded the same way.
+    The absorbed kernel reads the row once for scores (all its lanes) and
+    values (its first ``kv_lora_rank``): ``ops/pallas/latent_attention.py``.
+
     Flat slot layout (slot = block * block_size + offset), row-major: the
     paged forward and the paged-attention kernel both view the same buffer
     as ``[L, kvh, num_blocks, block_size, hd]`` (a free reshape), the kernel
@@ -90,6 +103,13 @@ def init_pool(cfg, num_blocks: int, block_size: int,
     kernel takes the int8 blocks plus scales and dequantizes per block
     in VMEM, so int8 is what crosses HBM (no pool-slice f32 copy)."""
     dtype = dtype or cfg.dtype
+    if cfg.kv_lora_rank:
+        if dtype == jnp.int8:
+            raise ValueError(
+                "an int8 KV pool with latent attention (kv_lora_rank): the "
+                "latent leaf has no quantized format (ROADMAP M4)")
+        return {"ckv": jnp.zeros((cfg.num_layers, 1, num_blocks * block_size,
+                                  cfg.latent_lanes), dtype)}
     shape = (cfg.num_layers, cfg.kv_heads, num_blocks * block_size,
              cfg.head_dim)
     index = ({"ki": jnp.zeros((shape[0], 1, shape[2],

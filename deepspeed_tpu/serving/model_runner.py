@@ -31,15 +31,18 @@ paged sequences are always exact-length.
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Any, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ..models.generation import attention_constants, decoder_forward
+from ..models.generation import (absorb_output, absorb_query,
+                                 attention_constants, decoder_forward)
 from ..models.transformer import TransformerConfig
 from ..ops.attention import paged_attention
 from ..ops.pallas.paged_attention import scale_rows
+from ..ops.pallas import latent_attention as latent
 from ..ops.pallas import sparse_select
 from .kv_cache import NULL_BLOCK
 
@@ -127,16 +130,18 @@ class PagedCache:
         self.cfg, self.pools, self.interpret = cfg, pools, interpret
         self.bs = bs = int(block_size)
         self.quantized = "k_scale" in pools
-        if pools["k"].dtype == jnp.int8 and not self.quantized:
+        # the leaf every other follows: K, or a latent model's one leaf
+        lead = pools["ckv" if cfg.kv_lora_rank else "k"]
+        if lead.dtype == jnp.int8 and not self.quantized:
             raise ValueError(
                 "int8 KV pool without k_scale/v_scale leaves — build pools "
                 "with serving.kv_cache.init_pool(dtype=jnp.int8)")
-        num_slots = pools["k"].shape[2]
+        num_slots = lead.shape[2]
         if num_slots % bs:
             raise ValueError(f"pool slots {num_slots} not divisible by "
                              f"block_size {bs}")
-        self.blocked_shape = pools["k"].shape[:2] + (
-            num_slots // bs, bs, cfg.head_dim)
+        self.blocked_shape = lead.shape[:2] + (
+            num_slots // bs, bs, lead.shape[3])
         self.bt = jnp.asarray(block_tables, jnp.int32)
         B, nbk = self.bt.shape
         self.q_start = jnp.asarray(q_start, jnp.int32).reshape(B)
@@ -222,6 +227,37 @@ class PagedCache:
                      [(0, 0)] * 3 + [(0, lanes - ki.shape[-1])])
         return {**kv, "ki": _write_kv(kv["ki"], li, ki[:, :, None], 3,
                                       self.write_plan)}
+
+    def write_latent(self, kv, li, row):
+        """A latent model's one row a token and layer, ``[B, T,
+        latent_width]`` (the normed latent, the rotated shared key), into
+        the ``ckv`` pool under the K/V plan: no heads, zeros on the lanes
+        past its width."""
+        lanes = kv["ckv"].shape[-1]
+        row = jnp.pad(row.astype(kv["ckv"].dtype),
+                      [(0, 0)] * 2 + [(0, lanes - row.shape[-1])])
+        return {**kv, "ckv": _write_kv(kv["ckv"], li, row[:, None, None], 3,
+                                       self.write_plan)}
+
+    def attend_latent(self, kv, li, q_nope, q_pe, wk, wv):
+        """Latent attention through the block table, ``[B, heads, T,
+        v_head_dim]``, absorbed at every call shape (a decode token's heads
+        are rows over the one stored row, a chunk's tiles 2 heads x 256
+        rows); the kernel on a TPU or under ``interpret``, its jnp twin
+        elsewhere. The call's own rows are in the pool already."""
+        cfg, lanes = self.cfg, kv["ckv"].shape[-1]
+        way, _ = latent.path(q_nope.shape[:3] + (lanes,), kv["ckv"].shape,
+                             self.impl, self.interpret)
+        attend = partial(latent.latent_attention, interpret=self.interpret) \
+            if way == "kernel" else latent.latent_attention_reference
+        with jax.named_scope("absorb"):
+            q = absorb_query(q_nope, q_pe, wk, lanes)
+        with jax.named_scope("attend"):
+            o = attend(q, kv["ckv"], self.bt, self.ctx,
+                       value=cfg.kv_lora_rank, sm_scale=self.sm_scale,
+                       layer_idx=li, q_start=self.q_start)
+        with jax.named_scope("absorb"):
+            return absorb_output(o, wv)
 
     def select(self, kv, li, qi, wi, window):
         """Which of its lane's keys each row attends: the indexer's scores

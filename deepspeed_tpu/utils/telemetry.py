@@ -300,6 +300,7 @@ def span_times(entries: List[Entry], since_ns: int = 0
 SCOPES = frozenset({
     "embed", "layers", "block.attn", "qkv", "kv_write", "index",
     "index_write", "select", "attend", "out",
+    "latent_q", "latent_write", "absorb",
     "block.mlp", "route", "dispatch", "experts", "combine", "shared",
     "head", "loss", "grad_reduce", "sample", "grad_accum", "optimizer",
     "zero.gather", "zero.scatter"})

@@ -43,7 +43,8 @@ SMOKE_SERVING = {"block_size": 32, "pool_blocks": 1024, "max_batch": 8,
 CELLS = {"mistral-7b-l16": "serve-mistral-7b-l16-chat",
          "olmoe-1b-7b-l8": "serve-olmoe-1b-7b-l8-gen",
          "k-exaone-236b-ep8-l5": "serve-k-exaone-236b-ep8-l5-mixed",
-         "keye-vl2-30b-ep8-l8": "serve-keye-vl2-30b-ep8-l8-longdoc"}
+         "keye-vl2-30b-ep8-l8": "serve-keye-vl2-30b-ep8-l8-longdoc",
+         "deepseek-v2-ep8-l5": "serve-deepseek-v2-ep8-l5-longdoc"}
 
 
 def _strip_kernel_locations():

@@ -648,6 +648,128 @@ def test_keye_serving_step_selects_keys_in_place(chip, monkeypatch, program):
                                          mem.temp_size_in_bytes)
 
 
+#: gpt2-1.3b's four serving programs (``scripts/serving_program_text.py``:
+#: the decode and the 256-row prefill step, bf16 and the int8 tier) as every
+#: tree since PR 44 has lowered them, sha256 of the text, first 16 digits. A
+#: PR for another model leaves them as they are (PR 26 was refused for a
+#: dense path it had touched); one that means to change the dense programs
+#: changes these with its reason
+_GPT2_PROGRAMS = {"gpt2-1.3b.decode": "da1edc35111a2336",
+                  "gpt2-1.3b.prefill256": "5c4405f57ff5d37b",
+                  "gpt2-1.3b-int8.decode": "b2066bebbff05fcd",
+                  "gpt2-1.3b-int8.prefill256": "9004358ba26b774e"}
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_gpt2_serving_programs_are_text_for_text_what_they_were(
+        chip, monkeypatch, int8):
+    """Latent attention, YaRN and the grouped router were added beside the
+    dense path, not inside it: the lowered text of gpt2-1.3b's decode and
+    prefill programs is the parent's."""
+    import hashlib
+    import importlib.util
+    import os
+    from benchmark import harness
+    from jax._src import tpu_custom_call
+    spec = importlib.util.spec_from_file_location(
+        "serving_program_text", os.path.join(
+            harness.ROOT, "scripts", "serving_program_text.py"))
+    script = importlib.util.module_from_spec(spec)
+    before = (tpu_custom_call._lower_mosaic_module_to_asm, list(os.sys.path))
+    try:
+        spec.loader.exec_module(script)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        script._strip_kernel_locations()
+        gpt2 = harness.load_json(os.path.join(harness.HERE, "configs",
+                                              "gpt2-1.3b.json"))
+        got = {label: hashlib.sha256(text.encode()).hexdigest()[:16]
+               for label, text in script.programs(
+                   "gpt2-1.3b", gpt2, script.SMOKE_SERVING, int8=int8,
+                   chip=chip)}
+    finally:
+        tpu_custom_call._lower_mosaic_module_to_asm, os.sys.path[:] = before
+    assert got == {k: v for k, v in _GPT2_PROGRAMS.items()
+                   if ("int8" in k) == int8}
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill1536"])
+def test_deepseek_serving_step_keeps_the_latent_pool_in_place(
+        chip, monkeypatch, program):
+    """The serving loop's two programs at the DeepSeek-V2 cell's widths,
+    read from the benchmark's own files (deepseek-v2-ep8-l5: hidden 5120,
+    128 heads over ONE stored row of 512 + 64 on 640 lanes, 20 of 160
+    experts of 1536 held, a leading dense layer of 12288; 32 lanes over a
+    16384 x 32 pool, tables of 800 blocks): each of the two stacks' bodies
+    has its latent-attention call, the sparse one the held experts' three
+    grouped matmuls; the pool is ONE leaf, donated and updated in place, and
+    no instruction copies it, a layer of it, or slices a layer out; the
+    counts carry the kept groups behind the experts; and weights, pool and
+    temporaries fit the chip."""
+    from benchmark import harness
+    from deepspeed_tpu.models import TransformerConfig, build_model
+    from deepspeed_tpu.serving.engine import (StepLayout, step_programs,
+                                              token_words)
+    from deepspeed_tpu.serving.kv_cache import init_pool
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cell = harness.load_cell("serve-deepseek-v2-ep8-l5-longdoc")
+    serving = cell.system["serving"]
+    BS, NB, B, NBK, chunk = (serving[k] for k in (
+        "block_size", "pool_blocks", "max_batch", "max_blocks_per_seq",
+        "prefill_chunk_tokens"))
+    assert program in ("decode", f"prefill{chunk}")
+    model, cfg = build_model(TransformerConfig(
+        **harness.load_family("deepseek_v2").model_kwargs(cell.config),
+        dtype=jnp.bfloat16))
+    L, LS, E, K, G = cfg.num_layers, cfg.sparse_layers, cfg.moe_experts, \
+        cfg.moe_k, cfg.moe_groups
+    assert (L, LS, E, cfg.moe_held, G, cfg.moe_topk_groups, cfg.hidden_size,
+            cfg.mlp_dim, cfg.num_heads, cfg.head_dim, cfg.latent_width,
+            cfg.latent_lanes) == (5, 4, 160, (0, 20), 8, 3, 5120, 1536, 128,
+                                  192, 576, 640)
+    on_chip = lambda tree: jax.tree.map(lambda x: chip(x.shape, x.dtype), tree)
+    params = on_chip(jax.eval_shape(lambda: jax.tree.map(
+        lambda x: x.astype(jnp.bfloat16), model.init(
+            jax.random.PRNGKey(0),
+            {"input_ids": jnp.zeros((1, 8), jnp.int32)})["params"])))
+    assert params["blocks"]["attn_kv_b"]["kernel"].shape == (LS, 512, 32768)
+    assert "attn_qkv" not in params["blocks"]
+    pools = on_chip(jax.eval_shape(lambda: init_pool(cfg, NB, BS,
+                                                     jnp.bfloat16)))
+    assert set(pools) == {"ckv"} and \
+        pools["ckv"].shape == (L, 1, NB * BS, 640)
+    rows = B if program == "decode" else chunk
+    decode, prefill = step_programs(cfg, BS, NBK)
+    if program == "decode":
+        fn, words = decode, StepLayout(NBK).decode_words(B)
+        fed = [chip((token_words(cfg, n),), jnp.int32) for n in (B, 1)]
+    else:
+        fn, words, fed = prefill, StepLayout(NBK).prefill_words(chunk), []
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, pools, chip((words,), jnp.int32), *fed).compile()
+    text = compiled.as_text()
+    (out, picks), _ = compiled.out_info
+    assert out.shape == ((B if program == "decode" else 1) + LS * (E + G),)
+    assert picks.shape == (LS, rows, K)
+    kernels = _kernel_scopes(text)
+    assert len([k for k in kernels if "jit(gmm)" in k]) == 3, kernels
+    latent = [k for k in kernels if "paged_attention" in k]
+    assert len(latent) == 2 and len(kernels) == 5, kernels
+    assert text.count("paged_attention_latent") >= 2
+    made = [r for r in _results(text) if r[1] not in (
+        "parameter", "get-tuple-element", "while", "tuple", "bitcast")]
+    layer = NB * BS * 640
+    moved = [r for r in made if r[3] >= layer and (
+        r[1] in ("copy", "transpose", "scatter")
+        or r[1] == "dynamic-slice" and r[3] == layer)]
+    assert not moved, moved
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(
+        p.size * p.dtype.itemsize for p in pools.values())
+    held_bytes = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 9.0e9 < held_bytes < 13.0e9, (mem.argument_size_in_bytes,
+                                         mem.temp_size_in_bytes)
+
+
 @pytest.mark.parametrize("layout,vocab", [("dp4", 50257), ("dp2_tp2", 50304)])
 def test_zero3_step_reduces_the_head_gradient_once_behind_the_loss_loop(
         topo, chip, monkeypatch, layout, vocab):

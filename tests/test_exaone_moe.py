@@ -149,15 +149,30 @@ def _keye_uncut():
         num_experts=16, num_local_experts=16), cut
 
 
-@pytest.mark.parametrize("family", ["exaone_moe", "keye_vl2"])
+def _deepseek_uncut():
+    """DeepSeek-V2's mixture (group-limited softmax top-6 of 32 in 8 groups
+    keep 3, scaled 16, two shared experts) at the sizes of
+    ``tests/test_deepseek_v2.py``, uncut: 32 experts held."""
+    from test_deepseek_v2 import TINY as cut, UNCUT as uncut
+    return harness.load_family("deepseek_v2"), uncut, cut
+
+
+@pytest.mark.parametrize("family", ["exaone_moe", "keye_vl2", "deepseek_v2"])
 def test_the_shares_add_up_to_the_uncut_layer(family):
     """Eight chips' routed parts, with what every chip computes alike (a
     shared expert, where the family has one) counted once, are the uncut
-    reference's mixture."""
+    reference's mixture. DeepSeek-V2's shares are its routing groups, one a
+    chip: a token keeps 3 of the 8, so five chips add nothing for it."""
+    each, router = (4, 32) if family == "deepseek_v2" else (2, 16)
     if family == "exaone_moe":
         fam, uncut, cut = FAM, UNCUT, TINY
         mixture = lambda moe, h, **kw: fam.reference_moe(
             moe, h, k=4, renorm=True, scale=2.5, **kw)[0]
+        routed_only = dict(shared=False)
+    elif family == "deepseek_v2":
+        fam, uncut, cut = _deepseek_uncut()
+        mixture = lambda moe, h, **kw: fam.reference_moe(
+            moe, h, k=6, groups=8, keep=3, renorm=False, scale=16.0, **kw)[0]
         routed_only = dict(shared=False)
     else:
         fam, uncut, cut = _keye_uncut()
@@ -174,26 +189,31 @@ def test_the_shares_add_up_to_the_uncut_layer(family):
     h = jax.random.normal(jax.random.PRNGKey(5), (1, 40, 32))
     want = mixture(moe, h[0])
     total = 0.0
+    idle = 0
     for chip in range(8):
-        config = dict(cut, deployment={"router_outputs": 16,
-                                       "experts_held": [2 * chip, 2]})
+        config = dict(cut, deployment={"router_outputs": router,
+                                       "experts_held": [each * chip, each]})
         cfg = TransformerConfig(**fam.model_kwargs(config),
                                 dtype=jnp.float32)
-        assert cfg.moe_held == (2 * chip, 2)
+        assert cfg.moe_held == (each * chip, each)
         held = dict(moe, experts=jax.tree.map(
-            lambda a: a[2 * chip:2 * chip + 2], moe["experts"]))
+            lambda a: a[each * chip:each * chip + each], moe["experts"]))
         y, routing = _moe_mlp(cfg, held, h)
         shared, _ = _moe_mlp(cfg, dict(held, experts=jax.tree.map(
             jnp.zeros_like, held["experts"])), h)
         # this chip's routed part alone; the reference's share is the same
-        mine = mixture(held, h[0], first=2 * chip, **routed_only)
-        np.testing.assert_allclose((y - shared)[0], mine, atol=1e-5)
+        mine = mixture(held, h[0], first=each * chip, **routed_only)
+        tol = 1e-5 if family != "deepseek_v2" else 5e-5     # weights x 16
+        np.testing.assert_allclose((y - shared)[0], mine, atol=tol)
         total = total + (y - shared)[0]
-    assert (family == "exaone_moe") == bool(np.abs(shared).max() > 0)
-    np.testing.assert_allclose(total + shared[0], want, atol=1e-5)
+        idle += int((np.abs(np.asarray(y - shared)[0]).max(-1) == 0).sum())
+    assert (family != "keye_vl2") == bool(np.abs(shared).max() > 0)
+    # a grouped router's token computes on 3 chips of 8 at most
+    assert (idle >= 5 * h.shape[1]) == (family == "deepseek_v2")
+    np.testing.assert_allclose(total + shared[0], want, atol=tol)
     # and the uncut program is the uncut reference
     y, _ = _moe_mlp(cfg_all, moe, h)
-    np.testing.assert_allclose(y[0], want, atol=1e-5)
+    np.testing.assert_allclose(y[0], want, atol=tol)
 
 
 def _engine(cfg, params):
