@@ -455,7 +455,7 @@ def decoder_forward(cfg: TransformerConfig, params: PyTree,
     def latent_attention(x, kv, p, li):
         """The attention branch of a latent model (``cfg.kv_lora_rank``):
         the token's one latent row goes to the cache, and what attends over
-        the cached rows, absorbed, is the cache's to answer
+        the cached rows, in which form, is the cache's to answer
         (``attend_latent``; ``ops/pallas/latent_attention.py``). ``(the
         cache, the branch's output)``."""
         rank, nope, vw = cfg.kv_lora_rank, cfg.qk_nope_head_dim, \

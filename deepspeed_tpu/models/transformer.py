@@ -233,8 +233,9 @@ class TransformerConfig:
     # qk_nope_head_dim | qk_rope_head_dim wide. The caches store the latent
     # row and nothing a head (``latent_lanes``); attention runs absorbed
     # (``attn_kv_b`` folded into the query and the output: the heads are rows
-    # over the one stored row; models/generation.py,
-    # ops/pallas/latent_attention.py). The inference decoder only: a training
+    # over the one stored row; models/generation.py), but for the paged
+    # kernel's prefill chunks, which expand a key tile once for all of their
+    # rows (ops/pallas/latent_attention.py). The inference decoder only: a training
     # Block with these set refuses (ROADMAP M4)
     kv_lora_rank: int = 0
     q_lora_rank: int = 0

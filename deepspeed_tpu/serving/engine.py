@@ -47,6 +47,8 @@ from ..testing import chaos
 from ..utils import telemetry
 from ..utils.logging import log_dist, logger
 from ..ops.pallas import sparse_select
+from ..ops.pallas.latent_attention import chunk_expanded_keys
+from ..ops.pallas.latent_attention import form as latent_form
 from ..ops.pallas.latent_attention import path as latent_path
 from ..ops.pallas.sparse_select import bits_to_positions, positions_of_bits
 from .kv_cache import (NULL_BLOCK, BlockPoolExhausted, SharedPagedState)
@@ -104,11 +106,20 @@ _GROUP_COUNTERS = ("moe.group_rows_sum",)
 #: own included) and the pages the latent kernel walks for them (a lane's
 #: live pages once a call, whatever its head programs copy again). The two
 #: ``mla.chunk_*`` are the prefill calls' part: the cached tokens their rows
-#: attend (of ``mla.ctx_tokens_sum``) and the cached tokens a call sees, once
-#: a call (what a chunk reads; what an expanded form would put through
-#: ``attn_kv_b`` again, so ``benchmark/mla_cost.py`` can price both)
+#: attend (of ``mla.ctx_tokens_sum``), the cached tokens a call sees, once
+#: a call (what a chunk reads, and what the mathematics has to put through
+#: ``attn_kv_b``: ``benchmark/mla_cost.py`` prices both forms from it), and
+#: the cached tokens a head of the call DOES put through ``attn_kv_b``, by
+#: the kernel's own grid and rule (``latent_attention.chunk_expanded_keys``):
+#: a program expands a key tile once for all of its rows and a chunk of up
+#: to 1 536 rows is one program a head, so ``chunk_expanded_keys_sum /
+#: chunk_keys_sum`` reads 1.0 there (a whole prompt of 24 576 rows in one
+#: call, sixteen row tiles: 8.5; a form that expanded a 256-row tile at a
+#: time would read up to rows / 256; the absorbed jnp twin, which expands
+#: nothing, 0)
 _MLA_COUNTERS = ("mla.rows_sum", "mla.ctx_tokens_sum", "mla.pages_walked_sum",
-                 "mla.chunk_ctx_tokens_sum", "mla.chunk_keys_sum")
+                 "mla.chunk_ctx_tokens_sum", "mla.chunk_keys_sum",
+                 "mla.chunk_expanded_keys_sum")
 #: a model with an indexer (``cfg.index_heads``), counted on the host from
 #: the positions of a call's rows, summed over the layers: query rows (a
 #: decode lane's token, a chunk's tokens), the keys the indexer scored for
@@ -781,11 +792,11 @@ class ServingEngine:
                         Kp - 1, Kp, 0, Kp, np)[1])
 
     def _count_latent(self, extent: np.ndarray, pages: int,
-                      chunk: bool = False) -> None:
+                      chunk: int = 0) -> None:
         """A latent model: a call's REAL query rows, ``extent`` the cached
         tokens each attends (its own included), and the ``pages`` the call's
         attention walks; in every layer (:data:`_MLA_COUNTERS`). ``chunk``: a
-        prefill call."""
+        prefill call's padded rows."""
         cfg = self.cfg
         if not cfg.kv_lora_rank:
             return
@@ -796,6 +807,10 @@ class ServingEngine:
         if chunk:
             c["mla.chunk_ctx_tokens_sum"] += L * int(extent.sum())
             c["mla.chunk_keys_sum"] += L * int(extent.max())
+            if self._latent_prefill_path(chunk)[0] == "kernel" \
+                    and latent_form(chunk) == "expanded":
+                c["mla.chunk_expanded_keys_sum"] += L * chunk_expanded_keys(
+                    chunk, int(extent[0]) - 1, int(extent.max()))
 
     def _count_experts(self, out: np.ndarray, call: int) -> None:
         """A dropless MoE model's router load, from the fetched output of
@@ -1338,7 +1353,7 @@ class ServingEngine:
         self._count_selection(np.minimum(q0 + 1 + np.arange(Tb), q0 + n),
                               slice(n), -(-(q0 + n) // self.block_size))
         self._count_latent(q0 + 1 + np.arange(n),
-                           -(-(q0 + n) // self.block_size), chunk=True)
+                           -(-(q0 + n) // self.block_size), chunk=Tb)
         if Tb not in self._prefill_shapes:
             self._note_prefill_path(Tb)
         buf = np.zeros((self._layout.prefill_words(Tb),), np.int32)
@@ -1356,11 +1371,15 @@ class ServingEngine:
         """Gauge ``paged.prefill_path``: which way the attention of the
         prefill programs goes, ``{"kernel" | "reference[: why]": [query rows
         of the programs that went it]}``, from the shapes alone, as the
-        dispatcher decides it when a program is traced."""
+        dispatcher decides it when a program is traced. A latent model's
+        kernel is named with the form a chunk's rows take in it: ``"kernel
+        (expanded)"``."""
         from ..ops.attention import paged_attention_path
         cfg = self.cfg
         if cfg.kv_lora_rank:
             path, why = self._latent_prefill_path(Tb)
+            if path == "kernel":
+                path = f"kernel ({latent_form(Tb)})"
         else:
             pool = self.pools["k"]
             path, why = paged_attention_path(

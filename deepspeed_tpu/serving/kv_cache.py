@@ -82,8 +82,10 @@ def init_pool(cfg, num_blocks: int, block_size: int,
     row is 4.5 lane tiles and the chip's compiler handles such a leaf as it
     handled a 64-wide one (above; PERF.md section 6, PR 49 has the readings);
     the zeros add nothing to a score against a query padded the same way.
-    The absorbed kernel reads the row once for scores (all its lanes) and
-    values (its first ``kv_lora_rank``): ``ops/pallas/latent_attention.py``.
+    A decode call's absorbed kernel reads the row once for scores (all its
+    lanes) and values (its first ``kv_lora_rank``); a chunk's expands the
+    latent to its heads' keys and values once for all of the chunk's rows:
+    ``ops/pallas/latent_attention.py``.
 
     Flat slot layout (slot = block * block_size + offset), row-major: the
     paged forward and the paged-attention kernel both view the same buffer

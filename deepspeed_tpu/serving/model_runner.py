@@ -241,21 +241,31 @@ class PagedCache:
 
     def attend_latent(self, kv, li, q_nope, q_pe, wk, wv):
         """Latent attention through the block table, ``[B, heads, T,
-        v_head_dim]``, absorbed at every call shape (a decode token's heads
-        are rows over the one stored row, a chunk's tiles 2 heads x 256
-        rows); the kernel on a TPU or under ``interpret``, its jnp twin
-        elsewhere. The call's own rows are in the pool already."""
+        v_head_dim]``; the kernel on a TPU or under ``interpret``, in the
+        form its rows ask for (``latent.form``): a decode token ABSORBED
+        (``attn_kv_b`` folded into the query and the output here, its heads
+        the rows of one tile over the one stored row), a chunk EXPANDED
+        inside the kernel, each key tile once for all of its rows. Elsewhere
+        the absorbed jnp twin. The call's own rows are in the pool already."""
         cfg, lanes = self.cfg, kv["ckv"].shape[-1]
+        T = q_nope.shape[2]
         way, _ = latent.path(q_nope.shape[:3] + (lanes,), kv["ckv"].shape,
                              self.impl, self.interpret)
+        if way == "kernel" and latent.form(T) == "expanded":
+            with jax.named_scope("attend"):
+                return latent.latent_chunk_attention(
+                    q_nope, q_pe, wk, wv, kv["ckv"], self.bt, self.ctx,
+                    sm_scale=self.sm_scale, layer_idx=li,
+                    q_start=self.q_start, interpret=self.interpret)
         attend = partial(latent.latent_attention, interpret=self.interpret) \
-            if way == "kernel" else latent.latent_attention_reference
+            if way == "kernel" else partial(
+                latent.latent_attention_reference, q_start=self.q_start)
         with jax.named_scope("absorb"):
             q = absorb_query(q_nope, q_pe, wk, lanes)
         with jax.named_scope("attend"):
             o = attend(q, kv["ckv"], self.bt, self.ctx,
                        value=cfg.kv_lora_rank, sm_scale=self.sm_scale,
-                       layer_idx=li, q_start=self.q_start)
+                       layer_idx=li)
         with jax.named_scope("absorb"):
             return absorb_output(o, wv)
 

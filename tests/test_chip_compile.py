@@ -692,6 +692,36 @@ def test_gpt2_serving_programs_are_text_for_text_what_they_were(
                    if ("int8" in k) == int8}
 
 
+@pytest.mark.parametrize("rows", [1536, 4000, 24576])
+def test_latent_chunk_call_compiles_at_any_row_count(chip, rows):
+    """A latent model's chunk call alone at DeepSeek-V2's widths (128 heads
+    of 128 + 64, a latent of 512 on rows of 640 lanes, the cell's pool and
+    table): the cell's own chunk, and a whole prompt handed to the kernel in
+    ONE call (``serving.prefill_chunk_tokens`` 0, the default): a program
+    holds a row tile of at most 1 536 rows, whatever the call's, so what it
+    takes of VMEM is the cell's and Mosaic compiles it (before the row
+    tiles went on the grid a program held the whole call: 12 KB a row, over
+    the kernel's 48 MB limit from about 3 500 rows)."""
+    from deepspeed_tpu.ops.pallas import latent_attention as la
+    bf16, nh, bs, nb, nbk = jnp.bfloat16, 128, 32, 16384, 800
+    n, per = la.chunk_tiles(rows)
+    assert per <= 1536
+
+    def call(qn, qp, wk, wv, pool, bt, lens, q0):
+        return la.latent_chunk_attention(
+            qn, qp, wk, wv, pool, bt, lens, sm_scale=0.1147, layer_idx=3,
+            q_start=q0)
+
+    text = jax.jit(call).lower(
+        chip((1, nh, rows, 128), bf16), chip((1, nh, rows, 64), bf16),
+        chip((nh, 512, 128), bf16), chip((nh, 512, 128), bf16),
+        chip((5, 1, nb, bs, 640), bf16), chip((1, nbk), jnp.int32),
+        chip((1,), jnp.int32), chip((1,), jnp.int32)).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "paged_attention_latent" in line]
+    assert len(calls) == 1 and f"bf16[1,128,{n * per},128]" in calls[0], calls
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill1536"])
 def test_deepseek_serving_step_keeps_the_latent_pool_in_place(
         chip, monkeypatch, program):
@@ -700,7 +730,8 @@ def test_deepseek_serving_step_keeps_the_latent_pool_in_place(
     128 heads over ONE stored row of 512 + 64 on 640 lanes, 20 of 160
     experts of 1536 held, a leading dense layer of 12288; 32 lanes over a
     16384 x 32 pool, tables of 800 blocks): each of the two stacks' bodies
-    has its latent-attention call, the sparse one the held experts' three
+    has its latent-attention call (a decode call's absorbed, a chunk's
+    expanded inside the kernel), the sparse one the held experts' three
     grouped matmuls; the pool is ONE leaf, donated and updated in place, and
     no instruction copies it, a layer of it, or slices a layer out; the
     counts carry the kept groups behind the experts; and weights, pool and
@@ -755,6 +786,15 @@ def test_deepseek_serving_step_keeps_the_latent_pool_in_place(
     latent = [k for k in kernels if "paged_attention" in k]
     assert len(latent) == 2 and len(kernels) == 5, kernels
     assert text.count("paged_attention_latent") >= 2
+    # a decode call attends absorbed (the heads the rows of a lane's tile,
+    # the latent's 512 lanes out, absorb matmuls beside it); a chunk
+    # expanded inside the kernel: all of its rows a head, 128 lanes out
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "paged_attention" in line]
+    out = "bf16[32,1,128,512]" if program == "decode" else \
+        f"bf16[1,128,{chunk},128]"
+    assert len(calls) == 2 and all(f" = {out}" in c for c in calls), calls
+    assert ("absorb" in text) == (program == "decode")
     made = [r for r in _results(text) if r[1] not in (
         "parameter", "get-tuple-element", "while", "tuple", "bitcast")]
     layer = NB * BS * 640

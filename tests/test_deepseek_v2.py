@@ -5,7 +5,8 @@ a rotated key of 4, narrower than the 4 heads x 12 it stands for; rope 4 of
 a 12-wide head as 64 of 192), YaRN rotary, and behind a leading dense layer
 a chip's share of a group-limited softmax mixture (32 outputs in 8 groups of
 which a token keeps 3, top-6, scaled 16, not renormalised, two shared
-experts; this chip holds group 0). The program attends ABSORBED."""
+experts; this chip holds group 0). The program's decode calls and its jnp
+twin attend ABSORBED, the kernel's chunk calls EXPANDED."""
 import dataclasses
 import functools
 
@@ -198,52 +199,112 @@ def _expanded(pool, bt, ctx, q0, qn, qp, wk, wv, layer, rank, scale):
     return out
 
 
-@pytest.mark.parametrize("shape", ["decode", "chunk", "two_row_tiles",
-                                   "first_chunk"])
+#: a call's rows, then lane 0's and lane 1's (first position, context);
+#: lane 2 is idle. The kernel's chunk tile is 256 rows, its key group the
+#: table's first 512 tokens, then the rest
+CALLS = {
+    "decode": (1, (76, 77), (8, 9)),
+    "chunk": (20, (50, 70), (3, 21)),
+    "two_row_tiles": (300, (50, 350), (3, 301)),
+    "first_chunk": (40, (0, 40), (0, 38)),
+    # behind a cached context that ends inside the second key group, the
+    # first row tile's diagonal in one group and the second's in the next
+    "behind_a_context": (300, (440, 740), (301, 601)),
+    # a prompt's last chunk: 37 and 5 real rows of a 512-row bucket
+    "padded_last_chunk": (512, (600, 637), (40, 45)),
+    # a prefix hit's: first positions that are whole blocks and no row tiles
+    "prefix_hit": (264, (520, 784), (8, 272)),
+    # a whole prompt in one call (``prefill_chunk_tokens`` 0): more rows
+    # than a program holds, so two row tiles of 1 024 on the grid, each
+    # expanding what it sees; lane 1's second tile is padding rows only
+    "whole_prompt": (1600, (0, 1600), (200, 1100)),
+}
+
+
+@pytest.mark.parametrize("shape", list(CALLS))
 def test_absorbed_equals_expanded_on_one_cache(shape):
-    """One pool of latent rows, one block table: the absorbed kernel and its
+    """One pool of latent rows, one block table: the kernel and the absorbed
     jnp twin give what the EXPANDED form gives on the same rows (every
     latent through ``attn_kv_b`` to a head's key and value, worked plainly
-    here); a decode call's heads are rows of one tile, a chunk of 300 rows
-    is two row tiles of which the first walks fewer pages, a first chunk's
-    context is its own rows (the one shape the chip read faster expanded),
-    and an idle lane reads zeros."""
+    here). A decode call's heads are rows of one absorbed tile; a chunk's
+    call is expanded inside the kernel, a key group once for all of its row
+    tiles, of which an earlier one meets fewer groups (:data:`CALLS`);
+    padding rows are finite and an idle lane reads zeros."""
     rng = np.random.default_rng(0)
-    L, NB, bs, W, R, ROPE, NH, NOPE, V = 2, 64, 8, 256, 128, 16, 8, 32, 32
+    L, bs, W, R, ROPE, NH, NOPE, V = 2, 8, 256, 128, 16, 8, 32, 32
+    T, *lanes = CALLS[shape]
+    NB, nbk = (128, 100) if max(ctx for _, ctx in lanes) <= 800 else (256, 200)
     pool = jnp.asarray(rng.normal(size=(L, 1, NB, bs, W)), jnp.float32
                        ).at[..., R + ROPE:].set(0)
-    T = {"decode": 1, "chunk": 20, "two_row_tiles": 300,
-         "first_chunk": 40}[shape]
-    nbk = 48
     bt = jnp.asarray(rng.permutation(NB - 1)[:nbk].reshape(1, nbk) + 1,
                      jnp.int32)
     bt = jnp.concatenate([bt, bt[:, ::-1], jnp.zeros_like(bt)])
-    q0 = np.asarray([0, 0, 0] if shape == "first_chunk" or T == 1
-                    else [50, 3, 0])
-    ctx = np.asarray([q0[0] + T, q0[1] + T - 2, 0] if T > 1 else [77, 9, 0])
+    q0 = np.asarray([lane[0] for lane in lanes] + [0])
+    ctx = np.asarray([lane[1] for lane in lanes] + [0])
     wk = jnp.asarray(rng.normal(size=(NH, R, NOPE)) * R ** -.5, jnp.float32)
     wv = jnp.asarray(rng.normal(size=(NH, R, V)) * R ** -.5, jnp.float32)
     qn = jnp.asarray(rng.normal(size=(3, NH, T, NOPE)), jnp.float32)
     qp = jnp.asarray(rng.normal(size=(3, NH, T, ROPE)), jnp.float32)
-    kw = dict(value=R, sm_scale=0.11, layer_idx=jnp.int32(1),
-              q_start=jnp.asarray(q0, jnp.int32) if T > 1 else None)
-    want = _expanded(pool, bt, ctx, q0 if T > 1 else ctx - 1, qn, qp, wk, wv,
-                     1, R, 0.11)
-    real = ctx[:2, None] - q0[:2, None] > np.arange(T)[None] if T > 1 \
-        else np.ones((2, 1), bool)
+    lens, first = jnp.asarray(ctx, jnp.int32), jnp.asarray(q0, jnp.int32)
+    kw = dict(sm_scale=0.11, layer_idx=jnp.int32(1))
+    want = _expanded(pool, bt, ctx, q0, qn, qp, wk, wv, 1, R, 0.11)
+    real = ctx[:2, None] - q0[:2, None] > np.arange(T)[None]
     qa = absorb_query(qn, qp, wk, W)
-    lens = jnp.asarray(ctx, jnp.int32)
-    forms = [absorb_output(attend(qa, pool, bt, lens, **kw), wv)
-             for attend in (la.latent_attention_reference,
-                            functools.partial(la.latent_attention,
-                                              interpret=True))]
-    for got in forms:
+    twin = absorb_output(la.latent_attention_reference(
+        qa, pool, bt, lens, value=R, q_start=first, **kw), wv)
+    assert la.form(T) == ("absorbed" if T == 1 else "expanded")
+    if T == 1:
+        kernel = absorb_output(la.latent_attention(
+            qa, pool, bt, lens, value=R, interpret=True, **kw), wv)
+    else:
+        kernel = la.latent_chunk_attention(
+            qn, qp, wk, wv, pool, bt, lens, q_start=first, interpret=True,
+            **kw)
+    for got in (twin, kernel):
         assert got.shape == (3, NH, T, V)
         for lane in range(2):
             np.testing.assert_allclose(
                 np.asarray(got)[lane][:, real[lane]],
                 want[lane][:, real[lane]], atol=2e-5, rtol=0)
-    assert not np.asarray(forms[1])[2].any()        # the idle lane
+    assert np.isfinite(np.asarray(kernel)).all()    # the padding rows
+    assert not np.asarray(kernel)[2].any()          # the idle lane
+
+
+@pytest.mark.parametrize("rows, tiles", [
+    (1, (1, 256)), (256, (1, 256)), (300, (1, 512)), (1536, (1, 1536)),
+    (1537, (2, 1024)), (3072, (2, 1536)), (4000, (3, 1536)),
+    (24576, (16, 1536)), (24577, (17, 1536))])
+def test_a_chunk_program_holds_a_bounded_number_of_rows(rows, tiles):
+    """A call's rows go to the fewest even row tiles of at most 1 536 rows
+    (what a program's VMEM is sized for), whole 256-row tiles each."""
+    assert la.chunk_tiles(rows) == tiles
+    n, per = tiles
+    assert per <= la._CHUNK_ROWS and per % 256 == 0 and n * per >= rows
+
+
+def test_the_host_counts_what_a_call_expands_by_the_kernels_own_grid():
+    """``chunk_expanded_keys``: a head of a one-tile call expands the
+    lane's live tokens once; of a longer call every row tile the keys up to
+    its own last row, none where a tile is padding only."""
+    assert la.chunk_expanded_keys(1536, 12288, 13824) == 13824
+    assert la.chunk_expanded_keys(512, 600, 637) == 637
+    # a whole prompt of 24 576 rows: sixteen tiles, 1 536 x (1 + ... + 16)
+    assert la.chunk_expanded_keys(24576, 0, 24576) == 1536 * 136
+    # two tiles of 1 024; 1 100 live tokens from 200: the second tile
+    # (from 1 224) is padding only
+    assert la.chunk_expanded_keys(1600, 200, 1100) == 1100
+    assert la.chunk_expanded_keys(1600, 0, 1600) == 1024 + 1600
+
+
+def test_a_chunk_is_no_call_of_the_absorbed_kernel():
+    """ONE chunk form: the absorbed kernel takes a decode call's one row a
+    lane and says whose a chunk is."""
+    pool = jnp.zeros((1, 1, 4, 8, 128))
+    with pytest.raises(ValueError, match="latent_chunk_attention"):
+        la.latent_attention(jnp.zeros((1, 8, 2, 128)), pool,
+                            jnp.zeros((1, 2), jnp.int32), jnp.asarray([9]),
+                            value=64, sm_scale=1.0, layer_idx=0,
+                            interpret=True)
 
 
 def test_yarn_frequencies_and_scale_against_numbers_worked_by_hand():
@@ -344,7 +405,6 @@ def test_the_hand_out_follows_the_reference_and_a_wrong_router_reads_a_deficit(
     assert c["mla.ctx_tokens_sum"] == 3 * sum(
         n * (n + 1) // 2 for n in (70 + 5, 19 + 5))
     assert c["mla.pages_walked_sum"] > 0
-    assert "mla.expanded_tokens_sum" not in c       # one form: none expands
     # under even routing 3 rows in 8 keep the held group; every held
     # assignment is of such a row
     assert 0 < c["moe.group_rows_sum"] < 2 * fed
@@ -354,9 +414,30 @@ def test_the_hand_out_follows_the_reference_and_a_wrong_router_reads_a_deficit(
         g["kv.latent_lanes"] == 128 and g["kv.stored_heads"] == 1
     # (this engine was built on the jnp twins)
     assert g["paged.prefill_path"] == {"reference": [16, 8]}
+    # the absorbed twin expands nothing
+    assert c["mla.chunk_keys_sum"] > 0 == c["mla.chunk_expanded_keys_sum"]
     srv.close()
+
+
+def test_a_served_chunk_expands_each_cached_token_once(tiny):
+    """On the kernel (interpreted) a prompt's chunks attend EXPANDED: the
+    cached tokens a head puts through ``attn_kv_b`` are the cached tokens
+    the calls see, once a call, and the gauge names the form; the tokens are
+    the absorbed twins' engine's."""
+    cfg, params = tiny
+    prompt = np.random.default_rng(5).integers(1, 64, size=45).tolist()
+    want = cold_tokens(cfg, params, prompt, 4)
     srv = _engine(dataclasses.replace(cfg, attention_impl="auto"), params)
     assert srv._latent_prefill_path(16) == ("kernel", None)
+    r = srv.submit(prompt, max_new_tokens=4)
+    srv.run_until_idle()
+    assert r.output_tokens == want
+    t = srv.telemetry()
+    c = t["counters"]
+    # chunks of 16, 16 and 13 rows see 16, 32 and 45 cached tokens, 3 layers
+    assert c["mla.chunk_expanded_keys_sum"] == c["mla.chunk_keys_sum"] == \
+        3 * (16 + 32 + 45)
+    assert t["gauges"]["paged.prefill_path"] == {"kernel (expanded)": [16]}
     srv.close()
 
 
