@@ -288,7 +288,7 @@ def _kernel(bt_ref, lens_ref, misc_ref, q_ref, pool, o_ref, buf, acc, m_scr,
         rows = buf[slot]                                # [1, P * bs, width]
         _attend(q, rows, rows[:, :, :value], None, None, i * (P * bs),
                 lens_ref[b], 0, None, acc, m_scr, l_scr, sm_scale=sm_scale,
-                softcap=0.0, q0=None)
+                softcap=0.0)
 
     loop(tile)
     l = l_scr[:, :, :1]

@@ -23,7 +23,8 @@ with the tokens each sequence has generated, not with the pool or the table.
 
 ``P`` is a function of the operand shapes alone (:func:`_pages_per_group`:
 the largest power of two at which four group buffers of the program's QUERY
-heads fit :data:`_VMEM_BUDGET`, at most the table's length).
+heads fit :data:`_VMEM_BUDGET`, at most the table's length; a chunk's, at
+most :data:`_CHUNK_KEYS` keys).
 
 **The tile is a stored head's query-head group** (PR 44). The pool holds
 the model's KV heads, ``q`` comes at its ``nh`` query heads, and ``group =
@@ -42,7 +43,11 @@ A prefill chunk is the same loop under taller query tiles, ``[query heads,
 T, head_dim]`` with a stored head's group one head after another: row r of
 each is the query at ``q_start + r``, so positions and masks are worked out
 once for all the program's heads, and each query head's two matmuls run
-against the one copy of the stored head it shares (:func:`_attend`). The
+against the one copy of the stored head it shares (:func:`_attend_chunk`,
+PR 51: a turn of 512 keys, a query head at a time, the keys as lane tiles
+of 128; the running max alike on a row's lanes, so that it crosses lanes
+once a head and turn, the running sum a lane apart, one rescale of the
+accumulator a head and turn). The
 causal and window masks are a row's own, the
 pages run from the first query's window to the last real query's page
 (``ctx - 1``: ``cache.write`` has put the chunk's own keys into the pool
@@ -50,10 +55,10 @@ just before), and rows at or past ``ctx`` are bucket padding whose finite
 garbage nobody reads. A chunk's rows are padded to whole tiles of
 :data:`_CHUNK_TILE` and the call is made under ``jax.jit``, so the prefill
 programs of a serving loop (one a chunk shape) trace ONE shape once; heads
-a program and pages a group shrink with the rows (:func:`_head_group`,
-:func:`_pages_per_group`, counted in QUERY heads' rows) so that the float32
-accumulator, the two running rows and one group's scores stay inside the
-chip's scoped VMEM beside the page buffers, and a group's page copies are a
+a program shrink with the rows (:func:`_head_group`, counted in QUERY
+heads' rows) so that the float32 accumulator and the two running rows stay
+inside the chip's scoped VMEM beside the page buffers and ONE head's scores
+of a row tile (``[256, 512]`` float32), and a group's page copies are a
 loop, not unrolled. Where a whole group's rows are more than a program
 holds (:data:`_CHUNK_ROWS`: K-EXAONE's 8 x 256), the group's query heads are
 split over programs that each copy the stored head (:func:`_program_heads`).
@@ -77,10 +82,12 @@ in kind with jax's own ``pallas/ops/tpu/paged_attention`` kernel.
 a layer whose queries attend their top-k keys by an index score hands the
 kernel those scores for every key of the lane and, a query row, the value of
 its k-th best and the position up to which equal scores count. The scores
-ride in HBM as ``[lanes, groups, rows, P * block_size]``, a copy group's keys
-a row of whole 128-lane tiles (so a selecting call takes at least ``128 /
-block_size`` pages a group), copied beside the group's pages under a third
-semaphore; the threshold and tie position are a lane's VMEM block. A key
+ride in HBM as ``[lanes, groups, rows, P * block_size]`` (a chunk's:
+``[lanes, groups, tiles, rows, 128]``, a lane tile of a copy group's keys at
+a time, as its turn takes them), a copy group's keys whole 128-lane tiles
+(so a selecting call takes at least ``128 / block_size`` pages a group),
+copied beside the group's pages under a third semaphore; the threshold and
+tie position are a lane's VMEM block. A key
 the row did not select is masked beside the causal, context and window
 masks: the loop still walks every live page (with seeded weights the
 selected keys scatter over all of them; reading fewer is ROADMAP M7 (a)).
@@ -107,7 +114,7 @@ and lets a kernel copy no less.
 
 from __future__ import annotations
 
-from functools import partial
+from functools import partial, reduce
 from typing import Optional
 
 import jax
@@ -121,7 +128,7 @@ from .sparse_select import selected as _selected
 from .sparse_select import untileable as _select_untileable
 
 __all__ = ["paged_attention", "paged_attention_reference", "scale_rows",
-           "untileable"]
+           "untileable", "group_span", "chunk_plan", "chunk_walk"]
 
 #: least query rows a stored head's tile holds — the sublane minimum, so
 #: every operand is a legal (>=8)x128 tile: a decode token of a head nobody
@@ -144,9 +151,19 @@ _CHUNK_ROWS = 1024
 #: chunk's own cost a first chunk little, since the loop walks live pages
 _CHUNK_TILE = 256
 
-#: VMEM one group's float32 scores [heads, rows, keys] may take (the mask,
-#: the probabilities and their bf16 copy live beside them, about 4x this)
-_SCORE_BUDGET = 1 << 20
+#: keys of a CHUNK program's copy group (PR 51). A turn pays a cost a (head,
+#: row) whatever its keys (the row's max across lanes, the accumulator read,
+#: scaled and written, the copies started and waited for): 256 / 512 / 1 024
+#: keys a turn read 2.70 / 2.36 / 2.57 ms for the long-document cell's chunk
+#: call behind 5 120 keys, and a first chunk 0.46 / 0.53 / 0.72 (PERF.md,
+#: PR 51). One query head's ``[256, 512]`` float32 scores (512 KB) live at
+#: once
+_CHUNK_KEYS = 512
+
+#: lanes of a vector register: a chunk's turn takes its keys a tile of this
+#: many at a time (:func:`_lane_tile`)
+_LANES = 128
+
 
 
 def _query_rows(T: int, group: int = 1) -> int:
@@ -223,7 +240,7 @@ def _pages_per_group(hq: int, bs: int, hd: int, itemsize: int, nbk: int,
     :data:`_VMEM_BUDGET` (the int8 tier's scale rows counted as the padded
     (8, 128) float32 tiles they may take in VMEM), never more than the
     table holds; under a chunk of T > 1 query rows a head also no more than
-    keep one group's scores inside :data:`_SCORE_BUDGET`.
+    :data:`_CHUNK_KEYS` keys.
 
     The buffers hold the STORED heads, ``hq // group`` of them, so a
     grouped-query model's take ``1 / group`` of the budget: the pages are
@@ -233,51 +250,112 @@ def _pages_per_group(hq: int, bs: int, hd: int, itemsize: int, nbk: int,
     lowering at every start: at the 16 pages 8 stored heads would allow,
     ``setup_s`` rose 3.3 s (mistral-7b, 4 pages before) and 5.7 s
     (K-EXAONE, 2), and the kernel was no faster than at 4 or 8 (PERF.md,
-    PR 44)."""
+    PR 44). A chunk's copies are a loop."""
     page = 4 * hq * bs * hd * itemsize            # K and V, two slots
-    if quant:
-        page += 4 * hq * 8 * _scale_lanes(bs) * 4
-    scores = hq * _query_rows(T) * bs * 4 if T > 1 else 0
+    if quant:                                # :func:`_scale_lanes` of bs
+        page += 4 * hq * 8 * (-(-bs // _LANES) * _LANES) * 4
     p = 1
     while 2 * p * page <= _VMEM_BUDGET and 2 * p <= nbk \
-            and 2 * p * scores <= _SCORE_BUDGET:
+            and (T == 1 or 2 * p * bs <= _CHUNK_KEYS):
         p *= 2
     return p
 
 
+def _lane_tile(n: int) -> int:
+    """Keys of one lane tile of a chunk's copy group of ``n`` keys: a vector
+    register's lanes, or the whole group where it is no whole tiles (a table
+    shorter than one, pages that do not divide one, the interpreter's small
+    shapes)."""
+    return _LANES if n % _LANES == 0 else n
+
+
+def group_span(ctx, start, window, P: int, bs: int, nbk: int, xp=jnp):
+    """``(first, cnt, g0, g1)``: a lane's first and one-past-last live page
+    and the copy groups of ``P`` pages that hold them, for a context of
+    ``ctx`` tokens whose first query's window ends at ``start`` (a decode
+    token: ``ctx``; a chunk: one past its first row). A window drops the
+    pages wholly before it (before the window of a chunk's FIRST query: the
+    last real one, at ctx - 1, sets the last page); an idle lane (ctx 0) has
+    no group at all. The kernel's own rule (:func:`_loop_kernel`, handed in:
+    the serving loop calls it on the host, and the package's linter takes
+    every function a traced body names for device work); ``xp``: numpy for a
+    host that counts what the kernel will do (:func:`chunk_walk`)."""
+    cnt = xp.minimum((ctx + bs - 1) // bs, nbk)
+    first = xp.where(window > 0, xp.maximum(start - window, 0) // bs, 0)
+    return first, cnt, first // P, (cnt + P - 1) // P
+
+
+def _plan(nh: int, kvh: int, bs: int, hd: int, itemsize: int, nbk: int,
+          T: int, quant: bool = False, select: bool = False):
+    """``(hg, gq, rows, P, lanes)`` of a call, from its shapes alone: the
+    stored heads a program takes and the query heads of each it serves
+    (:func:`_program_heads`), the rows of a query tile
+    (:func:`_query_rows`), the pages of its copy group
+    (:func:`_pages_per_group`; under a selection a group's keys fill whole
+    128-lane rows of the index scores) and the keys of a lane tile of it, as
+    a chunk's turn takes them (:func:`_lane_tile`). Made where the call is
+    (:func:`paged_attention`) and handed to the jitted one as a static: the
+    serving loop asks the same functions on the host
+    (:func:`chunk_plan`), and the package's linter takes every function a
+    traced body names for device work."""
+    hg, gq = _program_heads(nh, kvh, bs, hd, itemsize, T)
+    P = _pages_per_group(hg * gq, bs, hd, itemsize, nbk, quant, T)
+    if select:
+        P = max(P, -(-_LANES // bs))
+    return (hg, gq, _query_rows(T, nh // kvh if T == 1 else 1), P,
+            _lane_tile(P * bs))
+
+
+def chunk_plan(nh: int, kvh: int, bs: int, hd: int, itemsize: int, nbk: int,
+               T: int, quant: bool = False, select: bool = False):
+    """``(programs a lane, pages a copy group, keys a lane tile)`` of a call
+    of T > 1 rows a lane, from the shapes as :func:`paged_attention` derives
+    them."""
+    hg, gq, _, P, lanes = _plan(nh, kvh, bs, hd, itemsize, nbk, T, quant,
+                                select)
+    return kvh // hg * (nh // kvh // gq), P, lanes
+
+
+def chunk_walk(q_start: int, ctx: int, window: int, T: int, P: int,
+               lanes: int, bs: int, nbk: int):
+    """``(turns, key tiles, live key tiles)`` of ONE program of a lane whose
+    T > 1 rows start at ``q_start`` in a context of ``ctx`` under a layer's
+    ``window`` (<= 0: none), ``P`` pages of ``bs`` slots a copy group,
+    ``lanes`` keys a lane tile (:func:`chunk_plan`): the copy groups the
+    program walks (:func:`group_span`), the lane tiles those hold, each
+    computed for every :data:`_CHUNK_TILE` of the program's rows,
+    and those of them that hold a key some REAL row of that row tile sees
+    (causal, inside the context and the window): a first chunk's turn, a
+    window's, and the turn a context ends in compute tiles that are all
+    mask. On the host, with numpy: ``serving.engine`` counts them."""
+    n = P * bs
+    _, _, g0, g1 = group_span(ctx, q_start + 1, window, P, bs, nbk, np)
+    if g1 <= g0:
+        return 0, 0, 0
+    tile = np.arange(g0 * n, g1 * n, lanes)[:, None]        # its first key
+    first = q_start + np.arange(0, T, _CHUNK_TILE)[None]   # its row tiles
+    last = np.minimum(first + _CHUNK_TILE, ctx) - 1
+    low = np.maximum(first + 1 - window, 0) if window > 0 else 0
+    live = (first < ctx) & (tile <= last) & (tile + lanes > low)
+    return int(g1 - g0), live.size, int(live.sum())
+
+
 def _attend(q, k, v, ks, vs, k0, ctx, window, slopes_ref, acc, m_scr, l_scr,
-            *, sm_scale, softcap, q0=None, sel=None):
-    """One online-softmax update: the query tile ``q`` of ``hg`` stored
-    heads against their keys ``k`` / values ``v`` [hg, n, hd] at logical
-    positions ``[k0, k0 + n)``, folded into the running max, sum and output.
+            *, sm_scale, softcap, sel=None):
+    """One online-softmax update of a DECODE token: the query tile ``q``
+    [hg, rows, hd] of ``hg`` stored heads, a stored head's rows the query
+    heads that share it, their one token at ``ctx - 1`` each (a lone head's
+    token broadcast), against their keys ``k`` / values ``v`` [hg, n, hd] at
+    logical positions ``[k0, k0 + n)``, folded into the running max, sum and
+    output. (A prefill chunk's turn is :func:`_attend_chunk`.)
 
-    A decode token (``q0`` None): ``q`` [hg, rows, hd], a stored head's rows
-    the query heads that share it, their one token at ``ctx - 1`` each (a
-    lone head's token broadcast). A prefill chunk: ``q`` [heads, rows, hd]
-    for the program's ``heads = hg x gq`` QUERY heads, ``gq`` to a stored
-    head, row r the query at ``q0 + r``: a tile a query head, so positions
-    and masks are worked out once for all of them, and a matmul a query
-    head against the stored head it shares (ONE matmul over a stored head's
-    ``gq x rows`` queries ran a third slower on the chip: PERF.md, PR 44).
-
-    ``sel`` (a layer with an indexer, ``sparse_select``): ``(scores [R, n],
-    thr [R, 1], tie [R, 1])`` of these n keys for the tile's query rows (R
-    1 for a decode token: its query heads share the token's selection); a
-    key that is not one of its row's top k is masked like one the causal
-    mask hides."""
-    hg, heads = k.shape[0], q.shape[0]
-    gq = heads // hg
-
+    ``sel`` (a layer with an indexer, ``sparse_select``): ``(scores [1, n],
+    thr [1, 1], tie [1, 1])`` of these n keys (the token's query heads share
+    its selection); a key that is not one of the top k is masked like one
+    the causal mask hides."""
     def matmul(a, b, contract):
-        """``a`` [heads, ., .] against ``b`` [hg, ., .], query head h against
-        stored head ``h // gq``."""
-        dot = lambda a, b: jax.lax.dot_general(
-            a, b, (contract, ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        if gq == 1:
-            return dot(a, b)
-        return jnp.concatenate([dot(a[h:h + 1], b[h // gq:h // gq + 1])
-                                for h in range(heads)], axis=0)
+        return jax.lax.dot_general(a, b, (contract, ((0,), (0,))),
+                                   preferred_element_type=jnp.float32)
 
     if ks is not None:
         # int8 tier (round 17): the copies moved int8 rows + one f32 scale
@@ -290,8 +368,6 @@ def _attend(q, k, v, ks, vs, k0, ctx, window, slopes_ref, acc, m_scr, l_scr,
         # q.dtype convert is exact (|int8| <= 127)
         k = k.astype(jnp.float32).astype(q.dtype)
         v = v.astype(jnp.float32).astype(q.dtype)
-        if gq > 1:      # a stored head's scales for each of its query heads
-            ks, vs = jnp.repeat(ks, gq, axis=0), jnp.repeat(vs, gq, axis=0)
     s = matmul(q, k, ((2,), (2,)))
     if ks is not None:
         s = s * ks
@@ -299,18 +375,11 @@ def _attend(q, k, v, ks, vs, k0, ctx, window, slopes_ref, acc, m_scr, l_scr,
     if softcap:
         s = jnp.tanh(s / softcap) * softcap
     # the keys' logical positions do not depend on which PHYSICAL pages the
-    # table routed the copies to
-    if q0 is None:
-        # one real query a row (a lone head's: broadcast over the 8 padded
-        # rows), all at absolute (logical) position ctx - 1
-        q_abs = ctx - 1
-        k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-    else:
-        # a chunk: row r is the query at q0 + r; positions and masks are
-        # worked out once for the heads of the program, [1, rows, n]
-        one = (1,) + s.shape[1:]
-        q_abs = q0 + jax.lax.broadcasted_iota(jnp.int32, one, 1)
-        k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, one, 2)
+    # table routed the copies to; one real query a row (a lone head's:
+    # broadcast over the 8 padded rows), all at absolute (logical) position
+    # ctx - 1
+    q_abs = ctx - 1
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
     if slopes_ref is not None:
         # a slope a head of the tile, [heads, 1, 1]; a decode tile of a
         # group has a query head a row: [hg, rows, 1]
@@ -318,11 +387,6 @@ def _attend(q, k, v, ks, vs, k0, ctx, window, slopes_ref, acc, m_scr, l_scr,
                  else slopes_ref[0][:, :1][:, None, :])
         s = s + slope * (k_pos - q_abs).astype(jnp.float32)
     keep = k_pos <= q_abs                                   # causal + dead tail
-    if q0 is not None:
-        # a row at or past ctx is bucket padding: it sees the live keys and
-        # no page the loop skipped, so that what it gives is finite (nobody
-        # reads it)
-        keep &= k_pos < ctx
     keep &= (q_abs - k_pos < window) | (window <= 0)        # sliding window
     if sel is not None:
         sc, thr, tie = sel
@@ -343,6 +407,98 @@ def _attend(q, k, v, ks, vs, k0, ctx, window, slopes_ref, acc, m_scr, l_scr,
 
 def _finish(o_ref, acc, l_scr):
     l = l_scr[:, :, :1]
+    o_ref[0, 0] = (acc[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
+def _attend_chunk(q_ref, k_buf, v_buf, scales, sel, slot, k0, ctx, q0,
+                  window, slopes_ref, acc, m_scr, l_scr, *, gq, sm_scale,
+                  softcap):
+    """One turn of a prefill CHUNK's program: its ``heads = hg x gq`` query
+    heads' rows (``q_ref`` [1, 1, heads, rows, hd], row r of each the query
+    at ``q0 + r``, head h reading stored head ``h // gq``) against the copy
+    group in buffer ``slot`` (``k_buf`` / ``v_buf`` [2, hg, n, hd], keys at
+    logical positions ``[k0, k0 + n)``), folded into the running max, sum
+    and output; a :data:`_CHUNK_TILE` of rows and a query head at a time,
+    the group's keys as lane tiles of ``lanes`` (``m_scr``'s width).
+
+    * The masks once a row tile for all of its heads, as ONE additive tile a
+      lane tile, 0 or ``NEG_INF``: causal and the context's end (a key up to
+      ``min(row, ctx - 1)``: a row at or past ctx is bucket padding, sees the
+      live keys and no page the loop skipped, finite and read by nobody), the
+      window (a key behind ``row - window``), and a layer's selection
+      (``sel``: the group's index scores ``[2, tiles, rows, lanes]`` beside
+      each row's threshold and tie position, ``sparse_select.selected``).
+      Added, not selected by, so a key no copy brought has to be finite: the
+      first program zeroes the K buffers too (:func:`_loop_kernel`).
+    * A head's scores in ONE matmul against the stored head it shares (one
+      matmul over a stored head's ``gq x rows`` queries ran a third slower
+      on the chip: PERF.md, PR 44); the running max lies alike on all
+      ``lanes`` of its row, so the tiles' elementwise max crosses lanes ONCE
+      a (head, turn); the running sum lies A LANE APART (a lane its share of
+      the keys, added up in :func:`_finish_chunk`); one value matmul and ONE
+      rescale of the accumulator a (head, turn).
+
+    With a row's max AND sum across lanes, the four heads' ``[4, 256, 256]``
+    scores at once and an accumulator pass every 256 keys the call read 1.5
+    to 1.9 times this one's time behind 5 120 keys; loops of a dynamic trip
+    count over the live lane tiles only read SLOWER than the parent at every
+    shape (PERF.md, PR 51). int8 tier: ``scales`` = the group's K and V
+    scales ``[hg, 1, n]`` (:func:`_attend`)."""
+    heads, rows, hd = acc.shape
+    n, lanes, rt = k_buf.shape[2], m_scr.shape[-1], _CHUNK_TILE
+    tiles = [slice(j, j + lanes) for j in range(0, n, lanes)]
+    dot = partial(jax.lax.dot_general, preferred_element_type=jnp.float32)
+    for r0 in range(0, rows, rt):
+        at = pl.ds(r0, rt)
+        q_abs = q0 + r0 + jax.lax.broadcasted_iota(jnp.int32, (rt, lanes), 0)
+        k_pos = [k0 + t.start + jax.lax.broadcasted_iota(
+            jnp.int32, (rt, lanes), 1) for t in tiles]
+        last = jnp.minimum(q_abs, ctx - 1)
+        behind = q_abs - jnp.where(window > 0, window, 1 << 30)
+        keep = [(k <= last) & (k > behind) for k in k_pos]
+        if sel is not None:
+            sel_buf, thr_ref, tie_ref = sel
+            one = slice(None) if lanes == thr_ref.shape[-1] else slice(1)
+            thr, tie = thr_ref[0, at, one], tie_ref[0, at, one]
+            keep = [ok & _selected(sel_buf[slot, j, at], thr, tie, k)
+                    for j, (ok, k) in enumerate(zip(keep, k_pos))]
+        bias = [jnp.where(ok, 0.0, NEG_INF) for ok in keep]
+        for h in range(heads):
+            kv = h // gq
+            q, k, v = q_ref[0, 0, h, at], k_buf[slot, kv], v_buf[slot, kv]
+            if scales is not None:
+                k = k.astype(jnp.float32).astype(q.dtype)
+                v = v.astype(jnp.float32).astype(q.dtype)
+            s = dot(q, k, (((1,), (1,)), ((), ())))
+            if scales is not None:
+                s = s * scales[0][kv]
+            s = s * sm_scale
+            if softcap:
+                s = jnp.tanh(s / softcap) * softcap
+            s = [s[:, t] for t in tiles]
+            if slopes_ref is not None:
+                slope = slopes_ref[0][h:h + 1, :1]
+                s = [x + slope * (k - q_abs).astype(jnp.float32)
+                     for x, k in zip(s, k_pos)]
+            s = [x + b for x, b in zip(s, bias)]
+            m_prev = m_scr[h, at]
+            m_cur = jnp.maximum(m_prev, jnp.max(
+                reduce(jnp.maximum, s), axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_cur)
+            p = [jnp.exp(x - m_cur) for x in s]
+            l_scr[h, at] = l_scr[h, at] * alpha + sum(p[1:], p[0])
+            p = jnp.concatenate(p, axis=1)
+            if scales is not None:
+                p = p * scales[1][kv]
+            # the lanes of a row's ``alpha`` that rescale its accumulator:
+            # all of them where the head is as wide, else one, broadcast
+            acc[h, at] = acc[h, at] * alpha[:, :lanes if hd == lanes else 1] \
+                + dot(p.astype(v.dtype), v, (((1,), (0,)), ((), ())))
+            m_scr[h, at] = m_cur
+
+
+def _finish_chunk(o_ref, acc, l_scr):
+    l = jnp.sum(l_scr[...], axis=2, keepdims=True)
     o_ref[0, 0] = (acc[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
@@ -386,7 +542,7 @@ def _grid_kernel(bt_ref, lens_ref, misc_ref, q_ref, k_ref, v_ref, *rest, bs,
 
 def _loop_kernel(bt_ref, lens_ref, misc_ref, q_ref, k_hbm, v_hbm, *rest, hg,
                  bs, P, nbk, sm_scale, softcap, has_alibi, stacked, quant,
-                 splits=1, chunk=False, select=False):
+                 splits=1, chunk=False, select=False, spans=None):
     rest = list(rest)
     ks_hbm, vs_hbm = (rest.pop(0), rest.pop(0)) if quant else (None, None)
     slopes_ref = rest.pop(0)
@@ -405,15 +561,11 @@ def _loop_kernel(bt_ref, lens_ref, misc_ref, q_ref, k_hbm, v_hbm, *rest, hg,
 
     def span(b):
         """Sequence b's first and one-past-last live page, and the groups of
-        P pages that hold them. A window drops the pages wholly before it
-        (before the window of a chunk's FIRST query, at ``misc[2 + b]``: the
-        last real one, at ctx - 1, sets the last page); an idle lane (ctx 0)
-        has no group at all."""
+        P pages that hold them (a chunk's first query stands at
+        ``misc[2 + b]``)."""
         ctx = lens_ref[b]
-        cnt = jnp.minimum((ctx + bs - 1) // bs, nbk)
-        first = jnp.where(window > 0, jnp.maximum(
-            (misc_ref[2 + b] + 1 if chunk else ctx) - window, 0) // bs, 0)
-        return first, cnt, first // P, (cnt + P - 1) // P
+        return spans(ctx, misc_ref[2 + b] + 1 if chunk else ctx, window, P,
+                     bs, nbk)
 
     def page_copies(heads, b, first, cnt, i, slot, p):
         """(live, its copies) of page p of sequence b's group i into buffer
@@ -484,6 +636,11 @@ def _loop_kernel(bt_ref, lens_ref, misc_ref, q_ref, k_hbm, v_hbm, *rest, hg,
         v_buf[...] = jnp.zeros_like(v_buf)
         if quant:
             vs_buf[...] = jnp.zeros_like(vs_buf)
+        if chunk:
+            # ... and a chunk ADDS its masks to the scores: finite keys
+            k_buf[...] = jnp.zeros_like(k_buf)
+            if quant:
+                ks_buf[...] = jnp.zeros_like(ks_buf)
         state[0] = 0        # the slot this program's loop starts in
         state[1] = 0        # 1: the program before started its first group
 
@@ -516,16 +673,24 @@ def _loop_kernel(bt_ref, lens_ref, misc_ref, q_ref, k_hbm, v_hbm, *rest, hg,
             ks, vs = (jnp.concatenate(
                 [buf[slot, :, p, :, :bs] for p in range(P)], -1)
                 for buf in (ks_buf, vs_buf))
+        slopes = slopes_ref if has_alibi else None
+        if chunk:
+            _attend_chunk(q_ref, k_buf, v_buf, (ks, vs) if quant else None,
+                          (sel_buf, thr_ref, tie_ref) if select else None,
+                          slot, i * (P * bs), lens_ref[b], misc_ref[2 + b],
+                          window, slopes, acc, m_scr, l_scr,
+                          gq=acc.shape[0] // hg,
+                          sm_scale=sm_scale, softcap=softcap)
+            return
         sel = (sel_buf[slot], thr_ref[0][:, :1], tie_ref[0][:, :1]) \
             if select else None
         _attend(q_ref[0, 0], k_buf[slot], v_buf[slot], ks, vs, i * (P * bs),
-                lens_ref[b], window, slopes_ref if has_alibi else None, acc, m_scr,
-                l_scr, sm_scale=sm_scale, softcap=softcap,
-                q0=misc_ref[2 + b] if chunk else None, sel=sel)
+                lens_ref[b], window, slopes, acc, m_scr, l_scr,
+                sm_scale=sm_scale, softcap=softcap, sel=sel)
 
     jax.lax.fori_loop(g0, g1, group, None)
     state[0] = (slot0 + g1 - g0) % 2
-    _finish(o_ref, acc, l_scr)
+    (_finish_chunk if chunk else _finish)(o_ref, acc, l_scr)
 
 
 def _stored_heads(nh: int, pool_shape, stacked: bool) -> int:
@@ -649,10 +814,15 @@ def paged_attention(q: jnp.ndarray,
     elif k_pool.dtype == jnp.int8:
         raise ValueError("int8 KV pool needs k_scale/v_scale "
                          "(quant_format.kv_quantize layout)")
+    nh, hd = q.shape[1], q.shape[3]
+    plan = _plan(nh, _stored_heads(nh, k_pool.shape, stacked),
+                 k_pool.shape[3 if stacked else 2], hd,
+                 k_pool.dtype.itemsize, block_tables.shape[1], T, quant,
+                 select is not None)
     kw = dict(sm_scale=sm_scale, alibi_slopes=alibi_slopes,
               softcap=float(softcap) if softcap else 0.0, window=window,
               layer_idx=layer_idx, k_scale=k_scale, v_scale=v_scale,
-              select=select, interpret=interpret)
+              select=select, interpret=interpret, plan=plan)
     if T == 1:
         return _paged_attention(q, k_pool, v_pool, block_tables,
                                 context_lens, q_start=None, **kw)
@@ -671,10 +841,10 @@ def paged_attention(q: jnp.ndarray,
 
 def _paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
                      sm_scale, alibi_slopes, softcap, window, layer_idx,
-                     k_scale, v_scale, q_start, interpret, select=None):
+                     k_scale, v_scale, q_start, interpret, plan, select=None):
     """:func:`paged_attention`, its shapes found tileable, on a query of
     whole tiles (one row, or a chunk's rows padded to :func:`_query_rows`)
-    and scales in :func:`scale_rows`' layout."""
+    and scales in :func:`scale_rows`' layout; ``plan``: :func:`_plan`."""
     B, nh, T, hd = q.shape
     stacked = layer_idx is not None
     quant = k_scale is not None
@@ -687,11 +857,10 @@ def _paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
     # decode token's tile is [hg stored heads, a row a query head]; a
     # chunk's [heads = hg x gq query heads, a head's rows], as the output
     group = nh // kvh
-    hg, gq = _program_heads(nh, kvh, bs, hd, k_pool.dtype.itemsize, T)
+    hg, gq, rows, P, lanes = plan
     splits = group // gq
     ng = kvh // hg * splits
-    heads, rows = (hg, _query_rows(T, group)) if T == 1 else (
-        hg * gq, _query_rows(T))
+    heads = hg if T == 1 else hg * gq
     scale = sm_scale if sm_scale is not None else 1.0 / np.sqrt(hd)
 
     if T > 1 or group == 1:
@@ -730,15 +899,12 @@ def _paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
         # the pools stay in HBM: the kernel copies the pages a lane holds
         # (a chunk of narrower heads comes here under the interpreter only:
         # :func:`untileable`)
-        P = _pages_per_group(hg * gq, bs, hd, k_pool.dtype.itemsize, nbk,
-                             quant, T)
         if select is not None:
-            # a group's keys fill whole 128-lane rows of the index scores
-            P = max(P, -(-128 // bs))
-            sel_ops, sel_specs = _selection_operands(select, B, T, rows,
-                                                     -(-nbk // P), P * bs)
+            sel_ops, sel_specs = _selection_operands(
+                select, B, T, rows, -(-nbk // P), P * bs, lanes)
         kernel = partial(_loop_kernel, hg=hg, P=P, splits=splits,
-                         chunk=T > 1, select=select is not None, **static)
+                         chunk=T > 1, select=select is not None,
+                         spans=group_span, **static)
         grid = (B, ng)
         kv_specs = [pl.BlockSpec(memory_space=pl.ANY)] * (4 if quant else 2)
         scratch = [pltpu.VMEM((2, hg, P * bs, hd), k_pool.dtype)] * 2
@@ -747,6 +913,10 @@ def _paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
                                    jnp.float32)] * 2
         if select is not None:
             scratch += [pltpu.VMEM((2,) + sel_ops[0].shape[2:], jnp.float32)]
+        if T > 1:
+            # :func:`_attend_chunk`: the running max alike on a lane tile's
+            # lanes, the running sum a lane apart
+            online[1:] = [pltpu.VMEM((heads, rows, lanes), jnp.float32)] * 2
         scratch += online + [pltpu.SMEM((2,), jnp.int32),
                              pltpu.SemaphoreType.DMA(
                                  (3 if select is not None else 2, 2))]
@@ -800,20 +970,26 @@ def _paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
 
 
 def _selection_operands(select, B: int, T: int, rows: int, groups: int,
-                        n: int):
-    """``(operands, their specs)`` of a call's selection: the index scores as
-    ``[B, groups, R, n]`` (a copy group's ``n`` keys a row of tiles, R the
-    query rows: 1 for a decode token, a chunk's padded rows), left in HBM for
-    the kernel's own copies; ``thr`` and ``tie`` ``[B, R, 128]``, a row's on
-    every lane, a lane's block in VMEM. Rows and keys past the call's own
-    read ``-inf``: nothing of them is selected."""
+                        n: int, lanes: int):
+    """``(operands, their specs)`` of a call's selection: the index scores
+    of a copy group's ``n`` keys as ``n // lanes`` lane tiles ``[B, groups,
+    tiles, R, lanes]`` (R the query rows: a chunk's padded rows, whose turn
+    takes a tile at a time; a decode token's 1, the group ONE row of tiles:
+    ``[B, groups, 1, n]``), left in HBM for the kernel's own copies; ``thr``
+    and ``tie`` ``[B, R, 128]``, a row's on every lane, a lane's block in
+    VMEM. Rows and keys past the call's own read ``-inf``: nothing of them
+    is selected."""
     R = 1 if T == 1 else rows
     T, Kp = select.scores.shape[1:]     # the call's own rows, before padding
     keys = min(Kp, groups * n)
     sc = jnp.pad(select.scores[:, :, :keys].astype(jnp.float32),
                  [(0, 0), (0, R - T), (0, groups * n - keys)],
                  constant_values=-jnp.inf)
-    sc = sc.reshape(B, R, groups, n).transpose(0, 2, 1, 3)
+    if R == 1:
+        sc = sc.reshape(B, R, groups, n).transpose(0, 2, 1, 3)
+    else:
+        sc = sc.reshape(B, R, groups, n // lanes, lanes).transpose(
+            0, 2, 3, 1, 4)
     on_lanes = lambda a, fill: jnp.broadcast_to(jnp.pad(
         a, [(0, 0), (0, R - T)], constant_values=fill)[:, :, None],
         (B, R, 128))
@@ -827,7 +1003,8 @@ def _selection_operands(select, B: int, T: int, rows: int, groups: int,
 #: all make it at ONE shape (:data:`_CHUNK_TILE`), so the kernel is traced
 #: for the first and found for the rest (lowering is still a program's own)
 _shared_chunk_call = jax.jit(
-    _paged_attention, static_argnames=("sm_scale", "softcap", "interpret"))
+    _paged_attention,
+    static_argnames=("sm_scale", "softcap", "interpret", "plan"))
 
 
 def paged_attention_reference(q: jnp.ndarray,

@@ -50,6 +50,7 @@ from ..ops.pallas import sparse_select
 from ..ops.pallas.latent_attention import chunk_expanded_keys
 from ..ops.pallas.latent_attention import form as latent_form
 from ..ops.pallas.latent_attention import path as latent_path
+from ..ops.pallas.paged_attention import chunk_plan, chunk_walk
 from ..ops.pallas.sparse_select import bits_to_positions, positions_of_bits
 from .kv_cache import (NULL_BLOCK, BlockPoolExhausted, SharedPagedState)
 from .model_runner import attention_impl, paged_forward
@@ -83,6 +84,8 @@ _COUNTERS = (
     "prefix.prompt_tokens", "paged.live_pages_sum", "paged.table_pages_sum",
     "paged.window_pages_sum",
     "paged.chunk_live_pages_sum", "paged.chunk_table_pages_sum",
+    "paged.chunk_turns_sum", "paged.chunk_key_tiles_sum",
+    "paged.chunk_key_tiles_live_sum",
     "step_inputs.transfers_sum", "step_inputs.lane_rows_written_sum",
     "decode_ahead.launched", "decode_ahead.device_lane_tokens_sum",
     "decode_ahead.wasted_lane_tokens", "decode_ahead.retired_unread")
@@ -567,7 +570,8 @@ class ServingEngine:
         # the windows of the layers that have one (``paged.window_pages_sum``)
         self._windows = np.asarray(
             [w for w in cfg.layer_windows or () if w > 0], np.int64)
-        # window (0: none) -> the layers that have it
+        # window (0: none) -> the layers that have it (the indexer's
+        # counters and the paged kernel's chunk turns are a window's)
         self._index_windows = collections.Counter(
             max(int(w), 0) for w in cfg.layer_windows or (0,) * cfg.num_layers)
         if cfg.rope_scaling_type == "dynamic":
@@ -666,6 +670,9 @@ class ServingEngine:
             jax.tree_util.tree_leaves(self.params)[0],
             token_words(cfg, self.max_batch), token_words(cfg, 1))
         self._prefill_shapes: set = set()  # query rows of the prefill calls
+        # ... -> ``paged_attention.chunk_plan`` of those the paged kernel
+        # takes: programs a lane, pages a copy group, keys a lane tile
+        self._chunk_plans: dict = {}
         self._heartbeat = heartbeat
         self._watchdog = None
         self._lock = threading.Lock()
@@ -1356,6 +1363,7 @@ class ServingEngine:
                            -(-(q0 + n) // self.block_size), chunk=Tb)
         if Tb not in self._prefill_shapes:
             self._note_prefill_path(Tb)
+        self._count_chunk_turns(q0, n, Tb)
         buf = np.zeros((self._layout.prefill_words(Tb),), np.int32)
         ids, bt, first, ctx, last_idx, tk, temp, tp, _ = \
             self._layout.prefill(buf)
@@ -1389,8 +1397,35 @@ class ServingEngine:
                 stacked=True, quant="k_scale" in self.pools,
                 impl=attention_impl(cfg), interpret=self.interpret)
         self._prefill_shapes.add(Tb)
+        if path == "kernel":
+            self._chunk_plans[Tb] = chunk_plan(
+                cfg.num_heads, pool.shape[1], self.block_size, cfg.head_dim,
+                pool.dtype.itemsize, self.nbk, Tb, "k_scale" in self.pools,
+                bool(cfg.index_heads))
         paths = self.rec.gauges.setdefault("paged.prefill_path", {})
         paths.setdefault(f"{path}: {why}" if why else path, []).append(Tb)
+
+    def _count_chunk_turns(self, q0: int, n: int, Tb: int) -> None:
+        """A prefill call of ``n`` tokens at ``q0`` (``Tb`` rows) that rides
+        the paged kernel, by the kernel's own rule
+        (``paged_attention.chunk_walk``), over its programs and the layers:
+        ``paged.chunk_turns_sum`` (the turns its programs make, a copy group
+        of keys each: what a (head, row, turn) cost is paid for),
+        ``paged.chunk_key_tiles_sum`` (the 128-key tiles those turns
+        compute) and ``paged.chunk_key_tiles_live_sum`` (those of them that
+        hold a key some row of the chunk sees: the rest are all mask, a first
+        chunk's, a window's and a context's last turn)."""
+        plan = self._chunk_plans.get(Tb)
+        if plan is None:
+            return
+        programs, P, lanes = plan
+        c = self.stats
+        for window, layers in self._index_windows.items():
+            turns, tiles, live = chunk_walk(q0, q0 + n, window, Tb, P, lanes,
+                                            self.block_size, self.nbk)
+            c["paged.chunk_turns_sum"] += layers * programs * turns
+            c["paged.chunk_key_tiles_sum"] += layers * programs * tiles
+            c["paged.chunk_key_tiles_live_sum"] += layers * programs * live
 
     def _latent_prefill_path(self, Tb: int):
         """``PagedCache.attend_latent``'s way for a chunk of ``Tb`` rows, as

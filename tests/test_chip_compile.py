@@ -104,14 +104,17 @@ def test_flash_masked_wrapper_compiles(chip, monkeypatch):
 
 
 # layers, query heads, stored (KV) heads, head_dim, block, pool blocks, lanes,
-# table: the serving widths of chip_smoke.py, of the benchmark's three
-# serving cells (two of them grouped-query models: four and eight query heads
-# to a stored head), and of a preset whose heads are narrower than a lane tile
+# table: the serving widths of chip_smoke.py, of the benchmark's four
+# serving cells with K/V pools (three of them grouped-query models: four and
+# eight query heads to a stored head; the last one's chunk under a learned
+# indexer's selection), and of a preset whose heads are narrower than a lane
+# tile
 _PAGED_SHAPES = {
     "gpt2-1.3b": (L, NH, NH, HD, BS, NB, B, NBK),
     "serve-olmoe-1b-7b-l8-gen": (8, 16, 16, 128, 32, 2048, 64, 128),
     "serve-mistral-7b-l16-chat": (16, 32, 8, 128, 32, 384, 32, 40),
     "serve-k-exaone-236b-ep8-l5-mixed": (5, 64, 8, 128, 32, 1024, 32, 128),
+    "serve-keye-vl2-30b-ep8-l8-longdoc": (8, 32, 4, 128, 32, 8192, 16, 800),
     "llama-1.1b": (22, 32, 4, 64, 32, 256, 8, 32)}
 
 
@@ -173,35 +176,44 @@ def test_paged_attention_stacked_pool_compiles(chip, cell, quant):
     for T in range(32, 257, 32)] + [
     # every chunk shape pads to the one tile: the smallest and the largest
     ("serve-k-exaone-236b-ep8-l5-mixed", 32),
-    ("serve-k-exaone-236b-ep8-l5-mixed", 256)])
+    ("serve-k-exaone-236b-ep8-l5-mixed", 256),
+    ("serve-keye-vl2-30b-ep8-l8-longdoc", 32),
+    ("serve-keye-vl2-30b-ep8-l8-longdoc", 256)])
 def test_paged_attention_prefill_chunk_compiles(chip, cell, T):
-    """The same kernel under a prefill chunk's T query rows a lane, at both
-    serving cells' widths and every chunk shape their loops send (the
+    """The same kernel under a prefill chunk's T query rows a lane, at the
+    four serving cells' widths and tables and every chunk shape their loops
+    send (the
     multiples of the block up to ``prefill_chunk_tokens``, each padded to the
     one tile of 256 rows the kernel is traced at; a grouped-query model's
     tile is its stored head's whole group, mistral's 4 x 256 rows, or half
-    of K-EXAONE's 8 x 256): heads a program and
-    pages a group shrink with the rows so that the accumulator, the running
-    rows and one group's scores fit the chip's scoped VMEM beside the page
-    buffers, which only this compiler can say. One kernel, one lane, no
-    loop round it and nothing as large as a layer of the pool."""
+    of K-EXAONE's and Keye's 8 x 256; Keye's under its indexer's selection,
+    the scores of a copy group's keys copied beside its pages): heads a
+    program shrink with the rows and a turn takes 512 keys (PR 51) so that
+    the accumulator, the running rows and one head's scores fit the chip's
+    scoped VMEM beside the page buffers, which only this compiler can say.
+    One kernel, one lane, no loop round it and nothing as large as a layer
+    of the pool."""
     from deepspeed_tpu.ops.pallas.paged_attention import (
         _pages_per_group, _program_heads, _query_rows, paged_attention)
+    from deepspeed_tpu.ops.pallas.sparse_select import Selection
     L, NH, KVH, HD, BS, NB, _, NBK = _PAGED_SHAPES[cell]
     q = chip((1, NH, T, HD), jnp.bfloat16)
     pool = chip((L, KVH, NB, BS, HD), jnp.bfloat16)
     bt, lens, li = (chip((1, NBK), jnp.int32), chip((1,), jnp.int32),
                     chip((), jnp.int32))
-    fn = lambda q, k, v, bt, lens, q0, li: paged_attention(
-        q, k, v, bt, lens, layer_idx=li, window=li, q_start=q0)
+    fn = lambda q, k, v, bt, lens, q0, li, *sel: paged_attention(
+        q, k, v, bt, lens, layer_idx=li, window=li, q_start=q0,
+        select=Selection(*sel) if sel else None)
     args = (q, pool, pool, bt, lens, lens, li)
+    if "keye" in cell:
+        args += (chip((1, T, NBK * BS), jnp.float32),
+                 chip((1, T), jnp.float32), chip((1, T), jnp.int32))
     group = NH // KVH
     hg, gq = _program_heads(NH, KVH, BS, HD, 2, T)
     rows = gq * _query_rows(T)              # of one stored head's tile
     assert rows == gq * 256 and hg * rows <= 1024 and KVH % hg == 0
     assert (hg, gq) == {1: (4, 1), 4: (1, 4), 8: (1, 4)}[group]
-    assert _pages_per_group(hg * gq, BS, HD, 2, NBK, False, T) * BS * hg \
-        * rows * 4 <= 1 << 20
+    assert _pages_per_group(hg * gq, BS, HD, 2, NBK, False, T) * BS == 512
     # the call is a jitted one, shared by the chunk shapes: one level down
     calls = [e for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns
              if e.primitive.name == "jit"]
@@ -653,19 +665,22 @@ def test_keye_serving_step_selects_keys_in_place(chip, monkeypatch, program):
 #: tree since PR 44 has lowered them, sha256 of the text, first 16 digits. A
 #: PR for another model leaves them as they are (PR 26 was refused for a
 #: dense path it had touched); one that means to change the dense programs
-#: changes these with its reason
+#: changes these with its reason. PR 51 changed the paged kernel's CHUNK
+#: form for every model (a turn of 512 keys, a head at a time): both prefill
+#: programs; the decode programs are PR 44's still
 _GPT2_PROGRAMS = {"gpt2-1.3b.decode": "da1edc35111a2336",
-                  "gpt2-1.3b.prefill256": "5c4405f57ff5d37b",
+                  "gpt2-1.3b.prefill256": "36ca0b182b3cd0b3",
                   "gpt2-1.3b-int8.decode": "b2066bebbff05fcd",
-                  "gpt2-1.3b-int8.prefill256": "9004358ba26b774e"}
+                  "gpt2-1.3b-int8.prefill256": "5f8d1b0c90bf9aa6"}
 
 
 @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
 def test_gpt2_serving_programs_are_text_for_text_what_they_were(
         chip, monkeypatch, int8):
     """Latent attention, YaRN and the grouped router were added beside the
-    dense path, not inside it: the lowered text of gpt2-1.3b's decode and
-    prefill programs is the parent's."""
+    dense path, not inside it, and PR 51's chunk form left the decode form
+    alone: the lowered text of gpt2-1.3b's programs is what the table
+    says."""
     import hashlib
     import importlib.util
     import os

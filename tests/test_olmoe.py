@@ -355,7 +355,9 @@ def test_a_dense_configs_programs_and_counters_are_what_they_were():
         "prefix.prompt_tokens", "paged.live_pages_sum",
         "paged.table_pages_sum", "paged.window_pages_sum",
         "paged.chunk_live_pages_sum",
-        "paged.chunk_table_pages_sum", "step_inputs.transfers_sum",
+        "paged.chunk_table_pages_sum", "paged.chunk_turns_sum",
+        "paged.chunk_key_tiles_sum", "paged.chunk_key_tiles_live_sum",
+        "step_inputs.transfers_sum",
         "step_inputs.lane_rows_written_sum", "decode_ahead.launched",
         "decode_ahead.device_lane_tokens_sum",
         "decode_ahead.wasted_lane_tokens", "decode_ahead.retired_unread")

@@ -519,19 +519,20 @@ def test_paged_prefill_chunk_matches_reference(case):
 
 
 @pytest.mark.parametrize("T,nh,want", [
-    (1, 32, (32, 4)), (32, 32, (4, 8)), (96, 32, (4, 8)),
-    (256, 32, (4, 8)), (256, 16, (4, 8)), (257, 32, (2, 8)),
-    (1, 16, (16, 8)), (1024, 16, (1, 8)), (5, 4, (4, 8))],
+    (1, 32, (32, 4)), (32, 32, (4, 16)), (96, 32, (4, 16)),
+    (256, 32, (4, 16)), (256, 16, (4, 16)), (257, 32, (2, 16)),
+    (1, 16, (16, 8)), (1024, 16, (1, 16)), (5, 4, (4, 16))],
     ids=lambda v: str(v))
 def test_heads_and_pages_shrink_with_the_chunk(T, nh, want):
     """Heads a program and pages a group at the serving cells' widths
     (block 32, heads of 128, bf16, a table of 40): what a decode token gets
     is what it got before the kernel took chunks; a chunk's rows are padded
     to whole tiles of 256 (every chunk shape of a serving loop is ONE
-    shape to the kernel) and keep a program's accumulator and one group's
-    scores inside their budgets."""
+    shape to the kernel), keep a program's accumulator inside its budget
+    and take 512 keys a turn (PR 51; 256 until then, by a budget for four
+    heads' scores at once), whatever the rows."""
     from deepspeed_tpu.ops.pallas.paged_attention import (
-        _CHUNK_ROWS, _SCORE_BUDGET, _head_group, _pages_per_group,
+        _CHUNK_KEYS, _CHUNK_ROWS, _head_group, _pages_per_group,
         _query_rows)
     hg = _head_group(nh, 32, 128, 2, T)
     P = _pages_per_group(hg, 32, 128, 2, 40, False, T)
@@ -540,7 +541,7 @@ def test_heads_and_pages_shrink_with_the_chunk(T, nh, want):
                        _pages_per_group(hg, 32, 128, 2, 40)) or T > 1
     if T > 1:
         assert hg * _query_rows(T) <= _CHUNK_ROWS or hg == 1
-        assert hg * _query_rows(T) * P * 32 * 4 <= _SCORE_BUDGET or P == 1
+        assert P * 32 == _CHUNK_KEYS
 
 
 @pytest.mark.parametrize("shape,want", [
@@ -566,6 +567,176 @@ def test_pages_per_group_from_shapes(shape, want):
     if quant:
         held += 2 * 2 * hg * P * 8 * _scale_lanes(bs) * 4
     assert held <= _VMEM_BUDGET or P == 1, (held, _VMEM_BUDGET)
+
+
+# ---------------------------------------------------------------------------
+# A chunk's turn at the serving cells' geometry (PR 51): pages of 32 slots,
+# heads of 128, so a program's copy group is 16 pages = 512 keys, taken as
+# four lane tiles of 128, a query head at a time, the running sum a lane
+# apart.
+# The edges of that geometry against the jnp reference, at the tolerance the
+# small shapes above are held to.
+# ---------------------------------------------------------------------------
+
+_LBS, _LHD, _LNBK, _LNB, _LWIN = 32, 128, 40, 130, 200
+_LANE_CASES = {
+    # name: (query heads, stored heads, T, q_start a lane, ctx a lane, regime)
+    "ends_inside_a_lane_tile": (2, 2, 64, [136], [200], "plain"),
+    "ends_on_a_tile_edge": (2, 2, 64, [192], [256], "plain"),
+    "ends_on_a_group_edge": (2, 2, 64, [448], [512], "plain"),
+    "one_key_past_a_group": (2, 2, 64, [449], [513], "plain"),
+    # the window of the first row starts at key 501, on page 15 of group 0
+    "window_first_page_inside_a_group": (2, 2, 64, [700], [764], "window"),
+    "window_inside_the_first_group": (2, 2, 64, [250], [314], "window"),
+    "cached_context_beside_a_first_chunk": (2, 2, 64, [600, 0], [664, 64],
+                                            "plain"),
+    "idle_lane_between": (2, 2, 32, [515, 0, 40], [547, 0, 72], "plain"),
+    "padded_rows_past_ctx": (2, 2, 96, [500], [530], "plain"),
+    "two_row_tiles": (2, 1, 300, [300], [600], "plain"),
+    "full_table": (2, 2, 64, [1216], [1280], "plain"),
+    "k_exaone_split_programs": (16, 2, 64, [530], [594], "window"),
+    "olmoe_four_stored_heads": (4, 4, 64, [530], [594], "plain"),
+    "mistral_group_of_four": (8, 2, 64, [449], [513], "plain"),
+    "int8": (2, 2, 64, [449], [513], "int8"),
+    "int8_four_stored_heads": (4, 4, 64, [130], [194], "int8"),
+    "alibi": (2, 2, 64, [449], [513], "alibi"),
+    "softcap": (2, 2, 64, [449], [513], "softcap"),
+    # a learned indexer's selection (top ``_LTOPK`` of a row's keys, equal
+    # scores to the lower position): rows of at most and of more than
+    # ``_LTOPK`` keys in one call; behind a cached context; beside a window;
+    # a lane behind a context beside a lane's first chunk
+    "select_rows_below_and_above_topk": (8, 2, 96, [60], [156], "select"),
+    "select_behind_a_context": (2, 2, 64, [449], [513], "select"),
+    "select_under_a_window": (2, 2, 64, [700], [764], "select-window"),
+    "select_two_lanes": (8, 2, 64, [600, 0], [664, 64], "select"),
+    "select_split_programs": (16, 2, 64, [530], [594], "select"),
+}
+_LTOPK = 100
+
+
+def _lane_case(case, seed=41):
+    """``(args, kw, plan)`` of one call at the cells' geometry."""
+    from deepspeed_tpu.ops.pallas.paged_attention import _plan
+    nh, kvh, T, q0, ctx, regime = _LANE_CASES[case]
+    B = len(q0)
+    _, kp, vp, bt, _ = _data(B=B, nh=kvh, hd=_LHD, bs=_LBS, num_blocks=_LNB,
+                             nbk=_LNBK, seed=seed)
+    q = np.random.default_rng(seed + 1).standard_normal(
+        (B, nh, T, _LHD)).astype(np.float32)
+    kw = {"q_start": jnp.asarray(q0, jnp.int32)}
+    if regime.endswith("window"):
+        kw["window"] = jnp.asarray(_LWIN, jnp.int32)
+    if regime.startswith("select"):
+        from deepspeed_tpu.ops.pallas import sparse_select as ss
+        rng = np.random.default_rng(seed + 2)
+        pos = np.arange(ss.padded_keys(_LNBK * _LBS))[None, None]
+        seen = (pos <= (np.asarray(q0)[:, None] + np.arange(T))[:, :, None]
+                ) & (pos < np.asarray(ctx)[:, None, None])
+        sc = np.round(rng.standard_normal(seen.shape) * 2) / 2    # ties
+        kw["select"] = ss.select(jnp.asarray(
+            np.where(seen, sc, -np.inf), jnp.float32), _LTOPK, kernel=False)
+    if regime == "alibi":
+        kw["alibi_slopes"] = jnp.asarray(
+            [2.0 ** -(1 + 8 * i / nh) for i in range(nh)], jnp.float32)
+    if regime == "softcap":
+        kw["softcap"] = 30.0
+    if regime == "int8":
+        kp, kw["k_scale"], vp, kw["v_scale"], _, _ = _int8_pools(kp, vp)
+    args = (jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+            jnp.asarray(bt), jnp.asarray(ctx, jnp.int32))
+    plan = _plan(nh, kvh, _LBS, _LHD, kp.dtype.itemsize, _LNBK, T,
+                 regime == "int8", regime.startswith("select"))
+    return args, kw, plan
+
+
+@pytest.mark.parametrize("case", list(_LANE_CASES))
+def test_chunk_turn_of_lane_tiles_matches_reference(case):
+    nh, kvh, T, q0, ctx, _ = _LANE_CASES[case]
+    args, kw, (hg, gq, rows, P, lanes) = _lane_case(case)
+    assert (P * _LBS, lanes) == (512, 128)      # a turn: four lane tiles
+    assert rows == -(-T // 256) * 256 and hg * gq * rows <= 1024
+    static = {n: kw.pop(n) for n in ("softcap",) if n in kw}
+    out, ref = jax.jit(lambda args, kw: (
+        paged_attention(*args, interpret=True, **kw, **static),
+        paged_attention_reference(*args, **kw, **static)))(args, kw)
+    out, ref = np.asarray(out), np.asarray(ref)
+    assert out.shape == (len(q0), nh, T, _LHD) and np.isfinite(out).all()
+    for b, (s, c) in enumerate(zip(q0, ctx)):
+        n = min(T, c - s)
+        np.testing.assert_allclose(out[b, :, :n], ref[b, :, :n], rtol=2e-5,
+                                   atol=2e-5)
+    if "select" in kw:
+        # the selection selected: rows that see more than ``_LTOPK`` keys
+        # differ from dense attention, the others do not
+        dense = np.asarray(paged_attention_reference(
+            *args, **{k: v for k, v in kw.items() if k != "select"}))
+        for b, (s, c) in enumerate(zip(q0, ctx)):
+            few = max(0, min(T, c - s, _LTOPK - s))
+            if "window" not in kw:
+                np.testing.assert_allclose(out[b, :, :few], dense[b, :, :few],
+                                           rtol=2e-5, atol=2e-5)
+            n = min(T, c - s)
+            assert few == n or np.abs(out[b, :, few:n]
+                                      - dense[b, :, few:n]).max() > 1e-3
+
+
+@pytest.mark.parametrize("case", ["ends_inside_a_lane_tile",
+                                  "one_key_past_a_group",
+                                  "window_first_page_inside_a_group",
+                                  "window_inside_the_first_group",
+                                  "two_row_tiles", "full_table"])
+def test_chunk_walk_is_the_kernels_own_rule(case):
+    """``chunk_walk`` (what ``serving.engine`` counts by) against the kernel
+    itself: the pages outside the turns it names are never read (their
+    table entries point at a block of NaN), and a key in a lane tile it
+    does not call live reaches no row (filled with large values, the output
+    is bit for bit what it was), while its live tiles hold every key some
+    row sees."""
+    from deepspeed_tpu.ops.pallas.paged_attention import chunk_plan, chunk_walk
+    nh, kvh, T, (q0,), (ctx,), regime = _LANE_CASES[case]
+    args, kw, _ = _lane_case(case)
+    window = _LWIN if regime == "window" else 0
+    _, P, lanes = chunk_plan(nh, kvh, _LBS, _LHD, 4, _LNBK, T)
+    assert (P * _LBS, lanes) == (512, 128)
+    turns, tiles, live = chunk_walk(q0, ctx, window, T, P, lanes, _LBS,
+                                    _LNBK)
+    # by hand: the groups from the first row's window to the last real key,
+    # and of their tiles a row tile those that hold a key one of its real
+    # rows sees
+    low = max(q0 + 1 - window, 0) if window else 0
+    g0, g1 = low // _LBS // P, -(-(-(-ctx // _LBS)) // P)
+    assert turns == g1 - g0 and tiles == turns * 4 * -(-T // 256)
+    seen = np.zeros((-(-T // 256), g1 * 4), bool)
+    for r in range(min(T, ctx - q0)):
+        lo = max(q0 + r + 1 - window, 0) if window else 0
+        seen[r // 256, lo // 128:(q0 + r) // 128 + 1] = True
+    assert live == seen[:, g0 * 4:].sum() and not seen[:, :g0 * 4].any()
+    q, kp, vp, bt, lens = args
+    call = jax.jit(lambda kp, vp, bt: paged_attention(
+        q, kp, vp, bt, lens, interpret=True, **kw))
+    want = np.asarray(call(kp, vp, bt))
+    # a page outside the named turns: NaN, and nobody reads it
+    bt_np = np.asarray(bt).copy()
+    spare = next(i for i in range(1, _LNB) if i not in bt_np)
+    poisoned = np.ones(_LNBK, bool)
+    poisoned[g0 * P:min(g1 * P, _LNBK)] = False
+    bt_np[0, poisoned] = spare
+    nan = lambda pool: pool.at[:, spare].set(jnp.nan)
+    got = np.asarray(call(nan(kp), nan(vp), jnp.asarray(bt_np)))
+    assert np.array_equal(got[0, :, :ctx - q0], want[0, :, :ctx - q0])
+    # a tile no row tile sees: large keys and values change nothing
+    dead = ~seen.any(0)
+    kp2, vp2 = np.array(kp), np.array(vp)
+    for t in np.flatnonzero(dead):
+        for page in range(t * 4, min(t * 4 + 4, _LNBK)):
+            kp2[:, bt_np[0, page]] = vp2[:, bt_np[0, page]] = 1e3
+    if dead[g0 * 4:].any():
+        got = np.asarray(call(jnp.asarray(kp2), jnp.asarray(vp2), bt))
+        assert np.array_equal(got[0, :, :ctx - q0], want[0, :, :ctx - q0])
+    # ... and in a live tile they do
+    kp2[:, bt_np[0, (ctx - 1) // _LBS]] = 1e3
+    got = np.asarray(call(jnp.asarray(kp2), vp, bt))
+    assert not np.array_equal(got[0, :, :ctx - q0], want[0, :, :ctx - q0])
 
 
 # ---------------------------------------------------------------------------
@@ -676,23 +847,24 @@ def test_grouped_query_heads_share_a_stored_head(group, shape, regime):
     # (query heads, stored heads, rows a head) -> (stored heads a program,
     # query heads of a stored head a program, programs a lane, rows, pages)
     (32, 8, 1, (8, 4, 1, 8, 4)),            # mistral: a decode token
-    (32, 8, 256, (1, 4, 8, 1024, 8)),       # ... a chunk: one tile a group
+    (32, 8, 256, (1, 4, 8, 1024, 16)),      # ... a chunk: one tile a group
     (64, 8, 1, (8, 8, 1, 8, 2)),            # K-EXAONE: the tile filled
-    (64, 8, 256, (1, 4, 16, 1024, 8)),      # ... 8 x 256 rows: two programs
-    (64, 8, 512, (1, 2, 32, 1024, 8)),
+    (64, 8, 256, (1, 4, 16, 1024, 16)),     # ... 8 x 256 rows: two programs
+    (64, 8, 512, (1, 2, 32, 1024, 16)),
     (32, 4, 1, (4, 8, 1, 8, 4)),            # llama-1.1b's heads at 128 wide
     (32, 2, 1, (2, 16, 1, 16, 4)),          # a group of 16: two sublane tiles
     (16, 16, 1, (16, 1, 1, 8, 8)),          # OLMoE, gpt2: nothing to group
-    (16, 16, 256, (4, 1, 4, 256, 8)),
-    (32, 32, 256, (4, 1, 8, 256, 8)),       # the pool the parent stored
+    (16, 16, 256, (4, 1, 4, 256, 16)),
+    (32, 32, 256, (4, 1, 8, 256, 16)),      # the pool the parent stored
 ], ids=lambda v: str(v))
 def test_group_tile_from_shapes(nh, kvh, T, want):
     """The tile of one stored head at the serving cells' widths (block 32,
     heads of 128, bf16, a table of 128): what a model with nothing to group
     gets is what it got, a chunk's programs keep their rows within
     ``_CHUNK_ROWS`` by splitting a group's query heads over programs, and
-    the pages a group are what the QUERY heads got when the pool stored a
-    row for each (more of them cost set-up and no time: PERF.md, PR 44)."""
+    a decode token's pages a group are what the QUERY heads got when the
+    pool stored a row for each (more of them cost set-up and no time:
+    PERF.md, PR 44); a chunk's are 512 keys (PR 51)."""
     from deepspeed_tpu.ops.pallas.paged_attention import (
         _CHUNK_ROWS, _pages_per_group, _program_heads, _query_rows)
     group = nh // kvh

@@ -412,6 +412,51 @@ def test_prefill_calls_count_the_pages_they_walk_and_name_their_path(
         {"reference": [32, 16]}
 
 
+@pytest.mark.parametrize("window", [0, 24], ids=["global", "window24"])
+def test_prefill_calls_count_their_turns_by_the_kernels_rule(tiny_lm, window):
+    """``paged.chunk_turns_sum`` / ``paged.chunk_key_tiles_sum`` /
+    ``paged.chunk_key_tiles_live_sum`` (PR 51): what the paged kernel's
+    chunk programs do for a prefill call, by the kernel's own rule
+    (``paged_attention.chunk_plan`` / ``chunk_walk``: held to the kernel in
+    tests/test_paged_attention.py), over programs and layers; a call the
+    reference serves counts none."""
+    import dataclasses
+    from deepspeed_tpu.ops.pallas.paged_attention import (chunk_plan,
+                                                          chunk_walk)
+    cfg, params = tiny_lm
+    if window:
+        cfg = dataclasses.replace(
+            cfg, layer_windows=(window,) + (0,) * (cfg.num_layers - 1))
+    serving = {"block_size": 16, "pool_blocks": 24, "max_batch": 3,
+               "max_blocks_per_seq": 8, "prefill_chunk_tokens": 32}
+    srv = ServingEngine(cfg, params, serving=serving, interpret=True)
+    srv.submit(list(range(1, 71)), max_new_tokens=3)
+    srv.run_until_idle()
+    c = dict(srv.telemetry()["counters"])
+    pool = srv.pools["k"]
+    want = np.zeros(3, np.int64)
+    for q0, n, Tb in ((0, 32, 32), (32, 32, 32), (64, 6, 16)):
+        programs, P, lanes = chunk_plan(
+            cfg.num_heads, pool.shape[1], 16, cfg.head_dim,
+            pool.dtype.itemsize, 8, Tb)
+        for w in cfg.layer_windows or (0,) * cfg.num_layers:
+            want += programs * np.asarray(
+                chunk_walk(q0, q0 + n, w, Tb, P, lanes, 16, 8))
+    srv.close()
+    assert [c["paged.chunk_turns_sum"], c["paged.chunk_key_tiles_sum"],
+            c["paged.chunk_key_tiles_live_sum"]] == want.tolist()
+    # a table of 8 pages of 16 is one group of 128 keys: a turn a call,
+    # program and layer, one lane tile each, every one of them live
+    assert want[0] == want[1] == want[2] > 0
+    assert want[0] == 3 * cfg.num_layers * programs
+    ref = ServingEngine(cfg, params, serving=serving)
+    ref.submit(list(range(1, 71)), max_new_tokens=3)
+    ref.run_until_idle()
+    assert ref.stats["paged.chunk_turns_sum"] == 0 \
+        == ref.stats["paged.chunk_key_tiles_sum"]
+    ref.close()
+
+
 def test_a_blocked_step_counts_exactly_one_cause(served):
     srv, reqs, outside = served
     causes = ("admit_blocked.no_lane", "admit_blocked.no_blocks",
