@@ -146,6 +146,9 @@ def init_cache(cfg: TransformerConfig, batch_size: int, max_len: int,
         cache = {"ckv": jnp.zeros((cfg.num_layers, batch_size, 1, max_len,
                                    cfg.latent_width), dtype),
                  "pos": jnp.zeros((), jnp.int32)}
+        if cfg.index_heads:         # the indexer's one key beside the latent
+            cache["ki"] = jnp.zeros((cfg.num_layers, batch_size, 1, max_len,
+                                     cfg.index_head_dim), cfg.dtype)
         if pad_lens is not None:
             cache["pad"] = jnp.asarray(pad_lens, jnp.int32)
         return cache
@@ -306,6 +309,9 @@ def decoder_forward(cfg: TransformerConfig, params: PyTree,
     ``[L, B x T, k + Kp / 32]``, behind a row's experts the keys it attends
     as bits (``sparse_select.selection_bits``: bit r of word w is the key at
     position ``32 w + r``; ``Kp`` the cache's key capacity in whole 128s).
+    Its ``L`` is every layer that has picks OR a selection
+    (``cfg.routed_layers``): a leading dense layer with an indexer hands
+    out a row whose picks are -1, before the sparse layers' rows.
     ``forward_with_cache`` and ``serving.model_runner.
     paged_forward`` are this function over two caches; a new kind of
     per-sequence state (a latent cache, a recurrent state) is a third.
@@ -333,8 +339,8 @@ def decoder_forward(cfg: TransformerConfig, params: PyTree,
       ``select(carry, li, qi, wi, window) -> Selection`` answers which
       cached keys each of the call's rows attends (among those its causal,
       context and window masks leave), from its indexer queries ``[B, heads,
-      T, width]`` and head weights ``[B, T, heads]``; ``attend`` takes that
-      answer as ``select``;
+      T, width]`` and head weights ``[B, T, heads]``; ``attend`` (a latent
+      model's ``attend_latent``) takes that answer as ``select``;
     * ``real_tokens(pos)``: ``[B, T]`` int32, the tokens a request owns
       (``expert_counts``, a dropless MoE config only: how many of them each
       layer's router sent to each expert).
@@ -382,6 +388,19 @@ def decoder_forward(cfg: TransformerConfig, params: PyTree,
     cache.plan(T)
     real = cache.real_tokens(pos) if expert_counts else None
 
+    def indexer(p, q_in, h, turn):
+        """The indexer's queries ``[B, heads, T, width]`` (from ``q_in``: the
+        normed input, or a latent model's normed query latent), its ONE key
+        a token ``[B, 1, T, width]`` and its head weights ``[B, T, heads]``
+        (both from the normed input ``h``); ``turn`` rotates a head."""
+        Hi, Di = cfg.index_heads, cfg.index_head_dim
+        qi = dense(q_in, p["index_q"]).reshape(B, T, Hi, Di).transpose(
+            0, 2, 1, 3)
+        ki = _layer_norm(dense(h, p["index_k"]), p["index_k_norm"],
+                         cfg.layer_norm_eps)[:, None]
+        wi = dense(h, p["index_w"])
+        return turn(qi), turn(ki), wi
+
     def heads_attention(x, kv, p, window, rope, li):
         """The attention branch over per-head K/V: ``(the cache, the
         branch's output, the normed input, the layer's selection or
@@ -418,19 +437,13 @@ def decoder_forward(cfg: TransformerConfig, params: PyTree,
                 # its ONE key a token cached beside K/V; which cached keys a
                 # row attends is the cache's to answer
                 with jax.named_scope("index"):
-                    Hi, Di = cfg.index_heads, cfg.index_head_dim
-                    qi = dense(h, p["index_q"]).reshape(
-                        B, T, Hi, Di).transpose(0, 2, 1, 3)
-                    ki = _layer_norm(dense(h, p["index_k"]),
-                                     p["index_k_norm"],
-                                     cfg.layer_norm_eps)[:, None]
-                    wi = dense(h, p["index_w"])
-                    if cfg.pos_embed == "rotary":
-                        # the whole indexer head turns, at the model's theta
-                        qi, ki = rotated(partial(
+                    # the whole indexer head turns, at the model's theta
+                    turn = (lambda t: t) if cfg.pos_embed != "rotary" \
+                        else lambda t: rotated(partial(
                             apply_rotary, positions=pos, rotary_dim=None,
                             interleaved=cfg.rotary_interleaved,
-                            theta=cfg.rope_theta), qi, ki)
+                            theta=cfg.rope_theta), t)[0]
+                    qi, ki, wi = indexer(p, h, h, turn)
                 with jax.named_scope("index_write"):
                     kv = cache.write_index(kv, li, ki)
                 sel = cache.select(kv, li, qi, wi, window)
@@ -456,17 +469,20 @@ def decoder_forward(cfg: TransformerConfig, params: PyTree,
         """The attention branch of a latent model (``cfg.kv_lora_rank``):
         the token's one latent row goes to the cache, and what attends over
         the cached rows, in which form, is the cache's to answer
-        (``attend_latent``; ``ops/pallas/latent_attention.py``). ``(the
-        cache, the branch's output)``."""
+        (``attend_latent``; ``ops/pallas/latent_attention.py``). With an
+        indexer (DeepSeek Sparse Attention) the cache first answers which
+        cached rows each query attends, as the heads branch asks it, from
+        indexer queries that read the normed QUERY LATENT. ``(the cache,
+        the branch's output, the layer's selection or None)``."""
         rank, nope, vw = cfg.kv_lora_rank, cfg.qk_nope_head_dim, \
             cfg.v_head_dim
         rms_ = lambda t, q: _layer_norm(t, q, cfg.layer_norm_eps, rms=True)
         with jax.named_scope("block.attn"):
             with jax.named_scope("latent_q"):
                 h = norm(x, p["ln1"])
-                q = dense(rms_(dense(h, p["attn_q_a"]), p["q_a_norm"]),
-                          p["attn_q_b"]).reshape(B, T, nh, hd).transpose(
-                    0, 2, 1, 3)
+                qr = rms_(dense(h, p["attn_q_a"]), p["q_a_norm"])
+                q = dense(qr, p["attn_q_b"]).reshape(
+                    B, T, nh, hd).transpose(0, 2, 1, 3)
                 ckv = dense(h, p["attn_kv_a"])               # [B, T, rank+rope]
                 c = rms_(ckv[..., :rank], p["kv_a_norm"])
                 rot = partial(apply_rotary, positions=pos, rotary_dim=None,
@@ -475,15 +491,26 @@ def decoder_forward(cfg: TransformerConfig, params: PyTree,
                               inv_freq=cfg.rope_inv_freq(cache.rope_len))
                 q_nope, q_pe = q[..., :nope], rot(q[..., nope:])
                 k_pe = rot(ckv[:, None, :, rank:])[:, 0]     # ONE head
+            sel = None
+            if cfg.index_heads:
+                with jax.named_scope("index"):
+                    # the head's leading lanes turn under the model's own
+                    # table, halves rotated; the rest carry no position
+                    qi, ki, wi = indexer(p, qr, h, partial(
+                        rot, rotary_dim=cfg.index_rope_dim,
+                        interleaved=False))
+                with jax.named_scope("index_write"):
+                    kv = cache.write_index(kv, li, ki)
+                sel = cache.select(kv, li, qi, wi, jnp.int32(0))
             with jax.named_scope("latent_write"):
                 kv = cache.write_latent(
                     kv, li, jnp.concatenate([c, k_pe], axis=-1))
             wk, wv = latent_weights(cfg, p["attn_kv_b"], x.dtype)
-            o = cache.attend_latent(kv, li, q_nope, q_pe, wk, wv)
+            o = cache.attend_latent(kv, li, q_nope, q_pe, wk, wv, select=sel)
             with jax.named_scope("out"):
                 attn_out = dense(o.transpose(0, 2, 1, 3).reshape(
                     B, T, nh * vw), p["attn_proj"])
-        return kv, attn_out
+        return kv, attn_out, sel
 
     def layer(carry, xs, dense_mlp=False):
         """One layer; ``dense_mlp``: one of a mixture's leading dense
@@ -491,7 +518,7 @@ def decoder_forward(cfg: TransformerConfig, params: PyTree,
         x, kv = carry
         p, window, rope, li = xs
         if cfg.kv_lora_rank:
-            (kv, attn_out), h, sel = latent_attention(x, kv, p, li), None, None
+            (kv, attn_out, sel), h = latent_attention(x, kv, p, li), None
         else:
             kv, attn_out, h, sel = heads_attention(x, kv, p, window, rope, li)
 
@@ -500,7 +527,9 @@ def decoder_forward(cfg: TransformerConfig, params: PyTree,
             picks or None))``; behind a row's picks the keys it attends, as
             bits, where the layer has an indexer."""
             y, (counts, picks) = routed_mlp(hin)
-            if picks is not None and sel is not None:
+            if expert_picks and sel is not None:
+                if picks is None:       # a leading dense layer: no router
+                    picks = jnp.full((B * T, cfg.moe_k), -1, jnp.int32)
                 bits = selection_bits(sel)
                 picks = jnp.concatenate(
                     [picks, bits.reshape(B * T, bits.shape[-1])], axis=1)
@@ -563,13 +592,16 @@ def decoder_forward(cfg: TransformerConfig, params: PyTree,
         if n_dense:
             # a mixture's leading dense layers: their own stack first, the
             # same layer body, the pools in the one carry
-            carry, _ = jax.lax.scan(
+            carry, (_, dense_picks) = jax.lax.scan(
                 partial(layer, dense_mlp=True), carry,
                 (params["dense_blocks"],)
                 + jax.tree.map(lambda a: a[:n_dense], per_layer))
             per_layer = jax.tree.map(lambda a: a[n_dense:], per_layer)
         (x, carry), (counts, picks) = jax.lax.scan(layer, carry,
                                                    (blocks,) + per_layer)
+        if n_dense and dense_picks is not None:
+            # the leading dense layers' selections, before the sparse rows
+            picks = jnp.concatenate([dense_picks, picks], axis=0)
     out = cache.finish(carry, T)
     with jax.named_scope("head"):
         x = norm(x, params["ln_f"])
@@ -669,10 +701,11 @@ class DenseCache:
             kv["ckv"], row.astype(kv["ckv"].dtype)[None, :, None],
             (li, 0, 0, self.cache["pos"], 0))}
 
-    def attend_latent(self, kv, li, q_nope, q_pe, wk, wv):
+    def attend_latent(self, kv, li, q_nope, q_pe, wk, wv, select=None):
         """Latent attention over the whole preallocated buffer, absorbed
         (the dense-masked form; ``attend``'s f32 scores and -1e30 masks):
-        ``[B, heads, T, v_head_dim]``."""
+        ``[B, heads, T, v_head_dim]``. ``select``: the rows' top-k keys by
+        their indexer, masked beside the causal mask."""
         rank = self.cfg.kv_lora_rank
         rows = jax.lax.dynamic_index_in_dim(kv["ckv"], li, 0,
                                             keepdims=False)[:, 0]
@@ -681,6 +714,10 @@ class DenseCache:
         with jax.named_scope("attend"):
             s = jnp.einsum("bhtw,bkw->bhtk", q, rows).astype(jnp.float32)
             m = self.mask
+            if select is not None:
+                m = m & selected(select.scores[:, :, :self.rope_len],
+                                 select.thr[..., None], select.tie[..., None],
+                                 self.k_slot)
             s = jnp.where(m[:, None] if m.ndim == 3 else m[None, None],
                           s * self.sm_scale, -1e30)
             prob = jax.nn.softmax(s, axis=-1).astype(q.dtype)

@@ -193,11 +193,14 @@ class TransformerConfig:
     # ``count`` experts, a pick of an absent one keeps its weight in the
     # renormalisation and computes nothing. None = all of them
     moe_held: Optional[Tuple[int, int]] = None
-    # group-limited selection (DeepSeek-V2's ``group_limited_greedy``): the
-    # router's outputs lie in moe_groups groups of equal size, a token keeps
-    # the moe_topk_groups groups whose best score is largest and picks its
-    # moe_k among their experts only. 1 = no groups. ``moe_held`` is then
-    # whole groups (device-limited routing: a group a device)
+    # group-limited selection: the router's outputs lie in moe_groups groups
+    # of equal size, a token keeps moe_topk_groups of them and picks its
+    # moe_k among their experts only. 1 = no groups. A softmax router ranks a
+    # group by its best score (DeepSeek-V2's ``group_limited_greedy``), a
+    # sigmoid router by the sum of its two best scores, the selection bias in
+    # them (DeepSeek-V3's ``noaux_tc``). ``moe_held`` is then whole groups
+    # (device-limited routing: a group a device) or an equal part of one
+    # (several devices a group)
     moe_groups: int = 1
     moe_topk_groups: int = 1
     # width of a shared expert (``moe/shared``: a SwiGLU / MLP of the
@@ -219,8 +222,12 @@ class TransformerConfig:
     # index_head_dim against ONE indexer key a token (``index_q``,
     # ``index_k`` + ``index_k_norm``, ``index_w`` in the layer's tree); query
     # t attends the index_topk keys s <= t of largest score, all of them
-    # while it sees no more. 0 = none. The inference decoder only (ROADMAP
-    # M7): a training Block with these set refuses
+    # while it sees no more. 0 = none. Beside latent attention (kv_lora_rank:
+    # DeepSeek-V3.2) ``index_q`` reads the normed query latent ([q_lora_rank,
+    # heads x width]) and the head turns its first ``index_rope_dim`` lanes
+    # under the model's own rotary table, halves rotated; elsewhere it reads
+    # the normed input and turns whole, under the plain table. The inference
+    # decoder only (ROADMAP M7): a training Block with these set refuses
     index_heads: int = 0
     index_head_dim: int = 0
     index_topk: int = 0
@@ -307,17 +314,25 @@ class TransformerConfig:
                     f"{self.moe_topk_groups}: the router's {self.moe_experts}"
                     " outputs in equal groups, of which a token keeps enough "
                     f"to hold its {self.moe_k} picks")
-            if self.moe_select_bias:
+            if self.moe_select_bias and self.moe_scores != "sigmoid":
                 raise ValueError(
-                    "moe_groups with moe_select_bias: the grouped router "
+                    "moe_groups with moe_select_bias: the softmax router "
                     "ranks a group by its best SCORE (group_limited_greedy);"
-                    " a bias on the selection (DeepSeek-V3's noaux_tc, "
-                    "groups by their two best biased scores) is not carried")
-            if self.moe_held is not None and (self.moe_held[0] % size
-                                              or self.moe_held[1] % size):
+                    " a bias on the selection is the sigmoid router's "
+                    "(noaux_tc)")
+            if self.moe_scores == "sigmoid" and size < 2:
                 raise ValueError(
-                    f"moe_held {self.moe_held}: with moe_groups the share "
-                    f"held is whole groups of {size} experts")
+                    f"moe_groups {self.moe_groups} of a sigmoid router: a "
+                    "group is ranked by its TWO best scores (noaux_tc)")
+            if self.moe_held is not None:
+                first, count = self.moe_held
+                whole = first % size == 0 and count % size == 0
+                part = size % count == 0 and first % count == 0
+                if not (whole or part):
+                    raise ValueError(
+                        f"moe_held {self.moe_held}: with moe_groups the "
+                        f"share held is whole groups of {size} experts, or "
+                        "an equal part of one group")
         if self.dense_layers:
             if not (0 < self.dense_layers < self.num_layers
                     and self.moe_experts > 0 and self.dense_mlp_dim):
@@ -344,11 +359,14 @@ class TransformerConfig:
                     f"{self.index_head_dim}, index_topk {self.index_topk}: "
                     "an indexer has all three, each > 0")
             if not self.causal or self.post_ln or self.index_head_dim % 2 \
-                    or self.rope_scaling_type not in (None, "default"):
+                    or (self.rope_scaling_type not in (None, "default")
+                        and not self.kv_lora_rank):
                 raise ValueError(
                     "an indexer selects the keys of a causal decoder (not "
                     "post_ln), its head width is even (rotary), and its "
-                    "rotary table is the plain one (no rope_scaling_type)")
+                    "rotary table is the plain one (no rope_scaling_type) "
+                    "unless it stands beside latent attention, whose own "
+                    "table it turns under")
         if self.rope_scaling_type == "yarn":
             if self.rope_scaling_factor < 1.0 \
                     or self.rope_beta_fast <= self.rope_beta_slow:
@@ -377,7 +395,10 @@ class TransformerConfig:
                  self.pos_embed != "rotary"),
                 ("no sliding window (layer_windows)",
                  self.layer_windows is not None),
-                ("no indexer beside it (index_heads)", self.index_heads > 0),
+                ("an indexer head at least as wide as the rotated shared "
+                 "key (index_head_dim >= qk_rope_head_dim: its leading lanes "
+                 "turn under the model's table)",
+                 0 < self.index_head_dim < self.qk_rope_head_dim),
                 ("one stored row a token, no KV heads (num_kv_heads)",
                  self.num_kv_heads not in (None, self.num_heads)),
                 ("a causal pre-norm decoder without q/k norms, softcap, "
@@ -442,6 +463,24 @@ class TransformerConfig:
         and the rotated shared key (0: no latent attention)."""
         return self.kv_lora_rank + self.qk_rope_head_dim \
             if self.kv_lora_rank else 0
+
+    @property
+    def index_rope_dim(self) -> int:
+        """The leading lanes of an indexer head that turn with position:
+        the whole head (a model with K/V heads rotates whole heads), or,
+        beside latent attention, as many as its rotated shared key has
+        (DeepSeek-V3.2's indexer: 64 of 128), halves rotated, under the
+        model's own table."""
+        return min(self.qk_rope_head_dim, self.index_head_dim) \
+            if self.kv_lora_rank else self.index_head_dim
+
+    @property
+    def routed_layers(self) -> int:
+        """Layers that hand a row out to a request that asked for its
+        routing: those with picks or a selection (an indexer stands in
+        every layer, a mixture's leading dense ones too)."""
+        return self.num_layers if self.index_heads and self.moe_experts \
+            else self.sparse_layers
 
     @property
     def latent_lanes(self) -> int:
@@ -561,10 +600,18 @@ class TransformerConfig:
         if self.kv_lora_rank:
             # q_a + its norm, q_b, kv_a + its norm, kv_b, the output
             qr, kr, nh = self.q_lora_rank, self.kv_lora_rank, self.num_heads
-            return (h * qr + qr + qr * nh * self.head_dim
-                    + h * self.latent_width + kr
-                    + kr * nh * (self.qk_nope_head_dim + self.v_head_dim)
-                    + nh * self.v_head_dim * h)
+            n = (h * qr + qr + qr * nh * self.head_dim
+                 + h * self.latent_width + kr
+                 + kr * nh * (self.qk_nope_head_dim + self.v_head_dim)
+                 + nh * self.v_head_dim * h)
+            if self.index_heads:
+                # the indexer beside a latent: its queries from the query
+                # latent, its one key (and that key's LayerNorm) and its
+                # head weights from the normed input
+                n += qr * self.index_heads * self.index_head_dim \
+                    + h * (self.index_head_dim + self.index_heads) \
+                    + 2 * self.index_head_dim
+            return n
         n = (self.num_heads + 2 * self.kv_heads) * self.head_dim * h \
             + self.num_heads * self.head_dim * h   # qkv (GQA) + out proj
         if self.index_heads:
@@ -1007,6 +1054,16 @@ class Block(nn.Module):
                     jnp.zeros((B, S, cfg.kv_lora_rank), cfg.dtype))
                 dense(nh * (cfg.qk_nope_head_dim + cfg.v_head_dim),
                       "attn_kv_b", bias=False)(latent)
+                if cfg.index_heads:
+                    # the indexer beside a latent: its queries read the
+                    # normed query latent, its key and head weights ``h``
+                    Hi, Di = cfg.index_heads, cfg.index_head_dim
+                    dense(Hi * Di, "index_q", bias=False)(
+                        jnp.zeros((B, S, cfg.q_lora_rank), cfg.dtype))
+                    nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
+                                 param_dtype=jnp.float32, name="index_k_norm")(
+                        dense(Di, "index_k", bias=False)(h))
+                    dense(Hi, "index_w", bias=False)(h)
                 if not self.is_initializing():
                     raise NotImplementedError(
                         "kv_lora_rank: latent attention is served "

@@ -47,15 +47,19 @@ class Routing(NamedTuple):
     groups: Optional[jnp.ndarray] = None
 
 
-def kept_groups(scores: jnp.ndarray, groups: Tuple[int, int]) -> jnp.ndarray:
+def kept_groups(scores: jnp.ndarray, groups: Tuple[int, int],
+                best: int = 1) -> jnp.ndarray:
     """``[T, n]`` bool: of the ``n`` equal groups the ``E`` scores lie in,
     the ``keep`` whose BEST score is largest (DeepSeek-V2's
     ``group_limited_greedy``; of equal maxima the lower group, as
-    ``lax.top_k`` orders them)."""
+    ``lax.top_k`` orders them). ``best = 2``: a group ranks by the SUM of
+    its two best scores (DeepSeek-V3's ``noaux_tc``, on the biased scores)."""
     n, keep = groups
     T, E = scores.shape
-    best = jnp.max(scores.reshape(T, n, E // n), axis=-1)
-    _, which = jax.lax.top_k(best, keep)
+    in_groups = scores.reshape(T, n, E // n)
+    rank = jnp.max(in_groups, axis=-1) if best == 1 else jnp.sum(
+        jax.lax.top_k(in_groups, best)[0], axis=-1)
+    _, which = jax.lax.top_k(rank, keep)
     return jnp.zeros((T, n), bool).at[jnp.arange(T)[:, None], which].set(True)
 
 
@@ -86,13 +90,22 @@ def route_topk(logits: jnp.ndarray, k: int, renorm: bool,
 
 def route_sigmoid_topk(logits: jnp.ndarray, k: int, renorm: bool,
                        bias: Optional[jnp.ndarray] = None,
-                       scale: float = 1.0) -> Routing:
-    """The DeepSeek-V3 router without groups: every expert's score is its
-    own float32 sigmoid; the k largest of ``score + bias`` are picked (the
-    bias steers the SELECTION and never weighs); the picks' weights are their
-    scores, with ``renorm`` over their sum + 1e-20, times ``scale``."""
+                       scale: float = 1.0,
+                       groups: Optional[Tuple[int, int]] = None) -> Routing:
+    """The DeepSeek-V3 router: every expert's score is its own float32
+    sigmoid; the k largest of ``score + bias`` are picked (the bias steers
+    the SELECTION and never weighs); the picks' weights are their scores,
+    with ``renorm`` over their sum + 1e-20, times ``scale``. ``groups = (n,
+    keep)`` (``noaux_tc``): the k are picked among the experts of the
+    ``keep`` groups whose two best BIASED scores add up to most
+    (:func:`kept_groups`; the others' read 0 for the selection)."""
     scores = jax.nn.sigmoid(logits.astype(jnp.float32))
     select = scores if bias is None else scores + bias.astype(jnp.float32)
+    kept = None
+    if groups is not None:
+        kept = kept_groups(select, groups, best=2)
+        select = jnp.where(jnp.repeat(
+            kept, scores.shape[-1] // groups[0], axis=1), select, 0.0)
     _, experts = jax.lax.top_k(select, k)
     weights = jnp.take_along_axis(scores, experts, axis=-1)
     if renorm:
@@ -100,7 +113,7 @@ def route_sigmoid_topk(logits: jnp.ndarray, k: int, renorm: bool,
     if scale != 1.0:
         weights = weights * scale
     return Routing(scores, experts.astype(jnp.int32), weights,
-                   _group_sizes(experts, logits.shape[-1]))
+                   _group_sizes(experts, logits.shape[-1]), kept)
 
 
 def _group_sizes(experts: jnp.ndarray, n: int) -> jnp.ndarray:
@@ -224,10 +237,11 @@ def dropless_moe(tokens: jnp.ndarray, router_kernel: jnp.ndarray,
 
     ``scores``: "softmax" (:func:`route_topk`, with ``scale`` and the
     group-limited selection ``groups = (n, keep)``) or "sigmoid"
-    (:func:`route_sigmoid_topk`, with ``select_bias [E]`` and ``scale``).
-    ``held = (first, count)``: the router ranks all its ``E`` outputs and
-    ``experts`` holds ``count`` of them, ``first ..``: a chip's share of an
-    expert-parallel layer, run without its exchange. The groups are the held
+    (:func:`route_sigmoid_topk`, with ``select_bias [E]``, ``scale`` and
+    ``groups``, ranked its way). ``held = (first, count)``: the router ranks
+    all its ``E`` outputs and ``experts`` holds ``count`` of them, ``first
+    ..``: a chip's share of an expert-parallel layer (whole routing groups,
+    or a part of one), run without its exchange. The groups are the held
     experts'; a pick of an absent expert keeps its weight in the
     renormalisation, sorts behind the last group (rows the grouped matmul
     never walks) and adds nothing. ``Routing`` is over the router's ``E``.
@@ -252,7 +266,8 @@ def dropless_moe(tokens: jnp.ndarray, router_kernel: jnp.ndarray,
                          router_kernel.astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
         if scores == "sigmoid":
-            r = route_sigmoid_topk(logits, k, renorm, select_bias, scale)
+            r = route_sigmoid_topk(logits, k, renorm, select_bias, scale,
+                                   groups)
         else:
             r = route_topk(logits, k, renorm, groups, scale)
     with jax.named_scope("dispatch"):

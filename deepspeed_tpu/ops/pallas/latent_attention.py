@@ -75,10 +75,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import NEG_INF
 from .paged_attention import _CHUNK_TILE, _attend
+from .sparse_select import selected as _selected
 
 __all__ = ["latent_attention", "latent_chunk_attention",
            "latent_attention_reference", "untileable", "path", "form",
-           "chunk_tiles", "tile_keys", "chunk_expanded_keys", "KERNEL_NAME"]
+           "chunk_tiles", "tile_keys", "chunk_expanded_keys",
+           "chunk_row_tiles", "chunk_key_tiles", "KERNEL_NAME"]
 
 #: the custom call's name: ``paged_attention`` (what every metric of paged
 #: attention matches) and a suffix of its own
@@ -142,6 +144,44 @@ def chunk_expanded_keys(rows: int, q_start: int, ctx: int) -> int:
     return int(tile_keys(ctx, q_start + per * np.arange(n), per, np).sum())
 
 
+def chunk_row_tiles(q0, ctx, rows: int, k0, keys: int, xp=jnp):
+    """``(lo, full, hi)``: of a chunk program's ``rows // 256`` row tiles
+    (rows from position ``q0``, a lane of ``ctx`` live tokens), those that
+    meet the turn whose keys lie at ``[k0, k0 + keys)``: tiles ``[lo, hi)``,
+    from the one whose last row stands at the turn's first key to the last
+    with a real row; of them ``[full, hi)`` need no causal mask (their first
+    row stands at or past the turn's last key, and the context does not end
+    inside the turn). The kernel's own rule; ``xp``: numpy for a host that
+    counts what the kernel will do (:func:`chunk_key_tiles`)."""
+    rt = _CHUNK_TILE
+    lo = xp.maximum(k0 - q0, 0) // rt
+    hi = xp.minimum((ctx - q0 + rt - 1) // rt, rows // rt)
+    full = xp.where(k0 + keys <= ctx, xp.clip(
+        (xp.maximum(k0 + keys - 1 - q0, 0) + rt - 1) // rt, lo, hi), hi)
+    return lo, full, hi
+
+
+def chunk_key_tiles(rows: int, q_start: int, ctx: int, bs: int, nbk: int
+                    ) -> Tuple[int, int]:
+    """``(pairs, live pairs)`` of a chunk call of ``rows`` (padded) rows
+    from ``q_start`` over a lane of ``ctx`` live tokens, a head group: the
+    (256-row tile, key turn) pairs of the turns its programs walk, and those
+    whose matmuls :func:`chunk_row_tiles` lets them make (under a selection
+    a pair none of whose rows selected a key of the turn is skipped besides,
+    which only the device knows). On the host."""
+    n, per = chunk_tiles(rows)
+    keys = max(1, min(_CHUNK_KEYS // bs, nbk)) * bs
+    pairs = live = 0
+    for t in range(n):
+        q0 = q_start + t * per
+        turns = -(-int(tile_keys(ctx, q0, per, np)) // keys)
+        lo, _, hi = chunk_row_tiles(q0, ctx, per, np.arange(turns) * keys,
+                                    keys, np)
+        pairs += turns * (per // _CHUNK_TILE)
+        live += int(np.maximum(hi - lo, 0).sum())
+    return pairs, live
+
+
 def untileable(q_shape, pool_shape, interpret: bool = False
                ) -> Optional[str]:
     """The reason these shapes cannot ride the kernel, or None: asked BEFORE
@@ -184,7 +224,7 @@ def form(rows: int) -> str:
 
 
 def _pages(bt_ref, lens_ref, misc_ref, pool, buf, state, sem, *, bs, P, nbk,
-           rows=None, seen=None):
+           rows=None, seen=None, beside=None):
     """What a decode program and a chunk program share: the walk over lane
     b's live pages, ``P`` a group, through the two buffers of ``buf``, the
     group after (and behind a program's last group the next program's
@@ -194,7 +234,9 @@ def _pages(bt_ref, lens_ref, misc_ref, pool, buf, state, sem, *, bs, P, nbk,
     lane); ``rows``: what a chunk program holds (None: a decode token),
     ``seen``: :func:`tile_keys`, handed in (the serving loop calls it on the
     host, and the package's linter takes every function a traced body names
-    for device work)."""
+    for device work). ``beside(act, b, t, i, slot)``: one more copy a group,
+    started and waited for with its pages (a selecting chunk's index
+    scores)."""
     b, g, t = (pl.program_id(i) for i in range(3))
     nb, ng, nt = (pl.num_programs(i) for i in range(3))
     layer = misc_ref[0]
@@ -209,9 +251,13 @@ def _pages(bt_ref, lens_ref, misc_ref, pool, buf, state, sem, *, bs, P, nbk,
         cnt = jnp.minimum((ctx + bs - 1) // bs, nbk)
         return cnt, (cnt + P - 1) // P
 
-    def copies(act, b, cnt, i, slot):
-        """Start or wait for group i of lane b into buffer ``slot``: a page
-        a descriptor, out of the pool where the table says it lies."""
+    def copies(act, b, cnt, i, slot, t=t):
+        """Start or wait for group i of lane b (row tile t) into buffer
+        ``slot``: a page a descriptor, out of the pool where the table says
+        it lies."""
+        if beside is not None:
+            beside(act, b, t, i, slot)
+
         def body(p, _):
             page = i * P + p
 
@@ -261,7 +307,7 @@ def _pages(bt_ref, lens_ref, misc_ref, pool, buf, state, sem, *, bs, P, nbk,
 
             @pl.when((i + 1 == g1) & has_nxt)
             def _next_program():
-                start(b_nxt, cnt_nxt, 0, 1 - slot)
+                start(b_nxt, cnt_nxt, 0, 1 - slot, t_nxt)
                 state[1] = 1
 
             wait(b, cnt, i, slot)
@@ -273,10 +319,17 @@ def _pages(bt_ref, lens_ref, misc_ref, pool, buf, state, sem, *, bs, P, nbk,
     return b, loop
 
 
-def _kernel(bt_ref, lens_ref, misc_ref, q_ref, pool, o_ref, buf, acc, m_scr,
-            l_scr, state, sem, *, bs, P, nbk, value, sm_scale):
+def _kernel(bt_ref, lens_ref, misc_ref, q_ref, pool, *rest, bs, P, nbk,
+            value, sm_scale, select=False):
     """A decode program, ABSORBED: lane b's one tile of ``heads`` rows
-    against the lane's stored rows themselves (module docstring)."""
+    against the lane's stored rows themselves (module docstring).
+    ``select``: the lane's index scores ``[1, keys of the table]`` (a block
+    in VMEM) and its row's threshold and tie position; the heads share the
+    ONE mask row a turn they make."""
+    rest = list(rest)
+    sel_ref, thr_ref, tie_ref = (rest.pop(0), rest.pop(0), rest.pop(0)) \
+        if select else (None, None, None)
+    o_ref, buf, acc, m_scr, l_scr, state, sem = rest
     b, loop = _pages(bt_ref, lens_ref, misc_ref, pool, buf, state, sem,
                      bs=bs, P=P, nbk=nbk)
     acc[...] = jnp.zeros_like(acc)
@@ -286,9 +339,13 @@ def _kernel(bt_ref, lens_ref, misc_ref, q_ref, pool, o_ref, buf, acc, m_scr,
 
     def tile(i, slot):
         rows = buf[slot]                                # [1, P * bs, width]
+        sel = None
+        if select:
+            at = pl.ds(pl.multiple_of(i * (P * bs), P * bs), P * bs)
+            sel = (sel_ref[0, :, at], thr_ref[0][:, :1], tie_ref[0][:, :1])
         _attend(q, rows, rows[:, :, :value], None, None, i * (P * bs),
                 lens_ref[b], 0, None, acc, m_scr, l_scr, sm_scale=sm_scale,
-                softcap=0.0)
+                softcap=0.0, sel=sel)
 
     loop(tile)
     l = l_scr[:, :, :1]
@@ -296,19 +353,39 @@ def _kernel(bt_ref, lens_ref, misc_ref, q_ref, pool, o_ref, buf, acc, m_scr,
 
 
 def _chunk_kernel(bt_ref, lens_ref, misc_ref, qn_ref, qp_ref, wk_ref, wv_ref,
-                  pool, o_ref, buf, kx, vx, acc, m_scr, l_scr, state, sem, *,
-                  bs, P, nbk, rank, sm_scale, seen):
+                  pool, *rest, bs, P, nbk, rank, sm_scale, seen, tiles,
+                  select=False):
     """A chunk program, EXPANDED: ALL of a row tile's rows (the chunk's, up
     to ``_CHUNK_ROWS``) of ``gq`` heads against lane b's pages, each key group put through the heads' slices of
     ``attn_kv_b`` ONCE (``kx`` / ``vx``) and then met by every 256-row tile
-    of the program that sees it (module docstring)."""
+    of the program that sees it (module docstring). ``select``: the index
+    scores of the program's rows against a turn's keys ``[rows, keys]``,
+    copied out of the scores where the top-k left them beside the turn's
+    pages, and each row's threshold and tie position: a (row tile, turn) is
+    masked ONCE for the program's heads, and one none of whose rows selected
+    a key makes no matmul."""
+    rest = list(rest)
+    sel_hbm, thr_ref, tie_ref = (rest.pop(0), rest.pop(0), rest.pop(0)) \
+        if select else (None, None, None)
+    o_ref, buf, kx, vx, acc, m_scr, l_scr, state, sem = rest[:9]
+    sel_buf, sel_sem = rest[9:] if select else (None, None)
     gq, rows, rt, keys = acc.shape[0], acc.shape[1], _CHUNK_TILE, P * bs
     rope, lanes = qp_ref.shape[-1], m_scr.shape[-1]
     # the lanes of a row's ``alpha`` that rescale its accumulator: all of
     # them where the value is as wide, else one, broadcast
     width = lanes if acc.shape[-1] == lanes else 1
+
+    def scores_of(act, b, t, i, slot):
+        """Start or wait for the index scores of row tile t of lane b
+        against group i's keys."""
+        getattr(pltpu.make_async_copy(
+            sel_hbm.at[b, pl.ds(pl.multiple_of(t * rows, rows), rows),
+                       pl.ds(pl.multiple_of(i * keys, keys), keys)],
+            sel_buf.at[slot], sel_sem.at[slot]), act)()
+
     b, loop = _pages(bt_ref, lens_ref, misc_ref, pool, buf, state, sem,
-                     bs=bs, P=P, nbk=nbk, rows=rows, seen=seen)
+                     bs=bs, P=P, nbk=nbk, rows=rows, seen=seen,
+                     beside=scores_of if select else None)
     ctx, q0 = lens_ref[b], misc_ref[2 + b] + pl.program_id(2) * rows
     acc[...] = jnp.zeros_like(acc)
     m_scr[...] = jnp.full_like(m_scr, NEG_INF)
@@ -347,28 +424,45 @@ def _chunk_kernel(bt_ref, lens_ref, misc_ref, qn_ref, qp_ref, wk_ref, wv_ref,
         def row_tile(masked, r, _):
             r0 = pl.multiple_of(r * rt, rt)
             at = pl.ds(r0, rt)
-            if masked:
+            if select:
+                # the row's top-k keys by its index score, which reads -inf
+                # where the row does not see the key: the causal mask and
+                # the context's end are in it
+                sc = sel_buf[slot, at]
+                keep = _selected(
+                    sc, thr_ref[0, at, :1], tie_ref[0, at, :1],
+                    k0 + jax.lax.broadcasted_iota(jnp.int32, (rt, keys), 1)) \
+                    & (sc > -jnp.inf)
+            elif masked:
                 # row r0 + j sees the keys up to its own position and,
                 # a padding row, the live ones (finite, read by nobody)
                 last = jnp.minimum(q0 + r0 + jax.lax.broadcasted_iota(
                     jnp.int32, (rt, 1), 0), ctx - 1) - k0
                 keep = jax.lax.broadcasted_iota(
                     jnp.int32, (rt, keys), 1) <= last
-            for h in range(gq):
-                s = (dot(qn_ref[0, h, at], kx[h], nt)
-                     + dot(qp_ref[0, h, at], k_pe, nt)) * sm_scale
-                attend(h, at, jnp.where(keep, s, NEG_INF) if masked else s)
+
+            def heads():
+                for h in range(gq):
+                    s = (dot(qn_ref[0, h, at], kx[h], nt)
+                         + dot(qp_ref[0, h, at], k_pe, nt)) * sm_scale
+                    attend(h, at, jnp.where(keep, s, NEG_INF) if masked
+                           else s)
+
+            if select:
+                pl.when(jnp.max(keep.astype(jnp.int32)) > 0)(heads)
+            else:
+                heads()
 
         # the program's 256-row tiles that see this group: from the one whose
         # last row stands at its first key to the last with a real row;
         # those whose FIRST row stands at or past its last key (and none
         # where the context ends inside the group) need no mask
-        lo = jnp.maximum(k0 - q0, 0) // rt
-        hi = jnp.minimum((ctx - q0 + rt - 1) // rt, rows // rt)
-        full = jnp.where(k0 + keys <= ctx, jnp.clip(
-            (jnp.maximum(k0 + keys - 1 - q0, 0) + rt - 1) // rt, lo, hi), hi)
-        jax.lax.fori_loop(lo, full, partial(row_tile, True), None)
-        jax.lax.fori_loop(full, hi, partial(row_tile, False), None)
+        # (a selection masks every tile: one loop)
+        lo, full, hi = tiles(q0, ctx, rows, k0, keys)
+        jax.lax.fori_loop(lo, hi if select else full,
+                          partial(row_tile, True), None)
+        if not select:
+            jax.lax.fori_loop(full, hi, partial(row_tile, False), None)
 
     loop(tile)
     l = jnp.sum(l_scr[...], axis=2, keepdims=True)
@@ -382,10 +476,29 @@ def _lens_layer(context_lens, layer_idx, B):
             jnp.asarray(layer_idx, jnp.int32).reshape(()))
 
 
+def _selection_rows(select, rows: int, keys: int):
+    """A call's :class:`sparse_select.Selection` as the kernels take it:
+    the scores ``[B, rows, keys]`` (rows and keys past the call's own read
+    ``-inf``: nothing of them is selected; a call of whole tiles over a
+    table of whole turns is handed on as it is), and ``thr`` / ``tie`` ``[B,
+    rows, 128]``, a row's on every lane."""
+    B, T, Kp = select.scores.shape
+    sc = select.scores.astype(jnp.float32)[:, :, :keys]
+    more = (rows - T, keys - min(Kp, keys))
+    if any(more):
+        sc = jnp.pad(sc, [(0, 0), (0, more[0]), (0, more[1])],
+                     constant_values=-jnp.inf)
+    on_lanes = lambda a, fill: jnp.broadcast_to(jnp.pad(
+        a, [(0, 0), (0, rows - T)], constant_values=fill)[:, :, None],
+        (B, rows, 128))
+    return [sc, on_lanes(select.thr.astype(jnp.float32), jnp.inf),
+            on_lanes(select.tie.astype(jnp.int32), -1)]
+
+
 def latent_attention(q: jnp.ndarray, pool: jnp.ndarray,
                      block_tables: jnp.ndarray, context_lens: jnp.ndarray, *,
                      value: int, sm_scale: float, layer_idx,
-                     interpret: bool = False) -> jnp.ndarray:
+                     interpret: bool = False, select=None) -> jnp.ndarray:
     """A decode call, ABSORBED: every lane's fresh token, at
     ``context_lens[b] - 1``, against its latent pages.
 
@@ -394,6 +507,9 @@ def latent_attention(q: jnp.ndarray, pool: jnp.ndarray,
     pool: ``[L, 1, blocks, block_size, width]``, layer ``layer_idx`` (traced
        ok) read in place. ``value``: the lanes of a row that are its value
        (``kv_lora_rank``).
+    select: a layer with an indexer (``sparse_select.Selection`` of the
+       call's one row a lane): a lane attends the keys ``selected`` keeps
+       among those it sees; the loop still walks every live page.
     Returns ``[B, heads, 1, value]``, for ``absorb_output``.
     """
     B, nh, T, width = q.shape
@@ -410,10 +526,18 @@ def latent_attention(q: jnp.ndarray, pool: jnp.ndarray,
     tile = lambda w: pl.BlockSpec((1, 1, nh, w),
                                   lambda b, g, t, *_: (b, g, t, 0))
     kernel = partial(_kernel, bs=bs, P=P, nbk=nbk, value=value,
-                     sm_scale=float(sm_scale))
+                     sm_scale=float(sm_scale), select=select is not None)
+    sel_ops, sel_specs = [], []
+    if select is not None:
+        # a lane's scores of the table's keys, whole turns: its block
+        keys = -(-nbk // P) * P * bs
+        sel_ops = _selection_rows(select, 1, keys)
+        of_lane = lambda w: pl.BlockSpec((1, 1, w),
+                                         lambda b, g, t, *_: (b, 0, 0))
+        sel_specs = [of_lane(keys), of_lane(128), of_lane(128)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3, grid=(B, 1, 1),
-        in_specs=[tile(width), pl.BlockSpec(memory_space=pl.ANY)],
+        in_specs=[tile(width), pl.BlockSpec(memory_space=pl.ANY)] + sel_specs,
         out_specs=tile(value),
         scratch_shapes=[pltpu.VMEM((2, 1, P * bs, width), pool.dtype),
                         pltpu.VMEM((1, nh, value), jnp.float32),
@@ -426,13 +550,14 @@ def latent_attention(q: jnp.ndarray, pool: jnp.ndarray,
             kernel, grid_spec=grid_spec, name=KERNEL_NAME,
             out_shape=jax.ShapeDtypeStruct((B, 1, nh, value), q.dtype),
             interpret=interpret,
-        )(jnp.asarray(block_tables, jnp.int32), lens, misc, qf,
-          pool).reshape(B, nh, 1, value)
+        )(jnp.asarray(block_tables, jnp.int32), lens, misc, qf, pool,
+          *sel_ops).reshape(B, nh, 1, value)
 
 
 def latent_chunk_attention(q_nope, q_pe, wk, wv, pool, block_tables,
                            context_lens, *, sm_scale: float, layer_idx,
-                           q_start=None, interpret: bool = False):
+                           q_start=None, interpret: bool = False,
+                           select=None):
     """A prefill chunk's call, EXPANDED: T > 1 rows a lane at positions
     ``q_start[b] + r`` (``context_lens - T`` by default), causal among
     themselves, against the lane's latent pages; rows at or past
@@ -443,6 +568,10 @@ def latent_chunk_attention(q_nope, q_pe, wk, wv, pool, block_tables,
     wk ``[heads, rank, nope]``, wv ``[heads, rank, v]``: ``attn_kv_b`` a
        head (``models/generation.latent_weights``).
     pool: as :func:`latent_attention`'s, a row ``[c (rank) | k_pe | zeros]``.
+    select: a layer with an indexer (``sparse_select.Selection`` of the
+       call's T rows): each row attends the keys ``selected`` keeps. The
+       scores stay where the top-k left them (a last chunk of fewer rows
+       than whole row tiles, or a table of no whole turns, pads them).
     Returns ``[B, heads, T, v]``: the heads' outputs themselves.
     """
     B, nh, T, _ = q_nope.shape
@@ -460,13 +589,14 @@ def latent_chunk_attention(q_nope, q_pe, wk, wv, pool, block_tables,
     pad = lambda q: jnp.pad(q, [(0, 0), (0, 0), (0, n * per - T), (0, 0)])
     call = _shared_chunk_call if isinstance(interpret, bool) else _chunk_call
     return call(pad(q_nope), pad(q_pe), wk, wv, pool, block_tables, lens, li,
-                q0, rows=per, sm_scale=float(sm_scale),
-                interpret=interpret)[:, :, :T]
+                q0, rows=per, sm_scale=float(sm_scale), interpret=interpret,
+                sel=None if select is None else tuple(select))[:, :, :T]
 
 
 def _chunk_call(qn, qp, wk, wv, pool, block_tables, lens, li, q0, *,
-                rows, sm_scale, interpret):
-    """:func:`latent_chunk_attention` on whole row tiles of ``rows`` rows."""
+                rows, sm_scale, interpret, sel=None):
+    """:func:`latent_chunk_attention` on whole row tiles of ``rows`` rows
+    (``sel``: the call's selection, ``(scores, thr, tie)``)."""
     B, nh, nope = qn.shape[0], qn.shape[1], qn.shape[3]
     nt = qn.shape[2] // rows
     rope, rank, vw = qp.shape[-1], wk.shape[1], wv.shape[-1]
@@ -480,13 +610,23 @@ def _chunk_call(qn, qp, wk, wv, pool, block_tables, lens, li, q0, *,
     heads_of = lambda w: pl.BlockSpec((gq, rank, w),
                                       lambda b, g, t, *_: (g, 0, 0))
     kernel = partial(_chunk_kernel, bs=bs, P=P, nbk=nbk, rank=rank,
-                     sm_scale=sm_scale, seen=tile_keys)
+                     sm_scale=sm_scale, seen=tile_keys, tiles=chunk_row_tiles,
+                     select=sel is not None)
     # the running max and sum a row: a lane tile of the group's keys wide
     lanes = 128 if P * bs % 128 == 0 else P * bs
+    sel_ops, sel_specs, sel_scratch = [], [], []
+    if sel is not None:
+        from .sparse_select import Selection
+        sel_ops = _selection_rows(Selection(*sel), nt * rows,
+                                  -(-nbk // P) * P * bs)
+        of_rows = pl.BlockSpec((1, rows, 128), lambda b, g, t, *_: (b, t, 0))
+        sel_specs = [pl.BlockSpec(memory_space=pl.ANY), of_rows, of_rows]
+        sel_scratch = [pltpu.VMEM((2, rows, P * bs), jnp.float32),
+                       pltpu.SemaphoreType.DMA((2,))]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3, grid=(B, nh // gq, nt),
         in_specs=[rows_of(nope), rows_of(rope), heads_of(nope), heads_of(vw),
-                  pl.BlockSpec(memory_space=pl.ANY)],
+                  pl.BlockSpec(memory_space=pl.ANY)] + sel_specs,
         out_specs=rows_of(vw),
         scratch_shapes=[pltpu.VMEM((2, 1, P * bs, width), pool.dtype),
                         pltpu.VMEM((gq, P * bs, nope), pool.dtype),
@@ -495,7 +635,7 @@ def _chunk_call(qn, qp, wk, wv, pool, block_tables, lens, li, q0, *,
                         pltpu.VMEM((gq, rows, lanes), jnp.float32),
                         pltpu.VMEM((gq, rows, lanes), jnp.float32),
                         pltpu.SMEM((2,), jnp.int32),
-                        pltpu.SemaphoreType.DMA((2,))])
+                        pltpu.SemaphoreType.DMA((2,))] + sel_scratch)
     with jax.named_scope("paged_attention"):
         return pl.pallas_call(
             kernel, grid_spec=grid_spec, name=KERNEL_NAME,
@@ -504,7 +644,7 @@ def _chunk_call(qn, qp, wk, wv, pool, block_tables, lens, li, q0, *,
                 vmem_limit_bytes=_CHUNK_VMEM),
             interpret=interpret,
         )(jnp.asarray(block_tables, jnp.int32), lens, misc, qn, qp, wk, wv,
-          pool)
+          pool, *sel_ops)
 
 
 #: a chunk's call under ``jax.jit``: traced once for every prefill program
@@ -515,10 +655,11 @@ _shared_chunk_call = jax.jit(
 
 def latent_attention_reference(q, pool, block_tables, context_lens, *,
                                value: int, sm_scale: float, layer_idx,
-                               q_start=None) -> jnp.ndarray:
+                               q_start=None, select=None) -> jnp.ndarray:
     """jnp oracle / CPU fallback of :func:`latent_attention`: the lane's
     rows gathered through the table, float32 scores, -1e30 masks
-    (``paged_attention_reference``'s arithmetic)."""
+    (``paged_attention_reference``'s arithmetic; ``select``: the dense mask
+    of the rows' selected keys beside the causal one)."""
     B, nh, T, _ = q.shape
     bs, nbk = pool.shape[3], block_tables.shape[1]
     lens = jnp.asarray(context_lens, jnp.int32).reshape(B)
@@ -528,7 +669,14 @@ def latent_attention_reference(q, pool, block_tables, context_lens, *,
     q_abs = (lens[:, None] - T if q_start is None else jnp.asarray(
         q_start, jnp.int32).reshape(B)[:, None]) + jnp.arange(T)
     s = jnp.einsum("bhtw,bkw->bhtk", q, rows).astype(jnp.float32) * sm_scale
-    keep = jnp.arange(nbk * bs)[None, None, :] <= q_abs[:, :, None]
+    k_pos = jnp.arange(nbk * bs)
+    keep = k_pos[None, None, :] <= q_abs[:, :, None]
+    if select is not None:
+        sc = jnp.pad(select.scores[:, :, :nbk * bs], [(0, 0), (0, 0), (0, max(
+            0, nbk * bs - select.scores.shape[2]))],
+            constant_values=-jnp.inf)
+        keep = keep & _selected(sc, select.thr[..., None],
+                                select.tie[..., None], k_pos)
     s = jnp.where(keep[:, None], s, NEG_INF)
     prob = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return jnp.einsum("bhtk,bhkd->bhtd", prob, jnp.broadcast_to(

@@ -12,9 +12,9 @@ table. Admissions, evictions and completions only change the DATA in
 those arrays, never their shapes, so the loop compiles exactly one
 decode step for its whole lifetime (pinned by tests via
 ``_cache_size``); prefills compile once per block-rounded prompt-suffix
-bucket. This is the role CUDA-graph capture plays in the reference's
-``InferenceEngine`` — here XLA's compile cache IS the graph cache, and
-the fixed shapes are what keep it hot.
+bucket (``_prefill_rows``). This is the role CUDA-graph capture plays in
+the reference's ``InferenceEngine`` — here XLA's compile cache IS the graph
+cache, and the fixed shapes are what keep it hot.
 
 Supervision: each loop iteration stamps a ``SERVE`` heartbeat phase
 (runtime/heartbeat.py), so the PR-6 watchdog/health stack bounds a wedged
@@ -47,7 +47,8 @@ from ..testing import chaos
 from ..utils import telemetry
 from ..utils.logging import log_dist, logger
 from ..ops.pallas import sparse_select
-from ..ops.pallas.latent_attention import chunk_expanded_keys
+from ..ops.pallas.latent_attention import (chunk_expanded_keys,
+                                           chunk_key_tiles, chunk_tiles)
 from ..ops.pallas.latent_attention import form as latent_form
 from ..ops.pallas.latent_attention import path as latent_path
 from ..ops.pallas.paged_attention import chunk_plan, chunk_walk
@@ -123,6 +124,19 @@ _GROUP_COUNTERS = ("moe.group_rows_sum",)
 _MLA_COUNTERS = ("mla.rows_sum", "mla.ctx_tokens_sum", "mla.pages_walked_sum",
                  "mla.chunk_ctx_tokens_sum", "mla.chunk_keys_sum",
                  "mla.chunk_expanded_keys_sum")
+#: a latent model WITH an indexer (DeepSeek Sparse Attention): the keys its
+#: latent calls attend, ``min(a row's cached tokens, index_topk)`` a row
+#: (beside ``mla.ctx_tokens_sum``, the keys the kernel walks for them: their
+#: ratio is what reading the selected rows only would save, ROADMAP M7 (a);
+#: ``mla.chunk_selected_keys_sum`` is the prefill calls' part),
+#: and, of the expanded chunk form, the (256-row tile, key turn) pairs of the
+#: turns its programs walk and those whose matmuls its own rule lets them
+#: make (``latent_attention.chunk_key_tiles``, a head group; a pair none of
+#: whose rows selected a key is skipped besides, on the device alone)
+_MLA_SELECT_COUNTERS = ("mla.selected_keys_sum",
+                        "mla.chunk_selected_keys_sum",
+                        "mla.chunk_key_tiles_sum",
+                        "mla.chunk_key_tiles_live_sum")
 #: a model with an indexer (``cfg.index_heads``), counted on the host from
 #: the positions of a call's rows, summed over the layers: query rows (a
 #: decode lane's token, a chunk's tokens), the keys the indexer scored for
@@ -612,6 +626,8 @@ class ServingEngine:
             self.stats.update(dict.fromkeys(_GROUP_COUNTERS, 0))
         if cfg.kv_lora_rank:
             self.stats.update(dict.fromkeys(_MLA_COUNTERS, 0))
+            if cfg.index_heads:
+                self.stats.update(dict.fromkeys(_MLA_SELECT_COUNTERS, 0))
         # the paged-KV state: PRIVATE by default, SHARED when a
         # disaggregated pair (serving/disagg.py) passes one in — block
         # IDs then mean the same pool slots to both roles, which is what
@@ -811,6 +827,11 @@ class ServingEngine:
         c["mla.rows_sum"] += L * int(extent.size)
         c["mla.ctx_tokens_sum"] += L * int(extent.sum())
         c["mla.pages_walked_sum"] += L * int(pages)
+        if cfg.index_heads:
+            picked = L * int(np.minimum(extent, cfg.index_topk).sum())
+            c["mla.selected_keys_sum"] += picked
+            if chunk:
+                c["mla.chunk_selected_keys_sum"] += picked
         if chunk:
             c["mla.chunk_ctx_tokens_sum"] += L * int(extent.sum())
             c["mla.chunk_keys_sum"] += L * int(extent.max())
@@ -818,6 +839,12 @@ class ServingEngine:
                     and latent_form(chunk) == "expanded":
                 c["mla.chunk_expanded_keys_sum"] += L * chunk_expanded_keys(
                     chunk, int(extent[0]) - 1, int(extent.max()))
+                if cfg.index_heads:
+                    pairs, live = chunk_key_tiles(
+                        chunk, int(extent[0]) - 1, int(extent.max()),
+                        self.block_size, self.nbk)
+                    c["mla.chunk_key_tiles_sum"] += L * pairs
+                    c["mla.chunk_key_tiles_live_sum"] += L * live
 
     def _count_experts(self, out: np.ndarray, call: int) -> None:
         """A dropless MoE model's router load, from the fetched output of
@@ -839,11 +866,12 @@ class ServingEngine:
                 -1, words)
             if words > E:
                 # a grouped router: the (row, group) pairs whose row kept a
-                # group held here (a share is whole groups; one group a
-                # device, so a row counts once)
+                # group this program lies in (a share is whole groups, or a
+                # part of one; one group a device or more, so a row counts
+                # once)
                 counts, kept = counts[:, :E], counts[:, E:]
                 c["moe.group_rows_sum"] += int(
-                    kept[:, first // size:(first + held_n) // size].sum())
+                    kept[:, first // size:-(-(first + held_n) // size)].sum())
             routed = counts.sum(axis=1)
             live = routed > 0               # a call of padding only: nothing
             c["moe.assignments"] += int(routed.sum())
@@ -885,7 +913,10 @@ class ServingEngine:
         (``cfg.index_heads``) the array is ``[..., layers, k + index_topk]``:
         a token's routing, its experts and then the positions of the keys it
         attended in that layer, rising, -1 behind the row's own count (a row
-        that sees no more than ``index_topk`` keys lists them all). The
+        that sees no more than ``index_topk`` keys lists them all); its
+        ``layers`` are those with picks OR a selection (``cfg.
+        routed_layers``: a mixture's leading dense layer with an indexer
+        hands out a row whose picks read -1, before the sparse layers'). The
         device hands the selection out as bits, 32 keys a word, beside the
         picks of every call; the positions are made from them when the
         request finishes (a prompt chunk's on the device, by a sort). Costs
@@ -1347,13 +1378,13 @@ class ServingEngine:
 
     def _prefill_inputs(self, req: Request, toks: Sequence[int], table,
                         q0: int) -> np.ndarray:
-        """A prefill call's one buffer: ``toks`` (padded to a block
-        multiple, so the compile count is bounded by the table's width) at
-        positions ``q0 ..`` of ``req``'s ``table``. A new buffer each call:
-        a middle chunk's call is fetched by nobody, so its transfer may
-        still be in flight when the next is built."""
+        """A prefill call's one buffer: ``toks`` (padded to
+        :meth:`_prefill_rows`, so the compile count is bounded by the
+        table's width) at positions ``q0 ..`` of ``req``'s ``table``. A new
+        buffer each call: a middle chunk's call is fetched by nobody, so its
+        transfer may still be in flight when the next is built."""
         n = len(toks)
-        Tb = -(-n // self.block_size) * self.block_size
+        Tb = self._prefill_rows(n)
         self.rec.count("paged.chunk_live_pages_sum",
                        -(-(q0 + n) // self.block_size))
         self.rec.count("paged.chunk_table_pages_sum", self.nbk)
@@ -1374,6 +1405,19 @@ class ServingEngine:
         tk[0] = req.top_k or 0
         tp[0] = 1.0 if req.top_p is None else req.top_p
         return buf
+
+    def _prefill_rows(self, n: int) -> int:
+        """Rows a prefill call of ``n`` tokens brings: whole blocks; under a
+        selection over a latent cache the whole 256-row tiles its chunk
+        kernel takes the rows' scores by (``latent_attention.chunk_tiles``:
+        fewer rows would be padded there, their scores copied, a layer), so
+        a chunk of 1 536 has six programs where whole blocks of 32 have
+        forty-eight, each with an indexer's kernels to compile."""
+        cfg = self.cfg
+        if cfg.kv_lora_rank and cfg.index_heads:
+            tiles, per = chunk_tiles(n)
+            return tiles * per
+        return -(-n // self.block_size) * self.block_size
 
     def _note_prefill_path(self, Tb: int) -> None:
         """Gauge ``paged.prefill_path``: which way the attention of the
@@ -1735,7 +1779,7 @@ class ServingEngine:
                 if on_device else bits_to_positions(picks[..., k:], topk)
             return np.concatenate([np.asarray(picks[..., :k]), keys], axis=-1)
 
-        rows = np.full((len(req.prompt), cfg.sparse_layers, k + topk), -1,
+        rows = np.full((len(req.prompt), cfg.routed_layers, k + topk), -1,
                        np.int32)
         fed = []
         for part in req._routing:

@@ -85,7 +85,10 @@ def init_pool(cfg, num_blocks: int, block_size: int,
     A decode call's absorbed kernel reads the row once for scores (all its
     lanes) and values (its first ``kv_lora_rank``); a chunk's expands the
     latent to its heads' keys and values once for all of the chunk's rows:
-    ``ops/pallas/latent_attention.py``.
+    ``ops/pallas/latent_attention.py``. A latent model WITH an indexer
+    (DeepSeek Sparse Attention over MLA) has both leaves, ``ckv`` and ``ki``,
+    in the same blocks, written by the same plan: a prefix-cache hit, a fork
+    or a release carries both.
 
     Flat slot layout (slot = block * block_size + offset), row-major: the
     paged forward and the paged-attention kernel both view the same buffer
@@ -105,18 +108,18 @@ def init_pool(cfg, num_blocks: int, block_size: int,
     kernel takes the int8 blocks plus scales and dequantizes per block
     in VMEM, so int8 is what crosses HBM (no pool-slice f32 copy)."""
     dtype = dtype or cfg.dtype
+    slots = num_blocks * block_size
+    index = ({"ki": jnp.zeros((cfg.num_layers, 1, slots,
+                               -(-cfg.index_head_dim // 128) * 128),
+                              cfg.dtype)} if cfg.index_heads else {})
     if cfg.kv_lora_rank:
         if dtype == jnp.int8:
             raise ValueError(
                 "an int8 KV pool with latent attention (kv_lora_rank): the "
                 "latent leaf has no quantized format (ROADMAP M4)")
-        return {"ckv": jnp.zeros((cfg.num_layers, 1, num_blocks * block_size,
-                                  cfg.latent_lanes), dtype)}
-    shape = (cfg.num_layers, cfg.kv_heads, num_blocks * block_size,
-             cfg.head_dim)
-    index = ({"ki": jnp.zeros((shape[0], 1, shape[2],
-                               -(-cfg.index_head_dim // 128) * 128),
-                              cfg.dtype)} if cfg.index_heads else {})
+        return {"ckv": jnp.zeros((cfg.num_layers, 1, slots,
+                                  cfg.latent_lanes), dtype), **index}
+    shape = (cfg.num_layers, cfg.kv_heads, slots, cfg.head_dim)
     if dtype == jnp.int8:
         return {"k": jnp.zeros(shape, jnp.int8),
                 "v": jnp.zeros(shape, jnp.int8),

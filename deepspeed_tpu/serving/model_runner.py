@@ -117,7 +117,7 @@ class PagedCache:
     layer hands ``write`` K/V at the model's KV heads and ``attend`` the
     queries at all its heads: the kernel reads one stored head for the
     ``num_heads // kv_heads`` query heads that share it. Everything here
-    follows the pool's own shape. A model with an indexer has a third leaf,
+    follows the pool's own shape. A model with an indexer has one leaf more,
     ``ki`` ``[L, 1, slots, 128s]``: the indexer's one key a token on the
     first lanes of whole 128-lane tiles (``init_pool`` says why), in the
     same blocks through the same tables and written by the same plan, so whatever carries a block (a prefix-cache hit, a fork, a
@@ -239,14 +239,16 @@ class PagedCache:
         return {**kv, "ckv": _write_kv(kv["ckv"], li, row[:, None, None], 3,
                                        self.write_plan)}
 
-    def attend_latent(self, kv, li, q_nope, q_pe, wk, wv):
+    def attend_latent(self, kv, li, q_nope, q_pe, wk, wv, select=None):
         """Latent attention through the block table, ``[B, heads, T,
         v_head_dim]``; the kernel on a TPU or under ``interpret``, in the
         form its rows ask for (``latent.form``): a decode token ABSORBED
         (``attn_kv_b`` folded into the query and the output here, its heads
         the rows of one tile over the one stored row), a chunk EXPANDED
         inside the kernel, each key tile once for all of its rows. Elsewhere
-        the absorbed jnp twin. The call's own rows are in the pool already."""
+        the absorbed jnp twin. The call's own rows are in the pool already.
+        ``select`` (a layer with an indexer): the rows' selection, applied
+        inside whichever of the three runs."""
         cfg, lanes = self.cfg, kv["ckv"].shape[-1]
         T = q_nope.shape[2]
         way, _ = latent.path(q_nope.shape[:3] + (lanes,), kv["ckv"].shape,
@@ -256,7 +258,8 @@ class PagedCache:
                 return latent.latent_chunk_attention(
                     q_nope, q_pe, wk, wv, kv["ckv"], self.bt, self.ctx,
                     sm_scale=self.sm_scale, layer_idx=li,
-                    q_start=self.q_start, interpret=self.interpret)
+                    q_start=self.q_start, interpret=self.interpret,
+                    select=select)
         attend = partial(latent.latent_attention, interpret=self.interpret) \
             if way == "kernel" else partial(
                 latent.latent_attention_reference, q_start=self.q_start)
@@ -265,7 +268,7 @@ class PagedCache:
         with jax.named_scope("attend"):
             o = attend(q, kv["ckv"], self.bt, self.ctx,
                        value=cfg.kv_lora_rank, sm_scale=self.sm_scale,
-                       layer_idx=li)
+                       layer_idx=li, select=select)
         with jax.named_scope("absorb"):
             return absorb_output(o, wv)
 

@@ -44,7 +44,8 @@ CELLS = {"mistral-7b-l16": "serve-mistral-7b-l16-chat",
          "olmoe-1b-7b-l8": "serve-olmoe-1b-7b-l8-gen",
          "k-exaone-236b-ep8-l5": "serve-k-exaone-236b-ep8-l5-mixed",
          "keye-vl2-30b-ep8-l8": "serve-keye-vl2-30b-ep8-l8-longdoc",
-         "deepseek-v2-ep8-l5": "serve-deepseek-v2-ep8-l5-longdoc"}
+         "deepseek-v2-ep8-l5": "serve-deepseek-v2-ep8-l5-longdoc",
+         "deepseek-v32-exp-ep16-l5": "serve-deepseek-v32-exp-ep16-l5-longdoc"}
 
 
 def _strip_kernel_locations():

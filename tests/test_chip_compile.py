@@ -825,6 +825,115 @@ def test_deepseek_serving_step_keeps_the_latent_pool_in_place(
                                          mem.temp_size_in_bytes)
 
 
+@pytest.mark.parametrize("program",
+                         ["decode", "prefill256", "prefill1536"])
+def test_deepseek_v32_serving_step_selects_latent_rows_in_place(
+        chip, monkeypatch, program):
+    """The serving loop's two programs at the DeepSeek-V3.2-Exp cell's
+    widths, read from the benchmark's own files (deepseek-v32-exp-ep16-l5:
+    hidden 7168, 128 heads over ONE stored row of 512 + 64 on 640 lanes, a
+    64 x 128 indexer that picks 2048 of them, 16 of 256 experts of 2048 held
+    (half a routing group), a leading dense layer of 18432; 32 lanes over a
+    12288 x 32 pool, tables of 800 blocks): each of the two stacks' bodies
+    has its index-score call, its top-k and its latent-attention call UNDER
+    the selection (the scores and a row's threshold and tie position among
+    the kernel's operands), the sparse one the held experts' three grouped
+    matmuls; the pool is TWO leaves (``ckv`` + ``ki``), donated and updated
+    in place, and no instruction copies either, a layer of one, or slices a
+    layer out; no ``[rows, keys]`` mask is made beside the scores; every
+    layer hands a row out (the dense layer's selection too); and weights,
+    pool and temporaries fit the chip. A prefill call brings whole 256-row
+    tiles (``ServingEngine._prefill_rows``): the smallest program and the
+    chunk's own."""
+    from benchmark import harness
+    from deepspeed_tpu.models import TransformerConfig, build_model
+    from deepspeed_tpu.serving.engine import (StepLayout, step_programs,
+                                              token_words)
+    from deepspeed_tpu.serving.kv_cache import init_pool
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cell = harness.load_cell("serve-deepseek-v32-exp-ep16-l5-longdoc")
+    serving = cell.system["serving"]
+    BS, NB, B, NBK, chunk = (serving[k] for k in (
+        "block_size", "pool_blocks", "max_batch", "max_blocks_per_seq",
+        "prefill_chunk_tokens"))
+    from deepspeed_tpu.ops.pallas.latent_attention import chunk_tiles
+    assert program in ("decode", f"prefill{chunk_tiles(1)[1]}",
+                       f"prefill{chunk}")
+    model, cfg = build_model(TransformerConfig(
+        **harness.load_family("deepseek_v32").model_kwargs(cell.config),
+        dtype=jnp.bfloat16))
+    L, LS, E, K, G = cfg.num_layers, cfg.sparse_layers, cfg.moe_experts, \
+        cfg.moe_k, cfg.moe_groups
+    assert (L, LS, cfg.routed_layers, E, cfg.moe_held, G,
+            cfg.moe_topk_groups, cfg.hidden_size, cfg.mlp_dim, cfg.num_heads,
+            cfg.head_dim, cfg.latent_lanes, cfg.index_heads,
+            cfg.index_head_dim, cfg.index_rope_dim, cfg.index_topk) == (
+        5, 4, 5, 256, (0, 16), 8, 4, 7168, 2048, 128, 192, 640, 64, 128, 64,
+        2048)
+    on_chip = lambda tree: jax.tree.map(lambda x: chip(x.shape, x.dtype), tree)
+    params = on_chip(jax.eval_shape(lambda: jax.tree.map(
+        lambda x: x.astype(jnp.bfloat16), model.init(
+            jax.random.PRNGKey(0),
+            {"input_ids": jnp.zeros((1, 8), jnp.int32)})["params"])))
+    # the indexer's queries read the query latent, in both stacks
+    assert params["blocks"]["index_q"]["kernel"].shape == (LS, 1536, 8192)
+    assert params["dense_blocks"]["index_q"]["kernel"].shape == (
+        1, 1536, 8192)
+    pools = on_chip(jax.eval_shape(lambda: init_pool(cfg, NB, BS,
+                                                     jnp.bfloat16)))
+    assert {n: a.shape for n, a in pools.items()} == {
+        "ckv": (L, 1, NB * BS, 640), "ki": (L, 1, NB * BS, 128)}
+    rows = B if program == "decode" else int(program[len("prefill"):])
+    decode, prefill = step_programs(cfg, BS, NBK)
+    if program == "decode":
+        fn, words = decode, StepLayout(NBK).decode_words(B)
+        fed = [chip((token_words(cfg, n),), jnp.int32) for n in (B, 1)]
+    else:
+        fn, words, fed = prefill, StepLayout(NBK).prefill_words(rows), []
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, pools, chip((words,), jnp.int32), *fed).compile()
+    text = compiled.as_text()
+    (out, picks), _ = compiled.out_info
+    assert out.shape == ((B if program == "decode" else 1) + LS * (E + G),)
+    assert picks.shape == (L, rows, K + NBK * BS // 32)
+    kernels = _kernel_scopes(text)
+    assert len([k for k in kernels if "jit(gmm)" in k]) == 3, kernels
+    for scope in ("paged_attention", "sparse_index_scores", "sparse_topk"):
+        assert len([k for k in kernels if scope in k]) == 2, (scope, kernels)
+    assert len(kernels) == 9, kernels
+    # the latent call takes the scores where the top-k left them, float32,
+    # and the rows' threshold and tie position on 128 lanes
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "paged_attention_latent" in line]
+    out = "bf16[32,1,128,512]" if program == "decode" else \
+        f"bf16[1,128,{rows},128]"
+    sel = (f"f32[{B},1,{NBK * BS}]", f"s32[{B},1,128]") \
+        if program == "decode" else (f"f32[1,{rows},{NBK * BS}]",
+                                     f"s32[1,{rows},128]")
+    assert len(calls) == 2 and all(
+        f" = {out}" in c and all(s in c for s in sel) for c in calls), calls
+    made = [r for r in _results(text) if r[1] not in (
+        "parameter", "get-tuple-element", "while", "tuple", "bitcast")]
+    # (an activation of the chunk, [128, 1536, 448], lies between the two
+    # leaves' layers in size: the smaller leaf is held by its exact sizes)
+    ki, ckv = NB * BS * 128, NB * BS * 640
+    moved = [r for r in made if (r[3] >= ckv or r[3] in (ki, L * ki)) and (
+        r[1] in ("copy", "transpose", "scatter")
+        or r[1] == "dynamic-slice" and r[3] in (ki, ckv))]
+    assert not moved, moved
+    # no [rows, keys] mask beside the scores
+    masks = [r for r in made if r[2] == "pred" and "fused" not in r[0]
+             and r[3] >= rows * NBK * BS // 8]
+    assert not masks, masks
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(
+        p.size * p.dtype.itemsize for p in pools.values())
+    held_bytes = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    least = 12.0e9 if rows in (B, chunk) else 11.5e9
+    assert least < held_bytes < 14.0e9, (mem.argument_size_in_bytes,
+                                         mem.temp_size_in_bytes)
+
+
 @pytest.mark.parametrize("layout,vocab", [("dp4", 50257), ("dp2_tp2", 50304)])
 def test_zero3_step_reduces_the_head_gradient_once_behind_the_loss_loop(
         topo, chip, monkeypatch, layout, vocab):

@@ -502,11 +502,10 @@ def test_what_no_path_carries_is_refused_in_words(tiny):
     for knobs, words in (
             (dict(layer_windows=(4, 4, 4)), "no sliding window"),
             (dict(pos_embed="alibi"), "rotary positions"),
-            # (the indexer refuses a scaled rotary table first)
-            (dict(index_heads=2, index_head_dim=4, index_topk=4),
-             "an indexer selects|no indexer beside it"),
-            (dict(index_heads=2, index_head_dim=4, index_topk=4,
-                  rope_scaling_type=None), "no indexer beside it"),
+            # (an indexer beside a latent is DeepSeek-V3.2's, tests/
+            # test_deepseek_v32.py; its head is as wide as the rotated key)
+            (dict(index_heads=2, index_head_dim=2, index_topk=4),
+             "at least as wide as the rotated shared key"),
             (dict(num_kv_heads=2), "no KV heads"),
             (dict(moe_held=(2, 4)), "whole groups of 4"),
             (dict(moe_held=(0, 6)), "whole groups of 4"),
