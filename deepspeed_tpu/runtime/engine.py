@@ -244,7 +244,8 @@ class DeepSpeedEngine:
         # autotuner read; all the constructor does lies in train.init, so
         # set-up and its compiles are recorded like any step
         self.rec = telemetry.Recorder("train")
-        self.rec.counters.update({"train.h2d_bytes": 0, "compiles": 0})
+        self.rec.counters.update(dict.fromkeys(
+            ("train.h2d_bytes",) + telemetry.COMPILE_COUNTERS, 0))
         with self.rec.span("train.init"):
             self._init(model, config, model_parameters, loss_fn, apply_fn,
                        example_batch, rng, sharding_rules, mesh_manager,

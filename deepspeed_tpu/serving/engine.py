@@ -560,7 +560,8 @@ class ServingEngine:
         # so set-up and its compiles are recorded like any step
         self.rec = telemetry.Recorder("serve")
         self.stats: Dict[str, int] = self.rec.counters
-        self.stats.update(dict.fromkeys(_COUNTERS, 0))
+        self.stats.update(dict.fromkeys(
+            _COUNTERS + telemetry.COMPILE_COUNTERS, 0))
         with self.rec.span("serve.init"):
             self._init(cfg, params, serving, heartbeat, rng, interpret,
                        shared)

@@ -8,8 +8,9 @@ loop through a recorder whose spans are no-ops.
 
 A step with a prefill chunk is the longest the loop makes (a request admitted,
 a final chunk fetched and installed, one lane finished): 19 spans, 3 events,
-a step span that copies and diffs the counters. A decode-only step is 9
-spans. The recorder has no switch, so this is in every end-to-end number; the
+a step span that copies and diffs the counters (and adds its own time to
+``serve.step_us``), two of the spans waits (``serve.wait_us``). A decode-only
+step is 9 spans. The recorder has no switch, so this is in every end-to-end number; the
 number here is a CPU's, the chip's host is read from a pair of untraced runs
 (PERF.md, section 6, PR 38).
 """
@@ -24,22 +25,29 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from deepspeed_tpu.utils import telemetry  # noqa: E402
 
-#: the counters a serving engine of a dropless mixture holds (44)
+#: the counters a serving engine of a dropless mixture holds after a start
+#: its cache served (PR 56: 63)
 COUNTERS = (
     "completed failed timeout tokens_generated prefill_tokens "
     "prefix_hit_tokens preempted steps steps_with_queue queue_len_sum "
     "lane_sum admit_blocked.no_lane admit_blocked.no_blocks "
     "admit_blocked.prefilling compiles kv.held_blocks_sum "
     "kv.blocks_reserved_sum kv.tokens_written_sum prefix.prompt_tokens "
-    "paged.live_pages_sum paged.table_pages_sum paged.chunk_live_pages_sum "
-    "paged.chunk_table_pages_sum step_inputs.transfers_sum "
+    "paged.live_pages_sum paged.table_pages_sum paged.window_pages_sum "
+    "paged.chunk_live_pages_sum paged.chunk_table_pages_sum "
+    "paged.chunk_turns_sum paged.chunk_key_tiles_sum "
+    "paged.chunk_key_tiles_live_sum step_inputs.transfers_sum "
     "step_inputs.lane_rows_written_sum decode_ahead.launched "
     "decode_ahead.device_lane_tokens_sum decode_ahead.wasted_lane_tokens "
     "decode_ahead.retired_unread kv.alloc kv.release kv.exhausted "
     "prefix.lookups prefix.inserted_entries prefix.evicted_entries "
-    "prefix.evict_scanned_entries moe.assignments moe.layer_steps "
-    "moe.load_max_over_mean_sum moe.experts_idle_sum compile.trace_us "
-    "compile.lower_us compile.backend_us compile.cache_load_us").split()
+    "prefix.evict_scanned_entries moe.assignments moe.held_assignments "
+    "moe.layer_steps moe.load_max_over_mean_sum moe.experts_idle_sum "
+    "routing.fetches compile.trace_us compile.lower_us compile.backend_us "
+    "compile.cache_load_us compile.cache_requests compile.cache_hits "
+    "compile.cache_misses compile.backend_compiles compile.saved_us "
+    "compile.first_call_rest_us compile.first_calls serve.init_us "
+    "serve.step_us serve.wait_us").split()
 
 
 class Stub:

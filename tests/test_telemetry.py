@@ -201,10 +201,16 @@ def test_the_listener_books_a_cache_load_apart_and_keeps_short_traces_out():
             telemetry._on_compile(base + "backend_compile_duration", 0.007,
                                   fun_name="jit(other)")
             telemetry._on_compile("/jax/some/other_duration", 1.0)
+    step_us = rec.counters.pop("train.step_us")
     assert rec.counters == {
         "compile.trace_us": 200, "compile.lower_us": 3000,
         "compile.cache_load_us": 5000, "compile.backend_us": 7000,
-        "compiles": 2}
+        "compiles": 2, "compile.backend_compiles": 1,
+        # the phases claim 15.2 ms of a span that took microseconds: the
+        # rest of the first call is cut at nothing, never negative
+        "compile.first_calls": 1, "compile.first_call_rest_us": 0}
+    step = next(e for e in rec.ring if e[0] == "train.step")
+    assert step_us == step[3] // 1000 - step[2] // 1000
     entries = [(e[4]["phase"], e[4]["fun_name"], e[4]["step"], e[1])
                for e in rec.ring if e[0] == "compile"]
     assert entries == [
@@ -711,6 +717,353 @@ def test_autotuner_metric_file_gets_its_throughput(tmp_path, monkeypatch):
     out = json.load(open(metric))
     assert out["steps"] == 4 and out["throughput"] > 0
 
+
+
+# ------------------------------- the start, booked: init, steps, waits, rests
+
+
+def us(ns: int) -> int:
+    """A stamp cut to whole microseconds, as the recorder cuts it before
+    it takes a difference: sums of such differences telescope exactly."""
+    return ns // 1000
+
+
+KINDS = ("serve", "train")
+WAITS = {"serve": {"serve.decode.fetch", "serve.prefill.fetch"},
+         "train": {"train.sync"}}
+PHASES_AND_REST = ("compile.trace_us", "compile.lower_us",
+                   "compile.backend_us", "compile.cache_load_us",
+                   telemetry.FIRST_CALL_REST)
+
+
+def start_engine(kind: str, tiny_lm):
+    """An engine of ``kind`` at a tiny size run for a few steps (the
+    serving one with chunks of two shapes): its recorder and its own
+    ``telemetry()``."""
+    if kind == "serve":
+        cfg, params = tiny_lm
+        srv = ServingEngine(cfg, params, serving={
+            "block_size": 16, "pool_blocks": 24, "max_batch": 3,
+            "max_blocks_per_seq": 8, "prefill_chunk_tokens": 32})
+        rng = np.random.default_rng(0)
+        for n, k in [(40, 6), (70, 9), (20, 5)]:
+            srv.submit(list(rng.integers(1, 64, size=n)), max_new_tokens=k)
+        srv.run_until_idle()
+        return srv.rec, srv.telemetry
+    engine = lm_engine()
+    for i in range(3):
+        engine.train_batch(lm_batch(engine, i))
+    return engine.rec, engine.telemetry
+
+
+@pytest.fixture(scope="module", params=KINDS)
+def started(request, tiny_lm):
+    rec, snapshot = start_engine(request.param, tiny_lm)
+    return request.param, rec, snapshot()
+
+
+def steps_with_their_entries(rec, kind):
+    """[(step entry, the entries recorded since the step before)]: a span
+    is written when it ends, so a step's children come before it."""
+    out, inside = [], []
+    for e in rec.ring:
+        if e[0] == f"{kind}.step":
+            out.append((e, inside))
+            inside = []
+        else:
+            inside.append(e)
+    return out
+
+
+def test_from_the_constructor_to_any_step_init_steps_and_outside_are_the_wall(
+        started):
+    kind, rec, snap = started
+    ring = list(rec.ring)
+    init = next(e for e in ring if e[0] == f"{kind}.init")
+    steps = [e for e in ring if e[0] == f"{kind}.step"]
+    c = snap["counters"]
+    assert len(steps) >= 3 and rec.dropped == 0
+    assert snap["t0_ns"] == rec.t0_ns <= init[2]
+    assert c[f"{kind}.init_us"] == us(init[3]) - us(init[2])
+    assert c[f"{kind}.step_us"] == sum(us(e[3]) - us(e[2]) for e in steps)
+    # the gaps an outside observer sees: before the constructor's span,
+    # between it and the first step, between two steps
+    edges = [(rec.t0_ns, rec.t0_ns), (init[2], init[3])] + \
+        [(e[2], e[3]) for e in steps]
+    gaps = [us(b[0]) - us(a[1]) for a, b in zip(edges, edges[1:])]
+    assert all(g >= 0 for g in gaps)
+    step_us = 0
+    for k, e in enumerate(steps):
+        wall = us(e[2]) - us(rec.t0_ns)
+        # a step's own time is in the gains it carries, so a reader that
+        # sums d over the steps before this one has their time
+        assert step_us == sum(s[4]["d"][f"{kind}.step_us"]
+                              for s in steps[:k])
+        assert c[f"{kind}.init_us"] + step_us + sum(gaps[:k + 2]) == wall
+        step_us += us(e[3]) - us(e[2])
+
+
+def outermost(spans):
+    """Of ring entries, those that lie inside no other of them."""
+    return [e for e in spans
+            if not any(o is not e and o[2] <= e[2] and e[3] <= o[3]
+                       for o in spans)]
+
+
+def test_inside_a_step_phases_rest_wait_and_host_are_its_time(started):
+    kind, rec, snap = started
+    seen = dict.fromkeys(PHASES_AND_REST + (f"{kind}.wait_us",), 0)
+    for step, inside in steps_with_their_entries(rec, kind):
+        d = step[4]["d"]
+        assert d[f"{kind}.step_us"] == us(step[3]) - us(step[2])
+        # the spans that book their own time: the waits, and whichever a
+        # compile phase fell in (a compile entry names it as its parent)
+        compiled = {e[1] for e in inside if e[0] == "compile"}
+        inside = [e for e in inside if step[2] <= e[2]]
+        booking = [e for e in inside
+                   if e[0] in compiled or e[0] in WAITS[kind]]
+        booked = sum(d.get(k, 0) for k in seen)
+        assert booked == sum(us(e[3]) - us(e[2]) for e in outermost(booking))
+        host = d[f"{kind}.step_us"] - booked
+        assert host >= 0
+        # the rest of a first call is never negative, and a step in which
+        # nothing compiled books none and counts no first call
+        assert d.get(telemetry.FIRST_CALL_REST, 0) >= 0
+        if compiled:
+            assert d["compile.first_calls"] == len(
+                [e for e in inside if e[0] in compiled])
+        else:
+            assert not any(k.startswith("compile") for k in d)
+            assert d[f"{kind}.wait_us"] == sum(
+                us(e[3]) - us(e[2]) for e in inside if e[0] in WAITS[kind])
+        for k in seen:
+            seen[k] += d.get(k, 0)
+    # every step waited for the device, the first ones compiled
+    assert seen[f"{kind}.wait_us"] > 0 and seen["compile.lower_us"] > 0
+    assert seen[telemetry.FIRST_CALL_REST] > 0
+    # and what the steps did not gain, the constructor's span did (the
+    # model's init program; a serving engine's few helpers may be in
+    # JAX's memory from an earlier test)
+    c = snap["counters"]
+    in_init = {k: c.get(k, 0) - seen[k] for k in PHASES_AND_REST}
+    assert all(v >= 0 for v in in_init.values())
+    assert sum(in_init.values()) <= c[f"{kind}.init_us"]
+    assert sum(in_init.values()) > 0 or kind == "serve"
+
+
+def test_programs_has_a_row_a_function_and_shape_with_what_it_cost(started):
+    kind, rec, snap = started
+    rows = snap["programs"]
+    assert len({(r["fun_name"], r["shape"]) for r in rows}) == len(rows)
+    c = snap["counters"]
+    for part in ("trace_us", "lower_us", "backend_us", "cache_load_us",
+                 "saved_us"):
+        assert sum(r[part] for r in rows) == c.get("compile." + part, 0)
+    assert sum(r["rest_us"] for r in rows) == c[telemetry.FIRST_CALL_REST]
+    assert sum(r["compiles"] for r in rows) == c["compiles"]
+    assert sum(r["hit"] for r in rows) == c.get("compile.cache_hits", 0)
+    assert "compile.programs_dropped" not in c
+    by = {(r["fun_name"], r["shape"]): r for r in rows}
+    if kind == "serve":
+        # 40, 70 and 20 tokens in chunks of 32: calls of 32, 8, 32, 32, 6
+        # and 20 tokens in that order, in programs of 32 and of 16 rows: a
+        # row is keyed by the tokens of the call that compiled it
+        prefills = {k[1]: r for k, r in by.items() if k[0] == "jit(_prefill)"}
+        assert set(prefills) == {32, 8}
+        assert all(r["span"] == "serve.prefill.dispatch" and r["compiles"]
+                   == 1 and r["step"] is not None and r["rest_us"] > 0
+                   for r in prefills.values())
+        assert by[("jit(_decode)", None)]["span"] == "serve.decode.dispatch"
+        step = by[("jit(_decode)", None)]
+    else:
+        step = by[("jit(train_step)", None)]
+        assert step["span"] == "train.dispatch" and step["step"] == 0
+    # a program's row holds its whole trace, the helpers traced inside it
+    # too, short ones the ring leaves out with them
+    entries = [e for e in rec.ring if e[0] == "compile"
+               and e[4]["phase"] == "trace"
+               and e[4]["fun_name"] in step["fun_name"]]
+    # (each of a few hundred parts cut to whole microseconds)
+    assert step["trace_us"] >= 0.99 * max(e[3] - e[2] for e in entries) / 1e3
+    assert step["lower_us"] > 0 and step["compiles"] == 1
+    # the constructor's compiles fell in no step
+    outside = [r for r in rows if r["step"] is None]
+    assert all(r["span"].startswith(f"{kind}.init") for r in outside)
+    assert outside or kind == "serve"
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_what_the_ring_drops_the_counters_and_the_programs_keep(kind,
+                                                                tiny_lm,
+                                                                monkeypatch):
+    # the engines make their recorder themselves: give it a small ring
+    small = telemetry.Recorder.__init__.__defaults__
+    monkeypatch.setattr(telemetry.Recorder.__init__, "__defaults__",
+                        (8,) + small[1:])
+    rec, snapshot = start_engine(kind, tiny_lm)
+    snap = snapshot()
+    assert rec.ring.maxlen == 8 and snap["ring_dropped"] > 0
+    names = {e[0] for e in rec.ring}
+    assert f"{kind}.init" not in names             # pushed out long ago
+    assert snap["counters"][f"{kind}.init_us"] > 0
+    assert snap["counters"][f"{kind}.step_us"] > sum(
+        us(e[3]) - us(e[2]) for e in rec.ring if e[0] == f"{kind}.step")
+    held = {"serve": "jit(_decode)", "train": "jit(train_step)"}[kind]
+    assert not any(e[0] == "compile" and e[4]["fun_name"] == held
+                   for e in rec.ring)
+    row = next(r for r in snap["programs"] if r["fun_name"] == held)
+    assert row["lower_us"] > 0 and row["compiles"] == 1
+
+
+@pytest.fixture
+def persistent_cache(tmp_path):
+    """JAX's persistent compile cache in ``tmp_path``, its thresholds
+    lowered so that tiny programs are written; as it was, afterwards."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    names = {"jax_compilation_cache_dir": str(tmp_path / "cache"),
+             "jax_persistent_cache_min_entry_size_bytes": -1,
+             "jax_persistent_cache_min_compile_time_secs": 0.0}
+    before = {k: getattr(jax.config, k) for k in names}
+    try:
+        for k, v in names.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+        yield
+    finally:
+        for k, v in before.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_second_engine_finds_the_firsts_programs_in_the_cache(
+        kind, tiny_lm, persistent_cache):
+    rec, snapshot = start_engine(kind, tiny_lm)
+    cold = snapshot()["counters"]
+    assert cold.get("compile.cache_hits", 0) == 0
+    assert cold["compile.backend_compiles"] > 0 and cold[
+        "compile.backend_us"] > 0
+    # the thresholds are down: every program JAX looked up, it wrote
+    assert cold["compile.cache_misses"] == cold["compile.cache_requests"] \
+        <= cold["compile.backend_compiles"]
+    assert cold["compile.saved_us"] == 0 == cold["compile.cache_load_us"]
+    rec, snapshot = start_engine(kind, tiny_lm)
+    snap = snapshot()
+    warm = snap["counters"]
+    assert warm["compile.cache_hits"] == warm["compile.cache_requests"] > 0
+    # (an engine's compile counters start at zero: a served start reads 0)
+    assert warm["compile.backend_us"] == 0 == warm[
+        "compile.backend_compiles"] == warm["compile.cache_misses"]
+    assert warm["compile.cache_load_us"] > 0
+    assert warm["compiles"] == warm["compile.cache_hits"]
+    # JAX keeps an entry's compile time in whole seconds, cut: a program
+    # that compiled in under one says nothing was saved (the next test
+    # feeds the listener an entry that took three)
+    assert warm["compile.saved_us"] % 1_000_000 == 0
+    assert warm["compile.saved_us"] <= cold["compile.backend_us"]
+    assert all(r["hit"] == r["compiles"] for r in snap["programs"])
+    # tracing and lowering are paid again, cache or no cache
+    assert warm["compile.lower_us"] > 0.2 * cold["compile.lower_us"]
+
+
+def test_a_hit_saves_what_its_entry_says_the_compile_took():
+    rec = telemetry.Recorder("t", keep=False)
+    base, cache = "/jax/core/compile/", "/jax/compilation_cache/"
+    with rec.span("serve.prefill", tokens=96):
+        with rec.span("serve.prefill.dispatch"):
+            telemetry._on_compile(base + "jaxpr_to_mlir_module_duration",
+                                  0.002, fun_name="_prefill")
+            telemetry._on_cache_event(cache + "compile_requests_use_cache")
+            telemetry._on_cache_event(cache + "cache_hits")
+            # an entry that took 3 s (JAX keeps whole seconds), found in
+            # 0.25 s: JAX reports 2.75 s saved, then the retrieval
+            telemetry._on_compile(cache + "compile_time_saved_sec", 2.75)
+            telemetry._on_compile(cache + "cache_retrieval_time_sec", 0.25)
+            telemetry._on_compile(base + "backend_compile_duration", 0.26,
+                                  fun_name="jit(_prefill)")
+            # the next program's entry was never written: compiled
+            telemetry._on_cache_event(cache + "compile_requests_use_cache")
+            telemetry._on_compile(base + "backend_compile_duration", 0.001,
+                                  fun_name="jit(tiny)")
+            telemetry._on_cache_event(cache + "some_other_event")
+    c = rec.counters
+    assert (c["compile.cache_requests"], c["compile.cache_hits"],
+            c["compile.backend_compiles"], c["compiles"]) == (2, 1, 1, 2)
+    assert "compile.cache_misses" not in c
+    assert c["compile.saved_us"] == 3_000_000
+    assert c["compile.cache_load_us"] == 260_000
+    rows = {r["fun_name"]: r for r in rec.snapshot()["programs"]}
+    assert rows["jit(_prefill)"] == {
+        "fun_name": "jit(_prefill)", "shape": 96, "trace_us": 0,
+        "lower_us": 2000, "backend_us": 0, "cache_load_us": 260_000,
+        "saved_us": 3_000_000, "rest_us": 0, "compiles": 1, "hit": 1,
+        "span": "serve.prefill.dispatch", "step": None}
+    assert rows["jit(tiny)"]["backend_us"] == 1000 and \
+        rows["jit(tiny)"]["hit"] == 0 and rows["jit(tiny)"]["shape"] == 96
+    # outside every span of a recorder the events are nobody's
+    telemetry._on_cache_event(cache + "cache_hits")
+    assert c["compile.cache_hits"] == 1
+
+
+def test_rows_beyond_the_bound_are_counted_not_kept(monkeypatch):
+    monkeypatch.setattr(telemetry, "PROGRAM_ROWS", 2)
+    rec = telemetry.Recorder("t", keep=False)
+    with rec.span("s"):
+        for i in range(4):
+            telemetry._on_compile(
+                "/jax/core/compile/backend_compile_duration", 0.001,
+                fun_name=f"jit(f{i})")
+        # a row that is there still takes what its program costs again
+        telemetry._on_compile("/jax/core/compile/backend_compile_duration",
+                              0.001, fun_name="jit(f0)")
+    assert [r["fun_name"] for r in rec.snapshot()["programs"]] == [
+        "jit(f0)", "jit(f1)"]
+    assert rec.programs[("jit(f0)", None)]["compiles"] == 2
+    assert rec.counters["compile.programs_dropped"] == 2
+    assert rec.counters["compiles"] == 5
+
+
+def test_a_trace_nothing_compiled_still_gets_its_row_when_its_span_ends():
+    rec = telemetry.Recorder("t")
+    f, x = slow_to_trace(120), jnp.ones((5,))
+    with rec.span("s.lowering"):
+        f.trace(x).lower()
+    (row,) = rec.snapshot()["programs"]
+    assert "slow_120" in row["fun_name"] and row["span"] == "s.lowering"
+    assert row["trace_us"] > 0 and row["lower_us"] > 0
+    assert row["compiles"] == 0 == row["backend_us"]
+    assert row["rest_us"] == rec.counters[telemetry.FIRST_CALL_REST]
+    assert rec.counters["compile.first_calls"] == 1
+    assert "compiles" not in rec.counters
+
+
+def test_what_a_thread_has_booked_is_kept_no_longer_than_a_span_can_ask():
+    rec = telemetry.Recorder("t", keep=False)
+    for n in range(3):
+        with rec.step_span("train.step", step_num=n):
+            for _ in range(400):
+                with rec.span("train.sync"):
+                    pass
+    waits = sum(us(e[3]) - us(e[2]) for e in rec.ring if e[0] == "train.sync")
+    assert rec.counters["train.wait_us"] == waits
+    # 1 200 waits were booked; what lies before the open step went
+    assert len(telemetry._stack().booked) <= 256 + 400
+
+
+def test_a_wait_inside_a_span_that_compiled_is_booked_once():
+    rec = telemetry.Recorder("t", keep=False)
+    with rec.step_span("train.step", step_num=0):
+        with rec.span("train.dispatch"):
+            telemetry._on_compile(
+                "/jax/core/compile/jaxpr_to_mlir_module_duration", 0.0,
+                fun_name="f")
+            with rec.span("train.sync"):
+                pass
+    c = rec.counters
+    by = {e[0]: us(e[3]) - us(e[2]) for e in rec.ring}
+    assert c["train.wait_us"] == by["train.sync"]
+    assert c[telemetry.FIRST_CALL_REST] + c["train.wait_us"] == \
+        by["train.dispatch"] <= by["train.step"] == c["train.step_us"]
 
 # ------------------------------------------------------------ device scopes
 
