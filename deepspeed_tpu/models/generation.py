@@ -296,7 +296,7 @@ def attention_constants(cfg: TransformerConfig):
 def decoder_forward(cfg: TransformerConfig, params: PyTree,
                     input_ids: jnp.ndarray, cache, *,
                     interpret: bool = False, expert_counts: bool = False,
-                    expert_picks: bool = False):
+                    expert_picks: bool = False, head_rows=None):
     """The inference decoder over a KV cache: ``input_ids`` [B, T] ->
     ``(logits [B, T, V] f32, the cache as its caller keeps it, expert counts
     [L, E] or None)`` and, with ``expert_picks`` (a dropless MoE config
@@ -343,7 +343,12 @@ def decoder_forward(cfg: TransformerConfig, params: PyTree,
       model's ``attend_latent``) takes that answer as ``select``;
     * ``real_tokens(pos)``: ``[B, T]`` int32, the tokens a request owns
       (``expert_counts``, a dropless MoE config only: how many of them each
-      layer's router sent to each expert).
+      layer's router sent to each expert); a cache whose rows are of
+      several kinds (``model_runner.MixedCache``) answers with a tuple, one
+      mask a kind, and the counts are then ``[L, kinds, E]``.
+
+    ``head_rows`` (int32 ``[n]``): the head reads these of the ``T`` rows
+    and no other, and the logits are ``[B, n, V]``.
 
     Covers the policy architectures (learned/rotary/alibi positions, GQA,
     parallel residual, per-layer windows, sandwich norms, softcaps, q/k
@@ -548,7 +553,7 @@ def decoder_forward(cfg: TransformerConfig, params: PyTree,
                 picks = routing.experts if expert_picks else None
                 if not expert_counts:
                     return y, (None, picks)
-                with jax.named_scope("route"):
+                def count(real):
                     counts = jnp.zeros((cfg.moe_experts,), jnp.int32).at[
                         routing.experts.reshape(-1)].add(
                         jnp.repeat(real.reshape(-1), cfg.moe_k))
@@ -558,7 +563,12 @@ def decoder_forward(cfg: TransformerConfig, params: PyTree,
                         counts = jnp.concatenate([counts, jnp.sum(
                             routing.groups * real.reshape(-1, 1), axis=0,
                             dtype=jnp.int32)])
-                    return y, (counts, picks)
+                    return counts
+
+                with jax.named_scope("route"):
+                    return y, (jnp.stack([count(r) for r in real])
+                               if isinstance(real, tuple) else count(real),
+                               picks)
             if cfg.gated_mlp:            # SwiGLU (Llama family)
                 g = act(dense(hin, p["mlp_gate"]))
                 return (dense(g * dense(hin, p["mlp_fc"]), p["mlp_proj"]),
@@ -604,6 +614,8 @@ def decoder_forward(cfg: TransformerConfig, params: PyTree,
             picks = jnp.concatenate([dense_picks, picks], axis=0)
     out = cache.finish(carry, T)
     with jax.named_scope("head"):
+        if head_rows is not None:
+            x = jnp.take(x, head_rows, axis=1)
         x = norm(x, params["ln_f"])
         if cfg.tie_embeddings:
             logits = jnp.einsum("bth,vh->btv", x, wte.astype(x.dtype))

@@ -12,7 +12,9 @@ table. Admissions, evictions and completions only change the DATA in
 those arrays, never their shapes, so the loop compiles exactly one
 decode step for its whole lifetime (pinned by tests via
 ``_cache_size``); prefills compile once per block-rounded prompt-suffix
-bucket (``_prefill_rows``). This is the role CUDA-graph capture plays in
+bucket (``_prefill_rows``): the prefill program, or, in a chunked engine
+whose cache is not latent, the mixed program that carries the chunk AND the
+decode lanes (``_mixed_step``). This is the role CUDA-graph capture plays in
 the reference's ``InferenceEngine`` — here XLA's compile cache IS the graph
 cache, and the fixed shapes are what keep it hot.
 
@@ -54,7 +56,7 @@ from ..ops.pallas.latent_attention import path as latent_path
 from ..ops.pallas.paged_attention import chunk_plan, chunk_walk
 from ..ops.pallas.sparse_select import bits_to_positions, positions_of_bits
 from .kv_cache import (NULL_BLOCK, BlockPoolExhausted, SharedPagedState)
-from .model_runner import attention_impl, paged_forward
+from .model_runner import attention_impl, mixed_forward, paged_forward
 from .scheduler import (BATCH, FAILED, FINISHED, PREFILL, PRIORITY_TIERS,
                         QUEUED, RUNNING, STANDARD, TIER_RANK, TIMEOUT,
                         Request, Scheduler)
@@ -75,6 +77,10 @@ PyTree = Any
 #: launched that way, lane inputs the device found for itself (the previous
 #: call's output or a prefill's), lanes computed once more after their end
 #: (an EOS is seen one call late), calls in flight retired outside a step.
+#: The two ``mixed.*``: the steps whose prefill chunk and decode lanes were ONE
+#: device call (``ServingEngine._mixed_step``: every step that advances a
+#: chunk, where the cache is not latent), and the live lane rows that rode
+#: them; both stay 0 where the chunk and the lanes keep a program each.
 _COUNTERS = (
     "completed", "failed", "timeout", "tokens_generated", "prefill_tokens",
     "prefix_hit_tokens", "preempted",
@@ -89,7 +95,8 @@ _COUNTERS = (
     "paged.chunk_key_tiles_live_sum",
     "step_inputs.transfers_sum", "step_inputs.lane_rows_written_sum",
     "decode_ahead.launched", "decode_ahead.device_lane_tokens_sum",
-    "decode_ahead.wasted_lane_tokens", "decode_ahead.retired_unread")
+    "decode_ahead.wasted_lane_tokens", "decode_ahead.retired_unread",
+    "mixed.calls", "mixed.lane_rows_sum")
 #: a dropless MoE model's router load, from the [sparse layers, E] counts
 #: that ride the tokens' own fetch (``_count_experts``); a dense model has
 #: none of these. ``moe.held_assignments``: the assignments whose expert this
@@ -235,6 +242,8 @@ class StepLayout:
     call's output. The host need not have seen either.
     prefill, ``T`` tokens: ``ids[1, T] | table[1, nbk] | q0[1] | ctx[1] |
     last_idx[1] | top_k[1] | temp[1] | top_p[1] | key``
+    mixed, ``T`` tokens beside ``B`` lanes: a prefill buffer, then a decode
+    buffer, each whole and with its own key (:meth:`mixed`)
 
     ``key`` (the last words of either buffer) is the call's sampling key as
     raw words, derived on the host (``ServingEngine._call_key``): host data
@@ -266,6 +275,13 @@ class StepLayout:
     def prefill_words(self, tokens: int) -> int:
         return tokens + self.nbk + 6 + self.kw
 
+    def mixed(self, buf, lanes: int):
+        """(the prefill buffer, the decode buffer over ``lanes`` lanes) of a
+        mixed call's ``buf``."""
+        words = self.decode_words(lanes)
+        return _split(buf, (buf.shape[0] - words, np.int32),
+                      (words, np.int32))
+
     def decode(self, buf):
         """(toks, ctx, top_k, tables, temps, top_p, key) of ``buf``."""
         B, rest = divmod(buf.shape[0] - self.kw, 5 + self.nbk)
@@ -290,9 +306,10 @@ class StepLayout:
 
 def step_programs(cfg, block_size: int, table_width: int, *,
                   interpret: bool = False, use_filters: bool = False,
-                  key_words: int = 2):
-    """The loop's two device programs as plain functions, ``(decode,
-    prefill)``: ``prefill(params, pools, step_in)`` and ``decode(params,
+                  key_words: int = 2, mixed: bool = False):
+    """The loop's device programs as plain functions, ``(decode, prefill)``
+    and with ``mixed`` ``(decode, prefill, mixed)``: ``prefill(params,
+    pools, step_in)`` and ``decode(params,
     pools, step_in, prev, first)``, each ``-> (tokens, pools)``, with
     ``step_in`` the call's one int32 buffer (:class:`StepLayout`, built
     from ``table_width`` and ``key_words``) and ``prev`` / ``first`` the
@@ -310,7 +327,18 @@ def step_programs(cfg, block_size: int, table_width: int, *,
     as a second output, the call's picks ``[L, lanes x T, k]`` int32
     (``decoder_forward``'s ``expert_picks``), which stay on the device
     unless a request of the call asked for them (``submit``'s
-    ``keep_routing``). Every other config gets the plain token vector."""
+    ``keep_routing``). Every other config gets the plain token vector.
+
+    ``mixed(params, pools, step_in, prev, first) -> ((decode's tokens,
+    prefill's tokens), pools)`` is a prefill call and the decode call behind
+    it as ONE program (``model_runner.mixed_forward``: the chunk's ``T`` rows
+    and the ``B`` lanes' one row each through every matmul together, so the
+    weights are read once a step; not for a latent model). Its ``step_in`` is
+    a prefill buffer and a decode buffer end to end, its two outputs are
+    what the two programs would return, each with its own expert counts and
+    picks, each sampled by its kind's own sampler under its own key; ``B``
+    follows from ``prev``. No lane of the call may hold a block its chunk
+    writes: the prompt's own lane joins the next call."""
     bs, layout = int(block_size), StepLayout(table_width, key_words)
     counting = bool(cfg.moe_is_dropless)
 
@@ -328,6 +356,29 @@ def step_programs(cfg, block_size: int, table_width: int, *,
             if use_filters:
                 scaled = lane_topk_topp(scaled, tks, tps)
             sampled = jax.random.categorical(r, scaled, axis=-1)
+            return jnp.where(temps <= 0.0, greedy, sampled)
+
+    @jax.jit        # no chunk size changes its shapes: traced once
+    def _pick_kinds(logits, keys, temps, tks, tps):
+        """:func:`_pick` for a mixed call's ``1 + B`` rows, the chunk's row
+        first: each kind's rows are drawn under its own key (the lanes' as
+        the decode program draws them), but as ONE batched draw over the two
+        keys, the chunk's row in a block of the lanes' shape: a second
+        ``categorical`` is a second unrolled threefry in the text of every
+        prefill shape's program (0.5 s a program at every start on a TPU
+        host, PERF.md, PR 31)."""
+        with jax.named_scope("sample"):
+            greedy = jnp.argmax(logits, axis=-1)
+            scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
+            if use_filters:
+                scaled = lane_topk_topp(scaled, tks, tps)
+            lanes = scaled[1:]
+            blocks = jnp.stack([jnp.zeros_like(lanes).at[0].set(scaled[0]),
+                                lanes])
+            drawn = jax.vmap(lambda key, rows: jax.random.categorical(
+                jax.random.wrap_key_data(key), rows, axis=-1))(
+                    jnp.stack(keys), blocks)                    # [2, B]
+            sampled = jnp.concatenate([drawn[0, :1], drawn[1]])
             return jnp.where(temps <= 0.0, greedy, sampled)
 
     def _out(tokens, routed):
@@ -360,7 +411,32 @@ def step_programs(cfg, block_size: int, table_width: int, *,
                                             keepdims=False)   # [1, V]
         return _out(_pick(last, key, temps, tks, tps), routed), pools
 
-    return _decode, _prefill
+    def _mixed(params, pools, step_in, prev, first):
+        B = prev.shape[0] - token_words(cfg, 0)
+        chunk_in, lanes_in = layout.mixed(step_in, B)
+        ids, cbt, q0, cctx, last_idx, ctk, ctemp, ctp, ckey = \
+            layout.prefill(chunk_in)
+        toks, ctx, tks, bt, temps, tps, key = layout.decode(lanes_in)
+        toks = layout.lane_tokens(toks, prev, first)
+        T = ids.shape[1]
+        # the head reads the chunk's last real row and the lanes' rows
+        head_rows = jnp.concatenate([last_idx, T + jnp.arange(B)])
+        logits, pools, *routed = mixed_forward(
+            cfg, params, ids, toks, pools, (cbt, q0, cctx),
+            (bt, ctx, ctx + 1), bs, head_rows, interpret=interpret,
+            expert_counts=counting, expert_picks=counting)
+        chunk_routed = lanes_routed = None
+        if counting:
+            counts, picks = routed          # [L, 2, E], [L, T + B, k]
+            chunk_routed = counts[:, 0], picks[:, :T]
+            lanes_routed = counts[:, 1], picks[:, T:]
+        picked = _pick_kinds(logits[0], (ckey, key),
+                             *(jnp.concatenate(two) for two in (
+                                 (ctemp, temps), (ctk, tks), (ctp, tps))))
+        return (_out(picked[1:], lanes_routed),
+                _out(picked[:1], chunk_routed)), pools
+
+    return (_decode, _prefill, _mixed) if mixed else (_decode, _prefill)
 
 
 def token_words(cfg, lanes: int) -> int:
@@ -486,7 +562,8 @@ class _ChunkOut:
 
 @dataclass
 class _InFlight:
-    """A decode call launched and not yet fetched: its output on the device,
+    """A decode call (or a mixed one: the lanes' half of it) launched and
+    not yet fetched: its output on the device,
     the lanes whose token of it is still wanted (a lane freed meanwhile is
     struck out), and the call's number; of a dropless mixture also the
     call's picks ``[L, lanes, k]``, on the device."""
@@ -494,6 +571,9 @@ class _InFlight:
     go: np.ndarray
     call: int
     picks: Any = None
+    #: a mixed call (:meth:`ServingEngine._mixed_step`): its chunk, whose
+    #: output is fetched with the lanes' tokens
+    chunk: Optional[_ChunkOut] = None
 
 
 class _HeldBlocks:
@@ -696,13 +776,22 @@ class ServingEngine:
         self.steps = 0                     # decode steps executed
 
         # ---- compiled programs (fixed shapes; ONE decode specialization) ----
-        _decode, _prefill = step_programs(
+        # where the cache is not latent, a step that advances a prefill
+        # chunk is ONE program, the chunk's rows and the lanes' together
+        # (_mixed_step); a latent model's chunk and lanes keep a program
+        # each, and the third is never built for it
+        _decode, _prefill, *_mixed = step_programs(
             cfg, bs, self.nbk, interpret=self.interpret,
-            use_filters=self._use_filters, key_words=self._key.size)
+            use_filters=self._use_filters, key_words=self._key.size,
+            mixed=self._chunk > 0 and not cfg.kv_lora_rank)
         # pools are donated: the loop's only live copy moves through the
         # step, so the update is in-place on TPU (no 2x pool HBM)
         self._decode_fn = jax.jit(_decode, donate_argnums=(1,))
         self._prefill_fn = jax.jit(_prefill, donate_argnums=(1,))
+        self._mixed_fn = self._mixed_program = None
+        if _mixed:
+            self._mixed_fn = jax.jit(_mixed[0], donate_argnums=(1,))
+            self._mixed_program = "jit_" + _mixed[0].__name__
         # a third, for a request that asked for its routing of a model with
         # an indexer: a prompt chunk's selection from bits to positions
         self._positions_fn = positions_of_bits
@@ -745,16 +834,17 @@ class ServingEngine:
         under the shared state's device lock)."""
         return self._shared.run(fn, self.params, *args)
 
-    def _call_key(self, call: int) -> np.ndarray:
+    def _call_key(self, call: int, *kind: int) -> np.ndarray:
         """The sampling key of device call number ``call``, as raw words:
         numpy's ``SeedSequence`` hashes (base key, call) into them, on the
-        host. Same seed and same calls, same keys, run to run. (Not
+        host (``kind``: a second sampler of the call, a mixed call's chunk
+        beside its lanes). Same seed and same calls, same keys, run to run. (Not
         ``jax.random.fold_in`` inside the programs: lowering its unrolled
         threefry costs 0.5 s a program at every start on a TPU host,
         PERF.md, PR 31; and not an eager split: a device program of its own
         between two steps.)"""
         return np.random.SeedSequence(
-            self._key.tolist(), spawn_key=(call,)).generate_state(
+            self._key.tolist(), spawn_key=(call, *kind)).generate_state(
                 self._key.size, np.uint32)
 
     def _call_device(self, fn, step_in: np.ndarray, *on_device):
@@ -994,10 +1084,13 @@ class ServingEngine:
             # the call in flight is dropped unread: the requests resume from
             # prompt + emitted. Its K/V rows fall into blocks listed below;
             # whoever gets them next launches later (_finish)
+            flight = self._flight
             self._retire(book=False)
             blocks: List[List[int]] = []
             reqs: List[Request] = []
             chunk, self._chunk_out = self._chunk_out, None
+            if chunk is None and flight is not None:
+                chunk = flight.chunk        # a mixed call's, dropped with it
             # (a step died between a chunk's launch and its fetch) a staged
             # lane is among the slots, a middle chunk's prompt is the one in
             # prefill
@@ -1067,15 +1160,21 @@ class ServingEngine:
         then one fixed-shape decode step over the active set — so with
         ``serving.prefill_chunk_tokens > 0`` running lanes emit a token
         every iteration even while a long prompt prefills (the fairness
-        bound tests pin). The decode step LAUNCHES the next call before it
+        bound tests pin). Where the cache is not latent the chunk and the
+        decode step are ONE device call (:meth:`_mixed_step`: the weights
+        are read once); a latent model's are two. The decode step LAUNCHES
+        the next call before it
         fetches the one the last iteration launched
         (:meth:`_decode_step`): an iteration returns with the tokens of the
         call it retired appended, and with one call in flight wherever a
         lane goes on. Returns requests completed this iteration."""
         with self._lock, self._step_span():
             done = self._admit()
-            self._advance_prefill()
-            done += self._decode_step()
+            if self._mixed_fn is not None and self._prefilling is not None:
+                done += self._mixed_step()
+            else:
+                self._advance_prefill()
+                done += self._decode_step()
             self.steps += 1
             self.stats["timeout"] = self.scheduler.timed_out
             self._stamp_heartbeat()
@@ -1495,35 +1594,111 @@ class ServingEngine:
             if req.keep_routing:
                 req._routing.append((pf.done, n, self._picks_out))
         except BaseException as e:
-            # a failed chunk must not leak the lifetime allocation —
-            # release EVERYTHING (partial K/V is recomputed on retry; the
-            # chunk progress survives on req.prefill_progress for the
-            # fleet's death ledger). Chaos/interrupt-class escapes leave
-            # the request QUEUED for a requeue path; a plain Exception is
-            # a deterministic per-request failure
-            self._set_prefilling(None)
-            self.pool.release(pf.blocks)
-            if isinstance(e, Exception) \
-                    and not isinstance(e, chaos.ChaosError):
-                self.stats["failed"] += 1
-                req._finish(FAILED, error=repr(e))
-            else:
-                req.state = QUEUED
+            self._chunk_failed(pf, e)
             raise
+        chunk = self._chunk_launched(pf, n, tok)
+        if chunk.seq is None:
+            self._mid_chunk(tok)          # sampled token of a mid-chunk
+        else:                             # call is discarded — only the
+            self._chunk_out = chunk       # final chunk's is real
+
+    def _chunk_failed(self, pf: _Prefilling, e: BaseException) -> None:
+        """A failed chunk must not leak the lifetime allocation: release
+        EVERYTHING (partial K/V is recomputed on retry; the chunk progress
+        survives on ``req.prefill_progress`` for the fleet's death ledger).
+        Chaos/interrupt-class escapes leave the request QUEUED for a requeue
+        path; a plain Exception is a deterministic per-request failure."""
+        req = pf.req
+        self._set_prefilling(None)
+        self.pool.release(pf.blocks)
+        if isinstance(e, Exception) and not isinstance(e, chaos.ChaosError):
+            self.stats["failed"] += 1
+            req._finish(FAILED, error=repr(e))
+        else:
+            req.state = QUEUED
+
+    def _chunk_launched(self, pf: _Prefilling, n: int, tok) -> _ChunkOut:
+        """The call that carries ``n`` more of the prompt's tokens is
+        launched (``tok``: its output, on the device): book the progress;
+        behind a prompt's LAST chunk the sequence is staged (:meth:`_stage`)
+        and later decode calls find its first token in ``tok``."""
+        req = pf.req
         pf.done += n
         req.prefill_progress = pf.done
         self.stats["prefill_tokens"] += n
         if pf.done < pf.total:
-            self._mid_chunk(tok)          # sampled token of a mid-chunk
-            return                        # call is discarded — only the
-            #                               final chunk's is real
+            return _ChunkOut(tok, self._calls)
         self._set_prefilling(None)
         seq = _Prefilled(req, pf.blocks, pf.table, pf.total,
                          StepLayout.FROM_PREFILL)
         self._pre_out = tok
-        with rec.span("serve.prefill.install"):
-            self._chunk_out = _ChunkOut(tok, self._calls, seq,
-                                        self._stage(seq))
+        with self.rec.span("serve.prefill.install"):
+            return _ChunkOut(tok, self._calls, seq, self._stage(seq))
+
+    def _mixed_step(self) -> int:
+        """A step that advances the prompt in prefill, where the cache is
+        not latent: its chunk and the decode call behind it are ONE device
+        call (``step_programs``' ``mixed``), so the step reads the weights
+        once. The lanes are those a decode call would take now; the prompt's
+        own lane, staged behind its last chunk, joins the NEXT call, so no
+        lane reads what the call's chunk writes. The call is in flight like a
+        decode call (launched before the one in flight is fetched), with its
+        chunk riding it: the chunk's first token is booked with the lanes'
+        tokens, one call later, and a step's tokens come one mixed call
+        after those of the step before. It runs whether or not a lane
+        decodes (idle lanes are what they are in a decode call), in place of
+        the prefill program of its shape. Returns requests finished."""
+        pf, rec = self._prefilling, self.rec
+        req, prev = pf.req, self._flight
+        n = min(self._chunk, pf.total - pf.done)
+        with rec.span("serve.prefill", rid=req.rid, tokens=n,
+                      final=int(pf.done + n >= pf.total)):
+            with rec.span("serve.prefill.build"):
+                chunk_in = self._prefill_inputs(
+                    req, req.prompt[pf.done:pf.done + n], pf.table, pf.done)
+            try:
+                # before the lanes are moved past a call that is not made
+                chaos.failpoint("serve.chunk")
+                go = self._lanes.next_call()
+                with rec.span("serve.decode.build"):
+                    step_in = np.concatenate(
+                        [chunk_in, self._decode_inputs(go)])
+                with rec.span("serve.prefill.dispatch",
+                              program=self._mixed_program):
+                    out, tok, picks = self._call_mixed(step_in, chunk_in.size)
+                if req.keep_routing:
+                    req._routing.append((pf.done, n, picks[1]))
+            except BaseException as e:
+                self._chunk_failed(pf, e)
+                raise
+            self.stats["mixed.calls"] += 1
+            self.stats["mixed.lane_rows_sum"] += int(np.count_nonzero(go))
+            chunk = self._chunk_launched(pf, n, tok)
+        if prev is not None:
+            self.stats["decode_ahead.launched"] += 1
+        self._dec_out = out
+        self._flight = _InFlight(out, go, self._calls, picks[0], chunk)
+        if prev is None:
+            return 0
+        with rec.span("serve.decode", lanes=self.active):
+            return self._book(prev)
+
+    def _call_mixed(self, step_in: np.ndarray, chunk_words: int):
+        """One mixed call (:meth:`_call_device`'s work for a buffer of two
+        halves, each with its own key): ``(the lanes' output, the chunk's,
+        (the lanes' picks, the chunk's))``."""
+        self._calls += 1
+        kw = self._key.size
+        step_in[chunk_words - kw:chunk_words] = self._call_key(
+            self._calls, 1).view(np.int32)
+        step_in[-kw:] = self._call_key(self._calls).view(np.int32)
+        self.stats["step_inputs.transfers_sum"] += 1
+        out, tok = self._run_device(self._mixed_fn, step_in, self._dec_out,
+                                    self._pre_out)
+        if self.cfg.moe_is_dropless:
+            (out, lane_picks), (tok, chunk_picks) = out, tok
+            return out, tok, (lane_picks, chunk_picks)
+        return out, tok, (None, None)
 
     def _mid_chunk(self, tok) -> None:
         """A prompt's middle chunk is launched: the step waits for it behind
@@ -1556,8 +1731,11 @@ class ServingEngine:
         is booked; of a middle chunk (:meth:`_mid_chunk`) its end. Returns
         requests finished."""
         chunk, self._chunk_out = self._chunk_out, None
-        if chunk is None:
-            return 0
+        return 0 if chunk is None else self._book_chunk(chunk)
+
+    def _book_chunk(self, chunk: _ChunkOut) -> int:
+        """Fetch a launched chunk's output (its end) and, of a prompt's last
+        chunk, book the first token. Returns requests finished."""
         with self.rec.span("serve.prefill.fetch"):
             first = int(self._fetch(chunk.out, chunk.call)[0])
         if chunk.seq is None:
@@ -1689,29 +1867,9 @@ class ServingEngine:
     def _launch(self, go: np.ndarray, ahead: bool) -> None:
         """Build and dispatch one decode call over the lanes ``go``; it is
         in flight from here."""
-        B, rec, lanes = self.max_batch, self.rec, self._lanes
+        rec = self.rec
         with rec.span("serve.decode.build"):
-            # the pages the paged kernel walks this call (every lane up to
-            # the token it writes; an idle lane its one null page) against
-            # the tables' full width
-            rec.count("paged.live_pages_sum",
-                      int((lanes.ctx * go // self.block_size + 1).sum()))
-            rec.count("paged.table_pages_sum", B * self.nbk)
-            self._count_selection(lanes.ctx * go + 1, go, int(
-                (lanes.ctx[go] // self.block_size + 1).sum()), decode=True)
-            self._count_latent(lanes.ctx[go] + 1, int(
-                (lanes.ctx[go] // self.block_size + 1).sum()))
-            if self._windows.size:
-                # of those, what the window layers' calls walk, summed over
-                # those layers: from the page of a lane's first key in reach
-                n = lanes.ctx * go + 1
-                reach = np.maximum(n - self._windows[:, None], 0)
-                rec.count("paged.window_pages_sum", int(
-                    (-(-n // self.block_size)
-                     - reach // self.block_size).sum()))
-            rec.count("decode_ahead.device_lane_tokens_sum",
-                      int(np.count_nonzero(lanes.toks[go] < 0)))
-            step_in = lanes.launch(go)
+            step_in = self._decode_inputs(go)
         with rec.span("serve.decode.dispatch",
                       program=self._decode_program):
             out = self._call_device(self._decode_fn, step_in, self._dec_out,
@@ -1722,10 +1880,39 @@ class ServingEngine:
         self._flight = _InFlight(out, go, self._calls,
                                  getattr(self, "_picks_out", None))
 
+    def _decode_inputs(self, go: np.ndarray) -> np.ndarray:
+        """The buffer of a decode call over the lanes ``go`` (or of a mixed
+        call's decode half), counted, and the lanes moved past that call
+        (:meth:`_Lanes.launch`)."""
+        B, rec, lanes = self.max_batch, self.rec, self._lanes
+        # the pages the paged kernel walks this call (every lane up to
+        # the token it writes; an idle lane its one null page) against
+        # the tables' full width
+        rec.count("paged.live_pages_sum",
+                  int((lanes.ctx * go // self.block_size + 1).sum()))
+        rec.count("paged.table_pages_sum", B * self.nbk)
+        self._count_selection(lanes.ctx * go + 1, go, int(
+            (lanes.ctx[go] // self.block_size + 1).sum()), decode=True)
+        self._count_latent(lanes.ctx[go] + 1, int(
+            (lanes.ctx[go] // self.block_size + 1).sum()))
+        if self._windows.size:
+            # of those, what the window layers' calls walk, summed over
+            # those layers: from the page of a lane's first key in reach
+            n = lanes.ctx * go + 1
+            reach = np.maximum(n - self._windows[:, None], 0)
+            rec.count("paged.window_pages_sum", int(
+                (-(-n // self.block_size)
+                 - reach // self.block_size).sum()))
+        rec.count("decode_ahead.device_lane_tokens_sum",
+                  int(np.count_nonzero(lanes.toks[go] < 0)))
+        return lanes.launch(go)
+
     def _book(self, call: _InFlight) -> int:
         """Fetch a decode call's tokens and book them: a token a lane, and
-        the end of every lane that ends by it."""
-        rec, done = self.rec, 0
+        the end of every lane that ends by it; of a mixed call its chunk
+        first (:meth:`_book_chunk`)."""
+        rec = self.rec
+        done = 0 if call.chunk is None else self._book_chunk(call.chunk)
         with rec.span("serve.decode.fetch"):
             toks = self._fetch(call.out, call.call).tolist()
         with rec.span("serve.decode.bookkeep"):

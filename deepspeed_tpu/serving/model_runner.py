@@ -124,6 +124,9 @@ class PagedCache:
     preemption's release) carries its indexer keys. Built and used inside
     one trace."""
 
+    #: how a call's new rows go into a pool (:class:`_CalledWrites` has its own)
+    _write_kv = staticmethod(_write_kv)
+
     def __init__(self, cfg: TransformerConfig, pools: Dict[str, jnp.ndarray],
                  block_tables, q_start, context_lens, block_size: int,
                  interpret: bool):
@@ -208,13 +211,13 @@ class PagedCache:
             # a scale block keeps its slots on the last axis
             B, kvh, T, _ = k.shape
             to_lanes = lambda s: s.reshape(B, kvh, 1, 1, T)
-            new["k_scale"] = _write_kv(kv["k_scale"], li, to_lanes(k_scale),
+            new["k_scale"] = self._write_kv(kv["k_scale"], li, to_lanes(k_scale),
                                        4, plan)
-            new["v_scale"] = _write_kv(kv["v_scale"], li, to_lanes(v_scale),
+            new["v_scale"] = self._write_kv(kv["v_scale"], li, to_lanes(v_scale),
                                        4, plan)
-        new["k"] = _write_kv(kv["k"], li,
+        new["k"] = self._write_kv(kv["k"], li,
                              k.astype(kv["k"].dtype)[:, :, None], 3, plan)
-        new["v"] = _write_kv(kv["v"], li,
+        new["v"] = self._write_kv(kv["v"], li,
                              v.astype(kv["v"].dtype)[:, :, None], 3, plan)
         return new
 
@@ -225,7 +228,7 @@ class PagedCache:
         lanes = kv["ki"].shape[-1]
         ki = jnp.pad(ki.astype(kv["ki"].dtype),
                      [(0, 0)] * 3 + [(0, lanes - ki.shape[-1])])
-        return {**kv, "ki": _write_kv(kv["ki"], li, ki[:, :, None], 3,
+        return {**kv, "ki": self._write_kv(kv["ki"], li, ki[:, :, None], 3,
                                       self.write_plan)}
 
     def write_latent(self, kv, li, row):
@@ -236,7 +239,7 @@ class PagedCache:
         lanes = kv["ckv"].shape[-1]
         row = jnp.pad(row.astype(kv["ckv"].dtype),
                       [(0, 0)] * 2 + [(0, lanes - row.shape[-1])])
-        return {**kv, "ckv": _write_kv(kv["ckv"], li, row[:, None, None], 3,
+        return {**kv, "ckv": self._write_kv(kv["ckv"], li, row[:, None, None], 3,
                                        self.write_plan)}
 
     def attend_latent(self, kv, li, q_nope, q_pe, wk, wv, select=None):
@@ -319,6 +322,259 @@ class PagedCache:
                                layer_idx=li, q_start=self.q_start,
                                select=select, impl=self.impl,
                                interpret=self.interpret, **scale_kw)
+
+
+@partial(jax.jit, static_argnames="ax")
+def _slot_update(pool, li, new, plan: _WritePlan, b, *, ax: int):
+    """Lane ``b``'s one new row into its slot: :func:`_write_kv`'s decode
+    update, the lane an operand."""
+    at = [li, 0, plan.phys[b, 0], 0, 0]
+    at[ax] = plan.off[b]
+    return jax.lax.dynamic_update_slice(
+        pool, jax.lax.dynamic_slice_in_dim(new, b, 1, axis=0), at)
+
+
+@partial(jax.jit, static_argnames="ax")
+def _block_merge(pool, li, new, plan: _WritePlan, b, j, *, ax: int):
+    """Lane ``b``'s touched block ``j`` read, merged with its window of the
+    (padded) new rows and written back: :func:`_write_kv`'s prefill update,
+    the lane and the block operands."""
+    bs = plan.keep.shape[2]
+    size = (1,) + new.shape[1:ax] + (bs,) + new.shape[ax + 1:]
+    at = (li, 0, plan.phys[b, j], 0, 0)
+    start = [b, 0, 0, 0, 0]
+    start[ax] = (j + 1) * bs - plan.off[b]
+    rows = jax.lax.dynamic_slice(new, start, size)
+    held = jax.lax.dynamic_slice(pool, at, size)
+    mask = plan.keep[b, j].reshape((bs,) + (1,) * (4 - ax))
+    return jax.lax.dynamic_update_slice(pool, jnp.where(mask, rows, held), at)
+
+
+@partial(jax.jit, static_argnames="ax")
+def _write_kv_by_calls(pool, li, new, plan: _WritePlan, *, ax: int):
+    """:func:`_write_kv` as ONE function of a program's text, every unrolled
+    update in it a CALL of one jitted function a kind of update, the lane
+    and the block its operands: the same updates in the same order (the
+    chip's compiler inlines the calls, and the pool is updated in place as
+    before), but one body a kind, and the decode lanes' updates, whose shapes
+    no chunk size changes, traced once a process. A decode call's unrolled
+    updates are two thirds of its layer body's text; the mixed program of
+    EVERY prefill shape carries them beside its chunk's merges, a pool and a
+    layer stack at a time, and would trace and lower all of it again at every
+    start. The two programs the mixed one replaces keep :func:`_write_kv`,
+    and their text."""
+    (B, n_touch), T = plan.phys.shape, new.shape[ax]
+    if T == 1:
+        for b in range(B):
+            pool = _slot_update(pool, li, new, plan, b, ax=ax)
+        return pool
+    bs = plan.keep.shape[2]
+    pad = [(0, 0)] * 5
+    pad[ax] = (bs, bs)
+    new = jnp.pad(new, pad)
+    for b in range(B):
+        for j in range(n_touch):
+            pool = _block_merge(pool, li, new, plan, b, j, ax=ax)
+    return pool
+
+
+class _CalledWrites(PagedCache):
+    """A :class:`PagedCache` of a :class:`MixedCache`: its writes go through
+    :func:`_write_kv_by_calls`."""
+
+    @staticmethod
+    def _write_kv(pool, li, new, ax, plan: _WritePlan):
+        # (the plan's block size is static: not an operand)
+        return _write_kv_by_calls(pool, li, new, plan._replace(bs=None),
+                                  ax=ax)
+
+
+@jax.tree_util.register_pytree_node_class
+class _LaneCalls(_CalledWrites):
+    """The decode lanes' half of a :class:`MixedCache`. Nothing of it
+    depends on the chunk's rows, so each of its per-layer methods is ONE call
+    of a jitted function with the cache itself an operand (its tables and
+    its write plan the leaves, the rest static): traced once a process and
+    found again by the mixed program of every other prefill shape, where a
+    decode call's cache work would be traced anew a shape."""
+    _LEAVES = ("bt", "q_start", "ctx", "slopes", "write_plan")
+
+    def tree_flatten(self):
+        static = tuple(sorted((k, v) for k, v in vars(self).items()
+                              if k not in self._LEAVES and k != "pools"))
+        return tuple(getattr(self, k) for k in self._LEAVES), static
+
+    @classmethod
+    def tree_unflatten(cls, static, leaves):
+        cache = object.__new__(cls)
+        vars(cache).update(static, **dict(zip(cls._LEAVES, leaves)))
+        return cache
+
+    def plan(self, T: int) -> None:
+        super().plan(T)
+        self.write_plan = self.write_plan._replace(bs=None)  # static: no leaf
+
+    def write(self, *args):
+        return _lane_call(self, "write", *args)
+
+    def write_index(self, *args):
+        return _lane_call(self, "write_index", *args)
+
+    def select(self, *args):
+        return _lane_call(self, "select", *args)
+
+    def attend(self, *args, select=None):
+        return _lane_call(self, "attend", *args, select)
+
+
+@partial(jax.jit, static_argnames="method")
+def _lane_call(cache: _LaneCalls, method: str, *args):
+    return getattr(_CalledWrites, method)(cache, *args)
+
+
+def _row_major(rows):
+    """``rows`` held to the row-major layout. The chunk's new K/V rows are
+    cut out of the call's ``T + B`` rows, and the chip's compiler names that
+    cut's layout as it pleases (the new axis of one is free); the block
+    merges take the pool in the layout of the rows they merge, so with both
+    kinds of update on one int8 pool it carried K blocks-major through the
+    merges and copied it whole, there and back, every layer
+    (tests/test_chip_compile.py holds the program to that)."""
+    from jax.experimental.layout import Layout, with_layout_constraint
+    return with_layout_constraint(
+        rows, Layout(major_to_minor=tuple(range(rows.ndim))))
+
+
+def _lane_rows(x):
+    """``[1, n, B, d]``, the lanes' one row each on the row axis of a call
+    of one sequence, as a decode call has them, ``[B, n, 1, d]``; and back."""
+    return x.transpose(2, 1, 0, 3)
+
+
+class MixedCache:
+    """Rows of two kinds behind ONE ``decoder_forward`` call of ``[1, T +
+    B]`` rows: a prefill chunk's ``T`` rows, then the decode lanes' one row
+    each. Everything that is a row's own (projections, norms, the router,
+    the experts, the head) sees one batch of rows, so a weight is read once;
+    what differs by kind is cut at row ``T`` and handed to the
+    :class:`PagedCache` of its kind over the SAME pools, in the form a
+    prefill call and a decode call give it: the chunk's block merges and the
+    paged kernel's chunk form, the lanes' one-slot writes and its decode
+    form, each kind's own selection. Both kinds' rows are written before
+    either attends; no lane of a call holds a block its chunk writes (a
+    prompt's lane joins the next call), so the order between the kinds is
+    nobody's to see. Not for a latent model (the serving loop never builds
+    one over a latent pool)."""
+
+    def __init__(self, chunk: PagedCache, lanes: PagedCache, T: int):
+        self.chunk, self.lanes, self.T = chunk, lanes, int(T)
+        self.quantized, self.rope_len = chunk.quantized, chunk.rope_len
+
+    def _cut(self, x, axis: int = 2):
+        """Rows ``[1, n, T + B, d]`` -> (the chunk's ``[1, n, T, d]``, the
+        lanes' ``[B, n, 1, d]``)."""
+        chunk, lanes = jnp.split(x, [self.T], axis=axis)
+        return chunk, _lane_rows(lanes)
+
+    @staticmethod
+    def _join(chunk, lanes):
+        return jnp.concatenate([chunk, _lane_rows(lanes)], axis=2)
+
+    def _cut_select(self, sel):
+        if sel is None:
+            return None, None
+        # scores [1, T + B, Kp], thr / tie [1, T + B]: rows on axis 1
+        cut = [jnp.split(a, [self.T], axis=1) for a in sel]
+        return (type(sel)(*(c for c, _ in cut)),
+                type(sel)(*(jnp.swapaxes(l, 0, 1) for _, l in cut)))
+
+    def positions(self, rows: int):
+        return jnp.concatenate([self.chunk.positions(self.T),
+                                self.lanes.positions(1).T], axis=1)
+
+    def plan(self, rows: int) -> None:
+        self.chunk.plan(self.T)
+        self.lanes.plan(1)
+
+    def real_tokens(self, pos):
+        """The real tokens of each kind over all the rows, the chunk's
+        first: a call's expert counts are a kind's own, as two calls'
+        were."""
+        chunk = self.chunk.real_tokens(pos[:, :self.T])           # [1, T]
+        lanes = self.lanes.real_tokens(pos[:, self.T:].T).T       # [1, B]
+        return (jnp.concatenate([chunk, jnp.zeros_like(lanes)], axis=1),
+                jnp.concatenate([jnp.zeros_like(chunk), lanes], axis=1))
+
+    def carry(self):
+        return self.chunk.carry()
+
+    def finish(self, carry, rows: int):
+        return self.chunk.finish(carry, self.T)
+
+    def write(self, kv, li, k, v, k_scale, v_scale):
+        parts = [(None, None) if t is None else self._cut(t)
+                 for t in (k, v, k_scale, v_scale)]
+        kv = self.chunk.write(kv, li, *(
+            None if c is None else _row_major(c) for c, _ in parts))
+        return self.lanes.write(kv, li, *(l for _, l in parts))
+
+    def write_index(self, kv, li, ki):
+        chunk, lanes = self._cut(ki)
+        return self.lanes.write_index(
+            self.chunk.write_index(kv, li, chunk), li, lanes)
+
+    def select(self, kv, li, qi, wi, window):
+        """Each kind's rows keep their own selection (the chunk's over its
+        sequence's keys, a lane's over its own); handed out as one
+        ``Selection`` over the call's rows."""
+        (qc, ql), (wc, wl) = self._cut(qi), jnp.split(wi, [self.T], axis=1)
+        chunk = self.chunk.select(kv, li, qc, wc, window)
+        lanes = self.lanes.select(kv, li, ql, jnp.swapaxes(wl, 0, 1), window)
+        return type(chunk)(*(jnp.concatenate([c, jnp.swapaxes(l, 0, 1)],
+                                             axis=1)
+                             for c, l in zip(chunk, lanes)))
+
+    def attend(self, kv, li, q, k, v, window, select=None):
+        (qc, ql), (kc, kl), (vc, vl) = map(self._cut, (q, k, v))
+        sel_c, sel_l = self._cut_select(select)
+        with jax.named_scope("chunk"):
+            chunk = self.chunk.attend(kv, li, qc, kc, vc, window,
+                                      select=sel_c)
+        with jax.named_scope("lanes"):
+            lanes = self.lanes.attend(kv, li, ql, kl, vl, window,
+                                      select=sel_l)
+        return self._join(chunk, lanes)
+
+
+def mixed_forward(cfg: TransformerConfig, params: PyTree,
+                  chunk_ids: jnp.ndarray, lane_ids: jnp.ndarray,
+                  pools: Dict[str, jnp.ndarray],
+                  chunk_at, lanes_at, block_size: int, head_rows, *,
+                  interpret: bool = False, expert_counts: bool = False,
+                  expert_picks: bool = False) -> Tuple[jnp.ndarray, ...]:
+    """A prefill call's and a decode call's work as ONE forward over ``[1,
+    T + B]`` rows (:class:`MixedCache`): ``chunk_ids`` ``[1, T]`` at
+    ``chunk_at`` and ``lane_ids`` ``[B]`` at ``lanes_at``, each ``(block
+    tables, q_start, context_lens)`` as :func:`paged_forward` takes them for
+    a call of that kind. Returns what ``paged_forward`` returns, with logits
+    ``[1, len(head_rows), V]`` of the rows ``head_rows`` alone (the head
+    reads no other), expert counts ``[L, 2, E]`` (the chunk's real rows, the
+    lanes') and picks ``[L, T + B, k]``."""
+    if cfg.kv_lora_rank:
+        raise NotImplementedError(
+            "mixed_forward over a latent pool: a latent model's chunk and "
+            "its lanes keep a program each")
+    T = chunk_ids.shape[1]
+    cache = MixedCache(
+        _CalledWrites(cfg, pools, *chunk_at, block_size, interpret),
+        _LaneCalls(cfg, pools, *lanes_at, block_size, interpret), T)
+    logits, pools, counts, *picks = decoder_forward(
+        cfg, params, jnp.concatenate([chunk_ids, lane_ids[None, :]], axis=1),
+        cache, interpret=interpret, expert_counts=expert_counts,
+        expert_picks=expert_picks, head_rows=head_rows)
+    if expert_counts:
+        return (logits, pools, counts, *picks)
+    return (logits, pools, *picks)
 
 
 def paged_forward(cfg: TransformerConfig,

@@ -573,6 +573,94 @@ def test_k_exaone_serving_step_holds_a_share_in_place(chip, monkeypatch,
                                  mem.temp_size_in_bytes)
 
 
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("cell_name", ["serve-mistral-7b-l16-chat",
+                                       "serve-k-exaone-236b-ep8-l5-mixed"])
+def test_mixed_step_is_one_program_in_place(chip, monkeypatch, cell_name,
+                                            quant):
+    """The program of a step that advances a prefill chunk where the cache
+    is not latent (``step_programs(..., mixed=True)``, PR 57), at the two
+    claimed cells' shapes read from the benchmark's own files: a chunk's 256
+    rows beside 32 lanes, over a bf16 pool and an int8 one. The rows of both
+    kinds go through the matmuls together (288 rows, no matmul of 256 or of
+    32); each layer body has the paged kernel twice, the chunk form and the
+    decode form; the outputs are the two programs' (the lanes' vector, the
+    chunk's, a mixture's counts behind each and its picks beside); and with
+    both kinds' writes in one body the pool still keeps the one layout it
+    has at the jit boundary: no whole pool, and no layer of one, is copied,
+    transposed, scattered or sliced out (PR 53 saw the chip's compiler name
+    an int8 pool blocks-major here and copy K whole a layer)."""
+    from benchmark import harness
+    from deepspeed_tpu.models import TransformerConfig, build_model
+    from deepspeed_tpu.models.generation import ensure_scan_layout
+    from deepspeed_tpu.serving.engine import (StepLayout, step_programs,
+                                              token_words)
+    from deepspeed_tpu.serving.kv_cache import init_pool
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cell = harness.load_cell(cell_name)
+    serving = cell.system["serving"]
+    BS, NB, B, NBK, T = (serving[k] for k in (
+        "block_size", "pool_blocks", "max_batch", "max_blocks_per_seq",
+        "prefill_chunk_tokens"))
+    assert (B, T) == (32, 256)
+    model, cfg = build_model(TransformerConfig(
+        **harness.load_family(cell.config["family"]).model_kwargs(
+            cell.config), dtype=jnp.bfloat16))
+    assert not cfg.kv_lora_rank
+    on_chip = lambda tree: jax.tree.map(lambda x: chip(x.shape, x.dtype), tree)
+    params = on_chip(jax.eval_shape(lambda: ensure_scan_layout(jax.tree.map(
+        lambda x: x.astype(jnp.bfloat16), model.init(
+            jax.random.PRNGKey(0),
+            {"input_ids": jnp.zeros((1, 8), jnp.int32)})["params"]),
+        cfg.num_layers)))
+    pools = on_chip(jax.eval_shape(lambda: init_pool(
+        cfg, NB, BS, jnp.int8 if quant else jnp.bfloat16)))
+    _prefill_rides_the_kernel(cfg, pools, BS)
+    layout = StepLayout(NBK)
+    mixed = step_programs(cfg, BS, NBK, mixed=True)[2]
+    fed = [chip((token_words(cfg, n),), jnp.int32) for n in (B, 1)]
+    compiled = jax.jit(mixed, donate_argnums=(1,)).lower(
+        params, pools, chip((layout.prefill_words(T)
+                             + layout.decode_words(B),), jnp.int32),
+        *fed).compile()
+    text = compiled.as_text()
+    (lanes, first), _ = compiled.out_info
+    counts = cfg.sparse_layers * cfg.moe_experts if cfg.moe_is_dropless else 0
+    if counts:
+        (lanes, lane_picks), (first, chunk_picks) = lanes, first
+        assert lane_picks.shape == (cfg.sparse_layers, B, cfg.moe_k)
+        assert chunk_picks.shape == (cfg.sparse_layers, T, cfg.moe_k)
+    assert lanes.shape == (B + counts,) and first.shape == (1 + counts,)
+    # the decode program's own operands: its outputs feed the next call
+    assert [lanes.shape, first.shape] == [f.shape for f in fed]
+    # one batch of rows through the matmuls; the head on the 33 it reads
+    H = cfg.hidden_size
+    widths = {(m.group(1), m.group(2)) for m in re.finditer(
+        r"= bf16\[(?:1,)?(\d+),(\d+)\]\S* (?:fusion|convolution|dot)\(",
+        text)}
+    rows = {int(r) for r, w in widths if int(w) >= H}
+    assert T + B in rows and not rows & {T, B}, sorted(widths)
+    assert re.search(r"\[(?:1,)?%d,%d\]" % (B + 1, cfg.vocab_size), text)
+    # each stack's layer body: the kernel's chunk form and its decode form
+    kernels = _kernel_scopes(text)
+    paged = [k for k in kernels if "paged_attention" in k]
+    stacks = 2 if cfg.dense_layers else 1
+    assert len(paged) == 2 * stacks, kernels
+    assert sum("attend/chunk" in k for k in paged) == stacks \
+        and sum("attend/lanes" in k for k in paged) == stacks, paged
+    assert len(kernels) == len(paged) + (3 if counts else 0), kernels
+    layer = cfg.kv_heads * NB * BS * cfg.head_dim
+    moved = [r for r in _results(text) if r[3] >= layer and (
+        r[1] in ("copy", "transpose", "scatter")
+        or r[1] == "dynamic-slice" and r[3] == layer)]
+    assert not moved, moved
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(
+        p.size * p.dtype.itemsize for p in pools.values())
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.0e9, (
+        mem.argument_size_in_bytes, mem.temp_size_in_bytes)
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill256"])
 def test_keye_serving_step_selects_keys_in_place(chip, monkeypatch, program):
     """The serving loop's two programs at the Keye-VL-2.0 cell's widths,

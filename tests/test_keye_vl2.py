@@ -337,9 +337,10 @@ def test_the_topk_counters_follow_the_tiles(table_blocks):
     srv.submit(rng.integers(1, 64, size=TOPK - 4).tolist(), max_new_tokens=2)
     srv.run_until_idle()
     c = counters()
-    # a chunk's 16 rows and a decode call's 4 lanes, in each of 3 layers
+    # a chunk's 16 rows beside the 4 (idle) lanes of its own call, and a
+    # decode call's 4 lanes, in each of 3 layers
     assert c["sparse.topk_tiles_sum"] == c["sparse.topk_tiles_idle_sum"] \
-        == 3 * (tiles(16) + tiles(4))
+        == 3 * (tiles(16) + 2 * tiles(4))
     assert c["sparse.topk_columns_sum"] == 0 \
         == c["sparse.topk_columns_table_sum"]
     srv.submit(rng.integers(1, 64, size=70).tolist(), max_new_tokens=6)
@@ -370,10 +371,12 @@ def test_the_score_counters_follow_the_lanes_tiles():
     srv.run_until_idle()
     c = dict(srv.telemetry()["counters"])
     srv.close()
-    # the prefill's last chunk gives the first token, a decode call each of
-    # the others: one lane of 120 to 130 tokens beside three idle ones
-    held = np.zeros((new - 1, 4), np.int64)
-    held[:, 0] = np.arange(prompt, prompt + new - 1)
+    # the prefill's eight chunks ride a call with four idle lanes each; its
+    # last gives the first token, a decode call each of the others: one lane
+    # of 120 to 130 tokens beside three idle ones
+    assert c["mixed.calls"] == 8 and c["mixed.lane_rows_sum"] == 0
+    held = np.zeros((8 + new - 1, 4), np.int64)
+    held[8:, 0] = np.arange(prompt, prompt + new - 1)
     first, end = ss.score_tiles(held, held + 1, 0, Kp, np)
     assert not first.any() and np.array_equal(
         end[:, 0], 1 + (held[:, 0] >= 128)) and (end[:, 1:] == 1).all()

@@ -360,7 +360,19 @@ def test_a_dense_configs_programs_and_counters_are_what_they_were():
         "step_inputs.transfers_sum",
         "step_inputs.lane_rows_written_sum", "decode_ahead.launched",
         "decode_ahead.device_lane_tokens_sum",
-        "decode_ahead.wasted_lane_tokens", "decode_ahead.retired_unread")
+        "decode_ahead.wasted_lane_tokens", "decode_ahead.retired_unread",
+        "mixed.calls", "mixed.lane_rows_sum")       # since PR 57
+    # ... and asked for, the third: a chunk and the lanes in one program,
+    # under the decode program's signature, returning both kinds' tokens
+    _, _, mixed = eng.step_programs(cfg, 16, 4, mixed=True)
+    assert mixed.__name__ == "_mixed"
+    assert inspect.signature(mixed) == inspect.signature(decode)
+    (tok, first), pools = jax.eval_shape(
+        mixed, srv.params, srv.pools, np.zeros(
+            (layout.prefill_words(T) + layout.decode_words(B),), np.int32),
+        srv._dec_out, srv._pre_out)
+    assert tok.shape == (B,) and first.shape == (1,) \
+        and set(pools) == set(srv.pools)
     srv.submit(list(tokens(40, 5)), max_new_tokens=4)
     srv.run_until_idle()
     assert srv.stats["completed"] == 1

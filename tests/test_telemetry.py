@@ -336,11 +336,14 @@ def test_the_dispatch_spans_name_the_program_they_launch(served):
     srv, reqs, outside = served
     programs = {(e[0], e[4].get("program")) for e in srv.rec.ring
                 if e[0].endswith(".dispatch")}
+    # (a step that advances a chunk launches the mixed program, the chunk's
+    # rows and the lanes': the cache is not latent)
     assert programs == {("serve.decode.dispatch", "jit__decode"),
-                        ("serve.prefill.dispatch", "jit__prefill")}
+                        ("serve.prefill.dispatch", "jit__mixed")}
     # the names a device trace gives their runs: "jit_" + the function's
     assert srv._decode_fn.__name__ == "_decode"
     assert srv._prefill_fn.__name__ == "_prefill"
+    assert srv._mixed_fn.__name__ == "_mixed"
     # nothing else was added to any span of a step
     assert {k for e in srv.rec.ring for k in e[4]
             if e[0] not in ("compile", "serve.decode.dispatch",
@@ -868,7 +871,9 @@ def test_programs_has_a_row_a_function_and_shape_with_what_it_cost(started):
         # 40, 70 and 20 tokens in chunks of 32: calls of 32, 8, 32, 32, 6
         # and 20 tokens in that order, in programs of 32 and of 16 rows: a
         # row is keyed by the tokens of the call that compiled it
-        prefills = {k[1]: r for k, r in by.items() if k[0] == "jit(_prefill)"}
+        # (the mixed program of that shape: the chunk's rows and the lanes')
+        assert not any(k[0] == "jit(_prefill)" for k in by)
+        prefills = {k[1]: r for k, r in by.items() if k[0] == "jit(_mixed)"}
         assert set(prefills) == {32, 8}
         assert all(r["span"] == "serve.prefill.dispatch" and r["compiles"]
                    == 1 and r["step"] is not None and r["rest_us"] > 0
