@@ -427,7 +427,7 @@ class DisaggEngine:
         elif isinstance(serving, dict):
             serving = ServingConfig(**serving)
         self.scfg = serving
-        self.shared = SharedPagedState(cfg, serving,
+        self.shared = SharedPagedState(cfg, params, serving,
                                        dtype=resolve_kv_dtype(serving))
         self.handoff = BlockHandoff(self.shared.pool,
                                     capacity=serving.handoff_queue)

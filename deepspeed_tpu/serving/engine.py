@@ -11,8 +11,9 @@ Fixed-shape discipline: the decode step is ONE jitted program over
 table. Admissions, evictions and completions only change the DATA in
 those arrays, never their shapes, so the loop compiles exactly one
 decode step for its whole lifetime (pinned by tests via
-``_cache_size``); prefills compile once per block-rounded prompt-suffix
-bucket (``_prefill_rows``): the prefill program, or, in a chunked engine
+``_cache_size``); prefills compile once per prompt-suffix bucket
+(``_prefill_rows``: whole blocks, whole 256-row tiles where the cache is
+latent): the prefill program, or, in a chunked engine
 whose cache is not latent, the mixed program that carries the chunk AND the
 decode lanes (``_mixed_step``). This is the role CUDA-graph capture plays in
 the reference's ``InferenceEngine`` — here XLA's compile cache IS the graph
@@ -81,6 +82,9 @@ PyTree = Any
 #: device call (``ServingEngine._mixed_step``: every step that advances a
 #: chunk, where the cache is not latent), and the live lane rows that rode
 #: them; both stay 0 where the chunk and the lanes keep a program each.
+#: ``prefill_rows``: the rows the prefill calls brought, ``prefill_tokens``
+#: and their padding (``_prefill_rows``): 1 - tokens / rows is the share of
+#: rows computed for nothing.
 _COUNTERS = (
     "completed", "failed", "timeout", "tokens_generated", "prefill_tokens",
     "prefix_hit_tokens", "preempted",
@@ -96,7 +100,7 @@ _COUNTERS = (
     "step_inputs.transfers_sum", "step_inputs.lane_rows_written_sum",
     "decode_ahead.launched", "decode_ahead.device_lane_tokens_sum",
     "decode_ahead.wasted_lane_tokens", "decode_ahead.retired_unread",
-    "mixed.calls", "mixed.lane_rows_sum")
+    "mixed.calls", "mixed.lane_rows_sum", "prefill_rows")
 #: a dropless MoE model's router load, from the [sparse layers, E] counts
 #: that ride the tokens' own fetch (``_count_experts``); a dense model has
 #: none of these. ``moe.held_assignments``: the assignments whose expert this
@@ -714,7 +718,7 @@ class ServingEngine:
         # IDs then mean the same pool slots to both roles, which is what
         # makes the prefill->decode handoff zero-copy
         self._shared = shared if shared is not None else SharedPagedState(
-            cfg, serving, dtype=kv_dtype, counters=self.stats)
+            cfg, self.params, serving, dtype=kv_dtype, counters=self.stats)
         # what a token costs the pool, from the pool itself (a grouped-query
         # model's is stored at its KV heads): 2 x layers x stored heads x
         # head_dim x item size, the int8 tier's scales and an indexer's one
@@ -1507,14 +1511,17 @@ class ServingEngine:
         return buf
 
     def _prefill_rows(self, n: int) -> int:
-        """Rows a prefill call of ``n`` tokens brings: whole blocks; under a
-        selection over a latent cache the whole 256-row tiles its chunk
-        kernel takes the rows' scores by (``latent_attention.chunk_tiles``:
-        fewer rows would be padded there, their scores copied, a layer), so
-        a chunk of 1 536 has six programs where whole blocks of 32 have
-        forty-eight, each with an indexer's kernels to compile."""
-        cfg = self.cfg
-        if cfg.kv_lora_rank and cfg.index_heads:
+        """Rows a prefill call of ``n`` tokens brings: the rows its cache's
+        chunk kernel computes anyway. A latent cache's takes a call's rows
+        as whole 256-row tiles (``latent_attention.chunk_tiles``: fewer are
+        padded there, and under a selection their scores copied, a layer),
+        so a chunk of 1 536 has six programs to trace, lower and compile at
+        a start where whole blocks of 32 have forty-eight; K/V pools' takes
+        whole blocks, and keeps them (a short prompt's last chunk padded to
+        a tile would be rows through every matmul of a mixed step for
+        nothing). ``prefill_rows`` beside ``prefill_tokens`` counts what
+        either rule pads."""
+        if self.cfg.kv_lora_rank:
             tiles, per = chunk_tiles(n)
             return tiles * per
         return -(-n // self.block_size) * self.block_size
@@ -1626,6 +1633,7 @@ class ServingEngine:
         pf.done += n
         req.prefill_progress = pf.done
         self.stats["prefill_tokens"] += n
+        self.stats["prefill_rows"] += self._prefill_rows(n)
         if pf.done < pf.total:
             return _ChunkOut(tok, self._calls)
         self._set_prefilling(None)
@@ -1834,6 +1842,7 @@ class ServingEngine:
             first = int(self._fetch(tok, self._calls)[0])
         req.prefill_progress = P
         self.stats["prefill_tokens"] += len(suffix)
+        self.stats["prefill_rows"] += self._prefill_rows(len(suffix))
         return self._first_token(_Prefilled(req, blocks, table, P, first))
 
     # ---------------------------------------------------------------- decode

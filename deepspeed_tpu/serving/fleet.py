@@ -245,7 +245,7 @@ class ServingFleet:
             from .disagg import BlockHandoff
             self.n_replicas = self.n_prefill + self.n_decode
             self._shared = SharedPagedState(
-                cfg, serving, dtype=resolve_kv_dtype(serving))
+                cfg, params, serving, dtype=resolve_kv_dtype(serving))
             self._handoff = BlockHandoff(
                 self._shared.pool, capacity=int(serving.handoff_queue),
                 on_push=self._register_handoff)

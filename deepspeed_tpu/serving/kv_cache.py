@@ -34,10 +34,12 @@ padded/inactive lanes in the fixed-shape decode step, and never read
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import threading
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from ..testing import chaos
@@ -52,7 +54,7 @@ class BlockPoolExhausted(RuntimeError):
 
 
 def init_pool(cfg, num_blocks: int, block_size: int,
-              dtype=None) -> Dict[str, jnp.ndarray]:
+              dtype=None, device=None) -> Dict[str, jnp.ndarray]:
     """Device-side paged pool: k/v ``[L, kvh, num_blocks*block_size, hd]``,
     ``kvh = cfg.kv_heads``: K and V are stored at the model's KV heads (a
     grouped-query model's ``num_heads // kv_heads`` query heads read one
@@ -106,28 +108,55 @@ def init_pool(cfg, num_blocks: int, block_size: int,
     halving pool HBM vs bf16. The paged forward quantizes on write;
     reads dequantize IN-kernel (round 17): the Pallas paged-attention
     kernel takes the int8 blocks plus scales and dequantizes per block
-    in VMEM, so int8 is what crosses HBM (no pool-slice f32 copy)."""
+    in VMEM, so int8 is what crosses HBM (no pool-slice f32 copy).
+
+    ``device`` (a device or a sharding; :func:`beside`): where the leaves
+    are made, COMMITTED there. Without one they are uncommitted arrays on
+    the default device, which a jitted call beside committed weights hands
+    back committed: its second call is then another signature, and the
+    program is compiled twice."""
     dtype = dtype or cfg.dtype
     slots = num_blocks * block_size
-    index = ({"ki": jnp.zeros((cfg.num_layers, 1, slots,
-                               -(-cfg.index_head_dim // 128) * 128),
-                              cfg.dtype)} if cfg.index_heads else {})
+    zeros = functools.partial(jnp.zeros, device=device)
+    index = ({"ki": zeros((cfg.num_layers, 1, slots,
+                           -(-cfg.index_head_dim // 128) * 128),
+                          cfg.dtype)} if cfg.index_heads else {})
     if cfg.kv_lora_rank:
         if dtype == jnp.int8:
             raise ValueError(
                 "an int8 KV pool with latent attention (kv_lora_rank): the "
                 "latent leaf has no quantized format (ROADMAP M4)")
-        return {"ckv": jnp.zeros((cfg.num_layers, 1, slots,
-                                  cfg.latent_lanes), dtype), **index}
+        return {"ckv": zeros((cfg.num_layers, 1, slots, cfg.latent_lanes),
+                             dtype), **index}
     shape = (cfg.num_layers, cfg.kv_heads, slots, cfg.head_dim)
     if dtype == jnp.int8:
-        return {"k": jnp.zeros(shape, jnp.int8),
-                "v": jnp.zeros(shape, jnp.int8),
-                "k_scale": jnp.zeros(shape[:-1] + (1,), jnp.float32),
-                "v_scale": jnp.zeros(shape[:-1] + (1,), jnp.float32),
+        return {"k": zeros(shape, jnp.int8), "v": zeros(shape, jnp.int8),
+                "k_scale": zeros(shape[:-1] + (1,), jnp.float32),
+                "v_scale": zeros(shape[:-1] + (1,), jnp.float32),
                 **index}
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
-            **index}
+    return {"k": zeros(shape, dtype), "v": zeros(shape, dtype), **index}
+
+
+def beside(params):
+    """Where a pool that is called beside ``params`` is made (:func:`
+    init_pool`'s ``device``): where the serving programs hand it back. They
+    return the pools on the weights' device under the weights' own kind of
+    sharding (``init_inference`` replicates them over its mesh, of one
+    device or of several; a tree put on a device sits there), so a pool
+    made there goes into its first call as it goes into every later one.
+    Weights that are committed nowhere (host arrays, a fresh ``init``'s)
+    leave every output uncommitted, and the pools with them: None. Weights
+    SPLIT over a mesh (tensor parallelism): the pools start replicated over
+    it; the compiler then splits them over the stored heads itself, and the
+    first call's shape is compiled once more, as it was (no rule here says
+    which split it will choose)."""
+    leaf = jax.tree_util.tree_leaves(params)[0]
+    if not getattr(leaf, "committed", False):
+        return None
+    if leaf.sharding.is_fully_replicated:
+        return leaf.sharding
+    return jax.sharding.NamedSharding(leaf.sharding.mesh,
+                                      jax.sharding.PartitionSpec())
 
 
 class BlockPool:
@@ -394,14 +423,19 @@ class SharedPagedState:
     serving/engine.py), so exactly one program may hold the live buffer
     at a time — each call takes the pools, runs, and writes the returned
     pools back under the lock. A single-threaded engine pays one
-    uncontended acquire per step."""
+    uncontended acquire per step.
 
-    def __init__(self, cfg, serving, dtype=None,
+    The pools are made :func:`beside` ``params``, the weights they will be
+    called with: committed where every call hands them back, so the first
+    call of a shape is the only one that compiles it."""
+
+    def __init__(self, cfg, params, serving, dtype=None,
                  counters: Optional[Dict[str, int]] = None):
         self.pool = BlockPool(serving.pool_blocks, serving.block_size,
                               counters=counters)
         self.pools: Dict[str, Any] = init_pool(
-            cfg, serving.pool_blocks, serving.block_size, dtype=dtype)
+            cfg, serving.pool_blocks, serving.block_size, dtype=dtype,
+            device=beside(params))
         self.prefix_cache = (PrefixCache(self.pool)
                              if serving.prefix_cache else None)
         self.device_lock = threading.Lock()

@@ -1,7 +1,9 @@
 #!/usr/bin/env python3
 """The serving loop's device programs as text, to compare two trees without
 the chip: ``serving.engine.step_programs``' decode and prefill step for the
-benchmark's serving configurations, lowered for a described TPU v5e from
+benchmark's serving configurations (a latent cache's prefill step at every
+256-row tile up to its chunk, the others' mixed step: the programs their
+engines make), lowered for a described TPU v5e from
 ``jax.ShapeDtypeStruct``s at the cells' shapes, with every source location
 stripped. Two trees whose texts are equal hand the chip's compiler the same
 programs; a refactor of ``paged_forward`` or of the layer under it is held
@@ -89,13 +91,23 @@ def programs(name: str, config, serving, *, int8: bool, chip):
     pools = on_chip(jax.eval_shape(lambda: init_pool(
         cfg, serving["pool_blocks"], bs,
         jnp.int8 if int8 else jnp.bfloat16)))
-    decode, prefill = step_programs(cfg, bs, nbk)
+    decode, prefill, *mixed = step_programs(cfg, bs, nbk,
+                                            mixed=not cfg.kv_lora_rank)
     layout = StepLayout(nbk)
     # the decode program reads, beside its buffer, the previous decode
-    # call's and the last final prefill call's outputs where they lie
-    calls = {"decode": (decode, layout.decode_words(lanes),
-                        [token_words(cfg, lanes), token_words(cfg, 1)]),
-             f"prefill{chunk}": (prefill, layout.prefill_words(chunk), [])}
+    # call's and the last final prefill call's outputs where they lie; the
+    # mixed program (a chunk and the lanes in one, where the cache is not
+    # latent) takes the decode program's operands
+    fed = [token_words(cfg, lanes), token_words(cfg, 1)]
+    calls = {"decode": (decode, layout.decode_words(lanes), fed)}
+    # a latent cache's engine brings its prefill calls in whole 256-row
+    # tiles (``ServingEngine._prefill_rows``): every program a chunked
+    # engine of the cell makes; the others' largest
+    for rows in range(256, chunk + 1, 256) if cfg.kv_lora_rank else [chunk]:
+        calls[f"prefill{rows}"] = (prefill, layout.prefill_words(rows), [])
+    for fn in mixed:
+        calls[f"mixed{chunk}"] = (fn, layout.prefill_words(chunk)
+                                  + layout.decode_words(lanes), fed)
     for label, (fn, words, fed) in calls.items():
         lowered = jax.jit(fn, donate_argnums=(1,)).lower(
             params, pools, *(chip((n,), jnp.int32) for n in [words] + fed))

@@ -748,8 +748,9 @@ def test_keye_serving_step_selects_keys_in_place(chip, monkeypatch, program):
                                          mem.temp_size_in_bytes)
 
 
-#: gpt2-1.3b's four serving programs (``scripts/serving_program_text.py``:
-#: the decode and the 256-row prefill step, bf16 and the int8 tier) as every
+#: gpt2-1.3b's six serving programs (``scripts/serving_program_text.py``:
+#: the decode, the 256-row prefill and, as PR 57 left it and the script
+#: lowers it since PR 60, the mixed step, bf16 and the int8 tier) as every
 #: tree since PR 44 has lowered them, sha256 of the text, first 16 digits. A
 #: PR for another model leaves them as they are (PR 26 was refused for a
 #: dense path it had touched); one that means to change the dense programs
@@ -758,8 +759,10 @@ def test_keye_serving_step_selects_keys_in_place(chip, monkeypatch, program):
 #: programs; the decode programs are PR 44's still
 _GPT2_PROGRAMS = {"gpt2-1.3b.decode": "da1edc35111a2336",
                   "gpt2-1.3b.prefill256": "36ca0b182b3cd0b3",
+                  "gpt2-1.3b.mixed256": "3fb973366bb85346",
                   "gpt2-1.3b-int8.decode": "b2066bebbff05fcd",
-                  "gpt2-1.3b-int8.prefill256": "5f8d1b0c90bf9aa6"}
+                  "gpt2-1.3b-int8.prefill256": "5f8d1b0c90bf9aa6",
+                  "gpt2-1.3b-int8.mixed256": "ed40ab4001efda60"}
 
 
 @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
@@ -825,7 +828,8 @@ def test_latent_chunk_call_compiles_at_any_row_count(chip, rows):
     assert len(calls) == 1 and f"bf16[1,128,{n * per},128]" in calls[0], calls
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill1536"])
+@pytest.mark.parametrize("program",
+                         ["decode", "prefill256", "prefill1536"])
 def test_deepseek_serving_step_keeps_the_latent_pool_in_place(
         chip, monkeypatch, program):
     """The serving loop's two programs at the DeepSeek-V2 cell's widths,
@@ -838,7 +842,9 @@ def test_deepseek_serving_step_keeps_the_latent_pool_in_place(
     grouped matmuls; the pool is ONE leaf, donated and updated in place, and
     no instruction copies it, a layer of it, or slices a layer out; the
     counts carry the kept groups behind the experts; and weights, pool and
-    temporaries fit the chip."""
+    temporaries fit the chip. A prefill call brings whole 256-row tiles
+    (``ServingEngine._prefill_rows``): the smallest program and the chunk's
+    own."""
     from benchmark import harness
     from deepspeed_tpu.models import TransformerConfig, build_model
     from deepspeed_tpu.serving.engine import (StepLayout, step_programs,
@@ -850,7 +856,7 @@ def test_deepseek_serving_step_keeps_the_latent_pool_in_place(
     BS, NB, B, NBK, chunk = (serving[k] for k in (
         "block_size", "pool_blocks", "max_batch", "max_blocks_per_seq",
         "prefill_chunk_tokens"))
-    assert program in ("decode", f"prefill{chunk}")
+    assert program in ("decode", "prefill256", f"prefill{chunk}")
     model, cfg = build_model(TransformerConfig(
         **harness.load_family("deepseek_v2").model_kwargs(cell.config),
         dtype=jnp.bfloat16))
@@ -871,13 +877,13 @@ def test_deepseek_serving_step_keeps_the_latent_pool_in_place(
                                                      jnp.bfloat16)))
     assert set(pools) == {"ckv"} and \
         pools["ckv"].shape == (L, 1, NB * BS, 640)
-    rows = B if program == "decode" else chunk
+    rows = B if program == "decode" else int(program[len("prefill"):])
     decode, prefill = step_programs(cfg, BS, NBK)
     if program == "decode":
         fn, words = decode, StepLayout(NBK).decode_words(B)
         fed = [chip((token_words(cfg, n),), jnp.int32) for n in (B, 1)]
     else:
-        fn, words, fed = prefill, StepLayout(NBK).prefill_words(chunk), []
+        fn, words, fed = prefill, StepLayout(NBK).prefill_words(rows), []
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(
         params, pools, chip((words,), jnp.int32), *fed).compile()
     text = compiled.as_text()
@@ -895,7 +901,7 @@ def test_deepseek_serving_step_keeps_the_latent_pool_in_place(
     calls = [line for line in text.splitlines()
              if "tpu_custom_call" in line and "paged_attention" in line]
     out = "bf16[32,1,128,512]" if program == "decode" else \
-        f"bf16[1,128,{chunk},128]"
+        f"bf16[1,128,{rows},128]"
     assert len(calls) == 2 and all(f" = {out}" in c for c in calls), calls
     assert ("absorb" in text) == (program == "decode")
     made = [r for r in _results(text) if r[1] not in (

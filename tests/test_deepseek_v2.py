@@ -412,8 +412,9 @@ def test_the_hand_out_follows_the_reference_and_a_wrong_router_reads_a_deficit(
     assert 0 < c["moe.held_assignments"] < c["moe.assignments"] == 2 * 6 * fed
     assert g["kv.bytes_per_token"] == 3 * 128 * 4 and \
         g["kv.latent_lanes"] == 128 and g["kv.stored_heads"] == 1
-    # (this engine was built on the jnp twins)
-    assert g["paged.prefill_path"] == {"reference": [16, 8]}
+    # (this engine was built on the jnp twins; chunks of 16 and a tail of 6
+    # or 3 tokens all come in one whole row tile)
+    assert g["paged.prefill_path"] == {"reference": [256]}
     # the absorbed twin expands nothing
     assert c["mla.chunk_keys_sum"] > 0 == c["mla.chunk_expanded_keys_sum"]
     srv.close()
@@ -437,7 +438,41 @@ def test_a_served_chunk_expands_each_cached_token_once(tiny):
     # chunks of 16, 16 and 13 rows see 16, 32 and 45 cached tokens, 3 layers
     assert c["mla.chunk_expanded_keys_sum"] == c["mla.chunk_keys_sum"] == \
         3 * (16 + 32 + 45)
-    assert t["gauges"]["paged.prefill_path"] == {"kernel (expanded)": [16]}
+    assert t["gauges"]["paged.prefill_path"] == {"kernel (expanded)": [256]}
+    srv.close()
+
+
+def test_three_chunks_ride_one_program_with_the_unpadded_tokens(tiny):
+    """Chunks of 16, 16 and 13 tokens go through ONE prefill program of a
+    whole 256-row tile (on the kernel, interpreted); the padding rows write
+    to the null block and are counted nowhere but in ``prefill_rows``: the
+    tokens are ``generate()``'s, which pads nothing."""
+    cfg, params = tiny
+    prompt = np.random.default_rng(5).integers(1, 64, size=45).tolist()
+    want = np.asarray(generate(cfg, params, jnp.asarray([prompt], jnp.int32),
+                               4))[0, len(prompt):].tolist()
+    srv = _engine(dataclasses.replace(cfg, attention_impl="auto"), params)
+    r = srv.submit(prompt, max_new_tokens=4)
+    srv.run_until_idle()
+    assert r.output_tokens == want
+    assert srv._prefill_fn._cache_size() == 1
+    assert srv.telemetry()["gauges"]["paged.prefill_path"] == \
+        {"kernel (expanded)": [256]}
+    c = srv.stats
+    assert (c["prefill_tokens"], c["prefill_rows"]) == (45, 3 * 256)
+    srv.close()
+
+
+@pytest.mark.parametrize("tokens, rows", [(1, 256), (256, 256), (257, 512),
+                                          (1536, 1536), (1537, 2048)])
+def test_a_prefill_call_brings_whole_row_tiles(tiny, tokens, rows):
+    """A latent cache needs no indexer for it: a prefill call's rows are the
+    whole 256-row tiles its chunk kernel computes anyway (a program a tile
+    count, not a program a block count)."""
+    cfg, params = tiny
+    assert cfg.kv_lora_rank and not cfg.index_heads
+    srv = _engine(cfg, params)
+    assert srv._prefill_rows(tokens) == rows
     srv.close()
 
 

@@ -481,15 +481,17 @@ def test_a_served_chunk_counts_its_tile_pairs(tiny):
 @pytest.mark.parametrize("tokens, rows", [(1, 256), (256, 256), (257, 512),
                                           (1536, 1536), (1537, 2048)])
 def test_a_prefill_call_brings_whole_row_tiles(tiny, tokens, rows):
-    """Under a selection over a latent cache a prefill call's rows are the
-    whole 256-row tiles its chunk kernel computes anyway (a program a tile
-    count, not a program a block count); a latent model WITHOUT an indexer
-    keeps whole blocks."""
+    """Over a latent cache a prefill call's rows are the whole 256-row tiles
+    its chunk kernel computes anyway (a program a tile count, not a program
+    a block count), with an indexer or without; a K/V model keeps whole
+    blocks."""
     cfg, params = tiny
     srv = _engine(cfg, params)
     assert srv._prefill_rows(tokens) == rows
     srv.cfg = types.SimpleNamespace(kv_lora_rank=cfg.kv_lora_rank,
                                     index_heads=0)
+    assert srv._prefill_rows(tokens) == rows
+    srv.cfg = types.SimpleNamespace(kv_lora_rank=0, index_heads=2)
     assert srv._prefill_rows(tokens) == -(-tokens // 8) * 8
     srv.close()
 

@@ -373,12 +373,14 @@ def _program_rows(srv):
                                        "jit(_mixed)"))
 
 
-#: ``_program_rows`` of the latent script below AT THE PARENT (commit 8c4e2ad,
-#: run there by this PR's builder): a latent engine compiles these and no
-#: other, whatever this PR added
+#: ``_program_rows`` of the latent script below AT THE PARENT of PR 57 (commit
+#: 8c4e2ad, run there by that PR's builder): a latent engine compiles these
+#: and no other, whatever that PR added. Since PR 60 a latent cache without an
+#: indexer brings whole 256-row tiles too: its chunks of 16 and 5 tokens are
+#: ONE program (named by the tokens of its first call), where whole blocks
+#: made two
 PARENT_LATENT_ROWS = {
-    "deepseek_v2": [("jit(_decode)", None, 1), ("jit(_prefill)", 5, 1),
-                    ("jit(_prefill)", 16, 1)],
+    "deepseek_v2": [("jit(_decode)", None, 1), ("jit(_prefill)", 16, 1)],
     "deepseek_v32": [("jit(_decode)", None, 1), ("jit(_prefill)", 16, 1)],
 }
 

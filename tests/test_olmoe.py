@@ -361,7 +361,8 @@ def test_a_dense_configs_programs_and_counters_are_what_they_were():
         "step_inputs.lane_rows_written_sum", "decode_ahead.launched",
         "decode_ahead.device_lane_tokens_sum",
         "decode_ahead.wasted_lane_tokens", "decode_ahead.retired_unread",
-        "mixed.calls", "mixed.lane_rows_sum")       # since PR 57
+        "mixed.calls", "mixed.lane_rows_sum",       # since PR 57
+        "prefill_rows")                             # since PR 60
     # ... and asked for, the third: a chunk and the lanes in one program,
     # under the decode program's signature, returning both kinds' tokens
     _, _, mixed = eng.step_programs(cfg, 16, 4, mixed=True)

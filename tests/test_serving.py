@@ -17,7 +17,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models import build_model
+from deepspeed_tpu.models import TransformerConfig, build_model
 from deepspeed_tpu.models.generation import generate
 from deepspeed_tpu.serving.engine import ServingEngine
 from deepspeed_tpu.serving.kv_cache import (BlockPool, BlockPoolExhausted,
@@ -1478,3 +1478,60 @@ def test_run_until_idle_reads_a_call_that_holds_dropped_lanes_only(tiny):
     assert req.output_tokens == want[:at + 1]
     assert srv.generate_batch([prompt], 9)[0] == want      # and again, whole
     assert srv._flight is None
+
+
+def _latent_tiny():
+    """A latent (MLA) model at the smallest widths: one ``ckv`` leaf."""
+    model, cfg = build_model(TransformerConfig(
+        vocab_size=64, max_seq_len=256, hidden_size=32, num_layers=2,
+        num_heads=2, kv_lora_rank=16, q_lora_rank=24, qk_nope_head_dim=8,
+        qk_rope_head_dim=4, v_head_dim=8, norm="rmsnorm", pos_embed="rotary",
+        rotary_interleaved=True, use_bias=False, tie_embeddings=False,
+        attention_impl="reference", dtype=jnp.float32))
+    ids = np.zeros((1, 8), np.int32)
+    return cfg, model.init(jax.random.PRNGKey(0), {"input_ids": ids})["params"]
+
+
+@pytest.mark.parametrize("kind", ["kv", "int8", "latent"])
+def test_the_pools_start_committed_and_the_first_shape_compiles_once(
+        tiny, kind):
+    """Beside committed weights (as ``init_inference`` hands them over) the
+    pools are committed, on the weights' device, when the constructor
+    returns: the first call hands them back as it got them, so the second
+    call of that shape is the same signature and compiles nothing. (Made
+    uncommitted they came back committed, and the engine's first program,
+    its largest in a long-document cell, was compiled twice.)"""
+    cfg, params = _latent_tiny() if kind == "latent" else tiny
+    device = jax.devices()[0]
+    params = jax.device_put(params, device)
+    srv = ServingEngine(cfg, params, serving=dict(
+        SERVE_CFG, kv_cache_dtype="int8" if kind == "int8" else None))
+    assert set(srv.pools) == {"kv": {"k", "v"}, "latent": {"ckv"},
+                              "int8": {"k", "v", "k_scale", "v_scale"}}[kind]
+    before = {k: (p.committed, p.sharding) for k, p in srv.pools.items()}
+    assert all(c and s.device_set == {device} for c, s in before.values())
+    for seed in (1, 2):                  # two prompts, one shape: two calls
+        srv.generate_batch(
+            [np.random.default_rng(seed).integers(1, 64, 12).tolist()], 2)
+        assert srv._prefill_fn._cache_size() == 1
+        assert {k: (p.committed, p.sharding)
+                for k, p in srv.pools.items()} == before
+    assert srv._decode_fn._cache_size() == 1
+    assert srv.stats["prefill_tokens"] == 24
+    srv.close()
+
+
+@pytest.mark.parametrize("chunk, calls", [(0, [37, 5]), (16, [16, 16, 5, 5])])
+def test_prefill_rows_count_the_calls_padding(tiny, chunk, calls):
+    """``prefill_rows`` beside ``prefill_tokens``: the rows the prefill
+    calls brought, their padding to whole blocks (of 16 here) with them."""
+    cfg, params = tiny
+    srv = ServingEngine(cfg, params, serving=dict(
+        SERVE_CFG, prefill_chunk_tokens=chunk, prefix_cache=False))
+    rng = np.random.default_rng(3)
+    srv.generate_batch([rng.integers(1, 64, n).tolist() for n in (37, 5)], 3)
+    rows = sum(-(-n // 16) * 16 for n in calls)
+    assert srv.stats["prefill_tokens"] == sum(calls) == 42
+    assert srv.stats["prefill_rows"] == rows
+    assert rows - 42 == sum(-n % 16 for n in calls)
+    srv.close()
